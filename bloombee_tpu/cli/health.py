@@ -261,7 +261,8 @@ def main(argv=None):
                     # compile-witness counters (BBTPU_JITWATCH=1 runs):
                     # ANY nonzero steady_state_recompiles means a decode
                     # bucket escaped warmup — a first-token compile stall
-                    # some session actually paid
+                    # some session actually paid; ANY kernel_fallbacks is a
+                    # Pallas kernel the device refused (witness on or off)
                     jit = {
                         k: probe[k]
                         for k in (
@@ -269,6 +270,7 @@ def main(argv=None):
                             "compile_ms_total",
                             "warmup_compiles",
                             "warmup_failures",
+                            "kernel_fallbacks",
                             "steady_state_recompiles",
                             "compile_cache_hits",
                             "preinstalled_warmup_misses",

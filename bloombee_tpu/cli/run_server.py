@@ -202,7 +202,9 @@ def main(argv=None):
                              "cache served to peers over artifact_get and "
                              "pre-fetched from covering peers before "
                              "warmup compiles anything (default follows "
-                             "BBTPU_ARTIFACT_DIR; unset = no store)")
+                             "BBTPU_ARTIFACT_DIR; unset = no store; where "
+                             "JAX_COMPILATION_CACHE_DIR is set the store "
+                             "serves that directory)")
     parser.add_argument("--log-level", default="INFO")
     args = parser.parse_args(argv)
     logging.basicConfig(level=args.log_level)
@@ -218,6 +220,16 @@ def main(argv=None):
     from bloombee_tpu.swarm.registry import make_registry
     from bloombee_tpu.swarm.spans import compute_spans
 
+    from bloombee_tpu.server import artifacts
+    from bloombee_tpu.utils import env
+
+    # one persistent compile cache per server process, placed by
+    # JAX_COMPILATION_CACHE_DIR, else the artifact store's directory, else
+    # the checkout's fixed path
+    cache_dir = artifacts.enable_persistent_cache(
+        args.artifact_dir or env.get("BBTPU_ARTIFACT_DIR") or None
+    )
+    logging.info("persistent compile cache: %s", cache_dir)
     dtype = jnp.bfloat16 if args.dtype == "bfloat16" else jnp.float32
     # parse the registry spec BEFORE model resolution: a typo'd --registry
     # must fail fast, not after a multi-GB hub download
@@ -289,19 +301,26 @@ def main(argv=None):
             artifact_dir=args.artifact_dir,
         )
         await server.start()
+        warm = None
         if args.warmup_batches:
             batches = tuple(
                 int(x) for x in args.warmup_batches.split(",") if x
             )
-            server._warmup_task = asyncio.create_task(
+            warm = server._warmup_task = asyncio.create_task(
                 server.warmup(batches)
             )
         from bloombee_tpu.server.throughput import measure_and_announce
 
+        async def measure_when_warm():
+            # after the warm-up, not beside it: both feed the one compute
+            # queue, and decode steps timed between warm-up compiles
+            # announce the compiler's speed as the device's
+            if warm is not None:
+                await asyncio.wait([warm])
+            return await measure_and_announce(server)
+
         # keep a strong reference: the loop holds tasks only weakly
-        server._throughput_task = asyncio.create_task(
-            measure_and_announce(server)
-        )
+        server._throughput_task = asyncio.create_task(measure_when_warm())
         logging.info(
             "server %s serving %s[%d:%d) on port %d",
             server.server_id, model_uid, start, end, server.port,

@@ -109,9 +109,9 @@ class _DaemonPool:
     """Two-worker submit() pool built on daemon threads.
 
     concurrent.futures.ThreadPoolExecutor joins its (non-daemon) workers at
-    interpreter exit — with a PJRT-wedged d2h copy in flight that join
-    blocks forever and the process can never exit. Daemon threads let the
-    interpreter die with the wedge still pending."""
+    interpreter exit, so a d2h copy that never returns would keep the
+    process from exiting. Daemon threads let the interpreter die with the
+    copy still pending."""
 
     def __init__(self, max_workers: int = 2, name: str = "kv-park"):
         import concurrent.futures
@@ -1065,7 +1065,10 @@ class CacheManager:
         """KV-side byte/token accounting for the memory-observability
         surface (utils/memory.py) — kept here so it reads this manager's
         state through one accessor instead of private attributes."""
-        from bloombee_tpu.utils.memory import tree_nbytes
+        from bloombee_tpu.utils.memory import (
+            tree_nbytes,
+            tree_nbytes_by_device,
+        )
 
         parked_resolved = 0
         parked_total = 0
@@ -1075,6 +1078,7 @@ class CacheManager:
                 parked_resolved += tree_nbytes(entry.host)
         return {
             "kv_arena_bytes": tree_nbytes(self.arena),
+            "kv_arena_bytes_by_device": tree_nbytes_by_device(self.arena),
             "parked_kv_host_bytes": parked_resolved,
             "parked_seqs": parked_total,
             "kv_tokens_reserved": int(self._reserved_tokens),

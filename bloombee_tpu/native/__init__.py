@@ -1,8 +1,10 @@
 """Native (C++) runtime components, loaded via ctypes.
 
 Compiled lazily on first use with the system toolchain and cached under
-~/.cache/bloombee_tpu; every caller must tolerate `None` (pure-Python
-fallback) so the framework works on toolchain-less hosts.
+~/.cache/bloombee_tpu, keyed by a hash of the source; every caller must
+tolerate `None` (pure-Python fallback) so the framework works on
+toolchain-less hosts. The fallback is host-only and silent at the call
+site; `loaded()` says which is in use (a server logs it at start-up).
 """
 
 from __future__ import annotations
@@ -117,3 +119,12 @@ def byte_split_lib():
     except Exception as e:  # pragma: no cover
         logger.info("native load failed (%s); using numpy fallback", e)
     return _byte_split_lib
+
+
+def loaded() -> dict[str, bool]:
+    """Which native components this process runs on (False = the numpy /
+    pure-Python fallback). Builds or loads them if nothing has yet."""
+    return {
+        "paged_table": paged_table_lib() is not None,
+        "byte_split": byte_split_lib() is not None,
+    }

@@ -165,14 +165,29 @@ def _kernel(
     )
 
 
+def _f16_bits_to_f32(bits: jax.Array) -> jax.Array:
+    """Decode float16 bit patterns (uint16) to f32 with integer ops. Mosaic
+    cannot load f16 vectors, so the int4 kernel takes the slab's f16
+    scale/zero leaves bitcast to uint16 (free in XLA) and rebuilds the
+    values here; exact for every finite f16, subnormals and -0 included."""
+    bits = bits.astype(jnp.int32)
+    exp = (bits >> 10) & 0x1F
+    mant = bits & 0x3FF
+    normal = jax.lax.bitcast_convert_type(
+        ((exp + 112) << 23) | (mant << 13), jnp.float32
+    )
+    mag = jnp.where(exp == 0, mant.astype(jnp.float32) * 2.0**-24, normal)
+    return jnp.where(bits >> 15 == 1, -mag, mag)
+
+
 def _int4_kernel(
     pt_ref,  # [B, NP] i32 scalar prefetch
     lens_ref,  # [B] i32
     win_ref,  # [1] i32
     q_ref,  # [H, hd] — PERMUTED head dim (evens then odds)
     kc_ref,  # [page_size * Hkv, hd // 2] u8 int4 codes, current page
-    ks_ref,  # [page_size * Hkv, groups] f16 scales
-    kz_ref,  # [page_size * Hkv, groups] f16 zeros
+    ks_ref,  # [page_size * Hkv, groups] u16: the f16 scales' BITS
+    kz_ref,  # [page_size * Hkv, groups] u16: the f16 zeros' BITS
     vc_ref,
     vs_ref,
     vz_ref,
@@ -201,9 +216,10 @@ def _int4_kernel(
     per = half // groups  # permuted lanes per original group, per half
 
     def deq(codes_ref, s_ref, z_ref):
-        codes = codes_ref[...]
-        s = s_ref[...].astype(jnp.float32)
-        z = z_ref[...].astype(jnp.float32)
+        # widen before the nibble math: Mosaic has no uint8 -> f32 cast
+        codes = codes_ref[...].astype(jnp.int32)
+        s = _f16_bits_to_f32(s_ref[...])
+        z = _f16_bits_to_f32(z_ref[...])
         lo = (codes & 0xF).astype(jnp.float32)
         hi = (codes >> 4).astype(jnp.float32)
         halves = []
@@ -265,15 +281,18 @@ def paged_decode_attention_int4(
     def pages(x, last):
         return x.reshape(-1, rows, last)
 
+    def f16_bits(x):
+        return jax.lax.bitcast_convert_type(x, jnp.uint16)
+
     kc, ks, kz = (
         pages(k_slab.codes, hd // 2),
-        pages(k_slab.scale, groups),
-        pages(k_slab.zero, groups),
+        pages(f16_bits(k_slab.scale), groups),
+        pages(f16_bits(k_slab.zero), groups),
     )
     vc, vs, vz = (
         pages(v_slab.codes, hd // 2),
-        pages(v_slab.scale, groups),
-        pages(v_slab.zero, groups),
+        pages(f16_bits(v_slab.scale), groups),
+        pages(f16_bits(v_slab.zero), groups),
     )
 
     kv_index = _make_kv_index(page_size)

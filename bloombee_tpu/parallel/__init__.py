@@ -15,33 +15,6 @@ TPU-native constructs:
   one jit (the swarm-level span pipeline remains inter-host over the wire).
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.6 API drift shim: package code and tests call
-    # jax.shard_map(..., check_vma=False) (the current spelling); older
-    # jax only ships jax.experimental.shard_map.shard_map with the
-    # equivalent knob named check_rep. Install a top-level alias that
-    # translates, so both jax versions run the same call sites.
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    def _shard_map_compat(*args, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _legacy_shard_map(*args, **kwargs)
-
-    _jax.shard_map = _shard_map_compat
-
-if not hasattr(_jax.lax, "axis_size"):
-    # same drift: lax.axis_size is the current spelling; on older jax
-    # psum of the literal 1 constant-folds to the static axis size (a
-    # plain int, safe in Python control flow)
-
-    def _axis_size_compat(axis_name):
-        return _jax.lax.psum(1, axis_name)
-
-    _jax.lax.axis_size = _axis_size_compat
-
 from bloombee_tpu.parallel.mesh import make_mesh, MeshConfig
 from bloombee_tpu.parallel.ring_attention import ring_attention
 from bloombee_tpu.parallel.ulysses import ulysses_attention

@@ -11,6 +11,7 @@ executable (the CUDA-graph role of the reference's cuda_graphs.py).
 from __future__ import annotations
 
 import functools
+import logging
 
 import numpy as np
 
@@ -30,6 +31,8 @@ from bloombee_tpu.runtime.step import (
 )
 from bloombee_tpu.utils import env, jitwatch
 
+logger = logging.getLogger(__name__)
+
 env.declare(
     "BBTPU_FLASH_ATTENTION", bool, True,
     "use the Pallas flash kernel for eligible long prefill steps (T>=128, "
@@ -39,23 +42,24 @@ env.declare(
     "BBTPU_PAGED_ATTENTION", bool, True,
     "use the Pallas paged-attention kernel for eligible single-token decode "
     "steps (T=1, dense arena, no tree/window/alibi/softcap); TPU backend "
-    "only unless BBTPU_PAGED_INTERPRET forces the interpreter (tests)",
+    "only unless BBTPU_PAGED_INTERPRET asks for the interpreter (tests)",
 )
 env.declare(
     "BBTPU_PAGED_MIN_CONTEXT", int, 512,
     "use the paged decode kernel only when the bucketed context is at least "
-    "this many tokens (measured crossover vs the dense gather path on v5e: "
-    "dense wins at 256, paged wins 1k+ and is 1.5x at 4k)",
+    "this many tokens (the crossover vs the dense gather path; where it "
+    "really lies on the current chip is not measured)",
 )
 env.declare(
     "BBTPU_PAGED_INTERPRET", bool, False,
-    "run the paged decode kernel in interpreter mode on non-TPU backends "
-    "(CPU parity tests; far too slow for production)",
+    "run the paged kernels in Pallas interpreter mode: the only way onto "
+    "the kernel path off a TPU (CPU parity tests; far too slow to serve)",
 )
 env.declare(
     "BBTPU_FLASH_INTERPRET", bool, False,
-    "run the flash prefill kernel in interpreter mode on non-TPU backends "
-    "(CPU parity tests; far too slow for production)",
+    "run the flash prefill kernel in Pallas interpreter mode: the only way "
+    "onto the kernel path off a TPU (CPU parity tests; far too slow to "
+    "serve)",
 )
 env.declare(
     "BBTPU_SP_MIN_TOKENS", int, 1024,
@@ -71,6 +75,12 @@ env.declare(
     "queue task for the whole prompt). Rounded to a power of two so every "
     "chunk hits the same compiled bucket",
 )
+
+
+def _kernels_available(interpret_switch: str) -> bool:
+    """Pallas kernels compile for a TPU; on any other backend the kernel
+    path is taken only through the explicit interpret switch (tests)."""
+    return jax.default_backend() == "tpu" or bool(env.get(interpret_switch))
 
 
 def next_pow2(n: int, floor: int = 1) -> int:
@@ -260,6 +270,13 @@ class SpanExecutor:
             ml_dtypes.bfloat16 if compute_dtype == jnp.bfloat16 else np.float32
         )
         self.page_size = manager.page_size
+        # which attention path each device dispatch took, and how often a
+        # failing Pallas kernel gave way to the dense path (each one is a
+        # bug to report: rpc_info / health --probe surface both)
+        self.attn_dispatches = {"flash": 0, "paged": 0, "ragged": 0,
+                                "dense": 0}
+        self.kernel_fallbacks = 0
+        self._paged_broken = False
 
     # ------------------------------------------------------------------ steps
     def prefill(
@@ -276,8 +293,8 @@ class SpanExecutor:
 
         With fetch=False the (lazy) device array is returned instead of a
         host copy — callers fetch it OUTSIDE the serialized compute queue so
-        concurrent sessions' d2h round trips overlap (the round trip, not
-        compute, dominates per-step latency on DCN/tunnel-attached hosts).
+        concurrent sessions' d2h round trips overlap (one session's step
+        cannot hide its own dependent round trip; another session's can).
         """
         outs = []
         t = hidden.shape[1]
@@ -423,19 +440,15 @@ class SpanExecutor:
             vs.reshape(l, b * t_pad, hkv, hd), dev0
         )
         arena = self.manager.arena
-        try:
+
+        def _run(_use_kernel: bool):
             with jitwatch.region("arena_write_all", f"b{b},t{t_pad}"):
-                new_k, new_v = _arena_write_all(
+                return _arena_write_all(
                     arena["k"], arena["v"],
                     jnp.asarray(slots_pad.reshape(-1)), k_new, v_new,
                 )
-        except Exception:
-            # same contract as every other donated-arena step: a runtime
-            # failure after donation leaves deleted buffers — rebuild so
-            # the server survives (sessions replay), then re-raise
-            if self._arena_consumed(arena):
-                self._rebuild_after_failure("sp prefill")
-            raise
+
+        (new_k, new_v), _ = self._dispatch(_run, False, arena, "sp prefill")
         self.manager.arena = {"k": new_k, "v": new_v}
         out = out[:, :t]
         if not fetch:
@@ -734,7 +747,7 @@ class SpanExecutor:
         # spans run the dense attend_ragged path). Ineligible configs run
         # attend_ragged — still ONE dispatch.
         use_kernel = bool(
-            not getattr(self, "_paged_broken", False)
+            not self._paged_broken
             and self.mesh is None
             and self.manager.quant is None
             and rb * spec.num_attention_heads <= 2048
@@ -742,10 +755,7 @@ class SpanExecutor:
             and not spec.alibi
             and not spec.attn_logit_softcap
             and env.get("BBTPU_PAGED_ATTENTION")
-            and (
-                jax.default_backend() == "tpu"
-                or env.get("BBTPU_PAGED_INTERPRET")
-            )
+            and _kernels_available("BBTPU_PAGED_INTERPRET")
         )
 
         payload = pack_step_payload(h_pad, plan)
@@ -778,24 +788,10 @@ class SpanExecutor:
                     **step_kwargs,
                 )
 
-        try:
-            out, new_k, new_v = _run(use_kernel)
-        except Exception:
-            # same self-heal contract as _step: retry on the dense ragged
-            # path only if the donated arena buffers are still alive
-            if self._arena_consumed(arena):
-                self._rebuild_after_failure("ragged group step")
-                raise
-            if not use_kernel:
-                raise
-            import logging
-
-            logging.getLogger(__name__).exception(
-                "paged ragged kernel failed; retrying on the dense "
-                "ragged path"
-            )
-            out, new_k, new_v = _run(False)
-            self._paged_broken = True
+        (out, new_k, new_v), used_kernel = self._dispatch(
+            _run, use_kernel, arena, "ragged group step"
+        )
+        self.attn_dispatches["ragged" if used_kernel else "dense"] += 1
         self.manager.arena = {"k": new_k, "v": new_v}
         return out[0, :r], combined
 
@@ -900,16 +896,13 @@ class SpanExecutor:
             arena_tokens // self.page_size,
         )
         use_paged = bool(
-            not getattr(self, "_paged_broken", False)
+            not self._paged_broken
             and pb_start * self.page_size
             >= env.get("BBTPU_PAGED_MIN_CONTEXT")
             and not spec.alibi
             and not spec.attn_logit_softcap
             and env.get("BBTPU_PAGED_ATTENTION")
-            and (
-                jax.default_backend() == "tpu"
-                or env.get("BBTPU_PAGED_INTERPRET")
-            )
+            and _kernels_available("BBTPU_PAGED_INTERPRET")
         )
         ids_pad = np.zeros((bb,), np.int32)
         ids_pad[:b] = np.asarray(ids).reshape(-1)
@@ -936,24 +929,10 @@ class SpanExecutor:
                     use_paged=use_paged_now,
                 )
 
-        try:
-            toks, new_k, new_v = _run(use_paged)
-        except Exception:
-            # same self-heal contract as _step: retry on the gather path
-            # only if the donated arena buffers are still alive
-            if self._arena_consumed(arena):
-                self._rebuild_after_failure("decode_n")
-                raise
-            if not use_paged:
-                raise
-            import logging
-
-            logging.getLogger(__name__).exception(
-                "paged decode kernel failed in decode_n; retrying on the "
-                "dense gather path"
-            )
-            toks, new_k, new_v = _run(False)
-            self._paged_broken = True
+        (toks, new_k, new_v), used_paged = self._dispatch(
+            _run, use_paged, arena, "decode_n"
+        )
+        self.attn_dispatches["paged" if used_paged else "dense"] += 1
         self.manager.arena = {"k": new_k, "v": new_v}
         return toks[:b, :n]
 
@@ -981,14 +960,40 @@ class SpanExecutor:
             for a in jax.tree.leaves((arena["k"], arena["v"]))
         )
 
+    def _dispatch(self, run, use_kernel: bool, arena, where: str):
+        """Run one donated-arena dispatch: `run(use_kernel)` -> result.
+        Returns (result, kernel actually used).
+
+        A failure after donation consumed the arena leaves deleted
+        buffers: rebuild so the server survives (sessions replay), then
+        re-raise. A failure BEFORE donation (a compile error surfaces at
+        call time) on the Pallas path retries once on the dense path; that
+        keeps the server answering, but it is a kernel bug, so it is
+        logged, counted in kernel_fallbacks, and the kernel path stays
+        off for the life of the process."""
+        try:
+            return run(use_kernel), use_kernel
+        except Exception:
+            if self._arena_consumed(arena):
+                self._rebuild_after_failure(where)
+                raise
+            if not use_kernel:
+                raise
+            logger.exception(
+                "Pallas attention kernel failed in %s; retrying on the "
+                "dense path", where,
+            )
+            result = run(False)
+            self._paged_broken = True
+            self.kernel_fallbacks += 1
+            return result, False
+
     def _rebuild_after_failure(self, where: str) -> None:
         """A failure consumed the donated arena mid-chain: without a fresh
         arena every later step would compute on deleted buffers, bricking
         the server. Rebuild (zeroed) and bump the epoch so pre-rebuild
         sessions fail loudly and their clients replay (advisor, round 2)."""
-        import logging
-
-        logging.getLogger(__name__).error(
+        logger.error(
             "%s failed after the donated arena was consumed; rebuilding a "
             "fresh arena — live sessions' KV is lost and their clients "
             "must replay", where,
@@ -1179,7 +1184,7 @@ class SpanExecutor:
             and (tree_mask is None or all(w == 0 for w in self.windows))
         )
         use_paged = bool(
-            not getattr(self, "_paged_broken", False)
+            not self._paged_broken
             and self.attn_sparsity >= 1.0  # kernel has no top-k path
             and pb * self.page_size >= env.get("BBTPU_PAGED_MIN_CONTEXT")
             and self.mesh is None  # Pallas kernels don't GSPMD-partition
@@ -1188,10 +1193,7 @@ class SpanExecutor:
             and not self.spec.alibi
             and not self.spec.attn_logit_softcap
             and env.get("BBTPU_PAGED_ATTENTION")
-            and (
-                jax.default_backend() == "tpu"
-                or env.get("BBTPU_PAGED_INTERPRET")
-            )
+            and _kernels_available("BBTPU_PAGED_INTERPRET")
         )
 
         # flash eligibility: per-row starts/lens ride into the kernel as
@@ -1214,10 +1216,7 @@ class SpanExecutor:
             and all(w == 0 for w in self.windows)
             and np.all(total_lens == starts + t)
             and env.get("BBTPU_FLASH_ATTENTION")
-            and (
-                jax.default_backend() == "tpu"
-                or env.get("BBTPU_FLASH_INTERPRET")
-            )
+            and _kernels_available("BBTPU_FLASH_INTERPRET")
         )
 
         attn_topk = 0
@@ -1247,35 +1246,19 @@ class SpanExecutor:
                         use_paged_now, attn_topk, t_real=t,
                     )
 
-            try:
-                out, new_k, new_v = _run_off(use_paged)
-            except Exception:
-                # same self-heal contract as the dense branch below: retry
-                # on the gather path only if the donated arena buffers are
-                # still alive (a compile failure surfaces before donation
-                # consumes them; a mid-chain runtime failure does not)
-                if self._arena_consumed(arena):
-                    self._rebuild_after_failure("offloaded step")
-                    raise
-                if not use_paged:
-                    raise
-                import logging
-
-                logging.getLogger(__name__).exception(
-                    "paged decode kernel failed in the offload path; "
-                    "retrying on the dense gather path"
-                )
-                out, new_k, new_v = _run_off(False)
-                self._paged_broken = True
+            (out, new_k, new_v), use_paged = self._dispatch(
+                _run_off, use_paged, arena, "offloaded step"
+            )
         elif self.spec.heterogeneous:
             from bloombee_tpu.runtime.hetero import span_step_hetero
 
             payload_dev, tm_dev = self._place_step_inputs(h_pad, plan, tm_pad)
-            try:
+
+            def _run_hetero(_use_kernel: bool):
                 with jitwatch.region(
                     "span_step_hetero", f"b{bb},t{tb},p{pb}"
                 ):
-                    out, new_k, new_v = span_step_hetero(  # bbtpu: noqa[BB012] layer_active is the hetero residency mask — one value per (span, offload split), not per request
+                    return span_step_hetero(  # bbtpu: noqa[BB012] layer_active is the hetero residency mask — one value per (span, offload split), not per request
                         self.params,
                         arena["k"],
                         arena["v"],
@@ -1292,13 +1275,10 @@ class SpanExecutor:
                         layer_active=tuple(int(x) for x in layer_active),
                         attn_topk=attn_topk,
                     )
-            except Exception:
-                # same donated-arena contract as the dense branch: a
-                # runtime failure after donation must rebuild so the
-                # server survives (sessions replay), then re-raise
-                if self._arena_consumed(arena):
-                    self._rebuild_after_failure("hetero span step")
-                raise
+
+            (out, new_k, new_v), _ = self._dispatch(
+                _run_hetero, False, arena, "hetero span step"
+            )
         else:
             payload_dev, tm_dev = self._place_step_inputs(h_pad, plan, tm_pad)
 
@@ -1324,30 +1304,11 @@ class SpanExecutor:
                         t_real=t,
                     )
 
-            try:
-                out, new_k, new_v = _run(use_paged)
-            except Exception:
-                # Only the paged-kernel path self-heals, and only when the
-                # donated arena buffers are still alive (a compile failure
-                # surfaces at call time BEFORE donation consumes them; if a
-                # runtime failure already ate the arena, retrying would
-                # compute on deleted buffers — rebuild so the server
-                # survives, then re-raise the real error).
-                if self._arena_consumed(arena):
-                    self._rebuild_after_failure("span step")
-                    raise
-                if not use_paged:
-                    raise
-                import logging
-
-                logging.getLogger(__name__).exception(
-                    "paged decode kernel failed; retrying on the dense "
-                    "gather path"
-                )
-                out, new_k, new_v = _run(False)
-                # the dense path works while paged does not -> the kernel
-                # itself is broken on this backend; stop trying it
-                self._paged_broken = True
+            (out, new_k, new_v), use_paged = self._dispatch(
+                _run, use_paged, arena, "span step"
+            )
+        path = "paged" if use_paged else "flash" if use_flash else "dense"
+        self.attn_dispatches[path] += 1
         self.manager.arena = {"k": new_k, "v": new_v}
         out = out[:b, :t]
         if not fetch:

@@ -31,6 +31,7 @@ from bloombee_tpu.ops.alibi import alibi_slopes
 from bloombee_tpu.ops.attention import NEG_INF, repeat_kv
 from bloombee_tpu.ops.moe import moe_mlp
 from bloombee_tpu.ops.norms import layer_norm
+from bloombee_tpu.utils import env
 
 
 def _norm(x, params, key, spec):
@@ -223,7 +224,9 @@ def layer_body(
             paged_decode_attention_int4,
         )
 
-        interpret = jax.default_backend() != "tpu"
+        # the kernels compile for the device; interpret mode is only ever
+        # the explicit test switch (read at trace time, like the spec)
+        interpret = env.get("BBTPU_PAGED_INTERPRET")
         if t == 1:
             kernel = (
                 paged_decode_attention_int4
@@ -233,8 +236,6 @@ def layer_body(
             attn = kernel(
                 q[:, 0], k_slab, v_slab, page_table, total_lens,
                 page_size=page_size, scale=attn_scale(spec),
-                # Mosaic only exists on TPU; any other backend that
-                # reaches here (BBTPU_PAGED_INTERPRET) interprets
                 interpret=interpret,
                 window=window,  # per-layer traced scalar (0 = full)
             )[:, None]  # [B, 1, H, hd]
@@ -267,7 +268,7 @@ def layer_body(
         attn = flash_attention(
             q, k_ctx, v_ctx, causal=True, scale=attn_scale(spec),
             starts=q_positions[:, 0], lens=total_lens,
-            interpret=jax.default_backend() != "tpu",
+            interpret=env.get("BBTPU_FLASH_INTERPRET"),
         )
     else:
         attn = attend_paged(
@@ -412,7 +413,7 @@ def layer_body_ragged(
             q[0], k_slab, v_slab, page_table, total_lens,
             q_seq, q_positions[0],
             page_size=page_size, scale=attn_scale(spec),
-            interpret=jax.default_backend() != "tpu",
+            interpret=env.get("BBTPU_PAGED_INTERPRET"),
             window=window, nt=nt, tree_rows=tree_rows,
             has_tree=tree_rows is not None,
         )[None]

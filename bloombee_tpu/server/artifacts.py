@@ -36,6 +36,7 @@ import hashlib
 import json
 import logging
 import os
+import pathlib
 
 from bloombee_tpu.utils import env
 
@@ -44,10 +45,12 @@ logger = logging.getLogger(__name__)
 env.declare(
     "BBTPU_ARTIFACT_DIR", str, "",
     "directory for the swarm-shared compile-artifact store (doubles as "
-    "this process's JAX persistent compilation cache dir). Servers with "
-    "a store serve artifact_get, push artifacts to standbys alongside "
-    "KV replication, and pre-install fetched artifacts before warmup. "
-    "Empty = artifact path off (compile locally, serve/fetch nothing)",
+    "this process's JAX persistent compilation cache dir; where "
+    "JAX_COMPILATION_CACHE_DIR is set, that directory is used instead and "
+    "this switch only turns the store on). Servers with a store serve "
+    "artifact_get, push artifacts to standbys alongside KV replication, "
+    "and pre-install fetched artifacts before warmup. Empty = artifact "
+    "path off (compile locally, serve/fetch nothing)",
 )
 env.declare(
     "BBTPU_ARTIFACT_MAX_MB", int, 256,
@@ -84,15 +87,14 @@ def fingerprint(spec, start: int, end: int, dtype: str,
     artifacts even over the same span indices.
     """
     import jax
+    import jaxlib
 
     spec_src = json.dumps(
         dataclasses.asdict(spec), sort_keys=True, default=str
     )
     return {
         "jax": jax.__version__,
-        "jaxlib": getattr(
-            __import__("jaxlib"), "__version__", jax.__version__
-        ),
+        "jaxlib": jaxlib.__version__,
         "backend": jax.default_backend(),
         "device_count": jax.device_count(),
         "spec_hash": hashlib.blake2b(
@@ -121,36 +123,53 @@ def fingerprint_compatible(mine: dict, theirs: dict) -> str | None:
     return None
 
 
-def enable_persistent_cache(path: str) -> bool:
-    """Point JAX's persistent compilation cache at the artifact store
-    (idempotent; safe to call with a new dir mid-process — config is
-    re-read per compile). Thresholds drop to zero so every executable
-    lands in the store, not just the slow ones."""
-    try:
-        import jax
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc,
-        )
+# where the compile cache lives when nothing outside places it: one fixed,
+# git-ignored directory in the checkout, so every run of run_server /
+# bench.py / chip_smoke.py from that checkout finds the last run's entries
+DEFAULT_COMPILE_CACHE_DIR = str(
+    pathlib.Path(__file__).resolve().parents[2] / ".cache" / "xla"
+)
 
+
+def enable_persistent_cache(path: str | None = None) -> str | None:
+    """Turn on JAX's persistent compilation cache and return the directory
+    it uses (None when that directory cannot be created: the cache is an
+    optimization, never a crash).
+
+    JAX_COMPILATION_CACHE_DIR, when set, places the cache from outside:
+    JAX reads it itself and nothing here sets another directory, so the
+    artifact store serves from that same place. Otherwise `path` (an
+    artifact store's directory) or DEFAULT_COMPILE_CACHE_DIR is
+    configured. Thresholds drop to zero so every executable lands in the
+    cache, not just the slow ones. Safe to call again with a new dir
+    mid-process."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as _cc
+
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    path = placed or path or DEFAULT_COMPILE_CACHE_DIR
+    try:
         os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        # the default XLA-caches integration bakes an autotune-cache PATH
-        # (derived from the cache dir) into every compile's options — and
-        # the options are hashed into the cache key, so artifacts keyed
-        # under one store dir could NEVER hit from another server's
-        # store. Swarm portability requires dir-independent keys.
-        jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
-        # the cache OBJECT latches on first use: a compile that ran before
-        # any dir was configured disables it for the process, and a dir
-        # change after first use is silently ignored — reset so the next
-        # compile re-initializes against the dir just configured
-        _cc.reset_cache()
-        return True
-    except Exception as e:  # cache is an optimization, never a crash
+    except OSError as e:
         logger.warning("persistent compile cache unavailable: %s", e)
-        return False
+        return None
+    if not placed:
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # the default XLA-caches integration bakes an autotune-cache PATH
+    # (derived from the cache dir) into every compile's options, and the
+    # options are hashed into the cache key, so an entry written under one
+    # directory could never hit from another (a peer's store, a moved
+    # checkout). Re-checked under jax 0.9.0: still so; "none" makes the
+    # keys directory-independent.
+    jax.config.update("jax_persistent_cache_enable_xla_caches", "none")
+    # jax 0.9.0 honours a directory configured after earlier compiles, but
+    # still ignores a CHANGE of directory once the cache object exists
+    # (several stores in one process): drop it so the next compile opens
+    # the directory just configured
+    _cc.reset_cache()
+    return path
 
 
 def _safe_name(name: str) -> bool:

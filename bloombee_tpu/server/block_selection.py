@@ -232,17 +232,23 @@ def choose_num_blocks(
     spec, dtype, num_pages: int, page_size: int, memory_fraction: float = 0.8
 ) -> int:
     """How many blocks fit in this device's memory, after the KV arena
-    (reference Server._choose_num_blocks, server.py:427-477). Falls back to
-    the whole model when the backend exposes no memory stats (e.g. CPU)."""
+    (reference Server._choose_num_blocks, server.py:427-477). The CPU
+    backend has no device memory to budget and serves the whole model; an
+    accelerator that reports no memory limit is an error, not a licence
+    to load everything."""
     import numpy as np
 
     import jax
 
-    try:
-        stats = jax.devices()[0].memory_stats()
-        limit = stats["bytes_limit"]
-    except Exception:
+    device = jax.devices()[0]
+    if device.platform == "cpu":
         return spec.num_hidden_layers
+    limit = (device.memory_stats() or {}).get("bytes_limit")
+    if not limit:
+        raise RuntimeError(
+            f"{device} reports no memory limit; pass --num-blocks or "
+            "--blocks to size the span by hand"
+        )
     per_block = estimate_block_bytes(spec, dtype)
     arena_bytes = (
         num_pages * page_size * spec.num_key_value_heads * spec.head_dim

@@ -666,8 +666,11 @@ class BlockServer(PromotionLoopMixin):
         else:
             from bloombee_tpu.runtime.training import TrainingExecutor
 
+            # the executor's params, not the loader's: under --tp those are
+            # the mesh-placed shards, and a second reference to the
+            # unsharded stack would pin a full copy on the first device
             self.training = TrainingExecutor(
-                params, spec, windows=self.executor.windows,
+                self.executor.params, spec, windows=self.executor.windows,
                 compute_dtype=compute_dtype, adapters=self.adapter_factors,
             )
         self.decode_n_max = int(decode_n_max)
@@ -912,8 +915,12 @@ class BlockServer(PromotionLoopMixin):
         if artifact_dir is None:
             artifact_dir = env.get("BBTPU_ARTIFACT_DIR")
         self.artifact_store: artifacts.ArtifactStore | None = None
-        if artifact_dir and artifacts.enable_persistent_cache(artifact_dir):
-            self.artifact_store = artifacts.ArtifactStore(artifact_dir)
+        if artifact_dir:
+            # the store serves whatever directory the cache really uses
+            # (JAX_COMPILATION_CACHE_DIR wins over artifact_dir)
+            cache_dir = artifacts.enable_persistent_cache(artifact_dir)
+            if cache_dir:
+                self.artifact_store = artifacts.ArtifactStore(cache_dir)
         self._artifacts_preinstalled = False
         self._artifact_pushed_standbys: set[tuple[str, int]] = set()
         self.warmup_failures = 0
@@ -981,6 +988,15 @@ class BlockServer(PromotionLoopMixin):
         logger.info(
             "server %s serving %s[%d:%d] on port %d",
             self.server_id, self.model_uid, self.start_block, self.end_block, self.port,
+        )
+        from bloombee_tpu import native
+        from bloombee_tpu.utils.memory import device_report
+
+        # said once by the process that holds the device: what it runs on
+        # (off the loop: the first look at a native component may build it)
+        logger.info(
+            "device %s; native components %s",
+            device_report(), await asyncio.to_thread(native.loaded),
         )
 
     async def drain(self, timeout: float | None = None) -> None:
@@ -1415,15 +1431,24 @@ class BlockServer(PromotionLoopMixin):
             self._promotion_task = asyncio.create_task(
                 self._promotion_loop()
             )
+        self._report_startup_tasks()
+
+    def _report_startup_tasks(self) -> bool:
+        """Surface the one-shot start-up tasks (bucket warm-up, throughput
+        measurement) once each as they finish; True when none is still
+        running. One that RAISED (instead of swallowing per bucket) counts
+        as a warm-up failure too, so rpc_info / health --probe show it."""
         for name in ("_warmup_task", "_throughput_task"):
             task = getattr(self, name)
             if task is not None and task.done():
                 setattr(self, name, None)  # report once
                 if not task.cancelled() and task.exception() is not None:
+                    self._note_warmup_failure()
                     logger.error(
                         "%s failed: %s", name.strip("_"),
                         task.exception(),
                     )
+        return self._warmup_task is None and self._throughput_task is None
 
     def rebalance_unsupported(self) -> str | None:
         """Why this server cannot move its span at runtime; None if it can."""
@@ -1508,7 +1533,7 @@ class BlockServer(PromotionLoopMixin):
             from bloombee_tpu.runtime.training import TrainingExecutor
 
             training = TrainingExecutor(
-                params, spec, windows=executor.windows,
+                executor.params, spec, windows=executor.windows,
                 compute_dtype=self.compute_dtype,
             )
             # swap atomically from the event loop's view; any step already
@@ -1715,6 +1740,7 @@ class BlockServer(PromotionLoopMixin):
             self.start_block == 0
             and self.end_block == self.spec.num_hidden_layers
         )
+        warmup_done = self._report_startup_tasks()
         info = {
             "server_id": self.server_id,
             "server_time": clock.now(),  # NTP-style clock sync anchor
@@ -1859,6 +1885,15 @@ class BlockServer(PromotionLoopMixin):
             # ledgered local-compile fallbacks, and the bounded store's
             # occupancy/eviction gauges
             "warmup_failures": self.warmup_failures,
+            # False while start-up warm-up / throughput measurement still
+            # runs (a client that wants warm buckets waits for True)
+            "warmup_done": warmup_done,
+            # attention-path observability: device dispatches per path
+            # (flash / paged / ragged Pallas kernels vs dense), and Pallas
+            # kernel failures that gave way to the dense path — each a
+            # kernel bug, never a normal condition
+            "attn_dispatches": dict(self.executor.attn_dispatches),
+            "kernel_fallbacks": self.executor.kernel_fallbacks,
             "artifact_preinstalled": self._artifacts_preinstalled,
             "artifact_fallback_compiles": self.artifact_fallback_compiles,
             "artifact_gets_served": self.artifact_gets_served,
@@ -1906,11 +1941,17 @@ class BlockServer(PromotionLoopMixin):
         }
         if fused_decline is not None:
             info["decode_n_decline"] = fused_decline
-        from bloombee_tpu.utils.memory import server_memory_report
+        from bloombee_tpu import native
+        from bloombee_tpu.utils.memory import (
+            device_report,
+            server_memory_report,
+        )
 
         # operator-pollable memory accounting (reference memory_usage.py's
         # logging surface, as a remote field instead of a local probe)
         info["memory"] = server_memory_report(self)
+        info["device"] = device_report()
+        info["native"] = native.loaded()
         if self._client_params is not None:
             info["head_dtype"] = str(self._client_params["lm_head"].dtype)
         return info, []
@@ -3030,7 +3071,7 @@ class BlockServer(PromotionLoopMixin):
         # Two phases: dispatch runs on the serialized compute queue (device
         # work enqueues in order, ~1 ms), but the d2h fetch happens HERE, off
         # the queue, so concurrent sessions overlap their device round trips
-        # (the round trip dominates per-step latency on tunnel/DCN hosts —
+        # (a step cannot hide its own dependent h2d -> compute -> d2h trip —
         # the reference overlaps the same way with per-handler processes and
         # CUDA streams, task_pool.py:127-192).
         # ragged replay: the step writes a padded rectangle speculatively
@@ -3263,7 +3304,7 @@ class BlockServer(PromotionLoopMixin):
           prefix and pushes hidden downstream; the LAST span applies
           norm+head+select and pushes the next id back here; this
           coordinator replies [B, n] ids after n rounds. The client RTT —
-          the expensive tunnel/DCN hop — is paid once per N tokens; the
+          the expensive wide-area hop — is paid once per N tokens; the
           per-token hops ride server-to-server links. This beats the
           reference's per-token client loop for the multi-server topology
           (remote_generation.py:286-386).

@@ -112,19 +112,15 @@ declare(
     "comma-separated debug channels (wire, kv, microbatch, spec, timing)",
 )
 
-# bench.py switches live here rather than next to their readers: the
+# bench.py's switch lives here rather than next to its reader: the
 # bench is a standalone script (not importable from
 # import_declaring_modules without dragging its __main__ machinery in),
-# but its switches still belong in the authoritative table.
-declare(
-    "BBTPU_BENCH_DEADLINE_S", float, 1500.0,
-    "bench watchdog/backend-probe deadline in seconds; past it the "
-    "bench emits partial results and exits 0",
-)
+# but its switch still belongs in the authoritative table.
 declare(
     "BBTPU_BENCH_SMOKE", bool, False,
-    "force the bench's reduced CPU smoke profile (tiny model, short "
-    "phases) regardless of backend availability",
+    "run bench.py as a tiny rehearsal (tiny model, short phases) on "
+    "whatever backend is there, reporting the phase ledger and counts "
+    "but no rates; without it bench.py fails unless it finds a TPU",
 )
 
 

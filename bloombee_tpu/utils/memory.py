@@ -17,20 +17,40 @@ from __future__ import annotations
 from typing import Any
 
 
+def device_report() -> dict:
+    """The accelerator as the process that holds it sees it (JAX's own
+    words): platform, device kind and how many devices."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
 def device_memory_stats() -> dict:
-    """PJRT per-device memory counters (bytes_in_use / peak / limit);
+    """PJRT memory counters of the first device (bytes_in_use / peak /
+    limit) plus bytes_in_use of every device in jax.devices() order;
     empty on backends that expose none (CPU)."""
     import jax
 
     try:
-        stats = jax.devices()[0].memory_stats() or {}
+        per_device = [d.memory_stats() or {} for d in jax.devices()]
     except Exception:
         return {}
-    return {
+    stats = per_device[0]
+    out = {
         k: int(stats[k])
         for k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
         if k in stats
     }
+    if out:
+        out["bytes_in_use_by_device"] = [
+            int(s.get("bytes_in_use", 0)) for s in per_device
+        ]
+    return out
 
 
 def tree_nbytes(tree: Any) -> int:
@@ -48,11 +68,29 @@ def tree_nbytes(tree: Any) -> int:
     return total
 
 
+def tree_nbytes_by_device(tree: Any) -> list[int]:
+    """Bytes of a pytree's array leaves by the device that holds them, in
+    jax.devices() order: a mesh-sharded leaf counts each shard where it
+    lives, a replicated leaf once per device. Under --tp this is what
+    shows the span and the arena really spread over the chips."""
+    import jax
+
+    index = {d.id: i for i, d in enumerate(jax.devices())}
+    totals = [0] * len(index)
+    for leaf in jax.tree.leaves(tree):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            totals[index[shard.device.id]] += int(shard.data.nbytes)
+    return totals
+
+
 def server_memory_report(server) -> dict:
     """Exact framework-side accounting for one BlockServer + the device
     counters. All values in bytes (MiB is a presentation concern)."""
     report = {
         "span_params_bytes": tree_nbytes(server.executor.params),
+        "span_params_bytes_by_device": tree_nbytes_by_device(
+            server.executor.params
+        ),
         "host_layer_bytes": tree_nbytes(server.executor.host_layers),
         **server.manager.memory_stats(),
         "device": device_memory_stats(),
@@ -94,8 +132,10 @@ def format_report(report: dict) -> str:
 
 
 __all__ = [
+    "device_report",
     "device_memory_stats",
     "tree_nbytes",
+    "tree_nbytes_by_device",
     "server_memory_report",
     "format_report",
 ]

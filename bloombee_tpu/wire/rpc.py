@@ -407,6 +407,11 @@ class Connection:
                 return
         bufs = _frame_buffers(header, blobs)
         async with self._send_lock:
+            if self.writer.transport.is_closing():
+                # Python 3.12's socket transport raises TypeError from
+                # writelines() once closed (write() only logged and
+                # drain() then raised this); say what happened instead
+                raise ConnectionResetError("connection lost")
             self.writer.writelines(bufs)
             await self.writer.drain()
         self._advertised = True

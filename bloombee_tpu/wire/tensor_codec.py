@@ -17,6 +17,7 @@ bfloat16 is handled via ml_dtypes so client/server never need torch.
 from __future__ import annotations
 
 import dataclasses
+import threading
 import zlib
 
 import ml_dtypes
@@ -24,11 +25,28 @@ import numpy as np
 
 try:
     import zstandard as _zstd
-
-    _ZSTD_C = _zstd.ZstdCompressor(level=3)
-    _ZSTD_D = _zstd.ZstdDecompressor()
 except Exception:  # pragma: no cover - zstandard is in the base image
     _zstd = None
+
+# a zstandard (de)compressor object must not be used by two threads at
+# once, and the off-loop codec pool's workers do compress concurrently
+# (one shared object segfaulted concurrent sessions): one per thread
+_ZSTD_TLS = threading.local()
+
+
+def _zstd_compress(buf) -> bytes:
+    ctx = getattr(_ZSTD_TLS, "compressor", None)
+    if ctx is None:
+        ctx = _ZSTD_TLS.compressor = _zstd.ZstdCompressor(level=3)
+    return ctx.compress(buf)
+
+
+def _zstd_decompress(buf) -> bytes:
+    ctx = getattr(_ZSTD_TLS, "decompressor", None)
+    if ctx is None:
+        ctx = _ZSTD_TLS.decompressor = _zstd.ZstdDecompressor()
+    return ctx.decompress(buf)
+
 
 from bloombee_tpu.utils import env as _env
 from bloombee_tpu.utils import lockwatch as _lockwatch
@@ -118,7 +136,7 @@ _env.declare(
 _CODECS: dict[str, tuple] = {"zlib": (lambda b: zlib.compress(b, 6),
                                       zlib.decompress)}
 if _zstd is not None:
-    _CODECS["zstd"] = (_ZSTD_C.compress, _ZSTD_D.decompress)
+    _CODECS["zstd"] = (_zstd_compress, _zstd_decompress)
 
 # preference order when several codecs are permitted for a payload
 _PREFERENCE: list[str] = ["zstd", "zlib"]

@@ -9,6 +9,9 @@ never exercised incidentally — these tests force one (round-4 verdict):
   pre-rebuild session's next step gets the typed `session_lost` reply, the
   client replays its token history onto the same (healthy, UNBANNED)
   server and the generation completes token-exact.
+- a Pallas kernel failure BEFORE donation (what a compile error is) gives
+  way to the dense path once, and is counted where operators look:
+  `kernel_fallbacks` next to `warmup_failures` in rpc_info.
 
 Reference analog: a CUDA error kills the reference's runtime process and
 its supervisor restarts the whole container (server.py:524-541); here the
@@ -195,6 +198,77 @@ def test_e2e_kernel_failure_rebuild_replay_no_ban(
         np.testing.assert_array_equal(got, ref_ids)
 
         await s1.stop()
+        await reg.stop()
+
+    asyncio.run(run())
+
+
+def test_kernel_failure_before_donation_falls_back_and_is_counted(
+    tiny_model_dir, monkeypatch
+):
+    """A paged kernel the device refuses raises at call time, before the
+    arena is donated: the step reruns on the dense path (same tokens as a
+    server that never had the kernel), the kernel path stays off, and the
+    swallowed failure shows as kernel_fallbacks in rpc_info — it is a
+    bug to report (fatal in chip_smoke.py), never a silent condition."""
+    from bloombee_tpu.runtime import executor as exec_mod
+    from bloombee_tpu.wire.rpc import connect
+
+    model_dir, _, config = tiny_model_dir
+    # the kernel path is open on the CPU only through the test switch
+    monkeypatch.setenv("BBTPU_PAGED_INTERPRET", "1")
+    monkeypatch.setenv("BBTPU_PAGED_MIN_CONTEXT", "0")
+    real_step = exec_mod.span_step_packed
+    refused = []
+
+    def refusing_step(*args, **kw):
+        if kw.get("use_paged"):
+            refused.append(kw["t"])
+            raise NotImplementedError("Unsupported cast (injected)")
+        return real_step(*args, **kw)
+
+    async def run():
+        reg = RegistryServer(host="127.0.0.1")
+        await reg.start()
+        server = BlockServer(
+            model_uid="tiny", start=0, end=2, model_dir=model_dir,
+            registry=RegistryClient("127.0.0.1", reg.port),
+            compute_dtype=jnp.float32, num_pages=64, page_size=4,
+        )
+        await server.start()
+        model = DistributedModelForCausalLM.from_pretrained(
+            model_dir, RegistryClient("127.0.0.1", reg.port),
+            model_uid="tiny",
+        )
+        input_ids = np.random.default_rng(4).integers(
+            0, config.vocab_size, size=(1, 4)
+        )
+        monkeypatch.setenv("BBTPU_PAGED_ATTENTION", "0")
+        dense_ids = await model.generate(
+            input_ids, max_new_tokens=5, server_decode=False
+        )
+        assert server.executor.attn_dispatches["paged"] == 0
+
+        monkeypatch.setenv("BBTPU_PAGED_ATTENTION", "1")
+        monkeypatch.setattr(exec_mod, "span_step_packed", refusing_step)
+        ids = await model.generate(
+            input_ids, max_new_tokens=5, server_decode=False
+        )
+        np.testing.assert_array_equal(ids, dense_ids)
+        assert len(refused) == 1  # tried once, then the path stays off
+        assert server.executor.kernel_fallbacks == 1
+        assert server.executor.attn_dispatches["paged"] == 0
+
+        conn = await connect("127.0.0.1", server.port)
+        info, _ = await conn.call("rpc_info", {})
+        await conn.close()
+        assert info["kernel_fallbacks"] == 1
+        assert info["warmup_failures"] == 0
+        assert info["warmup_done"] is True  # no start-up task was started
+        assert info["attn_dispatches"]["dense"] > 0
+        assert info["device"]["platform"] == "cpu"
+        assert set(info["native"]) == {"paged_table", "byte_split"}
+        await server.stop()
         await reg.stop()
 
     asyncio.run(run())

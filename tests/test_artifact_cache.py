@@ -47,7 +47,11 @@ _CFG_KEYS = (
 
 
 @pytest.fixture(autouse=True)
-def clean_slate():
+def clean_slate(monkeypatch):
+    # one process stands for several machines here, each with its own
+    # store; a cache placed from outside (scripts/chaos.sh shares one
+    # across its matrix) would merge them into one directory
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     saved = {k: getattr(jax.config, k) for k in _CFG_KEYS}
     faults.set_plan(None)
     jitwatch.reset()
@@ -65,6 +69,31 @@ def clean_slate():
 
 
 # ------------------------------------------------------------- store unit
+def test_compile_cache_is_placed_from_outside_or_fixed(tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR places the cache and nothing in the code
+    sets another directory (a store then serves that one); unset, the
+    caller's directory or the checkout's fixed git-ignored one is used."""
+    before = jax.config.jax_compilation_cache_dir
+    placed = tmp_path / "placed"
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(placed))
+    assert artifacts.enable_persistent_cache(str(tmp_path / "store")) == str(
+        placed
+    )
+    assert placed.is_dir() and not (tmp_path / "store").exists()
+    assert jax.config.jax_compilation_cache_dir == before  # untouched
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    assert artifacts.enable_persistent_cache(str(tmp_path / "store")) == str(
+        tmp_path / "store"
+    )
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "store")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert artifacts.DEFAULT_COMPILE_CACHE_DIR == os.path.join(
+        repo, ".cache", "xla"
+    )
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".cache/" in f.read().split()
+
+
 def test_install_and_manifest_roundtrip(tmp_path):
     store = artifacts.ArtifactStore(str(tmp_path))
     blob = b"executable bytes" * 8

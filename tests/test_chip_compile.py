@@ -1,0 +1,281 @@
+"""The chip's compiler, without the chip: AOT compiles for a DESCRIBED TPU v5e.
+
+Interpret-mode tests cannot see what Mosaic refuses (an unsupported cast, a
+load of a dtype the TPU has no vector type for, too much VMEM, an unaligned
+slice). The TPU compiler is installed wherever libtpu is, and compiles for a
+topology that is described, not attached — so every Pallas kernel on the
+served path is compiled here at Llama-3-8B head geometry, plus whole span
+steps over the 8-layer span chip_smoke.py serves, with the kernels on. A
+compile that passes is not a chip run; it only says the chip's compiler
+takes the program.
+
+Steering: the traced code picks interpret mode from the BBTPU_*_INTERPRET
+test switches alone, so the tests pin those off and compile the jitted
+functions themselves (the executor's `jax.default_backend()` gate would
+route a CPU process around the kernels). The persistent compile cache is off
+around the file: an entry compiled for a described device cannot be read
+back without one and only produces warnings.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or libtpu logs to /tmp
+
+import functools  # noqa: E402
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import (  # noqa: E402
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+from bloombee_tpu.kv.quant import QuantSlab  # noqa: E402
+from bloombee_tpu.models.llama.block import init_block_params  # noqa: E402
+from bloombee_tpu.models.spec import ModelSpec  # noqa: E402
+from bloombee_tpu.ops.pallas.flash_attention import (  # noqa: E402
+    flash_attention,
+)
+from bloombee_tpu.ops.pallas.paged_attention import (  # noqa: E402
+    paged_chunk_attention,
+    paged_decode_attention,
+    paged_decode_attention_int4,
+    paged_ragged_attention,
+)
+from bloombee_tpu.runtime.step import (  # noqa: E402
+    span_step_packed,
+    span_step_ragged,
+)
+from bloombee_tpu.utils.tree import stack_params  # noqa: E402
+
+# Llama-3-8B head geometry and the arena chip_smoke.py serves with
+H, HKV, HD, D = 32, 8, 128, 4096
+PAGE, NUM_PAGES, LAYERS = 16, 1024, 8
+S_TOT = PAGE * NUM_PAGES
+B, NP = 8, 64  # 8 sequences, 64-page (1024-token) context bucket
+SPEC = ModelSpec(
+    family="llama", hidden_size=D, intermediate_size=14336,
+    num_attention_heads=H, num_key_value_heads=HKV, head_dim=HD,
+    num_hidden_layers=LAYERS, vocab_size=128256, rope_theta=500000.0,
+)
+i32, bf16 = jnp.int32, jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a described v5e 2x2 host (skip where libtpu
+    cannot describe one), with the persistent compile cache switched off."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        ).devices
+    except Exception as e:  # noqa: BLE001 — no libtpu / unknown topology
+        pytest.skip(f"cannot describe a TPU v5e here: {e!r}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield devices
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _compiled_not_interpreted(monkeypatch):
+    for switch in ("BBTPU_PAGED_INTERPRET", "BBTPU_FLASH_INTERPRET"):
+        monkeypatch.delenv(switch, raising=False)
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text(), "no Mosaic kernel inside"
+    return compiled
+
+
+def _kernel_cases():
+    """name -> (fn, shapes as (shape, dtype) tuples or QuantSlab of them)."""
+    slab = ((S_TOT, HKV, HD), bf16)
+    qslab = QuantSlab(
+        ((S_TOT, HKV, HD // 2), jnp.uint8),
+        ((S_TOT, HKV, HD // 32), jnp.float16),
+        ((S_TOT, HKV, HD // 32), jnp.float16),
+    )
+    pt, lens = ((B, NP), i32), ((B,), i32)
+    cases = {
+        "paged_decode": (
+            functools.partial(paged_decode_attention, page_size=PAGE),
+            [((B, H, HD), bf16), slab, slab, pt, lens],
+        ),
+        "paged_decode_int4": (
+            functools.partial(paged_decode_attention_int4, page_size=PAGE),
+            [((B, H, HD), bf16), qslab, qslab, pt, lens],
+        ),
+        "flash_t128": (  # one --prefill-chunk 128 chunk
+            lambda q, k, v, st, ln: flash_attention(
+                q, k, v, causal=True, starts=st, lens=ln
+            ),
+            [((1, 128, H, HD), bf16), ((1, 256, HKV, HD), bf16),
+             ((1, 256, HKV, HD), bf16), ((1,), i32), ((1,), i32)],
+        ),
+        "flash_t512": (  # max_chunk_tokens
+            lambda q, k, v, st, ln: flash_attention(
+                q, k, v, causal=True, starts=st, lens=ln
+            ),
+            [((1, 512, H, HD), bf16), ((1, 512, HKV, HD), bf16),
+             ((1, 512, HKV, HD), bf16), ((1,), i32), ((1,), i32)],
+        ),
+    }
+    # T=64 and R=64 sit on the executor's `rows * H <= 2048` VMEM edge
+    for t in (16, 64):
+        cases[f"paged_chunk_t{t}"] = (
+            functools.partial(paged_chunk_attention, page_size=PAGE),
+            [((B, t, H, HD), bf16), slab, slab, pt, lens],
+        )
+        cases[f"paged_chunk_tree_t{t}"] = (
+            lambda q, k, v, p, ln, tm: paged_chunk_attention(
+                q, k, v, p, ln, page_size=PAGE, tree_mask=tm, has_tree=True
+            ),
+            [((B, t, H, HD), bf16), slab, slab, pt, lens,
+             ((B, t, t), jnp.bool_)],
+        )
+    for r in (16, 64):
+        rows = [((r, H, HD), bf16), slab, slab, pt, lens, ((r,), i32),
+                ((r,), i32)]
+        cases[f"paged_ragged_r{r}"] = (
+            functools.partial(paged_ragged_attention, page_size=PAGE), rows,
+        )
+        cases[f"paged_ragged_tree_r{r}"] = (
+            lambda q, k, v, p, ln, qs, qp, nt, tr: paged_ragged_attention(
+                q, k, v, p, ln, qs, qp, page_size=PAGE, nt=nt, tree_rows=tr,
+                has_tree=True,
+            ),
+            rows + [((B,), i32), ((r, 16), i32)],
+        )
+    return cases
+
+
+_KERNELS = _kernel_cases()
+
+
+@pytest.mark.parametrize("name", sorted(_KERNELS))
+def test_kernel_compiles_for_v5e(v5e, name):
+    one_chip = SingleDeviceSharding(v5e[0])
+
+    def described(leaf):
+        shape, dtype = leaf
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fn, shapes = _KERNELS[name]
+    _compile(fn, *[
+        QuantSlab(*map(described, s)) if isinstance(s, QuantSlab)
+        else described(s)
+        for s in shapes
+    ])
+
+
+def _span_shapes(sharding_for):
+    """(params, arena) ShapeDtypeStructs for the 8-layer span at 8B widths;
+    sharding_for(kind, name) places each leaf."""
+    params = jax.eval_shape(
+        lambda: stack_params([
+            init_block_params(jax.random.PRNGKey(0), SPEC, dtype=bf16)
+            for _ in range(LAYERS)
+        ])
+    )
+    params = {
+        k: jax.ShapeDtypeStruct(
+            v.shape, v.dtype, sharding=sharding_for("param", k)
+        )
+        for k, v in params.items()
+    }
+    arena = jax.ShapeDtypeStruct(
+        (LAYERS, S_TOT, HKV, HD), bf16, sharding=sharding_for("arena", "")
+    )
+    return params, arena
+
+
+def _payload(hidden_rows: int, plan_len: int, sharding):
+    # pack_step_payload: bf16 hidden + int32 plan as uint16 lanes
+    return jax.ShapeDtypeStruct(
+        (hidden_rows * D + 2 * plan_len,), jnp.uint16, sharding=sharding
+    )
+
+
+def _packed_plan_len(b: int, t: int, pages: int) -> int:
+    return b * t + b * pages + b * t + b + LAYERS
+
+
+_SPAN_STEPS = {
+    # decode through the paged kernel, prefill chunk through flash, a short
+    # chunk through the chunk kernel (what warm-up + chip_smoke dispatch)
+    "decode_paged": dict(b=B, t=1, pages=NP, use_paged=True),
+    "prefill_flash": dict(b=1, t=128, pages=8, use_flash=True),
+    "chunk_paged": dict(b=1, t=64, pages=32, use_paged=True, t_real=44),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_SPAN_STEPS))
+def test_span_step_compiles_for_v5e(v5e, name):
+    one_chip = SingleDeviceSharding(v5e[0])
+    case = dict(_SPAN_STEPS[name])
+    b, t, pages = case.pop("b"), case.pop("t"), case.pop("pages")
+    params, arena = _span_shapes(lambda kind, key: one_chip)
+    compiled = span_step_packed.lower(
+        params, arena, arena,
+        _payload(b * t, _packed_plan_len(b, t, pages), one_chip),
+        None, None,
+        spec=SPEC, b=b, t=t, page_size=PAGE, max_pages=pages,
+        windows=(0,) * LAYERS, **case,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tree", [False, True], ids=["causal", "tree"])
+def test_span_step_ragged_compiles_for_v5e(v5e, tree):
+    """One fused decode + chunk dispatch (R=64: the widest row bucket the
+    kernel gate admits at 32 heads), causal and tree-verify variants."""
+    one_chip = SingleDeviceSharding(v5e[0])
+    r, n_seqs, pages, t_max = 64, 2, 32, (16 if tree else 0)
+    plan_len = r + n_seqs * pages + r + n_seqs + r + LAYERS
+    if tree:
+        plan_len += n_seqs + r * t_max
+    params, arena = _span_shapes(lambda kind, key: one_chip)
+    compiled = span_step_ragged.lower(
+        params, arena, arena, _payload(r, plan_len, one_chip), None,
+        spec=SPEC, r=r, n_seqs=n_seqs, page_size=PAGE, max_pages=pages,
+        windows=(0,) * LAYERS, use_kernel=True, t_max=t_max,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_tp4_span_step_compiles_and_shards_for_v5e(v5e):
+    """The --tp 4 serving step (GSPMD over the 2x2 host, dense attention:
+    Pallas kernels stay off under a mesh) compiles for four chips, and each
+    chip holds a quarter of the span's weights and arena."""
+    from bloombee_tpu.parallel.serving import ARENA_SPEC, SERVING_PARAM_SPECS
+
+    mesh = Mesh(v5e, ("tp",))
+    replicated = NamedSharding(mesh, P())
+
+    def sharding_for(kind, key):
+        spec = ARENA_SPEC if kind == "arena" else SERVING_PARAM_SPECS[key]
+        return NamedSharding(mesh, spec)
+
+    params, arena = _span_shapes(sharding_for)
+    compiled = span_step_packed.lower(
+        params, arena, arena,
+        _payload(B, _packed_plan_len(B, 1, NP), replicated), None, None,
+        spec=SPEC, b=B, t=1, page_size=PAGE, max_pages=NP,
+        windows=(0,) * LAYERS,
+    ).compile()
+    assert "all-reduce" in compiled.as_text()  # the Megatron psums
+    total = sum(
+        v.size * v.dtype.itemsize for v in [*params.values(), arena, arena]
+    )
+    per_chip = compiled.memory_analysis().argument_size_in_bytes
+    assert per_chip < 0.3 * total, (per_chip, total)

@@ -4,6 +4,7 @@ probe against them. The reference's equivalent is the manual live-swarm
 tier (SURVEY.md §4: run_dht + run_server processes + pytest)."""
 
 import asyncio
+import os
 import socket
 import subprocess
 import sys
@@ -19,10 +20,9 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-_BOOT = (
-    "import jax; jax.config.update('jax_platforms', 'cpu'); "
-    "from bloombee_tpu.cli.{mod} import main; main({args!r})"
-)
+# every child is held to the CPU through its environment; this process (the
+# pytest parent) is too, so no child ever waits on a device a parent holds
+_CPU_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
 
 
 def _spawn(mod: str, args: list[str], log_path) -> subprocess.Popen:
@@ -30,10 +30,11 @@ def _spawn(mod: str, args: list[str], log_path) -> subprocess.Popen:
     # after ~64KB and stalls the swarm
     log = open(log_path, "w")
     proc = subprocess.Popen(
-        [sys.executable, "-c", _BOOT.format(mod=mod, args=args)],
+        [sys.executable, "-m", f"bloombee_tpu.cli.{mod}", *args],
         stdout=log,
         stderr=subprocess.STDOUT,
         text=True,
+        env=_CPU_ENV,
     )
     proc._log_path = log_path
     return proc
@@ -147,13 +148,10 @@ def test_cli_registry_server_client_health(tmp_path):
         # bytes shipped vs raw (the bytes/token floor) and the off-loop
         # codec pipeline state, the BB006 no-log-access operator surface
         probe = subprocess.run(
-            [sys.executable, "-c",
-             _BOOT.format(
-                 mod="health",
-                 args=["tiny", "--num-blocks", "2", "--registry",
-                       f"127.0.0.1:{reg_port}", "--probe"],
-             )],
-            capture_output=True, text=True, timeout=60,
+            [sys.executable, "-m", "bloombee_tpu.cli.health",
+             "tiny", "--num-blocks", "2", "--registry",
+             f"127.0.0.1:{reg_port}", "--probe"],
+            capture_output=True, text=True, timeout=60, env=_CPU_ENV,
         )
         assert "COMPLETE" in probe.stdout, probe.stdout + probe.stderr
         assert "[reachable]" in probe.stdout, probe.stdout

@@ -174,31 +174,22 @@ def test_packed_payload_bitcast_roundtrip_bf16_and_f32():
 
     import jax
     import ml_dtypes
-    from jax import lax
 
-    from bloombee_tpu.runtime.step import pack_step_payload
+    from bloombee_tpu.runtime.step import (
+        pack_step_payload,
+        unpack_step_payload,
+    )
 
     rng = np.random.default_rng(0)
     plan = rng.integers(-(2**31), 2**31 - 1, size=(57,), dtype=np.int32)
 
-    for np_dt, jnp_dt in ((ml_dtypes.bfloat16, jnp.bfloat16),
-                          (np.float32, jnp.float32)):
+    for np_dt in (ml_dtypes.bfloat16, np.float32):
         h = rng.standard_normal((2, 3, 8)).astype(np_dt)
         payload = pack_step_payload(h, plan)
-
-        @functools.partial(jax.jit, static_argnames=("n_h",))
-        def unpack(p, n_h):
-            if p.dtype == jnp.uint16:
-                hid = lax.bitcast_convert_type(p[:n_h], jnp.bfloat16)
-                pl_ = lax.bitcast_convert_type(
-                    p[n_h:].reshape(-1, 2), jnp.int32
-                )
-            else:
-                hid = lax.bitcast_convert_type(p[:n_h], jnp.float32)
-                pl_ = lax.bitcast_convert_type(p[n_h:], jnp.int32)
-            return hid, pl_
-
-        hid, pl_ = unpack(jnp.asarray(payload), n_h=h.size)
+        hid, pl_ = jax.jit(
+            functools.partial(unpack_step_payload, b=2, t=3, d=8)
+        )(jnp.asarray(payload))
+        assert hid.shape == h.shape
         assert np.asarray(hid).view(np.uint8).tobytes() == h.tobytes()
         np.testing.assert_array_equal(np.asarray(pl_), plan)
 

@@ -53,6 +53,58 @@ def test_codec_compresses_large_redundant_bf16():
     np.testing.assert_array_equal(out.view(np.uint8), arr.view(np.uint8))
 
 
+def test_codec_concurrent_threads_roundtrip():
+    """The codec pool's workers (and every event loop that encodes inline)
+    compress at once. zstandard's context objects are not thread-safe —
+    one shared compressor crashed the process under concurrent sessions —
+    so each thread must get its own: more threads than cores hammer the
+    codec for a bounded time and every payload must round-trip."""
+    import os
+    import sys
+    import threading
+    import time
+
+    n_threads = 2 * (os.cpu_count() or 4)
+    rng = np.random.default_rng(3)
+    # compressible, distinct per thread, above the compression threshold
+    arrays = [
+        np.repeat(rng.normal(size=(MIN_COMPRESS_BYTES // 64,)), 64)
+        .astype(ml_dtypes.bfloat16)
+        for _ in range(n_threads)
+    ]
+    assert serialize_tensor(arrays[0])[0].codec == "zstd"
+    deadline = time.monotonic() + 1.5
+    rounds = [0] * n_threads
+    errors = []
+
+    def work(i):
+        try:
+            while time.monotonic() < deadline:
+                meta, payload = serialize_tensor(arrays[i])
+                out = deserialize_tensor(meta, payload)
+                if out.tobytes() != arrays[i].tobytes():
+                    raise AssertionError(f"thread {i}: payload corrupted")
+                rounds[i] += 1
+        except Exception as e:  # noqa: BLE001 — reported below
+            errors.append(e)
+
+    old_interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [
+            threading.Thread(target=work, args=(i,)) for i in range(n_threads)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old_interval)
+    assert not errors, errors
+    assert all(r > 0 for r in rounds), rounds
+
+
 def test_codec_incompressible_ships_raw():
     rng = np.random.default_rng(1)
     arr = rng.integers(0, 255, size=(MIN_COMPRESS_BYTES * 2,), dtype=np.uint8)
