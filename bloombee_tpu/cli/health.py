@@ -282,6 +282,24 @@ def main(argv=None):
                         line += "  " + " ".join(
                             f"{k}={v}" for k, v in sorted(jit.items())
                         )
+                    # where the host's time went (BBTPU_JITWATCH=1
+                    # runs): the compute worker's wall time by cause —
+                    # starved = no task existed, hop = one was queued and
+                    # nobody ran it, busy = inside bbtpu.task — then the
+                    # mean of every host span of the served step
+                    worker = probe.get("worker") or {}
+                    if worker.get("tasks"):
+                        line += "  worker " + " ".join(
+                            f"{k}={worker.get(k)}"
+                            for k in ("tasks", "starved_ms", "hop_ms",
+                                      "busy_ms")
+                        )
+                    spans = probe.get("host_spans") or {}
+                    if spans:
+                        line += "  host_spans " + " ".join(
+                            f"{name}={v['total_ms'] / v['n']:.3f}msx{v['n']}"
+                            for name, v in sorted(spans.items()) if v["n"]
+                        )
                     # compile-artifact counters (BBTPU_ARTIFACT_DIR runs):
                     # fallback_compiles > 0 means a server abandoned
                     # pre-installed artifacts and paid local compiles;

@@ -59,12 +59,14 @@ def moe_mlp(
     with psum outside.
     """
     if router_weights is None:
-        logits = x @ router_w
-        router_weights = router_topk_weights(
-            logits, top_k, pre_softmax=pre_softmax, norm_topk=norm_topk
-        )
-    g = jnp.einsum("btd,edi->btei", x, gate_w)
-    u = jnp.einsum("btd,edi->btei", x, up_w)
-    h = jax.nn.silu(g) * u
-    out = jnp.einsum("btei,eid->bted", h, down_w)
-    return jnp.einsum("bted,bte->btd", out, router_weights)
+        with jax.named_scope("moe_router"):
+            logits = x @ router_w
+            router_weights = router_topk_weights(
+                logits, top_k, pre_softmax=pre_softmax, norm_topk=norm_topk
+            )
+    with jax.named_scope("moe_experts"):
+        g = jnp.einsum("btd,edi->btei", x, gate_w)
+        u = jnp.einsum("btd,edi->btei", x, up_w)
+        h = jax.nn.silu(g) * u
+        out = jnp.einsum("btei,eid->bted", h, down_w)
+        return jnp.einsum("bted,bte->btd", out, router_weights)

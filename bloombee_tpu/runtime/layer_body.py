@@ -16,6 +16,14 @@ Replaces the reference's per-family Wrapped*Block zoo
 (/root/reference/src/bloombee/models/*/block.py) — there the per-family code
 wraps HF torch modules; here the differences are data (spec fields + param
 keys), so every family runs through the same scan/paged-attention machinery.
+
+Every part of a layer sits in a `jax.named_scope` (HLO metadata only: no op,
+shape or donation changes), so a device trace can say whose an op is:
+`norm`, `attn_proj` (q/k/v/o projections, QK-norm, rotary), `arena_write`,
+`arena_gather`, `attention` (the Pallas call or the XLA attend), `mlp` or
+`moe_router` + `moe_experts` (ops/moe.py). What the span step's `lax.scan`
+and `lax.cond` emit around the layer to slice and restack the arena carries
+none of them, and that absence is the reading.
 """
 
 from __future__ import annotations
@@ -35,11 +43,12 @@ from bloombee_tpu.utils import env
 
 
 def _norm(x, params, key, spec):
-    if spec.norm_type == "ln":
-        return layer_norm(
-            x, params[key], params.get(f"{key}_bias"), spec.rms_norm_eps
-        )
-    return rms_norm(x, params[key], spec.rms_norm_eps)
+    with jax.named_scope("norm"):
+        if spec.norm_type == "ln":
+            return layer_norm(
+                x, params[key], params.get(f"{key}_bias"), spec.rms_norm_eps
+            )
+        return rms_norm(x, params[key], spec.rms_norm_eps)
 
 
 def _proj(x, params, key, lora=None):
@@ -59,26 +68,36 @@ def _proj(x, params, key, lora=None):
 
 
 def _mlp(x, params, spec, lora=None):
+    if spec.num_experts:
+        with jax.named_scope("moe_experts"):
+            gate, up, down = (
+                maybe_dequantize(params[k], x.dtype)
+                for k in ("experts_gate", "experts_up", "experts_down")
+            )
+        return moe_mlp(
+            x,
+            params["router"],
+            gate,
+            up,
+            down,
+            spec.num_experts_per_tok,
+            pre_softmax=spec.moe_pre_softmax,
+            norm_topk=spec.moe_norm_topk,
+        )
+    with jax.named_scope("mlp"):
+        return _dense_mlp(x, params, spec, lora)
+
+
+def _dense_mlp(x, params, spec, lora=None):
     mlp_lora = lora is not None and any(
         k in lora for k in ("gate_proj", "up_proj", "down_proj")
     )
-    if mlp_lora and not spec.num_experts and spec.mlp_type == "silu":
+    if mlp_lora and spec.mlp_type == "silu":
         # lora-aware gated-SiLU composition (the fused silu_mlp takes raw
         # matrices, so the adapterized path spells it out)
         g = _proj(x, params, "gate_proj", lora)
         u = _proj(x, params, "up_proj", lora)
         return _proj(jax.nn.silu(g) * u, params, "down_proj", lora)
-    if spec.num_experts:
-        return moe_mlp(
-            x,
-            params["router"],
-            maybe_dequantize(params["experts_gate"], x.dtype),
-            maybe_dequantize(params["experts_up"], x.dtype),
-            maybe_dequantize(params["experts_down"], x.dtype),
-            spec.num_experts_per_tok,
-            pre_softmax=spec.moe_pre_softmax,
-            norm_topk=spec.moe_norm_topk,
-        )
     if spec.mlp_type == "silu":
         return silu_mlp(
             x,
@@ -190,24 +209,28 @@ def layer_body(
         spec.head_dim,
     )
     x = _norm(hidden, params, "input_layernorm", spec)
-    q = _proj(x, params, "q_proj", lora).reshape(b, t, h_heads, hd)
-    k = _proj(x, params, "k_proj", lora).reshape(b, t, kv_heads, hd)
-    if spec.k_eq_v:
-        # gemma-4 full-attention layers alias V to K (one shared
-        # projection; reference gemma4/block.py attention_k_eq_v)
-        v = k
-    else:
-        v = _proj(x, params, "v_proj", lora).reshape(b, t, kv_heads, hd)
-    if spec.qk_norm:
-        q = rms_norm(q, params["q_norm"], spec.rms_norm_eps)
-        k = rms_norm(k, params["k_norm"], spec.rms_norm_eps)
-    if not spec.alibi:
-        q, k = apply_rotary(q, k, cos, sin)
+    with jax.named_scope("attn_proj"):
+        q = _proj(x, params, "q_proj", lora).reshape(b, t, h_heads, hd)
+        k = _proj(x, params, "k_proj", lora).reshape(b, t, kv_heads, hd)
+        if spec.k_eq_v:
+            # gemma-4 full-attention layers alias V to K (one shared
+            # projection; reference gemma4/block.py attention_k_eq_v)
+            v = k
+        else:
+            v = _proj(x, params, "v_proj", lora).reshape(
+                b, t, kv_heads, hd
+            )
+        if spec.qk_norm:
+            q = rms_norm(q, params["q_norm"], spec.rms_norm_eps)
+            k = rms_norm(k, params["k_norm"], spec.rms_norm_eps)
+        if not spec.alibi:
+            q, k = apply_rotary(q, k, cos, sin)
 
-    k_slab, v_slab = arena_write(
-        k_slab, v_slab, slots,
-        k.reshape(b * t, kv_heads, hd), v.reshape(b * t, kv_heads, hd),
-    )
+    with jax.named_scope("arena_write"):
+        k_slab, v_slab = arena_write(
+            k_slab, v_slab, slots,
+            k.reshape(b * t, kv_heads, hd), v.reshape(b * t, kv_heads, hd),
+        )
     if use_paged:
         # the Pallas kernels stream K/V pages straight from the arena
         # (page table as scalar prefetch) — no gathered [B, S, Hkv, hd]
@@ -227,34 +250,38 @@ def layer_body(
         # the kernels compile for the device; interpret mode is only ever
         # the explicit test switch (read at trace time, like the spec)
         interpret = env.get("BBTPU_PAGED_INTERPRET")
-        if t == 1:
-            kernel = (
-                paged_decode_attention_int4
-                if isinstance(k_slab, QuantSlab)
-                else paged_decode_attention
-            )
-            attn = kernel(
-                q[:, 0], k_slab, v_slab, page_table, total_lens,
-                page_size=page_size, scale=attn_scale(spec),
-                interpret=interpret,
-                window=window,  # per-layer traced scalar (0 = full)
-            )[:, None]  # [B, 1, H, hd]
-        else:
-            attn = paged_chunk_attention(
-                q, k_slab, v_slab, page_table, total_lens,
-                page_size=page_size, tree_mask=tree_mask,
-                scale=attn_scale(spec), interpret=interpret,
-                window=window, has_tree=tree_mask is not None,
-                t_real=t_real,
-            )
-        attn_out = _proj(
-            attn.reshape(b, t, h_heads * hd), params, "o_proj", lora
-        )
+        with jax.named_scope("attention"):
+            if t == 1:
+                kernel = (
+                    paged_decode_attention_int4
+                    if isinstance(k_slab, QuantSlab)
+                    else paged_decode_attention
+                )
+                attn = kernel(
+                    q[:, 0], k_slab, v_slab, page_table, total_lens,
+                    page_size=page_size, scale=attn_scale(spec),
+                    interpret=interpret,
+                    window=window,  # per-layer traced scalar (0 = full)
+                )[:, None]  # [B, 1, H, hd]
+            else:
+                attn = paged_chunk_attention(
+                    q, k_slab, v_slab, page_table, total_lens,
+                    page_size=page_size, tree_mask=tree_mask,
+                    scale=attn_scale(spec), interpret=interpret,
+                    window=window, has_tree=tree_mask is not None,
+                    t_real=t_real,
+                )
         return _finish_layer(
-            spec, params, hidden, x, attn_out, k_slab, v_slab, lora
+            spec, params, hidden, x, _o_proj(attn, params, lora), k_slab,
+            v_slab, lora,
         )
-    k_ctx = gather_pages(k_slab, page_table, page_size).astype(hidden.dtype)
-    v_ctx = gather_pages(v_slab, page_table, page_size).astype(hidden.dtype)
+    with jax.named_scope("arena_gather"):
+        k_ctx = gather_pages(
+            k_slab, page_table, page_size
+        ).astype(hidden.dtype)
+        v_ctx = gather_pages(
+            v_slab, page_table, page_size
+        ).astype(hidden.dtype)
 
     if use_flash:
         # long-context prefill: the Pallas kernel streams K/V tiles through
@@ -265,20 +292,30 @@ def layer_body(
         # too, with the lens mask hiding each row's page-padded tail.
         from bloombee_tpu.ops.pallas.flash_attention import flash_attention
 
-        attn = flash_attention(
-            q, k_ctx, v_ctx, causal=True, scale=attn_scale(spec),
-            starts=q_positions[:, 0], lens=total_lens,
-            interpret=env.get("BBTPU_FLASH_INTERPRET"),
-        )
+        with jax.named_scope("attention"):
+            attn = flash_attention(
+                q, k_ctx, v_ctx, causal=True, scale=attn_scale(spec),
+                starts=q_positions[:, 0], lens=total_lens,
+                interpret=env.get("BBTPU_FLASH_INTERPRET"),
+            )
     else:
-        attn = attend_paged(
-            spec, q, k_ctx, v_ctx, q_positions, total_lens, tree_mask,
-            window, attn_topk,
-        )
-    attn_out = _proj(attn.reshape(b, t, h_heads * hd), params, "o_proj", lora)
+        with jax.named_scope("attention"):
+            attn = attend_paged(
+                spec, q, k_ctx, v_ctx, q_positions, total_lens, tree_mask,
+                window, attn_topk,
+            )
     return _finish_layer(
-        spec, params, hidden, x, attn_out, k_slab, v_slab, lora
+        spec, params, hidden, x, _o_proj(attn, params, lora), k_slab,
+        v_slab, lora,
     )
+
+
+def _o_proj(attn, params, lora):
+    """[..., T, H, hd] attention output -> [..., T, D] through o_proj."""
+    with jax.named_scope("attn_proj"):
+        return _proj(
+            attn.reshape(*attn.shape[:-2], -1), params, "o_proj", lora
+        )
 
 
 def attend_ragged(
@@ -386,22 +423,26 @@ def layer_body_ragged(
         spec.head_dim,
     )
     x = _norm(hidden, params, "input_layernorm", spec)
-    q = _proj(x, params, "q_proj", lora).reshape(1, r, h_heads, hd)
-    k = _proj(x, params, "k_proj", lora).reshape(1, r, kv_heads, hd)
-    if spec.k_eq_v:
-        v = k
-    else:
-        v = _proj(x, params, "v_proj", lora).reshape(1, r, kv_heads, hd)
-    if spec.qk_norm:
-        q = rms_norm(q, params["q_norm"], spec.rms_norm_eps)
-        k = rms_norm(k, params["k_norm"], spec.rms_norm_eps)
-    if not spec.alibi:
-        q, k = apply_rotary(q, k, cos, sin)
+    with jax.named_scope("attn_proj"):
+        q = _proj(x, params, "q_proj", lora).reshape(1, r, h_heads, hd)
+        k = _proj(x, params, "k_proj", lora).reshape(1, r, kv_heads, hd)
+        if spec.k_eq_v:
+            v = k
+        else:
+            v = _proj(x, params, "v_proj", lora).reshape(
+                1, r, kv_heads, hd
+            )
+        if spec.qk_norm:
+            q = rms_norm(q, params["q_norm"], spec.rms_norm_eps)
+            k = rms_norm(k, params["k_norm"], spec.rms_norm_eps)
+        if not spec.alibi:
+            q, k = apply_rotary(q, k, cos, sin)
 
-    k_slab, v_slab = arena_write(
-        k_slab, v_slab, slots,
-        k.reshape(r, kv_heads, hd), v.reshape(r, kv_heads, hd),
-    )
+    with jax.named_scope("arena_write"):
+        k_slab, v_slab = arena_write(
+            k_slab, v_slab, slots,
+            k.reshape(r, kv_heads, hd), v.reshape(r, kv_heads, hd),
+        )
     from bloombee_tpu.kv.quant import QuantSlab
 
     if use_kernel and not isinstance(k_slab, QuantSlab):
@@ -409,30 +450,31 @@ def layer_body_ragged(
             paged_ragged_attention,
         )
 
-        attn = paged_ragged_attention(
-            q[0], k_slab, v_slab, page_table, total_lens,
-            q_seq, q_positions[0],
-            page_size=page_size, scale=attn_scale(spec),
-            interpret=env.get("BBTPU_PAGED_INTERPRET"),
-            window=window, nt=nt, tree_rows=tree_rows,
-            has_tree=tree_rows is not None,
-        )[None]
+        with jax.named_scope("attention"):
+            attn = paged_ragged_attention(
+                q[0], k_slab, v_slab, page_table, total_lens,
+                q_seq, q_positions[0],
+                page_size=page_size, scale=attn_scale(spec),
+                interpret=env.get("BBTPU_PAGED_INTERPRET"),
+                window=window, nt=nt, tree_rows=tree_rows,
+                has_tree=tree_rows is not None,
+            )[None]
     else:
-        k_ctx = gather_pages(
-            k_slab, page_table, page_size
-        ).astype(hidden.dtype)
-        v_ctx = gather_pages(
-            v_slab, page_table, page_size
-        ).astype(hidden.dtype)
-        attn = attend_ragged(
-            spec, q[0], k_ctx, v_ctx, q_positions[0], q_seq, total_lens,
-            window, nt=nt, tree_rows=tree_rows,
-        )[None]
-    attn_out = _proj(
-        attn.reshape(1, r, h_heads * hd), params, "o_proj", lora
-    )
+        with jax.named_scope("arena_gather"):
+            k_ctx = gather_pages(
+                k_slab, page_table, page_size
+            ).astype(hidden.dtype)
+            v_ctx = gather_pages(
+                v_slab, page_table, page_size
+            ).astype(hidden.dtype)
+        with jax.named_scope("attention"):
+            attn = attend_ragged(
+                spec, q[0], k_ctx, v_ctx, q_positions[0], q_seq, total_lens,
+                window, nt=nt, tree_rows=tree_rows,
+            )[None]
     return _finish_layer(
-        spec, params, hidden, x, attn_out, k_slab, v_slab, lora
+        spec, params, hidden, x, _o_proj(attn, params, lora), k_slab,
+        v_slab, lora,
     )
 
 

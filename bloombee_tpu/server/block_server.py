@@ -205,8 +205,17 @@ class _ChainError(RuntimeError):
         self.permanent = permanent
 
 
+class _Member:
+    """What the compute queue reads off a group member for its
+    `bbtpu.task` span."""
+
+    @property
+    def rows(self) -> int:
+        return int(self.hidden.shape[0]) * int(self.hidden.shape[1])
+
+
 @dataclasses.dataclass
-class _BatchMember:
+class _BatchMember(_Member):
     """One session's single-token decode step inside a merged dispatch
     (continuous batching). `handle` is the session's cache handle or a row
     slice of it (micro-batch chunks batch like any other member)."""
@@ -217,7 +226,7 @@ class _BatchMember:
 
 
 @dataclasses.dataclass
-class _ChunkMember:
+class _ChunkMember(_Member):
     """One prefill chunk inside a MIXED dispatch (--mixed-batch): the
     multi-token member that rides a ragged span step alongside other
     sessions' single-token decodes. `first`/`last` carry the chunk
@@ -232,7 +241,7 @@ class _ChunkMember:
 
 
 @dataclasses.dataclass
-class _TreeMember:
+class _TreeMember(_Member):
     """One session's tree-verify step inside a batched ragged dispatch
     (--spec-batch): the linearized draft tree's rows verify alongside
     other sessions' trees in one executor.tree_group call. `handle` may be
@@ -1919,6 +1928,11 @@ class BlockServer(PromotionLoopMixin):
             # when the witness is off, so probes need no conditionals
             **lockwatch.counters(),
             **jitwatch.counters(),
+            # where the host's time went (BBTPU_JITWATCH=1; empty / zeros
+            # with it off): per span name {"n", "total_ms"}, and the
+            # compute worker's wall time by cause
+            "host_spans": jitwatch.host_spans(),
+            "worker": self.compute.worker_stats_ms(),
             # overload observability: shed/admit counters, retry_after
             # histogram, and per-client fair-share debt (None with the
             # admission controller off; the live load snapshot itself rides
@@ -3163,9 +3177,9 @@ class BlockServer(PromotionLoopMixin):
                 return
             raise
 
-        t0 = clock.perf_counter()
-        out = await asyncio.to_thread(self.executor.fetch, out_dev)
-        t_fetch_ms = (clock.perf_counter() - t0) * 1000.0
+        out, t_fetch_ms = await asyncio.to_thread(
+            self._fetch_timed, out_dev, session
+        )
         if self.liar_p > 0 and self._liar_rng.random() < self.liar_p:
             # TEST HOOK: lie BEFORE the digest/serialization below, so the
             # reply is a well-formed frame whose digest matches the lie —
@@ -3369,6 +3383,17 @@ class BlockServer(PromotionLoopMixin):
             return
         await self._run_decode_n_fused(session, stream, meta, tensors)
 
+    def _fetch_timed(self, out_dev, session: _Session, fetch=None):
+        """Runs on a fetch thread: the d2h wait as the span `bbtpu.fetch`,
+        whose duration is the wire's t_fetch_ms. `step` is the session's
+        count of steps served so far, the same on every span of one wire
+        step."""
+        with jitwatch.stopwatch(
+            "bbtpu.fetch", session=session.id, step=session.n_steps
+        ) as sw:
+            out = (fetch or self.executor.fetch)(out_dev)
+        return out, sw.ms
+
     async def _run_decode_n_fused(
         self, session: _Session, stream: Stream, meta: dict, tensors: list
     ) -> None:
@@ -3392,13 +3417,15 @@ class BlockServer(PromotionLoopMixin):
                     "replay"
                 )
             session.last_step_at = clock.monotonic()
-            t0 = clock.perf_counter()
-            out = self.executor.decode_n(
-                session.handle, ids, n, self._client_params,
-                eos_token_id=eos, finished=finished,
-                adapter=session.adapter,
-            )
-            return out, (clock.perf_counter() - t0) * 1000.0
+            with jitwatch.stopwatch(
+                "bbtpu.dispatch", session=session.id, step=session.n_steps
+            ) as sw:
+                out = self.executor.decode_n(
+                    session.handle, ids, n, self._client_params,
+                    eos_token_id=eos, finished=finished,
+                    adapter=session.adapter,
+                )
+            return out, sw.ms
 
         try:
             out_dev, t_dispatch_ms = await self.compute.submit(
@@ -3414,11 +3441,10 @@ class BlockServer(PromotionLoopMixin):
             ):
                 return
             raise
-        t0 = clock.perf_counter()
-        toks = await asyncio.to_thread(
-            lambda: np.asarray(out_dev, dtype=np.int32)
+        toks, t_fetch_ms = await asyncio.to_thread(
+            self._fetch_timed, out_dev, session,
+            lambda dev: np.asarray(dev, dtype=np.int32),
         )
-        t_fetch_ms = (clock.perf_counter() - t0) * 1000.0
         session.n_steps += n
         session.sum_tokens += int(ids.shape[0]) * n
         session.sum_dispatch_ms += t_dispatch_ms
@@ -3503,15 +3529,18 @@ class BlockServer(PromotionLoopMixin):
                             "lost — replay"
                         )
                     session.last_step_at = clock.monotonic()
-                    t0 = clock.perf_counter()
-                    h = self._embed_ids(ids_now)
-                    out = self.executor.decode(
-                        session.handle,
-                        h.astype(self.executor.transfer_dtype),
-                        commit=True, layers=session.layers, fetch=False,
-                        adapter=session.adapter,
-                    )
-                    return out, (clock.perf_counter() - t0) * 1000.0
+                    with jitwatch.stopwatch(
+                        "bbtpu.dispatch", session=session.id,
+                        step=session.n_steps,
+                    ) as sw:
+                        h = self._embed_ids(ids_now)
+                        out = self.executor.decode(
+                            session.handle,
+                            h.astype(self.executor.transfer_dtype),
+                            commit=True, layers=session.layers,
+                            fetch=False, adapter=session.adapter,
+                        )
+                    return out, sw.ms
                 out_dev, dt_ms = await self.compute.submit(
                     PRIORITY_INFERENCE, _dispatch
                 )
@@ -4034,25 +4063,28 @@ class BlockServer(PromotionLoopMixin):
                 "server KV arena was rebuilt; session cache lost — replay"
             )
         session.last_step_at = clock.monotonic()
-        t0 = clock.perf_counter()
-        if first and self.manager.has_adopted(handle):
-            # settle the probe adoption before the suffix's first chunk
-            # (same semantics as _compute_step's settle)
-            self.manager.ensure_resident(handle)
-            self.manager.trim_adopted(handle, int(prefix_skip or 0))
-        session.adoption_settled = True
-        # recovery owner: _run_chunked_prefill's except BaseException ->
-        # _abort_chunked_prefill (epoch-guarded rollback); this helper
-        # runs only inside that stream driver
-        out = self.executor.prefill_chunk(  # bbtpu: noqa[BB001]
-            handle, hidden, commit=False, layers=session.layers,
-            fetch=False, adapter=session.adapter,
-        )
-        if last:
-            self.manager.commit(handle)
-        self.step_dispatches += 1
-        self.step_tokens += int(hidden.shape[0]) * int(hidden.shape[1])
-        dt_ms = (clock.perf_counter() - t0) * 1000.0
+        with jitwatch.stopwatch(
+            "bbtpu.dispatch", session=session.id, step=session.n_steps
+        ) as sw:
+            if first and self.manager.has_adopted(handle):
+                # settle the probe adoption before the suffix's first chunk
+                # (same semantics as _compute_step's settle)
+                self.manager.ensure_resident(handle)
+                self.manager.trim_adopted(handle, int(prefix_skip or 0))
+            session.adoption_settled = True
+            # recovery owner: _run_chunked_prefill's except BaseException
+            # -> _abort_chunked_prefill (epoch-guarded rollback); this
+            # helper runs only inside that stream driver
+            out = self.executor.prefill_chunk(  # bbtpu: noqa[BB001]
+                handle, hidden, commit=False, layers=session.layers,
+                fetch=False, adapter=session.adapter,
+            )
+            if last:
+                with jitwatch.span("bbtpu.commit"):
+                    self.manager.commit(handle)
+            self.step_dispatches += 1
+            self.step_tokens += int(hidden.shape[0]) * int(hidden.shape[1])
+        dt_ms = sw.ms
         if env.log_channel_enabled("timing"):
             logger.info(
                 "[timing] session=%s prefill chunk tokens=%d%s "
@@ -4082,45 +4114,50 @@ class BlockServer(PromotionLoopMixin):
                 "server KV arena was rebuilt; session cache lost — replay"
             )
         session.last_step_at = clock.monotonic()
-        t0 = clock.perf_counter()
-        if self.manager.has_adopted(handle):
-            # settle an outstanding probe adoption: unpark first so the
-            # trim acts on live lengths, then shrink each row's adopted
-            # prefix to the chain-wide skip the client actually uses. A
-            # step that never declares prefix_skip drops the adoption
-            # entirely (skip 0) — the safe interpretation of a client that
-            # changed its mind (or a stale retry).
-            self.manager.ensure_resident(handle)
-            self.manager.trim_adopted(
-                handle, int(prefix_skip or 0)
-            )
-        session.adoption_settled = True
-        if hidden.shape[1] > 1 and tree_mask is None:
-            out = self.executor.prefill(
-                handle, hidden, commit=commit, layers=session.layers,
-                fetch=False, adapter=session.adapter,
-            )
-        else:
-            if hidden.shape[1] == 1 and self._chunking_sessions:
-                # a decode step ran while some session's chunked prefill
-                # was mid-stream: the stall this scheduler removes
-                self.decode_steps_interleaved += 1
-            out = self.executor.decode(
-                handle, hidden, commit=commit, tree_mask=tree_mask,
-                layers=session.layers, depths=depths, fetch=False,
-                adapter=session.adapter,
-            )
-        if commit_lens is not None:
-            # ragged explicit-length commit only happens on an id-session
-            # failover replay: account the replayed tokens so the chaos
-            # tests can assert the replication bound from rpc_info
-            self.manager.commit(handle, lengths=commit_lens)
-            self.failover_replayed_tokens += int(
-                hidden.shape[0] * hidden.shape[1]
-            )
-        self.step_dispatches += 1
-        self.step_tokens += int(hidden.shape[0]) * int(hidden.shape[1])
-        dt_ms = (clock.perf_counter() - t0) * 1000.0
+        with jitwatch.stopwatch(
+            "bbtpu.dispatch", session=session.id, step=session.n_steps
+        ) as sw:
+            if self.manager.has_adopted(handle):
+                # settle an outstanding probe adoption: unpark first so the
+                # trim acts on live lengths, then shrink each row's adopted
+                # prefix to the chain-wide skip the client actually uses. A
+                # step that never declares prefix_skip drops the adoption
+                # entirely (skip 0) — the safe interpretation of a client
+                # that changed its mind (or a stale retry).
+                self.manager.ensure_resident(handle)
+                self.manager.trim_adopted(
+                    handle, int(prefix_skip or 0)
+                )
+            session.adoption_settled = True
+            if hidden.shape[1] > 1 and tree_mask is None:
+                out = self.executor.prefill(
+                    handle, hidden, commit=commit, layers=session.layers,
+                    fetch=False, adapter=session.adapter,
+                )
+            else:
+                if hidden.shape[1] == 1 and self._chunking_sessions:
+                    # a decode step ran while some session's chunked
+                    # prefill was mid-stream: the stall this scheduler
+                    # removes
+                    self.decode_steps_interleaved += 1
+                out = self.executor.decode(
+                    handle, hidden, commit=commit, tree_mask=tree_mask,
+                    layers=session.layers, depths=depths, fetch=False,
+                    adapter=session.adapter,
+                )
+            if commit_lens is not None:
+                # ragged explicit-length commit only happens on an
+                # id-session failover replay: account the replayed tokens
+                # so the chaos tests can assert the replication bound from
+                # rpc_info
+                with jitwatch.span("bbtpu.commit"):
+                    self.manager.commit(handle, lengths=commit_lens)
+                self.failover_replayed_tokens += int(
+                    hidden.shape[0] * hidden.shape[1]
+                )
+            self.step_dispatches += 1
+            self.step_tokens += int(hidden.shape[0]) * int(hidden.shape[1])
+        dt_ms = sw.ms
         if env.log_channel_enabled("timing"):
             logger.info(
                 "[timing] session=%s tokens=%d dispatch_ms=%.2f",
@@ -4216,23 +4253,30 @@ class BlockServer(PromotionLoopMixin):
         succeeds, so a failure rolls the whole group's tables back to the
         pre-step state and the row-by-row replay appends no ghost tokens."""
 
-        t0 = clock.perf_counter()
-        now = clock.monotonic()
-        for m in group:
-            m.session.last_step_at = now
-        handles = [m.handle for m in group]
-        try:
-            out, combined = self.executor.decode_group(
-                handles,
-                [m.hidden for m in group],
-                layers=group[0].session.layers,
-                adapter=group[0].session.adapter,
-            )
-        except Exception:
-            self.manager.rollback(self.manager.combine_handles(handles))
-            raise
-        self.manager.commit(combined)
-        dt_ms = (clock.perf_counter() - t0) * 1000.0
+        with jitwatch.stopwatch(
+            "bbtpu.dispatch", members=len(group),
+            sessions="+".join(m.session.id for m in group),
+        ) as sw:
+            now = clock.monotonic()
+            for m in group:
+                m.session.last_step_at = now
+            handles = [m.handle for m in group]
+            try:
+                out, combined = self.executor.decode_group(
+                    handles,
+                    [m.hidden for m in group],
+                    layers=group[0].session.layers,
+                    adapter=group[0].session.adapter,
+                )
+            except Exception:
+                with jitwatch.span("bbtpu.commit"):
+                    self.manager.rollback(
+                        self.manager.combine_handles(handles)
+                    )
+                raise
+            with jitwatch.span("bbtpu.commit"):
+                self.manager.commit(combined)
+        dt_ms = sw.ms
         self.batch_dispatches += 1
         self.batched_steps += len(group)
         self.step_dispatches += 1
@@ -4247,10 +4291,11 @@ class BlockServer(PromotionLoopMixin):
             )
         outs = []
         row = 0
-        for m in group:
-            b = m.handle.batch_size
-            outs.append((out[row:row + b], dt_ms))
-            row += b
+        with jitwatch.span("bbtpu.slice", members=len(group)):
+            for m in group:
+                b = m.handle.batch_size
+                outs.append((out[row:row + b], dt_ms))
+                row += b
         return outs
 
     # ----------------------------------------- batched tree verification
@@ -4471,53 +4516,63 @@ class BlockServer(PromotionLoopMixin):
           success the surviving slots settle when the session's next
           accept rides in (accept_speculative, unchanged)."""
 
-        t0 = clock.perf_counter()
-        now = clock.monotonic()
-        for m in group:
-            m.session.last_step_at = now
-        chunk = group[-1] if isinstance(group[-1], _ChunkMember) else None
-        decodes = [m for m in group if isinstance(m, _BatchMember)]
-        trees = [m for m in group if isinstance(m, _TreeMember)]
-        # pre-dispatch speculative lengths: the truncate targets on
-        # failure for the chunk and for every tree member
-        chunk_snap = (
-            [int(x) for x in self.manager.context_lens(chunk.handle)]
-            if chunk is not None else None
-        )
-        tree_snaps = [
-            [int(x) for x in self.manager.context_lens(m.handle)]
-            for m in trees
-        ]
-        try:
-            out, _combined = self.executor.ragged_group(
-                [m.handle for m in group],
-                [m.hidden for m in group],
-                tree_masks=[
-                    m.tree_mask if isinstance(m, _TreeMember) else None
-                    for m in group
-                ],
-                depths_list=[
-                    m.depths if isinstance(m, _TreeMember) else None
-                    for m in group
-                ],
-                layers=group[0].session.layers,
-                adapter=group[0].session.adapter,
+        with jitwatch.stopwatch(
+            "bbtpu.dispatch", members=len(group),
+            sessions="+".join(m.session.id for m in group),
+        ) as sw:
+            now = clock.monotonic()
+            for m in group:
+                m.session.last_step_at = now
+            chunk = group[-1] if isinstance(group[-1], _ChunkMember) else None
+            decodes = [m for m in group if isinstance(m, _BatchMember)]
+            trees = [m for m in group if isinstance(m, _TreeMember)]
+            # pre-dispatch speculative lengths: the truncate targets on
+            # failure for the chunk and for every tree member
+            chunk_snap = (
+                [int(x) for x in self.manager.context_lens(chunk.handle)]
+                if chunk is not None else None
             )
-        except Exception:
-            if chunk is not None and self.manager.epoch_valid(chunk.handle):
-                self.manager.truncate_speculative(chunk.handle, chunk_snap)
-            for m, snap in zip(trees, tree_snaps):
-                if self.manager.epoch_valid(m.handle):
-                    self.manager.truncate_speculative(m.handle, snap)
-            for m in decodes:
-                if self.manager.epoch_valid(m.handle):
-                    self.manager.rollback(m.handle)
-            raise
-        for m in decodes:
-            self.manager.commit(m.handle)
-        if chunk is not None and chunk.last:
-            self.manager.commit(chunk.handle)
-        dt_ms = (clock.perf_counter() - t0) * 1000.0
+            tree_snaps = [
+                [int(x) for x in self.manager.context_lens(m.handle)]
+                for m in trees
+            ]
+            try:
+                out, _combined = self.executor.ragged_group(
+                    [m.handle for m in group],
+                    [m.hidden for m in group],
+                    tree_masks=[
+                        m.tree_mask if isinstance(m, _TreeMember) else None
+                        for m in group
+                    ],
+                    depths_list=[
+                        m.depths if isinstance(m, _TreeMember) else None
+                        for m in group
+                    ],
+                    layers=group[0].session.layers,
+                    adapter=group[0].session.adapter,
+                )
+            except Exception:
+                with jitwatch.span("bbtpu.commit"):
+                    if (chunk is not None
+                            and self.manager.epoch_valid(chunk.handle)):
+                        self.manager.truncate_speculative(
+                            chunk.handle, chunk_snap
+                        )
+                    for m, snap in zip(trees, tree_snaps):
+                        if self.manager.epoch_valid(m.handle):
+                            self.manager.truncate_speculative(
+                                m.handle, snap
+                            )
+                    for m in decodes:
+                        if self.manager.epoch_valid(m.handle):
+                            self.manager.rollback(m.handle)
+                raise
+            with jitwatch.span("bbtpu.commit"):
+                for m in decodes:
+                    self.manager.commit(m.handle)
+                if chunk is not None and chunk.last:
+                    self.manager.commit(chunk.handle)
+        dt_ms = sw.ms
         ntok = sum(
             m.handle.batch_size * int(m.hidden.shape[1]) for m in group
         )
@@ -4557,13 +4612,14 @@ class BlockServer(PromotionLoopMixin):
         # decode members get [b, 1, D], trees and the chunk [b, t, D]
         outs = []
         off = 0
-        for m in group:
-            b = m.handle.batch_size
-            t = int(m.hidden.shape[1])
-            outs.append(
-                (out[off:off + b * t].reshape(b, t, -1), dt_ms)
-            )
-            off += b * t
+        with jitwatch.span("bbtpu.slice", members=len(group)):
+            for m in group:
+                b = m.handle.batch_size
+                t = int(m.hidden.shape[1])
+                outs.append(
+                    (out[off:off + b * t].reshape(b, t, -1), dt_ms)
+                )
+                off += b * t
         return outs
 
     def _reclaim_idle(self, need_pages: int, exclude_seq_ids: set) -> int:

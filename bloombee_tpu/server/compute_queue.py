@@ -23,6 +23,7 @@ import collections
 import dataclasses
 import functools
 import itertools
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Hashable
 
@@ -85,6 +86,8 @@ class _Task:
     deadline: float | None  # clock.monotonic() cutoff, checked at pop time
     enqueued_at: float
     task_class: str | None = None  # "prefill"/"decode" wait-stat bucket
+    seq: int = 0  # the `task` id of its spans (bbtpu.enqueue, bbtpu.task)
+    enqueued_ns: int = 0  # time.perf_counter_ns() at submit
 
 
 @dataclasses.dataclass
@@ -101,6 +104,69 @@ class _GroupTask:
     deadline: float | None
     enqueued_at: float
     task_class: str | None = None
+    seq: int = 0
+    enqueued_ns: int = 0
+
+
+class _WorkerAccount:
+    """Where the serial worker's wall time went, on the real
+    perf-counter clock, per task that reached the compute thread:
+    `starved` from the previous task's end to this task's enqueue when
+    that is later (no work existed), `hop` from max(previous end,
+    enqueue) to the task's start on the compute thread (work existed,
+    nobody ran it: event-loop turn, gather window, run_in_executor),
+    `busy` the task itself. The three add up to the time from the
+    account's creation to the last task's end. Rides BBTPU_JITWATCH like
+    the spans: with it off `wrap` returns the function itself and every
+    counter stays 0."""
+
+    def __init__(self) -> None:
+        self.tasks = 0
+        self._starved_ns = 0
+        self._hop_ns = 0
+        self._busy_ns = 0
+        self._prev_end_ns = time.perf_counter_ns()
+
+    def wrap(self, fn: Callable[[], Any], enqueued_ns: int, **ids):
+        if not jitwatch.enabled():
+            return fn
+
+        def _accounted():
+            start = time.perf_counter_ns()
+            ready = max(self._prev_end_ns, enqueued_ns)
+            starved, hop = ready - self._prev_end_ns, max(0, start - ready)
+            try:
+                # hot_wrap: while this runs on the compute thread any host
+                # sync counts against jitwatch's hot-path budget (the queue
+                # serializes device work, so a sync here convoys every
+                # session), and the call is the span `bbtpu.task`
+                return jitwatch.hot_wrap(
+                    fn, starved_us=starved // 1000, hop_us=hop // 1000,
+                    **ids,
+                )()
+            finally:
+                end = time.perf_counter_ns()
+                self.tasks += 1
+                self._starved_ns += starved
+                self._hop_ns += hop
+                self._busy_ns += end - start
+                self._prev_end_ns = end
+
+        return _accounted
+
+    def stats_ms(self) -> dict:
+        return {
+            "tasks": self.tasks,
+            "starved_ms": round(self._starved_ns / 1e6, 3),
+            "hop_ms": round(self._hop_ns / 1e6, 3),
+            "busy_ms": round(self._busy_ns / 1e6, 3),
+        }
+
+
+def _kind(key: Hashable) -> str:
+    """A group key's kind for the task span: the server's keys lead with
+    it ("decode1", "chunkm", "tree")."""
+    return str(key[0] if isinstance(key, tuple) and key else key)
 
 
 class ComputeQueue:
@@ -151,6 +217,22 @@ class ComputeQueue:
         # NEXT pop will report — the only live signal during a jam, when the
         # sample deques go quiet precisely because nothing completes
         self._last_pop_at: float = clock.monotonic()
+        self._account = _WorkerAccount()
+
+    def worker_stats_ms(self) -> dict:
+        """{"tasks", "starved_ms", "hop_ms", "busy_ms"}: the worker's wall
+        time by cause (_WorkerAccount), `rpc_info["worker"]`."""
+        return self._account.stats_ms()
+
+    def _enqueue(self, priority: float, task) -> None:
+        task.seq = seq = next(self._seq)
+        task.enqueued_ns = time.perf_counter_ns()
+        # a few microseconds on the event loop's line: when work became
+        # available, to hold against the bbtpu.task of the same number
+        with jitwatch.span(
+            "bbtpu.enqueue", task=seq, **{"class": task.task_class or ""}
+        ):
+            self._queue.put_nowait((priority, seq, task))
 
     def start(self) -> None:
         self._worker_task = asyncio.create_task(self._worker())
@@ -238,7 +320,7 @@ class ComputeQueue:
             enqueued_at=clock.monotonic(),
             task_class=task_class,
         )
-        self._queue.put_nowait((priority, next(self._seq), task))
+        self._enqueue(priority, task)
         return await fut
 
     async def submit_group(
@@ -267,7 +349,7 @@ class ComputeQueue:
             enqueued_at=clock.monotonic(),
             task_class=task_class,
         )
-        self._queue.put_nowait((priority, next(self._seq), task))
+        self._enqueue(priority, task)
         return await fut
 
     async def _worker(self) -> None:
@@ -295,11 +377,12 @@ class ComputeQueue:
         if self._expired(task):
             return
         try:
-            # hot_wrap: while this runs on the compute thread any host
-            # sync counts against jitwatch's hot-path budget (the queue
-            # serializes device work, so a sync here convoys every session)
             result = await loop.run_in_executor(
-                self._thread, jitwatch.hot_wrap(task.fn)
+                self._thread,
+                self._account.wrap(
+                    task.fn, task.enqueued_ns, task=task.seq,
+                    **{"class": task.task_class or ""},
+                ),
             )
             if not task.fut.done():
                 task.fut.set_result(result)
@@ -347,9 +430,15 @@ class ComputeQueue:
                 return
             outcomes = await loop.run_in_executor(
                 self._thread,
-                jitwatch.hot_wrap(functools.partial(
-                    first.run_group, [m.payload for m in live]
-                )),
+                self._account.wrap(
+                    functools.partial(
+                        first.run_group, [m.payload for m in live]
+                    ),
+                    min(m.enqueued_ns for m in live),
+                    task=first.seq, members=len(live),
+                    rows=sum(getattr(m.payload, "rows", 1) for m in live),
+                    kinds="+".join(sorted({_kind(m.key) for m in live})),
+                ),
             )
             if len(outcomes) != len(live):
                 raise RuntimeError(

@@ -25,6 +25,21 @@ the compute-queue worker is mid-task (:func:`hot_wrap`) count as
 ``host_syncs_hot_path`` — a device stall inside the serialized step
 pipeline, the convoy BB011 flags statically.
 
+The same thread-local stack carries **host spans** (:func:`span`): every
+boundary of the served step (queue task, payload pack, h2d, the jit
+dispatches above, commit, output slicing, the off-queue fetch, the wire
+codec) is a named interval that (a) enters a
+``jax.profiler.TraceAnnotation`` with its ids, so a profiler session —
+and only a profiler session — puts it on the device trace's own clock,
+and (b) adds ``(n, total_ns)`` per name on ``time.perf_counter_ns``,
+read back through :func:`host_spans` / ``rpc_info["host_spans"]``. A
+:func:`region` IS a span, named ``bbtpu.jit.<function>`` with the bucket
+as an id. Spans are synchronous: never hold one across an ``await`` (an
+event-loop thread interleaves coroutines and would break the nesting).
+:func:`stopwatch` is the span whose duration its caller reads (the
+wire's ``t_dispatch_ms`` / ``t_fetch_ms``): it reads the clock with the
+witness off too, and is named and counted only with it on.
+
 At interpreter exit the witness appends one JSON line to
 ``BBTPU_JITWATCH_REPORT`` (append mode, multi-process merge — same
 contract as lockwatch/ledger). ``python -m bloombee_tpu.utils.jitwatch
@@ -54,6 +69,7 @@ from __future__ import annotations
 import atexit
 import json
 import threading
+import time
 
 from bloombee_tpu.utils import env
 
@@ -62,7 +78,10 @@ env.declare(
     "install the runtime compile/transfer witness: ledgers every XLA "
     "backend compile with (function, shape bucket, ms, phase) via the "
     "jax.monitoring hook, counts host syncs on the compute hot path, "
-    "and reports at exit. Off = listener never registered, zero overhead",
+    "names every boundary of the served step as a host span (bbtpu.*: "
+    "a jax.profiler.TraceAnnotation plus n/total_ms in rpc_info's "
+    "host_spans and worker groups), and reports at exit. Off = listener "
+    "never registered, spans are the shared no-op, zero overhead",
 )
 env.declare(
     "BBTPU_JITWATCH_REPORT", str, "",
@@ -97,11 +116,13 @@ class _Witness:
         self.warmup_failures = 0
         self.host_syncs: dict[str, int] = {}
         self.host_syncs_hot_path = 0
+        # span name -> [n, total_ns] on time.perf_counter_ns
+        self.spans: dict[str, list[int]] = {}
         self.phase = "warmup"
         self.fenced = False
 
     # ---------------------------------------------------- thread context
-    def _regions(self) -> list[tuple[str, str]]:
+    def _regions(self) -> list["_Span"]:
         st = getattr(self._tls, "regions", None)
         if st is None:
             st = self._tls.regions = []
@@ -121,8 +142,14 @@ class _Witness:
     def record_compile(self, duration_s: float) -> None:
         cached = getattr(self._tls, "cache_hit", False)
         self._tls.cache_hit = False
-        regions = self._regions()
-        function, shape = regions[-1] if regions else (_UNATTRIBUTED, "")
+        # the innermost REGION on this thread's stack owns the compile; a
+        # plain span (pack, slice, ...) owns none, so a compile under it
+        # stays unattributed exactly as before spans existed
+        function, shape = next(
+            ((f.function, f.shape) for f in reversed(self._regions())
+             if f.function is not None),
+            (_UNATTRIBUTED, ""),
+        )
         ms = float(duration_s) * 1000.0
         with self._mu:
             phase = self.phase
@@ -167,6 +194,25 @@ class _Witness:
             if hot:
                 self.host_syncs_hot_path += 1
 
+    def record_span(self, name: str, ns: int) -> None:
+        with self._mu:
+            rec = self.spans.get(name)
+            if rec is None:
+                self.spans[name] = [1, ns]
+            else:
+                rec[0] += 1
+                rec[1] += ns
+
+    def _spans_ms(self) -> dict:  # caller holds self._mu
+        return {
+            name: {"n": n, "total_ms": round(ns / 1e6, 3)}
+            for name, (n, ns) in sorted(self.spans.items())
+        }
+
+    def host_spans(self) -> dict:
+        with self._mu:
+            return self._spans_ms()
+
     def note_warmup_failure(self) -> None:
         with self._mu:
             self.warmup_failures += 1
@@ -201,6 +247,7 @@ class _Witness:
                 "warmup_degraded": bool(self.warmup_failures),
                 "host_syncs": dict(self.host_syncs),
                 "host_syncs_hot_path": self.host_syncs_hot_path,
+                "host_spans": self._spans_ms(),
                 "fenced": self.fenced,
             }
 
@@ -217,6 +264,7 @@ class _Witness:
             self.warmup_failures = 0
             self.host_syncs.clear()
             self.host_syncs_hot_path = 0
+            self.spans.clear()
             self.phase = "warmup"
             self.fenced = False
         # the CALLING thread's context only (other threads' region stacks
@@ -274,47 +322,135 @@ def install() -> bool:
     return True
 
 
-# ------------------------------------------------------------ attribution
-class _Region:
-    """Thread-local attribution frame for one dispatch: compiles fired
-    while entered are pinned to (function, shape_signature)."""
+# ------------------------------------------------------ spans + attribution
+_trace_annotation = None  # jax.profiler.TraceAnnotation, imported lazily
 
-    __slots__ = ("_function", "_shape", "_on")
 
-    def __init__(self, function: str, shape: str):
-        self._function = function
-        self._shape = shape
-        self._on = enabled()
+def _annotation(name: str, ids: dict):
+    """A ``jax.profiler.TraceAnnotation`` for one span, or None where JAX
+    is absent. Outside a profiler session entering it costs a flag read;
+    inside one the span lands in the profiler's own trace. The profiler
+    encodes ids as ``name#k=v,k=v#``, so a comma inside a value (a bucket
+    tag ``b2,t1,p64``) would cut it short: commas travel as ``;``."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except Exception:  # jax-free analysis/CLI contexts
+            TraceAnnotation = False
+        _trace_annotation = TraceAnnotation
+    if not _trace_annotation:
+        return None
+    return _trace_annotation(name, **{
+        k: v.replace(",", ";") if isinstance(v, str) else v
+        for k, v in ids.items()
+    })
+
+
+class _NoSpan:
+    """The shared frame of a span with the witness off: no clock, no
+    stack, no annotation."""
+
+    __slots__ = ()
+    ns = 0
+    ms = 0.0
 
     def __enter__(self):
-        if self._on:
-            _witness._regions().append((self._function, self._shape))
         return self
 
     def __exit__(self, *exc) -> None:
+        return None
+
+
+_NOOP = _NoSpan()
+
+
+class _Span:
+    """One named host interval on this thread's stack. `function` is set
+    for a region only: compiles fired while it is the innermost region
+    are pinned to (function, shape)."""
+
+    __slots__ = ("name", "ids", "function", "shape", "ns", "_on", "_t0",
+                 "_ann")
+
+    def __init__(self, name: str, ids: dict, on: bool,
+                 function: str | None = None, shape: str = ""):
+        self.name = name
+        self.ids = ids
+        self.function = function
+        self.shape = shape
+        self.ns = 0
+        self._on = on
+        self._ann = None
+
+    def __enter__(self):
         if self._on:
+            st = _witness._regions()
+            if st and "task" in st[-1].ids and "task" not in self.ids:
+                # everything a queue task runs repeats its number
+                self.ids = dict(self.ids, task=st[-1].ids["task"])
+            st.append(self)
+            self._ann = _annotation(self.name, self.ids)
+            if self._ann is not None:
+                self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.ns = time.perf_counter_ns() - self._t0
+        if self._on:
+            if self._ann is not None:
+                self._ann.__exit__(*exc)
             st = _witness._regions()
             if st:
                 st.pop()
+            _witness.record_span(self.name, self.ns)
+
+    @property
+    def ms(self) -> float:
+        return self.ns / 1e6
 
 
-def region(function: str, shape: str) -> _Region:
+def span(name: str, **ids):
+    """Name one synchronous host interval: ``with jitwatch.span(
+    "bbtpu.pack", session=sid, step=n): ...``. Witness off: the shared
+    no-op frame."""
+    if not enabled():
+        return _NOOP
+    return _Span(name, ids, True)
+
+
+def stopwatch(name: str, **ids) -> _Span:
+    """A span whose duration the caller reads (``.ms`` / ``.ns`` after the
+    block): ONE clock pair feeds both the caller's number and, with the
+    witness on, the span's. Witness off: the clock pair alone."""
+    return _Span(name, ids, enabled())
+
+
+def region(function: str, shape: str):
     """Wrap one jit dispatch: ``with jitwatch.region("span_step",
-    "b2,t1,p64"): ...``. Cheap no-op frame when the witness is off."""
-    return _Region(function, shape)
+    "b2,t1,p64"): ...``. A span named ``bbtpu.jit.<function>`` that also
+    owns the compiles fired inside it. Cheap no-op frame when the
+    witness is off."""
+    if not enabled():
+        return _NOOP
+    return _Span("bbtpu.jit." + function, {"bucket": shape}, True,
+                 function, shape)
 
 
-def hot_wrap(fn):
+def hot_wrap(fn, **ids):
     """Mark `fn` as compute-queue hot-path work: host syncs recorded
-    while it runs count as ``host_syncs_hot_path``. Returns `fn`
-    unchanged when the witness is off (zero-overhead contract)."""
+    while it runs count as ``host_syncs_hot_path``, and the call is the
+    span ``bbtpu.task`` carrying `ids`. Returns `fn` unchanged when the
+    witness is off (zero-overhead contract)."""
     if not enabled():
         return fn
 
     def _hot(*args, **kwargs):
         _witness._tls.hot = _witness._hot_depth() + 1
         try:
-            return fn(*args, **kwargs)
+            with _Span("bbtpu.task", ids, True):
+                return fn(*args, **kwargs)
         finally:
             _witness._tls.hot = _witness._hot_depth() - 1
 
@@ -374,6 +510,12 @@ def counters() -> dict:
     }
 
 
+def host_spans() -> dict:
+    """``{span name: {"n", "total_ms"}}`` since start (or reset): the
+    ``rpc_info["host_spans"]`` group. Empty with the witness off."""
+    return _witness.host_spans()
+
+
 def snapshot() -> dict:
     return _witness.snapshot()
 
@@ -413,6 +555,7 @@ def merge_lines(text: str) -> dict:
         "warmup_degraded": False,
         "host_syncs": {},
         "host_syncs_hot_path": 0,
+        "host_spans": {},
         "fenced": False,
     }
     for line in text.splitlines():
@@ -433,6 +576,14 @@ def merge_lines(text: str) -> dict:
         for tag, n in (snap.get("host_syncs") or {}).items():
             merged["host_syncs"][tag] = (
                 merged["host_syncs"].get(tag, 0) + int(n)
+            )
+        for name, rec in (snap.get("host_spans") or {}).items():
+            into = merged["host_spans"].setdefault(
+                name, {"n": 0, "total_ms": 0.0}
+            )
+            into["n"] += int(rec.get("n") or 0)
+            into["total_ms"] = round(
+                into["total_ms"] + float(rec.get("total_ms") or 0), 3
             )
         merged["fenced"] = merged["fenced"] or bool(snap.get("fenced"))
         merged["preinstalled"] = (
@@ -487,6 +638,12 @@ def _main(argv=None) -> int:
     )
     for tag, n in sorted(merged["host_syncs"].items()):
         print(f"  sync {tag} x{n}")
+    for name, rec in sorted(merged["host_spans"].items()):
+        if rec["n"]:
+            print(
+                f"  span {name} x{rec['n']} "
+                f"mean {rec['total_ms'] / rec['n']:.3f}ms"
+            )
     for c in steady:
         print(
             f"  STEADY RECOMPILE {c['function']}[{c['shape']}] "

@@ -27,7 +27,7 @@ import asyncio
 import concurrent.futures
 import threading
 
-from bloombee_tpu.utils import env
+from bloombee_tpu.utils import env, jitwatch
 from bloombee_tpu.wire import tensor_codec
 from bloombee_tpu.wire.flow import FlowLimiter
 
@@ -75,13 +75,22 @@ def codec_executor() -> concurrent.futures.ThreadPoolExecutor:
 
 def encode_now(tensors, compression: bool = True, allowed=None):
     """Synchronous serialize (worker-thread body / legacy sync path)."""
-    return tensor_codec.serialize_tensors(tensors, compression,
-                                          allowed=allowed)
+    with jitwatch.span(
+        "bbtpu.codec.encode",
+        bytes=sum(int(getattr(t, "nbytes", 0)) for t in tensors or ()),
+    ):
+        return tensor_codec.serialize_tensors(tensors, compression,
+                                              allowed=allowed)
 
 
 def decode_now(metas, blobs, writable: bool = False):
     """Synchronous deserialize (worker-thread body / legacy sync path)."""
-    return tensor_codec.deserialize_tensors(metas, blobs, writable=writable)
+    with jitwatch.span(
+        "bbtpu.codec.decode", bytes=sum(len(b) for b in blobs or ())
+    ):
+        return tensor_codec.deserialize_tensors(
+            metas, blobs, writable=writable
+        )
 
 
 class _NullSlot:

@@ -4,7 +4,10 @@ counting, the zero-overhead-when-off contract, the multi-process
 report/--require gate (vacuous-green, missing-fence, and steady-
 recompile failure modes), and one live e2e swarm run proving a
 multi-session steady-state decode incurs ZERO post-warmup recompiles
-while observing >=1 warmup compile.
+while observing >=1 warmup compile. Host spans: nesting on the region
+stack, the stopwatch, the compute worker's starved/hop/busy account, the
+span counts of a served decode step and a chunked prefill, and named
+scopes leaving the step's outputs bit-identical.
 """
 
 import asyncio
@@ -214,12 +217,201 @@ def test_flush_skips_empty_witness(tmp_path, watch_on):
     assert not report.exists() or report.read_text() == ""
 
 
+def test_report_carries_host_spans_and_merges_them(tmp_path, watch_on, capsys):
+    """The exit report (one line per process) holds the spans' n and
+    total_ms; the merge adds them up and the CLI prints their means."""
+    path = tmp_path / "r.jsonl"
+    for _ in range(2):  # two processes' lines
+        jitwatch.reset()
+        with jitwatch.region("span_step_packed", "b1,t1,p4"):
+            jitwatch._witness.record_compile(0.01)
+        with jitwatch.span("bbtpu.pack"):
+            pass
+        jitwatch.flush(str(path))
+    merged = jitwatch.merge_lines(path.read_text())
+    assert merged["host_spans"]["bbtpu.pack"]["n"] == 2
+    assert merged["host_spans"]["bbtpu.jit.span_step_packed"]["n"] == 2
+    jitwatch._main([str(path)])
+    assert "span bbtpu.pack x2 mean" in capsys.readouterr().out
+
+
 def test_merge_skips_garbage_lines(watch_on):
     merged = jitwatch.merge_lines(
         "not json\n" + json.dumps({"xla_compiles": 3, "fenced": True}) + "\n"
     )
     assert merged["xla_compiles"] == 3
     assert merged["fenced"] is True
+
+
+# --------------------------------------------------------------- host spans
+def test_span_nests_on_the_region_stack_and_pops_on_exception(watch_on):
+    stack = jitwatch._witness._regions()
+    with pytest.raises(RuntimeError):
+        with jitwatch.span("bbtpu.task", task=7):
+            with jitwatch.span("bbtpu.pack") as pack:
+                assert [f.name for f in stack] == ["bbtpu.task", "bbtpu.pack"]
+                # everything a queue task runs repeats its number
+                assert pack.ids == {"task": 7}
+            with jitwatch.region("span_step_packed", "b2,t1,p64") as reg:
+                assert stack[-1] is reg
+                assert reg.name == "bbtpu.jit.span_step_packed"
+                assert reg.ids == {"bucket": "b2,t1,p64", "task": 7}
+                # a plain span inside a region owns no compile: the region
+                # keeps it
+                with jitwatch.span("bbtpu.h2d"):
+                    jitwatch._witness.record_compile(0.01)
+                raise RuntimeError("dispatch failed")
+    assert stack == []
+    spans = jitwatch.host_spans()
+    assert {k: v["n"] for k, v in spans.items()} == {
+        "bbtpu.task": 1, "bbtpu.pack": 1, "bbtpu.h2d": 1,
+        "bbtpu.jit.span_step_packed": 1,
+    }
+    assert spans["bbtpu.task"]["total_ms"] >= spans["bbtpu.pack"]["total_ms"]
+    assert jitwatch.snapshot()["compiles"][0]["function"] == "span_step_packed"
+    # a compile under a plain span alone stays unattributed, as before spans
+    with jitwatch.span("bbtpu.slice"):
+        jitwatch._witness.record_compile(0.01)
+    assert jitwatch.snapshot()["compiles"][1]["function"] == "(unattributed)"
+
+
+def test_annotation_ids_survive_the_profilers_comma_encoding(watch_on):
+    """The profiler writes ids as name#k=v,k=v#: a bucket tag's commas
+    would cut the value short, so they travel as semicolons."""
+    ann = jitwatch._annotation("bbtpu.jit.x", {"bucket": "b2,t1,p64", "n": 3})
+    assert ann is not None
+    with jitwatch.region("x", "b2,t1,p64") as reg:
+        assert reg.shape == "b2,t1,p64"  # the compile ledger keeps commas
+
+
+def test_spans_off_are_the_shared_noop_and_stopwatch_still_times(monkeypatch):
+    monkeypatch.delenv("BBTPU_JITWATCH", raising=False)
+    assert jitwatch.span("bbtpu.pack", session="s") is jitwatch._NOOP
+    assert jitwatch.region("span_step_packed", "b1,t1,p4") is jitwatch._NOOP
+
+    def fn():
+        return 7
+
+    assert jitwatch.hot_wrap(fn, task=1) is fn
+    with jitwatch.stopwatch("bbtpu.dispatch", session="s") as sw:
+        sum(range(1000))
+    assert sw.ns > 0 and sw.ms == sw.ns / 1e6
+    assert jitwatch.host_spans() == {}
+    assert jitwatch._witness._regions() == []
+
+
+def test_worker_account_adds_up_to_the_workers_wall_time(watch_on):
+    """starved + hop + busy over a scripted sequence is the wall time from
+    the queue's creation to the last task's end (within 5%), and each
+    class holds what the script put there."""
+    import time
+
+    from bloombee_tpu.server.compute_queue import (
+        PRIORITY_INFERENCE,
+        ComputeQueue,
+    )
+
+    async def run():
+        t0 = time.perf_counter()
+        q = ComputeQueue()
+        q.start()
+        await asyncio.sleep(0.06)  # no task exists: starved
+        first = asyncio.create_task(
+            q.submit(PRIORITY_INFERENCE, time.sleep, 0.05)
+        )
+        await asyncio.sleep(0.005)
+        # enqueued while the first runs: hop from its end, nothing starved
+        second = asyncio.create_task(
+            q.submit(PRIORITY_INFERENCE, time.sleep, 0.03,
+                     task_class="decode")
+        )
+        await asyncio.gather(first, second)
+        wall_ms = (time.perf_counter() - t0) * 1000.0
+        stats = q.worker_stats_ms()
+        await q.stop()
+        return wall_ms, stats
+
+    wall_ms, stats = asyncio.run(run())
+    assert stats["tasks"] == 2
+    total = stats["starved_ms"] + stats["hop_ms"] + stats["busy_ms"]
+    assert abs(total - wall_ms) <= 0.05 * wall_ms, (stats, wall_ms)
+    assert 55.0 <= stats["starved_ms"] <= 75.0, stats
+    assert 78.0 <= stats["busy_ms"] <= 95.0, stats
+    assert stats["hop_ms"] <= 12.0, stats
+    spans = jitwatch.host_spans()
+    assert spans["bbtpu.task"]["n"] == 2
+    assert spans["bbtpu.enqueue"]["n"] == 2
+
+
+def test_worker_account_off_is_the_function_itself(monkeypatch):
+    monkeypatch.delenv("BBTPU_JITWATCH", raising=False)
+    from bloombee_tpu.server.compute_queue import _WorkerAccount
+
+    def fn():
+        return 7
+
+    account = _WorkerAccount()
+    assert account.wrap(fn, 0, task=1) is fn
+    assert account.stats_ms() == {
+        "tasks": 0, "starved_ms": 0.0, "hop_ms": 0.0, "busy_ms": 0.0,
+    }
+
+
+def test_named_scopes_leave_the_span_step_bit_identical(monkeypatch):
+    """The layer's named scopes are HLO metadata only: a prefill and two
+    decode steps give the same bits with `jax.named_scope` taken out."""
+    import contextlib
+
+    import jax.numpy as jnp
+
+    from bloombee_tpu.kv.cache_manager import CacheManager
+    from bloombee_tpu.models.llama.block import init_block_params
+    from bloombee_tpu.models.spec import ModelSpec
+    from bloombee_tpu.runtime.executor import SpanExecutor
+    from bloombee_tpu.utils.tree import stack_params
+
+    spec = ModelSpec(
+        family="llama", hidden_size=32, intermediate_size=64,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=8,
+        num_hidden_layers=2, vocab_size=64, rms_norm_eps=1e-5,
+        rope_theta=10000.0,
+    )
+    rng = jax.random.PRNGKey(0)
+    params = stack_params([
+        init_block_params(k, spec) for k in jax.random.split(rng, 2)
+    ])
+    hidden = np.asarray(
+        jax.random.normal(jax.random.PRNGKey(1), (1, 9, 32)), np.float32
+    )
+
+    def serve():
+        jax.clear_caches()
+        manager = CacheManager(
+            num_layers=2, num_pages=16, page_size=4, n_kv_heads=2,
+            head_dim=8, dtype=jnp.float32,
+        )
+        ex = SpanExecutor(params, spec, manager, compute_dtype=jnp.float32)
+
+        async def run():
+            async with manager.allocate(1, 16) as handle:
+                outs = [ex.prefill(handle, hidden[:, :7])]
+                for i in (7, 8):
+                    outs.append(ex.decode(handle, hidden[:, i:i + 1]))
+            return [np.asarray(o) for o in outs]
+
+        return asyncio.run(run())
+
+    scoped = serve()
+    seen = set()
+    monkeypatch.setattr(
+        jax, "named_scope",
+        lambda name: seen.add(name) or contextlib.nullcontext(),
+    )
+    plain = serve()
+    assert seen >= {"norm", "attn_proj", "arena_write", "arena_gather",
+                    "attention", "mlp"}, seen
+    for a, b in zip(scoped, plain):
+        assert a.tobytes() == b.tobytes()
 
 
 # ------------------------------------------------------------- live e2e run
@@ -345,3 +537,100 @@ def test_e2e_steady_state_decode_has_zero_recompiles(
     # under scripts/chaos.sh the same line feeds the entry's gate (the
     # autouse reset leaves nothing for the atexit flush to double-write)
     jitwatch.flush()
+
+
+@pytest.mark.chaos
+def test_e2e_served_steps_grow_host_spans_by_the_right_counts(
+    tiny_model_dir, monkeypatch
+):
+    """A live server under BBTPU_JITWATCH=1: a chunked prefill of two
+    chunks, then one decode step, read through rpc_info["host_spans"] and
+    rpc_info["worker"] before and after each. Every boundary of the
+    served step shows up the right number of times, and the wire's
+    t_dispatch_ms / t_fetch_ms are the spans' own durations."""
+    import jax.numpy as jnp
+
+    from bloombee_tpu.client.config import ClientConfig
+    from bloombee_tpu.client.model import DistributedModelForCausalLM
+    from bloombee_tpu.server.block_server import BlockServer
+    from bloombee_tpu.swarm.registry import RegistryClient, RegistryServer
+    from bloombee_tpu.wire.rpc import connect
+
+    monkeypatch.setenv("BBTPU_JITWATCH", "1")
+    model_dir, config = tiny_model_dir
+
+    async def run():
+        reg = RegistryServer(host="127.0.0.1")
+        await reg.start()
+
+        def rc():
+            return RegistryClient("127.0.0.1", reg.port)
+
+        server = BlockServer(
+            model_uid="tiny", start=0, end=3, model_dir=model_dir,
+            registry=rc(), compute_dtype=jnp.float32, num_pages=64,
+            page_size=4, prefill_chunk=4,
+        )
+        await server.start()
+        conn = await connect("127.0.0.1", server.port)
+
+        async def info():
+            got, _ = await conn.call("rpc_info", {})
+            return got["host_spans"], got["worker"]
+
+        def grew(before, after, name):
+            return after[name]["n"] - before.get(name, {"n": 0})["n"]
+
+        model = DistributedModelForCausalLM.from_pretrained(
+            model_dir, rc(), model_uid="tiny",
+            config=ClientConfig(use_push=False),
+        )
+        ids = (np.arange(8)[None, :] * 5 + 3) % config.vocab_size
+        async with model.inference_session(16, 1) as sess:
+            spans0, worker0 = await info()
+            out = await sess.step(model.embed(ids), ids=ids)
+            spans1, worker1 = await info()
+            # an 8-token prompt in chunks of 4: two queue tasks, each one
+            # pack, one jitted span step; ONE fetch of both chunks' rows
+            for name in ("bbtpu.enqueue", "bbtpu.task", "bbtpu.dispatch",
+                         "bbtpu.pack", "bbtpu.h2d",
+                         "bbtpu.jit.span_step_packed", "bbtpu.slice"):
+                assert grew(spans0, spans1, name) == 2, (name, spans1)
+            assert grew(spans0, spans1, "bbtpu.commit") == 1  # last chunk
+            assert grew(spans0, spans1, "bbtpu.fetch") == 1
+            assert worker1["tasks"] - worker0["tasks"] == 2
+
+            nxt = np.argmax(
+                model.logits(out[:, -1:])[:, 0], axis=-1
+            ).astype(ids.dtype)[:, None]
+            await sess.step(model.embed(nxt), ids=nxt)
+            spans2, worker2 = await info()
+            for name in ("bbtpu.enqueue", "bbtpu.task", "bbtpu.dispatch",
+                         "bbtpu.pack", "bbtpu.h2d",
+                         "bbtpu.jit.span_step_packed", "bbtpu.slice",
+                         "bbtpu.fetch"):
+                assert grew(spans1, spans2, name) == 1, (name, spans2)
+            assert worker2["tasks"] - worker1["tasks"] == 1
+            # the wire's numbers ARE the spans' durations: one clock pair
+            t = sess.timings[-1]
+            assert t["tokens"] == 1
+            d_dispatch = (spans2["bbtpu.dispatch"]["total_ms"]
+                          - spans1["bbtpu.dispatch"]["total_ms"])
+            d_fetch = (spans2["bbtpu.fetch"]["total_ms"]
+                       - spans1["bbtpu.fetch"]["total_ms"])
+            assert t["span_compute_ms"][0] == pytest.approx(
+                d_dispatch + d_fetch, abs=0.01
+            )
+        # the worker's account covers its wall time
+        total = sum(worker2[k] for k in ("starved_ms", "hop_ms", "busy_ms"))
+        assert total > 0 and worker2["busy_ms"] >= (
+            spans2["bbtpu.task"]["total_ms"] * 0.999
+        )
+        # the wire codec's synchronous bodies are spans too
+        assert spans2["bbtpu.codec.decode"]["n"] >= 1
+        assert spans2["bbtpu.codec.encode"]["n"] >= 1
+        await conn.close()
+        await server.stop()
+        await reg.stop()
+
+    asyncio.run(run())
