@@ -11,6 +11,17 @@ in-place HBM update — the slab-write-vs-cat win of the reference's arch reform
 Layout: per layer, a flat slot dimension of num_pages * page_size tokens:
     k, v: [L, num_pages * page_size, n_kv_heads, head_dim]
 Slot ids come from the host-side PagedKVTable (page * page_size + offset).
+
+Addressing inside a step: no layer ever gets its slab as an array of its own.
+The step views the stored arena as ONE flat slab [L * S_tot, n_kv, hd]
+(`flat_arena`, a bitcast), carries that whole through its layer scan, and
+layer `l` reaches its part by offset — it writes at `layer_slots(slots, l)`
+and reads through `layer_pages(page_table, l)`. `arena_write`, `gather_pages`
+and the Pallas kernels only ever see a slab plus ids into it, so they serve a
+layer slab and the flat arena alike. Why not slice a slab out of the stack
+per layer: the slice and the write-back each copy the whole slab, which moves
+the arena through HBM once a layer to write a handful of rows; the
+benchmark's `scan_slab_move_share` reads such copies.
 """
 
 from __future__ import annotations
@@ -42,17 +53,49 @@ def make_arena(
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def flat_arena(arena):
+    """[L, S_tot, ...] -> [L * S_tot, ...], leaf by leaf (an int4 QuantSlab
+    has three leaves). The two leading dimensions are contiguous, so this is
+    a bitcast, and under `--tp` it merges two unsharded dimensions."""
+    return jax.tree.map(
+        lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), arena
+    )
+
+
+def stacked_arena(flat, num_layers: int):
+    """Inverse of flat_arena: back to the stored [L, S_tot, ...] layout."""
+    return jax.tree.map(
+        lambda a: a.reshape(num_layers, -1, *a.shape[1:]), flat
+    )
+
+
+def layer_slots(slots: jax.Array, layer, s_tot: int, num_layers: int):
+    """Layer `layer`'s slot ids inside the flat arena: `slots + layer * S_tot`.
+    A padding row's id (anything outside [0, S_tot)) maps past the END of the
+    flat arena, so `mode="drop"` still discards it — offset naively, slot
+    S_tot of layer l would be slot 0 of layer l + 1."""
+    valid = (slots >= 0) & (slots < s_tot)
+    return jnp.where(valid, slots + layer * s_tot, num_layers * s_tot)
+
+
+def layer_pages(page_table: jax.Array, layer, num_pages: int):
+    """Layer `layer`'s physical page ids inside the flat arena. Padding
+    entries (page 0) become the layer's own page 0: read, then masked."""
+    return page_table + layer * num_pages
+
+
 def arena_write(
-    k_layer: jax.Array,  # [S_tot, n_kv, hd] one layer's slab
+    k_layer: jax.Array,  # [S, n_kv, hd]: a layer's slab or the flat arena
     v_layer: jax.Array,
     slots: jax.Array,  # [N] int32 flat slot ids
     k_new: jax.Array,  # [N, n_kv, hd]
     v_new: jax.Array,
 ) -> tuple[jax.Array, jax.Array]:
-    """Scatter new KV rows into a layer slab (functional; donate the slab).
+    """Scatter new KV rows into a slab (functional; donate the slab).
 
     Out-of-bounds slot ids are dropped — the span step points padding rows at
-    slot == num_slots to discard their writes.
+    slot == num_slots to discard their writes (`layer_slots` keeps them out
+    of bounds when the slab is the flat arena).
     """
     from bloombee_tpu.kv.quant import QuantSlab, quantize
 
@@ -77,8 +120,8 @@ def arena_write(
 
 
 def gather_pages(
-    layer_slab: jax.Array,  # [S_tot, n_kv, hd]
-    page_table: jax.Array,  # [B, max_pages] int32
+    layer_slab: jax.Array,  # [S, n_kv, hd]: a layer's slab or the flat arena
+    page_table: jax.Array,  # [B, max_pages] int32 page ids INTO that slab
     page_size: int,
 ) -> jax.Array:
     """Gather each sequence's pages: returns [B, max_pages*page_size, n_kv, hd].

@@ -4,7 +4,7 @@ Capability port of /root/reference/src/bloombee/flexgen_utils/compression.py
 :22-210 (`TorchCompressedDevice`: group-wise asymmetric 4-bit quant of
 weights/KV with `general_copy_compressed`), redesigned for the jitted paged
 arena: the quantized slab is a pytree (`QuantSlab`) whose leaves ride the
-span step's `lax.scan` and donation exactly like the dense slab, writes
+span step's `lax.scan` carry and donation exactly like the dense slab, writes
 quantize on-device as part of the step, and page gathers dequantize into the
 attention dtype — so int4 KV needs no separate copy path at all.
 

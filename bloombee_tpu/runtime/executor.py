@@ -114,20 +114,28 @@ def plan_prefill_chunks(
 
 @functools.partial(jax.jit, donate_argnames=("arena_k", "arena_v"))
 def _arena_write_all(arena_k, arena_v, slots, k_new, v_new):
-    """Scatter every layer's new KV rows into the donated arena (the
-    sp-prefill landing step; quantized slabs quantize inside arena_write)."""
-    from jax import lax
-
-    from bloombee_tpu.kv.arena import arena_write
-
-    def body(_, xs):
-        k_l, v_l, kn, vn = xs
-        return None, arena_write(k_l, v_l, slots, kn, vn)
-
-    _, (new_k, new_v) = lax.scan(
-        body, None, (arena_k, arena_v, k_new, v_new)
+    """Scatter every layer's new KV rows [L, N, Hkv, hd] into the donated
+    arena (the sp-prefill landing step; quantized slabs quantize inside
+    arena_write): ONE scatter into the flat arena, layer l's rows at its
+    offset slots — the same addressing as a span step's layer."""
+    from bloombee_tpu.kv.arena import (
+        arena_write,
+        flat_arena,
+        layer_slots,
+        stacked_arena,
     )
-    return new_k, new_v
+
+    num_layers, s_tot = arena_k.shape[:2]
+    all_slots = layer_slots(
+        slots[None, :], jnp.arange(num_layers, dtype=slots.dtype)[:, None],
+        s_tot, num_layers,
+    ).reshape(-1)
+    new_k, new_v = arena_write(
+        flat_arena(arena_k), flat_arena(arena_v), all_slots,
+        k_new.reshape(-1, *k_new.shape[2:]),
+        v_new.reshape(-1, *v_new.shape[2:]),
+    )
+    return stacked_arena(new_k, num_layers), stacked_arena(new_v, num_layers)
 
 
 class SpanExecutor:
@@ -1030,8 +1038,8 @@ class SpanExecutor:
         (jax transfers are async, so layer l+1's H2D copy overlaps layer l's
         compute — the copy-engine overlap of the reference's
         PipelineParallelWrapper pre-forward H2D, convert_block.py:138-263).
-        The arena never leaves the device; each layer_step updates its slab
-        in place via donation."""
+        The arena never leaves the device; each layer_step scatters its rows
+        into the donated arena in place."""
         from bloombee_tpu.runtime.step import layer_step
 
         ak, av = self.manager.arena["k"], self.manager.arena["v"]
@@ -1070,7 +1078,7 @@ class SpanExecutor:
                 spec=self.spec, b=bb, t=tb, page_size=self.page_size,
                 max_pages=pb, use_tree_mask=use_tm,
                 windows=self.windows[:resident], use_flash=use_flash,
-                use_paged=use_paged, resident=resident, attn_topk=attn_topk,
+                use_paged=use_paged, attn_topk=attn_topk,
                 t_real=t_real,
             )
         else:

@@ -21,9 +21,14 @@ Every part of a layer sits in a `jax.named_scope` (HLO metadata only: no op,
 shape or donation changes), so a device trace can say whose an op is:
 `norm`, `attn_proj` (q/k/v/o projections, QK-norm, rotary), `arena_write`,
 `arena_gather`, `attention` (the Pallas call or the XLA attend), `mlp` or
-`moe_router` + `moe_experts` (ops/moe.py). What the span step's `lax.scan`
-and `lax.cond` emit around the layer to slice and restack the arena carries
-none of them, and that absence is the reading.
+`moe_router` + `moe_experts` (ops/moe.py). A move op of a step that carries
+none of them is nothing a layer asked for, and that absence is the reading
+(`scan_slab_move_share`).
+
+A layer never owns its K/V slab as an array: the span step hands it the
+whole arena viewed flat plus slot and page ids already offset to the layer
+(runtime/step.py `_scan_layers`); runtime/hetero.py hands it a per-layer
+slab and plain ids. To the code below both are "a slab and ids into it".
 """
 
 from __future__ import annotations
@@ -184,12 +189,12 @@ def layer_body(
     page_size: int,
     hidden: jax.Array,  # [B, T, D]
     params: dict,  # one layer's params
-    k_slab: jax.Array,  # [S_tot, Hkv, hd]
+    k_slab: jax.Array,  # [S, Hkv, hd]: the flat arena (or a layer's slab)
     v_slab: jax.Array,
     cos: jax.Array,
     sin: jax.Array,
-    slots: jax.Array,
-    page_table: jax.Array,
+    slots: jax.Array,  # ids INTO k_slab / v_slab (out of bounds = dropped)
+    page_table: jax.Array,  # page ids INTO k_slab / v_slab
     q_positions: jax.Array,
     total_lens: jax.Array,
     tree_mask: jax.Array | None,
@@ -394,12 +399,12 @@ def layer_body_ragged(
     page_size: int,
     hidden: jax.Array,  # [1, R, D] — every member's tokens, ragged-packed
     params: dict,  # one layer's params
-    k_slab: jax.Array,  # [S_tot, Hkv, hd]
+    k_slab: jax.Array,  # [S, Hkv, hd]: the flat arena (or a layer's slab)
     v_slab: jax.Array,
     cos: jax.Array,
     sin: jax.Array,
-    slots: jax.Array,  # [R] (padding rows scatter out-of-bounds and drop)
-    page_table: jax.Array,  # [B, NP]
+    slots: jax.Array,  # [R] ids into the slab (padding rows out of bounds)
+    page_table: jax.Array,  # [B, NP] page ids into the slab
     q_positions: jax.Array,  # [1, R]
     total_lens: jax.Array,  # [B]
     q_seq: jax.Array,  # [R] owning sequence per token

@@ -5,9 +5,20 @@ Equivalent of the reference's merged-pool inference step
 `_MergedInferenceStep` runs every local block in one pool call, and
 backend.py:487-789 `inference_step` does select-cache -> mask -> forward ->
 finalize per block). Here the whole span is a single `lax.scan` over stacked
-block params; the paged KV arena rides the scan as per-layer xs/ys so XLA can
-alias the donated buffers, and the attention mask is computed once from
-positions + context lengths.
+block params, and the attention mask is computed once from positions +
+context lengths.
+
+The paged KV arena rides the scan's CARRY, whole, as one flat slab
+[L * S_tot, Hkv, hd] (a bitcast of the stored [L, S_tot, Hkv, hd]); the
+layer index rides as xs, and layer `l` writes at `slots + l * S_tot` and
+reads through `page_table + l * num_pages` (kv/arena.py `layer_slots` /
+`layer_pages`). So a layer scatters its 2-128 rows into the donated buffer
+in place and streams only its context's pages out of it. No step slices a
+layer's slab out of the arena or stacks one back: as xs/ys of the scan every
+layer would copy a whole slab out and in, and the donated buffer could not
+be shared between xs and ys, so every run would copy the whole arena too
+(measured: half of the device's busy time in the densest cell, PERF.md
+section 6, PR 28). The benchmark's `scan_slab_move_share` reads such copies.
 
 Shape discipline (SURVEY.md section 7 hard part #1): everything is padded to
 static buckets — batch, step tokens T, and cache pages — and validity is
@@ -23,6 +34,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from bloombee_tpu.kv.arena import (
+    flat_arena,
+    layer_pages,
+    layer_slots,
+    stacked_arena,
+)
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.ops.rotary import rotary_cos_sin
 from bloombee_tpu.runtime.layer_body import layer_body, layer_body_ragged
@@ -117,46 +134,105 @@ def span_step_packed_impl(
     windows: tuple | None = None,
     use_flash: bool = False,
     use_paged: bool = False,
-    resident: int | None = None,
     attn_topk: int = 0,
     t_real: int | None = None,
 ):
-    """span_step over a pack_step_payload buffer (one h2d per step).
-
-    `resident` (weight-offload mode): the params stack covers only the
-    first `resident` of the arena's layers — scan over that prefix, write
-    the updated slabs back into the full donated arena, and leave the
-    offloaded layers' slabs untouched (they get their own layer_step calls
-    with host-streamed weights)."""
+    """span_step over a pack_step_payload buffer (one h2d per step)."""
     hidden, plan = unpack_step_payload(payload, b, t, spec.hidden_size)
-    if resident is None:
-        return span_step_impl(
-            stacked_params, arena_k, arena_v, hidden, plan, tree_mask,
-            lora=lora,
-            spec=spec, page_size=page_size, max_pages=max_pages,
-            use_tree_mask=use_tree_mask, windows=windows, use_flash=use_flash,
-            use_paged=use_paged, attn_topk=attn_topk, t_real=t_real,
-        )
-    hidden, ak, av = span_step_impl(
-        stacked_params, arena_k[:resident], arena_v[:resident], hidden, plan,
-        tree_mask, lora=lora,
+    return span_step_impl(
+        stacked_params, arena_k, arena_v, hidden, plan, tree_mask,
+        lora=lora,
         spec=spec, page_size=page_size, max_pages=max_pages,
         use_tree_mask=use_tree_mask, windows=windows, use_flash=use_flash,
         use_paged=use_paged, attn_topk=attn_topk, t_real=t_real,
     )
-    arena_k = jax.lax.dynamic_update_slice_in_dim(arena_k, ak, 0, 0)
-    arena_v = jax.lax.dynamic_update_slice_in_dim(arena_v, av, 0, 0)
-    return hidden, arena_k, arena_v
 
 
 span_step_packed = functools.partial(
     jax.jit,
     static_argnames=(
         "spec", "b", "t", "page_size", "max_pages", "use_tree_mask",
-        "windows", "use_flash", "use_paged", "resident", "attn_topk",
+        "windows", "use_flash", "use_paged", "attn_topk",
     ),
     donate_argnames=("arena_k", "arena_v"),
 )(span_step_packed_impl)
+
+
+def _rope_by_window(spec: ModelSpec, q_positions: jax.Array, dtype):
+    """Rotary tables from the plan's positions (fp32 like HF, cast to the
+    compute dtype), as `pick(window_l) -> (cos, sin)` for one layer.
+    gemma3-style models rope their sliding layers with the local base
+    frequency; the per-layer window (riding the scan) selects the pair."""
+
+    def tables(theta):
+        return tuple(
+            x.astype(dtype)
+            for x in rotary_cos_sin(q_positions, spec.head_dim, theta)
+        )
+
+    cos, sin = tables(spec.rope_theta)
+    if spec.rope_local_theta and spec.rope_local_theta != spec.rope_theta:
+        cos_loc, sin_loc = tables(spec.rope_local_theta)
+    else:
+        cos_loc, sin_loc = cos, sin
+
+    def pick(window_l):
+        use_local = window_l > 0
+        return (
+            jnp.where(use_local, cos_loc, cos),
+            jnp.where(use_local, sin_loc, sin),
+        )
+
+    return pick
+
+
+def _scan_layers(
+    run_layer,  # (h, k_flat, v_flat, slots_l, pages_l, xs_l) -> (h, k, v)
+    hidden: jax.Array,
+    arena_k,  # [L, S_tot, Hkv, hd] or its int4 QuantSlab
+    arena_v,
+    slots: jax.Array,
+    page_table: jax.Array,
+    layer_active: jax.Array,  # [n]: the first n of the arena's L layers run
+    xs,  # per-layer inputs, leading dim n on every leaf
+    page_size: int,
+):
+    """The span's layer scan, the arena WHOLE in the carry.
+
+    The arena is viewed flat and never sliced: layer l (its index is the
+    scan's xs) gets the flat arena plus its own offset slot and page ids.
+    An inactive layer (`layer_active[l] == 0`: a session entering mid-span)
+    takes the `skip` branch, which hands the carry through untouched — no
+    compute, the arena bit-identical. `n < L` is the weight-offload prefix:
+    the scan covers the resident layers and cannot reach the others' rows.
+    """
+    num_layers, s_tot = arena_k.shape[:2]
+    num_pages = s_tot // page_size
+    n = layer_active.shape[0]
+
+    def body(carry, xs_l):
+        layer, active, rest = xs_l
+        slots_l = layer_slots(slots, layer, s_tot, num_layers)
+        pages_l = layer_pages(page_table, layer, num_pages)
+
+        def run(h, k_flat, v_flat):
+            return run_layer(h, k_flat, v_flat, slots_l, pages_l, rest)
+
+        def skip(h, k_flat, v_flat):
+            return h, k_flat, v_flat
+
+        return lax.cond(active > 0, run, skip, *carry), None
+
+    (hidden, k_flat, v_flat), _ = lax.scan(
+        body,
+        (hidden, flat_arena(arena_k), flat_arena(arena_v)),
+        (jnp.arange(n, dtype=jnp.int32), layer_active, xs),
+    )
+    return (
+        hidden,
+        stacked_arena(k_flat, num_layers),
+        stacked_arena(v_flat, num_layers),
+    )
 
 
 def span_step_impl(
@@ -188,63 +264,35 @@ def span_step_impl(
     rows are ignored.
     """
     b, t, _ = hidden.shape
-    num_layers = arena_k.shape[0]
+    # the params stack says how many layers run: all of the arena's, or the
+    # resident prefix in weight-offload mode (the offloaded layers get their
+    # own layer_step calls with host-streamed weights)
+    n = jax.tree.leaves(stacked_params)[0].shape[0]
     slots, page_table, q_positions, total_lens, layer_active = unpack_plan(
-        plan, b, t, max_pages, num_layers
+        plan, b, t, max_pages, n
     )
-    cos, sin = rotary_cos_sin(q_positions, spec.head_dim, spec.rope_theta)
-    cos = cos.astype(hidden.dtype)
-    sin = sin.astype(hidden.dtype)
-    if spec.rope_local_theta and spec.rope_local_theta != spec.rope_theta:
-        # gemma3-style: sliding layers rope with the local base frequency;
-        # the per-layer window (already riding the scan) selects the pair
-        cos_loc, sin_loc = rotary_cos_sin(
-            q_positions, spec.head_dim, spec.rope_local_theta
-        )
-        cos_loc = cos_loc.astype(hidden.dtype)
-        sin_loc = sin_loc.astype(hidden.dtype)
-    else:
-        cos_loc, sin_loc = cos, sin
-
+    rope = _rope_by_window(spec, q_positions, hidden.dtype)
     tm = tree_mask if use_tree_mask else None
     windows_arr = jnp.asarray(
-        windows if windows is not None else (0,) * num_layers, jnp.int32
+        windows if windows is not None else (0,) * n, jnp.int32
     )
 
-    xs = (stacked_params, arena_k, arena_v, layer_active, windows_arr)
-    if prompts is not None:
-        xs = xs + (prompts,)
-    if lora is not None:
-        xs = xs + (lora,)
+    def run_layer(h, k_flat, v_flat, slots_l, pages_l, xs_l):
+        params_l, window_l, prompt_l, lora_l = xs_l
+        if prompt_l is not None:
+            p = prompt_l.shape[0]
+            h = h.at[:, :p].add(prompt_l[None].astype(h.dtype))
+        return layer_body(
+            spec, page_size, h, params_l, k_flat, v_flat, *rope(window_l),
+            slots_l, pages_l, q_positions, total_lens, tm, window_l,
+            use_flash=use_flash, use_paged=use_paged, lora=lora_l,
+            attn_topk=attn_topk, t_real=t_real,
+        )
 
-    def body(h, xs):
-        params_l, k_l, v_l, active, window_l = xs[:5]
-        rest = list(xs[5:])
-        prompt_l = rest.pop(0) if prompts is not None else None
-        lora_l = rest.pop(0) if lora is not None else None
-        use_local = window_l > 0
-        cos_l = jnp.where(use_local, cos_loc, cos)
-        sin_l = jnp.where(use_local, sin_loc, sin)
-
-        def run(h, k_l, v_l):
-            if prompt_l is not None:
-                p = prompt_l.shape[0]
-                h = h.at[:, :p].add(prompt_l[None].astype(h.dtype))
-            return layer_body(
-                spec, page_size, h, params_l, k_l, v_l, cos_l, sin_l, slots,
-                page_table, q_positions, total_lens, tm, window_l,
-                use_flash=use_flash, use_paged=use_paged, lora=lora_l,
-                attn_topk=attn_topk, t_real=t_real,
-            )
-
-        def skip(h, k_l, v_l):
-            return h, k_l, v_l
-
-        h, k_l, v_l = lax.cond(active > 0, run, skip, h, k_l, v_l)
-        return h, (k_l, v_l)
-
-    hidden, (arena_k, arena_v) = lax.scan(body, hidden, xs)
-    return hidden, arena_k, arena_v
+    return _scan_layers(
+        run_layer, hidden, arena_k, arena_v, slots, page_table, layer_active,
+        (stacked_params, windows_arr, prompts, lora), page_size,
+    )
 
 
 span_step = functools.partial(
@@ -347,48 +395,24 @@ def span_step_ragged_impl(
         slots, page_table, q_positions, total_lens, q_seq, layer_active,
         nt, tree_rows,
     ) = unpack_ragged_plan(plan, r, n_seqs, max_pages, num_layers, t_max)
-    cos, sin = rotary_cos_sin(q_positions, spec.head_dim, spec.rope_theta)
-    cos = cos.astype(hidden.dtype)
-    sin = sin.astype(hidden.dtype)
-    if spec.rope_local_theta and spec.rope_local_theta != spec.rope_theta:
-        cos_loc, sin_loc = rotary_cos_sin(
-            q_positions, spec.head_dim, spec.rope_local_theta
-        )
-        cos_loc = cos_loc.astype(hidden.dtype)
-        sin_loc = sin_loc.astype(hidden.dtype)
-    else:
-        cos_loc, sin_loc = cos, sin
-
+    rope = _rope_by_window(spec, q_positions, hidden.dtype)
     windows_arr = jnp.asarray(
         windows if windows is not None else (0,) * num_layers, jnp.int32
     )
-    xs = (stacked_params, arena_k, arena_v, layer_active, windows_arr)
-    if lora is not None:
-        xs = xs + (lora,)
 
-    def body(h, xs):
-        params_l, k_l, v_l, active, window_l = xs[:5]
-        lora_l = xs[5] if lora is not None else None
-        use_local = window_l > 0
-        cos_l = jnp.where(use_local, cos_loc, cos)
-        sin_l = jnp.where(use_local, sin_loc, sin)
+    def run_layer(h, k_flat, v_flat, slots_l, pages_l, xs_l):
+        params_l, window_l, lora_l = xs_l
+        return layer_body_ragged(
+            spec, page_size, h, params_l, k_flat, v_flat, *rope(window_l),
+            slots_l, pages_l, q_positions, total_lens, q_seq,
+            window_l, use_kernel=use_kernel, lora=lora_l,
+            nt=nt, tree_rows=tree_rows,
+        )
 
-        def run(h, k_l, v_l):
-            return layer_body_ragged(
-                spec, page_size, h, params_l, k_l, v_l, cos_l, sin_l,
-                slots, page_table, q_positions, total_lens, q_seq,
-                window_l, use_kernel=use_kernel, lora=lora_l,
-                nt=nt, tree_rows=tree_rows,
-            )
-
-        def skip(h, k_l, v_l):
-            return h, k_l, v_l
-
-        h, k_l, v_l = lax.cond(active > 0, run, skip, h, k_l, v_l)
-        return h, (k_l, v_l)
-
-    hidden, (arena_k, arena_v) = lax.scan(body, hidden, xs)
-    return hidden, arena_k, arena_v
+    return _scan_layers(
+        run_layer, hidden, arena_k, arena_v, slots, page_table, layer_active,
+        (stacked_params, windows_arr, lora), page_size,
+    )
 
 
 span_step_ragged = functools.partial(
@@ -407,7 +431,7 @@ def layer_step_impl(
     arena_v: jax.Array,
     hidden: jax.Array,  # [B, T, D]
     plan: jax.Array,  # packed with ONE layer_active entry
-    layer_idx: jax.Array,  # traced i32 scalar: which arena slab to touch
+    layer_idx: jax.Array,  # traced i32 scalar: whose rows of the arena
     tree_mask: jax.Array | None = None,
     lora_l: dict | None = None,
     *,
@@ -425,9 +449,10 @@ def layer_step_impl(
     weight-offload path (reference FlexGen Policy weight percentages /
     convert_block.py PipelineParallelWrapper pre-forward H2D): offloaded
     layers' params arrive from host per step, so they can't ride the
-    resident stack's scan. The layer's K/V slab is read out of and written
-    back into the DONATED arena in place (dynamic_update_index aliases the
-    buffer), so the persistent KV state never leaves the device."""
+    resident stack's scan. The layer addresses the DONATED arena exactly as
+    a scanned layer does — the flat view plus `layer_idx` as the offset of
+    its slot and page ids — so its rows are scattered in place and the
+    persistent KV state never leaves the device or moves on it."""
     b, t, _ = hidden.shape
     slots, page_table, q_positions, total_lens, _ = unpack_plan(
         plan, b, t, max_pages, 1
@@ -441,19 +466,23 @@ def layer_step_impl(
     cos, sin = rotary_cos_sin(q_positions, spec.head_dim, theta)
     cos = cos.astype(hidden.dtype)
     sin = sin.astype(hidden.dtype)
-    k_l = jax.lax.dynamic_index_in_dim(arena_k, layer_idx, 0, keepdims=False)
-    v_l = jax.lax.dynamic_index_in_dim(arena_v, layer_idx, 0, keepdims=False)
-    hidden, k_l, v_l = layer_body(
-        spec, page_size, hidden, params_l, k_l, v_l, cos, sin, slots,
-        page_table, q_positions, total_lens,
+    num_layers, s_tot = arena_k.shape[:2]
+    hidden, k_flat, v_flat = layer_body(
+        spec, page_size, hidden, params_l,
+        flat_arena(arena_k), flat_arena(arena_v), cos, sin,
+        layer_slots(slots, layer_idx, s_tot, num_layers),
+        layer_pages(page_table, layer_idx, s_tot // page_size),
+        q_positions, total_lens,
         tree_mask if use_tree_mask else None,
         jnp.int32(window),
         use_flash=use_flash, use_paged=use_paged, lora=lora_l,
         attn_topk=attn_topk, t_real=t_real,
     )
-    arena_k = jax.lax.dynamic_update_index_in_dim(arena_k, k_l, layer_idx, 0)
-    arena_v = jax.lax.dynamic_update_index_in_dim(arena_v, v_l, layer_idx, 0)
-    return hidden, arena_k, arena_v
+    return (
+        hidden,
+        stacked_arena(k_flat, num_layers),
+        stacked_arena(v_flat, num_layers),
+    )
 
 
 layer_step = functools.partial(

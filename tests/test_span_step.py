@@ -338,3 +338,299 @@ def test_attn_sparsity_executor_smoke():
     sparse = asyncio.run(run(0.25))
     assert np.isfinite(sparse).all()
     assert np.abs(sparse - dense).max() > 1e-6  # actually approximated
+
+
+# --------------------------------------------------------------------------
+# The arena's addressing scheme (runtime/step.py `_scan_layers`, kv/arena.py
+# `layer_slots` / `layer_pages`): the arena rides the layer scan's carry
+# whole, as one flat slab, and a layer reaches its rows by offset. These run
+# on the CPU and pin the scheme and its semantics, not a speed.
+
+_L, _PAGE, _NUM_PAGES, _HKV, _HD, _D = 3, 4, 32, 2, 16, 64
+_S_TOT = _PAGE * _NUM_PAGES  # 128: no other dimension of these steps is
+_MAX_PAGES = 4
+
+
+def _arena_spec():
+    from bloombee_tpu.models.spec import ModelSpec
+
+    return ModelSpec(
+        family="llama", hidden_size=_D, intermediate_size=96,
+        num_attention_heads=4, num_key_value_heads=_HKV, head_dim=_HD,
+        num_hidden_layers=_L, vocab_size=64,
+    )
+
+
+def _arena_params(n_layers=_L):
+    import jax
+
+    from bloombee_tpu.models.llama.block import init_block_params
+
+    return stack_params([
+        init_block_params(jax.random.PRNGKey(i), _arena_spec(),
+                          dtype=jnp.float32)
+        for i in range(n_layers)
+    ])
+
+
+def _random_arena(quant=None):
+    """An arena with something in EVERY row, so a stray write shows."""
+    import jax
+
+    from bloombee_tpu.kv.arena import make_arena
+
+    arena = make_arena(_L, _NUM_PAGES, _PAGE, _HKV, _HD, jnp.float32, quant)
+    leaves, tree = jax.tree.flatten(arena)
+    rng = np.random.default_rng(7)
+    leaves = [
+        jnp.asarray(rng.integers(0, 255, a.shape), a.dtype)
+        if a.dtype == jnp.uint8
+        else jnp.asarray(rng.standard_normal(a.shape), a.dtype)
+        for a in leaves
+    ]
+    return jax.tree.unflatten(tree, leaves)
+
+
+def _step_case(kind, layer_active=None, n_layers=_L):
+    """(fn, args, real_slots, layers_written) of one tiny step of `kind`:
+    `fn(*args)` -> (hidden, arena_k, arena_v); rows beyond the real ones
+    are bucket padding pointed at slot == S_tot."""
+    import functools
+
+    from bloombee_tpu.runtime import step as S
+
+    spec = _arena_spec()
+    rng = np.random.default_rng(11)
+    oob = _S_TOT
+    arena = _random_arena("int4" if kind == "int4" else None)
+    active = (
+        np.ones(n_layers, np.int32) if layer_active is None
+        else np.asarray(layer_active, np.int32)
+    )
+    written = [l for l in range(n_layers) if active[l]]
+    if kind == "ragged":
+        # one decode token of sequence 0 + a 4-token chunk of sequence 1
+        # + 3 padding rows (q_seq == n_seqs)
+        r, n_seqs = 8, 2
+        slots = np.array([2 * _PAGE + 1, 20, 21, 22, 23, oob, oob, oob])
+        plan = S.pack_ragged_plan(
+            slots, np.array([[1, 2, 0, 0], [5, 0, 0, 0]]),
+            np.array([5, 0, 1, 2, 3, 0, 0, 0]), np.array([6, 4]),
+            np.array([0, 1, 1, 1, 1, 2, 2, 2]), active,
+        )
+        h = rng.standard_normal((1, r, _D)).astype(np.float32)
+        fn = functools.partial(
+            S.span_step_ragged_impl, spec=spec, r=r, n_seqs=n_seqs,
+            page_size=_PAGE, max_pages=_MAX_PAGES,
+        )
+        args = (_arena_params(n_layers), arena["k"], arena["v"],
+                jnp.asarray(S.pack_step_payload(h, plan)))
+        return fn, args, slots[slots < oob], written
+    if kind == "chunk":
+        # one 8-row chunk bucket holding 5 real tokens of a fresh sequence
+        b, t = 1, 8
+        slots = np.array([12, 13, 14, 15, 16, oob, oob, oob])
+        pages = np.array([[3, 4, 0, 0]])
+        positions, lens = np.arange(8)[None], np.array([5])
+    else:
+        # two decode rows: sequence 0 is real (6 tokens in pages 1, 2: the
+        # new token lands at page 2, offset 1), sequence 1 is padding
+        b, t = 2, 1
+        slots = np.array([2 * _PAGE + 1, oob])
+        pages = np.array([[1, 2, 0, 0], [0, 0, 0, 0]])
+        positions, lens = np.array([[5], [0]]), np.array([6, 0])
+    h = rng.standard_normal((b, t, _D)).astype(np.float32)
+    if kind == "layer_step":
+        plan = S.pack_plan(slots, pages, positions, lens, np.ones(1))
+        params_1 = {k: v[0] for k, v in _arena_params(1).items()}
+        fn = functools.partial(
+            S.layer_step_impl, spec=spec, page_size=_PAGE,
+            max_pages=_MAX_PAGES,
+        )
+        args = (params_1, arena["k"], arena["v"], jnp.asarray(h),
+                jnp.asarray(plan), jnp.int32(1))
+        return fn, args, slots[slots < oob], [1]
+    plan = S.pack_plan(slots, pages, positions, lens, active)
+    fn = functools.partial(
+        S.span_step_packed_impl, spec=spec, b=b, t=t, page_size=_PAGE,
+        max_pages=_MAX_PAGES,
+    )
+    args = (_arena_params(n_layers), arena["k"], arena["v"],
+            jnp.asarray(S.pack_step_payload(h, plan)))
+    return fn, args, slots[slots < oob], written
+
+
+_STEP_KINDS = ["decode", "chunk", "ragged", "layer_step", "int4"]
+
+
+def _has_slot_axis(aval) -> bool:
+    """An array that spans a whole layer's slots (a slab, the stacked or the
+    flat arena, any int4 leaf of them)."""
+    return any(d in (_S_TOT, _L * _S_TOT) for d in aval.shape)
+
+
+def _subjaxprs(eqn):
+    from jax.extend import core as jex_core
+
+    for v in eqn.params.values():
+        for x in v if isinstance(v, (tuple, list)) else (v,):
+            if isinstance(x, jex_core.ClosedJaxpr):
+                yield x.jaxpr
+            elif isinstance(x, jex_core.Jaxpr):
+                yield x
+
+
+def _walk_eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in _subjaxprs(eqn):
+            yield from _walk_eqns(sub)
+
+
+@pytest.mark.parametrize("kind", _STEP_KINDS)
+def test_no_step_moves_a_slab(kind):
+    """No scan of a step takes or emits a slab as xs / ys (the arena is in
+    the CARRY), and no dynamic_slice / dynamic_update_slice yields an array
+    with a whole slot axis: a layer's rows are reached by offset ids."""
+    import jax
+
+    fn, args, _, _ = _step_case(kind)
+    closed = jax.make_jaxpr(fn)(*args)
+    scans = 0
+    for eqn in _walk_eqns(closed.jaxpr):
+        name = eqn.primitive.name
+        if name == "scan":
+            scans += 1
+            first_x = eqn.params["num_consts"] + eqn.params["num_carry"]
+            xs = [v.aval for v in eqn.invars[first_x:]]
+            ys = [v.aval for v in eqn.outvars[eqn.params["num_carry"]:]]
+            moved = [a.shape for a in xs + ys if _has_slot_axis(a)]
+            assert not moved, f"scan moves slabs as xs/ys: {moved}"
+            carry = [v.aval for v in
+                     eqn.invars[eqn.params["num_consts"]:first_x]]
+            assert any(a.shape[0] == _L * _S_TOT for a in carry), (
+                "the flat arena is not in the scan's carry"
+            )
+        elif name in ("dynamic_slice", "dynamic_update_slice"):
+            out = [v.aval.shape for v in eqn.outvars
+                   if _has_slot_axis(v.aval)]
+            assert not out, f"{name} yields a slab or more: {out}"
+    assert scans == (0 if kind == "layer_step" else 1)
+
+
+def _rows_changed(before, after):
+    """[L, S_tot] bool: which rows of an arena side differ, over all of its
+    leaves (bitwise: NaN-safe, int4 leaf by leaf)."""
+    import jax
+
+    changed = np.zeros((_L, _S_TOT), bool)
+    for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+        a, b = (np.asarray(x).reshape(_L, _S_TOT, -1) for x in (a, b))
+        changed |= (a.view(np.uint8) != b.view(np.uint8)).any(-1)
+    return changed
+
+
+@pytest.mark.parametrize("kind", _STEP_KINDS)
+def test_step_writes_its_rows_and_no_others(kind):
+    """Padding rows (slot >= S_tot) are dropped at EVERY layer: offset
+    naively, layer l's slot S_tot is layer l + 1's slot 0. After a step the
+    arena differs from before in exactly (layer, real slot) and nowhere
+    else, K and V alike."""
+    import jax
+
+    fn, args, real_slots, layers = _step_case(kind)
+    _, new_k, new_v = jax.jit(fn)(*args)
+    want = np.zeros((_L, _S_TOT), bool)
+    want[np.ix_(layers, real_slots)] = True
+    assert not want[:, 0].any()  # slot 0 is nobody's: the naive offset's victim
+    for side, (old, new) in {"k": (args[1], new_k),
+                             "v": (args[2], new_v)}.items():
+        got = _rows_changed(old, new)
+        np.testing.assert_array_equal(got, want, err_msg=side)
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk", "ragged"])
+def test_layer_active_suffix_skips_leading_layers(kind):
+    """layer_active = [0, 1, 1] (a session entering mid-span): layer 0's part
+    of the arena stays bit-identical, and hidden + the other layers' rows
+    equal a step of the two active layers alone over their own arena."""
+    import jax
+
+    fn, args, real_slots, layers = _step_case(kind, layer_active=[0, 1, 1])
+    h, new_k, new_v = jax.jit(fn)(*args)
+    assert layers == [1, 2]
+    assert not _rows_changed(args[1], new_k)[0].any()
+    assert not _rows_changed(args[2], new_v)[0].any()
+
+    fn2, args2, _, _ = _step_case(kind, n_layers=2)
+    tail = lambda tree: jax.tree.map(lambda x: x[1:], tree)  # noqa: E731
+    h2, k2, v2 = jax.jit(fn2)(
+        tail(args[0]), args[1][1:], args[2][1:], *args2[3:]
+    )
+    np.testing.assert_array_equal(np.asarray(h), np.asarray(h2))
+    np.testing.assert_array_equal(np.asarray(new_k[1:]), np.asarray(k2))
+    np.testing.assert_array_equal(np.asarray(new_v[1:]), np.asarray(v2))
+
+
+def test_resident_prefix_leaves_offloaded_layers_alone():
+    """Weight-offload mode: a params stack of the first 2 of the arena's 3
+    layers scans that prefix over the FULL arena; layer 2's rows are
+    untouched and the rest equals a step over a 2-layer arena."""
+    import jax
+
+    fn, args, real_slots, _ = _step_case("decode", n_layers=2)
+    h, new_k, new_v = jax.jit(fn)(*args)
+    changed = _rows_changed(args[1], new_k) | _rows_changed(args[2], new_v)
+    assert not changed[2].any()
+    assert changed[:2, real_slots].all()
+    h2, k2, v2 = jax.jit(fn)(args[0], args[1][:2], args[2][:2], args[3])
+    np.testing.assert_array_equal(np.asarray(h), np.asarray(h2))
+    np.testing.assert_array_equal(np.asarray(new_k[:2]), np.asarray(k2))
+    np.testing.assert_array_equal(np.asarray(new_v[:2]), np.asarray(v2))
+
+
+@pytest.mark.parametrize("quant", [None, "int4"])
+def test_arena_write_all_is_one_offset_scatter(quant):
+    """The sp-prefill landing step writes every layer's rows with ONE
+    scatter into the flat arena (no scan over slabs), equal to a per-layer
+    arena_write; its padding rows drop at every layer too."""
+    import jax
+
+    from bloombee_tpu.kv.arena import arena_write
+    from bloombee_tpu.runtime.executor import _arena_write_all
+
+    arena = _random_arena(quant)
+    rng = np.random.default_rng(3)
+    slots = jnp.asarray([9, 40, _S_TOT, 41, _S_TOT], jnp.int32)
+    k_new, v_new = (
+        jnp.asarray(rng.standard_normal((_L, 5, _HKV, _HD)), jnp.float32)
+        for _ in range(2)
+    )
+    names = {
+        e.primitive.name for e in _walk_eqns(
+            jax.make_jaxpr(_arena_write_all.__wrapped__)(
+                arena["k"], arena["v"], slots, k_new, v_new
+            ).jaxpr
+        )
+    }
+    assert "scan" not in names and "dynamic_update_slice" not in names
+    layer = lambda tree, l: jax.tree.map(lambda x: x[l], tree)  # noqa: E731
+    want = [
+        arena_write(layer(arena["k"], l), layer(arena["v"], l), slots,
+                    k_new[l], v_new[l])
+        for l in range(_L)
+    ]
+    old_k, old_v = jax.tree.map(np.asarray, (arena["k"], arena["v"]))
+    got_k, got_v = _arena_write_all(
+        arena["k"], arena["v"], slots, k_new, v_new
+    )
+    for l in range(_L):
+        for got, exp in zip(
+            jax.tree.leaves((layer(got_k, l), layer(got_v, l))),
+            jax.tree.leaves(want[l]),
+        ):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(exp))
+    changed = _rows_changed(old_k, got_k) | _rows_changed(old_v, got_v)
+    want_rows = np.zeros((_L, _S_TOT), bool)
+    want_rows[:, [9, 40, 41]] = True
+    np.testing.assert_array_equal(changed, want_rows)
