@@ -1,11 +1,14 @@
 """Seeded checkpoint writer (copied from chip_smoke.py, which stays as it is).
 
-Writes an HF-layout directory (config.json + safetensors + index) under the
-names bloombee_tpu/models/checkpoint.py reads, straight from the generator's
-bits: sign and mantissa random, magnitude over four octaves 2**-9..2**-6
-(std ~0.0137, HF's 0.02 init in spirit); norm weights are ones. Every value
-is exactly a bfloat16, so a bf16 server holds the reference's weights
-unrounded. JAX is never imported here (the parent calls this).
+Writes an HF-layout directory (config.json + safetensors + index), one file a
+layer and one for the client, from the PLAN of the configuration's family
+(cellbench/families/<model_type>.py: each tensor's name, torch-layout shape
+and fill; no tensor's name is known here). The default fill, "bits", comes
+straight from the generator's bits: sign and mantissa random, magnitude over
+four octaves 2**-9..2**-6 (std ~0.0137, HF's 0.02 init in spirit); "ones" is
+1.0; a range is drawn as `fill_values` says. Every value is exactly a
+bfloat16, so a bf16 server holds the reference's weights unrounded. JAX is
+never imported here (the parent calls this).
 """
 
 from __future__ import annotations
@@ -18,61 +21,70 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
+from cellbench import families
+
 CLIENT_SHARD = "client"
+BITS, ONES = "bits", "ones"
+_THEN = {
+    None: lambda x: x,
+    "log": np.log,
+    # the x with log(1 + e**x) == y
+    "softplus_inverse": lambda y: y + np.log(-np.expm1(-y)),
+}
 
 
-def tensor_plan(config: dict) -> list[tuple[str, list[tuple[str, tuple]]]]:
-    """(file tag, [(tensor name, torch-layout shape)]) per file: one per
-    layer, one for the client's trio (embed, final norm, head)."""
-    d = config["hidden_size"]
-    heads, kv_heads = config["num_attention_heads"], config["num_key_value_heads"]
-    hd = config.get("head_dim") or d // heads
-    moe = config["model_type"] == "qwen3_moe"
-    files = []
-    for layer in range(config["num_hidden_layers"]):
-        p = f"model.layers.{layer}"
-        tensors = [
-            (f"{p}.input_layernorm.weight", (d,)),
-            (f"{p}.post_attention_layernorm.weight", (d,)),
-            (f"{p}.self_attn.q_proj.weight", (heads * hd, d)),
-            (f"{p}.self_attn.k_proj.weight", (kv_heads * hd, d)),
-            (f"{p}.self_attn.v_proj.weight", (kv_heads * hd, d)),
-            (f"{p}.self_attn.o_proj.weight", (d, heads * hd)),
-        ]
-        if moe:
-            i = config["moe_intermediate_size"]
-            tensors += [
-                (f"{p}.self_attn.q_norm.weight", (hd,)),
-                (f"{p}.self_attn.k_norm.weight", (hd,)),
-                (f"{p}.mlp.gate.weight", (config["num_experts"], d)),
-            ]
-            for e in range(config["num_experts"]):
-                q = f"{p}.mlp.experts.{e}"
-                tensors += [
-                    (f"{q}.gate_proj.weight", (i, d)),
-                    (f"{q}.up_proj.weight", (i, d)),
-                    (f"{q}.down_proj.weight", (d, i)),
-                ]
-        else:
-            i = config["intermediate_size"]
-            tensors += [
-                (f"{p}.mlp.gate_proj.weight", (i, d)),
-                (f"{p}.mlp.up_proj.weight", (i, d)),
-                (f"{p}.mlp.down_proj.weight", (d, i)),
-            ]
-        files.append((f"layer{layer:03d}", tensors))
-    v = config["vocab_size"]
-    files.append((CLIENT_SHARD, [
-        ("model.embed_tokens.weight", (v, d)),
-        ("model.norm.weight", (d,)),
-        ("lm_head.weight", (v, d)),
-    ]))
-    return files
+def layer_tag(layer: int) -> str:
+    return f"layer{layer:03d}"
+
+
+def tensor_plan(config: dict) -> list[tuple[str, list[tuple[str, tuple, object]]]]:
+    """(file tag, [(tensor name, torch-layout shape, fill)]) per file: one
+    per layer, one for the client, as the configuration's family gives them
+    (a tensor without a fill gets BITS)."""
+    family = families.of(config)
+    files = [(layer_tag(layer), family.layer_tensors(config, layer))
+             for layer in range(config["num_hidden_layers"])]
+    files.append((CLIENT_SHARD, family.client_tensors(config)))
+    return [(tag, [(t[0], tuple(t[1]), t[2] if len(t) > 2 else BITS)
+                   for t in tensors]) for tag, tensors in files]
+
+
+def fill_values(fill: dict, n: int, gen) -> np.ndarray:
+    """`n` bfloat16 bit patterns of a range fill {"low", "high", "spacing":
+    "uniform" | "log", "then": None | "log" | "softplus_inverse"}: drawn
+    uniformly (or log-uniformly) in [low, high) from the file's generator,
+    put through `then`, rounded to the nearest bfloat16. So a decay's
+    `A_log` is {"low": 1, "high": 16, "spacing": "uniform", "then": "log"}
+    and a step's bias {"low": 1e-3, "high": 1e-1, "spacing": "log", "then":
+    "softplus_inverse"}."""
+    u = (gen.random_raw(n) >> np.uint64(11)) * 2.0 ** -53
+    low, high = float(fill["low"]), float(fill["high"])
+    if fill.get("spacing", "uniform") == "log":
+        x = np.exp(np.log(low) + u * (np.log(high) - np.log(low)))
+    else:
+        x = low + u * (high - low)
+    bits = _THEN[fill.get("then")](x).astype(np.float32).view(np.uint32)
+    bits = bits + (0x7FFF + ((bits >> 16) & 1))  # round to nearest even
+    return (bits >> 16).astype(np.uint16)
+
+
+def _slice(fill, m: int, gen) -> np.ndarray:
+    """The next `m` bfloat16 bit patterns of a tensor with this fill."""
+    if fill == ONES:
+        return np.full(m, 0x3F80, np.uint16)  # 1.0
+    if fill != BITS:
+        return fill_values(fill, m, gen)
+    bits = gen.random_raw(-(-m // 4)).view(np.uint16)[:m]
+    exp = ((bits >> 7) & 3) + 118  # 2**-9 .. 2**-6
+    exp <<= 7
+    bits &= 0x807F
+    bits |= exp
+    return bits
 
 
 def _write_file(path: pathlib.Path, tensors, seed_seq) -> int:
     header, offset = {}, 0
-    for name, shape in tensors:
+    for name, shape, _fill in tensors:
         nbytes = 2 * int(np.prod(shape))
         header[name] = {"dtype": "BF16", "shape": list(shape),
                         "data_offsets": [offset, offset + nbytes]}
@@ -85,19 +97,10 @@ def _write_file(path: pathlib.Path, tensors, seed_seq) -> int:
     with open(tmp, "wb") as f:
         f.write(len(head).to_bytes(8, "little"))
         f.write(head)
-        for name, shape in tensors:
+        for _name, shape, fill in tensors:
             n = int(np.prod(shape))
-            if name.endswith("norm.weight"):
-                f.write(np.full(n, 0x3F80, np.uint16).tobytes())  # 1.0
-                continue
             for start in range(0, n, step):
-                m = min(step, n - start)
-                bits = gen.random_raw(-(-m // 4)).view(np.uint16)[:m]
-                exp = ((bits >> 7) & 3) + 118  # 2**-9 .. 2**-6
-                exp <<= 7
-                bits &= 0x807F
-                bits |= exp
-                f.write(bits.tobytes())
+                f.write(_slice(fill, min(step, n - start), gen).tobytes())
     os.replace(tmp, path)
     return offset
 
@@ -108,7 +111,7 @@ def file_name(tag: str) -> str:
 
 def write_checkpoint(path: pathlib.Path, config: dict, seed: int,
                      only: str | None = None, workers: int = 8) -> dict:
-    """Write the layer files (only="layers"), the client trio
+    """Write the layer files (only="layers"), the client's file
     (only="client") or both, plus config.json and the index. The two halves
     can be written at different times: the server reads only layer files."""
     t0 = time.time()

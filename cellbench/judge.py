@@ -27,6 +27,20 @@ sys.path.insert(0, str(ROOT))
 
 import numpy as np  # noqa: E402
 
+from cellbench import families  # noqa: E402
+
+
+def reference_key(plan: dict, ids: list, rows: list) -> str:
+    """Names the kept reference: configuration, seed, judged ids and rows,
+    and the code that computes it (reference.py, checkpoint.py and the
+    configuration's family file, by their bytes)."""
+    code = (HERE / "reference.py", HERE / "checkpoint.py",
+            families.path_of(plan["config"]))
+    return hashlib.sha256(json.dumps([
+        plan.get("uid"), plan.get("seed"), plan["config"], ids, rows,
+        *(hashlib.sha256(f.read_bytes()).hexdigest() for f in code),
+    ]).encode()).hexdigest()[:32]
+
 
 def main(plan_path: str) -> int:
     plan = json.loads(pathlib.Path(plan_path).read_text())
@@ -58,11 +72,7 @@ def main(plan_path: str) -> int:
     # The reference's own output, kept per (configuration, seed, judged ids,
     # reference code) inside the checkout: a later run of the same seed (the
     # second set of a check) reads it back instead of computing it again.
-    key = hashlib.sha256(json.dumps([
-        plan.get("uid"), plan.get("seed"), config, ids.tolist(), rows,
-        *(hashlib.sha256((HERE / f).read_bytes()).hexdigest()
-          for f in ("reference.py", "checkpoint.py")),
-    ]).encode()).hexdigest()[:32]
+    key = reference_key(plan, ids.tolist(), rows)
     cache = (pathlib.Path(plan["reference_cache"]) / f"{key}.npz"
              if plan.get("reference_cache") else None)
     if cache is not None and cache.exists():
@@ -101,7 +111,8 @@ def main(plan_path: str) -> int:
         "int8_reference_err_median": float(np.median(cmp["control_err"])),
         "int8_projection_by_row": [round(float(e), 4) for e in proj],
         "greedy_token_agreement": agree,
-        "reference": "cellbench/reference.py, float32, precision highest",
+        "reference": "cellbench/reference.py + families/"
+                     f"{families.path_of(config).name}, float32, precision highest",
         "reference_platform": timing.get("platform", "cache"),
         "reference_seconds": round(time.time() - t0, 2),
         "reference_split_s": {

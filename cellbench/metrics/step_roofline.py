@@ -1,9 +1,10 @@
 """Kernels: least time for a decode step's NEEDED bytes and FLOPs at the
-chip's peaks (cellbench/roofline.py) over a decode group step's median device
-time in the trace (`server_step_ms_p50`: decode runs only). The rows are the window's mean decode-group width, the context the
-mean live context of the window's decode tokens."""
+chip's peaks (the family's `decode_step_needs`, cellbench/roofline.py) over a
+decode group step's median device time in the trace (`server_step_ms_p50`:
+decode runs only). The rows are the window's mean decode-group width, the
+context the mean live context of the window's decode tokens."""
 
-from cellbench import roofline, stats
+from cellbench import families, roofline, stats
 
 
 def read(ctx: dict):
@@ -15,7 +16,7 @@ def read(ctx: dict):
                 if 0.0 <= t < ctx["window_s"]]
     if not step_ms or not groups or not contexts:
         return None
-    needs = roofline.decode_step_needs(
+    needs = families.of(ctx["config"]).decode_step_needs(
         ctx["config"], steps / groups, sum(contexts) / len(contexts))
     least_s, bound = roofline.least_seconds(needs, ctx["device_kind"])
     ctx.setdefault("notes", {})["step_roofline_bound"] = bound

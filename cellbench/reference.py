@@ -1,20 +1,22 @@
-"""Plain reference for both configurations, independent of the program.
+"""Plain reference for every configuration, independent of the program.
 
-The published forward pass of a Mistral / Qwen3-MoE decoder stack in
-straightforward jax.numpy and float32 (`highest` matmul precision, set by the
-caller): no kernels, no cache, no batching tricks. Its inputs are the
-checkpoint file the benchmark wrote from --seed (parsed here, not through the
-program's loader) and token ids from the same seed. Departures from the
-published description: none; the depth is the configuration's cut.
+The published forward pass of a decoder stack in straightforward jax.numpy
+and float32 (`highest` matmul precision, set by the caller): no kernels, no
+cache, no batching tricks. Its inputs are the checkpoint files the benchmark
+wrote from --seed (parsed here, not through the program's loader) and token
+ids from the same seed. Departures from the published description: none; the
+depth is the configuration's cut.
 
-  hidden = embed[ids]
-  per layer: x = rms(hidden) ; q,k,v = x@Wq^T.. ; per-head rms on q,k (Qwen3)
-             rotary (HF rotate_half) ; causal softmax attention with GQA and
-             the sliding window ; hidden += attn@Wo^T
-             x = rms(hidden) ; hidden += mlp(x)
-  mlp: silu(x@Wg^T) * (x@Wu^T) @ Wd^T, or the Qwen3-MoE block: softmax over
-       all experts, top-k, renormalised (norm_topk_prob), experts' gated MLPs
-  logits = rms(hidden) @ head^T
+  hidden = family.embed(client tensors, ids)
+  per layer: hidden = family.layer_forward(family.layer_params(file), hidden)
+  logits = family.logits_rows(client tensors, hidden at the judged rows)
+
+The layer and the two ends are the family's (cellbench/families/<model_type>
+.py, which also says what they compute); what every family shares is here:
+the safetensors reader, the layer loop with read-ahead, the int8 control,
+`compare`, and plain helpers for a family file to import (`_rms`,
+`_rotate_half`, `_attention`, `_rope_attention`). No tensor's name is known
+here.
 """
 
 from __future__ import annotations
@@ -25,6 +27,9 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
+
+from cellbench import families
+from cellbench.checkpoint import CLIENT_SHARD, file_name, layer_tag
 
 
 def read_safetensors(path: pathlib.Path) -> dict[str, np.ndarray]:
@@ -47,38 +52,9 @@ def read_safetensors(path: pathlib.Path) -> dict[str, np.ndarray]:
 
 
 def layer_params(ckpt: pathlib.Path, config: dict, layer: int) -> dict:
-    """One layer's tensors under short names, torch layout [out, in]; the
-    experts stacked [E, out, in]. Still bfloat16 (exact); cast on use."""
-    from cellbench.checkpoint import file_name
-
-    t = read_safetensors(ckpt / file_name(f"layer{layer:03d}"))
-    p = f"model.layers.{layer}."
-    out = {
-        "ln1": t[p + "input_layernorm.weight"],
-        "ln2": t[p + "post_attention_layernorm.weight"],
-        **{k: t[p + f"self_attn.{k}_proj.weight"] for k in "qkvo"},
-    }
-    if config["model_type"] == "qwen3_moe":
-        out["q_norm"] = t[p + "self_attn.q_norm.weight"]
-        out["k_norm"] = t[p + "self_attn.k_norm.weight"]
-        out["router"] = t[p + "mlp.gate.weight"]
-        for k in ("gate", "up", "down"):
-            out[f"e_{k}"] = np.stack([
-                t[p + f"mlp.experts.{e}.{k}_proj.weight"]
-                for e in range(config["num_experts"])
-            ])
-    else:
-        for k in ("gate", "up", "down"):
-            out[k] = t[p + f"mlp.{k}_proj.weight"]
-    return out
-
-
-def client_params(ckpt: pathlib.Path) -> dict:
-    from cellbench.checkpoint import CLIENT_SHARD, file_name
-
-    t = read_safetensors(ckpt / file_name(CLIENT_SHARD))
-    return {"embed": t["model.embed_tokens.weight"],
-            "norm": t["model.norm.weight"], "head": t["lm_head.weight"]}
+    """One layer's file, as its family's `layer_params` names its leaves."""
+    tensors = read_safetensors(ckpt / file_name(layer_tag(layer)))
+    return families.of(config).layer_params(tensors, config, layer)
 
 
 def _rms(x, w, eps):
@@ -93,34 +69,6 @@ def _rotate_half(x):
 
     half = x.shape[-1] // 2
     return jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-
-
-def _moe(x, p, config, block: int = 512):
-    """Qwen3MoeSparseMoeBlock on [R, D] rows, every expert computed for a
-    block of rows at a time and weighted by the renormalised top-k router
-    probabilities (zero off the top-k): the same sum the sparse form makes."""
-    import jax
-    import jax.numpy as jnp
-
-    k = config["num_experts_per_tok"]
-    r, d = x.shape
-    pad = -r % block
-    xb = jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, block, d)
-
-    def one(rows):
-        probs = jax.nn.softmax(rows @ p["router"].T, axis=-1)
-        top, idx = jax.lax.top_k(probs, k)
-        if config.get("norm_topk_prob"):
-            top = top / top.sum(-1, keepdims=True)
-        w = jnp.zeros_like(probs).at[jnp.arange(block)[:, None], idx].set(top)
-        g = jnp.einsum("rd,eid->rei", rows, p["e_gate"])
-        u = jnp.einsum("rd,eid->rei", rows, p["e_up"])
-        # the router weight goes in before the down projection (linear, so
-        # the same sum) to keep the [R, E, D] intermediate out of memory
-        h = jax.nn.silu(g) * u * w[:, :, None]
-        return jnp.einsum("rei,edi->rd", h, p["e_down"])
-
-    return jax.lax.map(one, xb).reshape(-1, d)[:r]
 
 
 def _attention(q, k, v, positions, window, block: int = 512):
@@ -149,60 +97,34 @@ def _attention(q, k, v, positions, window, block: int = 512):
     return jax.lax.map(one, (qb, pb)).reshape(-1, heads * hd)[:t]
 
 
-def layer_forward(p: dict, config: dict, hidden, positions):
-    """One sequence: hidden [T, D] float32, positions [T]; p's leaves may be
-    bfloat16 (exact) and are cast to float32 here."""
-    import jax
+def _rope_attention(q, k, v, positions, theta: float, window: int):
+    """Rotary positions (HF rotate_half) on q [T, H, hd] and k [T, Hkv, hd],
+    then `_attention`: [T, H * hd]."""
     import jax.numpy as jnp
 
-    p = jax.tree.map(lambda a: a.astype(jnp.float32), p)
-    t, d = hidden.shape
-    heads, kvh = config["num_attention_heads"], config["num_key_value_heads"]
-    hd = config.get("head_dim") or d // heads
-    eps = config["rms_norm_eps"]
-    x = _rms(hidden, p["ln1"], eps)
-    q = (x @ p["q"].T).reshape(t, heads, hd)
-    k = (x @ p["k"].T).reshape(t, kvh, hd)
-    v = (x @ p["v"].T).reshape(t, kvh, hd)
-    if "q_norm" in p:
-        q, k = _rms(q, p["q_norm"], eps), _rms(k, p["k_norm"], eps)
-    inv = 1.0 / (config["rope_theta"] ** (jnp.arange(0, hd, 2) / hd))
+    hd = q.shape[-1]
+    inv = 1.0 / (theta ** (jnp.arange(0, hd, 2) / hd))
     ang = positions.astype(jnp.float32)[:, None] * inv
     ang = jnp.concatenate([ang, ang], -1)[:, None, :]
     q = q * jnp.cos(ang) + _rotate_half(q) * jnp.sin(ang)
     k = k * jnp.cos(ang) + _rotate_half(k) * jnp.sin(ang)
-    attn = _attention(q, k, v, positions, config.get("sliding_window") or 0)
-    hidden = hidden + attn @ p["o"].T
-    x = _rms(hidden, p["ln2"], eps)
-    if "router" in p:
-        y = _moe(x, p, config)
-    else:
-        y = (jax.nn.silu(x @ p["gate"].T) * (x @ p["up"].T)) @ p["down"].T
-    return hidden + y
+    return _attention(q, k, v, positions, window)
 
 
-def logits_rows(client: dict, config: dict, hidden_rows):
-    import jax.numpy as jnp
-
-    w = {k: jnp.asarray(v).astype(jnp.float32) for k, v in client.items()
-         if k != "embed"}
-    return _rms(hidden_rows, w["norm"], config["rms_norm_eps"]) @ w["head"].T
-
-
-def int8_weights(p: dict) -> dict:
-    """The control's weights: every matrix as symmetric int8 codes with one
-    scale per output channel, dequantised (norm vectors untouched): the
+def int8_weights(p: dict, keep: tuple[str, ...] = ()) -> dict:
+    """The control's weights: every array of two or more dimensions as
+    symmetric int8 codes with one scale per output channel, dequantised
+    (vectors, and the leaves a family names in `keep`, untouched): the
     nearest precision below the bfloat16 the configurations state."""
     import jax.numpy as jnp
 
     def quant(w):
-        if w.ndim < 2:
-            return w
         w = w.astype(jnp.float32)
         scale = jnp.max(jnp.abs(w), axis=-1, keepdims=True) / 127.0
         return jnp.clip(jnp.round(w / scale), -127, 127) * scale
 
-    return {name: quant(w) for name, w in p.items()}
+    return {name: w if w.ndim < 2 or name in keep else quant(w)
+            for name, w in p.items()}
 
 
 def reference_logits(ckpt: pathlib.Path, config: dict, ids: np.ndarray,
@@ -215,16 +137,18 @@ def reference_logits(ckpt: pathlib.Path, config: dict, ids: np.ndarray,
     import jax
     import jax.numpy as jnp
 
-    client = client_params(ckpt)
+    family = families.of(config)
+    keep = getattr(family, "INT8_KEEPS", ())
+    client = read_safetensors(ckpt / file_name(CLIENT_SHARD))
     positions = jnp.arange(ids.shape[1])
-    hidden = jnp.asarray(np.asarray(client["embed"][ids], np.float32))
+    hidden = jnp.asarray(family.embed(client, config, ids))
 
     def forward(p, hs):
         return jax.lax.map(
-            lambda h: layer_forward(p, config, h, positions), hs)
+            lambda h: family.layer_forward(p, config, h, positions), hs)
 
     step = jax.jit(lambda p, hs, hq: (
-        forward(p, hs), forward(int8_weights(p), hq)))
+        forward(p, hs), forward(int8_weights(p, keep), hq)))
     exact = low = hidden
     timing = {} if timing is None else timing
     timing["forward_by_layer"] = []
@@ -250,7 +174,8 @@ def reference_logits(ckpt: pathlib.Path, config: dict, ids: np.ndarray,
     out = {}
     for name, hs in (("exact", exact), ("int8", low)):
         picked = jnp.stack([hs[s, t] for s, t in rows])
-        out[name] = np.asarray(logits_rows(client, config, picked), np.float32)
+        out[name] = np.asarray(
+            family.logits_rows(client, config, picked), np.float32)
     return out
 
 

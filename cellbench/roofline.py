@@ -1,15 +1,15 @@
-"""Peaks of the chips the benchmark knows, and the least work a decode step
-or a prefill chunk needs: the yardstick's side of `step_roofline` and
+"""Peaks of the chips the benchmark knows, and the least time a stated amount
+of work takes at them: the yardstick's side of `step_roofline` and
 `chunk_roofline`.
 
 A roofline share is least_time / measured device time. least_time counts only
-what the algorithm NEEDS for one step of `rows` single-token decodes at a mean
-live context: each layer's attention weights once, the MLP weights once (for
-a sparse-expert layer: the router and only the DISTINCT experts the rows are
-routed to, in expectation under uniform routing), every row's live keys and
-values once (inside the sliding window), and the rows' activations in and
-out. Work the program does beyond that (computing every expert, copying the
-arena) lowers the share; it can never push it past 100%.
+what the algorithm NEEDS for one decode step or one prefill chunk: the bytes
+and FLOPs that the configuration's family states
+(cellbench/families/<model_type>.py: `decode_step_needs`, `chunk_needs`; each
+layer's weights once, for sparse experts only the DISTINCT ones the rows are
+routed to, every row's live keys, values or state once, the rows' activations
+in and out). Work the program does beyond that (computing every expert,
+copying the arena) lowers the share; it can never push it past 100%.
 """
 
 from __future__ import annotations
@@ -33,86 +33,10 @@ def peaks(device_kind: str) -> dict:
     return PEAKS[device_kind]
 
 
-def layer_weights(config: dict) -> dict:
-    """Parameter counts of one layer: attention, and the MLP as either one
-    dense block or (router, one expert, number of experts, experts/token)."""
-    d = config["hidden_size"]
-    hd = config.get("head_dim") or d // config["num_attention_heads"]
-    q = config["num_attention_heads"] * hd
-    kv = config["num_key_value_heads"] * hd
-    out = {"attn": d * q + 2 * d * kv + q * d}
-    if config.get("num_experts"):
-        out["router"] = d * config["num_experts"]
-        out["expert"] = 3 * d * config["moe_intermediate_size"]
-        out["experts"] = config["num_experts"]
-        out["top_k"] = config["num_experts_per_tok"]
-    else:
-        out["mlp"] = 3 * d * config["intermediate_size"]
-    return out
-
-
 def expected_distinct_experts(experts: int, top_k: int, rows: float) -> float:
     """Distinct experts hit by `rows` tokens that each pick top_k of
     `experts` uniformly: E * (1 - (1 - k/E)**rows)."""
     return experts * (1.0 - (1.0 - top_k / experts) ** rows)
-
-
-def decode_step_needs(config: dict, rows: float, context: float) -> dict:
-    """Bytes and FLOPs one decode step of `rows` rows at mean live context
-    `context` needs, over the configuration's layers."""
-    w = layer_weights(config)
-    layers = config["num_hidden_layers"]
-    d = config["hidden_size"]
-    hd = config.get("head_dim") or d // config["num_attention_heads"]
-    if config.get("sliding_window"):
-        context = min(context, config["sliding_window"])
-    if "expert" in w:
-        distinct = expected_distinct_experts(w["experts"], w["top_k"], rows)
-        mlp_read = w["router"] + distinct * w["expert"]
-        mlp_active = w["router"] + w["top_k"] * w["expert"]
-    else:
-        mlp_read = mlp_active = w["mlp"]
-    kv_row = 2 * config["num_key_value_heads"] * hd  # K and V of one token
-    weight_bytes = layers * (w["attn"] + mlp_read) * BF16
-    kv_bytes = layers * rows * (context + 1) * kv_row * BF16
-    act_bytes = 2 * rows * d * BF16
-    flops = layers * rows * (
-        2 * (w["attn"] + mlp_active)
-        + 4 * context * config["num_attention_heads"] * hd
-    )
-    return {"bytes": weight_bytes + kv_bytes + act_bytes, "flops": flops,
-            "weight_bytes": weight_bytes, "kv_bytes": kv_bytes}
-
-
-def chunk_needs(config: dict, rows: float, context: float) -> dict:
-    """Bytes and FLOPs one prefill chunk of `rows` tokens of ONE sequence
-    needs when `context` tokens of it are already cached: every layer's
-    weights once (sparse experts: the distinct ones `rows` tokens pick), the
-    cached keys and values once (inside the sliding window), the chunk's own
-    keys and values written once, activations in and out; causal attention
-    over the cache and over the chunk's own lower triangle."""
-    w = layer_weights(config)
-    layers = config["num_hidden_layers"]
-    d = config["hidden_size"]
-    hd = config.get("head_dim") or d // config["num_attention_heads"]
-    if config.get("sliding_window"):
-        context = min(context, config["sliding_window"])
-    if "expert" in w:
-        distinct = expected_distinct_experts(w["experts"], w["top_k"], rows)
-        mlp_read = w["router"] + distinct * w["expert"]
-        mlp_active = w["router"] + w["top_k"] * w["expert"]
-    else:
-        mlp_read = mlp_active = w["mlp"]
-    kv_row = 2 * config["num_key_value_heads"] * hd
-    weight_bytes = layers * (w["attn"] + mlp_read) * BF16
-    kv_bytes = layers * (context + rows) * kv_row * BF16
-    act_bytes = 2 * rows * d * BF16
-    flops = layers * rows * (
-        2 * (w["attn"] + mlp_active)
-        + 4 * (context + rows / 2) * config["num_attention_heads"] * hd
-    )
-    return {"bytes": weight_bytes + kv_bytes + act_bytes, "flops": flops,
-            "weight_bytes": weight_bytes, "kv_bytes": kv_bytes}
 
 
 def least_seconds(needs: dict, device_kind: str) -> tuple[float, str]:

@@ -4,12 +4,15 @@
 
 It is the benchmark's own test, so it lives here and not under tests/. It
 builds a copy of the benchmark in a git-ignored directory and ADDS files to it
--- two tiny configurations, two tiny traffic mixes, two cells and a per-layer
-metric -- without editing a file that is there: what a later PR has to be able
-to do. Then: the command runs, its last line has exactly the contract's keys,
-the added metric is found by name, and a server in a lower precision than the
-configuration states (the control, --weight-quant int8) makes `correct` false.
-No time or rate read here is a device number.
+-- three tiny configurations, two tiny traffic mixes, three cells, a per-layer
+metric and a FAMILY FILE (`families/falcon.py`, from tests/family_falcon.py:
+a family the program serves and the harness has no file for) -- without
+editing a file that is there: what a later PR has to be able to do. Then: the
+command runs, its last line has exactly the contract's keys, the added metric
+is found by name, the added family's cell is `correct`, and a server in a
+lower precision than the configuration states (the control, --weight-quant
+int8) makes `correct` false in a built-in family's cell and in the added
+one's. No time or rate read here is a device number.
 """
 
 from __future__ import annotations
@@ -43,6 +46,15 @@ TINY_MOE = {
     "rope_theta": 1000000.0, "max_position_embeddings": 8192,
     "tie_word_embeddings": False, "torch_dtype": "bfloat16",
 }
+# the falcon-7b shape: one key/value head, parallel residual, tied head
+TINY_FALCON = {
+    "model_type": "falcon", "architectures": ["FalconForCausalLM"],
+    "hidden_size": 256, "num_attention_heads": 8, "num_hidden_layers": 2,
+    "vocab_size": 1024, "layer_norm_epsilon": 1e-05, "rope_theta": 10000.0,
+    "multi_query": True, "parallel_attn": True, "alibi": False, "bias": False,
+    "new_decoder_architecture": False, "max_position_embeddings": 8192,
+    "tie_word_embeddings": True, "torch_dtype": "bfloat16",
+}
 
 
 def _harness(uid: str, limit: float) -> dict:
@@ -69,6 +81,9 @@ def tree() -> pathlib.Path:
         dict(TINY_DENSE, cellbench=_harness("tiny-dense", 0.05))))
     (cb / "configs" / "tiny-moe.json").write_text(json.dumps(
         dict(TINY_MOE, cellbench=_harness("tiny-moe", 0.05))))
+    (cb / "configs" / "tiny-falcon.json").write_text(json.dumps(
+        dict(TINY_FALCON, cellbench=_harness("tiny-falcon", 0.05))))
+    shutil.copy(cb / "tests" / "family_falcon.py", cb / "families" / "falcon.py")
     (cb / "traffic" / "tiny-chat.json").write_text(json.dumps({
         "loop": "closed", "sessions": 3, "stagger_s": 0.1,
         "prompt_tokens": [72, 136, 100, 200], "new_tokens": [5, 4, 6, 4],
@@ -77,7 +92,8 @@ def tree() -> pathlib.Path:
         "loop": "closed", "sessions": 2, "stagger_s": 0.1,
         "prompt_tokens": [600, 520], "new_tokens": [4, 5],
         "judge": {"requests": 1, "new_tokens": 4}}))
-    (cb / "cells" / "tiny-moe-chat.json").write_text('{"num_pages": 128}')
+    for cell in ("tiny-moe-chat", "tiny-falcon-chat"):
+        (cb / "cells" / f"{cell}.json").write_text('{"num_pages": 128}')
     (cb / "metrics" / "requests_in_window.py").write_text(
         '"""Added by the rehearsal: requests due inside the window."""\n\n\n'
         "def read(ctx):\n"
@@ -87,15 +103,20 @@ def tree() -> pathlib.Path:
         {"name": "tiny-dense", "source": "none", "reduced": [],
          "file": "cellbench/configs/tiny-dense.json", "why": "rehearsal"},
         {"name": "tiny-moe", "source": "none", "reduced": [],
-         "file": "cellbench/configs/tiny-moe.json", "why": "rehearsal"}]
+         "file": "cellbench/configs/tiny-moe.json", "why": "rehearsal"},
+        {"name": "tiny-falcon", "source": "none", "reduced": [],
+         "file": "cellbench/configs/tiny-falcon.json", "why": "rehearsal"}]
     bench["workloads"] += [
         {"name": "tiny-moe-chat", "config": "tiny-moe",
          "traffic": "tiny-chat", "chips": 1, "why": "rehearsal"},
         {"name": "tiny-dense-long", "config": "tiny-dense",
-         "traffic": "tiny-long", "chips": 1, "why": "rehearsal"}]
+         "traffic": "tiny-long", "chips": 1, "why": "rehearsal"},
+        {"name": "tiny-falcon-chat", "config": "tiny-falcon",
+         "traffic": "tiny-chat", "chips": 1, "why": "rehearsal"}]
     for metric in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in metric:
-            metric["workloads"] += ["tiny-moe-chat", "tiny-dense-long"]
+            metric["workloads"] += ["tiny-moe-chat", "tiny-dense-long",
+                                    "tiny-falcon-chat"]
     bench["per_layer"].append(
         {"name": "requests_in_window", "unit": "count", "better": "higher",
          "source": "program_counter", "layer": "client",
@@ -150,6 +171,26 @@ def test_lower_precision_server_is_not_correct(tree):
     rc, last, out = _run(
         tree, "--workload", "tiny-moe-chat", "--seed", "7", "--seconds", "2",
         "--trace", "0", "--server-arg=--weight-quant", "--server-arg=int8")
+    assert last is not None and last["correct"] is False, out[-3000:]
+    assert rc != 0
+
+
+def test_added_family_serves_and_is_correct(tree):
+    """A third family, added by files only: other tensor names, fills from a
+    range, a tied head; through the same run.py as the built-in two."""
+    assert not (ROOT / "cellbench" / "families" / "falcon.py").exists()
+    rc, last, out = _run(tree, "--workload", "tiny-falcon-chat", "--seed",
+                         str(2**31 + 29), "--seconds", "4", "--trace", "1")
+    assert last is not None and set(last) == CONTRACT_KEYS, out[-3000:]
+    assert rc == 0 and last["correct"] is True, out[-3000:]
+    assert last["failed"] == 0 and last["attempted"] >= 3
+
+
+def test_added_family_lower_precision_server_is_not_correct(tree):
+    rc, last, out = _run(
+        tree, "--workload", "tiny-falcon-chat", "--seed", "13", "--seconds",
+        "2", "--trace", "0", "--server-arg=--weight-quant",
+        "--server-arg=int8")
     assert last is not None and last["correct"] is False, out[-3000:]
     assert rc != 0
 

@@ -7,6 +7,7 @@ lockstep batch is either confirmed or corrected by the numbers, not by trust.
 
 from __future__ import annotations
 
+import math
 import pathlib
 import sys
 
@@ -15,7 +16,7 @@ import pytest
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from cellbench import roofline, stats, trace  # noqa: E402
+from cellbench import checkpoint, families, roofline, stats, trace  # noqa: E402
 
 MS = 1_000_000_000  # picoseconds in a millisecond
 
@@ -159,14 +160,14 @@ def test_roofline_stays_under_100_for_both_configurations(name, config):
     faster: against the time the chip needs merely to READ what the step
     reads (the weights it touches once, the live KV), the share is <= 100%
     for every batch width and context the cells reach."""
-    w = roofline.layer_weights(config)
-    layers = config["num_hidden_layers"]
-    per_layer = w["attn"] + (
-        w["router"] + w["experts"] * w["expert"] if "expert" in w else w["mlp"])
-    all_weight_bytes = layers * per_layer * roofline.BF16
+    family = families.of(config)
+    # every tensor of every layer file the checkpoint holds, norms included
+    all_weight_bytes = roofline.BF16 * sum(
+        math.prod(shape) for tag, tensors in checkpoint.tensor_plan(config)
+        if tag != checkpoint.CLIENT_SHARD for _name, shape, _fill in tensors)
     for rows in (1, 2, 3.5, 8):
         for context in (72, 512, 2120, 4096):
-            needs = roofline.decode_step_needs(config, rows, context)
+            needs = family.decode_step_needs(config, rows, context)
             assert needs["weight_bytes"] <= all_weight_bytes
             least, bound = roofline.least_seconds(needs, "TPU v5 lite")
             assert bound in ("memory", "compute")
@@ -177,14 +178,14 @@ def test_roofline_stays_under_100_for_both_configurations(name, config):
             assert 100.0 * least / honest_floor <= 100.0
     # a full prefill chunk: the same rule, at every context a chunk can start
     for context in (0, 1024, 3968):
-        needs = roofline.chunk_needs(config, 128, context)
+        needs = family.chunk_needs(config, 128, context)
         assert needs["weight_bytes"] <= all_weight_bytes
         least, _ = roofline.least_seconds(needs, "TPU v5 lite")
         assert 0 < least <= needs["bytes"] / 819e9 + needs["flops"] / 197e12
-    # the fastest decode steps this PR's traces held (my chip runs, PR 26):
-    # 7.35 ms with the 4-layer Qwen3 span, 14.56 ms with the Mistral span
-    fastest = 0.00735 if "num_experts" in config else 0.01456
-    needs = roofline.decode_step_needs(config, 3.0, 400)
+    # the decode step's median device time since PR 28 (ledger, PR 28)
+    fastest = {"qwen3-30b-a3b-span4": 0.0070731,
+               "mistral-7b-span16": 0.010938}[name]
+    needs = family.decode_step_needs(config, 3.0, 400)
     least, _ = roofline.least_seconds(needs, "TPU v5 lite")
     assert 100.0 * least / fastest < 100.0
 
