@@ -203,6 +203,14 @@ def main(argv=None):
                     declines = probe.get("ragged_declines") or {}
                     for reason, n in sorted(declines.items()):
                         line += f"  ragged_decline[{reason}]={n}"
+                    # recurrent state beside the KV arena (a family with a
+                    # state-space mixer): the slot pool, slots held, bytes
+                    state = (probe.get("memory") or {}).get("state")
+                    if state:
+                        line += (
+                            f"  memory.state=slots:{state['slots']}"
+                            f",live:{state['live']},bytes:{state['bytes']}"
+                        )
                     # elastic self-healing counters: standby promotions /
                     # drain-backs and measured-load rebalance outcomes —
                     # the control loop's every decision, probeable without

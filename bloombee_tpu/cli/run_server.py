@@ -252,7 +252,8 @@ def main(argv=None):
                 model_uid, range(spec.num_hidden_layers)
             )
             n = args.num_blocks or choose_num_blocks(
-                spec, dtype, args.num_pages, args.page_size
+                spec, dtype, args.num_pages, args.page_size,
+                max_batch=args.max_batch,
             )
             start, end = choose_best_blocks(
                 # departing (DRAINING) servers are not coverage

@@ -32,16 +32,21 @@ _embed = functools.partial(
 )(embed_impl)
 
 _norm_head = functools.partial(
-    jax.jit, static_argnames=("eps", "soft_cap", "norm_type")
+    jax.jit,
+    static_argnames=("eps", "soft_cap", "norm_type", "logit_multiplier"),
 )(norm_head_impl)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("eps", "soft_cap", "norm_type", "step")
+    jax.jit,
+    static_argnames=(
+        "eps", "soft_cap", "norm_type", "step", "logit_multiplier",
+    ),
 )
 def _norm_head_chunked(
     params, hidden, eps: float, soft_cap: float = 0.0,
     norm_type: str = "rms", step: int = 16384,
+    logit_multiplier: float = 1.0,
 ):
     """Vocab-chunked head: the matmul runs `step` vocab columns at a time
     (lax.map keeps one chunk's intermediates live), bounding transient
@@ -72,6 +77,8 @@ def _norm_head_chunked(
         )
 
     logits = jax.lax.fori_loop(0, n, body, out)
+    if logit_multiplier != 1.0:
+        logits = logits * logit_multiplier
     if soft_cap:
         logits = jnp.tanh(logits / soft_cap) * soft_cap
     return logits
@@ -176,6 +183,7 @@ class DistributedModelForCausalLM:
                     soft_cap=self.spec.logits_soft_cap,
                     norm_type=self.spec.norm_type,
                     step=self.config.chunked_head_step,
+                    logit_multiplier=self.spec.lm_head_multiplier,
                 )
             )
         return np.asarray(
@@ -185,6 +193,7 @@ class DistributedModelForCausalLM:
                 eps=self.spec.rms_norm_eps,
                 soft_cap=self.spec.logits_soft_cap,
                 norm_type=self.spec.norm_type,
+                logit_multiplier=self.spec.lm_head_multiplier,
             )
         )
 

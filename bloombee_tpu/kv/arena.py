@@ -22,6 +22,15 @@ layer slab and the flat arena alike. Why not slice a slab out of the stack
 per layer: the slice and the write-back each copy the whole slab, which moves
 the arena through HBM once a layer to write a handful of rows; the
 benchmark's `scan_slab_move_share` reads such copies.
+
+Recurrent state beside the pages: a family with a state-space mixer
+(`ModelSpec.ssm`) keeps, per sequence and layer, ONE fixed-size slot that is
+never paged: `make_state_arena` -> {"ssm": [L, slots, H, P, N] float32, "conv":
+[L, slots, K-1, C]}. It is addressed the same way: viewed flat over
+(layer, slot), carried whole through the step's scan, layer `l` reads and
+writes rows `layer_state_slots(slots, l)`. A slot can be kept or zeroed,
+never cut to a position: everything that truncates pages refuses such a
+family or resets the slot (kv/cache_manager.py).
 """
 
 from __future__ import annotations
@@ -51,6 +60,41 @@ def make_arena(
     if quant not in (None, "none"):
         raise ValueError(f"unknown KV quant mode {quant!r}")
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def make_state_arena(
+    num_layers: int, num_slots: int, ssm, conv_dtype=jnp.bfloat16
+) -> dict:
+    """The recurrent-state arena of a family with `ssm` (a models.spec
+    SsmSpec): the state in float32 (a sum over thousands of positions), the
+    convolution's tail in the compute dtype (its rows are the projection's
+    outputs, so nothing is rounded)."""
+    return {
+        "ssm": jnp.zeros(
+            (num_layers, num_slots, ssm.heads, ssm.head_dim, ssm.state),
+            jnp.float32,
+        ),
+        "conv": jnp.zeros(
+            (num_layers, num_slots, ssm.conv - 1, ssm.conv_dim), conv_dtype
+        ),
+    }
+
+
+def state_slot_bytes(ssm, conv_itemsize: int = 2) -> int:
+    """Bytes ONE sequence's recurrent state takes in ONE layer: the state in
+    float32, the convolution's tail in the compute dtype."""
+    return (
+        ssm.heads * ssm.head_dim * ssm.state * 4
+        + (ssm.conv - 1) * ssm.conv_dim * conv_itemsize
+    )
+
+
+def layer_state_slots(slots: jax.Array, layer, num_slots: int, num_layers: int):
+    """Layer `layer`'s state slots inside the flat state arena; a padding
+    row's id (outside [0, num_slots)) maps past the END, so a scatter with
+    `mode="drop"` discards it and a gather clamps to a row nobody keeps."""
+    valid = (slots >= 0) & (slots < num_slots)
+    return jnp.where(valid, slots + layer * num_slots, num_layers * num_slots)
 
 
 def flat_arena(arena):

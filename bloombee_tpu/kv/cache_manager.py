@@ -164,6 +164,22 @@ def _reorder_all_layers(ak, av, src, dst):
     return jax.tree.map(move, ak), jax.tree.map(move, av)
 
 
+def state_slots_for(spec, num_pages: int, page_size: int, max_batch: int) -> int:
+    """How many sequences may hold a recurrent-state slot at once, derived
+    from what the server is already told: as many as make the state arena
+    the size of the K/V arena (a sequence's state costs what
+    `ssm state bytes / kv bytes a token` tokens of context cost: 2048 at
+    falcon-h1-34b's widths), and at least twice the batcher's width, so a
+    full decode group and the sessions opening behind it all fit. 0 for a
+    family without recurrent state."""
+    ssm = spec.ssm
+    if ssm is None:
+        return 0
+    kv_token = 2 * spec.num_key_value_heads * spec.head_dim * 2  # bf16 K+V
+    slot = arena_ops.state_slot_bytes(ssm)
+    return max(2 * int(max_batch), (num_pages * page_size * kv_token) // slot)
+
+
 @dataclasses.dataclass
 class CacheHandle:
     handle_id: int
@@ -192,6 +208,8 @@ class CacheManager:
         start_block: int = 0,
         oversubscribe: float = 1.0,  # admit up to this x capacity (parking)
         prefix_cache: bool | None = None,  # None -> BBTPU_PREFIX_CACHE env
+        ssm=None,  # models.spec.SsmSpec: the family keeps recurrent state
+        state_slots: int = 0,  # sequences that may hold a state slot at once
     ):
         dtype = dtype or jnp.bfloat16
         if quant is None:
@@ -199,6 +217,16 @@ class CacheManager:
         self.quant = None if quant in (None, "none") else quant
         if prefix_cache is None:
             prefix_cache = env.get("BBTPU_PREFIX_CACHE")
+        # what this manager refused because a recurrent state can be kept
+        # or zeroed but never cut, copied by pages or parked: by reason
+        # (rpc_info `ragged_declines` carries them to health --probe)
+        self.state_refusals: dict[str, int] = {}
+        self.ssm = ssm
+        if ssm is not None and prefix_cache:
+            # a pooled page holds K/V of a prefix; the state after that
+            # prefix went with the session that wrote it
+            self._refuse("prefix cache")
+            prefix_cache = False
         self.prefix_cache = bool(prefix_cache)
         from bloombee_tpu.kv.paged_native import make_table
 
@@ -229,6 +257,34 @@ class CacheManager:
                 dtype, quant=self.quant,
             )
         self.arena = self._make_arena()
+        # recurrent state beside the pages (kv/arena.py): one slot per
+        # sequence and layer, taken at allocate() and given back at its
+        # exit. A slot is never zeroed by a write of its own: a sequence at
+        # position 0 starts from zeros inside the step (runtime/layer_body.py
+        # reads `start == 0`), so a fresh slot, a rollback to 0 and a replay
+        # from 0 are one case.
+        self.state = None
+        self._make_state = None
+        self._state_slot: dict[int, int] = {}
+        self._free_state_slots: list[int] = []
+        # sequences whose state ran ahead of their pages (a rollback or a
+        # cut to a position > 0): not servable until their length is 0
+        self._state_lost: set[int] = set()
+        self.num_state_slots = 0
+        if ssm is not None:
+            if state_slots < 1:
+                raise ValueError("a family with recurrent state needs state_slots")
+            if self.quant is not None or hetero_spec is not None:
+                raise ValueError(
+                    "recurrent state + quantized or heterogeneous KV arena "
+                    "not supported together"
+                )
+            self.num_state_slots = int(state_slots)
+            self._make_state = lambda: arena_ops.make_state_arena(
+                num_layers, self.num_state_slots, ssm, dtype
+            )
+            self.state = self._make_state()
+            self._free_state_slots = list(range(self.num_state_slots))[::-1]
         # bumped by rebuild_arena(); sessions opened under an older epoch
         # hold table state describing KV that no longer exists
         self.arena_epoch = 0
@@ -313,10 +369,19 @@ class CacheManager:
                 f"request for {need} tokens exceeds capacity "
                 f"{admit_limit}"
             )
+        if self.ssm is not None and batch_size > self.num_state_slots:
+            raise AllocationTimeout(
+                f"request for {batch_size} state slots exceeds the pool of "
+                f"{self.num_state_slots}"
+            )
         cond = self._condition()
         deadline = clock.deadline(timeout)
         async with cond:
-            while self._reserved_tokens + need > admit_limit:
+            while (
+                self._reserved_tokens + need > admit_limit
+                or (self.ssm is not None
+                    and len(self._free_state_slots) < batch_size)
+            ):
                 remaining = None
                 if deadline is not None:
                     remaining = deadline - clock.monotonic()
@@ -340,6 +405,8 @@ class CacheManager:
             for sid in handle.seq_ids:
                 self.table.add_seq(sid)
                 self._seq_epoch[sid] = self.arena_epoch
+                if self.ssm is not None:
+                    self._state_slot[sid] = self._free_state_slots.pop()
             self._live_seqs.update(handle.seq_ids)
         try:
             yield handle
@@ -352,6 +419,10 @@ class CacheManager:
                     self._seq_epoch.pop(sid, None)
                     self._adopted.pop(sid, None)
                     self._live_seqs.discard(sid)
+                    self._state_lost.discard(sid)
+                    slot = self._state_slot.pop(sid, None)
+                    if slot is not None:
+                        self._free_state_slots.append(slot)
                     entry = self._lease_parked.pop(sid, None)
                     if entry is not None and hasattr(
                         self.table, "purge_parked"
@@ -459,6 +530,7 @@ class CacheManager:
         failed dispatch's speculative writes without discarding earlier
         still-speculative tokens (mid-stream prefill chunks)."""
         for sid, length in zip(handle.seq_ids, lengths):
+            self._state_cut(sid, int(length))
             self.table.truncate_speculative(sid, int(length))
 
     def page_table(self, handle: CacheHandle, max_pages: int) -> np.ndarray:
@@ -472,12 +544,49 @@ class CacheManager:
     @_locked
     def commit(self, handle: CacheHandle, lengths: list[int] | None = None):
         for i, sid in enumerate(handle.seq_ids):
+            if lengths is not None:
+                self._state_cut(sid, int(lengths[i]))
             self.table.commit(sid, None if lengths is None else lengths[i])
 
     @_locked
     def rollback(self, handle: CacheHandle):
         for sid in handle.seq_ids:
+            self._state_cut(sid, self.table.seq(sid).l_acc)
             self.table.rollback(sid)
+
+    # ------------------------------------------------------- recurrent state
+    def _refuse(self, reason: str) -> None:
+        self.state_refusals[reason] = self.state_refusals.get(reason, 0) + 1
+
+    def _state_cut(self, seq_id: int, length: int) -> None:
+        """A sequence's pages are about to be cut to `length`. Its recurrent
+        state (if the family has one) stands after every row it was fed and
+        cannot follow: cut to 0 it starts from zeros again, cut to anything
+        else it is lost, and `epoch_valid` says so until the session replays
+        from 0 (the server answers `session_lost`, as after an arena
+        rebuild). Caller holds the lock."""
+        if self.ssm is None:
+            return
+        if length <= 0:
+            self._state_lost.discard(seq_id)
+        elif length < self.table.seq(seq_id).l_seq:
+            self._state_lost.add(seq_id)
+
+    def state_slots(self, handle: CacheHandle) -> np.ndarray:
+        """[B] state slot of each sequence of `handle`."""
+        return np.asarray(
+            [self._state_slot[sid] for sid in handle.seq_ids], np.int32
+        )
+
+    def state_stats(self) -> dict:
+        """rpc_info["memory"]["state"]: the pool's size, slots held, bytes."""
+        from bloombee_tpu.utils.memory import tree_nbytes
+
+        return {
+            "slots": int(self.num_state_slots),
+            "live": int(self.num_state_slots - len(self._free_state_slots)),
+            "bytes": tree_nbytes(self.state) if self.state is not None else 0,
+        }
 
     def accept_speculative(
         self, handle: CacheHandle, accepted_indices: list
@@ -489,6 +598,12 @@ class CacheManager:
         `accepted_indices[i]` lists row i's surviving tree-relative indices
         in path order (depth 0, 1, ...).
         """
+        if self.ssm is not None:
+            self._refuse("speculative accept")
+            raise ValueError(
+                "speculative accept unsupported: recurrent state cannot be "
+                "compacted onto the accepted rows"
+            )
         # an over-subscribed server may have parked this session between
         # rounds. Unpark OUTSIDE the lock — ensure_resident's d2h resolve
         # must not run with the manager lock held — then re-check under
@@ -795,6 +910,16 @@ class CacheManager:
         to host-tier parking (same resume contract, a d2h/h2d copy more).
         The session's token reservation is returned to the admission
         budget for the duration of the park."""
+        if self.ssm is not None:
+            # pages could become evictable pool entries, the state slot
+            # could not follow them: the session stays resident, pages,
+            # slot and reservation, until it resumes or its lease runs out
+            with self._lock:
+                for sid in handle.seq_ids:
+                    if self.table.has_seq(sid):
+                        self._state_cut(sid, self.table.seq(sid).l_acc)
+                        self.table.rollback(sid)
+            return
         with self._lock:
             for sid in handle.seq_ids:
                 if sid in self._parked or not self.table.has_seq(sid):
@@ -886,6 +1011,11 @@ class CacheManager:
         if tier not in ("host", "disk"):
             # before the expensive d2h copy, not after
             raise ValueError(f"unknown park tier {tier!r}")
+        if self.ssm is not None:
+            # pages could go to host, the state slot is not carried: the
+            # sequence stays resident and the reclaimer finds other victims
+            self._refuse("host park")
+            return
         if seq_id in self._adopted:
             # probe-adopted, prefill imminent: parking now would record the
             # un-trimmed adopted length and desync the client's suffix
@@ -1040,6 +1170,9 @@ class CacheManager:
             self.table.invalidate_pool()
         self._adopted.clear()
         self.arena = self._make_arena()
+        if self._make_state is not None:
+            self.state = self._make_state()
+            self._state_lost.clear()  # every resident sequence is stale now
         self.arena_epoch += 1
         for sid in self._parked:
             if sid in self._seq_epoch:
@@ -1083,6 +1216,7 @@ class CacheManager:
             "parked_seqs": parked_total,
             "kv_tokens_reserved": int(self._reserved_tokens),
             "kv_tokens_capacity": int(self.capacity_tokens),
+            **({"state": self.state_stats()} if self.ssm is not None else {}),
         }
 
     @_locked
@@ -1118,6 +1252,13 @@ class CacheManager:
         validity epoch matches the current arena epoch (either no rebuild
         happened since allocation, or the seq was host-parked through every
         rebuild)."""
+        for sid in handle.seq_ids:
+            if sid in self._state_lost:
+                # counted once, when a step first finds it (a warm-up's own
+                # truncations never get here)
+                self._state_lost.discard(sid)
+                self._seq_epoch[sid] = -1
+                self._refuse("rollback to a position > 0")
         return all(
             self._seq_epoch.get(sid) == self.arena_epoch
             for sid in handle.seq_ids

@@ -99,6 +99,7 @@ def _register_builtins() -> None:
     # side-effect registrations
     import bloombee_tpu.models.bloom  # noqa: F401
     import bloombee_tpu.models.falcon  # noqa: F401
+    import bloombee_tpu.models.falcon_h1  # noqa: F401
     import bloombee_tpu.models.gemma2  # noqa: F401
     import bloombee_tpu.models.gemma4  # noqa: F401
     import bloombee_tpu.models.mistral  # noqa: F401

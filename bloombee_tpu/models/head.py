@@ -35,14 +35,18 @@ def embed_impl(
 
 
 def norm_head_impl(
-    params, hidden, eps: float, soft_cap: float = 0.0, norm_type: str = "rms"
+    params, hidden, eps: float, soft_cap: float = 0.0, norm_type: str = "rms",
+    logit_multiplier: float = 1.0,
 ):
-    """Final norm + LM head -> fp32 logits (optionally soft-capped)."""
+    """Final norm + LM head -> fp32 logits (optionally scaled by the
+    family's `lm_head_multiplier`, then soft-capped)."""
     if norm_type == "ln":
         h = layer_norm(hidden, params["norm"], params.get("norm_bias"), eps)
     else:
         h = rms_norm(hidden, params["norm"], eps)
     logits = (h @ params["lm_head"]).astype(jnp.float32)
+    if logit_multiplier != 1.0:
+        logits = logits * logit_multiplier
     if soft_cap:
         logits = jnp.tanh(logits / soft_cap) * soft_cap
     return logits

@@ -14,6 +14,40 @@ from typing import Any
 
 
 @dataclasses.dataclass(frozen=True)
+class SsmSpec:
+    """A state-space (Mamba-2 SSD) mixer beside attention in every layer
+    (falcon_h1). One nested descriptor, hashable like the spec that holds
+    it. Per sequence and layer the mixer keeps a recurrent state
+    [heads, head_dim, state] and the last `conv - 1` rows of the
+    convolution's input; both live in the state arena (kv/arena.py)."""
+
+    heads: int
+    head_dim: int
+    state: int  # d_state
+    groups: int  # heads // groups heads share one B and one C
+    conv: int  # depthwise causal convolution width
+    chunk: int  # SSD chunk length (quadratic inside, recurrent across)
+    in_multiplier: float = 1.0  # on the mixer's input
+    # on in_proj's output segments z | x | B | C | dt
+    multipliers: tuple[float, ...] = (1.0, 1.0, 1.0, 1.0, 1.0)
+    out_multiplier: float = 1.0  # on the mixer's output
+
+    @property
+    def d_ssm(self) -> int:
+        return self.heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: x | B | C."""
+        return self.d_ssm + 2 * self.groups * self.state
+
+    @property
+    def proj_dim(self) -> int:
+        """in_proj's output: z | x | B | C | dt."""
+        return self.d_ssm + self.conv_dim + self.heads
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelSpec:
     family: str
     hidden_size: int
@@ -64,6 +98,17 @@ class ModelSpec:
     k_eq_v_full: bool = False  # full layers share one K=V projection
     # this layer's resolved per-layer overrides (set by spec_for_layer)
     k_eq_v: bool = False
+    # a state-space mixer beside attention in every layer (falcon_h1);
+    # None = attention and MLP only
+    ssm: SsmSpec | None = None
+    # muP-style scalar multipliers, applied where the published code applies
+    # them (1.0 = absent): attention input / keys / attention output, the
+    # MLP's gate and output, the client's logits
+    attention_in_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    mlp_multipliers: tuple[float, float] = (1.0, 1.0)
+    lm_head_multiplier: float = 1.0
 
     def window_for_layer(self, layer_idx: int) -> int:
         return (

@@ -25,6 +25,7 @@ the span step exactly like dense arrays.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -38,6 +39,9 @@ QUANT_KEYS = (
     "q_proj", "k_proj", "v_proj", "o_proj",
     "gate_proj", "up_proj", "down_proj",
     "experts_gate", "experts_up", "experts_down",
+    # falcon_h1's mixer: its two projections only (the convolution's taps,
+    # A_log, D, dt_bias and the norms stay as the checkpoint has them)
+    "ssm_in_proj", "ssm_out_proj",
 )
 
 
@@ -51,8 +55,12 @@ class QuantWeight(NamedTuple):
         return 8 if self.codes.dtype == jnp.int8 else 4
 
 
+@functools.partial(jax.jit, static_argnames=("bits",))
 def quantize_weight(w: jax.Array, bits: int = 8) -> QuantWeight:
-    """Quantize [..., in, out] along the input (contraction) dim."""
+    """Quantize [..., in, out] along the input (contraction) dim. Jitted, so
+    a stacked span's leaf is never held in float32 beside itself (eagerly,
+    an 8-layer 5120 x 21504 stack asked for two 3.5 GB temporaries and did
+    not load on a 16 GB chip)."""
     w = w.astype(jnp.float32)
     if bits == 8:
         amax = jnp.max(jnp.abs(w), axis=-2, keepdims=True)  # [..., 1, out]

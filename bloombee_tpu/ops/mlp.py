@@ -14,7 +14,12 @@ def silu_mlp(
     gate_w: jax.Array,  # [D, I]
     up_w: jax.Array,  # [D, I]
     down_w: jax.Array,  # [I, D]
+    gate_mult: float | None = None,  # on the gate, before the activation
+    out_mult: float | None = None,  # on the output, after the down projection
 ) -> jax.Array:
     g = x @ gate_w
+    if gate_mult is not None:
+        g = g * gate_mult
     u = x @ up_w
-    return (jax.nn.silu(g) * u) @ down_w
+    y = (jax.nn.silu(g) * u) @ down_w
+    return y if out_mult is None else y * out_mult

@@ -25,6 +25,7 @@ from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.runtime.step import (
     pack_plan,
     pack_ragged_plan,
+    pack_ragged_ssm_tail,
     pack_step_payload,
     span_step_packed,
     span_step_ragged,
@@ -165,6 +166,27 @@ class SpanExecutor:
         if not 0.0 < attn_sparsity <= 1.0:
             raise ValueError(f"attn_sparsity in (0, 1], got {attn_sparsity}")
         self.attn_sparsity = float(attn_sparsity)
+        if spec.ssm is not None:
+            # one recurrent-state slot a sequence, read and written by the
+            # scanned span step only: the paths below address K/V pages
+            # layer by layer or chip by chip and have no way to carry it
+            for on, what in (
+                (mesh is not None, "--tp (tensor-parallel serving)"),
+                (sp_mesh is not None, "--sp (sequence-parallel prefill)"),
+                (bool(host_layers), "weight offload"),
+                (spec.heterogeneous, "heterogeneous spans"),
+            ):
+                if on:
+                    raise ValueError(
+                        f"{what} unsupported for {spec.family}: the "
+                        "recurrent state beside the KV arena is carried by "
+                        "the single-chip span step only"
+                    )
+            if manager.state is None:
+                raise ValueError(
+                    f"{spec.family} needs a CacheManager with state slots "
+                    "(ssm=spec.ssm, state_slots=...)"
+                )
         self.mesh = mesh
         self.sp_mesh = sp_mesh
         self._sp_params = None
@@ -531,6 +553,8 @@ class SpanExecutor:
             return "sparse (top-k) attention"
         if has_tree and any(w > 0 for w in self.windows):
             return "sliding-window layers"
+        if has_tree and self.spec.ssm is not None:
+            return "recurrent state (tree rows would branch it)"
         return None
 
     def mixed_unsupported(self) -> str | None:
@@ -661,6 +685,16 @@ class SpanExecutor:
                 row_blocks.append(hid.reshape(b_i * t_i, d))
             n_seqs = len(counts)
             r = sum(counts)
+            # a family with recurrent state runs the chunk form on ONE
+            # sequence a pack (the server sends a chunk of several
+            # sequences on its own; a pack of single rows went the packed
+            # way above, and tree rows are refused)
+            multi = [i for i, c in enumerate(counts) if c > 1]
+            if spec.ssm is not None and len(multi) != 1:
+                raise ValueError(
+                    "ragged_group unsupported: recurrent state (a pack "
+                    "takes ONE sequence of more than one row)"
+                )
             # the tree-mask variant keeps every row's in-step width static:
             # causal members' rows become lower-triangular tree rows, so one
             # t_max bucket covers the whole mix
@@ -750,6 +784,16 @@ class SpanExecutor:
                     slots_pad, pt_pad, positions, lens_pad, q_seq, layer_active
                 )
                 tag = f"r{rb},s{sb},p{pb}"
+            step_kwargs = {"t_max": t_max} if has_tree else {}
+            if spec.ssm is not None:
+                row0 = np.zeros((sb,), np.int32)
+                row0[:n_seqs] = np.cumsum([0] + counts[:-1])
+                counts_pad = np.zeros((sb,), np.int32)
+                counts_pad[:n_seqs] = counts
+                plan = np.concatenate([plan, pack_ragged_ssm_tail(
+                    self._state_slots_padded(combined, sb), row0,
+                    counts_pad, multi[0],
+                )])
 
             # ragged-kernel eligibility mirrors _step's chunk gate: dense
             # arena, [R*H, hd] VMEM budget, contexts past the paged crossover,
@@ -778,8 +822,7 @@ class SpanExecutor:
                 payload_dev = tp_serving.replicated(payload, self.mesh)
             else:
                 payload_dev = jnp.asarray(payload)
-        arena = self.manager.arena
-        step_kwargs = {"t_max": t_max} if has_tree else {}
+        arena = self._arena()
 
         def _run(use_kernel_now: bool):
             with jitwatch.region("span_step_ragged", tag):
@@ -789,6 +832,7 @@ class SpanExecutor:
                     arena["v"],
                     payload_dev,
                     lora,
+                    arena.get("state"),
                     spec=spec,
                     r=rb,
                     n_seqs=sb,
@@ -799,11 +843,11 @@ class SpanExecutor:
                     **step_kwargs,
                 )
 
-        (out, new_k, new_v), used_kernel = self._dispatch(
+        result, used_kernel = self._dispatch(
             _run, use_kernel, arena, "ragged group step"
         )
         self.attn_dispatches["ragged" if used_kernel else "dense"] += 1
-        self.manager.arena = {"k": new_k, "v": new_v}
+        out = self._keep_arena(result)
         with jitwatch.span("bbtpu.slice"):
             return out[0, :r], combined
 
@@ -853,6 +897,8 @@ class SpanExecutor:
             )
         if self.manager.quant is not None:
             raise ValueError("decode_n + quantized KV arena not supported")
+        if spec.ssm is not None:
+            raise ValueError("decode_n + recurrent state not supported")
         if self.attn_sparsity < 1.0:
             # the per-step path recomputes top-k from the CURRENT context
             # length every step; a k frozen at trace time would diverge
@@ -972,11 +1018,35 @@ class SpanExecutor:
                 jnp.asarray(tm_pad) if tm_pad is not None else None,
             )
 
+    def _arena(self) -> dict:
+        """What a span step is handed and donates: the K/V arena and, for a
+        family with recurrent state, the state arena under "state"."""
+        arena = dict(self.manager.arena)
+        if self.manager.state is not None:
+            arena["state"] = self.manager.state
+        return arena
+
+    def _keep_arena(self, result):
+        """Store a span step's returned arenas (K, V and, where the family
+        has one, the state arena) on the manager; returns the step's output."""
+        out, new_k, new_v, *rest = result
+        self.manager.arena = {"k": new_k, "v": new_v}
+        if rest:
+            self.manager.state = rest[0]
+        return out
+
+    def _state_slots_padded(self, handle, bucket: int) -> np.ndarray:
+        """[bucket] state slots of the handle's sequences; padding rows get
+        the pool's size, which no slot has (read clamped, write dropped)."""
+        slots = np.full((bucket,), self.manager.num_state_slots, np.int32)
+        slots[: handle.batch_size] = self.manager.state_slots(handle)
+        return slots
+
     @staticmethod
     def _arena_consumed(arena) -> bool:
         return any(
             getattr(a, "is_deleted", lambda: False)()
-            for a in jax.tree.leaves((arena["k"], arena["v"]))
+            for a in jax.tree.leaves(arena)
         )
 
     def _dispatch(self, run, use_kernel: bool, arena, where: str):
@@ -1133,6 +1203,11 @@ class SpanExecutor:
         lora = resolve_adapter(self.adapters, adapter)
         b, t, d = hidden.shape
         assert d == spec.hidden_size
+        if spec.ssm is not None and (tree_mask is not None or depths is not None):
+            raise ValueError(
+                "tree verify unsupported: a recurrent state cannot branch "
+                "over tree rows or be cut back to the accepted ones"
+            )
 
         with jitwatch.span("bbtpu.pack"):
             # over-subscribed servers may have parked this session's KV to
@@ -1180,6 +1255,10 @@ class SpanExecutor:
                 layer_active[:] = 0
                 layer_active[layers[0] : layers[1]] = 1
             plan = pack_plan(slots_pad, pt_pad, positions, lens_pad, layer_active)
+            if spec.ssm is not None:
+                plan = np.concatenate(
+                    [plan, self._state_slots_padded(handle, bb)]
+                )
             tm_pad = None
             if tree_mask is not None:
                 tm_pad = np.zeros((bb, tb, tb), dtype=bool)
@@ -1256,7 +1335,7 @@ class SpanExecutor:
                     ),
                 )
 
-        arena = self.manager.arena
+        arena = self._arena()
         if self.host_layers:
             def _run_off(use_paged_now: bool):
                 with jitwatch.region("layer_step", f"b{bb},t{tb},p{pb}"):
@@ -1269,6 +1348,7 @@ class SpanExecutor:
             (out, new_k, new_v), use_paged = self._dispatch(
                 _run_off, use_paged, arena, "offloaded step"
             )
+            self.manager.arena = {"k": new_k, "v": new_v}
         elif self.spec.heterogeneous:
             from bloombee_tpu.runtime.hetero import span_step_hetero
 
@@ -1299,6 +1379,7 @@ class SpanExecutor:
             (out, new_k, new_v), _ = self._dispatch(
                 _run_hetero, False, arena, "hetero span step"
             )
+            self.manager.arena = {"k": new_k, "v": new_v}
         else:
             payload_dev, tm_dev = self._place_step_inputs(h_pad, plan, tm_pad)
 
@@ -1313,6 +1394,7 @@ class SpanExecutor:
                         payload_dev,
                         tm_dev,
                         lora,
+                        arena.get("state"),
                         attn_topk=attn_topk,
                         spec=spec,
                         b=bb,
@@ -1326,12 +1408,12 @@ class SpanExecutor:
                         t_real=t,
                     )
 
-            (out, new_k, new_v), use_paged = self._dispatch(
+            result, use_paged = self._dispatch(
                 _run, use_paged, arena, "span step"
             )
+            out = self._keep_arena(result)
         path = "paged" if use_paged else "flash" if use_flash else "dense"
         self.attn_dispatches[path] += 1
-        self.manager.arena = {"k": new_k, "v": new_v}
         with jitwatch.span("bbtpu.slice"):
             out = out[:b, :t]
         if not fetch:

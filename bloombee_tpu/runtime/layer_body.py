@@ -25,6 +25,17 @@ shape or donation changes), so a device trace can say whose an op is:
 none of them is nothing a layer asked for, and that absence is the reading
 (`scan_slab_move_share`).
 
+A family with a state-space mixer (`spec.ssm`, falcon_h1) runs it beside
+attention on the same normed input, under three more scopes: `ssm_proj`
+(in_proj, gate, grouped norm, out_proj), `ssm_scan` (convolution, dt, the
+recurrence: one step a decode row, the chunk form for a prefill chunk) and
+`state_io` (a sequence's slot read out of and written into the state arena).
+The mixer sees a step as flat rows plus `SsmRows`, which says which rows are
+whose: a packed decode step, a solo chunk and the fused ragged pack are one
+code path. Rows past a sequence's real count (bucket tails, padding rows) get
+dt = 0, which neither decays nor feeds the state, and are left out of the
+convolution's new tail.
+
 A layer never owns its K/V slab as an array: the span step hands it the
 whole arena viewed flat plus slot and page ids already offset to the layer
 (runtime/step.py `_scan_layers`); runtime/hetero.py hands it a per-layer
@@ -33,8 +44,11 @@ slab and plain ids. To the code below both are "a slab and ids into it".
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from bloombee_tpu.kv.arena import arena_write, gather_pages
 from bloombee_tpu.models.spec import ModelSpec
@@ -44,6 +58,7 @@ from bloombee_tpu.ops.alibi import alibi_slopes
 from bloombee_tpu.ops.attention import NEG_INF, repeat_kv
 from bloombee_tpu.ops.moe import moe_mlp
 from bloombee_tpu.ops.norms import layer_norm
+from bloombee_tpu.ops.ssm import conv_taps, ssd_sequence, ssm_step
 from bloombee_tpu.utils import env
 
 
@@ -97,18 +112,28 @@ def _dense_mlp(x, params, spec, lora=None):
     mlp_lora = lora is not None and any(
         k in lora for k in ("gate_proj", "up_proj", "down_proj")
     )
-    if mlp_lora and spec.mlp_type == "silu":
-        # lora-aware gated-SiLU composition (the fused silu_mlp takes raw
-        # matrices, so the adapterized path spells it out)
-        g = _proj(x, params, "gate_proj", lora)
-        u = _proj(x, params, "up_proj", lora)
-        return _proj(jax.nn.silu(g) * u, params, "down_proj", lora)
     if spec.mlp_type == "silu":
+        # falcon_h1 scales the gate before the activation and the output
+        # after the down projection (HF FalconH1MLP); every other family
+        # has (1, 1) and traces no multiply
+        gate_mult, out_mult = (
+            None if m == 1.0 else m for m in spec.mlp_multipliers
+        )
+        if mlp_lora:
+            # lora-aware composition (the fused silu_mlp takes raw matrices,
+            # so the adapterized path spells it out)
+            g = _proj(x, params, "gate_proj", lora)
+            if gate_mult is not None:
+                g = g * gate_mult
+            u = _proj(x, params, "up_proj", lora)
+            y = _proj(jax.nn.silu(g) * u, params, "down_proj", lora)
+            return y if out_mult is None else y * out_mult
         return silu_mlp(
             x,
             maybe_dequantize(params["gate_proj"], x.dtype),
             maybe_dequantize(params["up_proj"], x.dtype),
             maybe_dequantize(params["down_proj"], x.dtype),
+            gate_mult, out_mult,
         )
     if spec.mlp_type == "gelu_tanh_gated":
         g = _proj(x, params, "gate_proj", lora)
@@ -120,6 +145,154 @@ def _dense_mlp(x, params, spec, lora=None):
         _proj(x, params, "up_proj", lora), approximate=spec.mlp_type != "gelu"
     )
     return _proj(h, params, "down_proj", lora)
+
+
+class SsmRows(NamedTuple):
+    """Whose the flat rows of a step are, for the mixer. Built once a step
+    (runtime/step.py), the same for every layer."""
+
+    q_seq: jax.Array  # [R] owning sequence of a row (>= S: a padding row)
+    row0: jax.Array  # [S] a sequence's first row
+    nt: jax.Array  # [S] a sequence's REAL rows in this step (0: padding)
+    fresh: jax.Array  # [S] bool: the sequence stands at position 0, so its
+    # state is empty whatever the slot holds (a new session, a replay)
+    chunk_seqs: jax.Array  # [n] sequences that take the chunk form
+    window: int  # static: rows a chunk-form sequence may span
+    step_form: bool  # static: sequences with nt == 1 take one recurrence step
+
+
+def packed_ssm_rows(b: int, t: int, q_positions, state_slots, num_slots,
+                    t_real) -> SsmRows:
+    """SsmRows of a packed [B, T] step: sequence i owns rows i*T..i*T+T-1,
+    of which `t_real` are real (a bucket's tail is not); a padding row of
+    the batch bucket (slot out of range) has none."""
+    seqs = jnp.arange(b, dtype=jnp.int32)
+    real = (state_slots >= 0) & (state_slots < num_slots)
+    n = jnp.asarray(t if t_real is None else t_real, jnp.int32)
+    return SsmRows(
+        q_seq=jnp.repeat(seqs, t),
+        row0=seqs * t,
+        nt=jnp.where(real, n, 0),
+        fresh=q_positions[:, 0] == 0,
+        chunk_seqs=seqs if t > 1 else seqs[:0],
+        window=t,
+        step_form=t == 1,
+    )
+
+
+def _ssm_mixer(spec: ModelSpec, params: dict, x, state: dict, slots,
+               rows: SsmRows):
+    """The state-space mixer on flat rows: x [R, D] (the layer's normed
+    input) -> (m [R, D] before `out_multiplier`, the state arena).
+    `state` is the flat state arena, `slots` [S] each sequence's row of it
+    (out of range: read clamped, write dropped)."""
+    ssm = spec.ssm
+    r = x.shape[0]
+    s = rows.row0.shape[0]
+    h, p, g, n = ssm.heads, ssm.head_dim, ssm.groups, ssm.state
+    f32 = jnp.float32
+    with jax.named_scope("ssm_proj"):
+        mup = jnp.concatenate([
+            jnp.full((width,), mult, x.dtype) for width, mult in zip(
+                (ssm.d_ssm, ssm.d_ssm, g * n, g * n, h), ssm.multipliers)
+        ])
+        zxbcdt = _proj(x * ssm.in_multiplier, params, "ssm_in_proj") * mup
+        z = zxbcdt[:, : ssm.d_ssm]
+        xbc = zxbcdt[:, ssm.d_ssm : ssm.d_ssm + ssm.conv_dim]
+        dt = zxbcdt[:, ssm.d_ssm + ssm.conv_dim :]
+    with jax.named_scope("state_io"):
+        tails = state["conv"].at[slots].get(mode="clip")
+        tails = jnp.where(rows.fresh[:, None, None], 0, tails)
+    with jax.named_scope("ssm_scan"):
+        taps, new_tails = conv_taps(xbc, tails, rows.q_seq, rows.row0, rows.nt)
+        conv = jnp.einsum(
+            "rkc,kc->rc", taps.astype(f32), params["ssm_conv_w"].astype(f32)
+        ) + params["ssm_conv_b"].astype(f32)
+        conv = jax.nn.silu(conv)
+        xs = conv[:, : ssm.d_ssm].reshape(r, h, p)
+        bm = conv[:, ssm.d_ssm : ssm.d_ssm + g * n].reshape(r, g, n)
+        cm = conv[:, ssm.d_ssm + g * n :].reshape(r, g, n)
+        dt = jax.nn.softplus(dt.astype(f32) + params["ssm_dt_bias"])
+        a = -jnp.exp(params["ssm_a_log"])
+        d_skip = params["ssm_d"]
+    y = jnp.zeros((r, h, p), f32)
+    oob = state["ssm"].shape[0]
+    writes = []  # (slots [k], states [k, H, P, N])
+    if rows.step_form:
+        one = rows.nt == 1
+        at = jnp.clip(rows.row0, 0, r - 1)
+        with jax.named_scope("state_io"):
+            s0 = state["ssm"].at[slots].get(mode="clip")
+            s0 = jnp.where(rows.fresh[:, None, None, None], 0.0, s0)
+        with jax.named_scope("ssm_scan"):
+            y_s, s_new = ssm_step(
+                xs[at], jnp.where(one[:, None], dt[at], 0.0), a, bm[at],
+                cm[at], d_skip, s0,
+            )
+            y = y.at[jnp.where(one, rows.row0, r)].set(y_s, mode="drop")
+        writes.append((jnp.where(one, slots, oob), s_new))
+    w = rows.window
+    whole = s == 1 and w == r  # static: one sequence owns every row
+    for i in range(rows.chunk_seqs.shape[0]):
+        c = rows.chunk_seqs[i]
+        r0, n_c, slot_c = rows.row0[c], rows.nt[c], slots[c]
+        with jax.named_scope("state_io"):
+            s0 = state["ssm"].at[slot_c].get(mode="clip")
+            s0 = jnp.where(rows.fresh[c], 0.0, s0)
+        with jax.named_scope("ssm_scan"):
+            def take(z_rows):
+                if whole:
+                    return z_rows
+                pad = jnp.zeros((w, *z_rows.shape[1:]), z_rows.dtype)
+                return lax.dynamic_slice_in_dim(
+                    jnp.concatenate([z_rows, pad]), r0, w
+                )
+
+            valid = jnp.arange(w, dtype=jnp.int32) < n_c
+            y_c, s_c = ssd_sequence(
+                take(xs), jnp.where(valid[:, None], take(dt), 0.0), a,
+                take(bm), take(cm), d_skip, s0, ssm.chunk,
+            )
+            y_c = jnp.where(valid[:, None, None], y_c, 0.0)
+            if whole:
+                y = y + y_c
+            else:
+                y = y + lax.dynamic_update_slice_in_dim(
+                    jnp.zeros((r + w, h, p), f32), y_c, r0, 0
+                )[:r]
+        writes.append((slot_c[None], s_c[None]))
+    with jax.named_scope("state_io"):
+        ssm_arena = state["ssm"]
+        for slots_w, s_w in writes:
+            ssm_arena = ssm_arena.at[slots_w].set(s_w, mode="drop")
+        state = {
+            "ssm": ssm_arena,
+            "conv": state["conv"].at[slots].set(
+                new_tails.astype(state["conv"].dtype), mode="drop"
+            ),
+        }
+    with jax.named_scope("ssm_proj"):
+        # the gate BEFORE the grouped norm (mamba_norm_before_gate false)
+        y = y.reshape(r, ssm.d_ssm) * jax.nn.silu(z.astype(f32))
+        y = y.reshape(r, g, -1)
+        y = y * lax.rsqrt(
+            jnp.mean(y * y, axis=-1, keepdims=True) + spec.rms_norm_eps
+        )
+        y = params["ssm_norm"] * y.reshape(r, ssm.d_ssm).astype(x.dtype)
+        return _proj(y, params, "ssm_out_proj"), state
+
+
+def _mix(spec, params, x, ssm):
+    """The mixer's share of the layer's residual update and the new state
+    arena: (None, None) for a family without one. `ssm` is (state arena,
+    this layer's slots, SsmRows) as the step hands them down."""
+    if ssm is None:
+        return None, None
+    state, slots, rows = ssm
+    m, state = _ssm_mixer(
+        spec, params, x.reshape(-1, x.shape[-1]), state, slots, rows
+    )
+    return m.reshape(x.shape) * spec.ssm.out_multiplier, state
 
 
 def attn_scale(spec: ModelSpec) -> float:
@@ -206,6 +379,9 @@ def layer_body(
     # kernels when this is on)
     t_real: int | None = None,  # real (unpadded) step tokens when T is a
     # padded bucket (the chunk kernel needs it to place query positions)
+    ssm: tuple | None = None,  # (flat state arena, this layer's slots [B],
+    # SsmRows) for a family with a state-space mixer; the layer then
+    # returns the state arena as a fourth value
 ):
     b, t, d = hidden.shape
     h_heads, kv_heads, hd = (
@@ -214,15 +390,19 @@ def layer_body(
         spec.head_dim,
     )
     x = _norm(hidden, params, "input_layernorm", spec)
+    mix, state = _mix(spec, params, x, ssm)
     with jax.named_scope("attn_proj"):
-        q = _proj(x, params, "q_proj", lora).reshape(b, t, h_heads, hd)
-        k = _proj(x, params, "k_proj", lora).reshape(b, t, kv_heads, hd)
+        xa = _attn_in(spec, x)
+        q = _proj(xa, params, "q_proj", lora).reshape(b, t, h_heads, hd)
+        k = _key_scale(spec, _proj(xa, params, "k_proj", lora)).reshape(
+            b, t, kv_heads, hd
+        )
         if spec.k_eq_v:
             # gemma-4 full-attention layers alias V to K (one shared
             # projection; reference gemma4/block.py attention_k_eq_v)
             v = k
         else:
-            v = _proj(x, params, "v_proj", lora).reshape(
+            v = _proj(xa, params, "v_proj", lora).reshape(
                 b, t, kv_heads, hd
             )
         if spec.qk_norm:
@@ -277,8 +457,8 @@ def layer_body(
                     t_real=t_real,
                 )
         return _finish_layer(
-            spec, params, hidden, x, _o_proj(attn, params, lora), k_slab,
-            v_slab, lora,
+            spec, params, hidden, x, _o_proj(spec, attn, params, lora, mix),
+            k_slab, v_slab, lora, state,
         )
     with jax.named_scope("arena_gather"):
         k_ctx = gather_pages(
@@ -310,17 +490,31 @@ def layer_body(
                 window, attn_topk,
             )
     return _finish_layer(
-        spec, params, hidden, x, _o_proj(attn, params, lora), k_slab,
-        v_slab, lora,
+        spec, params, hidden, x, _o_proj(spec, attn, params, lora, mix),
+        k_slab, v_slab, lora, state,
     )
 
 
-def _o_proj(attn, params, lora):
-    """[..., T, H, hd] attention output -> [..., T, D] through o_proj."""
+def _attn_in(spec, x):
+    m = spec.attention_in_multiplier
+    return x if m == 1.0 else x * m
+
+
+def _key_scale(spec, k):
+    m = spec.key_multiplier
+    return k if m == 1.0 else k * m
+
+
+def _o_proj(spec, attn, params, lora, mix=None):
+    """[..., T, H, hd] attention output -> [..., T, D] through o_proj, times
+    the family's attention-output multiplier, plus the mixer's share."""
     with jax.named_scope("attn_proj"):
-        return _proj(
+        out = _proj(
             attn.reshape(*attn.shape[:-2], -1), params, "o_proj", lora
         )
+        if spec.attention_out_multiplier != 1.0:
+            out = out * spec.attention_out_multiplier
+    return out if mix is None else out + mix
 
 
 def attend_ragged(
@@ -413,6 +607,7 @@ def layer_body_ragged(
     lora: dict | None = None,
     nt: jax.Array | None = None,  # [B] in-step token counts (tree groups)
     tree_rows: jax.Array | None = None,  # [R, t_max] in-step visibility
+    ssm: tuple | None = None,  # as layer_body's
 ):
     """layer_body for the ragged mixed-batch step: one [1, R, D] row-major
     pack of N decode tokens plus one prefill chunk's tokens — or, when
@@ -428,13 +623,17 @@ def layer_body_ragged(
         spec.head_dim,
     )
     x = _norm(hidden, params, "input_layernorm", spec)
+    mix, state = _mix(spec, params, x, ssm)
     with jax.named_scope("attn_proj"):
-        q = _proj(x, params, "q_proj", lora).reshape(1, r, h_heads, hd)
-        k = _proj(x, params, "k_proj", lora).reshape(1, r, kv_heads, hd)
+        xa = _attn_in(spec, x)
+        q = _proj(xa, params, "q_proj", lora).reshape(1, r, h_heads, hd)
+        k = _key_scale(spec, _proj(xa, params, "k_proj", lora)).reshape(
+            1, r, kv_heads, hd
+        )
         if spec.k_eq_v:
             v = k
         else:
-            v = _proj(x, params, "v_proj", lora).reshape(
+            v = _proj(xa, params, "v_proj", lora).reshape(
                 1, r, kv_heads, hd
             )
         if spec.qk_norm:
@@ -478,8 +677,8 @@ def layer_body_ragged(
                 window, nt=nt, tree_rows=tree_rows,
             )[None]
     return _finish_layer(
-        spec, params, hidden, x, _o_proj(attn, params, lora), k_slab,
-        v_slab, lora,
+        spec, params, hidden, x, _o_proj(spec, attn, params, lora, mix),
+        k_slab, v_slab, lora, state,
     )
 
 
@@ -496,6 +695,8 @@ def dense_unsupported(spec: ModelSpec) -> str | None:
         return "attention logit soft-cap lives inside attention"
     if spec.heterogeneous:
         return "heterogeneous head_dim layers"
+    if spec.ssm is not None:
+        return "a state-space mixer beside attention (recurrent state)"
     return None
 
 
@@ -543,8 +744,16 @@ def dense_block_forward(
 
 
 def _finish_layer(spec, params, hidden, x, attn_out, k_slab, v_slab,
-                  lora=None):
-    """Residual + MLP tail shared by the dense/flash/paged attention paths."""
+                  lora=None, state=None):
+    """Residual + MLP tail shared by the dense/flash/paged attention paths.
+    A family with recurrent state gets its state arena back as a fourth
+    value."""
+    out = _residual_mlp(spec, params, hidden, x, attn_out, k_slab, v_slab,
+                        lora)
+    return out if state is None else (*out, state)
+
+
+def _residual_mlp(spec, params, hidden, x, attn_out, k_slab, v_slab, lora):
     if spec.parallel_attn:
         # falcon: parallel residual. 7b shares one input norm for attention
         # AND the MLP; 40b/180b new-arch uses two (ln_attn already fed the

@@ -20,6 +20,14 @@ be shared between xs and ys, so every run would copy the whole arena too
 (measured: half of the device's busy time in the densest cell, PERF.md
 section 6, PR 28). The benchmark's `scan_slab_move_share` reads such copies.
 
+A family with a state-space mixer (`spec.ssm`) has a second arena, the
+recurrent state (kv/arena.py `make_state_arena`): it rides the same carry,
+whole and flat over (layer, slot), and layer `l` reads and writes the rows
+`layer_state_slots(state_slots, l)`. The steps then take `state=` (donated)
+and return it as a fourth value; the plan carries each sequence's slot at its
+end. A family without one passes `state=None` and compiles the programs it
+compiled before.
+
 Shape discipline (SURVEY.md section 7 hard part #1): everything is padded to
 static buckets — batch, step tokens T, and cache pages — and validity is
 carried by `ctx_lens` / position masks. Out-of-bucket padding rows scatter to
@@ -38,11 +46,17 @@ from bloombee_tpu.kv.arena import (
     flat_arena,
     layer_pages,
     layer_slots,
+    layer_state_slots,
     stacked_arena,
 )
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.ops.rotary import rotary_cos_sin
-from bloombee_tpu.runtime.layer_body import layer_body, layer_body_ragged
+from bloombee_tpu.runtime.layer_body import (
+    SsmRows,
+    layer_body,
+    layer_body_ragged,
+    packed_ssm_rows,
+)
 
 
 def unpack_plan(plan: jax.Array, b: int, t: int, max_pages: int, num_layers: int):
@@ -124,6 +138,7 @@ def span_step_packed_impl(
     payload: jax.Array,  # uint16 (bf16 compute) or uint32 (f32 compute)
     tree_mask: jax.Array | None = None,
     lora: dict | None = None,  # per-request LoRA factors, leading dim L
+    state: dict | None = None,  # the recurrent-state arena (donated)
     *,
     spec: ModelSpec,
     b: int,
@@ -141,7 +156,7 @@ def span_step_packed_impl(
     hidden, plan = unpack_step_payload(payload, b, t, spec.hidden_size)
     return span_step_impl(
         stacked_params, arena_k, arena_v, hidden, plan, tree_mask,
-        lora=lora,
+        lora=lora, state=state,
         spec=spec, page_size=page_size, max_pages=max_pages,
         use_tree_mask=use_tree_mask, windows=windows, use_flash=use_flash,
         use_paged=use_paged, attn_topk=attn_topk, t_real=t_real,
@@ -154,7 +169,7 @@ span_step_packed = functools.partial(
         "spec", "b", "t", "page_size", "max_pages", "use_tree_mask",
         "windows", "use_flash", "use_paged", "attn_topk",
     ),
-    donate_argnames=("arena_k", "arena_v"),
+    donate_argnames=("arena_k", "arena_v", "state"),
 )(span_step_packed_impl)
 
 
@@ -187,7 +202,8 @@ def _rope_by_window(spec: ModelSpec, q_positions: jax.Array, dtype):
 
 
 def _scan_layers(
-    run_layer,  # (h, k_flat, v_flat, slots_l, pages_l, xs_l) -> (h, k, v)
+    run_layer,  # (h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l) ->
+    # (h, k, v) or, with a state arena, (h, k, v, state)
     hidden: jax.Array,
     arena_k,  # [L, S_tot, Hkv, hd] or its int4 QuantSlab
     arena_v,
@@ -196,6 +212,9 @@ def _scan_layers(
     layer_active: jax.Array,  # [n]: the first n of the arena's L layers run
     xs,  # per-layer inputs, leading dim n on every leaf
     page_size: int,
+    state: dict | None = None,  # {"ssm", "conv"}: [L, slots, ...] each
+    state_slots: jax.Array | None = None,  # [S] a sequence's state slot
+    ssm_rows: SsmRows | None = None,
 ):
     """The span's layer scan, the arena WHOLE in the carry.
 
@@ -205,34 +224,53 @@ def _scan_layers(
     takes the `skip` branch, which hands the carry through untouched — no
     compute, the arena bit-identical. `n < L` is the weight-offload prefix:
     the scan covers the resident layers and cannot reach the others' rows.
+    The recurrent-state arena, where the family has one, is carried and
+    addressed the same way; with it the result has a fourth value.
     """
     num_layers, s_tot = arena_k.shape[:2]
     num_pages = s_tot // page_size
     n = layer_active.shape[0]
+    num_state_slots = 0 if state is None else state["ssm"].shape[1]
 
     def body(carry, xs_l):
         layer, active, rest = xs_l
         slots_l = layer_slots(slots, layer, s_tot, num_layers)
         pages_l = layer_pages(page_table, layer, num_pages)
 
-        def run(h, k_flat, v_flat):
-            return run_layer(h, k_flat, v_flat, slots_l, pages_l, rest)
+        def run(h, k_flat, v_flat, state_flat):
+            ssm_l = None
+            if state_flat is not None:
+                ssm_l = (
+                    state_flat,
+                    layer_state_slots(
+                        state_slots, layer, num_state_slots, num_layers
+                    ),
+                    ssm_rows,
+                )
+            out = run_layer(h, k_flat, v_flat, slots_l, pages_l, rest, ssm_l)
+            return out if state_flat is not None else (*out, None)
 
-        def skip(h, k_flat, v_flat):
-            return h, k_flat, v_flat
+        def skip(h, k_flat, v_flat, state_flat):
+            return h, k_flat, v_flat, state_flat
 
         return lax.cond(active > 0, run, skip, *carry), None
 
-    (hidden, k_flat, v_flat), _ = lax.scan(
+    (hidden, k_flat, v_flat, state_flat), _ = lax.scan(
         body,
-        (hidden, flat_arena(arena_k), flat_arena(arena_v)),
+        (
+            hidden, flat_arena(arena_k), flat_arena(arena_v),
+            None if state is None else flat_arena(state),
+        ),
         (jnp.arange(n, dtype=jnp.int32), layer_active, xs),
     )
-    return (
+    out = (
         hidden,
         stacked_arena(k_flat, num_layers),
         stacked_arena(v_flat, num_layers),
     )
+    if state is None:
+        return out
+    return (*out, stacked_arena(state_flat, num_layers))
 
 
 def span_step_impl(
@@ -244,6 +282,8 @@ def span_step_impl(
     tree_mask: jax.Array | None = None,  # [B, T, T] bool
     prompts: jax.Array | None = None,  # [L, P, D] deep p-tuning prompts
     lora: dict | None = None,  # {proj: {a: [L,in,r], b: [L,r,out]}}
+    state: dict | None = None,  # the recurrent-state arena (donated); the
+    # plan then ends with each row's state slot [B]
     *,
     spec: ModelSpec,
     page_size: int,
@@ -255,7 +295,8 @@ def span_step_impl(
     attn_topk: int = 0,
     t_real: int | None = None,
 ):
-    """Run all local blocks over one step; returns (hidden, arena_k, arena_v).
+    """Run all local blocks over one step; returns (hidden, arena_k, arena_v)
+    and, given a state arena, that as a fourth value.
 
     Rotary cos/sin are computed on-device from the plan's positions (no
     per-step host tables), in fp32 like HF. `prompts` adds a trainable
@@ -276,8 +317,14 @@ def span_step_impl(
     windows_arr = jnp.asarray(
         windows if windows is not None else (0,) * n, jnp.int32
     )
+    state_slots = ssm_rows = None
+    if state is not None:
+        state_slots = plan[-b:]
+        ssm_rows = packed_ssm_rows(
+            b, t, q_positions, state_slots, state["ssm"].shape[1], t_real
+        )
 
-    def run_layer(h, k_flat, v_flat, slots_l, pages_l, xs_l):
+    def run_layer(h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l):
         params_l, window_l, prompt_l, lora_l = xs_l
         if prompt_l is not None:
             p = prompt_l.shape[0]
@@ -286,12 +333,13 @@ def span_step_impl(
             spec, page_size, h, params_l, k_flat, v_flat, *rope(window_l),
             slots_l, pages_l, q_positions, total_lens, tm, window_l,
             use_flash=use_flash, use_paged=use_paged, lora=lora_l,
-            attn_topk=attn_topk, t_real=t_real,
+            attn_topk=attn_topk, t_real=t_real, ssm=ssm_l,
         )
 
     return _scan_layers(
         run_layer, hidden, arena_k, arena_v, slots, page_table, layer_active,
         (stacked_params, windows_arr, prompts, lora), page_size,
+        state=state, state_slots=state_slots, ssm_rows=ssm_rows,
     )
 
 
@@ -301,7 +349,7 @@ span_step = functools.partial(
         "spec", "page_size", "max_pages", "use_tree_mask", "windows",
         "use_flash", "use_paged", "attn_topk",
     ),
-    donate_argnames=("arena_k", "arena_v"),
+    donate_argnames=("arena_k", "arena_v", "state"),
 )(span_step_impl)
 
 
@@ -363,12 +411,28 @@ def pack_ragged_plan(
     return np.concatenate(parts)
 
 
+def pack_ragged_ssm_tail(state_slots, row0, nt, chunk_seq: int):
+    """What a ragged plan of a family with recurrent state ends with: per
+    sequence its state slot, its first row and its real row count, then THE
+    sequence that has more than one row and takes the chunk form (a pack
+    holds exactly one: a prefill chunk beside decode rows)."""
+    import numpy as np
+
+    return np.concatenate([
+        np.ravel(x).astype(np.int32)
+        for x in (state_slots, row0, nt, [chunk_seq])
+    ])
+
+
 def span_step_ragged_impl(
     stacked_params: dict,
     arena_k: jax.Array,  # [L, S_tot, Hkv, hd] (donated)
     arena_v: jax.Array,
     payload: jax.Array,  # uint16 (bf16 compute) or uint32 (f32 compute)
     lora: dict | None = None,
+    state: dict | None = None,  # the recurrent-state arena (donated); the
+    # plan then ends with [state_slots(S) | row0(S) | nt(S) | chunk_seq(1)]
+    # (pack_ragged_ssm_tail)
     *,
     spec: ModelSpec,
     r: int,  # ragged token bucket (pow2-padded sum of member tokens)
@@ -399,19 +463,32 @@ def span_step_ragged_impl(
     windows_arr = jnp.asarray(
         windows if windows is not None else (0,) * num_layers, jnp.int32
     )
+    state_slots = ssm_rows = None
+    if state is not None:
+        tail = plan[plan.shape[0] - (3 * n_seqs + 1):]
+        state_slots, row0, rows_nt = (
+            tail[i * n_seqs : (i + 1) * n_seqs] for i in range(3)
+        )
+        at = jnp.clip(row0, 0, r - 1)
+        ssm_rows = SsmRows(
+            q_seq=q_seq, row0=row0, nt=rows_nt,
+            fresh=q_positions[0, at] == 0,
+            chunk_seqs=tail[3 * n_seqs :], window=r, step_form=True,
+        )
 
-    def run_layer(h, k_flat, v_flat, slots_l, pages_l, xs_l):
+    def run_layer(h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l):
         params_l, window_l, lora_l = xs_l
         return layer_body_ragged(
             spec, page_size, h, params_l, k_flat, v_flat, *rope(window_l),
             slots_l, pages_l, q_positions, total_lens, q_seq,
             window_l, use_kernel=use_kernel, lora=lora_l,
-            nt=nt, tree_rows=tree_rows,
+            nt=nt, tree_rows=tree_rows, ssm=ssm_l,
         )
 
     return _scan_layers(
         run_layer, hidden, arena_k, arena_v, slots, page_table, layer_active,
         (stacked_params, windows_arr, lora), page_size,
+        state=state, state_slots=state_slots, ssm_rows=ssm_rows,
     )
 
 
@@ -421,7 +498,7 @@ span_step_ragged = functools.partial(
         "spec", "r", "n_seqs", "page_size", "max_pages", "windows",
         "use_kernel", "t_max",
     ),
-    donate_argnames=("arena_k", "arena_v"),
+    donate_argnames=("arena_k", "arena_v", "state"),
 )(span_step_ragged_impl)
 
 

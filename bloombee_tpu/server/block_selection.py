@@ -224,12 +224,22 @@ def estimate_block_bytes(spec, dtype) -> int:
     else:
         mlp = 2 * d * i
     norms = 4 * d
+    mixer = 0
+    if spec.ssm is not None:
+        # a state-space mixer beside attention: in_proj, out_proj, the
+        # convolution's taps and bias, the grouped norm, A_log / D / dt_bias
+        ssm = spec.ssm
+        mixer = (
+            d * ssm.proj_dim + ssm.d_ssm * d
+            + (ssm.conv + 1) * ssm.conv_dim + ssm.d_ssm + 3 * ssm.heads
+        )
     itemsize = np.dtype(dtype).itemsize if dtype is not None else 2
-    return (attn + mlp + norms) * itemsize
+    return (attn + mlp + norms + mixer) * itemsize
 
 
 def choose_num_blocks(
-    spec, dtype, num_pages: int, page_size: int, memory_fraction: float = 0.8
+    spec, dtype, num_pages: int, page_size: int, memory_fraction: float = 0.8,
+    max_batch: int = 8,
 ) -> int:
     """How many blocks fit in this device's memory, after the KV arena
     (reference Server._choose_num_blocks, server.py:427-477). The CPU
@@ -254,6 +264,14 @@ def choose_num_blocks(
         num_pages * page_size * spec.num_key_value_heads * spec.head_dim
         * 2 * np.dtype(dtype).itemsize
     )  # per layer (k+v)
+    # a family with recurrent state also holds a slot per sequence and layer
+    from bloombee_tpu.kv.arena import state_slot_bytes
+    from bloombee_tpu.kv.cache_manager import state_slots_for
+
+    if spec.ssm is not None:
+        arena_bytes += state_slots_for(
+            spec, num_pages, page_size, max_batch
+        ) * state_slot_bytes(spec.ssm, np.dtype(dtype).itemsize)
     budget = limit * memory_fraction
     n = int(budget // (per_block + arena_bytes))
     return max(1, min(n, spec.num_hidden_layers))

@@ -35,6 +35,7 @@ from bloombee_tpu.kv.cache_manager import (
     CacheManager,
     ParkedKVLost,
     SessionKVLost,
+    state_slots_for,
 )
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.runtime.executor import SpanExecutor, plan_prefill_chunks
@@ -213,6 +214,12 @@ class _Member:
     def rows(self) -> int:
         return int(self.hidden.shape[0]) * int(self.hidden.shape[1])
 
+    @property
+    def state_rows(self) -> int:
+        """Sequences of this member that read and write a recurrent-state
+        slot in the step (0 for a family without a state-space mixer)."""
+        return self.handle.batch_size if self.session.has_state else 0
+
 
 @dataclasses.dataclass
 class _BatchMember(_Member):
@@ -264,6 +271,7 @@ class _Session:
         self.handle = handle
         self.batch_size = batch_size
         self.layers = layers  # relative (l0, l1) within this server's span
+        self.has_state = False  # the family keeps a recurrent-state slot a row
         self.adapter = adapter  # per-request LoRA adapter name (or base)
         # admission-control identity: the client's self-declared id (one
         # per client process) or the session id when an old client sends
@@ -635,6 +643,10 @@ class BlockServer(PromotionLoopMixin):
             start_block=start,
             oversubscribe=oversubscribe,
             prefix_cache=prefix_cache,
+            ssm=spec.ssm,
+            state_slots=state_slots_for(
+                spec, num_pages, page_size, max_batch
+            ),
         )
         self.idle_park_s = idle_park_s
         if oversubscribe > 1.0:
@@ -669,8 +681,9 @@ class BlockServer(PromotionLoopMixin):
             sp_mesh=sp_mesh,
         )
         self.wire_dtype = name_for_dtype(self.executor.transfer_dtype)
-        if spec.heterogeneous or host_layers:
-            # hetero / weight-offloaded spans: no dense training stack
+        if spec.heterogeneous or host_layers or spec.ssm is not None:
+            # hetero / weight-offloaded spans: no dense training stack; a
+            # state-space mixer has no training-mode forward here either
             self.training = None
         else:
             from bloombee_tpu.runtime.training import TrainingExecutor
@@ -1529,6 +1542,11 @@ class BlockServer(PromotionLoopMixin):
                 start_block=start,
                 oversubscribe=self.manager.oversubscribe,
                 prefix_cache=self.manager.prefix_cache,
+                ssm=spec.ssm,
+                state_slots=state_slots_for(
+                    spec, self._num_pages, self.manager.page_size,
+                    self.max_batch,
+                ),
             )
             if self.manager.reclaimer is not None:
                 manager.reclaimer = self._reclaim_idle
@@ -1541,7 +1559,7 @@ class BlockServer(PromotionLoopMixin):
             )
             from bloombee_tpu.runtime.training import TrainingExecutor
 
-            training = TrainingExecutor(
+            training = None if spec.ssm is not None else TrainingExecutor(
                 executor.params, spec, windows=executor.windows,
                 compute_dtype=self.compute_dtype,
             )
@@ -1827,7 +1845,14 @@ class BlockServer(PromotionLoopMixin):
             # fusing on a span that can't run it)
             "ragged_group_dispatches": self.ragged_group_dispatches,
             "ragged_cross_kind_dispatches": self.ragged_cross_kind_dispatches,
-            "ragged_declines": dict(self.ragged_declines),
+            # with what the cache manager refused because a recurrent
+            # state can be kept or zeroed, never cut or copied by pages
+            "ragged_declines": {
+                k: self.ragged_declines.get(k, 0)
+                + self.manager.state_refusals.get(k, 0)
+                for k in {*self.ragged_declines,
+                          *self.manager.state_refusals}
+            },
             # spec-decode observability (batched tree verification):
             # tree-verify steps served, the session rows they carried,
             # drafted vs accepted speculative tokens (from the accept
@@ -1984,7 +2009,8 @@ class BlockServer(PromotionLoopMixin):
         elif not self.manager.repl_supported:
             decline = (
                 "kv replication unsupported (prefix cache off, quantized "
-                "or heterogeneous arena)"
+                "or heterogeneous arena, or recurrent state beside the "
+                "pages)"
             )
         elif int(meta.get("page_size", 0)) != self.manager.page_size:
             decline = "page_size mismatch"
@@ -2478,6 +2504,7 @@ class BlockServer(PromotionLoopMixin):
 
             session = _Session(session_id, handle, batch, layers, adapter,
                                client_id=client_id)
+            session.has_state = self.spec.ssm is not None
             session.opened_at = clock.monotonic()
             session.last_step_at = session.opened_at
             self._sessions[session_id] = session
@@ -3871,6 +3898,8 @@ class BlockServer(PromotionLoopMixin):
             )
         if self.spec.heterogeneous:
             return "heterogeneous head_dim span"
+        if self.spec.ssm is not None:
+            return "recurrent state beside the KV arena"
         if self.executor.host_layers:
             return "span has weight-offloaded layers"
         if self.executor.mesh is not None:
@@ -3983,7 +4012,11 @@ class BlockServer(PromotionLoopMixin):
                     raise DeadlineExpired(
                         "client deadline expired between prefill chunks"
                     )
-                if self.mixed_batch:
+                # a chunk of SEVERAL sequences with recurrent state goes
+                # alone: a ragged pack runs the mixer's chunk form on one
+                if self.mixed_batch and not (
+                    self.spec.ssm is not None and hidden.shape[0] > 1
+                ):
                     # batchable chunk: the worker may fuse this chunk with
                     # queued decode steps — and, with --spec-batch also
                     # on, tree-verify rows — into one ragged dispatch (and
@@ -4129,6 +4162,17 @@ class BlockServer(PromotionLoopMixin):
                     handle, int(prefix_skip or 0)
                 )
             session.adoption_settled = True
+            if commit_lens is not None and self.spec.ssm is not None:
+                full = self.manager.context_lens(handle) + hidden.shape[1]
+                if any(int(c) < int(f) for c, f in zip(commit_lens, full)):
+                    # a ragged replay writes a padded rectangle and commits
+                    # each row shorter: the padding would have fed the state
+                    self.manager._refuse("ragged replay commit")
+                    raise ValueError(
+                        "ragged replay (rows of different lengths in one "
+                        "step) unsupported: a recurrent state cannot be cut "
+                        "back to a row's own length — replay row by row"
+                    )
             if hidden.shape[1] > 1 and tree_mask is None:
                 out = self.executor.prefill(
                     handle, hidden, commit=commit, layers=session.layers,

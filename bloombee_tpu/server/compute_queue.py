@@ -428,6 +428,11 @@ class ComputeQueue:
                 live.append(m)
             if not live:
                 return
+            # sequences that touch a recurrent-state slot in this task (a
+            # family with a state-space mixer; absent from the span else)
+            state_rows = sum(
+                getattr(m.payload, "state_rows", 0) for m in live
+            )
             outcomes = await loop.run_in_executor(
                 self._thread,
                 self._account.wrap(
@@ -438,6 +443,7 @@ class ComputeQueue:
                     task=first.seq, members=len(live),
                     rows=sum(getattr(m.payload, "rows", 1) for m in live),
                     kinds="+".join(sorted({_kind(m.key) for m in live})),
+                    **({"state_rows": state_rows} if state_rows else {}),
                 ),
             )
             if len(outcomes) != len(live):

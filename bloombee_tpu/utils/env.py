@@ -16,6 +16,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import os
+import threading
 
 logger = logging.getLogger(__name__)
 
@@ -52,6 +53,15 @@ def _parse(flag: Flag, raw: str):
         return flag.default
 
 
+# One thread at a time pulls in the declaring modules: a client process that
+# opens its first sessions from several threads at once (one thread a session
+# in cellbench/loadgen.py) otherwise imports one package from two threads,
+# and the loser sees it half-initialised ("cannot import name ... from
+# partially initialized module"). Reentrant: a module imported here may read
+# a switch while it loads.
+_IMPORTING = threading.RLock()
+
+
 def get(name: str):
     """Read a declared flag from the environment (or its default)."""
     flag = _REGISTRY.get(name)
@@ -61,7 +71,8 @@ def get(name: str):
         # declared next to the server-side pool it also controls). Pull
         # in the declaring modules once; only a genuinely unknown name —
         # a typo — still fails loudly after that.
-        import_declaring_modules()
+        with _IMPORTING:
+            import_declaring_modules()
         flag = _REGISTRY[name]
     raw = os.environ.get(flag.name)
     if raw is None:

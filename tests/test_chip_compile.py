@@ -279,3 +279,103 @@ def test_tp4_span_step_compiles_and_shards_for_v5e(v5e):
     )
     per_chip = compiled.memory_analysis().argument_size_in_bytes
     assert per_chip < 0.3 * total, (per_chip, total)
+
+
+# --------------------------------------------------------------- falcon_h1
+# a state-space mixer beside attention: the span steps of the benchmark's
+# cell (cellbench/configs/falcon-h1-34b-span8.json: published widths, 8
+# layers, 1280 pages, 16 state slots) with the recurrent-state arena in the
+# carry, the attention kernels on
+def _falcon_h1_shapes(one_chip):
+    import json
+    import pathlib
+
+    from bloombee_tpu.kv.cache_manager import state_slots_for
+    from bloombee_tpu.models.auto import spec_from_config_dict
+
+    config = json.loads((
+        pathlib.Path(__file__).resolve().parents[1]
+        / "cellbench/configs/falcon-h1-34b-span8.json").read_text())
+    config.pop("cellbench")
+    spec = spec_from_config_dict(config)
+    ssm, layers = spec.ssm, spec.num_hidden_layers
+    d, i, hd = spec.hidden_size, spec.intermediate_size, spec.head_dim
+    h, kv = spec.num_attention_heads, spec.num_key_value_heads
+    f32 = jnp.float32
+
+    def s(shape, dtype=bf16):
+        return jax.ShapeDtypeStruct((layers, *shape), dtype, sharding=one_chip)
+
+    params = {
+        "input_layernorm": s((d,)), "post_attention_layernorm": s((d,)),
+        "q_proj": s((d, h * hd)), "k_proj": s((d, kv * hd)),
+        "v_proj": s((d, kv * hd)), "o_proj": s((h * hd, d)),
+        "gate_proj": s((d, i)), "up_proj": s((d, i)), "down_proj": s((i, d)),
+        "ssm_in_proj": s((d, ssm.proj_dim)), "ssm_out_proj": s((ssm.d_ssm, d)),
+        "ssm_conv_w": s((ssm.conv, ssm.conv_dim)),
+        "ssm_conv_b": s((ssm.conv_dim,)), "ssm_norm": s((ssm.d_ssm,)),
+        "ssm_a_log": s((ssm.heads,), f32), "ssm_d": s((ssm.heads,), f32),
+        "ssm_dt_bias": s((ssm.heads,), f32),
+    }
+    pages = 1280
+    slots = state_slots_for(spec, pages, PAGE, 8)
+    arena = s((pages * PAGE, kv, hd))
+    state = {
+        "ssm": s((slots, ssm.heads, ssm.head_dim, ssm.state), f32),
+        "conv": s((slots, ssm.conv - 1, ssm.conv_dim)),
+    }
+    return spec, params, arena, state
+
+
+def _falcon_h1_payload(spec, rows, plan_len, sharding):
+    return jax.ShapeDtypeStruct(
+        (rows * spec.hidden_size + 2 * plan_len,), jnp.uint16,
+        sharding=sharding)
+
+
+_FALCON_H1_STEPS = {
+    # 4096-token page bucket, as the cell's contexts: a decode group through
+    # the paged kernel (one recurrence step a row), a full chunk through
+    # flash (the SSD chunk form), a tail through the chunk kernel
+    "decode_paged": dict(b=4, t=1, pages=256, use_paged=True),
+    "prefill_flash": dict(b=1, t=128, pages=256, use_flash=True, t_real=128),
+    "tail_paged": dict(b=1, t=64, pages=256, use_paged=True, t_real=44),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_FALCON_H1_STEPS))
+def test_falcon_h1_span_step_compiles_for_v5e(v5e, name):
+    one_chip = SingleDeviceSharding(v5e[0])
+    spec, params, arena, state = _falcon_h1_shapes(one_chip)
+    case = dict(_FALCON_H1_STEPS[name])
+    b, t, pages = case.pop("b"), case.pop("t"), case.pop("pages")
+    layers = spec.num_hidden_layers
+    plan_len = b * t + b * pages + b * t + b + layers + b  # + state slots
+    compiled = span_step_packed.lower(
+        params, arena, arena, _falcon_h1_payload(spec, b * t, plan_len, one_chip),
+        None, None, state,
+        spec=spec, b=b, t=t, page_size=PAGE, max_pages=pages,
+        windows=(0,) * layers, **case,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the state arena comes back: donated in, aliased out
+    assert len(compiled.output_shardings) == 4
+
+
+@pytest.mark.parametrize("r,use_kernel", [(256, False), (64, True)],
+                         ids=["chunk128+decodes", "tail+decodes-kernel"])
+def test_falcon_h1_ragged_step_compiles_for_v5e(v5e, r, use_kernel):
+    """A chunk fused with decode rows: 128 + k rows make the 256 bucket
+    (20 heads x 256 rows is past the ragged kernel's gate: dense attention),
+    a short tail + k rows the 64 bucket (the ragged kernel)."""
+    one_chip = SingleDeviceSharding(v5e[0])
+    spec, params, arena, state = _falcon_h1_shapes(one_chip)
+    n_seqs, pages, layers = 4, 256, spec.num_hidden_layers
+    plan_len = r + n_seqs * pages + r + n_seqs + r + layers + 3 * n_seqs + 1
+    compiled = span_step_ragged.lower(
+        params, arena, arena, _falcon_h1_payload(spec, r, plan_len, one_chip),
+        None, state,
+        spec=spec, r=r, n_seqs=n_seqs, page_size=PAGE, max_pages=pages,
+        windows=(0,) * layers, use_kernel=use_kernel,
+    ).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == use_kernel

@@ -193,3 +193,34 @@ def test_hub_resolve_download_cache_and_lru(tmp_path):
     assert freed > 0
     assert not os.path.exists(d1)  # model-a was stalest
     assert os.path.exists(os.path.join(cache, "org--model-b"))
+
+
+def test_first_flag_read_from_many_threads_at_once():
+    """A pure-client process reads its first switch when it opens its first
+    session, and the benchmark's load generator opens one session a THREAD:
+    the declaring modules are pulled in by one thread while the others wait
+    (two threads importing one package at once: the loser saw it
+    half-initialised, `cannot import name ... from partially initialized
+    module`, and the run had no result)."""
+    import subprocess
+    import sys
+
+    code = (
+        "import threading\n"
+        "from bloombee_tpu.utils import env\n"
+        "n, errors = 8, []\n"
+        "gate = threading.Barrier(n)\n"
+        "def read():\n"
+        "    gate.wait()\n"
+        "    try:\n"
+        "        env.get('BBTPU_PREFIX_CACHE')\n"
+        "    except BaseException as e:\n"
+        "        errors.append(repr(e))\n"
+        "threads = [threading.Thread(target=read) for _ in range(n)]\n"
+        "[t.start() for t in threads]\n"
+        "[t.join() for t in threads]\n"
+        "assert not errors, errors\n"
+    )
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
