@@ -290,6 +290,14 @@ def main(argv=None):
                         line += "  " + " ".join(
                             f"{k}={v}" for k, v in sorted(jit.items())
                         )
+                    # a family with experts: dispatches whose experts took
+                    # the grouped form (a decode group reads only the
+                    # experts its rows chose) or the dense one
+                    moe = probe.get("moe") or {}
+                    if any(moe.values()):
+                        line += "  moe " + " ".join(
+                            f"{k}={v}" for k, v in sorted(moe.items())
+                        )
                     # where the host's time went (BBTPU_JITWATCH=1
                     # runs): the compute worker's wall time by cause —
                     # starved = no task existed, hop = one was queued and

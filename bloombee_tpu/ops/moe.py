@@ -1,11 +1,31 @@
-"""Mixture-of-experts MLP (Mixtral family).
+"""Mixture-of-experts MLP: rows go to their top-k experts by index.
 
-The reference runs all 8 experts densely inside one HF block with NO expert
-parallelism (SURVEY.md section 2.3 Mixtral row, 2.8: "EP is absent"). Here
-the experts are stacked weight tensors so the whole MoE layer is a few
-einsums — dense over experts, masked by top-k router weights — which tiles
-onto the MXU, and the expert dimension shards over the mesh for real expert
-parallelism (bloombee_tpu/parallel/spmd.py psums the partial outputs).
+The router gives each row exactly `top_k` expert indices and their weights
+(`route_topk`: Mixtral's form masks to the top-k logits and takes the softmax
+over them; Qwen3-MoE's takes the softmax over all experts, picks the top-k
+and renormalises). The experts are stacked weight tensors `[E, D, I]` /
+`[E, I, D]`, the loader's layout, and a step computes them in one of two
+forms, chosen at trace time from the shape it is compiled for:
+
+- `rows * top_k < E` (a decode group) and Pallas kernels may run in this
+  program: the GROUPED form. The experts some row chose are listed in index
+  order and ops/pallas/grouped_experts.py walks the list over the stacks
+  where they lie, so an expert nobody chose is never read. A row whose input
+  is exactly zero (a bucket's padding row) has a zero output whichever
+  experts it goes to, so it adds none to the list.
+- otherwise (the 128-row prefill chunk and the fused ragged pack, where the
+  rows hit every expert anyway; any program under a mesh or off the TPU,
+  where the kernel cannot run): the DENSE form, three einsums over all
+  experts. The router's `[rows, E]` weights, zero off the top-k, scale the
+  gated activations, so the down projection is ONE contraction over (expert,
+  intermediate) that reads `[E, I, D]` as it lies; per-expert outputs
+  `[rows, E, D]` weighted afterwards made the compiler re-lay-out the whole
+  stack every run. It tiles onto the MXU, and the expert dimension shards
+  over a mesh for real expert parallelism (bloombee_tpu/parallel/spmd.py
+  psums the partial outputs).
+
+Both compute every chosen (row, expert) pair, drop none, accumulate in
+float32 on the MXU and differ only in the order of a row's top_k-term sum.
 """
 
 from __future__ import annotations
@@ -14,31 +34,75 @@ import jax
 import jax.numpy as jnp
 
 
+def route_topk(
+    logits: jax.Array,  # [..., E]
+    top_k: int,
+    pre_softmax: bool = False,
+    norm_topk: bool = False,
+) -> tuple[jax.Array, jax.Array]:
+    """Per row exactly `top_k` expert indices [..., k] and their float32
+    weights [..., k]; a tie goes to the lower index (`lax.top_k`), as in the
+    published implementations.
+
+    pre_softmax=False: HF Mixtral semantics — the top-k logits, then softmax
+    over them. pre_softmax=True: HF Qwen3-MoE semantics — softmax over ALL
+    experts, select top-k, renormalize iff norm_topk."""
+    if pre_softmax:
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        weights, idx = jax.lax.top_k(probs, top_k)
+        if norm_topk:
+            weights = weights / jnp.maximum(
+                weights.sum(axis=-1, keepdims=True), 1e-20
+            )
+        return idx, weights
+    top_vals, idx = jax.lax.top_k(logits, top_k)
+    return idx, jax.nn.softmax(top_vals.astype(jnp.float32), axis=-1)
+
+
+def _spread(idx: jax.Array, weights: jax.Array, num_experts: int) -> jax.Array:
+    """[..., k] indices and weights -> [..., E] weights, zero off the k."""
+    chose = idx[..., None] == jnp.arange(num_experts, dtype=idx.dtype)
+    return jnp.where(chose, weights[..., None], 0.0).sum(axis=-2)
+
+
 def router_topk_weights(
     logits: jax.Array,  # [B, T, E]
     top_k: int,
     pre_softmax: bool = False,
     norm_topk: bool = False,
 ) -> jax.Array:
-    """Top-k router weights, zero off the selected experts.
+    """`route_topk` spread over the expert axis: [B, T, E], zero off the
+    `top_k` selected experts."""
+    idx, weights = route_topk(logits, top_k, pre_softmax, norm_topk)
+    return _spread(idx, weights, logits.shape[-1]).astype(logits.dtype)
 
-    pre_softmax=False: HF Mixtral semantics — mask to the top-k logits,
-    then softmax over them. pre_softmax=True: HF Qwen3-MoE semantics —
-    softmax over ALL experts, select top-k, renormalize iff norm_topk."""
-    if pre_softmax:
-        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-        top_vals, _ = jax.lax.top_k(probs, top_k)
-        kept = jnp.where(probs >= top_vals[..., -1:], probs, 0.0)
-        if norm_topk:
-            kept = kept / jnp.maximum(
-                kept.sum(axis=-1, keepdims=True), 1e-20
-            )
-        return kept.astype(logits.dtype)
-    top_vals, _ = jax.lax.top_k(logits, top_k)
-    thresh = top_vals[..., -1:]
-    neg = jnp.finfo(jnp.float32).min
-    masked = jnp.where(logits >= thresh, logits.astype(jnp.float32), neg)
-    return jax.nn.softmax(masked, axis=-1).astype(logits.dtype)  # [B, T, E]
+
+def takes_grouped_form(rows: int, top_k: int, num_experts: int) -> bool:
+    """Can a step of `rows` rows hit fewer than all experts?"""
+    return rows * top_k < num_experts
+
+
+def _chosen_experts(x, idx, weights, num_experts: int):
+    """The grouped form's plan from the router's per-row choices: the experts
+    some LIVE row chose, ascending ([P], padded with the last one), their
+    count, and each slot's per-row weights [P, R]."""
+    r, k = idx.shape
+    live_row = jnp.any(x != 0, axis=-1)  # a zero row's output is zero
+    chose = (
+        idx[..., None] == jnp.arange(num_experts, dtype=idx.dtype)
+    ) & live_row[:, None, None]  # [R, k, E]
+    row_weights = jnp.where(chose, weights[..., None], 0.0).sum(axis=1)
+    hit = chose.any(axis=(0, 1))  # [E]
+    n = hit.sum().astype(jnp.int32)
+    (listed,) = jnp.nonzero(hit, size=r * k, fill_value=0)
+    slot = jnp.arange(r * k, dtype=jnp.int32)
+    slot_expert = jnp.where(
+        slot < n, listed, listed[jnp.maximum(n - 1, 0)]
+    ).astype(jnp.int32)
+    slot_weights = jnp.where(
+        (slot < n)[:, None], row_weights.T[slot_expert], 0.0
+    )
+    return slot_expert, n, slot_weights
 
 
 def moe_mlp(
@@ -51,22 +115,54 @@ def moe_mlp(
     router_weights: jax.Array | None = None,  # precomputed [B, T, E]
     pre_softmax: bool = False,
     norm_topk: bool = False,
+    expert_base: jax.Array | None = None,  # i32 scalar: take the GROUPED
+    # form; gate/up/down may then hold several layers' experts [N, D, I],
+    # this layer's E starting at that row
+    interpret: bool = False,
 ) -> jax.Array:
-    """Dense-over-experts gated MLP weighted by top-k router probabilities.
+    """Gated expert MLPs weighted by the top-k router weights, grouped by
+    chosen expert or dense over all of them (module docstring). The caller
+    picks the form (`takes_grouped_form`, and whether the kernel can run).
 
     When experts are sharded, pass `router_weights` computed from the full
     router and slice gate/up/down to the local experts; sum partial outputs
-    with psum outside.
+    with psum outside. That takes the dense form.
     """
+    b, t, d = x.shape
+    grouped = expert_base is not None
+    num_experts = router_w.shape[-1] if grouped else gate_w.shape[0]
     if router_weights is None:
         with jax.named_scope("moe_router"):
-            logits = x @ router_w
-            router_weights = router_topk_weights(
-                logits, top_k, pre_softmax=pre_softmax, norm_topk=norm_topk
+            idx, weights = route_topk(
+                x @ router_w, top_k, pre_softmax=pre_softmax,
+                norm_topk=norm_topk,
             )
+            if grouped:
+                rows = x.reshape(b * t, d)
+                plan = _chosen_experts(
+                    rows, idx.reshape(b * t, top_k),
+                    weights.reshape(b * t, top_k), num_experts,
+                )
+            else:
+                router_weights = _spread(idx, weights, num_experts).astype(
+                    x.dtype
+                )
     with jax.named_scope("moe_experts"):
+        if grouped:
+            from bloombee_tpu.ops.pallas.grouped_experts import (
+                grouped_experts,
+            )
+
+            slot_expert, live, slot_weights = plan
+            out = grouped_experts(
+                rows, slot_expert + expert_base, live, slot_weights,
+                gate_w, up_w, down_w, interpret=interpret,
+            )
+            return out.astype(x.dtype).reshape(b, t, d)
         g = jnp.einsum("btd,edi->btei", x, gate_w)
         u = jnp.einsum("btd,edi->btei", x, up_w)
-        h = jax.nn.silu(g) * u
-        out = jnp.einsum("btei,eid->bted", h, down_w)
-        return jnp.einsum("bted,bte->btd", out, router_weights)
+        # the router's weight goes in before the down projection (linear, so
+        # the same sum): one contraction over (expert, intermediate), the
+        # stack read as it lies, and no [B, T, E, D] partial outputs
+        h = jax.nn.silu(g) * u * router_weights[..., None]
+        return jnp.einsum("btei,eid->btd", h, down_w)

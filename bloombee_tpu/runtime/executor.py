@@ -23,6 +23,7 @@ import ml_dtypes
 from bloombee_tpu.kv.cache_manager import CacheHandle, CacheManager
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.runtime.step import (
+    experts_grouped,
     pack_plan,
     pack_ragged_plan,
     pack_ragged_ssm_tail,
@@ -307,6 +308,9 @@ class SpanExecutor:
                                 "dense": 0}
         self.kernel_fallbacks = 0
         self._paged_broken = False
+        # a family with experts: device dispatches by the form its experts
+        # took (ops/moe.py), counted from the bucket's rows
+        self.moe_dispatches = {"grouped": 0, "dense": 0}
 
     # ------------------------------------------------------------------ steps
     def prefill(
@@ -847,6 +851,7 @@ class SpanExecutor:
             _run, use_kernel, arena, "ragged group step"
         )
         self.attn_dispatches["ragged" if used_kernel else "dense"] += 1
+        self._count_moe(rb, used_kernel)
         out = self._keep_arena(result)
         with jitwatch.span("bbtpu.slice"):
             return out[0, :r], combined
@@ -996,6 +1001,7 @@ class SpanExecutor:
             _run, use_paged, arena, "decode_n"
         )
         self.attn_dispatches["paged" if used_paged else "dense"] += 1
+        self._count_moe(bb, used_paged)
         self.manager.arena = {"k": new_k, "v": new_v}
         with jitwatch.span("bbtpu.slice"):
             return toks[:b, :n]
@@ -1041,6 +1047,11 @@ class SpanExecutor:
         slots = np.full((bucket,), self.manager.num_state_slots, np.int32)
         slots[: handle.batch_size] = self.manager.state_slots(handle)
         return slots
+
+    def _count_moe(self, rows: int, kernels: bool) -> None:
+        if self.spec.num_experts:
+            grouped = experts_grouped(self.spec, self.params, rows, kernels)
+            self.moe_dispatches["grouped" if grouped else "dense"] += 1
 
     @staticmethod
     def _arena_consumed(arena) -> bool:
@@ -1414,6 +1425,7 @@ class SpanExecutor:
             out = self._keep_arena(result)
         path = "paged" if use_paged else "flash" if use_flash else "dense"
         self.attn_dispatches[path] += 1
+        self._count_moe(bb * tb, use_paged and not self.spec.heterogeneous)
         with jitwatch.span("bbtpu.slice"):
             out = out[:b, :t]
         if not fetch:

@@ -103,6 +103,10 @@ def _mlp(x, params, spec, lora=None):
             spec.num_experts_per_tok,
             pre_softmax=spec.moe_pre_softmax,
             norm_topk=spec.moe_norm_topk,
+            # the step lifted the stacks out of the scan (runtime/step.py
+            # `lift_expert_stacks`): the grouped form, by index
+            expert_base=params.get("expert_base"),
+            interpret=env.get("BBTPU_PAGED_INTERPRET"),
         )
     with jax.named_scope("mlp"):
         return _dense_mlp(x, params, spec, lora)

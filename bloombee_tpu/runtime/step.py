@@ -50,6 +50,7 @@ from bloombee_tpu.kv.arena import (
     stacked_arena,
 )
 from bloombee_tpu.models.spec import ModelSpec
+from bloombee_tpu.ops.moe import takes_grouped_form
 from bloombee_tpu.ops.rotary import rotary_cos_sin
 from bloombee_tpu.runtime.layer_body import (
     SsmRows,
@@ -201,6 +202,53 @@ def _rope_by_window(spec: ModelSpec, q_positions: jax.Array, dtype):
     return pick
 
 
+EXPERT_STACKS = ("experts_gate", "experts_up", "experts_down")
+
+
+def experts_grouped(
+    spec: ModelSpec, stacked_params: dict, rows: int, kernels: bool
+) -> bool:
+    """Does a step of `rows` rows take the experts' grouped form
+    (ops/moe.py)? Fewer rows than can hit every expert, a program in which
+    Pallas kernels may run, and stacks the kernel can read as they lie (a
+    quantised stack is dequantised a layer at a time and stays dense)."""
+    return bool(
+        spec.num_experts
+        and kernels
+        and takes_grouped_form(rows, spec.num_experts_per_tok, spec.num_experts)
+        and all(
+            isinstance(stacked_params.get(k), jax.Array)
+            for k in EXPERT_STACKS
+        )
+    )
+
+
+def lift_expert_stacks(
+    spec: ModelSpec, stacked_params: dict, rows: int, kernels: bool
+):
+    """(the params that ride the scan as xs, the expert stacks held WHOLE or
+    None).
+
+    The grouped form walks the chosen experts over the stacks where they lie,
+    so the stacks must not ride the scan: a layer's [E, D, I] slice of xs
+    handed to a kernel is a copy of it (1.2 GB a layer at 128 experts). Like
+    the arena they are viewed flat over (layer, expert), closed over by the
+    layer, and the scan carries only `expert_base`, the row where layer l's
+    experts start. Every other step (the rows hit all experts anyway, no
+    kernel may run, quantised stacks, a family without experts) gets its
+    params back as they came and traces what it traced before."""
+    if not experts_grouped(spec, stacked_params, rows, kernels):
+        return stacked_params, None
+    xs = {k: w for k, w in stacked_params.items() if k not in EXPERT_STACKS}
+    n = stacked_params[EXPERT_STACKS[0]].shape[0]
+    xs["expert_base"] = jnp.arange(n, dtype=jnp.int32) * spec.num_experts
+    whole = {
+        k: stacked_params[k].reshape(-1, *stacked_params[k].shape[2:])
+        for k in EXPERT_STACKS
+    }
+    return xs, whole
+
+
 def _scan_layers(
     run_layer,  # (h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l) ->
     # (h, k, v) or, with a state arena, (h, k, v, state)
@@ -309,6 +357,9 @@ def span_step_impl(
     # resident prefix in weight-offload mode (the offloaded layers get their
     # own layer_step calls with host-streamed weights)
     n = jax.tree.leaves(stacked_params)[0].shape[0]
+    stacked_params, experts = lift_expert_stacks(
+        spec, stacked_params, b * t, use_paged
+    )
     slots, page_table, q_positions, total_lens, layer_active = unpack_plan(
         plan, b, t, max_pages, n
     )
@@ -326,6 +377,8 @@ def span_step_impl(
 
     def run_layer(h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l):
         params_l, window_l, prompt_l, lora_l = xs_l
+        if experts is not None:
+            params_l = {**params_l, **experts}
         if prompt_l is not None:
             p = prompt_l.shape[0]
             h = h.at[:, :p].add(prompt_l[None].astype(h.dtype))
@@ -455,6 +508,9 @@ def span_step_ragged_impl(
     host-side)."""
     hidden, plan = unpack_step_payload(payload, 1, r, spec.hidden_size)
     num_layers = arena_k.shape[0]
+    stacked_params, experts = lift_expert_stacks(
+        spec, stacked_params, r, use_kernel
+    )
     (
         slots, page_table, q_positions, total_lens, q_seq, layer_active,
         nt, tree_rows,
@@ -478,6 +534,8 @@ def span_step_ragged_impl(
 
     def run_layer(h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l):
         params_l, window_l, lora_l = xs_l
+        if experts is not None:
+            params_l = {**params_l, **experts}
         return layer_body_ragged(
             spec, page_size, h, params_l, k_flat, v_flat, *rope(window_l),
             slots_l, pages_l, q_positions, total_lens, q_seq,

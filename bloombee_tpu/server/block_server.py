@@ -1928,6 +1928,15 @@ class BlockServer(PromotionLoopMixin):
             # kernel bug, never a normal condition
             "attn_dispatches": dict(self.executor.attn_dispatches),
             "kernel_fallbacks": self.executor.kernel_fallbacks,
+            # a family with experts: dispatches by the form the experts
+            # took, grouped by chosen expert or dense over all (ops/moe.py)
+            **(
+                {"moe": {
+                    f"{form}_dispatches": n
+                    for form, n in self.executor.moe_dispatches.items()
+                }}
+                if self.spec.num_experts else {}
+            ),
             "artifact_preinstalled": self._artifacts_preinstalled,
             "artifact_fallback_compiles": self.artifact_fallback_compiles,
             "artifact_gets_served": self.artifact_gets_served,

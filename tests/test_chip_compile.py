@@ -39,6 +39,9 @@ from bloombee_tpu.models.spec import ModelSpec  # noqa: E402
 from bloombee_tpu.ops.pallas.flash_attention import (  # noqa: E402
     flash_attention,
 )
+from bloombee_tpu.ops.pallas.grouped_experts import (  # noqa: E402
+    grouped_experts,
+)
 from bloombee_tpu.ops.pallas.paged_attention import (  # noqa: E402
     paged_chunk_attention,
     paged_decode_attention,
@@ -156,6 +159,19 @@ def _kernel_cases():
             ),
             rows + [((B,), i32), ((r, 16), i32)],
         )
+    # a decode group's experts by index: 2 rows x top-8 of Qwen3-30B-A3B's
+    # 128 experts over a 4-layer stack (one [D, I] block an expert), and 3
+    # rows x top-2 at Mixtral-8x7B's widths (the intermediate dim in tiles)
+    for name, (layers_experts, d, i, rows, slots) in {
+        "grouped_experts_qwen3": (4 * 128, 2048, 768, 2, 16),
+        "grouped_experts_mixtral": (8, 4096, 14336, 3, 6),
+    }.items():
+        cases[name] = (
+            grouped_experts,
+            [((rows, d), bf16), ((slots,), i32), ((), i32),
+             ((slots, rows), jnp.float32), ((layers_experts, d, i), bf16),
+             ((layers_experts, d, i), bf16), ((layers_experts, i, d), bf16)],
+        )
     return cases
 
 
@@ -233,6 +249,44 @@ def test_span_step_compiles_for_v5e(v5e, name):
         windows=(0,) * LAYERS, **case,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("b", [1, 2, 8])
+def test_moe_decode_step_reads_the_expert_stacks_where_they_lie(v5e, b):
+    """A decode group of a 4-layer span at Qwen3-30B-A3B's widths: the
+    grouped kernel beside the paged one, and no temporary the size of a
+    layer's expert stack (402 MB): the stacks do not ride the scan."""
+    one_chip = SingleDeviceSharding(v5e[0])
+    layers, d, e, i, heads = 4, 2048, 128, 768, 32
+    spec = ModelSpec(
+        family="qwen3_moe", hidden_size=d, intermediate_size=i,
+        num_attention_heads=heads, num_key_value_heads=4, head_dim=128,
+        num_hidden_layers=layers, vocab_size=151936, rope_theta=1e6,
+        qk_norm=True, num_experts=e, num_experts_per_tok=8,
+        moe_pre_softmax=True, moe_norm_topk=True,
+    )
+    s = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        (layers, *shape), bf16, sharding=one_chip)
+    params = {
+        "input_layernorm": s(d), "post_attention_layernorm": s(d),
+        "q_proj": s(d, heads * 128), "k_proj": s(d, 512),
+        "v_proj": s(d, 512), "o_proj": s(heads * 128, d),
+        "q_norm": s(128), "k_norm": s(128), "router": s(d, e),
+        "experts_gate": s(e, d, i), "experts_up": s(e, d, i),
+        "experts_down": s(e, i, d),
+    }
+    arena = s(S_TOT, 4, 128)
+    pages = 256
+    plan_len = b + b * pages + b + b + layers
+    payload = jax.ShapeDtypeStruct(
+        (b * d + 2 * plan_len,), jnp.uint16, sharding=one_chip)
+    compiled = span_step_packed.lower(
+        params, arena, arena, payload, None, None,
+        spec=spec, b=b, t=1, page_size=PAGE, max_pages=pages,
+        windows=(0,) * layers, use_paged=True,
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 200e6
 
 
 @pytest.mark.parametrize("tree", [False, True], ids=["causal", "tree"])
