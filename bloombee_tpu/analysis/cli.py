@@ -22,7 +22,7 @@ from bloombee_tpu.analysis.core import (
 )
 from bloombee_tpu.analysis.rules import make_rules
 
-DEFAULT_PATHS = ["bloombee_tpu", "bench.py"]
+DEFAULT_PATHS = ["bloombee_tpu"]
 ENV_TABLE_BEGIN = "<!-- bbtpu-env-table:begin -->"
 ENV_TABLE_END = "<!-- bbtpu-env-table:end -->"
 LOCK_TABLE_BEGIN = "<!-- bbtpu-lock-table:begin -->"

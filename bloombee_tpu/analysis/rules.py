@@ -93,7 +93,7 @@ class SpeculativeWriteRule(Rule):
     )
 
     # These mutate KV speculatively no matter how they're called.
-    ALWAYS = {"append_speculative", "decode_group", "mixed_group"}
+    ALWAYS = {"append_speculative", "decode_group"}
     # These are speculative only when explicitly called commit=False
     # (a literal False keyword; `commit=commit` pass-through is the
     # callee's own contract and stays quiet).
@@ -541,8 +541,8 @@ class EnvRegistryRule(Rule):
 
     The registry is what makes `cli/health --switches` and the README
     table authoritative; a raw read is an undocumented switch with no
-    type coercion and no default in one place. Raw WRITES (tests and
-    bench save/set/restore) are out of scope.
+    type coercion and no default in one place. Raw WRITES (tests'
+    save/set/restore) are out of scope.
     """
 
     code = "BB005"
@@ -772,8 +772,8 @@ class RawClockRule(Rule):
     of those names (they escape as callbacks). ``time.perf_counter()``
     stays legal: duration *measurement* (throughput, codec timing) must
     read real hardware time even under a virtual clock — but it must
-    never feed a deadline. Out-of-package harnesses (bench.py, scripts)
-    keep real time and are out of scope.
+    never feed a deadline. Harnesses outside the package (chip_smoke.py,
+    scripts/) keep real time and are out of scope.
     """
 
     code = "BB008"
@@ -863,8 +863,8 @@ class AsyncBlockingRule(Rule):
       the deeper search is worth its false-positive risk there, and
       only there.
 
-    Out-of-package harnesses (bench.py, scripts/) keep their blocking
-    I/O and are out of scope, like BB008.
+    Harnesses outside the package (chip_smoke.py, scripts/) keep their
+    blocking I/O and are out of scope, like BB008.
     """
 
     code = "BB009"
@@ -1209,10 +1209,7 @@ class HotPathHostSyncRule(Rule):
     name = "hot-path-host-sync"
     summary = "implicit device->host sync reachable from a decode hot path"
 
-    HOT_ROOTS = {
-        "decode_group", "mixed_group", "tree_group", "prefill_chunk",
-        "_run_step",
-    }
+    HOT_ROOTS = {"decode_group", "prefill_chunk", "_run_step"}
     ALWAYS_SYNC_ATTRS = {"item", "block_until_ready", "device_get"}
     CAST_NAMES = {"float", "int", "bool"}
     NP_ALIASES = {"np", "numpy", "onp"}

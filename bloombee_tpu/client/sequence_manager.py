@@ -125,7 +125,7 @@ class RemoteSequenceManager:
         self._quarantine: dict[str, _BanState] = {}
         self._quarantine_history: dict[str, int] = {}  # strikes survive readmit
         self._integrity_strikes: dict[str, int] = {}
-        self.peers_quarantined = 0  # counter: quarantine events (bench/health)
+        self.peers_quarantined = 0  # counter: quarantine events
         self._last_update = 0.0
         self._rng = rng or random.Random()
         # measured client->server RTTs (reference ping.py PingAggregator);

@@ -65,7 +65,7 @@ env.declare(
 )
 
 # the first no-embed_fn decode_n session in the process warns loudly; later
-# sessions demote to DEBUG (a bench tail spawning many raw sessions would
+# sessions demote to DEBUG (a client spawning many raw sessions would
 # otherwise repeat the identical warning once per session)
 _warned_no_embed_process = False
 
@@ -185,7 +185,7 @@ class InferenceSession:
             else bool(integrity)
         ) or self.audit_p > 0
         self._gate = SanityGate() if self.integrity else None
-        # integrity observability (bench + tests read these)
+        # integrity observability
         self.sanity_rejects = 0
         self.audits_run = 0
         self.audit_mismatches = 0
@@ -1190,49 +1190,6 @@ class InferenceSession:
         )
         return out
 
-    def timing_summary(self) -> dict:
-        """Aggregate decode-step timing: mean per-span compute vs wire+other
-        (the client-side view of the reference's paper timing tables)."""
-        decode = [t for t in self.timings if t["tokens"] == 1]
-        rows = decode or self.timings
-        if not rows:
-            return {}
-        n_spans = max(len(t["span_compute_ms"]) for t in rows)
-        per_span = [
-            float(
-                np.mean(
-                    [
-                        t["span_compute_ms"][i]
-                        for t in rows
-                        if len(t["span_compute_ms"]) > i
-                        and t["span_compute_ms"][i] is not None
-                    ]
-                    or [0.0]
-                )
-            )
-            for i in range(n_spans)
-        ]
-        total = float(np.mean([t["total_ms"] for t in rows]))
-        compute = float(np.sum(per_span))
-        from bloombee_tpu.wire.tensor_codec import transport_stats
-
-        return {
-            "steps": len(rows),
-            "mean_total_ms": total,
-            "mean_compute_ms_per_span": per_span,
-            "mean_wire_and_overhead_ms": total - compute,
-            # process-wide codec counters (the reference transport
-            # profiling channels' client half)
-            "transport": transport_stats(),
-            # per-span off-loop pipeline counters (wire/pipeline.py): the
-            # client half of the codec scheduling the servers report via
-            # rpc_info["wire_pipeline"]
-            "wire_pipeline": [
-                s.conn.pipeline.stats() for s in self._spans
-                if s.conn is not None
-            ],
-        }
-
     async def decode_n(
         self,
         ids: np.ndarray,  # [B] int: input token of the first step
@@ -1267,8 +1224,8 @@ class InferenceSession:
             # transient transport failure becomes a hard RuntimeError in
             # _recover instead of a transparent re-route (fail-loud is
             # intentional; the warning makes the trade visible up front).
-            # WARNING once per process, DEBUG for later sessions — a bench
-            # tail spawning many raw sessions repeats the identical line
+            # WARNING once per process, DEBUG for later sessions — a client
+            # spawning many raw sessions repeats the identical line
             global _warned_no_embed_process
             self._warned_no_embed = True
             log = (

@@ -7,7 +7,7 @@ the same property into a trie; a chained flat list is equivalent for the
 page-granular pool in kv/paged.py and is trivially wire-serializable).
 
 Shared by the client (hash computation over the prompt), the server
-(pool lookup + adoption), the bench, and the tests — one definition so a
+(pool lookup + adoption) and the tests — one definition so a
 version skew shows up as a clean cache miss, never a wrong hit.
 """
 

@@ -561,54 +561,6 @@ class SpanExecutor:
             return "recurrent state (tree rows would branch it)"
         return None
 
-    def mixed_unsupported(self) -> str | None:
-        """PR-8 surface: why causal (decode + chunk) ragged dispatch is
-        unavailable. Thin delegation onto the unified gate."""
-        return self.ragged_unsupported(has_tree=False)
-
-    def tree_group_unsupported(self) -> str | None:
-        """PR-10 surface: why tree-verify rows can't join a ragged
-        dispatch. Thin delegation onto the unified gate."""
-        return self.ragged_unsupported(has_tree=True)
-
-    def mixed_group(
-        self,
-        handles: list[CacheHandle],
-        hiddens: list[np.ndarray],  # per-member [b_i, t_i, D], same dtype
-        layers: tuple[int, int] | None = None,
-        adapter: str | None = None,
-    ):
-        """Causal ragged dispatch (N single-token decodes plus one
-        multi-token prefill chunk — the Sarathi-Serve fused iteration).
-        Thin delegation onto `ragged_group`; kept as the PR-8 call
-        surface."""
-        reason = self.mixed_unsupported()
-        if reason is not None:
-            raise ValueError(f"mixed_group unsupported: {reason}")
-        return self.ragged_group(
-            handles, hiddens, layers=layers, adapter=adapter
-        )
-
-    def tree_group(
-        self,
-        handles: list[CacheHandle],
-        hiddens: list[np.ndarray],  # per-member [b_i, t_i, D], same dtype
-        tree_masks: list[np.ndarray],  # per-member [b_i, t_i, t_i] bool
-        depths_list: list[np.ndarray],  # per-member [b_i, t_i] i32
-        layers: tuple[int, int] | None = None,
-        adapter: str | None = None,
-    ):
-        """Tree-verify ragged dispatch (N sessions' linearized speculative
-        trees verified as ONE span step). Thin delegation onto
-        `ragged_group`; kept as the PR-10 call surface."""
-        reason = self.tree_group_unsupported()
-        if reason is not None:
-            raise ValueError(f"tree_group unsupported: {reason}")
-        return self.ragged_group(
-            handles, hiddens, tree_masks=tree_masks,
-            depths_list=depths_list, layers=layers, adapter=adapter,
-        )
-
     def ragged_group(
         self,
         handles: list[CacheHandle],
@@ -799,21 +751,16 @@ class SpanExecutor:
                     counts_pad, multi[0],
                 )])
 
-            # ragged-kernel eligibility mirrors _step's chunk gate: dense
-            # arena, [R*H, hd] VMEM budget, contexts past the paged crossover,
+            # ragged-kernel eligibility: what every paged kernel needs
+            # (_paged_kernel_ok), a dense arena, the [R*H, hd] VMEM budget,
             # single-chip (Pallas kernels don't GSPMD-partition — TP-mesh
             # spans run the dense attend_ragged path). Ineligible configs run
             # attend_ragged — still ONE dispatch.
             use_kernel = bool(
-                not self._paged_broken
+                self._paged_kernel_ok(pb * self.page_size)
                 and self.mesh is None
                 and self.manager.quant is None
                 and rb * spec.num_attention_heads <= 2048
-                and pb * self.page_size >= env.get("BBTPU_PAGED_MIN_CONTEXT")
-                and not spec.alibi
-                and not spec.attn_logit_softcap
-                and env.get("BBTPU_PAGED_ATTENTION")
-                and _kernels_available("BBTPU_PAGED_INTERPRET")
             )
 
             payload = pack_step_payload(h_pad, plan)
@@ -959,15 +906,7 @@ class SpanExecutor:
                 ),
                 arena_tokens // self.page_size,
             )
-            use_paged = bool(
-                not self._paged_broken
-                and pb_start * self.page_size
-                >= env.get("BBTPU_PAGED_MIN_CONTEXT")
-                and not spec.alibi
-                and not spec.attn_logit_softcap
-                and env.get("BBTPU_PAGED_ATTENTION")
-                and _kernels_available("BBTPU_PAGED_INTERPRET")
-            )
+            use_paged = self._paged_kernel_ok(pb_start * self.page_size)
             ids_pad = np.zeros((bb,), np.int32)
             ids_pad[:b] = np.asarray(ids).reshape(-1)
             fin_pad = np.ones((bb,), bool)  # padding rows never select real ids
@@ -1058,6 +997,23 @@ class SpanExecutor:
         return any(
             getattr(a, "is_deleted", lambda: False)()
             for a in jax.tree.leaves(arena)
+        )
+
+    def _paged_kernel_ok(self, context_tokens: int) -> bool:
+        """What every use of a paged Pallas kernel needs, whatever the step:
+        no kernel has failed on this device, the model's attention is one
+        the kernels compute (no ALiBi, no logit soft-cap), the context
+        bucket is past the crossover below which the dense gather is
+        cheaper, and the kernels are switched on and can run here. Each
+        caller adds what its own step needs (mesh, arena quantisation, row
+        budget, sparsity)."""
+        return bool(
+            not self._paged_broken
+            and context_tokens >= env.get("BBTPU_PAGED_MIN_CONTEXT")
+            and not self.spec.alibi
+            and not self.spec.attn_logit_softcap
+            and env.get("BBTPU_PAGED_ATTENTION")
+            and _kernels_available("BBTPU_PAGED_INTERPRET")
         )
 
     def _dispatch(self, run, use_kernel: bool, arena, where: str):
@@ -1294,16 +1250,11 @@ class SpanExecutor:
                 and (tree_mask is None or all(w == 0 for w in self.windows))
             )
             use_paged = bool(
-                not self._paged_broken
+                self._paged_kernel_ok(pb * self.page_size)
                 and self.attn_sparsity >= 1.0  # kernel has no top-k path
-                and pb * self.page_size >= env.get("BBTPU_PAGED_MIN_CONTEXT")
                 and self.mesh is None  # Pallas kernels don't GSPMD-partition
                 and not self.spec.heterogeneous
                 and (t1_ok or chunk_ok)
-                and not self.spec.alibi
-                and not self.spec.attn_logit_softcap
-                and env.get("BBTPU_PAGED_ATTENTION")
-                and _kernels_available("BBTPU_PAGED_INTERPRET")
             )
 
             # flash eligibility: per-row starts/lens ride into the kernel as

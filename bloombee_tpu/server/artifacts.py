@@ -125,7 +125,7 @@ def fingerprint_compatible(mine: dict, theirs: dict) -> str | None:
 
 # where the compile cache lives when nothing outside places it: one fixed,
 # git-ignored directory in the checkout, so every run of run_server /
-# bench.py / chip_smoke.py from that checkout finds the last run's entries
+# chip_smoke.py from that checkout finds the last run's entries
 DEFAULT_COMPILE_CACHE_DIR = str(
     pathlib.Path(__file__).resolve().parents[2] / ".cache" / "xla"
 )

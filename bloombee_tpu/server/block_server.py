@@ -189,7 +189,7 @@ env.declare(
 env.declare(
     "BBTPU_LIAR_SEED", int, 0,
     "seed for the BBTPU_LIAR_P perturbation RNG (which steps lie and "
-    "how), so integrity chaos/bench runs are reproducible",
+    "how), so integrity chaos runs are reproducible",
 )
 
 
@@ -251,7 +251,7 @@ class _ChunkMember(_Member):
 class _TreeMember(_Member):
     """One session's tree-verify step inside a batched ragged dispatch
     (--spec-batch): the linearized draft tree's rows verify alongside
-    other sessions' trees in one executor.tree_group call. `handle` may be
+    other sessions' trees in one executor.ragged_group call. `handle` may be
     a row slice of the session handle (the client shrinks the step to its
     live-row window as rows finish)."""
 
@@ -708,7 +708,7 @@ class BlockServer(PromotionLoopMixin):
         if mixed_batch is None:
             mixed_batch = bool(env.get("BBTPU_MIXED_BATCH"))
         if mixed_batch:
-            reason = self.executor.mixed_unsupported()
+            reason = self.executor.ragged_unsupported(has_tree=False)
             if reason is not None:
                 logger.info(
                     "mixed-batch dispatch disabled: %s", reason
@@ -721,7 +721,7 @@ class BlockServer(PromotionLoopMixin):
         if spec_batch is None:
             spec_batch = bool(env.get("BBTPU_SPEC_BATCH"))
         if spec_batch:
-            reason = self.executor.tree_group_unsupported()
+            reason = self.executor.ragged_unsupported(has_tree=True)
             if reason is not None:
                 logger.info(
                     "batched tree verification disabled: %s", reason
@@ -4373,12 +4373,6 @@ class BlockServer(PromotionLoopMixin):
             and not self._draining
         )
 
-    def _compute_tree_group(self, members: list[_TreeMember]) -> list:
-        """PR-10 surface: thin delegation onto the unified ragged runner
-        (a tree-only group packs and rolls back exactly as the dedicated
-        tree stack used to)."""
-        return self._compute_ragged_group(members)
-
     def _solo_tree_step(self, m: _TreeMember):
         self.batch_solo_steps += 1
         try:
@@ -4441,12 +4435,6 @@ class BlockServer(PromotionLoopMixin):
         if cand.key[0] == "chunkm":
             return "chunkm" not in kinds
         return sum(1 for k in kinds if k != "chunkm") < self.max_batch
-
-    def _compute_mixed_group(self, members: list) -> list:
-        """PR-8 surface: thin delegation onto the unified ragged runner
-        (decode+chunk groups pack, commit and roll back exactly as the
-        dedicated mixed stack used to)."""
-        return self._compute_ragged_group(members)
 
     def _compute_ragged_group(self, members: list) -> list:
         """Runs on the compute thread: ONE group that may hold decode

@@ -6,7 +6,7 @@ promotion/demotion state machine (server/promotion.py mixin), measured
 rebalancing (server/block_selection.rebalance_if_needed), and client-side
 Dijkstra routing with ban/quarantine/overload penalty classes
 (client/sequence_manager.py) — against thousands of virtual sessions on a
-``SteppableClock``, with device compute replaced by a calibrated cost
+``SteppableClock``, with device compute replaced by a cost
 model. Only the two leaves are simulated: the matmul (a ``clock.sleep``
 of the modeled cost on the compute thread) and the wire (a virtual RTT).
 Everything between — every watermark, dwell window, backoff, and
@@ -23,7 +23,7 @@ utils/lockwatch.py.
 Layout:
   engine.py    discrete-event conductor over SteppableClock + counting
                executor (knows when real compute threads are mid-flight)
-  cost.py      calibrated per-dispatch cost model (fit from BENCH JSON)
+  cost.py      per-dispatch cost model (constants; not measured on a TPU)
   node.py      SimServer: real queue/admission/promotion/rebalance
   client.py    virtual sessions driving real RemoteSequenceManager routes
   workload.py  generative arrivals: heavy tails, diurnal ramps, agent

@@ -40,7 +40,7 @@ def _main() -> None:
     )
     ap.add_argument(
         "--smoke", action="store_true",
-        help="~200-session quick profile (bench phase / chaos matrix)",
+        help="~200-session quick profile (chaos matrix)",
     )
     ap.add_argument(
         "--json", dest="json_path", default=None,

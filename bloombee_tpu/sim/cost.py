@@ -1,33 +1,19 @@
-"""Calibrated compute-cost model: what a dispatch costs in device seconds.
+"""Compute-cost model: what a dispatch costs in device seconds.
 
 The simulator replaces ``SpanExecutor`` with ``clock.sleep(cost)`` on the
-compute thread; this module decides the cost. The shape mirrors the
-measured bench phases (bench.py): a fixed per-dispatch overhead (jit call
-+ host sync) plus per-row work for fused ragged decode and per-token work
-for prefill chunks, both scaling with the span's block count.
+compute thread; this module decides the cost: a fixed per-dispatch
+overhead (jit call + host sync) plus per-row work for fused ragged decode
+and per-token work for prefill chunks, both scaling with the span's block
+count.
 
-Defaults are CPU-smoke-bench magnitudes; ``from_bench_json`` refits them
-from a real BENCH JSON (``--cost-json`` / ``BBTPU_SIM_COST_JSON``) so a
-TPU-calibrated simulation costs one flag. The fitter is tolerant: it
-reads whichever of ``chain.steps_per_sec`` / ``decode.tbt_p50_ms`` /
-``prefill.ttft_ms``-style keys the bench emitted and keeps defaults for
-the rest (bench JSONs evolve; a sim that hard-fails on a missing key
-can't consume last month's artifact).
+The defaults are CPU magnitudes from before the system ran on a chip; they
+were not measured on a TPU. The scenario gates compare runs under the same
+constants, so they decide on ratios, not on these absolute values.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import json
-
-from bloombee_tpu.utils import env
-
-env.declare(
-    "BBTPU_SIM_COST_JSON", str, "",
-    "path to a bench results JSON (bench.py output) to calibrate the "
-    "simulator's compute-cost model from; empty = built-in CPU-smoke "
-    "magnitudes",
-)
 
 
 @dataclasses.dataclass
@@ -58,65 +44,3 @@ class CostModel:
         if kind == "decode":
             return self.decode_group_s(rows, blocks)
         return self.prefill_chunk_s(tokens, blocks)
-
-    # ------------------------------------------------------------ calibration
-    @classmethod
-    def from_bench_json(
-        cls, source, num_blocks: int = 8
-    ) -> "CostModel":
-        """Fit from a bench results dict or JSON file path. Bench numbers
-        are end-to-end (all spans + wire); the fit attributes the wire
-        share to hop_rtt_ms's default and the rest to per-block compute,
-        which is the right split for *relative* scenario comparisons (the
-        sim's job) even when the absolute split is approximate."""
-        if isinstance(source, (str, bytes)):
-            with open(source) as f:
-                data = json.load(f)
-        else:
-            data = dict(source or {})
-        model = cls()
-        step_ms = None
-        sps = _dig(data, "chain.steps_per_sec", "steps_per_sec")
-        if isinstance(sps, (int, float)) and sps > 0:
-            step_ms = 1000.0 / float(sps)
-        tbt = _dig(data, "decode.tbt_p50_ms", "tbt_p50_ms", "chain.tbt_p50_ms")
-        if isinstance(tbt, (int, float)) and tbt > 0:
-            step_ms = float(tbt) if step_ms is None else min(step_ms, tbt)
-        if step_ms is not None:
-            # one chain step = dispatch + wire + blocks * row cost
-            compute_ms = max(0.1, step_ms - model.dispatch_ms
-                             - model.hop_rtt_ms)
-            model.decode_row_ms_per_block = compute_ms / max(1, num_blocks)
-        ttft = _dig(data, "prefill.ttft_ms", "ttft_ms", "chain.ttft_ms")
-        toks = _dig(data, "prefill.prompt_tokens", "prompt_tokens")
-        if (
-            isinstance(ttft, (int, float)) and ttft > 0
-            and isinstance(toks, (int, float)) and toks > 0
-        ):
-            compute_ms = max(0.1, float(ttft) - model.dispatch_ms
-                             - model.hop_rtt_ms)
-            model.prefill_tok_ms_per_block = compute_ms / (
-                float(toks) * max(1, num_blocks)
-            )
-        return model
-
-    @classmethod
-    def from_env(cls, num_blocks: int = 8) -> "CostModel":
-        path = env.get("BBTPU_SIM_COST_JSON")
-        if path:
-            return cls.from_bench_json(path, num_blocks=num_blocks)
-        return cls()
-
-
-def _dig(data: dict, *dotted: str):
-    """First present dotted key, tolerant of either nesting or flat keys."""
-    for key in dotted:
-        node = data
-        for part in key.split("."):
-            if not isinstance(node, dict) or part not in node:
-                node = None
-                break
-            node = node[part]
-        if node is not None:
-            return node
-    return None

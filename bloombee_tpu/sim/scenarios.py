@@ -49,7 +49,7 @@ BASE_PORT = 4200
 env.declare(
     "BBTPU_SIM_SESSIONS", int, 1000,
     "virtual sessions per simulator scenario (the --require CI gate "
-    "runs this many; --smoke drops to ~200 for bench/chaos rides)",
+    "runs this many; --smoke drops to ~200 for chaos rides)",
 )
 env.declare(
     "BBTPU_SIM_SEED", int, 0,
@@ -107,11 +107,8 @@ async def _drive(engine, swarm, specs, seed, horizon_s):
     return [t.result() for t in tasks], sampler.samples, start_t
 
 
-def _new_swarm(cost=None) -> SimSwarm:
-    return SimSwarm(
-        InProcessRegistry(), MODEL_UID, NUM_BLOCKS,
-        cost or CostModel.from_env(num_blocks=NUM_BLOCKS),
-    )
+def _new_swarm() -> SimSwarm:
+    return SimSwarm(InProcessRegistry(), MODEL_UID, NUM_BLOCKS, CostModel())
 
 
 # ------------------------------------------------------------- flash crowd

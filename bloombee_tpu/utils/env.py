@@ -92,7 +92,6 @@ def import_declaring_modules() -> None:
     import bloombee_tpu.server.artifacts  # noqa: F401
     import bloombee_tpu.server.block_selection  # noqa: F401
     import bloombee_tpu.server.block_server  # noqa: F401
-    import bloombee_tpu.sim.cost  # noqa: F401
     import bloombee_tpu.sim.metrics  # noqa: F401
     import bloombee_tpu.sim.scenarios  # noqa: F401
     import bloombee_tpu.utils.clock  # noqa: F401
@@ -121,17 +120,6 @@ declare("BBTPU_DEBUG", bool, False, "enable all debug log channels")
 declare(
     "BBTPU_LOG_CHANNELS", str, "",
     "comma-separated debug channels (wire, kv, microbatch, spec, timing)",
-)
-
-# bench.py's switch lives here rather than next to its reader: the
-# bench is a standalone script (not importable from
-# import_declaring_modules without dragging its __main__ machinery in),
-# but its switch still belongs in the authoritative table.
-declare(
-    "BBTPU_BENCH_SMOKE", bool, False,
-    "run bench.py as a tiny rehearsal (tiny model, short phases) on "
-    "whatever backend is there, reporting the phase ledger and counts "
-    "but no rates; without it bench.py fails unless it finds a TPU",
 )
 
 

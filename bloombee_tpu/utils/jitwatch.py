@@ -286,7 +286,7 @@ def enabled() -> bool:
 
 def install() -> bool:
     """Register the XLA compile listener (idempotent; no-op when the
-    switch is off). Called by BlockServer/bench startup — the listener
+    switch is off). Called by BlockServer startup — the listener
     is process-global and permanent, so the callback re-checks
     :func:`enabled` per event to honor env flips in tests."""
     global _installed, _atexit_registered

@@ -212,7 +212,7 @@ class Connection:
         self._closed = asyncio.Event()
         # --- codec negotiation + off-loop pipeline -----------------------
         # legacy_wire emulates a pre-negotiation peer (compat shim for
-        # mixed-swarm tests and the bench's legacy leg): never advertise,
+        # mixed-swarm tests): never advertise,
         # ignore adverts, codec work stays synchronous on the loop
         self.legacy_wire = bool(legacy_wire)
         self.codecs_local = (

@@ -72,7 +72,7 @@ LLAMA3_8B = {
     "bos_token_id": 128000,
     "eos_token_id": 128001,
 }
-SPAN_LAYERS = 8  # one server's span of the 32 (bench.py's span)
+SPAN_LAYERS = 8  # one server's span of the 32
 # the same family at rehearsal size: runs on the CPU in a test's time
 TINY = dict(
     LLAMA3_8B, hidden_size=256, intermediate_size=512,

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # Lint gate: bbtpu-lint (project AST rules BB001-BB006 + README
 # env-table drift, scripts/analyze.sh) then ruff over the package,
-# tests, bench, and entry scripts. Ruff config lives in pyproject.toml
+# tests and entry scripts. Ruff config lives in pyproject.toml
 # ([tool.ruff]); run with --fix to apply safe autofixes (e.g. deleting
 # unused imports) in place.
 set -euo pipefail
@@ -17,4 +17,4 @@ fi
 RUFF=ruff
 command -v ruff >/dev/null 2>&1 || RUFF="python -m ruff"
 
-exec $RUFF check "$@" bloombee_tpu tests bench.py __graft_entry__.py
+exec $RUFF check "$@" bloombee_tpu tests __graft_entry__.py

@@ -414,8 +414,8 @@ def test_bb011_direct_sync_in_hot_root():
     assert codes(
         """
         class BlockServer:
-            def tree_group(self, members):
-                out = self.executor.tree_group(members)
+            def prefill_chunk(self, members):
+                out = self.executor.prefill_chunk(members)
                 out.block_until_ready()
                 return out
         """
@@ -876,10 +876,10 @@ def test_bb008_flags_from_import():
 
 
 def test_bb008_exempts_clock_module_and_harness_code():
-    # utils/clock.py IS the real-time boundary; bench.py is an
-    # out-of-package harness that reports wall time on purpose
+    # utils/clock.py IS the real-time boundary; chip_smoke.py is a
+    # harness outside the package that reports wall time on purpose
     assert codes(BB008_TP, path="bloombee_tpu/utils/clock.py") == []
-    assert codes(BB008_TP, path="bench.py") == []
+    assert codes(BB008_TP, path="chip_smoke.py") == []
 
 
 # ------------------------------------------------- suppressions & baseline

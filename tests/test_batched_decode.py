@@ -135,12 +135,24 @@ def test_lockstep_sessions_share_one_dispatch_per_round(
             assert s.batch_dispatches + s.batch_solo_steps <= ROUNDS + 2
             width = s.batched_steps / max(s.batch_dispatches, 1)
             assert width >= 3.0
+            # dispatches per token over ALL inference dispatches: one per
+            # prefill, one per merged or solo decode round; every prompt
+            # and decode token counted once
+            assert s.step_dispatches == (
+                N + s.batch_dispatches + s.batch_solo_steps
+            )
+            assert s.step_tokens == (
+                sum(p.shape[1] for p in prompts) + N * ROUNDS
+            )
 
             conn = await connect("127.0.0.1", s.port)
             info, _ = await conn.call("rpc_info", {})
             assert info["batched_steps"] == s.batched_steps
             assert info["batch_dispatches"] == s.batch_dispatches
             assert info["mean_batch_width"] == pytest.approx(width)
+            assert info["dispatches_per_token"] == pytest.approx(
+                s.step_dispatches / s.step_tokens
+            )
             assert info["queue_wait_ms"]["p95"] >= 0.0
             await conn.close()
         finally:

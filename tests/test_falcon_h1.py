@@ -398,7 +398,9 @@ async def _refuse_tree_group(ex, m, h):
     assert "recurrent state" in ex.ragged_unsupported(has_tree=True)
     assert ex.ragged_unsupported(has_tree=False) is None
     mask, depths = _tree(3)
-    ex.tree_group([h], [_hidden(0, 3)], [mask], [depths])
+    ex.ragged_group(
+        [h], [_hidden(0, 3)], tree_masks=[mask], depths_list=[depths]
+    )
 
 
 async def _refuse_accept(ex, m, h):

@@ -10,12 +10,10 @@ coverage.
 """
 
 import asyncio
-import json
 import time
 
 import pytest
 
-from bloombee_tpu.sim.cost import CostModel
 from bloombee_tpu.sim.engine import SimEngine
 from bloombee_tpu.sim.scenarios import SCENARIOS, run_scenario
 from bloombee_tpu.utils import clock as vclock
@@ -32,7 +30,7 @@ _STOCK_TUNING = [
     "BBTPU_PROMOTE_HIGH_MS", "BBTPU_PROMOTE_SUSTAIN_S",
     "BBTPU_MIXED_BATCH", "BBTPU_SPEC_BATCH", "BBTPU_BATCH_WINDOW_MS",
     "BBTPU_CHUNK_AGE_S", "BBTPU_KEEPALIVE_S", "BBTPU_CLOCK_SCALE",
-    "BBTPU_SIM_SESSIONS", "BBTPU_SIM_SEED", "BBTPU_SIM_COST_JSON",
+    "BBTPU_SIM_SESSIONS", "BBTPU_SIM_SEED",
     "BBTPU_SIM_SETTLE_S", "BBTPU_SIM_RETRY_AMP_MAX",
     "BBTPU_SIM_SHED_AMP_MAX", "BBTPU_SIM_FLAP_MAX",
     "BBTPU_SIM_PROMOTE_LATENCY_S", "BBTPU_SIM_WALL_BUDGET_S",
@@ -170,23 +168,3 @@ def test_mistuned_retry_hint_trips_metastable_gate(monkeypatch):
 
 def test_scenario_catalog_is_stable():
     assert list(SCENARIOS) == ["flash_crowd", "span_loss", "diurnal"]
-
-
-# -------------------------------------------------------------- cost model
-
-
-def test_cost_model_fits_bench_json(tmp_path):
-    data = {
-        "chain": {"steps_per_sec": 20.0},
-        "prefill": {"ttft_ms": 500.0, "prompt_tokens": 100},
-    }
-    path = tmp_path / "bench.json"
-    path.write_text(json.dumps(data))
-    m = CostModel.from_bench_json(str(path), num_blocks=4)
-    # 50ms/step minus dispatch (2ms) and wire rtt (10ms), over 4 blocks
-    assert m.decode_row_ms_per_block == pytest.approx(38.0 / 4)
-    assert m.prefill_tok_ms_per_block == pytest.approx(488.0 / (100 * 4))
-    # tolerant fitter: an empty / alien bench JSON keeps the defaults
-    d = CostModel.from_bench_json({})
-    assert d.decode_row_ms_per_block == CostModel().decode_row_ms_per_block
-    assert d.prefill_tok_ms_per_block == CostModel().prefill_tok_ms_per_block

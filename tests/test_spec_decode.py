@@ -798,6 +798,9 @@ def test_e2e_spec_batch_concurrent_sessions_token_identical(
     assert s_b.tree_group_dispatches > 0
     assert s_u.tree_group_dispatches == 0
     assert s_b.tree_steps > 0 and s_u.tree_steps > 0
+    # the same tokens from fewer device dispatches: a grouped round is ONE
+    # dispatch where the solo run pays one per session
+    assert s_b.step_dispatches < s_u.step_dispatches
 
     for got_b, got_u, p in zip(batched, solo, prompts):
         np.testing.assert_array_equal(got_b, got_u)

@@ -222,8 +222,6 @@ def test_tp_mesh_not_on_unsupported_list():
     )
     assert ex.ragged_unsupported(has_tree=False) is None
     assert ex.ragged_unsupported(has_tree=True) is None
-    assert ex.mixed_unsupported() is None
-    assert ex.tree_group_unsupported() is None
 
 
 # ---------------------------------------------------------- server fixture

@@ -477,6 +477,100 @@ def tiny_model_dir(tmp_path_factory):
     return str(d), model, config
 
 
+# the three ways a decode step's frames can be scheduled: codec work off
+# the loop (default), on the loop (BBTPU_WIRE_PIPELINE=0), and against a
+# peer from before the negotiation (sync codec, no advert, ours ignored)
+_LEGS = {"on": ("1", False), "off": ("0", False), "legacy": ("1", True)}
+_leg_results: dict = {}
+
+
+def _decode_leg(tiny_model_dir, leg):
+    """One greedy decode through a real one-server swarm under seeded wire
+    delays; what it generated, what the wire carried, and whether the
+    server's accepted connections ran the off-loop pipeline."""
+    if leg in _leg_results:
+        return _leg_results[leg]
+    import jax.numpy as jnp
+    import torch
+
+    from bloombee_tpu.client.model import DistributedModelForCausalLM
+    from bloombee_tpu.server.block_server import BlockServer
+    from bloombee_tpu.swarm.registry import RegistryClient, RegistryServer
+    from bloombee_tpu.wire.faults import FaultPlan, FaultRule
+    from bloombee_tpu.wire.tensor_codec import transport_stats
+
+    pipeline_on, legacy_peer = _LEGS[leg]
+    model_dir, hf_model, config = tiny_model_dir
+    prompt = np.arange(5)[None, :] % config.vocab_size
+    with torch.no_grad():
+        ref = hf_model.generate(
+            torch.tensor(prompt), max_new_tokens=6, do_sample=False,
+            use_cache=True,
+        ).numpy()
+
+    async def run():
+        reg = RegistryServer(host="127.0.0.1")
+        await reg.start()
+        srv = BlockServer(
+            model_uid="tiny", start=0, end=2, model_dir=model_dir,
+            registry=RegistryClient("127.0.0.1", reg.port),
+            compute_dtype=jnp.float32, num_pages=64, page_size=4,
+        )
+        await srv.start()
+        srv.rpc.legacy_wire = legacy_peer
+        try:
+            model = DistributedModelForCausalLM.from_pretrained(
+                model_dir, RegistryClient("127.0.0.1", reg.port),
+                model_uid="tiny",
+            )
+            plan = FaultPlan(seed=29)
+            plan.add(FaultRule(site="send", action="delay", method="sitem",
+                               prob=0.25, delay_s=0.004))
+            faults.set_plan(plan)
+            before = transport_stats()["tx"]
+            async with model.inference_session(16, 1) as session:
+                ids = await model.generate(
+                    prompt, max_new_tokens=6, session=session,
+                )
+                pipe = srv.rpc.pipeline_stats()
+            after = transport_stats()["tx"]
+        finally:
+            faults.set_plan(None)
+            await srv.stop()
+            await reg.stop()
+        return {
+            "ids": np.asarray(ids),
+            "ref": ref,
+            "raw_bytes": after["raw_bytes"] - before["raw_bytes"],
+            "wire_bytes": after["wire_bytes"] - before["wire_bytes"],
+            "pipelined": bool(pipe["enabled"]) and pipe["rx_jobs"] > 0,
+        }
+
+    with pytest.MonkeyPatch.context() as mp:
+        # enablement is read as a Connection is made: set it while this
+        # leg's swarm comes up, and send every frame through the pool
+        mp.setenv("BBTPU_WIRE_PIPELINE", pipeline_on)
+        mp.setenv("BBTPU_WIRE_PIPELINE_INLINE", "0")
+        _leg_results[leg] = asyncio.run(run())
+    return _leg_results[leg]
+
+
+@pytest.mark.parametrize("leg", list(_LEGS))
+def test_decode_leg_matches_pipeline_on_in_tokens_and_bytes(
+    tiny_model_dir, leg
+):
+    """The pipeline and the negotiation choose where codec work runs and
+    which codec a peer is offered, never what is computed or shipped: each
+    leg generates HF greedy's tokens and puts the same bytes per token on
+    the wire as the default leg, and only the default leg's server ran
+    frames through the codec pool."""
+    got, on = _decode_leg(tiny_model_dir, leg), _decode_leg(tiny_model_dir, "on")
+    np.testing.assert_array_equal(got["ids"], got["ref"])
+    assert got["raw_bytes"] == on["raw_bytes"] > 0
+    assert got["wire_bytes"] == on["wire_bytes"]
+    assert got["pipelined"] == (leg == "on")
+
+
 @pytest.mark.chaos
 @pytest.mark.slow
 def test_chaos_decode_through_forced_codec_pool(tiny_model_dir, monkeypatch):
