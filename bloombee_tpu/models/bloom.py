@@ -55,9 +55,10 @@ def _load_block(reader, layer_idx: int, dtype=None) -> dict:
     head_dim = d // n_head
     w4 = w.reshape(n_head, 3, head_dim, d)
     b4 = b.reshape(n_head, 3, head_dim)
-    params["q_proj"] = w4[:, 0].reshape(n_head * head_dim, d).T
-    params["k_proj"] = w4[:, 1].reshape(n_head * head_dim, d).T
-    params["v_proj"] = w4[:, 2].reshape(n_head * head_dim, d).T
+    # q/k/v are stored output-major, as these rows lie (models/layout.py)
+    params["q_proj"] = w4[:, 0].reshape(n_head * head_dim, d)
+    params["k_proj"] = w4[:, 1].reshape(n_head * head_dim, d)
+    params["v_proj"] = w4[:, 2].reshape(n_head * head_dim, d)
     params["q_bias"] = b4[:, 0].reshape(-1)
     params["k_bias"] = b4[:, 1].reshape(-1)
     params["v_bias"] = b4[:, 2].reshape(-1)

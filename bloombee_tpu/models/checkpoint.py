@@ -18,6 +18,7 @@ import pathlib
 import numpy as np
 from safetensors import safe_open
 
+from bloombee_tpu.models.layout import OUT_MAJOR_KEYS
 from bloombee_tpu.models.spec import ModelSpec
 
 
@@ -69,6 +70,14 @@ def read_tensor(reader: CheckpointReader, name: str, dtype=None):
 
     w = jnp.asarray(reader.tensor(name))
     return w.astype(dtype) if dtype is not None else w
+
+
+def read_weight(reader: CheckpointReader, name: str, key: str, dtype=None):
+    """The torch `[out, in]` matrix `name` as param `key` is STORED
+    (models/layout.py): as the checkpoint has it for an output-major key,
+    transposed to `[in, out]` for every other projection."""
+    w = read_tensor(reader, name, dtype)
+    return w if key in OUT_MAJOR_KEYS else w.T
 
 
 def stack_expert_weights(
@@ -126,7 +135,12 @@ def load_span_params(
         # per-layer shapes differ (gemma-4): no stacking — the hetero span
         # step unrolls over a tuple of per-layer param dicts
         return tuple(layers), spec
-    return stack_params(layers), spec
+    # leaf by leaf, letting go of each layer's tensor once it is stacked:
+    # the span is never held twice (one stacked leaf over it at most)
+    return {
+        key: stack_params([params.pop(key) for params in layers])
+        for key in list(layers[0])
+    }, spec
 
 
 def load_span_params_split(
@@ -289,7 +303,9 @@ class LoraAdapter:
                 continue
             a = self._get(ka)
             b = self._get(kb)
-            delta = (b @ a).T * self.scaling  # [in, out], matches our layout
+            delta = (b @ a) * self.scaling  # [out, in], as torch has it
+            if name not in OUT_MAJOR_KEYS:
+                delta = delta.T  # stored [in, out] (models/layout.py)
             params[name] = (
                 params[name].astype(jnp.float32) + jnp.asarray(delta)
             ).astype(params[name].dtype)

@@ -77,6 +77,8 @@ def _load_block(reader, layer_idx: int, dtype=None) -> dict:
         params["input_layernorm_bias"] = _t(
             reader, f"{p}.input_layernorm.bias", dtype
         )
+    # q/k/v are stored output-major, as the fused matrix's rows lie
+    # (models/layout.py)
     w = _t(reader, f"{p}.self_attention.query_key_value.weight", dtype)
     if new_arch:
         # grouped layout: per kv group [n_rep q rows | 1 k row | 1 v row]
@@ -84,19 +86,17 @@ def _load_block(reader, layer_idx: int, dtype=None) -> dict:
         n_kv = reader.config.get("num_kv_heads") or n_head
         n_rep = n_head // n_kv
         grouped = w.reshape(n_kv, n_rep + 2, head_dim, d)
-        params["q_proj"] = (
-            grouped[:, :-2].reshape(n_kv * n_rep * head_dim, d).T
-        )
-        params["k_proj"] = grouped[:, -2].reshape(n_kv * head_dim, d).T
-        params["v_proj"] = grouped[:, -1].reshape(n_kv * head_dim, d).T
+        params["q_proj"] = grouped[:, :-2].reshape(n_kv * n_rep * head_dim, d)
+        params["k_proj"] = grouped[:, -2].reshape(n_kv * head_dim, d)
+        params["v_proj"] = grouped[:, -1].reshape(n_kv * head_dim, d)
     else:
         n_kv = 1 if reader.config.get("multi_query", True) else n_head
         # rows: H query heads, then n_kv k heads, then n_kv v heads
         q_rows = n_head * head_dim
         kv_rows = n_kv * head_dim
-        params["q_proj"] = w[:q_rows].T
-        params["k_proj"] = w[q_rows : q_rows + kv_rows].T
-        params["v_proj"] = w[q_rows + kv_rows :].T
+        params["q_proj"] = w[:q_rows]
+        params["k_proj"] = w[q_rows : q_rows + kv_rows]
+        params["v_proj"] = w[q_rows + kv_rows :]
     params["o_proj"] = _t(reader, f"{p}.self_attention.dense.weight", dtype).T
     params["up_proj"] = _t(reader, f"{p}.mlp.dense_h_to_4h.weight", dtype).T
     params["down_proj"] = _t(reader, f"{p}.mlp.dense_4h_to_h.weight", dtype).T

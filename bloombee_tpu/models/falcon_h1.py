@@ -13,10 +13,14 @@ logits are scaled by `lm_head_multiplier`.
 
 from __future__ import annotations
 
+from types import SimpleNamespace
 from typing import Any
 
+import numpy as np
+
 from bloombee_tpu.models.auto import Family, register_family
-from bloombee_tpu.models.checkpoint import read_tensor as _t
+from bloombee_tpu.models.checkpoint import read_tensor as _t, read_weight
+from bloombee_tpu.models.layout import lane_padded
 from bloombee_tpu.models.spec import ModelSpec, SsmSpec
 
 
@@ -86,23 +90,30 @@ def _load_block(reader, layer_idx: int, dtype=None) -> dict:
         ),
     }
     for proj in ("q", "k", "v", "o"):
-        params[f"{proj}_proj"] = _t(
-            reader, f"{p}.self_attn.{proj}_proj.weight", dtype
-        ).T
+        params[f"{proj}_proj"] = read_weight(
+            reader, f"{p}.self_attn.{proj}_proj.weight", f"{proj}_proj",
+            dtype,
+        )
     for proj in ("gate", "up", "down"):
         params[f"{proj}_proj"] = _t(
             reader, f"{p}.feed_forward.{proj}_proj.weight", dtype
         ).T
     m = f"{p}.mamba"
-    params["ssm_in_proj"] = _t(reader, f"{m}.in_proj.weight", dtype).T
+    # in_proj is stored with zero columns up to whole lanes, or every step
+    # re-lays the stack out before its scan (models/layout.py); the rows
+    # are added on the host, so the device never holds a second copy
+    import jax.numpy as jnp
+
+    ssm = falcon_h1_spec_from_hf(SimpleNamespace(**reader.config)).ssm
+    w = reader.tensor(f"{m}.in_proj.weight")  # [proj_dim, D]
+    w = np.pad(w, ((0, lane_padded(ssm.proj_dim) - w.shape[0]), (0, 0)))
+    params["ssm_in_proj"] = jnp.asarray(w, dtype=dtype).T
     params["ssm_out_proj"] = _t(reader, f"{m}.out_proj.weight", dtype).T
     # torch [C, 1, K] -> [K, C]: tap k of every channel is one row
     params["ssm_conv_w"] = _t(reader, f"{m}.conv1d.weight", dtype)[:, 0, :].T
     params["ssm_conv_b"] = _t(reader, f"{m}.conv1d.bias", dtype)
     params["ssm_norm"] = _t(reader, f"{m}.norm.weight", dtype)
     # the recurrence's own vectors stay float32 whatever the compute dtype
-    import jax.numpy as jnp
-
     for key, name in (("ssm_a_log", "A_log"), ("ssm_d", "D"),
                       ("ssm_dt_bias", "dt_bias")):
         params[key] = _t(reader, f"{m}.{name}", jnp.float32)

@@ -15,7 +15,7 @@ from typing import Any
 
 
 from bloombee_tpu.models.auto import Family, register_family
-from bloombee_tpu.models.checkpoint import read_tensor as _t
+from bloombee_tpu.models.checkpoint import read_tensor as _t, read_weight
 from bloombee_tpu.models.spec import ModelSpec
 
 _NORMS = (
@@ -66,9 +66,10 @@ def _load_block(reader, layer_idx: int, dtype=None) -> dict:
     for ln in _NORMS:
         params[ln] = 1.0 + _t(reader, f"{p}.{ln}.weight", dtype)
     for proj in ("q", "k", "v", "o"):
-        params[f"{proj}_proj"] = _t(
-            reader, f"{p}.self_attn.{proj}_proj.weight", dtype
-        ).T
+        params[f"{proj}_proj"] = read_weight(
+            reader, f"{p}.self_attn.{proj}_proj.weight", f"{proj}_proj",
+            dtype,
+        )
     for proj in ("gate", "up", "down"):
         params[f"{proj}_proj"] = _t(
             reader, f"{p}.mlp.{proj}_proj.weight", dtype
@@ -132,9 +133,10 @@ def _load_block_gemma3(reader, layer_idx: int, dtype=None) -> dict:
     for ln in _NORMS:
         params[ln] = 1.0 + _t(reader, f"{p}.{ln}.weight", dtype)
     for proj in ("q", "k", "v", "o"):
-        params[f"{proj}_proj"] = _t(
-            reader, f"{p}.self_attn.{proj}_proj.weight", dtype
-        ).T
+        params[f"{proj}_proj"] = read_weight(
+            reader, f"{p}.self_attn.{proj}_proj.weight", f"{proj}_proj",
+            dtype,
+        )
     for proj in ("gate", "up", "down"):
         params[f"{proj}_proj"] = _t(
             reader, f"{p}.mlp.{proj}_proj.weight", dtype

@@ -21,7 +21,7 @@ import math
 from typing import Any
 
 from bloombee_tpu.models.auto import Family, register_family
-from bloombee_tpu.models.checkpoint import read_tensor as _t
+from bloombee_tpu.models.checkpoint import read_tensor as _t, read_weight
 from bloombee_tpu.models.spec import ModelSpec
 
 _PREFIX = "model.language_model"
@@ -92,9 +92,10 @@ def _load_block(reader, layer_idx: int, dtype=None, spec=None) -> dict:
     if reader.has(f"{p}.self_attn.v_proj.weight"):
         projs.append("v")
     for proj in projs:
-        params[f"{proj}_proj"] = _t(
-            reader, f"{p}.self_attn.{proj}_proj.weight", dtype
-        ).T
+        params[f"{proj}_proj"] = read_weight(
+            reader, f"{p}.self_attn.{proj}_proj.weight", f"{proj}_proj",
+            dtype,
+        )
     for name, key in (("q_norm", "q_norm"), ("k_norm", "k_norm")):
         full = f"{p}.self_attn.{key}.weight"
         if reader.has(full):

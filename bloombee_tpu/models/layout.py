@@ -1,0 +1,49 @@
+"""Stored layouts of stacked weights: a span's weights lie on the device the
+way its step programs read them, so no step holds a copy of a parameter.
+
+The TPU compiler reads each layer's q/k/v weight with the INPUT dimension
+minor. Stored `[L, in, out]`, a span-step program began with a `copy` of
+the whole stack into that layout: 2.5 ms of a 14.6 ms Mistral chunk, every
+chunk run (PERF.md section 6, PR 34). Stored `[L, out, in]`, which is how
+the checkpoint holds them (torch `[out, in]`), the same matmul reads them
+where they lie. The other projections (`o_proj`, `gate/up/down_proj`, the expert
+stacks, `ssm_out_proj`) are read as `[L, in, out]` without a copy and stay.
+
+A stack whose minor dimension is not a multiple of the 128-lane vector
+width is re-laid out the same way; Falcon-H1's `ssm_in_proj` (9248 columns)
+is the one such stack (2.3 ms of EVERY run of a 12.6 ms step), and is stored
+with zero columns up to `lane_padded` (models/falcon_h1.py; the mixer reads
+`[:, :proj_dim]` of the product).
+
+The rule for a new family's loader: read every projection through
+`checkpoint.read_weight(reader, name, key)`, which leaves an output-major
+key as the checkpoint has it and transposes the rest; apply it through
+`project`. Which keys are output-major is this one constant: a square
+`q_proj` cannot be told from its transpose by shape.
+"""
+
+from __future__ import annotations
+
+# stored [out, in] (stacked [L, out, in]); every other projection [in, out]
+OUT_MAJOR_KEYS = frozenset(("q_proj", "k_proj", "v_proj"))
+
+LANES = 128  # the TPU's vector width: a minor dimension is tiled by it
+
+
+def in_axis_of(key: str) -> int:
+    """The contraction (input) axis of the stored weight `key`."""
+    return -1 if key in OUT_MAJOR_KEYS else -2
+
+
+def project(x, w, key: str):
+    """x [..., in] through the dense stored weight `w` of `key`."""
+    if key in OUT_MAJOR_KEYS:
+        import jax.numpy as jnp
+
+        return jnp.einsum("...d,od->...o", x, w)
+    return x @ w
+
+
+def lane_padded(n: int) -> int:
+    """n rounded up to whole lanes."""
+    return -(-n // LANES) * LANES

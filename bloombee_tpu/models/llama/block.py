@@ -8,9 +8,10 @@ return values; KV-cache policy lives entirely in the caller-provided `attend`
 closure, so the same block code serves dense prefill, paged decode, and
 speculative tree verify.
 
-Weight convention: all projection matrices are stored transposed relative to
-torch `nn.Linear` — shape [in_features, out_features] — so application is `x @ w`
-(row-major friendly for XLA tiling onto the MXU).
+Weight convention (models/layout.py): q/k/v projections are stored as torch
+`nn.Linear` has them — [out_features, in_features], the layout the TPU
+compiler reads them in — and every other projection transposed,
+[in_features, out_features], applied as `x @ w`.
 """
 
 from __future__ import annotations
@@ -20,6 +21,7 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from bloombee_tpu.models.layout import OUT_MAJOR_KEYS, project
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.ops import apply_rotary, masked_attention, rms_norm, silu_mlp
 from bloombee_tpu.ops.attention import causal_mask
@@ -41,9 +43,9 @@ def init_block_params(rng: jax.Array, spec: ModelSpec, dtype=jnp.float32) -> dic
     return {
         "input_layernorm": jnp.ones((d,), dtype),
         "post_attention_layernorm": jnp.ones((d,), dtype),
-        "q_proj": w(keys[0], (d, h * hd)),
-        "k_proj": w(keys[1], (d, kv * hd)),
-        "v_proj": w(keys[2], (d, kv * hd)),
+        "q_proj": w(keys[0], (h * hd, d)),
+        "k_proj": w(keys[1], (kv * hd, d)),
+        "v_proj": w(keys[2], (kv * hd, d)),
         "o_proj": w(keys[3], (h * hd, d)),
         "gate_proj": w(keys[4], (d, i)),
         "up_proj": w(keys[5], (d, i)),
@@ -63,9 +65,11 @@ def block_forward(
     h, kv, hd = spec.num_attention_heads, spec.num_key_value_heads, spec.head_dim
 
     x = rms_norm(hidden, params["input_layernorm"], spec.rms_norm_eps)
-    q = (x @ params["q_proj"]).reshape(b, t, h, hd)
-    k = (x @ params["k_proj"]).reshape(b, t, kv, hd)
-    v = (x @ params["v_proj"]).reshape(b, t, kv, hd)
+    q, k, v = (project(x, params[key], key)
+               for key in ("q_proj", "k_proj", "v_proj"))
+    q = q.reshape(b, t, h, hd)
+    k = k.reshape(b, t, kv, hd)
+    v = v.reshape(b, t, kv, hd)
     q, k = apply_rotary(q, k, cos, sin)
 
     attn_out, aux = attend(q, k, v)
@@ -102,17 +106,23 @@ def dense_attend(
     return attend
 
 
+def _matrix(name: str) -> tuple[str, bool]:
+    # a torch [out, in] matrix is transposed at load unless its key is
+    # stored output-major (models/layout.py)
+    return name, name not in OUT_MAJOR_KEYS
+
+
 # HF checkpoint key mapping: per-layer torch name -> (our name, transpose?)
 HF_BLOCK_KEYS = {
     "input_layernorm.weight": ("input_layernorm", False),
     "post_attention_layernorm.weight": ("post_attention_layernorm", False),
-    "self_attn.q_proj.weight": ("q_proj", True),
-    "self_attn.k_proj.weight": ("k_proj", True),
-    "self_attn.v_proj.weight": ("v_proj", True),
-    "self_attn.o_proj.weight": ("o_proj", True),
-    "mlp.gate_proj.weight": ("gate_proj", True),
-    "mlp.up_proj.weight": ("up_proj", True),
-    "mlp.down_proj.weight": ("down_proj", True),
+    "self_attn.q_proj.weight": _matrix("q_proj"),
+    "self_attn.k_proj.weight": _matrix("k_proj"),
+    "self_attn.v_proj.weight": _matrix("v_proj"),
+    "self_attn.o_proj.weight": _matrix("o_proj"),
+    "mlp.gate_proj.weight": _matrix("gate_proj"),
+    "mlp.up_proj.weight": _matrix("up_proj"),
+    "mlp.down_proj.weight": _matrix("down_proj"),
 }
 
 

@@ -12,7 +12,7 @@ from typing import Any
 
 
 from bloombee_tpu.models.auto import Family, register_family
-from bloombee_tpu.models.checkpoint import read_tensor as _t
+from bloombee_tpu.models.checkpoint import read_tensor as _t, read_weight
 from bloombee_tpu.models.spec import ModelSpec
 
 
@@ -44,9 +44,10 @@ def _load_block(reader, layer_idx: int, dtype=None) -> dict:
         ),
     }
     for proj in ("q", "k", "v", "o"):
-        params[f"{proj}_proj"] = _t(
-            reader, f"{p}.self_attn.{proj}_proj.weight", dtype
-        ).T
+        params[f"{proj}_proj"] = read_weight(
+            reader, f"{p}.self_attn.{proj}_proj.weight", f"{proj}_proj",
+            dtype,
+        )
     params["router"] = _t(
         reader, f"{p}.block_sparse_moe.gate.weight", dtype
     ).T  # [D, E]

@@ -76,7 +76,7 @@ def qwen3_moe_spec_from_hf(config: Any) -> ModelSpec:
 
 def _load_block_moe(reader, layer_idx: int, dtype=None) -> dict:
     p = f"model.layers.{layer_idx}"
-    from bloombee_tpu.models.checkpoint import read_tensor as _t
+    from bloombee_tpu.models.checkpoint import read_tensor as _t, read_weight
 
     params = {
         "input_layernorm": _t(reader, f"{p}.input_layernorm.weight", dtype),
@@ -85,9 +85,10 @@ def _load_block_moe(reader, layer_idx: int, dtype=None) -> dict:
         ),
     }
     for proj in ("q", "k", "v", "o"):
-        params[f"{proj}_proj"] = _t(
-            reader, f"{p}.self_attn.{proj}_proj.weight", dtype
-        ).T
+        params[f"{proj}_proj"] = read_weight(
+            reader, f"{p}.self_attn.{proj}_proj.weight", f"{proj}_proj",
+            dtype,
+        )
     for name in ("q_norm", "k_norm"):
         params[name] = _t(reader, f"{p}.self_attn.{name}.weight", dtype)
     params["router"] = _t(reader, f"{p}.mlp.gate.weight", dtype).T  # [D, E]

@@ -10,8 +10,9 @@ dequantize (convert + scale multiply) is an elementwise producer that XLA
 fuses into the matmul's operand read on TPU, so the dequantized matrix is
 never materialized in HBM.
 
-Layouts:
-- int8: per-output-column symmetric scale. codes [..., in, out] int8,
+Layouts (for a weight stored [..., in, out]; an output-major key,
+models/layout.py, has the last two axes of every leaf swapped):
+- int8: per-output-channel symmetric scale. codes [..., in, out] int8,
   scale [..., 1, out].
 - int4: group-wise (GROUP=32 x out) asymmetric — same group size as the
   int4 KV slab; round-to-nearest at larger groups is too noisy — two
@@ -30,6 +31,8 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+from bloombee_tpu.models.layout import in_axis_of
 
 GROUP = 32
 
@@ -55,18 +58,28 @@ class QuantWeight(NamedTuple):
         return 8 if self.codes.dtype == jnp.int8 else 4
 
 
-@functools.partial(jax.jit, static_argnames=("bits",))
-def quantize_weight(w: jax.Array, bits: int = 8) -> QuantWeight:
-    """Quantize [..., in, out] along the input (contraction) dim. Jitted, so
-    a stacked span's leaf is never held in float32 beside itself (eagerly,
-    an 8-layer 5120 x 21504 stack asked for two 3.5 GB temporaries and did
-    not load on a 16 GB chip)."""
+def _swap(x):
+    return None if x is None else jnp.swapaxes(x, -1, -2)
+
+
+@functools.partial(jax.jit, static_argnames=("bits", "in_axis"))
+def quantize_weight(
+    w: jax.Array, bits: int = 8, in_axis: int = -2
+) -> QuantWeight:
+    """Quantize along the input (contraction) dim, `in_axis` of the last
+    two: -2 for [..., in, out], -1 for an output-major [..., out, in].
+    Jitted, so a stacked span's leaf is never held in float32 beside itself
+    (eagerly, an 8-layer 5120 x 21504 stack asked for two 3.5 GB
+    temporaries and did not load on a 16 GB chip)."""
     w = w.astype(jnp.float32)
     if bits == 8:
-        amax = jnp.max(jnp.abs(w), axis=-2, keepdims=True)  # [..., 1, out]
+        # one scale per output channel: [..., 1, out] or [..., out, 1]
+        amax = jnp.max(jnp.abs(w), axis=in_axis, keepdims=True)
         scale = jnp.where(amax > 0, amax / 127.0, 1.0)
         codes = jnp.clip(jnp.round(w / scale), -127, 127).astype(jnp.int8)
         return QuantWeight(codes=codes, scale=scale.astype(jnp.float32))
+    if bits == 4 and in_axis == -1:
+        return QuantWeight(*map(_swap, quantize_weight(_swap(w), 4)))
     if bits == 4:
         *lead, din, dout = w.shape
         gs = min(GROUP, din)
@@ -89,9 +102,14 @@ def quantize_weight(w: jax.Array, bits: int = 8) -> QuantWeight:
     raise ValueError(f"unsupported weight bits {bits}")
 
 
-def dequantize_weight(qw: QuantWeight, dtype=jnp.bfloat16) -> jax.Array:
+def dequantize_weight(
+    qw: QuantWeight, dtype=jnp.bfloat16, in_axis: int = -2
+) -> jax.Array:
     if qw.bits == 8:
+        # the scale's shape says which axis is the channels'
         return (qw.codes.astype(jnp.float32) * qw.scale).astype(dtype)
+    if in_axis == -1:
+        return _swap(dequantize_weight(QuantWeight(*map(_swap, qw)), dtype))
     codes = qw.codes
     lo = (codes & 0xF).astype(jnp.float32)
     hi = (codes >> 4).astype(jnp.float32)
@@ -108,10 +126,10 @@ def dequantize_weight(qw: QuantWeight, dtype=jnp.bfloat16) -> jax.Array:
     return out.reshape(*lead, din, dout).astype(dtype)
 
 
-def maybe_dequantize(w, dtype=jnp.bfloat16):
+def maybe_dequantize(w, dtype=jnp.bfloat16, in_axis: int = -2):
     """Dense passthrough or fused-dequant entry used by the layer body."""
     if isinstance(w, QuantWeight):
-        return dequantize_weight(w, dtype)
+        return dequantize_weight(w, dtype, in_axis)
     return w
 
 
@@ -122,7 +140,7 @@ def quantize_span_params(stacked: dict, bits: int) -> dict:
     out = {}
     for key, leaf in stacked.items():
         if key in QUANT_KEYS and getattr(leaf, "ndim", 0) >= 3:
-            out[key] = quantize_weight(leaf, bits)
+            out[key] = quantize_weight(leaf, bits, in_axis_of(key))
         else:
             out[key] = leaf
     return out

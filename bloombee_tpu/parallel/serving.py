@@ -46,9 +46,10 @@ SERVING_PARAM_SPECS = {
     "mlp_layernorm_bias": P(None, None),
     "pre_feedforward_layernorm": P(None, None),
     "post_feedforward_layernorm": P(None, None),
-    "q_proj": P(None, None, "tp"),
-    "k_proj": P(None, None, "tp"),
-    "v_proj": P(None, None, "tp"),
+    # stored output-major [L, out, in] (models/layout.py)
+    "q_proj": P(None, "tp", None),
+    "k_proj": P(None, "tp", None),
+    "v_proj": P(None, "tp", None),
     "o_proj": P(None, "tp", None),
     "q_bias": P(None, "tp"),
     "k_bias": P(None, "tp"),
@@ -106,7 +107,8 @@ def _quant_leaf_spec(base, shape, tp):
     Handles every layout by shape alone: int8 scales [L, 1, out] drop an
     input-dim "tp" (size 1), int4 group scales [L, in/GROUP, out] keep it,
     packed int4 codes [L, in/2, out] keep it, expert leaves [L, E, ...]
-    keep the expert-dim shard."""
+    keep the expert-dim shard; an output-major key's leaves have the last
+    two axes swapped, as its base spec has."""
     spec = tuple(
         None if (s == "tp" and shape[i] % tp != 0) else s
         for i, s in enumerate(base)

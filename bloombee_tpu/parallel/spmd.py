@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from bloombee_tpu.models.layout import project
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.ops import rms_norm, silu_mlp
 from bloombee_tpu.ops.rotary import apply_rotary, rotary_cos_sin
@@ -35,9 +36,10 @@ PARAM_SPECS = {
     "mlp_layernorm_bias": P("pp", None),
     "pre_feedforward_layernorm": P("pp", None),  # gemma2 sandwich
     "post_feedforward_layernorm": P("pp", None),
-    "q_proj": P("pp", None, "tp"),
-    "k_proj": P("pp", None, "tp"),
-    "v_proj": P("pp", None, "tp"),
+    # stored output-major [L, out, in] (models/layout.py)
+    "q_proj": P("pp", "tp", None),
+    "k_proj": P("pp", "tp", None),
+    "v_proj": P("pp", "tp", None),
     "o_proj": P("pp", "tp", None),
     # qkv biases shard with their projection's OUTPUT dim, so they add
     # shard-locally before any psum (qwen2-style biased attention)
@@ -160,7 +162,7 @@ def spmd_block_forward(
     def col(x, key):
         # column-parallel projection: output dim sharded, so the bias
         # shard adds locally (before any reduction)
-        y = x @ params_l[key]
+        y = project(x, params_l[key], key)
         bias = params_l.get(f"{key.removesuffix('_proj')}_bias")
         if bias is not None:
             y = y + bias

@@ -51,6 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from bloombee_tpu.kv.arena import arena_write, gather_pages
+from bloombee_tpu.models.layout import in_axis_of, project
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.models.wquant import maybe_dequantize
 from bloombee_tpu.ops import apply_rotary, rms_norm, silu_mlp
@@ -73,8 +74,10 @@ def _norm(x, params, key, spec):
 
 def _proj(x, params, key, lora=None):
     # quantized projections dequantize here; XLA fuses the convert+scale
-    # into the matmul's operand read (no dense copy lands in HBM)
-    y = x @ maybe_dequantize(params[key], x.dtype)
+    # into the matmul's operand read (no dense copy lands in HBM). q/k/v
+    # are stored output-major, as the step reads them (models/layout.py)
+    w = maybe_dequantize(params[key], x.dtype, in_axis_of(key))
+    y = project(x, w, key)
     b = params.get(f"{key.removesuffix('_proj')}_bias")
     if b is not None:
         y = y + b
@@ -200,7 +203,14 @@ def _ssm_mixer(spec: ModelSpec, params: dict, x, state: dict, slots,
             jnp.full((width,), mult, x.dtype) for width, mult in zip(
                 (ssm.d_ssm, ssm.d_ssm, g * n, g * n, h), ssm.multipliers)
         ])
-        zxbcdt = _proj(x * ssm.in_multiplier, params, "ssm_in_proj") * mup
+        # the stored in_proj is zero-padded to whole lanes (models/layout.py).
+        # The product is taken at the STORED width and cut after the barrier:
+        # without it the compiler moves the cut onto the weight, and a
+        # [D, proj_dim] window of the layer's slice is no tile-aligned view,
+        # so every layer copies its in_proj out of the stack first (96 MB,
+        # 1.2 ms of a 13.2 ms chunk at 34B widths)
+        zxbcdt = _proj(x * ssm.in_multiplier, params, "ssm_in_proj")
+        zxbcdt = lax.optimization_barrier(zxbcdt)[:, : ssm.proj_dim] * mup
         z = zxbcdt[:, : ssm.d_ssm]
         xbc = zxbcdt[:, ssm.d_ssm : ssm.d_ssm + ssm.conv_dim]
         dt = zxbcdt[:, ssm.d_ssm + ssm.conv_dim :]

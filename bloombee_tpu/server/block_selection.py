@@ -228,9 +228,12 @@ def estimate_block_bytes(spec, dtype) -> int:
     if spec.ssm is not None:
         # a state-space mixer beside attention: in_proj, out_proj, the
         # convolution's taps and bias, the grouped norm, A_log / D / dt_bias
+        # (in_proj as it is stored: padded to whole lanes, models/layout.py)
+        from bloombee_tpu.models.layout import lane_padded
+
         ssm = spec.ssm
         mixer = (
-            d * ssm.proj_dim + ssm.d_ssm * d
+            d * lane_padded(ssm.proj_dim) + ssm.d_ssm * d
             + (ssm.conv + 1) * ssm.conv_dim + ssm.d_ssm + 3 * ssm.heads
         )
     itemsize = np.dtype(dtype).itemsize if dtype is not None else 2

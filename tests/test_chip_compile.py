@@ -269,8 +269,8 @@ def test_moe_decode_step_reads_the_expert_stacks_where_they_lie(v5e, b):
         (layers, *shape), bf16, sharding=one_chip)
     params = {
         "input_layernorm": s(d), "post_attention_layernorm": s(d),
-        "q_proj": s(d, heads * 128), "k_proj": s(d, 512),
-        "v_proj": s(d, 512), "o_proj": s(heads * 128, d),
+        "q_proj": s(heads * 128, d), "k_proj": s(512, d),
+        "v_proj": s(512, d), "o_proj": s(heads * 128, d),
         "q_norm": s(128), "k_norm": s(128), "router": s(d, e),
         "experts_gate": s(e, d, i), "experts_up": s(e, d, i),
         "experts_down": s(e, i, d),
@@ -340,16 +340,20 @@ def test_tp4_span_step_compiles_and_shards_for_v5e(v5e):
 # cell (cellbench/configs/falcon-h1-34b-span8.json: published widths, 8
 # layers, 1280 pages, 16 state slots) with the recurrent-state arena in the
 # carry, the attention kernels on
-def _falcon_h1_shapes(one_chip):
+def _cell_shapes(config_name, one_chip, pages=1280):
+    """(spec, params, arena, state) ShapeDtypeStructs of a benchmark cell's
+    span as the loaders store it (models/layout.py): q/k/v output-major,
+    the mixer's in_proj padded to whole lanes."""
     import json
     import pathlib
 
     from bloombee_tpu.kv.cache_manager import state_slots_for
     from bloombee_tpu.models.auto import spec_from_config_dict
+    from bloombee_tpu.models.layout import lane_padded
 
     config = json.loads((
         pathlib.Path(__file__).resolve().parents[1]
-        / "cellbench/configs/falcon-h1-34b-span8.json").read_text())
+        / f"cellbench/configs/{config_name}.json").read_text())
     config.pop("cellbench")
     spec = spec_from_config_dict(config)
     ssm, layers = spec.ssm, spec.num_hidden_layers
@@ -362,18 +366,31 @@ def _falcon_h1_shapes(one_chip):
 
     params = {
         "input_layernorm": s((d,)), "post_attention_layernorm": s((d,)),
-        "q_proj": s((d, h * hd)), "k_proj": s((d, kv * hd)),
-        "v_proj": s((d, kv * hd)), "o_proj": s((h * hd, d)),
-        "gate_proj": s((d, i)), "up_proj": s((d, i)), "down_proj": s((i, d)),
-        "ssm_in_proj": s((d, ssm.proj_dim)), "ssm_out_proj": s((ssm.d_ssm, d)),
+        "q_proj": s((h * hd, d)), "k_proj": s((kv * hd, d)),
+        "v_proj": s((kv * hd, d)), "o_proj": s((h * hd, d)),
+    }
+    if spec.qk_norm:
+        params.update(q_norm=s((hd,)), k_norm=s((hd,)))
+    if spec.num_experts:
+        e = spec.num_experts
+        params.update(
+            router=s((d, e)), experts_gate=s((e, d, i)),
+            experts_up=s((e, d, i)), experts_down=s((e, i, d)))
+    else:
+        params.update(
+            gate_proj=s((d, i)), up_proj=s((d, i)), down_proj=s((i, d)))
+    arena = s((pages * PAGE, kv, hd))
+    if ssm is None:
+        return spec, params, arena, None
+    params.update({
+        "ssm_in_proj": s((d, lane_padded(ssm.proj_dim))),
+        "ssm_out_proj": s((ssm.d_ssm, d)),
         "ssm_conv_w": s((ssm.conv, ssm.conv_dim)),
         "ssm_conv_b": s((ssm.conv_dim,)), "ssm_norm": s((ssm.d_ssm,)),
         "ssm_a_log": s((ssm.heads,), f32), "ssm_d": s((ssm.heads,), f32),
         "ssm_dt_bias": s((ssm.heads,), f32),
-    }
-    pages = 1280
+    })
     slots = state_slots_for(spec, pages, PAGE, 8)
-    arena = s((pages * PAGE, kv, hd))
     state = {
         "ssm": s((slots, ssm.heads, ssm.head_dim, ssm.state), f32),
         "conv": s((slots, ssm.conv - 1, ssm.conv_dim)),
@@ -381,7 +398,7 @@ def _falcon_h1_shapes(one_chip):
     return spec, params, arena, state
 
 
-def _falcon_h1_payload(spec, rows, plan_len, sharding):
+def _cell_payload(spec, rows, plan_len, sharding):
     return jax.ShapeDtypeStruct(
         (rows * spec.hidden_size + 2 * plan_len,), jnp.uint16,
         sharding=sharding)
@@ -400,13 +417,13 @@ _FALCON_H1_STEPS = {
 @pytest.mark.parametrize("name", sorted(_FALCON_H1_STEPS))
 def test_falcon_h1_span_step_compiles_for_v5e(v5e, name):
     one_chip = SingleDeviceSharding(v5e[0])
-    spec, params, arena, state = _falcon_h1_shapes(one_chip)
+    spec, params, arena, state = _cell_shapes("falcon-h1-34b-span8", one_chip)
     case = dict(_FALCON_H1_STEPS[name])
     b, t, pages = case.pop("b"), case.pop("t"), case.pop("pages")
     layers = spec.num_hidden_layers
     plan_len = b * t + b * pages + b * t + b + layers + b  # + state slots
     compiled = span_step_packed.lower(
-        params, arena, arena, _falcon_h1_payload(spec, b * t, plan_len, one_chip),
+        params, arena, arena, _cell_payload(spec, b * t, plan_len, one_chip),
         None, None, state,
         spec=spec, b=b, t=t, page_size=PAGE, max_pages=pages,
         windows=(0,) * layers, **case,
@@ -423,13 +440,74 @@ def test_falcon_h1_ragged_step_compiles_for_v5e(v5e, r, use_kernel):
     (20 heads x 256 rows is past the ragged kernel's gate: dense attention),
     a short tail + k rows the 64 bucket (the ragged kernel)."""
     one_chip = SingleDeviceSharding(v5e[0])
-    spec, params, arena, state = _falcon_h1_shapes(one_chip)
+    spec, params, arena, state = _cell_shapes("falcon-h1-34b-span8", one_chip)
     n_seqs, pages, layers = 4, 256, spec.num_hidden_layers
     plan_len = r + n_seqs * pages + r + n_seqs + r + layers + 3 * n_seqs + 1
     compiled = span_step_ragged.lower(
-        params, arena, arena, _falcon_h1_payload(spec, r, plan_len, one_chip),
+        params, arena, arena, _cell_payload(spec, r, plan_len, one_chip),
         None, state,
         spec=spec, r=r, n_seqs=n_seqs, page_size=PAGE, max_pages=pages,
         windows=(0,) * layers, use_kernel=use_kernel,
     ).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
+
+
+# ------------------------------------------------- stored layouts (PR 34)
+# A stacked weight lies on the device the way the step programs read it
+# (models/layout.py), so no span-step program of a benchmark cell holds a
+# copy of a parameter: stored [L, in, out], q/k/v were re-laid out before
+# the layer scan in every run (Mistral decode: 806 MB of temporaries), and
+# Falcon-H1's in_proj likewise at 9248 columns (758 MB).
+_CELL_SPANS = {
+    # cell -> (config, decode rows, temporaries' bound by program in MB;
+    # a fused pack's dense attention scores are its temporaries: no bound)
+    "mistral": ("mistral-7b-span16", 2, {"decode": 50, "chunk": 50}),
+    "qwen3moe": ("qwen3-30b-a3b-span4", 2, {"decode": 20, "chunk": 20}),
+    "falconh1": ("falcon-h1-34b-span8", 4, {"decode": 600, "chunk": 50}),
+}
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "fused"])
+@pytest.mark.parametrize("cell", sorted(_CELL_SPANS))
+def test_cell_span_step_copies_no_parameter(v5e, cell, program):
+    """The three cells' spans x (decode group at the 4096-token page bucket,
+    128-row chunk through flash, 256-row fused pack): the compiled text
+    holds no `copy` of a `stacked_params` parameter, and the temporaries
+    stay under what the program's activations need."""
+    import re
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    config, b, bounds = _CELL_SPANS[cell]
+    spec, params, arena, state = _cell_shapes(config, one_chip)
+    layers, pages = spec.num_hidden_layers, 256
+    common = dict(
+        spec=spec, page_size=PAGE, max_pages=pages, windows=(0,) * layers)
+    extra = () if state is None else (state,)
+    if program == "fused":
+        r, n_seqs = 256, 4
+        plan_len = r + n_seqs * pages + r + n_seqs + r + layers
+        plan_len += 0 if state is None else 3 * n_seqs + 1
+        compiled = span_step_ragged.lower(
+            params, arena, arena,
+            _cell_payload(spec, r, plan_len, one_chip), None, *extra,
+            r=r, n_seqs=n_seqs, use_kernel=False, **common,
+        ).compile()
+    else:
+        b, t, case = (
+            (b, 1, dict(use_paged=True)) if program == "decode"
+            else (1, 128, dict(use_flash=True, t_real=128))
+        )
+        plan_len = b * t + b * pages + b * t + b + layers
+        plan_len += 0 if state is None else b
+        compiled = span_step_packed.lower(
+            params, arena, arena,
+            _cell_payload(spec, b * t, plan_len, one_chip), None, None,
+            *extra, b=b, t=t, **case, **common,
+        ).compile()
+    text = compiled.as_text()
+    assert "%stacked_params__q_proj" in text  # the names the check reads
+    copied = re.findall(r"copy\([^)\n]*%(stacked_params\w+)", text)
+    assert not copied, copied
+    if program in bounds:
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < bounds[program] * 1e6, temp

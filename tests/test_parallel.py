@@ -324,9 +324,9 @@ def _rand_family_params(spec, seed, qkv_bias=False):
         return jnp.asarray(rng.normal(size=shape).astype(np.float32) * 0.05)
 
     p = {
-        "q_proj": w(d, h * hd),
-        "k_proj": w(d, kv * hd),
-        "v_proj": w(d, kv * hd),
+        "q_proj": w(h * hd, d),  # output-major (models/layout.py)
+        "k_proj": w(kv * hd, d),
+        "v_proj": w(kv * hd, d),
         "o_proj": w(h * hd, d),
         "up_proj": w(d, inter),
         "down_proj": w(inter, d),
