@@ -298,6 +298,17 @@ def main(argv=None):
                         line += "  moe " + " ".join(
                             f"{k}={v}" for k, v in sorted(moe.items())
                         )
+                    # a server that holds a share of the routed experts,
+                    # and what a cached token costs where the page is a
+                    # latent (deepseek_v2)
+                    if probe.get("experts_held"):
+                        first, count = probe["experts_held"]
+                        line += f"  experts_held={first}:{count}"
+                    if probe.get("latent_bytes_per_token"):
+                        line += (
+                            "  latent_bytes_per_token="
+                            f"{probe['latent_bytes_per_token']}"
+                        )
                     # where the host's time went (BBTPU_JITWATCH=1
                     # runs): the compute worker's wall time by cause —
                     # starved = no task existed, hop = one was queued and

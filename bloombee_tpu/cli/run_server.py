@@ -16,6 +16,16 @@ import asyncio
 import logging
 
 
+def parse_experts(text):
+    """'FIRST:COUNT' -> (first, count), None for no --experts."""
+    if not text:
+        return None
+    first, sep, count = text.partition(":")
+    if not sep or not first.isdigit() or not count.isdigit() or not int(count):
+        raise SystemExit(f"bad --experts {text!r}: need FIRST:COUNT")
+    return int(first), int(count)
+
+
 def parse_adapters(items):
     """NAME=DIR pairs (or bare DIRs, named by basename) -> {name: dir}."""
     if not items:
@@ -49,6 +59,15 @@ def main(argv=None):
                         help="'start:end' or omit for automatic selection")
     parser.add_argument("--num-blocks", type=int, default=None,
                         help="how many blocks to serve when auto-selecting")
+    parser.add_argument("--experts", default=None, metavar="FIRST:COUNT",
+                        help="hold only COUNT of each sparse layer's routed "
+                             "experts, from FIRST of the router's numbering "
+                             "(one chip's share of an expert-parallel "
+                             "deployment: the router still scores all of "
+                             "them, a pair whose expert is not held adds "
+                             "nothing here). Default: every expert the "
+                             "checkpoint has. Announced in rpc_info "
+                             "(experts_held)")
     parser.add_argument("--host", default="0.0.0.0")
     parser.add_argument("--public-host", default="127.0.0.1")
     parser.add_argument("--port", type=int, default=0)
@@ -237,7 +256,7 @@ def main(argv=None):
     from bloombee_tpu.models.hub import resolve_model_dir
 
     args.model_dir = resolve_model_dir(args.model_dir)
-    spec = load_spec(args.model_dir)
+    spec = load_spec(args.model_dir, parse_experts(args.experts))
     model_uid = args.model_uid or args.model_dir.rstrip("/").split("/")[-1]
 
     async def run():
@@ -278,6 +297,7 @@ def main(argv=None):
             tp=args.tp,
             sp=args.sp,
             kv_quant=args.kv_quant,
+            experts=parse_experts(args.experts),
             weight_quant=args.weight_quant,
             oversubscribe=args.oversubscribe,
             idle_park_s=args.idle_park_s,

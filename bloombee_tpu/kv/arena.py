@@ -47,11 +47,30 @@ def make_arena(
     head_dim: int,
     dtype=jnp.bfloat16,
     quant: str | None = None,
+    payload: tuple[tuple[int, ...], tuple[int, ...]] | None = None,
 ) -> dict:
     """quant="int4": store the slabs group-quantized (the reference's
     TorchCompressedDevice KV capacity lever, compression.py:22-210) — ~3.2x
     more tokens per HBM byte; writes quantize and reads dequantize inside
-    the jitted span step."""
+    the jitted span step.
+
+    `payload`: what a token's row holds in each of the two slabs when it is
+    not K and V head slabs; a latent-attention family declares it
+    (models/spec.py `MlaSpec.page_payload`: the latent in "k", the rotary
+    key in "v"). Addressing is unchanged: slots, pages, `flat_arena`,
+    `layer_slots`, `layer_pages`, `arena_write`, `gather_pages` only ever
+    see [L, S_tot, ...] and ids."""
+    if payload is not None:
+        if quant not in (None, "none"):
+            raise ValueError(
+                "a latent page (latent attention) has no int4 form: "
+                "--kv-quant is unsupported for this family"
+            )
+        s_tot = num_pages * page_size
+        return {
+            "k": jnp.zeros((num_layers, s_tot, *payload[0]), dtype),
+            "v": jnp.zeros((num_layers, s_tot, *payload[1]), dtype),
+        }
     shape = (num_layers, num_pages * page_size, n_kv_heads, head_dim)
     if quant == "int4":
         from bloombee_tpu.kv.quant import make_quant_slab

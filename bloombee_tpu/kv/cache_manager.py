@@ -210,6 +210,8 @@ class CacheManager:
         prefix_cache: bool | None = None,  # None -> BBTPU_PREFIX_CACHE env
         ssm=None,  # models.spec.SsmSpec: the family keeps recurrent state
         state_slots: int = 0,  # sequences that may hold a state slot at once
+        payload=None,  # a latent-attention family's page payload
+        # (models/spec.py MlaSpec.page_payload); None = K and V head slabs
     ):
         dtype = dtype or jnp.bfloat16
         if quant is None:
@@ -254,7 +256,7 @@ class CacheManager:
         else:
             self._make_arena = lambda: arena_ops.make_arena(
                 num_layers, num_pages, page_size, n_kv_heads, head_dim,
-                dtype, quant=self.quant,
+                dtype, quant=self.quant, payload=payload,
             )
         self.arena = self._make_arena()
         # recurrent state beside the pages (kv/arena.py): one slot per
@@ -829,18 +831,18 @@ class CacheManager:
         skipped; returns the number actually installed."""
         if not self.repl_supported:
             return 0
-        want = (
-            self.num_layers, self.page_size,
-        ) + tuple(self.arena["k"].shape[2:])
+        lead = (len(hashes), self.num_layers, self.page_size)
+        want = lead + tuple(self.arena["k"].shape[2:])
         k_pages = np.asarray(k_pages)
         v_pages = np.asarray(v_pages)
+        # the two slabs' rows differ for a latent page (latent | rotary key)
         if (
-            k_pages.shape != (len(hashes),) + want
-            or v_pages.shape != k_pages.shape
+            k_pages.shape != want
+            or v_pages.shape != lead + tuple(self.arena["v"].shape[2:])
         ):
             raise ValueError(
                 f"replicated page payload {k_pages.shape} does not match "
-                f"arena geometry {(len(hashes),) + want}"
+                f"arena geometry {want}"
             )
         pages, rows = [], []
         for i, h in enumerate(hashes):

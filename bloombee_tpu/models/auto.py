@@ -26,6 +26,9 @@ class Family:
         convert_block: Callable | None = None,
         loader: Callable | None = None,
         client_loader: Callable | None = None,
+        refine_spec: Callable | None = None,  # (spec, reader) -> spec: what
+        # only the checkpoint says (deepseek_v2: the router's width, the
+        # experts held)
     ):
         self.name = name
         self._spec_fn = spec_fn
@@ -39,6 +42,14 @@ class Family:
         self._convert_block = convert_block
         self._loader = loader
         self.client_loader = client_loader
+        self._refine_spec = refine_spec
+
+    def spec_from_reader(self, reader) -> ModelSpec:
+        """The spec of the checkpoint `reader` is open on."""
+        spec = self.spec_from_config_dict(reader.config)
+        if self._refine_spec is not None:
+            spec = self._refine_spec(spec, reader)
+        return spec
 
     def spec_from_config_dict(self, config: dict) -> ModelSpec:
         return self._spec_fn(SimpleNamespace(**config))
@@ -98,6 +109,7 @@ def _register_builtins() -> None:
     )
     # side-effect registrations
     import bloombee_tpu.models.bloom  # noqa: F401
+    import bloombee_tpu.models.deepseek_v2  # noqa: F401
     import bloombee_tpu.models.falcon  # noqa: F401
     import bloombee_tpu.models.falcon_h1  # noqa: F401
     import bloombee_tpu.models.gemma2  # noqa: F401

@@ -25,8 +25,11 @@ from bloombee_tpu.models.spec import ModelSpec
 class CheckpointReader:
     """Lazy tensor reader over a local HF model directory."""
 
-    def __init__(self, model_dir: str | pathlib.Path):
+    def __init__(self, model_dir: str | pathlib.Path, experts=None):
         self.dir = pathlib.Path(model_dir)
+        # (first, count) of the published expert numbering a server was told
+        # to hold (`run_server --experts`); None = all the checkpoint has
+        self.experts = experts
         with open(self.dir / "config.json") as f:
             self.config = json.load(f)
         index_path = self.dir / "model.safetensors.index.json"
@@ -82,15 +85,16 @@ def read_weight(reader: CheckpointReader, name: str, key: str, dtype=None):
 
 def stack_expert_weights(
     reader, expert_fmt: str, gate_name: str, up_name: str, down_name: str,
-    n_experts: int, dtype=None,
+    n_experts: int, dtype=None, first: int = 0,
 ) -> dict:
     """Stack per-expert gate/up/down matrices into [E, D, I] / [E, I, D]
     tensors (the dense-over-experts MoE layout shared by Mixtral and
-    Qwen3-MoE loaders). expert_fmt receives the expert index."""
+    Qwen3-MoE loaders). expert_fmt receives the expert index; `first` is
+    the first expert read (a server that holds a share of them)."""
     import jax.numpy as jnp
 
     gates, ups, downs = [], [], []
-    for e in range(n_experts):
+    for e in range(first, first + n_experts):
         p = expert_fmt.format(e)
         gates.append(read_tensor(reader, f"{p}.{gate_name}.weight", dtype).T)
         ups.append(read_tensor(reader, f"{p}.{up_name}.weight", dtype).T)
@@ -102,27 +106,54 @@ def stack_expert_weights(
     }
 
 
-def load_spec(model_dir: str) -> ModelSpec:
+def load_spec(model_dir: str, experts=None) -> ModelSpec:
     """ModelSpec from a local model dir via the family registry."""
-    from bloombee_tpu.models.auto import spec_from_config_dict
+    from bloombee_tpu.models.auto import get_family
 
-    reader = CheckpointReader(model_dir)
-    return spec_from_config_dict(reader.config)
+    reader = CheckpointReader(model_dir, experts)
+    return get_family(reader.model_type()).spec_from_reader(reader)
+
+
+def _stack_runs(layers: list[dict]) -> dict:
+    """Per-layer params -> the stacked dict, leaf by leaf, letting go of
+    each layer's tensor once it is stacked (the span is never held twice).
+    Layers of one kind (the same keys) are ONE stack; a span whose first
+    layers have other keys than the rest (a dense MLP before sparse ones)
+    is two, the leading run under models/layout.py `LEAD`."""
+    from bloombee_tpu.models.layout import LEAD
+    from bloombee_tpu.utils.tree import stack_params
+
+    kinds = [frozenset(p) for p in layers]
+    cut = next((i for i, k in enumerate(kinds) if k != kinds[0]), len(layers))
+    if any(k != kinds[-1] for k in kinds[cut:]):
+        raise NotImplementedError(
+            "a span of more than two runs of same-kind layers"
+        )
+    out = {}
+    for prefix, run in (
+        ((LEAD, layers[:cut]), ("", layers[cut:])) if cut < len(layers)
+        else (("", layers),)
+    ):
+        for key in list(run[0]):
+            out[prefix + key] = stack_params([p.pop(key) for p in run])
+    return out
 
 
 def load_span_params(
     model_dir: str, start: int, end: int, dtype=None,
-    adapter_dirs: list[str] | None = None,
+    adapter_dirs: list[str] | None = None, experts=None,
 ):
     """Stacked per-layer params for blocks [start, end), with optional LoRA
     adapters merged into the base weights (W' = W + alpha/r * B A — the
     capability of the reference's utils/peft.py LoraLinear; merging at load
     keeps the serving path a plain matmul)."""
     from bloombee_tpu.models.auto import get_family
-    from bloombee_tpu.utils.tree import stack_params
 
-    reader = CheckpointReader(model_dir)
+    reader = CheckpointReader(model_dir, experts)
     family = get_family(reader.model_type())
+    spec = family.spec_from_reader(reader)
+    if experts is not None and not spec.num_experts:
+        raise ValueError(f"--experts given, but {spec.family} has no experts")
     adapters = [LoraAdapter(d) for d in (adapter_dirs or [])]
     layers = []
     for i in range(start, end):
@@ -130,17 +161,11 @@ def load_span_params(
         for adapter in adapters:
             params = adapter.merge_into(params, i)
         layers.append(params)
-    spec = family.spec_from_config_dict(reader.config)
     if spec.heterogeneous:
         # per-layer shapes differ (gemma-4): no stacking — the hetero span
         # step unrolls over a tuple of per-layer param dicts
         return tuple(layers), spec
-    # leaf by leaf, letting go of each layer's tensor once it is stacked:
-    # the span is never held twice (one stacked leaf over it at most)
-    return {
-        key: stack_params([params.pop(key) for params in layers])
-        for key in list(layers[0])
-    }, spec
+    return _stack_runs(layers), spec
 
 
 def load_span_params_split(

@@ -25,9 +25,41 @@ key as the checkpoint has it and transposes the rest; apply it through
 from __future__ import annotations
 
 # stored [out, in] (stacked [L, out, in]); every other projection [in, out]
-OUT_MAJOR_KEYS = frozenset(("q_proj", "k_proj", "v_proj"))
+OUT_MAJOR_KEYS = frozenset((
+    "q_proj", "k_proj", "v_proj",
+    # latent attention's projections out of the hidden rows and the query's
+    # up-projection: as the checkpoint has them, like q/k/v
+    "q_a_proj", "q_b_nope", "q_b_rope", "kv_a_proj", "router_t",
+    # kv_b's two halves [heads, dim, kv_rank]: the checkpoint's rows
+    "kv_b_k", "kv_b_v",
+))
 
 LANES = 128  # the TPU's vector width: a minor dimension is tiled by it
+
+# A span whose first layers are of another KIND of MLP than the rest (a
+# dense layer before sparse ones: deepseek_v2) is stored as two stacks in
+# the one params dict: the leading run's leaves under this prefix, the
+# rest under the plain keys. Each run is scanned on its own (runtime/step.py)
+# with the hidden rows and the one flat arena carried through both; a span
+# of one kind has no such keys and is what it always was.
+LEAD = "lead."
+
+
+def split_runs(stacked: dict) -> tuple[dict | None, dict]:
+    """(the leading run's stack under plain keys or None, the main stack)."""
+    lead = {k[len(LEAD):]: w for k, w in stacked.items() if k.startswith(LEAD)}
+    if not lead:
+        return None, stacked
+    return lead, {k: w for k, w in stacked.items() if not k.startswith(LEAD)}
+
+
+def stacked_layers(stacked: dict) -> int:
+    """How many layers a stacked params dict holds, both runs."""
+    import jax
+
+    lead, main = split_runs(stacked)
+    n = jax.tree.leaves(main)[0].shape[0]
+    return n if lead is None else n + jax.tree.leaves(lead)[0].shape[0]
 
 
 def in_axis_of(key: str) -> int:

@@ -48,6 +48,63 @@ class SsmSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class MlaSpec:
+    """Multi-head latent attention (deepseek_v2): queries and keys/values
+    go through low-rank projections with their own RMSNorms, and the cache
+    keeps per token and layer ONE latent of `kv_rank` values (after its
+    norm) and ONE rotary key of `rope_dim` values shared by all heads (after
+    rotary) instead of per-head K and V. Nested and hashable like `SsmSpec`;
+    it declares the page payload the arena has to hold (`page_payload`)."""
+
+    q_rank: int  # q_lora_rank
+    kv_rank: int  # kv_lora_rank: the cached latent's width
+    nope_dim: int  # per head, the part of q/k without positions
+    rope_dim: int  # per head q, ONE shared k: the rotary part
+    v_dim: int  # per head
+    # YaRN (rope_scaling type "yarn"); factor 1.0 = plain rotary
+    rope_factor: float = 1.0
+    rope_original_max: int = 4096
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+    @property
+    def page_payload(self) -> tuple[tuple[int, ...], tuple[int, ...]]:
+        """Trailing shape of a token's row in the arena's two slabs: the
+        latent in `k`, the rotary key in `v` (two slabs, not one of
+        kv_rank + rope_dim: the absorbed kernel reads the latent alone as
+        its values). The rotary key's row is padded with zeros to whole
+        lanes: with 64 values in the minor dimension every span-step
+        program re-laid the whole slab out, once a run."""
+        from bloombee_tpu.models.layout import lane_padded
+
+        return (self.kv_rank,), (lane_padded(self.rope_dim),)
+
+    @property
+    def token_bytes(self) -> int:
+        """Bytes a cached token takes in ONE layer at 2 bytes a value."""
+        return 2 * sum(shape[0] for shape in self.page_payload)
+
+    @property
+    def softmax_scale(self) -> float:
+        """qk_dim ** -0.5, times mscale ** 2 under YaRN with
+        `mscale_all_dim` (the published modeling_deepseek.py)."""
+        from bloombee_tpu.ops.rotary import yarn_mscale
+
+        scale = self.qk_dim**-0.5
+        if self.rope_factor != 1.0 and self.rope_mscale_all_dim:
+            scale *= yarn_mscale(
+                self.rope_factor, self.rope_mscale_all_dim
+            ) ** 2
+        return scale
+
+
+@dataclasses.dataclass(frozen=True)
 class ModelSpec:
     family: str
     hidden_size: int
@@ -109,6 +166,35 @@ class ModelSpec:
     attention_out_multiplier: float = 1.0
     mlp_multipliers: tuple[float, float] = (1.0, 1.0)
     lm_head_multiplier: float = 1.0
+    # latent attention (deepseek_v2); None = per-head K and V in the cache
+    mla: MlaSpec | None = None
+    # deepseek-style sparse layers: the softmax router picks `top_k` among
+    # the experts of the `moe_topk_groups` best of `moe_groups` groups and
+    # scales the weights (moe_groups 0 = no groups); shared experts of one
+    # fused width run beside the routed ones; the first
+    # `first_dense_layers` layers of the MODEL have a dense MLP of
+    # `intermediate_size`, the others experts of `moe_intermediate_size`
+    moe_groups: int = 0
+    moe_topk_groups: int = 0
+    moe_route_scale: float = 1.0
+    moe_shared_intermediate: int = 0
+    moe_intermediate_size: int = 0
+    first_dense_layers: int = 0
+    # the experts this server holds, [first, first + count) of the router's
+    # numbering (run_server --experts); None = all of them. The router
+    # still scores all `num_experts`; a pair whose expert is not held adds
+    # nothing here (its chip adds it)
+    moe_held: tuple[int, int] | None = None
+
+    @property
+    def experts_held(self) -> tuple[int, int]:
+        return self.moe_held or (0, self.num_experts)
+
+    def mlp_kind(self, layer_idx: int) -> str:
+        """"dense" or "sparse": a property of the LAYER (absolute index)."""
+        if self.num_experts and layer_idx >= self.first_dense_layers:
+            return "sparse"
+        return "dense"
 
     def window_for_layer(self, layer_idx: int) -> int:
         return (

@@ -32,7 +32,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from bloombee_tpu.models.layout import in_axis_of
+from bloombee_tpu.models.layout import LEAD, in_axis_of
 
 GROUP = 32
 
@@ -45,6 +45,10 @@ QUANT_KEYS = (
     # falcon_h1's mixer: its two projections only (the convolution's taps,
     # A_log, D, dt_bias and the norms stay as the checkpoint has them)
     "ssm_in_proj", "ssm_out_proj",
+    # deepseek_v2: latent attention's projections and the shared experts
+    # (the router and the norms stay as the checkpoint has them)
+    "q_a_proj", "q_b_nope", "q_b_rope", "kv_a_proj", "kv_b_k", "kv_b_v",
+    "shared_gate", "shared_up", "shared_down",
 )
 
 
@@ -139,8 +143,9 @@ def quantize_span_params(stacked: dict, bits: int) -> dict:
     (norms, biases, router) pass through dense."""
     out = {}
     for key, leaf in stacked.items():
-        if key in QUANT_KEYS and getattr(leaf, "ndim", 0) >= 3:
-            out[key] = quantize_weight(leaf, bits, in_axis_of(key))
+        name = key.removeprefix(LEAD)  # a span's leading run of layers
+        if name in QUANT_KEYS and getattr(leaf, "ndim", 0) >= 3:
+            out[key] = quantize_weight(leaf, bits, in_axis_of(name))
         else:
             out[key] = leaf
     return out

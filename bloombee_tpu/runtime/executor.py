@@ -10,8 +10,10 @@ executable (the CUDA-graph role of the reference's cuda_graphs.py).
 
 from __future__ import annotations
 
+import collections
 import functools
 import logging
+import threading
 
 import numpy as np
 
@@ -188,6 +190,23 @@ class SpanExecutor:
                     f"{spec.family} needs a CacheManager with state slots "
                     "(ssm=spec.ssm, state_slots=...)"
                 )
+        if spec.mla is not None:
+            # the latent page is attended by the single-chip span step's own
+            # paths (runtime/layer_body.py `_mla_attention`); nothing shards
+            # a latent over heads or streams one layer's weights at a time
+            for on, what in (
+                (mesh is not None, "--tp (tensor-parallel serving)"),
+                (sp_mesh is not None, "--sp (sequence-parallel prefill)"),
+                (bool(host_layers), "weight offload"),
+                (manager.quant is not None, "--kv-quant"),
+                (attn_sparsity < 1.0, "--attn-sparsity"),
+            ):
+                if on:
+                    raise ValueError(
+                        f"{what} unsupported for {spec.family}: the cache "
+                        "holds one latent and one rotary key a token, "
+                        "attended by the single-chip span step only"
+                    )
         self.mesh = mesh
         self.sp_mesh = sp_mesh
         self._sp_params = None
@@ -311,6 +330,16 @@ class SpanExecutor:
         # a family with experts: device dispatches by the form its experts
         # took (ops/moe.py), counted from the bucket's rows
         self.moe_dispatches = {"grouped": 0, "dense": 0}
+        # a server that holds a share of the experts (spec.moe_held): what
+        # each step's rows reached of them per sparse layer (ops/moe.py
+        # `held_reach`), handed out of the step program as a device array
+        # and read at the next `fetch`, off the compute thread
+        self._reach_pending = collections.deque(maxlen=256)
+        self._reach_lock = threading.Lock()
+        self.moe_reach = {
+            "steps": 0, "rows": 0, "routed_pairs_here": 0,
+            "rows_with_held_expert": 0, "held_hit_last": [],
+        }
 
     # ------------------------------------------------------------------ steps
     def prefill(
@@ -559,7 +588,16 @@ class SpanExecutor:
             return "sliding-window layers"
         if has_tree and self.spec.ssm is not None:
             return "recurrent state (tree rows would branch it)"
+        if has_tree and self.spec.mla is not None:
+            return "latent attention (no tree mask in its kernels)"
         return None
+
+    @property
+    def one_chunk_a_pack(self) -> bool:
+        """A ragged pack may hold ONE sequence of more than one row: the
+        state-space mixer and latent attention run their chunk form on one
+        (runtime/layer_body.py)."""
+        return self.spec.ssm is not None or self.spec.mla is not None
 
     def ragged_group(
         self,
@@ -646,10 +684,10 @@ class SpanExecutor:
             # sequences on its own; a pack of single rows went the packed
             # way above, and tree rows are refused)
             multi = [i for i, c in enumerate(counts) if c > 1]
-            if spec.ssm is not None and len(multi) != 1:
+            if self.one_chunk_a_pack and len(multi) != 1:
                 raise ValueError(
-                    "ragged_group unsupported: recurrent state (a pack "
-                    "takes ONE sequence of more than one row)"
+                    "ragged_group unsupported: recurrent state or a latent "
+                    "cache (a pack takes ONE sequence of more than one row)"
                 )
             # the tree-mask variant keeps every row's in-step width static:
             # causal members' rows become lower-triangular tree rows, so one
@@ -741,7 +779,7 @@ class SpanExecutor:
                 )
                 tag = f"r{rb},s{sb},p{pb}"
             step_kwargs = {"t_max": t_max} if has_tree else {}
-            if spec.ssm is not None:
+            if self.one_chunk_a_pack:
                 row0 = np.zeros((sb,), np.int32)
                 row0[:n_seqs] = np.cumsum([0] + counts[:-1])
                 counts_pad = np.zeros((sb,), np.int32)
@@ -760,7 +798,9 @@ class SpanExecutor:
                 self._paged_kernel_ok(pb * self.page_size)
                 and self.mesh is None
                 and self.manager.quant is None
-                and rb * spec.num_attention_heads <= 2048
+                # latent attention's kernels block their queries themselves
+                and (spec.mla is not None
+                     or rb * spec.num_attention_heads <= 2048)
             )
 
             payload = pack_step_payload(h_pad, plan)
@@ -799,7 +839,7 @@ class SpanExecutor:
         )
         self.attn_dispatches["ragged" if used_kernel else "dense"] += 1
         self._count_moe(rb, used_kernel)
-        out = self._keep_arena(result)
+        out = self._keep_arena(result, "fused", r, starts)
         with jitwatch.span("bbtpu.slice"):
             return out[0, :r], combined
 
@@ -815,10 +855,45 @@ class SpanExecutor:
         sync — the convoy BB011 flags statically)."""
         jitwatch.host_sync("executor.fetch")
         if isinstance(out, (list, tuple)):
-            return np.concatenate(  # bbtpu: noqa[BB011] wire-bound d2h by contract; hot dispatchers use fetch=False and fetch off-queue
+            host = np.concatenate(  # bbtpu: noqa[BB011] wire-bound d2h by contract; hot dispatchers use fetch=False and fetch off-queue
                 [np.asarray(o) for o in out], axis=1
             ).astype(self.transfer_dtype)
-        return np.asarray(out).astype(self.transfer_dtype)  # bbtpu: noqa[BB011] wire-bound d2h by contract; hot dispatchers use fetch=False and fetch off-queue
+        else:
+            host = np.asarray(out).astype(self.transfer_dtype)  # bbtpu: noqa[BB011] wire-bound d2h by contract; hot dispatchers use fetch=False and fetch off-queue
+        self._drain_reach()
+        return host
+
+    def _drain_reach(self) -> None:
+        """Count what the finished steps reached of the held experts: each
+        is one zero-length `bbtpu.moe_reach` span (the step's `kind` and
+        `rows`; per sparse layer `held_hit`, `routed_pairs_here`,
+        `rows_with_held_expert`) and an increment of `moe_reach` (rpc_info).
+        Only arrays the device has finished are read: no wait is added.
+        Several fetch threads may come here at once: a step is taken, and
+        the sums moved, under the lock; the read and the span are not."""
+        done = []
+        with self._reach_lock:
+            while self._reach_pending and self._reach_pending[0][2].is_ready():
+                done.append(self._reach_pending.popleft())
+        for kind, rows, reach in done:
+            hit, pairs, held_rows = (
+                [int(v) for v in col] for col in np.asarray(reach).T  # bbtpu: noqa[BB011] a finished [layers, 3] counter, read off the compute thread
+            )
+            with jitwatch.span(
+                "bbtpu.moe_reach", kind=kind, rows=rows,
+                # `;`-separated: the profiler cuts an id at a comma
+                held_hit=";".join(map(str, hit)),
+                routed_pairs_here=";".join(map(str, pairs)),
+                rows_with_held_expert=";".join(map(str, held_rows)),
+            ):
+                pass
+            with self._reach_lock:
+                m = self.moe_reach
+                m["steps"] += 1
+                m["rows"] += rows
+                m["routed_pairs_here"] += sum(pairs)
+                m["rows_with_held_expert"] += sum(held_rows)
+                m["held_hit_last"] = hit
 
     def decode_n(
         self,
@@ -851,6 +926,8 @@ class SpanExecutor:
             raise ValueError("decode_n + quantized KV arena not supported")
         if spec.ssm is not None:
             raise ValueError("decode_n + recurrent state not supported")
+        if spec.mla is not None:
+            raise ValueError("decode_n + latent attention not supported")
         if self.attn_sparsity < 1.0:
             # the per-step path recomputes top-k from the CURRENT context
             # length every step; a k frozen at trace time would diverge
@@ -971,9 +1048,25 @@ class SpanExecutor:
             arena["state"] = self.manager.state
         return arena
 
-    def _keep_arena(self, result):
+    def _keep_arena(self, result, kind: str, rows: int, starts):
         """Store a span step's returned arenas (K, V and, where the family
-        has one, the state arena) on the manager; returns the step's output."""
+        has one, the state arena) on the manager; returns the step's output.
+        A latent-attention family's step is stamped as it is dispatched,
+        the zero-length span `bbtpu.step`: its `kind` ("decode" | "chunk" |
+        "fused"), real `rows` and `context` (its sequences' mean cached
+        tokens before it, `starts`): the attention core's time follows the
+        context, and a trace's reader has to know WHICH steps it holds.
+        `kind` and `rows` are also kept beside what the rows reached of the
+        held experts, where the step says it."""
+        if self.spec.mla is not None:
+            with jitwatch.span(
+                "bbtpu.step", kind=kind, rows=rows,
+                context=int(np.mean(starts)),
+            ):
+                pass
+        if self.spec.moe_held is not None:
+            *result, reach = result
+            self._reach_pending.append((kind, rows, reach))
         out, new_k, new_v, *rest = result
         self.manager.arena = {"k": new_k, "v": new_v}
         if rest:
@@ -984,7 +1077,8 @@ class SpanExecutor:
         """[bucket] state slots of the handle's sequences; padding rows get
         the pool's size, which no slot has (read clamped, write dropped)."""
         slots = np.full((bucket,), self.manager.num_state_slots, np.int32)
-        slots[: handle.batch_size] = self.manager.state_slots(handle)
+        if self.manager.state is not None:
+            slots[: handle.batch_size] = self.manager.state_slots(handle)
         return slots
 
     def _count_moe(self, rows: int, kernels: bool) -> None:
@@ -1175,6 +1269,13 @@ class SpanExecutor:
                 "tree verify unsupported: a recurrent state cannot branch "
                 "over tree rows or be cut back to the accepted ones"
             )
+        if spec.mla is not None and (
+            tree_mask is not None or depths is not None
+        ):
+            raise ValueError(
+                "tree verify unsupported: latent attention's kernels take "
+                "no tree mask"
+            )
 
         with jitwatch.span("bbtpu.pack"):
             # over-subscribed servers may have parked this session's KV to
@@ -1249,6 +1350,12 @@ class SpanExecutor:
                 and tb * self.spec.num_attention_heads <= 2048
                 and (tree_mask is None or all(w == 0 for w in self.windows))
             )
+            if self.spec.mla is not None:
+                # latent attention: the paged decode kernel for T == 1, the
+                # flash form over the gathered latent rows for any chunk
+                # (it blocks its queries itself); `use_paged` says kernels
+                # may run in this program, the experts' grouped form too
+                t1_ok = chunk_ok = True
             use_paged = bool(
                 self._paged_kernel_ok(pb * self.page_size)
                 and self.attn_sparsity >= 1.0  # kernel has no top-k path
@@ -1267,6 +1374,7 @@ class SpanExecutor:
                 self.mesh is None  # Pallas kernels don't GSPMD-partition
                 # (attn_sparsity is decode-only, so flash PREFILL is unaffected)
                 and not self.spec.heterogeneous
+                and self.spec.mla is None  # its own flash form (use_paged)
                 and tree_mask is None
                 and tb >= 128
                 and tb % 128 == 0
@@ -1373,7 +1481,9 @@ class SpanExecutor:
             result, use_paged = self._dispatch(
                 _run, use_paged, arena, "span step"
             )
-            out = self._keep_arena(result)
+            out = self._keep_arena(
+                result, "decode" if t == 1 else "chunk", b * t, starts
+            )
         path = "paged" if use_paged else "flash" if use_flash else "dense"
         self.attn_dispatches[path] += 1
         self._count_moe(bb * tb, use_paged and not self.spec.heterogeneous)

@@ -44,6 +44,8 @@ slab and plain ids. To the code below both are "a slab and ids into it".
 
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import NamedTuple
 
 import jax
@@ -55,6 +57,7 @@ from bloombee_tpu.models.layout import in_axis_of, project
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.models.wquant import maybe_dequantize
 from bloombee_tpu.ops import apply_rotary, rms_norm, silu_mlp
+from bloombee_tpu.ops.rotary import _rotate_half
 from bloombee_tpu.ops.alibi import alibi_slopes
 from bloombee_tpu.ops.attention import NEG_INF, repeat_kv
 from bloombee_tpu.ops.moe import moe_mlp
@@ -90,16 +93,41 @@ def _proj(x, params, key, lora=None):
     return y
 
 
+class _Reach(threading.local):
+    sink: list | None = None
+
+
+_reach = _Reach()
+
+
+@contextlib.contextmanager
+def collecting_reach():
+    """The list a sparse layer traced inside this block appends its
+    `ops/moe.py held_reach` to (a server told which experts it holds:
+    `spec.moe_held`). The span step's scan opens it around one layer's body
+    and carries what it finds out as the scan's ys: the MLP sits three calls
+    under the layer body, and no signature between them changes for it."""
+    prev, _reach.sink = _reach.sink, []
+    try:
+        yield _reach.sink
+    finally:
+        _reach.sink = prev
+
+
 def _mlp(x, params, spec, lora=None):
-    if spec.num_experts:
+    # the MLP's kind is the LAYER's: a family whose first layers are dense
+    # (deepseek_v2) hands such a layer no router
+    if spec.num_experts and ("router" in params or "router_t" in params):
         with jax.named_scope("moe_experts"):
             gate, up, down = (
                 maybe_dequantize(params[k], x.dtype)
                 for k in ("experts_gate", "experts_up", "experts_down")
             )
-        return moe_mlp(
+        out = moe_mlp(
             x,
-            params["router"],
+            # the router's weight [D, E], or (deepseek_v2: 160 columns are
+            # no whole lanes) output-major [E, D] as the checkpoint has it
+            params.get("router"),
             gate,
             up,
             down,
@@ -110,9 +138,39 @@ def _mlp(x, params, spec, lora=None):
             # `lift_expert_stacks`): the grouped form, by index
             expert_base=params.get("expert_base"),
             interpret=env.get("BBTPU_PAGED_INTERPRET"),
+            groups=spec.moe_groups,
+            topk_groups=spec.moe_topk_groups,
+            route_scale=spec.moe_route_scale,
+            held=spec.moe_held,
+            router_logits=(
+                _router_logits_f32(x, params["router_t"])
+                if "router_t" in params else None
+            ),
+            reach_out=_reach.sink,
         )
+        if spec.moe_shared_intermediate:
+            with jax.named_scope("moe_shared"):
+                out = out + silu_mlp(
+                    x, *(
+                        maybe_dequantize(params[k], x.dtype) for k in
+                        ("shared_gate", "shared_up", "shared_down")
+                    ),
+                ).astype(out.dtype)
+        return out
     with jax.named_scope("mlp"):
         return _dense_mlp(x, params, spec, lora)
+
+
+def _router_logits_f32(x, router_t):
+    """The router's product in float32 at full precision, as the published
+    DeepSeek-V2 gate computes it (`F.linear(x.float(), w.float())`): a score
+    rounded to bfloat16 flips a near-tie, and with weights times 16 a
+    flipped expert is no rounding. `router_t` is [E, D], output-major."""
+    with jax.named_scope("moe_router"):
+        return jnp.einsum(
+            "...d,ed->...e", x.astype(jnp.float32),
+            router_t.astype(jnp.float32), precision=lax.Precision.HIGHEST,
+        )
 
 
 def _dense_mlp(x, params, spec, lora=None):
@@ -168,13 +226,11 @@ class SsmRows(NamedTuple):
     step_form: bool  # static: sequences with nt == 1 take one recurrence step
 
 
-def packed_ssm_rows(b: int, t: int, q_positions, state_slots, num_slots,
-                    t_real) -> SsmRows:
-    """SsmRows of a packed [B, T] step: sequence i owns rows i*T..i*T+T-1,
-    of which `t_real` are real (a bucket's tail is not); a padding row of
-    the batch bucket (slot out of range) has none."""
+def packed_rows(b: int, t: int, q_positions, real, t_real) -> SsmRows:
+    """Whose the flat rows of a packed [B, T] step are: sequence i owns rows
+    i*T..i*T+T-1, of which `t_real` are real (a bucket's tail is not); a
+    padding row of the batch bucket (`real` [B] false) has none."""
     seqs = jnp.arange(b, dtype=jnp.int32)
-    real = (state_slots >= 0) & (state_slots < num_slots)
     n = jnp.asarray(t if t_real is None else t_real, jnp.int32)
     return SsmRows(
         q_seq=jnp.repeat(seqs, t),
@@ -185,6 +241,14 @@ def packed_ssm_rows(b: int, t: int, q_positions, state_slots, num_slots,
         window=t,
         step_form=t == 1,
     )
+
+
+def packed_ssm_rows(b: int, t: int, q_positions, state_slots, num_slots,
+                    t_real) -> SsmRows:
+    """`packed_rows` for a family with recurrent state: a padding row of
+    the batch bucket is one whose state slot is out of range."""
+    real = (state_slots >= 0) & (state_slots < num_slots)
+    return packed_rows(b, t, q_positions, real, t_real)
 
 
 def _ssm_mixer(spec: ModelSpec, params: dict, x, state: dict, slots,
@@ -309,6 +373,180 @@ def _mix(spec, params, x, ssm):
     return m.reshape(x.shape) * spec.ssm.out_multiplier, state
 
 
+def _mla_attention(spec: ModelSpec, page_size: int, params: dict, x,
+                   c_slab, pe_slab, cos, sin, slots, page_table, q_pos,
+                   total_lens, rows: SsmRows, kernels: bool, lora=None):
+    """Latent attention (models/spec.py MlaSpec) on flat rows: x [R, D] (the
+    layer's normed input) -> (attention output [R, D] after o_proj, the two
+    slabs). ABSORBED throughout: queries go through W_kvb's key half into
+    the latent's space, attend the cached latents and rotary keys, and the
+    result comes back through W_kvb's value half; per-head keys and values
+    of a context never exist. A sequence with one row (a decode row) streams
+    its latent pages out of the arena (ops/pallas/latent_attention.py
+    `paged_decode_attention_latent`); one with more (a prefill chunk) runs
+    the flash form over its gathered latent rows. The packed decode step,
+    the solo chunk and the fused ragged pack are this one code path, told
+    apart by `rows`. `cos`/`sin` are [R, rope_dim]."""
+    from bloombee_tpu.ops.pallas.latent_attention import (
+        latent_attend_dense,
+        latent_flash_attention,
+        paged_decode_attention_latent,
+    )
+
+    mla = spec.mla
+    r = x.shape[0]
+    h = spec.num_attention_heads
+    scale = mla.softmax_scale
+    interpret = env.get("BBTPU_PAGED_INTERPRET")
+    pe_pad = pe_slab.shape[-1] - mla.rope_dim  # the slab's rows are whole lanes
+    kv_b_k, kv_b_v = (
+        maybe_dequantize(params[k], x.dtype, in_axis_of(k))
+        for k in ("kv_b_k", "kv_b_v")
+    )
+
+    def rope(z, cos, sin):
+        # the loader stored the rotary rows de-interleaved (evens, then
+        # odds: models/deepseek_v2.py), so this is the plain half-rotation
+        return z * cos.astype(z.dtype) + _rotate_half(z) * sin.astype(z.dtype)
+
+    # everything per head is kept HEAD-major [H, R, .]: the head is the
+    # batch dimension of both absorb products, so that is how they leave
+    # their results, and the flash form takes and gives that layout
+    with jax.named_scope("attn_proj"):
+        with jax.named_scope("mla_q"):
+            c_q = rms_norm(
+                _proj(x, params, "q_a_proj", lora), params["q_a_norm"],
+                spec.rms_norm_eps,
+            )
+            # the barrier keeps the per-head reshape on the PRODUCT: without
+            # it a decode program moves it onto the weight and every layer
+            # copies its q_b_rope out of the stack first (25 MB a layer)
+            q_nope, q_rope = lax.optimization_barrier((
+                _proj(c_q, params, "q_b_nope", lora),
+                _proj(c_q, params, "q_b_rope", lora),
+            ))
+            q_nope = q_nope.reshape(r, h, mla.nope_dim).transpose(1, 0, 2)
+            q_pe = rope(
+                q_rope.reshape(r, h, mla.rope_dim), cos[:, None], sin[:, None]
+            ).transpose(1, 0, 2)
+            q_pe = jnp.pad(q_pe, ((0, 0), (0, 0), (0, pe_pad)))
+        with jax.named_scope("mla_kv"):
+            ckv = _proj(x, params, "kv_a_proj", lora)
+            c_kv = rms_norm(
+                ckv[:, : mla.kv_rank], params["kv_a_norm"], spec.rms_norm_eps
+            )
+            k_pe = jnp.pad(
+                rope(ckv[:, mla.kv_rank :], cos, sin), ((0, 0), (0, pe_pad))
+            )
+    with jax.named_scope("arena_write"), jax.named_scope("latent_io"):
+        c_slab, pe_slab = arena_write(c_slab, pe_slab, slots, c_kv, k_pe)
+
+    def absorb_q(qn):  # [H, n, nope] -> [H, n, C]
+        with jax.named_scope("attn_proj"), jax.named_scope("mla_absorb"):
+            return jnp.einsum("hrn,hnc->hrc", qn, kv_b_k)
+
+    def absorb_o(o):  # [H, n, C] -> [n, H, v]
+        with jax.named_scope("attn_proj"), jax.named_scope("mla_absorb"):
+            return jnp.einsum("hrc,hvc->rhv", o, kv_b_v)
+
+    def gathered(pages):  # [n, NP] -> ([n, S, C], [n, S, R])
+        with jax.named_scope("arena_gather"), jax.named_scope("latent_io"):
+            return (
+                gather_pages(c_slab, pages, page_size).astype(x.dtype),
+                gather_pages(pe_slab, pages, page_size).astype(x.dtype),
+            )
+
+    heads = jnp.zeros((r, h, mla.v_dim), x.dtype)
+    if rows.step_form:
+        # the sequences with one row: absorbed on those rows alone
+        one = rows.nt == 1
+        at = jnp.clip(rows.row0, 0, r - 1)
+        lens_one = jnp.where(one, total_lens, 0)
+        ql_one = absorb_q(q_nope[:, at])  # [H, S, C]
+        qp_one = q_pe[:, at]
+        if kernels:
+            with jax.named_scope("attention"), jax.named_scope(
+                "mla_attention"
+            ):
+                o = paged_decode_attention_latent(
+                    ql_one.transpose(1, 0, 2), qp_one.transpose(1, 0, 2),
+                    c_slab, pe_slab, page_table, lens_one,
+                    page_size=page_size, scale=scale, interpret=interpret,
+                ).transpose(1, 0, 2)
+        else:
+            c_ctx, pe_ctx = gathered(page_table)
+            with jax.named_scope("attention"), jax.named_scope(
+                "mla_attention"
+            ):
+                o = latent_attend_dense(
+                    ql_one.transpose(1, 0, 2)[:, :, None],
+                    qp_one.transpose(1, 0, 2)[:, :, None], c_ctx, pe_ctx,
+                    (lens_one - 1)[:, None], lens_one, scale,
+                )[:, :, 0].transpose(1, 0, 2)
+        heads = heads.at[jnp.where(one, rows.row0, r)].set(
+            absorb_o(o), mode="drop"
+        )
+    w = rows.window
+    whole = rows.row0.shape[0] == 1 and w == r  # one sequence owns every row
+    for i in range(rows.chunk_seqs.shape[0]):
+        c = rows.chunk_seqs[i]
+        r0, n_c = rows.row0[c], rows.nt[c]
+
+        def take(z):  # rows r0 .. r0 + w of [H, R, .]
+            if whole:
+                return z
+            pad = jnp.zeros((h, w, z.shape[-1]), z.dtype)
+            return lax.dynamic_slice_in_dim(
+                jnp.concatenate([z, pad], axis=1), r0, w, axis=1
+            )
+
+        q_lat = absorb_q(take(q_nope))
+        c_ctx, pe_ctx = gathered(page_table[c][None])
+        start = q_pos[jnp.clip(r0, 0, r - 1)]
+        with jax.named_scope("attention"), jax.named_scope("mla_attention"):
+            if kernels:
+                o_c = latent_flash_attention(
+                    q_lat, take(q_pe), c_ctx[0], pe_ctx[0], start,
+                    total_lens[c], n_c, scale=scale, interpret=interpret,
+                )
+            else:
+                o_c = latent_attend_dense(
+                    q_lat[None], take(q_pe)[None], c_ctx, pe_ctx,
+                    (start + jnp.arange(w, dtype=jnp.int32))[None],
+                    total_lens[c][None], scale,
+                )[0]
+        real = (jnp.arange(w, dtype=jnp.int32) < n_c)[:, None, None]
+        heads_c = jnp.where(real, absorb_o(o_c.astype(x.dtype)), 0)
+        if whole:
+            heads = heads + heads_c
+        else:
+            heads = heads + lax.dynamic_update_slice_in_dim(
+                jnp.zeros((r + w, h, mla.v_dim), x.dtype), heads_c, r0, 0
+            )[:r]
+    with jax.named_scope("attn_proj"):
+        out = _proj(heads.reshape(r, h * mla.v_dim), params, "o_proj", lora)
+    return out, c_slab, pe_slab
+
+
+def _mla_layer(spec, page_size, hidden, params, c_slab, pe_slab, cos, sin,
+               slots, page_table, q_positions, total_lens, rows, kernels,
+               lora=None):
+    """A whole layer of a latent-attention family on [B, T, D] (or the
+    ragged [1, R, D]) rows."""
+    d = hidden.shape[-1]
+    x = _norm(hidden, params, "input_layernorm", spec)
+    attn, c_slab, pe_slab = _mla_attention(
+        spec, page_size, params, x.reshape(-1, d), c_slab, pe_slab,
+        cos.reshape(-1, cos.shape[-1]), sin.reshape(-1, sin.shape[-1]),
+        slots, page_table, q_positions.reshape(-1), total_lens, rows,
+        kernels, lora,
+    )
+    return _finish_layer(
+        spec, params, hidden, x, attn.reshape(hidden.shape), c_slab, pe_slab,
+        lora,
+    )
+
+
 def attn_scale(spec: ModelSpec) -> float:
     return (
         spec.attention_multiplier
@@ -396,7 +634,14 @@ def layer_body(
     ssm: tuple | None = None,  # (flat state arena, this layer's slots [B],
     # SsmRows) for a family with a state-space mixer; the layer then
     # returns the state arena as a fourth value
+    rows: SsmRows | None = None,  # latent attention (spec.mla): whose the
+    # flat rows are; k_slab / v_slab are then the latent and rotary-key slabs
 ):
+    if spec.mla is not None:
+        return _mla_layer(
+            spec, page_size, hidden, params, k_slab, v_slab, cos, sin, slots,
+            page_table, q_positions, total_lens, rows, use_paged, lora,
+        )
     b, t, d = hidden.shape
     h_heads, kv_heads, hd = (
         spec.num_attention_heads,
@@ -622,6 +867,7 @@ def layer_body_ragged(
     nt: jax.Array | None = None,  # [B] in-step token counts (tree groups)
     tree_rows: jax.Array | None = None,  # [R, t_max] in-step visibility
     ssm: tuple | None = None,  # as layer_body's
+    rows: SsmRows | None = None,  # as layer_body's
 ):
     """layer_body for the ragged mixed-batch step: one [1, R, D] row-major
     pack of N decode tokens plus one prefill chunk's tokens — or, when
@@ -630,6 +876,11 @@ def layer_body_ragged(
     position-wise, so they need no per-member structure — only attention
     does, and it gets it from (q_seq, q_positions) per row instead of
     layer_body's block-uniform (B, T)."""
+    if spec.mla is not None:
+        return _mla_layer(
+            spec, page_size, hidden, params, k_slab, v_slab, cos, sin, slots,
+            page_table, q_positions, total_lens, rows, use_kernel, lora,
+        )
     _, r, d = hidden.shape
     h_heads, kv_heads, hd = (
         spec.num_attention_heads,
@@ -711,6 +962,8 @@ def dense_unsupported(spec: ModelSpec) -> str | None:
         return "heterogeneous head_dim layers"
     if spec.ssm is not None:
         return "a state-space mixer beside attention (recurrent state)"
+    if spec.mla is not None:
+        return "latent attention (the cache holds latents, not K and V)"
     return None
 
 

@@ -49,13 +49,16 @@ from bloombee_tpu.kv.arena import (
     layer_state_slots,
     stacked_arena,
 )
+from bloombee_tpu.models.layout import split_runs, stacked_layers
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.ops.moe import takes_grouped_form
-from bloombee_tpu.ops.rotary import rotary_cos_sin
+from bloombee_tpu.ops.rotary import mla_cos_sin, rotary_cos_sin
 from bloombee_tpu.runtime.layer_body import (
     SsmRows,
+    collecting_reach,
     layer_body,
     layer_body_ragged,
+    packed_rows,
     packed_ssm_rows,
 )
 
@@ -181,6 +184,12 @@ def _rope_by_window(spec: ModelSpec, q_positions: jax.Array, dtype):
     frequency; the per-layer window (riding the scan) selects the pair."""
 
     def tables(theta):
+        if spec.mla is not None:
+            # the rotary part only, YaRN frequencies from the descriptor
+            return tuple(
+                x.astype(dtype)
+                for x in mla_cos_sin(q_positions, spec.mla, theta)
+            )
         return tuple(
             x.astype(dtype)
             for x in rotary_cos_sin(q_positions, spec.head_dim, theta)
@@ -241,7 +250,12 @@ def lift_expert_stacks(
         return stacked_params, None
     xs = {k: w for k, w in stacked_params.items() if k not in EXPERT_STACKS}
     n = stacked_params[EXPERT_STACKS[0]].shape[0]
-    xs["expert_base"] = jnp.arange(n, dtype=jnp.int32) * spec.num_experts
+    # a layer's experts in the stack: all the router scores, or the share
+    # this server holds
+    xs["expert_base"] = (
+        jnp.arange(n, dtype=jnp.int32)
+        * stacked_params[EXPERT_STACKS[0]].shape[1]
+    )
     whole = {
         k: stacked_params[k].reshape(-1, *stacked_params[k].shape[2:])
         for k in EXPERT_STACKS
@@ -263,6 +277,11 @@ def _scan_layers(
     state: dict | None = None,  # {"ssm", "conv"}: [L, slots, ...] each
     state_slots: jax.Array | None = None,  # [S] a sequence's state slot
     ssm_rows: SsmRows | None = None,
+    first_layer: int = 0,  # static: the arena's layer the scan begins at (a
+    # span of two runs of layers scans each on its own: `_scan_runs`)
+    reach: bool = False,  # static: these layers route over experts of which
+    # the server holds a share; the result then ends with what each layer's
+    # rows reached of them, i32 [n, 3] (ops/moe.py `held_reach`)
 ):
     """The span's layer scan, the arena WHOLE in the carry.
 
@@ -295,30 +314,94 @@ def _scan_layers(
                     ),
                     ssm_rows,
                 )
-            out = run_layer(h, k_flat, v_flat, slots_l, pages_l, rest, ssm_l)
-            return out if state_flat is not None else (*out, None)
+            if not reach:
+                out = run_layer(
+                    h, k_flat, v_flat, slots_l, pages_l, rest, ssm_l
+                )
+                return out if state_flat is not None else (*out, None)
+            with collecting_reach() as sown:
+                out = run_layer(
+                    h, k_flat, v_flat, slots_l, pages_l, rest, ssm_l
+                )
+            (reach_l,) = sown  # one sparse MLP a layer
+            return (*out[:3], out[3] if len(out) == 4 else None, reach_l)
 
         def skip(h, k_flat, v_flat, state_flat):
-            return h, k_flat, v_flat, state_flat
+            out = (h, k_flat, v_flat, state_flat)
+            return (*out, jnp.zeros((3,), jnp.int32)) if reach else out
 
-        return lax.cond(active > 0, run, skip, *carry), None
+        out = lax.cond(active > 0, run, skip, *carry)
+        return out[:4], (out[4] if reach else None)
 
-    (hidden, k_flat, v_flat, state_flat), _ = lax.scan(
+    (hidden, k_flat, v_flat, state_flat), reached = lax.scan(
         body,
         (
             hidden, flat_arena(arena_k), flat_arena(arena_v),
             None if state is None else flat_arena(state),
         ),
-        (jnp.arange(n, dtype=jnp.int32), layer_active, xs),
+        (
+            jnp.arange(first_layer, first_layer + n, dtype=jnp.int32),
+            layer_active, xs,
+        ),
     )
     out = (
         hidden,
         stacked_arena(k_flat, num_layers),
         stacked_arena(v_flat, num_layers),
     )
-    if state is None:
-        return out
-    return (*out, stacked_arena(state_flat, num_layers))
+    if state is not None:
+        out = (*out, stacked_arena(state_flat, num_layers))
+    return (*out, reached) if reach else out
+
+
+def _scan_runs(run_layer, spec, stacked_params, rows, kernels, hidden,
+               arena_k, arena_v, slots, page_table, layer_active, per_layer,
+               page_size, **state):
+    """`_scan_layers` over a span's runs of same-kind layers: ONE run for
+    every span whose layers are of one kind (the scan it always was), two
+    where the first layers' MLP is of another kind than the rest's
+    (models/layout.py `LEAD`): each run its own scan over its own stack,
+    the hidden rows and the one flat arena handed from the first to the
+    second. `per_layer` are the xs beside the params (leading dim = all the
+    span's layers, or None); `run_layer` takes (…, xs_l, ssm_l) with
+    xs_l = (params_l, *per_layer_l). On a server that holds a share of the
+    experts (`spec.moe_held`) the result ends with what the sparse layers'
+    rows reached of them, i32 [sparse layers, 3] (`_scan_layers`)."""
+    lead, main = split_runs(stacked_params)
+    runs = [main] if lead is None else [lead, main]
+    first = 0
+    out = (hidden, arena_k, arena_v)
+    reached = []
+    for params in runs:
+        reach = spec.moe_held is not None and (
+            "router" in params or "router_t" in params
+        )
+        params, experts = lift_expert_stacks(spec, params, rows, kernels)
+        n = jax.tree.leaves(params)[0].shape[0]
+        cut = (lambda a: a) if lead is None else (
+            lambda a, lo=first, hi=first + n: a[lo:hi]
+        )
+        xs = (params, *(
+            None if x is None else jax.tree.map(cut, x) for x in per_layer
+        ))
+
+        def run(h, k, v, slots_l, pages_l, xs_l, ssm_l, experts=experts):
+            if experts is not None:
+                xs_l = ({**xs_l[0], **experts}, *xs_l[1:])
+            return run_layer(h, k, v, slots_l, pages_l, xs_l, ssm_l)
+
+        out = _scan_layers(
+            run, out[0], out[1], out[2], slots, page_table,
+            cut(layer_active), xs, page_size, first_layer=first, reach=reach,
+            **state,
+        )
+        if reach:
+            *out, reached_run = out
+            reached.append(reached_run)
+        if len(out) == 4:
+            state = {**state, "state": out[3]}
+        first += n
+    return (*out, jnp.concatenate(reached)) if reached else tuple(out)
 
 
 def span_step_impl(
@@ -356,10 +439,7 @@ def span_step_impl(
     # the params stack says how many layers run: all of the arena's, or the
     # resident prefix in weight-offload mode (the offloaded layers get their
     # own layer_step calls with host-streamed weights)
-    n = jax.tree.leaves(stacked_params)[0].shape[0]
-    stacked_params, experts = lift_expert_stacks(
-        spec, stacked_params, b * t, use_paged
-    )
+    n = stacked_layers(stacked_params)
     slots, page_table, q_positions, total_lens, layer_active = unpack_plan(
         plan, b, t, max_pages, n
     )
@@ -374,11 +454,14 @@ def span_step_impl(
         ssm_rows = packed_ssm_rows(
             b, t, q_positions, state_slots, state["ssm"].shape[1], t_real
         )
+    rows = None
+    if spec.mla is not None:
+        # latent attention reads whose the rows are as the mixer does; a
+        # padding row of the batch bucket has length 0
+        rows = packed_rows(b, t, q_positions, total_lens > 0, t_real)
 
     def run_layer(h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l):
         params_l, window_l, prompt_l, lora_l = xs_l
-        if experts is not None:
-            params_l = {**params_l, **experts}
         if prompt_l is not None:
             p = prompt_l.shape[0]
             h = h.at[:, :p].add(prompt_l[None].astype(h.dtype))
@@ -386,12 +469,13 @@ def span_step_impl(
             spec, page_size, h, params_l, k_flat, v_flat, *rope(window_l),
             slots_l, pages_l, q_positions, total_lens, tm, window_l,
             use_flash=use_flash, use_paged=use_paged, lora=lora_l,
-            attn_topk=attn_topk, t_real=t_real, ssm=ssm_l,
+            attn_topk=attn_topk, t_real=t_real, ssm=ssm_l, rows=rows,
         )
 
-    return _scan_layers(
-        run_layer, hidden, arena_k, arena_v, slots, page_table, layer_active,
-        (stacked_params, windows_arr, prompts, lora), page_size,
+    return _scan_runs(
+        run_layer, spec, stacked_params, b * t, use_paged, hidden, arena_k,
+        arena_v, slots, page_table, layer_active,
+        (windows_arr, prompts, lora), page_size,
         state=state, state_slots=state_slots, ssm_rows=ssm_rows,
     )
 
@@ -508,9 +592,6 @@ def span_step_ragged_impl(
     host-side)."""
     hidden, plan = unpack_step_payload(payload, 1, r, spec.hidden_size)
     num_layers = arena_k.shape[0]
-    stacked_params, experts = lift_expert_stacks(
-        spec, stacked_params, r, use_kernel
-    )
     (
         slots, page_table, q_positions, total_lens, q_seq, layer_active,
         nt, tree_rows,
@@ -520,7 +601,9 @@ def span_step_ragged_impl(
         windows if windows is not None else (0,) * num_layers, jnp.int32
     )
     state_slots = ssm_rows = None
-    if state is not None:
+    if state is not None or spec.mla is not None:
+        # whose the rows are (pack_ragged_ssm_tail): the mixer and latent
+        # attention both take a sequence's rows by (row0, nt)
         tail = plan[plan.shape[0] - (3 * n_seqs + 1):]
         state_slots, row0, rows_nt = (
             tail[i * n_seqs : (i + 1) * n_seqs] for i in range(3)
@@ -531,22 +614,23 @@ def span_step_ragged_impl(
             fresh=q_positions[0, at] == 0,
             chunk_seqs=tail[3 * n_seqs :], window=r, step_form=True,
         )
+    rows = ssm_rows if spec.mla is not None else None
+    if state is None:
+        state_slots = ssm_rows = None
 
     def run_layer(h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l):
         params_l, window_l, lora_l = xs_l
-        if experts is not None:
-            params_l = {**params_l, **experts}
         return layer_body_ragged(
             spec, page_size, h, params_l, k_flat, v_flat, *rope(window_l),
             slots_l, pages_l, q_positions, total_lens, q_seq,
             window_l, use_kernel=use_kernel, lora=lora_l,
-            nt=nt, tree_rows=tree_rows, ssm=ssm_l,
+            nt=nt, tree_rows=tree_rows, ssm=ssm_l, rows=rows,
         )
 
-    return _scan_layers(
-        run_layer, hidden, arena_k, arena_v, slots, page_table, layer_active,
-        (stacked_params, windows_arr, lora), page_size,
-        state=state, state_slots=state_slots, ssm_rows=ssm_rows,
+    return _scan_runs(
+        run_layer, spec, stacked_params, r, use_kernel, hidden, arena_k,
+        arena_v, slots, page_table, layer_active, (windows_arr, lora),
+        page_size, state=state, state_slots=state_slots, ssm_rows=ssm_rows,
     )
 
 

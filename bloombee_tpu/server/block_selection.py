@@ -206,10 +206,28 @@ def should_choose_other_blocks(
     return rebalance_target(peer_id, module_infos, spans) is not None
 
 
-def estimate_block_bytes(spec, dtype) -> int:
+def kv_token_bytes(spec, itemsize: int = 2) -> int:
+    """Bytes ONE cached token takes in ONE layer: K and V head slabs, or
+    what a latent-attention family's page holds (one latent and one rotary
+    key, models/spec.py `MlaSpec.page_payload`)."""
+    if spec.mla is not None:
+        return sum(s[0] for s in spec.mla.page_payload) * itemsize
+    return 2 * spec.num_key_value_heads * spec.head_dim * itemsize
+
+
+def estimate_span_bytes(spec, dtype, start: int, end: int) -> int:
+    """Parameter bytes of blocks [start, end), counted by layer KIND (a
+    family whose first layers are dense and the rest sparse)."""
+    return sum(
+        estimate_block_bytes(spec, dtype, layer) for layer in range(start, end)
+    )
+
+
+def estimate_block_bytes(spec, dtype, layer: int | None = None) -> int:
     """Parameter bytes of one block (reference block_utils.get_block_size:
     param count x dtype width, meta-device instantiation not needed — the
-    spec already knows the shapes)."""
+    spec already knows the shapes). `layer` says WHICH block where the kinds
+    differ (default: the last one, the kind most of the model has)."""
     import numpy as np
 
     d, i = spec.hidden_size, spec.intermediate_size
@@ -217,7 +235,25 @@ def estimate_block_bytes(spec, dtype) -> int:
         spec.num_attention_heads, spec.num_key_value_heads, spec.head_dim,
     )
     attn = d * h * hd + 2 * d * kv * hd + h * hd * d
-    if spec.num_experts:
+    if spec.mla is not None:
+        m = spec.mla
+        attn = (
+            d * m.q_rank + m.q_rank * h * m.qk_dim + d * (m.kv_rank + m.rope_dim)
+            + m.kv_rank * h * (m.nope_dim + m.v_dim) + h * m.v_dim * d
+            + m.q_rank + m.kv_rank
+        )
+    if layer is None:
+        layer = spec.num_hidden_layers - 1
+    if spec.num_experts and spec.mlp_kind(layer) == "sparse" and (
+        spec.moe_intermediate_size
+    ):
+        # the experts this server HOLDS, the router over all of them, the
+        # shared experts
+        mlp = (
+            spec.experts_held[1] * 3 * d * spec.moe_intermediate_size
+            + d * spec.num_experts + 3 * d * spec.moe_shared_intermediate
+        )
+    elif spec.num_experts and spec.mlp_kind(layer) == "sparse":
         mlp = spec.num_experts * 3 * d * i + d * spec.num_experts
     elif spec.mlp_type == "silu" or spec.mlp_type == "gelu_tanh_gated":
         mlp = 3 * d * i
@@ -264,9 +300,8 @@ def choose_num_blocks(
         )
     per_block = estimate_block_bytes(spec, dtype)
     arena_bytes = (
-        num_pages * page_size * spec.num_key_value_heads * spec.head_dim
-        * 2 * np.dtype(dtype).itemsize
-    )  # per layer (k+v)
+        num_pages * page_size * kv_token_bytes(spec, np.dtype(dtype).itemsize)
+    )  # per layer (k+v, or a latent page)
     # a family with recurrent state also holds a slot per sequence and layer
     from bloombee_tpu.kv.arena import state_slot_bytes
     from bloombee_tpu.kv.cache_manager import state_slots_for

@@ -421,6 +421,8 @@ class BlockServer(PromotionLoopMixin):
         # chips via ring attention (parallel/sp_serving.py); decode stays
         # single-chip paged
         kv_quant: str | None = None,  # "int4" -> quantized KV arena
+        experts: tuple[int, int] | None = None,  # (first, count) of the
+        # router's experts this server holds (--experts); None = all
         weight_quant: str | None = None,  # "int8"/"int4" -> quantized weights
         oversubscribe: float = 1.0,  # admit > capacity; park idle sessions
         idle_park_s: float = 5.0,  # a session this idle may be parked
@@ -523,6 +525,7 @@ class BlockServer(PromotionLoopMixin):
         # empty = artifact path off)
     ):
         self.model_dir = model_dir
+        self.experts = experts
         if weight_quant is None:
             weight_quant = env.get("BBTPU_WEIGHT_QUANT")
         host_layers: list = []
@@ -543,7 +546,7 @@ class BlockServer(PromotionLoopMixin):
 
             params, spec = load_span_params(
                 model_dir, start, end, dtype=compute_dtype,
-                adapter_dirs=adapter_dirs,
+                adapter_dirs=adapter_dirs, experts=experts,
             )
         elif offload_layers > 0:
             # pre-built params + offload: split the stacked span, move the
@@ -647,6 +650,7 @@ class BlockServer(PromotionLoopMixin):
             state_slots=state_slots_for(
                 spec, num_pages, page_size, max_batch
             ),
+            payload=spec.mla.page_payload if spec.mla is not None else None,
         )
         self.idle_park_s = idle_park_s
         if oversubscribe > 1.0:
@@ -681,9 +685,11 @@ class BlockServer(PromotionLoopMixin):
             sp_mesh=sp_mesh,
         )
         self.wire_dtype = name_for_dtype(self.executor.transfer_dtype)
-        if spec.heterogeneous or host_layers or spec.ssm is not None:
+        if (spec.heterogeneous or host_layers or spec.ssm is not None
+                or spec.mla is not None):
             # hetero / weight-offloaded spans: no dense training stack; a
-            # state-space mixer has no training-mode forward here either
+            # state-space mixer and latent attention have no training-mode
+            # forward here either
             self.training = None
         else:
             from bloombee_tpu.runtime.training import TrainingExecutor
@@ -1529,7 +1535,7 @@ class BlockServer(PromotionLoopMixin):
 
             params, spec = await asyncio.to_thread(
                 load_span_params, self.model_dir, start, end,
-                self.compute_dtype, self._adapter_dirs,
+                self.compute_dtype, self._adapter_dirs, self.experts,
             )
             manager = CacheManager(
                 num_layers=end - start,
@@ -1547,6 +1553,9 @@ class BlockServer(PromotionLoopMixin):
                     spec, self._num_pages, self.manager.page_size,
                     self.max_batch,
                 ),
+                payload=(
+                    spec.mla.page_payload if spec.mla is not None else None
+                ),
             )
             if self.manager.reclaimer is not None:
                 manager.reclaimer = self._reclaim_idle
@@ -1559,7 +1568,9 @@ class BlockServer(PromotionLoopMixin):
             )
             from bloombee_tpu.runtime.training import TrainingExecutor
 
-            training = None if spec.ssm is not None else TrainingExecutor(
+            training = None if (
+                spec.ssm is not None or spec.mla is not None
+            ) else TrainingExecutor(
                 executor.params, spec, windows=executor.windows,
                 compute_dtype=self.compute_dtype,
             )
@@ -1936,6 +1947,24 @@ class BlockServer(PromotionLoopMixin):
                     for form, n in self.executor.moe_dispatches.items()
                 }}
                 if self.spec.num_experts else {}
+            ),
+            # which of the router's experts this server holds (--experts),
+            # and what a cached token costs in one layer, where the page is
+            # a latent and not K and V head slabs
+            **(
+                {"experts_held": list(self.spec.experts_held)}
+                if self.spec.num_experts else {}
+            ),
+            # a share of the experts held: what the steps read so far
+            # reached of it (sums over steps and sparse layers; per sparse
+            # layer the distinct held experts the last step's rows chose)
+            **(
+                {"moe_reach": dict(self.executor.moe_reach)}
+                if self.spec.moe_held is not None else {}
+            ),
+            **(
+                {"latent_bytes_per_token": self.spec.mla.token_bytes}
+                if self.spec.mla is not None else {}
             ),
             "artifact_preinstalled": self._artifacts_preinstalled,
             "artifact_fallback_compiles": self.artifact_fallback_compiles,
@@ -3909,6 +3938,8 @@ class BlockServer(PromotionLoopMixin):
             return "heterogeneous head_dim span"
         if self.spec.ssm is not None:
             return "recurrent state beside the KV arena"
+        if self.spec.mla is not None:
+            return "latent attention (the decode loop attends K and V pages)"
         if self.executor.host_layers:
             return "span has weight-offloaded layers"
         if self.executor.mesh is not None:
@@ -4021,10 +4052,11 @@ class BlockServer(PromotionLoopMixin):
                     raise DeadlineExpired(
                         "client deadline expired between prefill chunks"
                     )
-                # a chunk of SEVERAL sequences with recurrent state goes
-                # alone: a ragged pack runs the mixer's chunk form on one
+                # a chunk of SEVERAL sequences with recurrent state (or a
+                # latent cache) goes alone: a ragged pack runs the mixer's
+                # (latent attention's) chunk form on one
                 if self.mixed_batch and not (
-                    self.spec.ssm is not None and hidden.shape[0] > 1
+                    self.executor.one_chunk_a_pack and hidden.shape[0] > 1
                 ):
                     # batchable chunk: the worker may fuse this chunk with
                     # queued decode steps — and, with --spec-batch also

@@ -322,3 +322,317 @@ def test_a_mixer_metric_reads_nothing_where_there_is_no_trace(tmp_path, name):
     ctx = {"trace_dir": str(tmp_path / "trace"), "config": _published(),
            "prefill_chunk": 128, "device_kind": "TPU v5 lite"}
     assert module.read(ctx) is None
+
+
+# ---------------------------------------------------- deepseek_v2's pins
+# (PR 35) the plan at the published size, a tiny checkpoint's files, every
+# key of the needs, and the CPU rehearsal of the cell `deepseekv2-longctx`
+# through `cellbench/run.py`: `correct` true; false with an int8-weight
+# server; false with each fault of `scripts/plant_mla_fault.py` planted in a
+# copy of the program. The values were produced by this file's own code when
+# the family was added: a later edit that moves one has to say so here.
+TREE_DSV2 = ROOT / ".cache" / "cellbench_rehearsal_deepseek_v2"
+
+TINY_DEEPSEEK_V2 = {
+    "model_type": "deepseek_v2", "hidden_size": 128,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "q_lora_rank": 48,
+    "kv_lora_rank": 32, "qk_nope_head_dim": 32, "qk_rope_head_dim": 16,
+    "v_head_dim": 32, "n_routed_experts": 4, "router_experts": 16,
+    "experts_held": [4, 4], "n_group": 4, "topk_group": 2,
+    "num_experts_per_tok": 3, "n_shared_experts": 2,
+    "moe_intermediate_size": 64, "intermediate_size": 256,
+    "first_k_dense_replace": 1, "moe_layer_freq": 1, "num_hidden_layers": 3,
+    "routed_scaling_factor": 16, "vocab_size": 512, "rms_norm_eps": 1e-06,
+    "rope_theta": 10000, "topk_method": "group_limited_greedy",
+    "norm_topk_prob": False, "scoring_func": "softmax",
+    "max_position_embeddings": 8192, "tie_word_embeddings": False,
+    "attention_bias": False, "hidden_act": "silu",
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                     "mscale": 0.707, "mscale_all_dim": 0.707,
+                     "original_max_position_embeddings": 64, "type": "yarn"},
+    "torch_dtype": "bfloat16",
+}
+
+DSV2_PINS = {
+    "plan": (
+        "ff573df00defc23b7455b9ecc8b8dadbd9b1655e4b6695d9ffb96c118f8c372c", 6, 307),
+    "files": {
+        "config.json": "eed7d68020f23677feb7b8160aaaee15e7f3a950e113540a3ab14e2b16ab817e",
+        "model-client.safetensors": "d05fc500f703d51048bf0d41104e0ca37c5bc093addb6d0bdd1af7b36bcd4910",
+        "model-layer000.safetensors": "426d16dfb9467636e66829417935d107c923df773fbd8287f85db612866f6af2",
+        "model-layer001.safetensors": "8f6f4510810c7d22f79163eb2ed4078e38a0366fa287796057cf02bec9e04ea2",
+        "model-layer002.safetensors": "648e29b95cf02d533182db4d270cb6b025927a6c602ebfabda0c6f48d602cd2a",
+        "model.safetensors.index.json": "6db50bcf4249ddf72852c4950c19729152d1e20afb8ee5c55472faa9073cfb19",
+    },
+    "needs": [
+        ("decode_step_needs", 2.0, 10000.0,
+         {"bytes": 2649627903.9999995, "flops": 32646266880.0,
+          "weight_bytes": 2531590143.9999995, "kv_bytes": 117996800.0}),
+        ("chunk_needs", 512, 5120.0,
+         {"bytes": 6787563508.03038, "flops": 5059672801280.0,
+          "weight_bytes": 6028656628.03038, "kv_bytes": 748421120.0}),
+        ("mla_attention_needs", 512, 5120.0, "chunk",
+         {"bytes": 748421120.0, "flops": 3833258311680.0}),
+        ("mla_attention_needs", 2.0, 10000.0, "decode",
+         {"bytes": 117996800.0, "flops": 27855585280.0}),
+    ],
+}
+
+
+def _published_dsv2() -> dict:
+    config = json.loads(
+        (ROOT / "cellbench/configs/deepseek-v2-ep8-span5.json").read_text())
+    config.pop("cellbench")
+    return config
+
+
+def test_deepseek_v2_plan_at_the_published_size():
+    import numpy as np
+
+    plan = checkpoint.tensor_plan(_published_dsv2())
+    listed = [[tag, [[n, list(shape), fill] for n, shape, fill in tensors]]
+              for tag, tensors in plan]
+    digest = _sha(json.dumps(listed).encode())
+    assert (digest, len(plan), sum(len(ts) for _, ts in plan)
+            ) == DSV2_PINS["plan"]
+    size = lambda tensors: sum(  # noqa: E731
+        int(np.prod(shape)) for _, shape, _ in tensors)
+    # layer 0 with its dense MLP; a sparse layer with the 20 HELD experts,
+    # the router over all 160 and the shared experts; the span (ISSUE 35)
+    assert [size(ts) for _, ts in plan[:2]] == [337981440, 669102080]
+    assert sum(size(ts) for _, ts in plan[:-1]) == 3014389760
+    names = [n for n, _, _ in plan[1][1]]
+    assert "model.layers.1.mlp.experts.19.down_proj.weight" in names
+    assert "model.layers.1.mlp.experts.20.down_proj.weight" not in names
+    assert dict((n, s) for n, s, _ in plan[1][1])[
+        "model.layers.1.mlp.gate.weight"] == (160, 5120)
+
+
+def test_deepseek_v2_tiny_checkpoint_files(tmp_path):
+    checkpoint.write_checkpoint(tmp_path, TINY_DEEPSEEK_V2, SEED)
+    got = {p.name: _sha(p.read_bytes()) for p in sorted(tmp_path.iterdir())}
+    assert got == DSV2_PINS["files"]
+
+
+def test_deepseek_v2_needs_every_key():
+    family, config = families.of(_published_dsv2()), _published_dsv2()
+    got = [
+        (fn, *args, getattr(family, fn)(config, *args))
+        for fn, args in (
+            ("decode_step_needs", (2.0, 10000.0)),
+            ("chunk_needs", (512, 5120.0)),
+            ("mla_attention_needs", (512, 5120.0, "chunk")),
+            ("mla_attention_needs", (2.0, 10000.0, "decode")),
+        )
+    ]
+    assert repr(got) == repr(DSV2_PINS["needs"])
+    # a cached token is 1,152 B a layer; 20 held experts are all reached by
+    # a 512-row chunk and about one and a half by two decode rows
+    assert family.latent_row_bytes(config) == 1152
+    assert family._expert_reach(config, 512)[1] == pytest.approx(20, abs=1e-6)
+    assert family._expert_reach(config, 2)[1] == pytest.approx(1.47, abs=0.01)
+
+
+@pytest.fixture(scope="module")
+def tree_dsv2() -> pathlib.Path:
+    """A copy of the benchmark with a tiny deepseek_v2 configuration, a
+    traffic mix and a cell ADDED (the family file and the metric readers are
+    already there), no file edited."""
+    shutil.rmtree(TREE_DSV2, ignore_errors=True)
+    TREE_DSV2.mkdir(parents=True)
+    shutil.copytree(ROOT / "cellbench", TREE_DSV2 / "cellbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (TREE_DSV2 / "bloombee_tpu").symlink_to(ROOT / "bloombee_tpu")
+    cb = TREE_DSV2 / "cellbench"
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    (cb / "configs" / "tiny-deepseek-v2.json").write_text(json.dumps(dict(
+        TINY_DEEPSEEK_V2, cellbench={
+            "source": "none: a rehearsal preset", "uid": "tiny-deepseek-v2",
+            "reduced": {"everything": "tiny"},
+            # a float32 server, as the tiny falcon_h1 above: sound 2.1e-7;
+            # the rotary key zeroed in the cache write 5.2e-4, the route
+            # scale left out 0.028, the routed sum dropped 0.030; an
+            # int8-weight server 6.5e-4 with projection 1.0000
+            "server_flags": ["--mixed-batch", "--prefill-chunk", "128",
+                             "--experts", "4:4", "--dtype", "float32"],
+            "prefill_chunk": 128, "logit_error_limit": 2e-5,
+            "int8_projection_limit": 0.5})))
+    (cb / "traffic" / "tiny-ctx.json").write_text(json.dumps({
+        "loop": "closed", "sessions": 2, "stagger_s": 0.1,
+        "prompt_tokens": [300, 171, 260], "new_tokens": [4, 5, 4],
+        "judge": {"requests": 2, "new_tokens": 4}}))
+    (cb / "cells" / "tiny-deepseek-v2-ctx.json").write_text(
+        '{"num_pages": 128}')
+    bench["configs"].append(
+        {"name": "tiny-deepseek-v2", "source": "none", "reduced": [],
+         "file": "cellbench/configs/tiny-deepseek-v2.json", "why": "rehearsal"})
+    bench["workloads"].append(
+        {"name": "tiny-deepseek-v2-ctx", "config": "tiny-deepseek-v2",
+         "traffic": "tiny-ctx", "chips": 1, "why": "rehearsal"})
+    for metric in bench["end_to_end"] + bench["per_layer"]:
+        if "deepseekv2-longctx" in metric.get("workloads", ()):
+            metric["workloads"].append("tiny-deepseek-v2-ctx")
+    (TREE_DSV2 / "BENCHMARK.json").write_text(json.dumps(bench))
+    return TREE_DSV2
+
+
+def test_deepseek_v2_cell_rehearsal_is_correct(tree_dsv2):
+    rc, last, out = _run(tree_dsv2, "--workload", "tiny-deepseek-v2-ctx",
+                         "--seed", str(2**31 + 35), "--seconds", "4",
+                         "--trace", "1")
+    assert last is not None and rc == 0, out[-3000:]
+    assert last["correct"] is True and last["failed"] == 0, out[-3000:]
+    assert last["attempted"] >= 3
+    # a CPU run reports no device metric under a device metric's name
+    for name in ("chunk_mla_ms_p50", "step_mla_ms_p50", "chunk_moe_ms_p50",
+                 "mla_attention_roofline", "mla_decode_roofline",
+                 "latent_io_move_share", "device_idle_share"):
+        assert name not in last["metrics"]
+
+
+def test_deepseek_v2_cell_rehearsal_int8_server_is_not_correct(tree_dsv2):
+    rc, last, out = _run(
+        tree_dsv2, "--workload", "tiny-deepseek-v2-ctx", "--seed", "17",
+        "--seconds", "2", "--trace", "0", "--server-arg=--weight-quant",
+        "--server-arg=int8")
+    assert last is not None and last["correct"] is False, out[-3000:]
+    assert rc != 0
+    got = _compared(out)
+    assert got["int8_projection_median"][0] == pytest.approx(1.0, abs=0.05)
+
+
+@pytest.mark.parametrize("fault", ["rope_key", "route_scale", "routed_sum"])
+def test_deepseek_v2_cell_rehearsal_sees_a_planted_fault(
+        tree_dsv2, tmp_path, fault):
+    """The timed path BROKEN underneath the harness, in a copy of the
+    program (`scripts/plant_mla_fault.py`, which planted the same three on
+    the chip): the rotary key zeroed in the cache write, the route scale
+    left out, the held experts' partial sum dropped. The served tokens still
+    come, no request fails, and `correct` is false by the logit error."""
+    broken = tmp_path / "tree"
+    shutil.copytree(tree_dsv2, broken, symlinks=True)
+    (broken / "bloombee_tpu").unlink()
+    shutil.copytree(ROOT / "bloombee_tpu", broken / "bloombee_tpu",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    spec = importlib.util.spec_from_file_location(
+        "plant_mla_fault", ROOT / "scripts" / "plant_mla_fault.py")
+    planter = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(planter)
+    planter.plant(broken, fault)
+    rc, last, out = _run(broken, "--workload", "tiny-deepseek-v2-ctx",
+                         "--seed", "23", "--seconds", "2", "--trace", "0")
+    assert last is not None and last["correct"] is False, out[-3000:]
+    assert last["failed"] == 0 and rc != 0
+    err, limit = _compared(out)["logit_err_median"]
+    assert err > 10 * limit, (err, limit)
+
+
+def test_a_latent_metric_reads_nothing_where_there_is_no_trace(tmp_path):
+    """The new readers on a run without a trace (and so on the parent of the
+    PR that brought the scopes): None, no exception."""
+    from cellbench import mlatrace
+    from cellbench.metrics import (  # noqa: F401
+        chunk_mla_ms_p50,
+        chunk_moe_ms_p50,
+        latent_io_move_share,
+        step_mla_ms_p50,
+    )
+
+    from cellbench.metrics import held_experts_hit_share
+
+    ctx = {"work_dir": str(tmp_path), "trace": None,
+           "config": {"experts_held": [0, 20]}, "prefill_chunk": 512}
+    for module in (chunk_mla_ms_p50, step_mla_ms_p50, chunk_moe_ms_p50,
+                   latent_io_move_share, held_experts_hit_share):
+        assert module.read(dict(ctx)) is None
+    assert mlatrace.reduce({"device": [], "host": []}) is None
+    # ops of another family (no latent scope): nothing to read either
+    ops = [("fusion.1", 0.0, 1e-3, "jit(f)/while/body/moe_experts/dot")]
+    mods = [("jit_span_step_packed_impl(1)", 0.0, 1e-3)]
+    assert mlatrace.reduce(
+        {"device": [{"name": "/device:TPU:0", "ops": ops, "modules": mods}],
+         "host": []}) is None
+
+
+def test_held_experts_hit_share_reads_the_full_chunks_of_the_reach_spans():
+    """`bbtpu.moe_reach` spans as the program stamps them (one a step; per
+    sparse layer `;`-separated lists) -> the median over full chunks' sparse layers
+    of distinct held experts reached, as a share of the experts held; a
+    decode step's and a tail's spans are left out."""
+    from cellbench import mlatrace
+    from cellbench.metrics import held_experts_hit_share
+
+    def span(rows, hit, kind="chunk"):
+        return ("bbtpu.moe_reach", 0.0, 0.0, {
+            "kind": kind, "rows": rows, "held_hit": hit,
+            "routed_pairs_here": "9;9;9;9",
+            "rows_with_held_expert": "7;7;7;7"})
+
+    host = [{"line": 1, "events": [
+        span(512, "20;19;18;20"), span(2, "1;2;1;1", "decode"),
+        ("bbtpu.task", 0, 1, {}), span(137, "12;11;13;12")]}, {"line": 2, "events": [
+            span(512, "17;20;20;16")]}]
+    reach = mlatrace.reach_spans(host)
+    assert [r["rows"] for r in reach] == [512, 2, 137, 512]
+    assert [r["kind"] for r in reach] == ["chunk", "decode", "chunk", "chunk"]
+    got = {"reach": reach}
+    assert len(mlatrace.traced_steps(got, "chunk", spans="reach")) == 3
+    assert len(mlatrace.traced_steps(got, "chunk", 512, "reach")) == 2
+    # the steps as they were DISPATCHED are another span (`bbtpu.step`)
+    host[0]["events"].append(
+        ("bbtpu.step", 0.0, 0.0, {"kind": "decode", "rows": 2,
+                                  "context": 9000}))
+    host[0]["events"].append(
+        ("bbtpu.step", 0.0, 0.0, {"kind": "chunk", "rows": 512}))
+    assert mlatrace.step_spans(host) == [
+        {"kind": "decode", "rows": 2, "context": 9000},
+        {"kind": "chunk", "rows": 512, "context": 0}]
+    assert reach[0]["held_hit"] == [20, 19, 18, 20]
+    assert reach[1]["rows_with_held_expert"] == [7, 7, 7, 7]
+    ctx = {"_mlatrace": got, "prefill_chunk": 512,
+           "config": {"experts_held": [0, 20]}}
+    # full chunks: 20 19 18 20 17 20 20 16 -> median 19.5 of 20
+    assert held_experts_hit_share.read(ctx) == pytest.approx(97.5)
+    assert held_experts_hit_share.read(dict(ctx, config={})) is None
+    assert held_experts_hit_share.read(
+        dict(ctx, _mlatrace={"reach": reach[1:3]})) is None
+
+
+def test_the_core_roofline_takes_the_traced_steps_own_context():
+    """The core's time follows the context, and a 5 s trace holds the chunks
+    of four requests: where the program stamped its steps, the needs are
+    taken at THEIR median context (a traced sample 1,000 tokens under the
+    window's mean read 100.5 on the chip before this, and spans stamped when
+    the counters were read, up to a prefill late, 68.5); with no span the
+    window's means stand in."""
+    from cellbench import families, mlatrace, roofline
+
+    config = json.loads((ROOT / "cellbench" / "configs"
+                         / "deepseek-v2-ep8-span5.json").read_text())
+    config.pop("cellbench")
+    needs = families.of(config).mla_attention_needs
+
+    def share(context, ms, kind="chunk", rows=512):
+        least, _ = roofline.least_seconds(
+            needs(config, rows, context, kind), "TPU v5 lite")
+        return 100.0 * least / (ms * 1e-3)
+
+    def step(kind, rows, context):
+        return {"kind": kind, "rows": rows, "context": context}
+
+    steps = [step("chunk", 512, c) for c in (1024, 4096, 3072)] + [
+        step("chunk", 137, 11776), step("decode", 2, 9000),
+        step("decode", 3, 11000), step("fused", 513, 8000)]
+    ctx = {"_mlatrace": {"chunk_core_ms_p50": 16.0, "step_core_ms_p50": 0.7,
+                         "steps": steps},
+           "config": config, "prefill_chunk": 512,
+           "device_kind": "TPU v5 lite"}
+    got = mlatrace.core_roofline(ctx, "chunk", 512, 5200.0)
+    assert got == pytest.approx(share(3072, 16.0))  # the full chunks' median
+    assert got < share(5200.0, 16.0)
+    assert ctx["notes"]["mla_chunk_traced_steps"] == [3, 512, 3072]
+    got = mlatrace.core_roofline(ctx, "decode", 2.0, 5000.0)
+    assert got == pytest.approx(share(10000, 0.7, "decode", 2.5))
+    bare = dict(ctx, _mlatrace={"chunk_core_ms_p50": 16.0, "steps": []})
+    assert mlatrace.core_roofline(bare, "chunk", 512, 5200.0) == (
+        pytest.approx(share(5200.0, 16.0)))

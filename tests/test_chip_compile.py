@@ -511,3 +511,127 @@ def test_cell_span_step_copies_no_parameter(v5e, cell, program):
     if program in bounds:
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < bounds[program] * 1e6, temp
+
+
+# ------------------------------------------------------------- deepseek_v2
+# latent attention with a latent page in the arena, a dense layer before
+# sparse ones in one step program, 20 of 160 experts held: the span steps of
+# the benchmark's cell (cellbench/configs/deepseek-v2-ep8-span5.json:
+# published widths, layer 0 and four sparse layers, 5376 pages) at the
+# 1024-page bucket its contexts take
+def _deepseek_shapes(one_chip, pages=5376):
+    import dataclasses
+    import json
+    import pathlib
+
+    from bloombee_tpu.models.auto import spec_from_config_dict
+    from bloombee_tpu.models.layout import LEAD
+
+    config = json.loads((
+        pathlib.Path(__file__).resolve().parents[1]
+        / "cellbench/configs/deepseek-v2-ep8-span5.json").read_text())
+    held = tuple(config["experts_held"])
+    spec = dataclasses.replace(
+        spec_from_config_dict(config), num_experts=config["router_experts"],
+        moe_held=held)
+    d, h, m = spec.hidden_size, spec.num_attention_heads, spec.mla
+
+    def s(n, *shape):
+        return jax.ShapeDtypeStruct((n, *shape), bf16, sharding=one_chip)
+
+    def attention(n):
+        return {
+            "input_layernorm": s(n, d), "post_attention_layernorm": s(n, d),
+            "q_a_norm": s(n, m.q_rank), "kv_a_norm": s(n, m.kv_rank),
+            "q_a_proj": s(n, m.q_rank, d),
+            "q_b_nope": s(n, h * m.nope_dim, m.q_rank),
+            "q_b_rope": s(n, h * m.rope_dim, m.q_rank),
+            "kv_a_proj": s(n, m.kv_rank + m.rope_dim, d),
+            "kv_b_k": s(n, h, m.nope_dim, m.kv_rank),
+            "kv_b_v": s(n, h, m.v_dim, m.kv_rank),
+            "o_proj": s(n, h * m.v_dim, d),
+        }
+
+    i, e, si = spec.moe_intermediate_size, held[1], spec.moe_shared_intermediate
+    dense = {**attention(1), "gate_proj": s(1, d, spec.intermediate_size),
+             "up_proj": s(1, d, spec.intermediate_size),
+             "down_proj": s(1, spec.intermediate_size, d)}
+    sparse = {**attention(4), "router_t": s(4, spec.num_experts, d),
+              "experts_gate": s(4, e, d, i), "experts_up": s(4, e, d, i),
+              "experts_down": s(4, e, i, d), "shared_gate": s(4, d, si),
+              "shared_up": s(4, d, si), "shared_down": s(4, si, d)}
+    params = {**{LEAD + k: v for k, v in dense.items()}, **sparse}
+    latent, rotary = m.page_payload
+    return (spec, params, s(5, pages * PAGE, *latent),
+            s(5, pages * PAGE, *rotary))
+
+
+@pytest.mark.parametrize("name", ["decode", "flash_t512", "flash_t8"])
+def test_latent_attention_kernel_compiles_for_v5e(v5e, name):
+    """The two kernels of ops/pallas/latent_attention.py at the published
+    widths (128 heads, a 512-wide latent, the rotary key in whole lanes)."""
+    from bloombee_tpu.ops.pallas.latent_attention import (
+        latent_flash_attention,
+        paged_decode_attention_latent,
+    )
+
+    def s(shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=SingleDeviceSharding(v5e[0]))
+
+    if name == "decode":
+        fn = functools.partial(
+            paged_decode_attention_latent, page_size=PAGE, scale=0.1147)
+        shapes = [s((8, 128, 512)), s((8, 128, 128)), s((5 * 5376 * PAGE, 512)),
+                  s((5 * 5376 * PAGE, 128)), s((8, 1024), i32), s((8,), i32)]
+    else:
+        t = int(name.removeprefix("flash_t"))
+        fn = functools.partial(latent_flash_attention, scale=0.1147)
+        shapes = [s((128, t, 512)), s((128, t, 128)), s((16384, 512)),
+                  s((16384, 128)), s((), i32), s((), i32), s((), i32)]
+    assert "tpu_custom_call" in jax.jit(fn).lower(
+        *shapes).compile().as_text()
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "fused"])
+def test_deepseek_v2_span_step_compiles_and_copies_no_parameter(v5e, program):
+    """The cell's three step programs (a decode group, a solo 512-row chunk,
+    a 1024-row fused pack; 1024-page bucket, kernels on): ONE program runs
+    layer 0's dense MLP and the four sparse layers over one flat latent
+    arena; the compiled text holds no `copy` of a `stacked_params` parameter
+    and no slice or copy of a whole arena, and the temporaries stay what the
+    rows' activations need."""
+    import re
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    spec, params, latent, rotary = _deepseek_shapes(one_chip)
+    layers, pages = 5, 1024
+    common = dict(
+        spec=spec, page_size=PAGE, max_pages=pages, windows=(0,) * layers)
+    if program == "fused":
+        r, n_seqs = 1024, 4
+        plan_len = r + n_seqs * pages + r + n_seqs + r + layers + 3 * n_seqs + 1
+        compiled = span_step_ragged.lower(
+            params, latent, rotary, _cell_payload(spec, r, plan_len, one_chip),
+            None, None, r=r, n_seqs=n_seqs, use_kernel=True, **common,
+        ).compile()
+    else:
+        b, t = (2, 1) if program == "decode" else (1, 512)
+        plan_len = b * t + b * pages + b * t + b + layers
+        compiled = span_step_packed.lower(
+            params, latent, rotary,
+            _cell_payload(spec, b * t, plan_len, one_chip), None, None, None,
+            b=b, t=t, use_paged=True, t_real=None if t == 1 else t, **common,
+        ).compile()
+    text = compiled.as_text()
+    assert "%stacked_params__lead_q_a_proj" in text  # the names read below
+    assert "%stacked_params__router_t" in text
+    copied = re.findall(r"copy\([^)\n]*%(stacked_params\w+)", text)
+    assert not copied, copied
+    assert not re.findall(r"copy\([^)\n]*%arena_[kv]", text)
+    kernels = text.count("tpu_custom_call")
+    # decode: the paged latent kernel in both runs + the grouped experts;
+    # chunk: the flash form in both runs; fused: both kernels in both runs
+    assert kernels == {"decode": 3, "chunk": 2, "fused": 4}[program]
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < {"decode": 20, "chunk": 300, "fused": 600}[program] * 1e6
