@@ -214,6 +214,19 @@ def main(argv=None):
                             f"  memory.state=slots:{state['slots']}"
                             f",live:{state['live']},bytes:{state['bytes']}"
                         )
+                    # layer kinds that differ in their cache: how many of
+                    # each, and the rows and bytes of each kind's arena
+                    kinds = probe.get("layer_kinds")
+                    if kinds:
+                        mem = probe.get("memory") or {}
+                        line += (
+                            "  layer_kinds="
+                            + ",".join(f"{k}:{n}" for k, n in sorted(kinds.items()))
+                            + f"  memory.kv=layers:{mem.get('kv_arena_layers')}"
+                            f",bytes:{mem.get('kv_arena_bytes')}"
+                            f"  memory.state.layers="
+                            f"{mem.get('state_arena_layers')}"
+                        )
                     # elastic self-healing counters: standby promotions /
                     # drain-backs and measured-load rebalance outcomes —
                     # the control loop's every decision, probeable without

@@ -24,8 +24,9 @@ the arena through HBM once a layer to write a handful of rows; the
 benchmark's `scan_slab_move_share` reads such copies.
 
 Recurrent state beside the pages: a family with a state-space mixer
-(`ModelSpec.ssm`) keeps, per sequence and layer, ONE fixed-size slot that is
-never paged: `make_state_arena` -> {"ssm": [L, slots, H, P, N] float32, "conv":
+(`ModelSpec.ssm`) or gated-DeltaNet layers (`ModelSpec.gdn`) keeps, per
+sequence and layer that has such state, ONE fixed-size slot that is never
+paged: `make_state_arena` -> {"ssm": [L, slots, *state_shape] float32, "conv":
 [L, slots, K-1, C]}. It is addressed the same way: viewed flat over
 (layer, slot), carried whole through the step's scan, layer `l` reads and
 writes rows `layer_state_slots(slots, l)`. A slot can be kept or zeroed,
@@ -84,27 +85,26 @@ def make_arena(
 def make_state_arena(
     num_layers: int, num_slots: int, ssm, conv_dtype=jnp.bfloat16
 ) -> dict:
-    """The recurrent-state arena of a family with `ssm` (a models.spec
-    SsmSpec): the state in float32 (a sum over thousands of positions), the
+    """The recurrent-state arena of a family with recurrent state (`ssm`:
+    a models.spec SsmSpec or GdnSpec, whichever `ModelSpec.recurrent`
+    gives): the state in float32 (a sum over thousands of positions), the
     convolution's tail in the compute dtype (its rows are the projection's
-    outputs, so nothing is rounded)."""
+    outputs, so nothing is rounded). `num_layers`: the layers that KEEP
+    such state (`ModelSpec.arena_layers`)."""
     return {
-        "ssm": jnp.zeros(
-            (num_layers, num_slots, ssm.heads, ssm.head_dim, ssm.state),
-            jnp.float32,
-        ),
-        "conv": jnp.zeros(
-            (num_layers, num_slots, ssm.conv - 1, ssm.conv_dim), conv_dtype
-        ),
+        "ssm": jnp.zeros((num_layers, num_slots, *ssm.state_shape), jnp.float32),
+        "conv": jnp.zeros((num_layers, num_slots, *ssm.tail_shape), conv_dtype),
     }
 
 
 def state_slot_bytes(ssm, conv_itemsize: int = 2) -> int:
     """Bytes ONE sequence's recurrent state takes in ONE layer: the state in
     float32, the convolution's tail in the compute dtype."""
+    import math
+
     return (
-        ssm.heads * ssm.head_dim * ssm.state * 4
-        + (ssm.conv - 1) * ssm.conv_dim * conv_itemsize
+        math.prod(ssm.state_shape) * 4
+        + math.prod(ssm.tail_shape) * conv_itemsize
     )
 
 

@@ -171,10 +171,20 @@ def state_slots_for(spec, num_pages: int, page_size: int, max_batch: int) -> int
     `ssm state bytes / kv bytes a token` tokens of context cost: 2048 at
     falcon-h1-34b's widths), and at least twice the batcher's width, so a
     full decode group and the sessions opening behind it all fit. 0 for a
-    family without recurrent state."""
-    ssm = spec.ssm
+    family without recurrent state.
+
+    The rule was written for a family with both caches in every layer. A
+    family whose layers keep ONE of them each (`spec.gdn`) gets twice the
+    batcher's width and no more: its K/V arena is sized for contexts
+    hundreds of times a state's worth of tokens in a quarter of the layers,
+    and a state arena as large (82 slots, 1 GB at qwen3-next's widths under
+    5376 pages) would hold states no session could own, since the K/V
+    pages admit a few long sessions at a time."""
+    ssm = spec.recurrent
     if ssm is None:
         return 0
+    if spec.gdn is not None:
+        return 2 * int(max_batch)
     kv_token = 2 * spec.num_key_value_heads * spec.head_dim * 2  # bf16 K+V
     slot = arena_ops.state_slot_bytes(ssm)
     return max(2 * int(max_batch), (num_pages * page_size * kv_token) // slot)
@@ -212,6 +222,10 @@ class CacheManager:
         state_slots: int = 0,  # sequences that may hold a state slot at once
         payload=None,  # a latent-attention family's page payload
         # (models/spec.py MlaSpec.page_payload); None = K and V head slabs
+        arena_layers: tuple[int, int] | None = None,  # (rows of the K/V
+        # arena, rows of the state arena) where the span's layers keep ONE
+        # cache kind each (models/spec.py `ModelSpec.arena_layers`); None =
+        # every layer has a row in each arena the family has
     ):
         dtype = dtype or jnp.bfloat16
         if quant is None:
@@ -224,6 +238,8 @@ class CacheManager:
         # (rpc_info `ragged_declines` carries them to health --probe)
         self.state_refusals: dict[str, int] = {}
         self.ssm = ssm
+        kv_layers, state_layers = arena_layers or (num_layers, num_layers)
+        self.kv_layers, self.state_layers = kv_layers, state_layers
         if ssm is not None and prefix_cache:
             # a pooled page holds K/V of a prefix; the state after that
             # prefix went with the session that wrote it
@@ -255,7 +271,7 @@ class CacheManager:
             )
         else:
             self._make_arena = lambda: arena_ops.make_arena(
-                num_layers, num_pages, page_size, n_kv_heads, head_dim,
+                kv_layers, num_pages, page_size, n_kv_heads, head_dim,
                 dtype, quant=self.quant, payload=payload,
             )
         self.arena = self._make_arena()
@@ -283,7 +299,7 @@ class CacheManager:
                 )
             self.num_state_slots = int(state_slots)
             self._make_state = lambda: arena_ops.make_state_arena(
-                num_layers, self.num_state_slots, ssm, dtype
+                state_layers, self.num_state_slots, ssm, dtype
             )
             self.state = self._make_state()
             self._free_state_slots = list(range(self.num_state_slots))[::-1]
@@ -1213,12 +1229,17 @@ class CacheManager:
                 parked_resolved += tree_nbytes(entry.host)
         return {
             "kv_arena_bytes": tree_nbytes(self.arena),
+            "kv_arena_layers": int(self.kv_layers),
             "kv_arena_bytes_by_device": tree_nbytes_by_device(self.arena),
             "parked_kv_host_bytes": parked_resolved,
             "parked_seqs": parked_total,
             "kv_tokens_reserved": int(self._reserved_tokens),
             "kv_tokens_capacity": int(self.capacity_tokens),
-            **({"state": self.state_stats()} if self.ssm is not None else {}),
+            **(
+                {"state": self.state_stats(),
+                 "state_arena_layers": int(self.state_layers)}
+                if self.ssm is not None else {}
+            ),
         }
 
     @_locked

@@ -118,6 +118,7 @@ def _register_builtins() -> None:
     import bloombee_tpu.models.mixtral  # noqa: F401
     import bloombee_tpu.models.qwen2  # noqa: F401
     import bloombee_tpu.models.qwen3  # noqa: F401
+    import bloombee_tpu.models.qwen3_next  # noqa: F401
 
 
 _register_builtins()

@@ -114,15 +114,27 @@ def load_spec(model_dir: str, experts=None) -> ModelSpec:
     return get_family(reader.model_type()).spec_from_reader(reader)
 
 
-def _stack_runs(layers: list[dict]) -> dict:
+def _stack_runs(layers: list[dict], period: int = 0) -> dict:
     """Per-layer params -> the stacked dict, leaf by leaf, letting go of
     each layer's tensor once it is stacked (the span is never held twice).
     Layers of one kind (the same keys) are ONE stack; a span whose first
     layers have other keys than the rest (a dense MLP before sparse ones)
-    is two, the leading run under models/layout.py `LEAD`."""
-    from bloombee_tpu.models.layout import LEAD
+    is two, the leading run under models/layout.py `LEAD`. `period` > 0:
+    the kinds interleave, the last layer of every `period` of another kind
+    than the ones before it: one stack a POSITION in the period, each
+    [periods, ...], the j-th leading layers' under `linear_prefix(j)`, the
+    closing layers' under the plain keys."""
+    from bloombee_tpu.models.layout import LEAD, linear_prefix
     from bloombee_tpu.utils.tree import stack_params
 
+    if period:
+        out = {}
+        for j in range(period):
+            prefix = linear_prefix(j) if j < period - 1 else ""
+            run = layers[j::period]
+            for key in list(run[0]):
+                out[prefix + key] = stack_params([p.pop(key) for p in run])
+        return out
     kinds = [frozenset(p) for p in layers]
     cut = next((i for i, k in enumerate(kinds) if k != kinds[0]), len(layers))
     if any(k != kinds[-1] for k in kinds[cut:]):
@@ -154,6 +166,15 @@ def load_span_params(
     spec = family.spec_from_reader(reader)
     if experts is not None and not spec.num_experts:
         raise ValueError(f"--experts given, but {spec.family} has no experts")
+    reason = spec.span_unsupported(start, end)
+    if reason is not None:
+        raise ValueError(reason)
+    if spec.gdn is not None and adapter_dirs:
+        raise ValueError(
+            f"LoRA adapters unsupported for {spec.family}: q_proj is stored "
+            "split into its query rows and its gate rows, and the linear "
+            "layers have no projection an adapter names"
+        )
     adapters = [LoraAdapter(d) for d in (adapter_dirs or [])]
     layers = []
     for i in range(start, end):
@@ -165,7 +186,49 @@ def load_span_params(
         # per-layer shapes differ (gemma-4): no stacking — the hetero span
         # step unrolls over a tuple of per-layer param dicts
         return tuple(layers), spec
-    return _stack_runs(layers), spec
+    period = len(spec.layer_types) if spec.gdn is not None else 0
+    return _stack_runs(layers, period), spec
+
+
+def held_experts(reader, config_key: str) -> tuple[int, int]:
+    """[first, count) of the published expert numbering this server loads:
+    what it was told (`--experts`), else every expert of the checkpoint
+    (`config_key`: the config's name for their number)."""
+    held = getattr(reader, "experts", None)
+    return tuple(held) if held else (0, reader.config[config_key])
+
+
+def refine_held(spec: ModelSpec, reader, config_key: str) -> ModelSpec:
+    """What the config alone does not say of a family whose experts are
+    shared among chips: the router's width (a checkpoint cut to one chip's
+    share of the experts keeps the router over ALL of them, so it is read
+    off the router's tensor) and the experts held."""
+    import dataclasses
+
+    if not spec.num_experts:
+        return spec
+    first_sparse = next(
+        (i for i in range(spec.num_hidden_layers)
+         if reader.has(f"model.layers.{i}.mlp.gate.weight")), None,
+    )
+    width = spec.num_experts
+    if first_sparse is not None:
+        width = reader.tensor(
+            f"model.layers.{first_sparse}.mlp.gate.weight"
+        ).shape[0]
+    first, count = held_experts(reader, config_key)
+    if first < 0 or count < 1 or first + count > width:
+        raise ValueError(
+            f"--experts {first}:{count} outside the router's {width} experts"
+        )
+    if spec.moe_groups and width % spec.moe_groups:
+        raise ValueError(
+            f"router width {width} not divisible into {spec.moe_groups} groups"
+        )
+    return dataclasses.replace(
+        spec, num_experts=width,
+        moe_held=None if (first, count) == (0, width) else (first, count),
+    )
 
 
 def load_span_params_split(

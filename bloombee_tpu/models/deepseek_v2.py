@@ -23,15 +23,17 @@ makes it.
 
 from __future__ import annotations
 
-import dataclasses
+import functools
 from typing import Any
 
 import numpy as np
 
 from bloombee_tpu.models.auto import Family, register_family
 from bloombee_tpu.models.checkpoint import (
+    held_experts,
     read_tensor as _t,
     read_weight,
+    refine_held,
     stack_expert_weights,
 )
 from bloombee_tpu.models.spec import MlaSpec, ModelSpec
@@ -102,41 +104,8 @@ def deepseek_v2_spec_from_hf(config: Any) -> ModelSpec:
     )
 
 
-def _held(reader) -> tuple[int, int]:
-    """[first, count) of the published expert numbering this server loads:
-    what it was told (`--experts`), else every expert of the checkpoint."""
-    held = getattr(reader, "experts", None)
-    return tuple(held) if held else (0, reader.config["n_routed_experts"])
-
-
-def refine_spec(spec: ModelSpec, reader) -> ModelSpec:
-    """What the config alone does not say: the router's width (a checkpoint
-    cut to one chip's share of the experts keeps the router over ALL of
-    them, so it is read off the router's tensor) and the experts held."""
-    if not spec.num_experts:
-        return spec
-    first_sparse = next(
-        (i for i in range(spec.num_hidden_layers)
-         if reader.has(f"model.layers.{i}.mlp.gate.weight")), None,
-    )
-    width = spec.num_experts
-    if first_sparse is not None:
-        width = reader.tensor(
-            f"model.layers.{first_sparse}.mlp.gate.weight"
-        ).shape[0]
-    first, count = _held(reader)
-    if first < 0 or count < 1 or first + count > width:
-        raise ValueError(
-            f"--experts {first}:{count} outside the router's {width} experts"
-        )
-    if spec.moe_groups and width % spec.moe_groups:
-        raise ValueError(
-            f"router width {width} not divisible into {spec.moe_groups} groups"
-        )
-    return dataclasses.replace(
-        spec, num_experts=width,
-        moe_held=None if (first, count) == (0, width) else (first, count),
-    )
+# the router's width and the experts held, read off the checkpoint
+refine_spec = functools.partial(refine_held, config_key="n_routed_experts")
 
 
 def _load_block(reader, layer_idx: int, dtype=None) -> dict:
@@ -195,7 +164,7 @@ def _load_block(reader, layer_idx: int, dtype=None) -> dict:
     # output-major [E, D] as the checkpoint has it: 160 columns are no
     # whole lanes, and stored [D, E] every program copied the stack
     params["router_t"] = _t(reader, f"{p}.mlp.gate.weight", dtype)
-    first, count = _held(reader)
+    first, count = held_experts(reader, "n_routed_experts")
     params.update(
         stack_expert_weights(
             reader, f"{p}.mlp.experts.{{}}", "gate_proj", "up_proj",

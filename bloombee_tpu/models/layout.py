@@ -32,6 +32,8 @@ OUT_MAJOR_KEYS = frozenset((
     "q_a_proj", "q_b_nope", "q_b_rope", "kv_a_proj", "router_t",
     # kv_b's two halves [heads, dim, kv_rank]: the checkpoint's rows
     "kv_b_k", "kv_b_v",
+    # a gated attention's gate rows, cut out of q_proj at load (qwen3_next)
+    "q_gate_proj",
 ))
 
 LANES = 128  # the TPU's vector width: a minor dimension is tiled by it
@@ -45,6 +47,49 @@ LANES = 128  # the TPU's vector width: a minor dimension is tiled by it
 LEAD = "lead."
 
 
+# A span whose layer kinds INTERLEAVE with a period (qwen3_next: linear,
+# linear, linear, full) is stored as one stack a POSITION in the period:
+# the j-th linear layer of every period under `linear_prefix(j)`, the full
+# layers under the plain keys, each [periods, ...]. The step scans PERIODS
+# and runs a period's layers in order (runtime/step.py `_scan_periods`),
+# each stack an xs of its own: ONE stack [periods, 3, ...] for the linear
+# kind made every period copy its three layers out of it before the first
+# ran (2.4 GB of experts at qwen3-next's widths: the slice has three
+# consumers and is no view of any).
+LINEAR = "lin"
+
+
+def linear_prefix(j: int) -> str:
+    return f"{LINEAR}{j}."
+
+
+def plain_key(key: str) -> str:
+    """A stacked dict's key without its run's prefix."""
+    if key.startswith(LEAD):
+        return key[len(LEAD):]
+    head, dot, rest = key.partition(".")
+    if dot and head.startswith(LINEAR) and head[len(LINEAR):].isdigit():
+        return rest
+    return key
+
+
+def split_kinds(stacked: dict) -> tuple[list[dict], dict]:
+    """([the j-th linear layers' stack for j = 0 ..], the full layers'
+    stack), all under plain keys, of a span stored by position in its
+    period; ([], stacked) for any other span."""
+    linear, j = [], 0
+    while any(k.startswith(linear_prefix(j)) for k in stacked):
+        n = len(linear_prefix(j))
+        linear.append({
+            k[n:]: w for k, w in stacked.items()
+            if k.startswith(linear_prefix(j))
+        })
+        j += 1
+    if not linear:
+        return [], stacked
+    return linear, {k: w for k, w in stacked.items() if plain_key(k) == k}
+
+
 def split_runs(stacked: dict) -> tuple[dict | None, dict]:
     """(the leading run's stack under plain keys or None, the main stack)."""
     lead = {k[len(LEAD):]: w for k, w in stacked.items() if k.startswith(LEAD)}
@@ -54,9 +99,12 @@ def split_runs(stacked: dict) -> tuple[dict | None, dict]:
 
 
 def stacked_layers(stacked: dict) -> int:
-    """How many layers a stacked params dict holds, both runs."""
+    """How many layers a stacked params dict holds, every run and kind."""
     import jax
 
+    linear, main = split_kinds(stacked)
+    if linear:
+        return jax.tree.leaves(main)[0].shape[0] * (len(linear) + 1)
     lead, main = split_runs(stacked)
     n = jax.tree.leaves(main)[0].shape[0]
     return n if lead is None else n + jax.tree.leaves(lead)[0].shape[0]

@@ -42,9 +42,64 @@ class SsmSpec:
         return self.d_ssm + 2 * self.groups * self.state
 
     @property
+    def state_shape(self) -> tuple[int, ...]:
+        """One sequence's recurrent state in one layer (kv/arena.py)."""
+        return (self.heads, self.head_dim, self.state)
+
+    @property
+    def tail_shape(self) -> tuple[int, int]:
+        """The convolution's carried input rows."""
+        return (self.conv - 1, self.conv_dim)
+
+    @property
     def proj_dim(self) -> int:
         """in_proj's output: z | x | B | C | dt."""
         return self.d_ssm + self.conv_dim + self.heads
+
+
+@dataclasses.dataclass(frozen=True)
+class GdnSpec:
+    """A gated-DeltaNet linear-attention mixer IN PLACE of attention in the
+    layers of kind "linear" (qwen3_next: three of every four). Nested and
+    hashable like `SsmSpec`. Per sequence and linear layer the mixer keeps
+    ONE matrix a value head, S [value_heads, key_dim, value_dim], and the
+    last `conv - 1` rows of the convolution's input (channels q | k | v);
+    both live in the state arena, which then has a row a LINEAR layer and
+    none for the others (`ModelSpec.cache_rows`). Key head g serves value
+    heads g * r .. g * r + r - 1, r = value_heads // key_heads."""
+
+    key_heads: int
+    value_heads: int
+    key_dim: int  # per head
+    value_dim: int  # per head
+    conv: int  # depthwise causal convolution width
+    chunk: int = 64  # block length of the chunk form (triangular inside)
+
+    @property
+    def d_key(self) -> int:
+        return self.key_heads * self.key_dim
+
+    @property
+    def d_value(self) -> int:
+        return self.value_heads * self.value_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: q | k | v."""
+        return 2 * self.d_key + self.d_value
+
+    @property
+    def proj_dim(self) -> int:
+        """in_proj's output as stored: q | k | v | z."""
+        return self.conv_dim + self.d_value
+
+    @property
+    def state_shape(self) -> tuple[int, ...]:
+        return (self.value_heads, self.key_dim, self.value_dim)
+
+    @property
+    def tail_shape(self) -> tuple[int, int]:
+        return (self.conv - 1, self.conv_dim)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,7 +198,10 @@ class ModelSpec:
     # Per-layer rope theta override for sliding layers (Gemma3-style)
     rope_local_theta: float = 0.0
     # block structure knobs
-    norm_type: str = "rms"  # "rms" | "ln"
+    # "rms1p": RMSNorm whose stored weight is zero-centred, x/rms * (1 + w),
+    # on the layer norms and q_norm / k_norm (qwen3_next); a client folds
+    # the 1 into its final norm at load and runs "rms"
+    norm_type: str = "rms"  # "rms" | "ln" | "rms1p"
     mlp_type: str = "silu"  # "silu" | "gelu" | "gelu_tanh_gated"
     sandwich_norms: bool = False  # Gemma2-style post-attn/post-ffn norms
     attn_logit_softcap: float = 0.0
@@ -185,6 +243,65 @@ class ModelSpec:
     # still scores all `num_experts`; a pair whose expert is not held adds
     # nothing here (its chip adds it)
     moe_held: tuple[int, int] | None = None
+    # the shared expert's output is scaled by sigmoid(x @ w), w [D]
+    moe_shared_gate: bool = False
+    # a gated-DeltaNet mixer in the layers `layer_types` calls "linear"
+    # (qwen3_next); None = every layer attends
+    gdn: GdnSpec | None = None
+    # rotary on the first `rotary_dim` of a head's dims only (0 = all)
+    rotary_dim: int = 0
+    # q_proj makes a query AND an output gate a head: the attention output
+    # is multiplied by sigmoid(gate) before o_proj (stored split at load:
+    # `q_proj` the query rows, `q_gate_proj` the gate rows)
+    attn_gate: bool = False
+
+    @property
+    def recurrent(self):
+        """The descriptor of the family's recurrent state (`SsmSpec` |
+        `GdnSpec`: both give `state_shape` and `tail_shape`), None without
+        one. What refuses to cut, copy or park a cache asks this."""
+        return self.ssm if self.ssm is not None else self.gdn
+
+    def cache_rows(self, start: int, end: int) -> tuple[tuple[str, int], ...]:
+        """For each layer of the span [start, end): which arena it uses
+        ("state" | "kv") and its row there, the layer's index AMONG ITS KIND
+        in the span. Only a family whose kinds differ in their cache
+        (`gdn`) has arenas of fewer rows than layers."""
+        rows, n = [], {"state": 0, "kv": 0}
+        for i in range(start, end):
+            arena = (
+                "state" if self.gdn is not None
+                and self.layer_type(i) == "linear" else "kv"
+            )
+            rows.append((arena, n[arena]))
+            n[arena] += 1
+        return tuple(rows)
+
+    def arena_layers(self, start: int, end: int) -> tuple[int, int]:
+        """(rows of the K/V arena, rows of the state arena) of a span: its
+        layer count each, except where the kinds differ in their cache."""
+        n = end - start
+        if self.gdn is None:
+            return n, (n if self.ssm is not None else 0)
+        kinds = [arena for arena, _ in self.cache_rows(start, end)]
+        return kinds.count("kv"), kinds.count("state")
+
+    def span_unsupported(self, start: int, end: int) -> str | None:
+        """Why this family cannot serve the span [start, end); None when it
+        can. A periodic pattern of layer kinds is scanned period by period
+        (runtime/step.py `_scan_periods`), so a span holds whole periods
+        from a period's first layer."""
+        if self.gdn is None:
+            return None
+        per = len(self.layer_types)
+        if start % per or (end - start) % per or end <= start:
+            return (
+                f"a {self.family} span must hold whole periods of "
+                f"{per} layers {self.layer_types} from a period's first "
+                f"layer (got [{start}, {end})): the step scans periods, and "
+                "each kind's stack and arena have one row a period's layer"
+            )
+        return None
 
     @property
     def experts_held(self) -> tuple[int, int]:

@@ -32,7 +32,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from bloombee_tpu.models.layout import LEAD, in_axis_of
+from bloombee_tpu.models.layout import in_axis_of, plain_key
 
 GROUP = 32
 
@@ -49,6 +49,10 @@ QUANT_KEYS = (
     # (the router and the norms stay as the checkpoint has them)
     "q_a_proj", "q_b_nope", "q_b_rope", "kv_a_proj", "kv_b_k", "kv_b_v",
     "shared_gate", "shared_up", "shared_down",
+    # qwen3_next: the gate rows of a gated attention, and the gated-DeltaNet
+    # mixer's two wide projections (b | a, the taps, A_log, dt_bias and the
+    # norms stay as the checkpoint has them)
+    "q_gate_proj", "gdn_in_proj", "gdn_out_proj",
 )
 
 
@@ -143,7 +147,7 @@ def quantize_span_params(stacked: dict, bits: int) -> dict:
     (norms, biases, router) pass through dense."""
     out = {}
     for key, leaf in stacked.items():
-        name = key.removeprefix(LEAD)  # a span's leading run of layers
+        name = plain_key(key)  # a span's leading run, or its linear kind
         if name in QUANT_KEYS and getattr(leaf, "ndim", 0) >= 3:
             out[key] = quantize_weight(leaf, bits, in_axis_of(name))
         else:

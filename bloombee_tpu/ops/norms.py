@@ -28,14 +28,22 @@ def layer_norm(
     return y.astype(in_dtype)
 
 
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
+def rms_norm(
+    x: jax.Array, weight: jax.Array, eps: float = 1e-6,
+    one_plus: bool = False,
+) -> jax.Array:
     """RMSNorm with fp32 accumulation, output cast back to input dtype.
 
     Order of operations matches HF LlamaRMSNorm: normalize in fp32, cast back to
-    the input dtype, then multiply by the (un-cast) weight.
+    the input dtype, then multiply by the (un-cast) weight. `one_plus`: the
+    stored weight is zero-centred and the scale is `1 + weight`, taken in
+    fp32 before the cast (HF Qwen3NextRMSNorm: in bfloat16 `1 + w` would
+    round the weight to 2**-8 of its scale).
     """
     in_dtype = x.dtype
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     y = x32 * jax.lax.rsqrt(var + eps)
+    if one_plus:
+        return (y * (1.0 + weight.astype(jnp.float32))).astype(in_dtype)
     return weight * y.astype(in_dtype)

@@ -110,7 +110,16 @@ def apply_rotary(
     cos: jax.Array,  # [B, T, hd]
     sin: jax.Array,  # [B, T, hd]
 ) -> tuple[jax.Array, jax.Array]:
-    """Apply RoPE to q and k (head axis broadcast)."""
+    """Apply RoPE to q and k (head axis broadcast). Tables narrower than
+    the heads (`ModelSpec.rotary_dim`) turn the first dims only: the rest
+    of a head carries no position."""
+    rd = cos.shape[-1]
+    if rd < q.shape[-1]:
+        q_rot, k_rot = apply_rotary(q[..., :rd], k[..., :rd], cos, sin)
+        return (
+            jnp.concatenate([q_rot, q[..., rd:]], axis=-1),
+            jnp.concatenate([k_rot, k[..., rd:]], axis=-1),
+        )
     cos = cos[:, :, None, :].astype(q.dtype)
     sin = sin[:, :, None, :].astype(q.dtype)
     q_out = q * cos + _rotate_half(q) * sin

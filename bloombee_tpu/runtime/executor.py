@@ -169,7 +169,7 @@ class SpanExecutor:
         if not 0.0 < attn_sparsity <= 1.0:
             raise ValueError(f"attn_sparsity in (0, 1], got {attn_sparsity}")
         self.attn_sparsity = float(attn_sparsity)
-        if spec.ssm is not None:
+        if spec.recurrent is not None:
             # one recurrent-state slot a sequence, read and written by the
             # scanned span step only: the paths below address K/V pages
             # layer by layer or chip by chip and have no way to carry it
@@ -188,7 +188,28 @@ class SpanExecutor:
             if manager.state is None:
                 raise ValueError(
                     f"{spec.family} needs a CacheManager with state slots "
-                    "(ssm=spec.ssm, state_slots=...)"
+                    "(ssm=spec.recurrent, state_slots=...)"
+                )
+        reason = spec.span_unsupported(
+            start_block, start_block + manager.num_layers
+        )
+        if reason is not None:
+            raise ValueError(reason)
+        if spec.gdn is not None:
+            want = spec.arena_layers(
+                start_block, start_block + manager.num_layers
+            )
+            if (manager.kv_layers, manager.state_layers) != want:
+                raise ValueError(
+                    f"{spec.family} needs a CacheManager whose K/V arena has "
+                    f"a row a full layer and whose state arena a row a linear "
+                    f"one (arena_layers={want}), got "
+                    f"{(manager.kv_layers, manager.state_layers)}"
+                )
+            if adapters:
+                raise ValueError(
+                    f"LoRA adapters unsupported for {spec.family}: q_proj is "
+                    "stored split into its query rows and its gate rows"
                 )
         if spec.mla is not None:
             # the latent page is attended by the single-chip span step's own
@@ -590,7 +611,7 @@ class SpanExecutor:
             return "sparse (top-k) attention"
         if has_tree and any(w > 0 for w in self.windows):
             return "sliding-window layers"
-        if has_tree and self.spec.ssm is not None:
+        if has_tree and self.spec.recurrent is not None:
             return "recurrent state (tree rows would branch it)"
         if has_tree and self.spec.mla is not None:
             return "latent attention (no tree mask in its kernels)"
@@ -601,7 +622,7 @@ class SpanExecutor:
         """A ragged pack may hold ONE sequence of more than one row: the
         state-space mixer and latent attention run their chunk form on one
         (runtime/layer_body.py)."""
-        return self.spec.ssm is not None or self.spec.mla is not None
+        return self.spec.recurrent is not None or self.spec.mla is not None
 
     def _ragged_bucket(self, counts: list[int], total_lens, t_max: int):
         """(rb, sb, pb, tag) of the fused ragged program for sequences of
@@ -826,8 +847,10 @@ class SpanExecutor:
                 self._paged_kernel_ok(pb * self.page_size)
                 and self.mesh is None
                 and self.manager.quant is None
-                # latent attention's kernels block their queries themselves
-                and (spec.mla is not None
+                # latent attention's kernels block their queries
+                # themselves; a family with linear layers attends a pack
+                # sequence by sequence (layer_body.py `_attend_by_rows`)
+                and (spec.mla is not None or spec.gdn is not None
                      or rb * spec.num_attention_heads <= 2048)
             )
 
@@ -953,7 +976,7 @@ class SpanExecutor:
             )
         if self.manager.quant is not None:
             raise ValueError("decode_n + quantized KV arena not supported")
-        if spec.ssm is not None:
+        if spec.recurrent is not None:
             raise ValueError("decode_n + recurrent state not supported")
         if spec.mla is not None:
             raise ValueError("decode_n + latent attention not supported")
@@ -1080,14 +1103,15 @@ class SpanExecutor:
     def _keep_arena(self, result, kind: str, rows: int, starts):
         """Store a span step's returned arenas (K, V and, where the family
         has one, the state arena) on the manager; returns the step's output.
-        A latent-attention family's step is stamped as it is dispatched,
+        A step of a latent-attention family, or of one with linear-attention
+        layers among attention layers, is stamped as it is dispatched,
         the zero-length span `bbtpu.step`: its `kind` ("decode" | "chunk" |
         "fused"), real `rows` and `context` (its sequences' mean cached
         tokens before it, `starts`): the attention core's time follows the
         context, and a trace's reader has to know WHICH steps it holds.
         `kind` and `rows` are also kept beside what the rows reached of the
         held experts, where the step says it."""
-        if self.spec.mla is not None:
+        if self.spec.mla is not None or self.spec.gdn is not None:
             with jitwatch.span(
                 "bbtpu.step", kind=kind, rows=rows,
                 context=int(np.mean(starts)),
@@ -1293,7 +1317,7 @@ class SpanExecutor:
         lora = resolve_adapter(self.adapters, adapter)
         b, t, d = hidden.shape
         assert d == spec.hidden_size
-        if spec.ssm is not None and (tree_mask is not None or depths is not None):
+        if spec.recurrent is not None and (tree_mask is not None or depths is not None):
             raise ValueError(
                 "tree verify unsupported: a recurrent state cannot branch "
                 "over tree rows or be cut back to the accepted ones"
@@ -1352,7 +1376,7 @@ class SpanExecutor:
                 layer_active[:] = 0
                 layer_active[layers[0] : layers[1]] = 1
             plan = pack_plan(slots_pad, pt_pad, positions, lens_pad, layer_active)
-            if spec.ssm is not None:
+            if spec.recurrent is not None:
                 plan = np.concatenate(
                     [plan, self._state_slots_padded(handle, bb)]
                 )

@@ -36,6 +36,15 @@ code path. Rows past a sequence's real count (bucket tails, padding rows) get
 dt = 0, which neither decays nor feeds the state, and are left out of the
 convolution's new tail.
 
+A family whose layer kinds differ (`spec.gdn`, qwen3_next) has LINEAR layers
+with a gated-DeltaNet mixer in attention's place (`_gdn_layer`: the scopes
+`gdn_proj` (in_proj, gated norm, out_proj), `gdn_conv`, `gdn_rule` (one rule
+step a decode row, the triangular chunk form for a prefill chunk) and
+`state_io`), told from its FULL layers by the keys their params hold. The
+full layers run the generic body with a gated query projection
+(`attn_gate`), rotary on the first `rotary_dim` dims and `1 + w` norms; a
+fused pack of theirs is attended sequence by sequence (`_attend_by_rows`).
+
 A layer never owns its K/V slab as an array: the span step hands it the
 whole arena viewed flat plus slot and page ids already offset to the layer
 (runtime/step.py `_scan_layers`); runtime/hetero.py hands it a per-layer
@@ -62,6 +71,11 @@ from bloombee_tpu.ops.alibi import alibi_slopes
 from bloombee_tpu.ops.attention import NEG_INF, repeat_kv
 from bloombee_tpu.ops.moe import moe_mlp
 from bloombee_tpu.ops.norms import layer_norm
+from bloombee_tpu.ops.linear_attention import (
+    gdn_sequence,
+    gdn_step,
+    l2_normalize,
+)
 from bloombee_tpu.ops.ssm import conv_taps, ssd_sequence, ssm_step
 from bloombee_tpu.utils import env
 
@@ -72,7 +86,9 @@ def _norm(x, params, key, spec):
             return layer_norm(
                 x, params[key], params.get(f"{key}_bias"), spec.rms_norm_eps
             )
-        return rms_norm(x, params[key], spec.rms_norm_eps)
+        return rms_norm(
+            x, params[key], spec.rms_norm_eps, spec.norm_type == "rms1p"
+        )
 
 
 def _proj(x, params, key, lora=None):
@@ -150,12 +166,20 @@ def _mlp(x, params, spec, lora=None):
         )
         if spec.moe_shared_intermediate:
             with jax.named_scope("moe_shared"):
-                out = out + silu_mlp(
+                shared = silu_mlp(
                     x, *(
                         maybe_dequantize(params[k], x.dtype) for k in
                         ("shared_gate", "shared_up", "shared_down")
                     ),
-                ).astype(out.dtype)
+                )
+                if spec.moe_shared_gate:
+                    # qwen3_next: ONE shared expert, scaled a row by
+                    # sigmoid(x @ w); every chip computes it alike
+                    shared = shared * jax.nn.sigmoid(jnp.einsum(
+                        "...d,d->...", x, params["shared_gate_w"],
+                        preferred_element_type=jnp.float32,
+                    ))[..., None].astype(shared.dtype)
+                out = out + shared.astype(out.dtype)
         return out
     with jax.named_scope("mlp"):
         return _dense_mlp(x, params, spec, lora)
@@ -371,6 +395,143 @@ def _mix(spec, params, x, ssm):
         spec, params, x.reshape(-1, x.shape[-1]), state, slots, rows
     )
     return m.reshape(x.shape) * spec.ssm.out_multiplier, state
+
+
+def _window_rows(z, r0, w: int):
+    """Rows r0 .. r0 + w of `z` [R, ...] (zeros past the end): the rows a
+    pack's multi-row sequence may span."""
+    pad = jnp.zeros((w, *z.shape[1:]), z.dtype)
+    return lax.dynamic_slice_in_dim(jnp.concatenate([z, pad]), r0, w)
+
+
+def _place_rows(rows_c, r0, r: int):
+    """The inverse: `rows_c` [w, ...] at rows r0 .. of an [r, ...] of zeros."""
+    w = rows_c.shape[0]
+    return lax.dynamic_update_slice_in_dim(
+        jnp.zeros((r + w, *rows_c.shape[1:]), rows_c.dtype), rows_c, r0, 0
+    )[:r]
+
+
+def _gdn_mixer(spec: ModelSpec, params: dict, x, state: dict, slots,
+               rows: SsmRows):
+    """The gated-DeltaNet mixer (models/spec.py GdnSpec) on flat rows: x
+    [R, D] (the layer's normed input) -> (m [R, D], the state arena).
+    `state` is the flat state arena, `slots` [S] each sequence's row of it
+    for THIS layer (out of range: read clamped, write dropped). A sequence
+    with one row takes one rule step, one with more the chunk form
+    (ops/linear_attention.py), exactly as `_ssm_mixer` tells them apart.
+    Rows past a sequence's real count get beta = 0 and g = 0, which neither
+    decay nor feed S, and are left out of the convolution's new tail."""
+    gdn = spec.gdn
+    r = x.shape[0]
+    s = rows.row0.shape[0]
+    hk, hv, dk, dv = gdn.key_heads, gdn.value_heads, gdn.key_dim, gdn.value_dim
+    rep = hv // hk
+    f32 = jnp.float32
+    with jax.named_scope("gdn_proj"):
+        # q | k | v | z, every cut a whole number of lanes (the loader
+        # regrouped in_proj_qkvz: models/qwen3_next.py)
+        # the products are taken at the STORED widths and cut after the
+        # barrier: without it the compiler moves a cut onto the weight and
+        # every layer copies its in_proj out of the stack first (50 MB a
+        # layer in a decode step, as Falcon-H1's in_proj did)
+        qkvz, ba = lax.optimization_barrier((
+            _proj(x, params, "gdn_in_proj"), _proj(x, params, "gdn_ba_proj"),
+        ))
+        qkv, z = qkvz[:, : gdn.conv_dim], qkvz[:, gdn.conv_dim :]
+        ba = ba.astype(f32)
+        lanes = ba.shape[-1] // 2
+        b, a = ba[:, :hv], ba[:, lanes : lanes + hv]
+    with jax.named_scope("state_io"):
+        tails = state["conv"].at[slots].get(mode="clip")
+        tails = jnp.where(rows.fresh[:, None, None], 0, tails)
+    with jax.named_scope("gdn_conv"):
+        taps, new_tails = conv_taps(qkv, tails, rows.q_seq, rows.row0, rows.nt)
+        conv = jax.nn.silu(jnp.einsum(
+            "rkc,kc->rc", taps.astype(f32), params["gdn_conv_w"].astype(f32)
+        ))
+    with jax.named_scope("gdn_rule"):
+        q = l2_normalize(conv[:, : gdn.d_key].reshape(r, hk, dk)) * dk**-0.5
+        k = l2_normalize(
+            conv[:, gdn.d_key : 2 * gdn.d_key].reshape(r, hk, dk)
+        )
+        # key head g serves value heads g * rep .. g * rep + rep - 1
+        q, k = jnp.repeat(q, rep, axis=1), jnp.repeat(k, rep, axis=1)
+        v = conv[:, 2 * gdn.d_key :].reshape(r, hv, dv)
+        beta = jax.nn.sigmoid(b)
+        g = -jnp.exp(params["gdn_a_log"]) * jax.nn.softplus(
+            a + params["gdn_dt_bias"]
+        )
+    o = jnp.zeros((r, hv, dv), f32)
+    oob = state["ssm"].shape[0]
+    writes = []  # (slots [n], states [n, Hv, dk, dv])
+    if rows.step_form:
+        one = rows.nt == 1
+        at = jnp.clip(rows.row0, 0, r - 1)
+        with jax.named_scope("state_io"):
+            s0 = state["ssm"].at[slots].get(mode="clip")
+            s0 = jnp.where(rows.fresh[:, None, None, None], 0.0, s0)
+        with jax.named_scope("gdn_rule"):
+            o_s, s_new = gdn_step(
+                q[at], k[at], v[at], jnp.where(one[:, None], g[at], 0.0),
+                jnp.where(one[:, None], beta[at], 0.0), s0,
+            )
+            o = o.at[jnp.where(one, rows.row0, r)].set(o_s, mode="drop")
+        writes.append((jnp.where(one, slots, oob), s_new))
+    w = rows.window
+    whole = s == 1 and w == r  # static: one sequence owns every row
+    for i in range(rows.chunk_seqs.shape[0]):
+        c = rows.chunk_seqs[i]
+        r0, n_c, slot_c = rows.row0[c], rows.nt[c], slots[c]
+        with jax.named_scope("state_io"):
+            s0 = state["ssm"].at[slot_c].get(mode="clip")
+            s0 = jnp.where(rows.fresh[c], 0.0, s0)  # gdn
+        with jax.named_scope("gdn_rule"):
+            def take(z_rows):
+                return z_rows if whole else _window_rows(z_rows, r0, w)
+
+            valid = (jnp.arange(w, dtype=jnp.int32) < n_c)[:, None]
+            o_c, s_c = gdn_sequence(
+                take(q), take(k), take(v), jnp.where(valid, take(g), 0.0),
+                jnp.where(valid, take(beta), 0.0), s0, gdn.chunk,
+            )
+            o_c = jnp.where(valid[:, :, None], o_c, 0.0)
+            o = o + (o_c if whole else _place_rows(o_c, r0, r))
+        writes.append((slot_c[None], s_c[None]))
+    with jax.named_scope("state_io"):
+        rule_arena = state["ssm"]
+        for slots_w, s_w in writes:
+            rule_arena = rule_arena.at[slots_w].set(s_w, mode="drop")
+        state = {
+            "ssm": rule_arena,
+            "conv": state["conv"].at[slots].set(
+                new_tails.astype(state["conv"].dtype), mode="drop"
+            ),
+        }
+    with jax.named_scope("gdn_proj"):
+        # the gated norm: per value head, PLAIN weight, then silu(z)
+        y = o * lax.rsqrt(
+            jnp.mean(o * o, axis=-1, keepdims=True) + spec.rms_norm_eps
+        )
+        y = params["gdn_norm"] * y.astype(x.dtype)
+        y = y * jax.nn.silu(z.reshape(r, hv, dv).astype(f32)).astype(x.dtype)
+        return _proj(y.reshape(r, gdn.d_value), params, "gdn_out_proj"), state
+
+
+def _gdn_layer(spec, hidden, params, k_slab, v_slab, ssm, lora=None):
+    """A whole LINEAR layer of a family with gated-DeltaNet layers among
+    attention layers, on [B, T, D] (or the ragged [1, R, D]) rows: the
+    mixer in attention's place, then the MLP. The K/V slabs pass through
+    untouched: this kind of layer has no row in them."""
+    state, slots, rows = ssm
+    x = _norm(hidden, params, "input_layernorm", spec)
+    m, state = _gdn_mixer(
+        spec, params, x.reshape(-1, x.shape[-1]), state, slots, rows
+    )
+    return _finish_layer(
+        spec, params, hidden, x, m.reshape(hidden.shape), k_slab, v_slab,
+        lora, state,
+    )
 
 
 def _mla_attention(spec: ModelSpec, page_size: int, params: dict, x,
@@ -642,6 +803,8 @@ def layer_body(
             spec, page_size, hidden, params, k_slab, v_slab, cos, sin, slots,
             page_table, q_positions, total_lens, rows, use_paged, lora,
         )
+    if spec.gdn is not None and "gdn_in_proj" in params:
+        return _gdn_layer(spec, hidden, params, k_slab, v_slab, ssm, lora)
     b, t, d = hidden.shape
     h_heads, kv_heads, hd = (
         spec.num_attention_heads,
@@ -664,9 +827,11 @@ def layer_body(
             v = _proj(xa, params, "v_proj", lora).reshape(
                 b, t, kv_heads, hd
             )
+        gate = (
+            _proj(xa, params, "q_gate_proj", lora) if spec.attn_gate else None
+        )
         if spec.qk_norm:
-            q = rms_norm(q, params["q_norm"], spec.rms_norm_eps)
-            k = rms_norm(k, params["k_norm"], spec.rms_norm_eps)
+            q, k = _qk_norm(spec, params, q, k)
         if not spec.alibi:
             q, k = apply_rotary(q, k, cos, sin)
 
@@ -716,7 +881,7 @@ def layer_body(
                     t_real=t_real,
                 )
         return _finish_layer(
-            spec, params, hidden, x, _o_proj(spec, attn, params, lora, mix),
+            spec, params, hidden, x, _o_proj(spec, attn, params, lora, mix, gate),
             k_slab, v_slab, lora, state,
         )
     with jax.named_scope("arena_gather"):
@@ -749,7 +914,7 @@ def layer_body(
                 window, attn_topk,
             )
     return _finish_layer(
-        spec, params, hidden, x, _o_proj(spec, attn, params, lora, mix),
+        spec, params, hidden, x, _o_proj(spec, attn, params, lora, mix, gate),
         k_slab, v_slab, lora, state,
     )
 
@@ -764,13 +929,24 @@ def _key_scale(spec, k):
     return k if m == 1.0 else k * m
 
 
-def _o_proj(spec, attn, params, lora, mix=None):
+def _qk_norm(spec, params, q, k):
+    one_plus = spec.norm_type == "rms1p"
+    return (
+        rms_norm(q, params["q_norm"], spec.rms_norm_eps, one_plus),
+        rms_norm(k, params["k_norm"], spec.rms_norm_eps, one_plus),
+    )
+
+
+def _o_proj(spec, attn, params, lora, mix=None, gate=None):
     """[..., T, H, hd] attention output -> [..., T, D] through o_proj, times
-    the family's attention-output multiplier, plus the mixer's share."""
+    the family's attention-output multiplier, plus the mixer's share.
+    `gate` [..., T, H * hd]: a gated attention's output gate, applied as
+    sigmoid(gate) before o_proj."""
     with jax.named_scope("attn_proj"):
-        out = _proj(
-            attn.reshape(*attn.shape[:-2], -1), params, "o_proj", lora
-        )
+        attn = attn.reshape(*attn.shape[:-2], -1)
+        if gate is not None:
+            attn = attn * jax.nn.sigmoid(gate).astype(attn.dtype)
+        out = _proj(attn, params, "o_proj", lora)
         if spec.attention_out_multiplier != 1.0:
             out = out * spec.attention_out_multiplier
     return out if mix is None else out + mix
@@ -847,6 +1023,62 @@ def attend_ragged(
     return jnp.einsum("rhs,shd->rhd", probs, v_r.reshape(b * s, h, hd))
 
 
+def _attend_by_rows(spec, page_size, q, k_slab, v_slab, page_table,
+                    total_lens, q_pos, rows: SsmRows):
+    """Attention of a fused pack whose context is too long for the ragged
+    kernel's one [R * H, hd] block and for dense scores over every member's
+    pages (16 query heads x 1024 rows x 4 x 16384 keys in float32 is 4 GB):
+    q [R, H, hd] -> [R, H, hd], sequence by sequence as `rows` tells them
+    apart, the way latent attention and the mixers take a pack. A sequence
+    with one row streams its pages through the paged decode kernel; THE one
+    with more (a pack holds one: `one_chunk_a_pack`) runs the flash kernel
+    over its own gathered pages. Kernels only: the caller keeps
+    `attend_ragged` where none may run."""
+    from bloombee_tpu.ops.pallas.flash_attention import flash_attention
+    from bloombee_tpu.ops.pallas.paged_attention import paged_decode_attention
+
+    r, h, hd = q.shape
+    scale = attn_scale(spec)
+    out = jnp.zeros((r, h, hd), q.dtype)
+    if rows.step_form:
+        one = rows.nt == 1
+        at = jnp.clip(rows.row0, 0, r - 1)
+        with jax.named_scope("attention"):
+            o = paged_decode_attention(
+                q[at], k_slab, v_slab, page_table,
+                jnp.where(one, total_lens, 0), page_size=page_size,
+                scale=scale, interpret=env.get("BBTPU_PAGED_INTERPRET"),
+            )
+        out = out.at[jnp.where(one, rows.row0, r)].set(o, mode="drop")
+    w = rows.window
+    for i in range(rows.chunk_seqs.shape[0]):
+        c = rows.chunk_seqs[i]
+        r0, n_c = rows.row0[c], rows.nt[c]
+        q_c = _window_rows(q, r0, w)
+        with jax.named_scope("arena_gather"):
+            k_ctx = gather_pages(k_slab, page_table[c][None], page_size)
+            v_ctx = gather_pages(v_slab, page_table[c][None], page_size)
+        start = q_pos[jnp.clip(r0, 0, r - 1)]
+        with jax.named_scope("attention"):
+            if w % 128 == 0 and k_ctx.shape[1] % 128 == 0:
+                o_c = flash_attention(
+                    q_c[None], k_ctx.astype(q.dtype), v_ctx.astype(q.dtype),
+                    causal=True, scale=scale, starts=start[None],
+                    lens=total_lens[c][None],
+                    interpret=env.get("BBTPU_FLASH_INTERPRET"),
+                )[0]
+            else:  # a bucket under the flash kernel's tile (tests)
+                o_c = attend_paged(
+                    spec, q_c[None], k_ctx.astype(q.dtype),
+                    v_ctx.astype(q.dtype),
+                    (start + jnp.arange(w, dtype=jnp.int32))[None],
+                    total_lens[c][None], None, jnp.int32(0),
+                )[0]
+        real = (jnp.arange(w, dtype=jnp.int32) < n_c)[:, None, None]
+        out = out + _place_rows(jnp.where(real, o_c, 0), r0, r)
+    return out
+
+
 def layer_body_ragged(
     spec: ModelSpec,
     page_size: int,
@@ -881,6 +1113,8 @@ def layer_body_ragged(
             spec, page_size, hidden, params, k_slab, v_slab, cos, sin, slots,
             page_table, q_positions, total_lens, rows, use_kernel, lora,
         )
+    if spec.gdn is not None and "gdn_in_proj" in params:
+        return _gdn_layer(spec, hidden, params, k_slab, v_slab, ssm, lora)
     _, r, d = hidden.shape
     h_heads, kv_heads, hd = (
         spec.num_attention_heads,
@@ -901,9 +1135,11 @@ def layer_body_ragged(
             v = _proj(xa, params, "v_proj", lora).reshape(
                 1, r, kv_heads, hd
             )
+        gate = (
+            _proj(xa, params, "q_gate_proj", lora) if spec.attn_gate else None
+        )
         if spec.qk_norm:
-            q = rms_norm(q, params["q_norm"], spec.rms_norm_eps)
-            k = rms_norm(k, params["k_norm"], spec.rms_norm_eps)
+            q, k = _qk_norm(spec, params, q, k)
         if not spec.alibi:
             q, k = apply_rotary(q, k, cos, sin)
 
@@ -914,7 +1150,12 @@ def layer_body_ragged(
         )
     from bloombee_tpu.kv.quant import QuantSlab
 
-    if use_kernel and not isinstance(k_slab, QuantSlab):
+    if use_kernel and rows is not None:
+        attn = _attend_by_rows(
+            spec, page_size, q[0], k_slab, v_slab, page_table, total_lens,
+            q_positions[0], rows,
+        )[None]
+    elif use_kernel and not isinstance(k_slab, QuantSlab):
         from bloombee_tpu.ops.pallas.paged_attention import (
             paged_ragged_attention,
         )
@@ -942,7 +1183,7 @@ def layer_body_ragged(
                 window, nt=nt, tree_rows=tree_rows,
             )[None]
     return _finish_layer(
-        spec, params, hidden, x, _o_proj(spec, attn, params, lora, mix),
+        spec, params, hidden, x, _o_proj(spec, attn, params, lora, mix, gate),
         k_slab, v_slab, lora, state,
     )
 
@@ -962,6 +1203,8 @@ def dense_unsupported(spec: ModelSpec) -> str | None:
         return "heterogeneous head_dim layers"
     if spec.ssm is not None:
         return "a state-space mixer beside attention (recurrent state)"
+    if spec.gdn is not None:
+        return "linear-attention layers among attention layers (recurrent state)"
     if spec.mla is not None:
         return "latent attention (the cache holds latents, not K and V)"
     return None

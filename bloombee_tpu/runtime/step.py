@@ -28,6 +28,11 @@ and return it as a fourth value; the plan carries each sequence's slot at its
 end. A family without one passes `state=None` and compiles the programs it
 compiled before.
 
+A family whose layer KINDS interleave with a period and keep different
+caches (`spec.gdn`: three linear layers, then a full one) is scanned period
+by period (`_scan_periods`): the K/V arena has a row a full layer, the state
+arena a row a linear one, and both ride that one scan's carry.
+
 Shape discipline (SURVEY.md section 7 hard part #1): everything is padded to
 static buckets — batch, step tokens T, and cache pages — and validity is
 carried by `ctx_lens` / position masks. Out-of-bucket padding rows scatter to
@@ -49,7 +54,11 @@ from bloombee_tpu.kv.arena import (
     layer_state_slots,
     stacked_arena,
 )
-from bloombee_tpu.models.layout import split_runs, stacked_layers
+from bloombee_tpu.models.layout import (
+    split_kinds,
+    split_runs,
+    stacked_layers,
+)
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.ops.moe import takes_grouped_form
 from bloombee_tpu.ops.rotary import mla_cos_sin, rotary_cos_sin
@@ -190,9 +199,13 @@ def _rope_by_window(spec: ModelSpec, q_positions: jax.Array, dtype):
                 x.astype(dtype)
                 for x in mla_cos_sin(q_positions, spec.mla, theta)
             )
+        # `rotary_dim`: tables of the dims that turn (ops/rotary.py
+        # `apply_rotary` leaves the rest of a head alone)
         return tuple(
             x.astype(dtype)
-            for x in rotary_cos_sin(q_positions, spec.head_dim, theta)
+            for x in rotary_cos_sin(
+                q_positions, spec.rotary_dim or spec.head_dim, theta
+            )
         )
 
     cos, sin = tables(spec.rope_theta)
@@ -354,6 +367,100 @@ def _scan_layers(
     return (*out, reached) if reach else out
 
 
+def _scan_periods(run_layer, spec, stacked_params, rows, kernels, hidden,
+                  arena_k, arena_v, slots, page_table, layer_active,
+                  per_layer, page_size, state, state_slots, ssm_rows):
+    """The layer scan of a span whose layer KINDS interleave with a period
+    (`spec.gdn`: linear, linear, linear, full): ONE scan over periods whose
+    body runs the period's layers in order. Its xs are one stack a position
+    in the period (models/layout.py `split_kinds`), each [periods, ...];
+    both flat arenas ride the carry whole. A layer's row in ITS arena is
+    its index among its kind: linear layer j of period p reads and writes
+    row p * m + j of the state arena and has no row in the K/V arena; the
+    full layer of period p has row p there and none in the state arena
+    (`ModelSpec.cache_rows`). `layer_active` still gates layer by layer (a
+    session entering mid-span). Returns what `_scan_runs` returns with a
+    state arena."""
+    linear, full = split_kinds(stacked_params)
+    m = len(linear)
+    per = m + 1
+    periods = jax.tree.leaves(full)[0].shape[0]
+    reach = spec.moe_held is not None
+    stacks = [
+        lift_expert_stacks(spec, params, rows, kernels)
+        for params in (*linear, full)
+    ]
+    kv_layers, s_tot = arena_k.shape[:2]
+    num_pages = s_tot // page_size
+    state_layers, num_state_slots = state["ssm"].shape[:2]
+
+    def at(j):  # [layers, ...] xs beside the params -> position j's [periods]
+        return lambda x: None if x is None else jax.tree.map(
+            lambda a: a.reshape(periods, per, *a.shape[1:])[:, j], x
+        )
+
+    def body(carry, xs_p):
+        p, *by_position = xs_p
+        # the period's ONE full layer: its row of the K/V arena
+        slots_l = layer_slots(slots, p, s_tot, kv_layers)
+        pages_l = layer_pages(page_table, p, num_pages)
+        reached = []
+        for j, (active, params_l, *extras_l) in enumerate(by_position):
+            is_linear = j < m
+            experts = stacks[j][1]
+            if experts is not None:
+                params_l = {**params_l, **experts}
+            xs_l = (params_l, *extras_l)
+
+            def run(h, k_flat, v_flat, state_flat, xs_l=xs_l,
+                    is_linear=is_linear, row=p * m + j):
+                ssm_l = None
+                if is_linear:
+                    ssm_l = (
+                        state_flat,
+                        layer_state_slots(
+                            state_slots, row, num_state_slots, state_layers
+                        ),
+                        ssm_rows,
+                    )
+                with collecting_reach() as sown:
+                    out = run_layer(
+                        h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l
+                    )
+                out = out if is_linear else (*out, state_flat)
+                return (*out, sown[0]) if reach else out
+
+            def skip(h, k_flat, v_flat, state_flat):
+                out = (h, k_flat, v_flat, state_flat)
+                return (*out, jnp.zeros((3,), jnp.int32)) if reach else out
+
+            out = lax.cond(active > 0, run, skip, *carry)
+            carry = out[:4]
+            if reach:
+                reached.append(out[4])
+        return carry, (jnp.stack(reached) if reach else None)
+
+    (hidden, k_flat, v_flat, state_flat), reached = lax.scan(
+        body,
+        (hidden, flat_arena(arena_k), flat_arena(arena_v), flat_arena(state)),
+        (
+            jnp.arange(periods, dtype=jnp.int32),
+            *(
+                (at(j)(layer_active), stacks[j][0],
+                 *(at(j)(x) for x in per_layer))
+                for j in range(per)
+            ),
+        ),
+    )
+    out = (
+        hidden,
+        stacked_arena(k_flat, kv_layers),
+        stacked_arena(v_flat, kv_layers),
+        stacked_arena(state_flat, state_layers),
+    )
+    return (*out, reached.reshape(periods * per, 3)) if reach else out
+
+
 def _scan_runs(run_layer, spec, stacked_params, rows, kernels, hidden,
                arena_k, arena_v, slots, page_table, layer_active, per_layer,
                page_size, **state):
@@ -366,7 +473,14 @@ def _scan_runs(run_layer, spec, stacked_params, rows, kernels, hidden,
     span's layers, or None); `run_layer` takes (…, xs_l, ssm_l) with
     xs_l = (params_l, *per_layer_l). On a server that holds a share of the
     experts (`spec.moe_held`) the result ends with what the sparse layers'
-    rows reached of them, i32 [sparse layers, 3] (`_scan_layers`)."""
+    rows reached of them, i32 [sparse layers, 3] (`_scan_layers`). A span
+    whose kinds interleave goes to `_scan_periods`."""
+    if spec.gdn is not None:
+        return _scan_periods(
+            run_layer, spec, stacked_params, rows, kernels, hidden, arena_k,
+            arena_v, slots, page_table, layer_active, per_layer, page_size,
+            **state,
+        )
     lead, main = split_runs(stacked_params)
     runs = [main] if lead is None else [lead, main]
     first = 0
@@ -591,7 +705,12 @@ def span_step_ragged_impl(
     stay on their dedicated paths (the executor gates eligibility
     host-side)."""
     hidden, plan = unpack_step_payload(payload, 1, r, spec.hidden_size)
-    num_layers = arena_k.shape[0]
+    # the span's layers: the K/V arena's rows, except where the layer kinds
+    # differ in their cache and the arena has a row a FULL layer
+    num_layers = (
+        arena_k.shape[0] if spec.gdn is None
+        else stacked_layers(stacked_params)
+    )
     (
         slots, page_table, q_positions, total_lens, q_seq, layer_active,
         nt, tree_rows,
@@ -614,7 +733,11 @@ def span_step_ragged_impl(
             fresh=q_positions[0, at] == 0,
             chunk_seqs=tail[3 * n_seqs :], window=r, step_form=True,
         )
-    rows = ssm_rows if spec.mla is not None else None
+    # latent attention, and the full layers among linear ones (their packs'
+    # contexts are long: layer_body.py `_attend_by_rows`), attend by rows
+    rows = (
+        ssm_rows if spec.mla is not None or spec.gdn is not None else None
+    )
     if state is None:
         state_slots = ssm_rows = None
 

@@ -235,6 +235,21 @@ def estimate_block_bytes(spec, dtype, layer: int | None = None) -> int:
         spec.num_attention_heads, spec.num_key_value_heads, spec.head_dim,
     )
     attn = d * h * hd + 2 * d * kv * hd + h * hd * d
+    if layer is None:
+        layer = spec.num_hidden_layers - 1
+    if spec.attn_gate:
+        attn += d * h * hd + 2 * hd  # the gate rows of q_proj, q/k norms
+    if spec.gdn is not None and spec.layer_type(layer) == "linear":
+        # a gated-DeltaNet mixer in attention's place: in_proj (q | k | v |
+        # z), b | a as stored (two whole lanes), out_proj, the taps, the
+        # gated norm, A_log / dt_bias
+        from bloombee_tpu.models.layout import LANES
+
+        g = spec.gdn
+        attn = (
+            d * g.proj_dim + d * 2 * LANES + g.d_value * d
+            + g.conv * g.conv_dim + g.value_dim + 2 * g.value_heads
+        )
     if spec.mla is not None:
         m = spec.mla
         attn = (
@@ -242,8 +257,6 @@ def estimate_block_bytes(spec, dtype, layer: int | None = None) -> int:
             + m.kv_rank * h * (m.nope_dim + m.v_dim) + h * m.v_dim * d
             + m.q_rank + m.kv_rank
         )
-    if layer is None:
-        layer = spec.num_hidden_layers - 1
     if spec.num_experts and spec.mlp_kind(layer) == "sparse" and (
         spec.moe_intermediate_size
     ):
@@ -252,6 +265,7 @@ def estimate_block_bytes(spec, dtype, layer: int | None = None) -> int:
         mlp = (
             spec.experts_held[1] * 3 * d * spec.moe_intermediate_size
             + d * spec.num_experts + 3 * d * spec.moe_shared_intermediate
+            + (d if spec.moe_shared_gate else 0)
         )
     elif spec.num_experts and spec.mlp_kind(layer) == "sparse":
         mlp = spec.num_experts * 3 * d * i + d * spec.num_experts
@@ -306,12 +320,24 @@ def choose_num_blocks(
     from bloombee_tpu.kv.arena import state_slot_bytes
     from bloombee_tpu.kv.cache_manager import state_slots_for
 
-    if spec.ssm is not None:
-        arena_bytes += state_slots_for(
+    state_bytes = 0
+    if spec.recurrent is not None:
+        state_bytes = state_slots_for(
             spec, num_pages, page_size, max_batch
-        ) * state_slot_bytes(spec.ssm, np.dtype(dtype).itemsize)
+        ) * state_slot_bytes(spec.recurrent, np.dtype(dtype).itemsize)
     budget = limit * memory_fraction
-    n = int(budget // (per_block + arena_bytes))
+    if spec.gdn is not None:
+        # the kinds interleave: count by whole periods, each layer's own
+        # weights and the ONE arena it has a row in
+        per = len(spec.layer_types)
+        kv_layers, state_layers = spec.arena_layers(0, per)
+        period = (
+            estimate_span_bytes(spec, dtype, 0, per)
+            + kv_layers * arena_bytes + state_layers * state_bytes
+        )
+        n = per * int(budget // period)
+        return max(per, min(n, spec.num_hidden_layers // per * per))
+    n = int(budget // (per_block + arena_bytes + state_bytes))
     return max(1, min(n, spec.num_hidden_layers))
 
 

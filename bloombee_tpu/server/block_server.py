@@ -21,6 +21,7 @@ asyncio process:
 from __future__ import annotations
 
 import asyncio
+import collections
 import dataclasses
 import logging
 import random
@@ -661,11 +662,12 @@ class BlockServer(PromotionLoopMixin):
             start_block=start,
             oversubscribe=oversubscribe,
             prefix_cache=prefix_cache,
-            ssm=spec.ssm,
+            ssm=spec.recurrent,
             state_slots=state_slots_for(
                 spec, num_pages, page_size, max_batch
             ),
             payload=spec.mla.page_payload if spec.mla is not None else None,
+            arena_layers=spec.arena_layers(start, end),
         )
         self.idle_park_s = idle_park_s
         if oversubscribe > 1.0:
@@ -700,7 +702,7 @@ class BlockServer(PromotionLoopMixin):
             sp_mesh=sp_mesh,
         )
         self.wire_dtype = name_for_dtype(self.executor.transfer_dtype)
-        if (spec.heterogeneous or host_layers or spec.ssm is not None
+        if (spec.heterogeneous or host_layers or spec.recurrent is not None
                 or spec.mla is not None):
             # hetero / weight-offloaded spans: no dense training stack; a
             # state-space mixer and latent attention have no training-mode
@@ -1575,7 +1577,7 @@ class BlockServer(PromotionLoopMixin):
                 start_block=start,
                 oversubscribe=self.manager.oversubscribe,
                 prefix_cache=self.manager.prefix_cache,
-                ssm=spec.ssm,
+                ssm=spec.recurrent,
                 state_slots=state_slots_for(
                     spec, self._num_pages, self.manager.page_size,
                     self.max_batch,
@@ -1583,6 +1585,7 @@ class BlockServer(PromotionLoopMixin):
                 payload=(
                     spec.mla.page_payload if spec.mla is not None else None
                 ),
+                arena_layers=spec.arena_layers(start, end),
             )
             if self.manager.reclaimer is not None:
                 manager.reclaimer = self._reclaim_idle
@@ -1596,7 +1599,7 @@ class BlockServer(PromotionLoopMixin):
             from bloombee_tpu.runtime.training import TrainingExecutor
 
             training = None if (
-                spec.ssm is not None or spec.mla is not None
+                spec.recurrent is not None or spec.mla is not None
             ) else TrainingExecutor(
                 executor.params, spec, windows=executor.windows,
                 compute_dtype=self.compute_dtype,
@@ -1988,6 +1991,15 @@ class BlockServer(PromotionLoopMixin):
             **(
                 {"experts_held": list(self.spec.experts_held)}
                 if self.spec.num_experts else {}
+            ),
+            # a span whose layer kinds differ in their cache: how many of
+            # each it holds (the K/V arena has a row a full layer, the state
+            # arena a row a linear one: "memory" gives both arenas' layers)
+            **(
+                {"layer_kinds": dict(collections.Counter(
+                    self.spec.layer_type(i)
+                    for i in range(self.start_block, self.end_block)
+                ))} if self.spec.gdn is not None else {}
             ),
             # a share of the experts held: what the steps read so far
             # reached of it (sums over steps and sparse layers; per sparse
@@ -2576,7 +2588,7 @@ class BlockServer(PromotionLoopMixin):
 
             session = _Session(session_id, handle, batch, layers, adapter,
                                client_id=client_id)
-            session.has_state = self.spec.ssm is not None
+            session.has_state = self.spec.recurrent is not None
             session.opened_at = clock.monotonic()
             session.last_step_at = session.opened_at
             self._sessions[session_id] = session
@@ -4001,7 +4013,7 @@ class BlockServer(PromotionLoopMixin):
             )
         if self.spec.heterogeneous:
             return "heterogeneous head_dim span"
-        if self.spec.ssm is not None:
+        if self.spec.recurrent is not None:
             return "recurrent state beside the KV arena"
         if self.spec.mla is not None:
             return "latent attention (the decode loop attends K and V pages)"
@@ -4268,7 +4280,7 @@ class BlockServer(PromotionLoopMixin):
                     handle, int(prefix_skip or 0)
                 )
             session.adoption_settled = True
-            if commit_lens is not None and self.spec.ssm is not None:
+            if commit_lens is not None and self.spec.recurrent is not None:
                 full = self.manager.context_lens(handle) + hidden.shape[1]
                 if any(int(c) < int(f) for c, f in zip(commit_lens, full)):
                     # a ragged replay writes a padded rectangle and commits

@@ -44,8 +44,8 @@ FAULTS = {
     ),
     "routed_sum": (
         BODY,
-        "                out = out + silu_mlp(",
-        "                out = silu_mlp(",
+        "                out = out + shared.astype(out.dtype)",
+        "                out = shared.astype(out.dtype)",
     ),
 }
 

@@ -195,11 +195,21 @@ def _run(tree: pathlib.Path, *argv: str):
     env = dict(os.environ, CELLBENCH_REHEARSAL="1", JAX_PLATFORMS="cpu")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.pop("XLA_FLAGS", None)  # conftest's 8 virtual devices: one is the cell's
-    proc = subprocess.run(
-        [sys.executable, "cellbench/run.py", *argv], cwd=tree, env=env,
-        capture_output=True, text=True, timeout=900)
-    lines = proc.stdout.strip().splitlines()
-    last = json.loads(lines[-1]) if lines else None
+    for attempt in (1, 2):
+        proc = subprocess.run(
+            [sys.executable, "cellbench/run.py", *argv], cwd=tree, env=env,
+            capture_output=True, text=True, timeout=900)
+        lines = proc.stdout.strip().splitlines()
+        last = json.loads(lines[-1]) if lines else None
+        # exit code 4: the run ended WITHOUT a verdict (no result line; its
+        # last line is the summary with the fault: a child that could not
+        # start, e.g. the port run.py's `_free_port` found taken by another
+        # test worker's swarm before the child bound it). Tried once more;
+        # a run that gave a verdict, right or wrong, is never run again.
+        if proc.returncode != 4 or attempt == 2:
+            break
+    if last is not None and "correct" not in last:
+        last = None  # the summary line of a run without a verdict
     return proc.returncode, last, proc.stdout + proc.stderr
 
 

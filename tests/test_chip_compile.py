@@ -648,3 +648,129 @@ def test_deepseek_v2_span_step_compiles_and_copies_no_parameter(v5e, program):
     assert kernels == {"decode": 3, "chunk": 2, "fused": 4}[program]
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < {"decode": 20, "chunk": 300, "fused": 600}[program] * 1e6
+
+
+# -------------------------------------------------------------- qwen3_next
+# gated-DeltaNet layers with a gated full-attention layer every fourth, two
+# cache kinds in one span, 128 of 512 experts held: the span steps of the
+# benchmark's cell (cellbench/configs/qwen3-next-80b-ep4-span8.json:
+# published widths, two periods, 5376 pages) at the 1024-page bucket its
+# contexts take. The K/V arena has a row a FULL layer (2), the state arena a
+# row a LINEAR one (6); head_dim 256 with 2 KV heads through every kernel.
+def _qwen3_next_shapes(one_chip, pages=5376):
+    import dataclasses
+    import json
+    import pathlib
+
+    from bloombee_tpu.kv.cache_manager import state_slots_for
+    from bloombee_tpu.models.auto import spec_from_config_dict
+    from bloombee_tpu.models.layout import LANES, linear_prefix
+
+    config = json.loads((
+        pathlib.Path(__file__).resolve().parents[1]
+        / "cellbench/configs/qwen3-next-80b-ep4-span8.json").read_text())
+    held = tuple(config["experts_held"])
+    spec = dataclasses.replace(
+        spec_from_config_dict(config), num_experts=config["router_experts"],
+        moe_held=held)
+    d, h, kv, hd, g = (spec.hidden_size, spec.num_attention_heads,
+                       spec.num_key_value_heads, spec.head_dim, spec.gdn)
+    periods, m = spec.num_hidden_layers // 4, 3
+    f32 = jnp.float32
+
+    def s(lead, *shape, dtype=bf16):
+        return jax.ShapeDtypeStruct((*lead, *shape), dtype, sharding=one_chip)
+
+    def moe(lead):
+        i, e, si = spec.moe_intermediate_size, held[1], spec.moe_shared_intermediate
+        return {
+            "input_layernorm": s(lead, d),
+            "post_attention_layernorm": s(lead, d),
+            "router": s(lead, d, spec.num_experts),
+            "experts_gate": s(lead, e, d, i), "experts_up": s(lead, e, d, i),
+            "experts_down": s(lead, e, i, d), "shared_gate": s(lead, d, si),
+            "shared_up": s(lead, d, si), "shared_down": s(lead, si, d),
+            "shared_gate_w": s(lead, d),
+        }
+
+    lin = (periods,)
+    linear = {
+        **moe(lin), "gdn_in_proj": s(lin, d, g.proj_dim),
+        "gdn_ba_proj": s(lin, d, 2 * LANES),
+        "gdn_conv_w": s(lin, g.conv, g.conv_dim),
+        "gdn_a_log": s(lin, g.value_heads, dtype=f32),
+        "gdn_dt_bias": s(lin, g.value_heads, dtype=f32),
+        "gdn_norm": s(lin, g.value_dim),
+        "gdn_out_proj": s(lin, g.d_value, d),
+    }
+    full = {
+        **moe((periods,)), "q_proj": s((periods,), h * hd, d),
+        "q_gate_proj": s((periods,), h * hd, d),
+        "k_proj": s((periods,), kv * hd, d),
+        "v_proj": s((periods,), kv * hd, d),
+        "o_proj": s((periods,), h * hd, d),
+        "q_norm": s((periods,), hd), "k_norm": s((periods,), hd),
+    }
+    params = {**full, **{
+        linear_prefix(j) + k: v for j in range(m) for k, v in linear.items()
+    }}
+    kv_layers, state_layers = spec.arena_layers(0, spec.num_hidden_layers)
+    slots = state_slots_for(spec, pages, PAGE, 8)
+    state = {
+        "ssm": s((state_layers, slots), *g.state_shape, dtype=f32),
+        "conv": s((state_layers, slots), *g.tail_shape),
+    }
+    return spec, params, s((kv_layers,), pages * PAGE, kv, hd), state
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "tail", "fused"])
+def test_qwen3_next_span_step_compiles_and_copies_no_parameter(v5e, program):
+    """The cell's step programs (a decode group through the paged kernel and
+    the experts' grouped form, a solo 512-row chunk through flash, an 8-row
+    tail through the chunk kernel, a 1024-row fused pack attended sequence
+    by sequence): ONE program scans two periods of three linear layers and
+    a full one over a 2-row K/V arena and a 6-row state arena; the compiled
+    text holds no `copy` of a `stacked_params` parameter and no copy of a
+    whole arena, and the temporaries stay what the rows' activations need."""
+    import re
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    spec, params, arena, state = _qwen3_next_shapes(one_chip)
+    assert (arena.shape[0], state["ssm"].shape[0]) == (2, 6)
+    layers, pages = 8, 1024
+    common = dict(
+        spec=spec, page_size=PAGE, max_pages=pages, windows=(0,) * layers)
+    if program == "fused":
+        r, n_seqs = 1024, 4
+        plan_len = r + n_seqs * pages + r + n_seqs + r + layers + 3 * n_seqs + 1
+        compiled = span_step_ragged.lower(
+            params, arena, arena, _cell_payload(spec, r, plan_len, one_chip),
+            None, state, r=r, n_seqs=n_seqs, use_kernel=True, **common,
+        ).compile()
+    else:
+        b, t, case = {
+            "decode": (4, 1, dict(use_paged=True)),
+            "chunk": (1, 512, dict(use_flash=True, t_real=512)),
+            "tail": (1, 8, dict(use_paged=True, t_real=5)),
+        }[program]
+        plan_len = b * t + b * pages + b * t + b + layers + b
+        compiled = span_step_packed.lower(
+            params, arena, arena,
+            _cell_payload(spec, b * t, plan_len, one_chip), None, None, state,
+            b=b, t=t, **case, **common,
+        ).compile()
+    text = compiled.as_text()
+    assert "%stacked_params__lin2_gdn_in_proj" in text  # the names read below
+    assert "%stacked_params__q_gate_proj" in text
+    copied = re.findall(r"copy\([^)\n]*%(stacked_params\w+)", text)
+    assert not copied, copied
+    # (the convolution tails' arena, 4.7 MB, is re-laid out for its 3-row
+    # second-minor dimension, as Falcon-H1's: `gdn_state_move_share` reads it)
+    assert not re.findall(r"copy\([^)\n]*%(arena_[kv]|state__ssm)", text)
+    assert "tpu_custom_call" in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    # decode and tail: one state-arena-sized and one slab-sized buffer (the
+    # scatters' results, as Falcon-H1's decode program); ONE stack for the
+    # three linear positions made the chunk program's 2,742 MB
+    assert temp < {"decode": 400, "chunk": 150, "tail": 400,
+                   "fused": 500}[program] * 1e6, temp
