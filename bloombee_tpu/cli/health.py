@@ -308,7 +308,8 @@ def main(argv=None):
                         )
                     # a family with experts: dispatches whose experts took
                     # the grouped form (a decode group reads only the
-                    # experts its rows chose) or the dense one
+                    # experts its rows chose), the tiled one (a chunk above
+                    # the ridge computes only its chosen pairs) or the dense
                     moe = probe.get("moe") or {}
                     if any(moe.values()):
                         line += "  moe " + " ".join(

@@ -4,28 +4,47 @@ The router gives each row exactly `top_k` expert indices and their weights
 (`route_topk`: Mixtral's form masks to the top-k logits and takes the softmax
 over them; Qwen3-MoE's takes the softmax over all experts, picks the top-k
 and renormalises). The experts are stacked weight tensors `[E, D, I]` /
-`[E, I, D]`, the loader's layout, and a step computes them in one of two
-forms, chosen at trace time from the shape it is compiled for:
+`[E, I, D]`, the loader's layout, and a step computes them in one of three
+forms. The rule (`expert_form`) reads the shape the program is compiled for
+and whether Pallas kernels may run in it, and nothing else: no family, no
+flag, and not how many of the router's experts the server holds.
 
-- `rows * top_k < E` (a decode group) and Pallas kernels may run in this
-  program: the GROUPED form. The experts some row chose are listed in index
-  order and ops/pallas/grouped_experts.py walks the list over the stacks
-  where they lie, so an expert nobody chose is never read. A row whose input
-  is exactly zero (a bucket's padding row) has a zero output whichever
-  experts it goes to, so it adds none to the list.
-- otherwise (the 128-row prefill chunk and the fused ragged pack, where the
-  rows hit every expert anyway; any program under a mesh or off the TPU,
-  where the kernel cannot run): the DENSE form, three einsums over all
-  experts. The router's `[rows, E]` weights, zero off the top-k, scale the
-  gated activations, so the down projection is ONE contraction over (expert,
-  intermediate) that reads `[E, I, D]` as it lies; per-expert outputs
-  `[rows, E, D]` weighted afterwards made the compiler re-lay-out the whole
-  stack every run. It tiles onto the MXU, and the expert dimension shards
-  over a mesh for real expert parallelism (bloombee_tpu/parallel/spmd.py
-  psums the partial outputs).
+- LIST, `rows * top_k < E` (a decode group, a short tail): the experts some
+  row chose are listed in index order and ops/pallas/grouped_experts.py
+  `grouped_experts` walks the list over the stacks where they lie, so an
+  expert nobody chose is never read. Every listed expert multiplies ALL the
+  step's rows (one row costs the MXU what sixteen do) and the per-row router
+  weights, zero for a row that did not choose it, are the whole routing. A
+  row whose input is exactly zero (a bucket's padding row) has a zero output
+  whichever experts it goes to, so it adds none to the list.
+- DENSE, three einsums over all experts: every program in which no kernel
+  may run (a mesh, off the TPU, after a kernel fallback, quantised stacks),
+  and the rows between the two other forms. The router's `[rows, E]`
+  weights, zero off the top-k, scale the gated activations, so the down
+  projection is ONE contraction over (expert, intermediate) that reads
+  `[E, I, D]` as it lies; per-expert outputs `[rows, E, D]` weighted
+  afterwards made the compiler re-lay-out the whole stack every run. It
+  tiles onto the MXU, and the expert dimension shards over a mesh for real
+  expert parallelism (bloombee_tpu/parallel/spmd.py psums the partial
+  outputs). It does `rows` multiply-adds a weight it reads, 2 * rows FLOPs
+  for 2 bytes: `rows` FLOPs a byte. The chip's ridge is its peak FLOPs over
+  its peak bytes, 197e12 / 819e9 = 240 on a v5e: under about 240 rows the
+  dense form waits for the weights it reads, which a chunk whose rows reach
+  every expert has to read anyway, and is at its floor; above it the form is
+  compute-bound by `rows / 240` on (row, expert) products whose router
+  weight is exactly zero.
+- TILED, `rows >= TILED_MIN_ROWS` (the 512-row prefill chunk, the fused
+  pack): the (row, expert) pairs the router chose are sorted by expert,
+  `tiled_experts` walks the experts that have a pair over the stacks where
+  they lie, each read ONCE a layer, gathers only that expert's rows, a row
+  tile at a time inside the grid step, multiplies them with the expert's
+  blocks and adds each weighted output into its row: rows * top_k products
+  in place of rows * E. Padding rows and pairs whose expert another chip
+  holds list nothing.
 
-Both compute every chosen (row, expert) pair, drop none, accumulate in
-float32 on the MXU and differ only in the order of a row's top_k-term sum.
+All three compute every chosen (row, expert) pair, drop none (no capacity),
+take bfloat16 operands, accumulate in float32 on the MXU and differ only in
+the order of a row's top_k-term sum.
 """
 
 from __future__ import annotations
@@ -95,9 +114,26 @@ def router_topk_weights(
     return _spread(idx, weights, logits.shape[-1]).astype(logits.dtype)
 
 
-def takes_grouped_form(rows: int, top_k: int, num_experts: int) -> bool:
-    """Can a step of `rows` rows hit fewer than all experts?"""
-    return rows * top_k < num_experts
+# rows from which a program takes the TILED form where it would otherwise be
+# dense: the first row bucket above the v5e's ridge of 240 FLOPs a byte
+# (module docstring), where the dense form's time is the MXU's and no longer
+# the weights'. Measured on the chip at Qwen3-Next's and DeepSeek-V2's held
+# stacks, a layer alone (PERF.md section 6, PR 40): tiled ahead by 4-7% at
+# 128 rows before its plan's sort, 7-10% at 256, 1.9 times at 512.
+TILED_MIN_ROWS = 256
+
+
+def expert_form(
+    rows: int, top_k: int, num_experts: int, kernels: bool
+) -> str:
+    """The form a program of `rows` rows takes for experts of which the
+    router scores `num_experts` and a row chooses `top_k`: "list", "tiled"
+    or "dense" (module docstring). `kernels`: may Pallas kernels run in it."""
+    if not kernels:
+        return "dense"
+    if rows * top_k < num_experts:
+        return "list"  # the rows cannot hit every expert
+    return "tiled" if rows >= TILED_MIN_ROWS else "dense"
 
 
 def _held_local(idx, weights, held):
@@ -132,6 +168,17 @@ def held_reach(x, local, here, count: int) -> jax.Array:
     ).astype(jnp.int32)
 
 
+def _listed(hit: jax.Array, pairs: int):
+    """The experts that are `hit` [E], ascending, as a list of static length
+    P = min(pairs, E) (no more can be hit), padded past the `n` hit ones
+    with the last of them; which slots count [P]."""
+    n = hit.sum().astype(jnp.int32)
+    (listed,) = jnp.nonzero(hit, size=min(pairs, hit.shape[0]), fill_value=0)
+    live = jnp.arange(listed.shape[0], dtype=jnp.int32) < n
+    slot_expert = jnp.where(live, listed, listed[jnp.maximum(n - 1, 0)])
+    return slot_expert.astype(jnp.int32), n, live
+
+
 def _chosen_experts(x, idx, weights, num_experts: int, here=None):
     """The grouped form's plan from the router's per-row choices: the experts
     some LIVE row chose, ascending ([P], padded with the last one), their
@@ -145,18 +192,39 @@ def _chosen_experts(x, idx, weights, num_experts: int, here=None):
     if here is not None:
         chose &= here[..., None]
     row_weights = jnp.where(chose, weights[..., None], 0.0).sum(axis=1)
-    hit = chose.any(axis=(0, 1))  # [E]
-    n = hit.sum().astype(jnp.int32)
-    p = min(r * k, num_experts)
-    (listed,) = jnp.nonzero(hit, size=p, fill_value=0)
-    slot = jnp.arange(p, dtype=jnp.int32)
-    slot_expert = jnp.where(
-        slot < n, listed, listed[jnp.maximum(n - 1, 0)]
-    ).astype(jnp.int32)
-    slot_weights = jnp.where(
-        (slot < n)[:, None], row_weights.T[slot_expert], 0.0
-    )
+    slot_expert, n, live = _listed(chose.any(axis=(0, 1)), r * k)
+    slot_weights = jnp.where(live[:, None], row_weights.T[slot_expert], 0.0)
     return slot_expert, n, slot_weights
+
+
+def _tiled_plan(x, idx, weights, num_experts: int, here=None):
+    """The tiled form's plan from the router's per-row choices: the LIVE
+    pairs sorted by expert (`src` [R*k] each one's row, `w` [R*k] its
+    weight; the pairs of zero rows and of experts held elsewhere sort past
+    the end and are in no run), and the experts that have a pair, ascending
+    ([P], padded with the last one), their count, and each one's run of
+    pairs as (start [P], count [P])."""
+    r, k = idx.shape
+    live = jnp.broadcast_to(jnp.any(x != 0, axis=-1)[:, None], (r, k))
+    if here is not None:
+        live &= here
+    key = jnp.where(live, idx, num_experts).astype(jnp.int32).reshape(-1)
+    row = jnp.broadcast_to(
+        jnp.arange(r, dtype=jnp.int32)[:, None], (r, k)
+    ).reshape(-1)
+    key, src, w = jax.lax.sort(
+        (key, row, weights.astype(jnp.float32).reshape(-1)), num_keys=1
+    )
+    # expert e's run is key == e: [starts[e], starts[e + 1])
+    starts = jnp.searchsorted(
+        key, jnp.arange(num_experts + 1, dtype=jnp.int32), side="left"
+    ).astype(jnp.int32)
+    counts = starts[1:] - starts[:-1]
+    slot_expert, n, live = _listed(counts > 0, r * k)
+    return (
+        slot_expert, n, starts[slot_expert],
+        jnp.where(live, counts[slot_expert], 0), src, w,
+    )
 
 
 def moe_mlp(
@@ -169,9 +237,9 @@ def moe_mlp(
     router_weights: jax.Array | None = None,  # precomputed [B, T, E]
     pre_softmax: bool = False,
     norm_topk: bool = False,
-    expert_base: jax.Array | None = None,  # i32 scalar: take the GROUPED
-    # form; gate/up/down may then hold several layers' experts [N, D, I],
-    # this layer's E starting at that row
+    expert_base: jax.Array | None = None,  # i32 scalar: kernels may run (the
+    # LIST or the TILED form, by `expert_form`); gate/up/down may then hold
+    # several layers' experts [N, D, I], this layer's E starting at that row
     interpret: bool = False,
     groups: int = 0,  # the group-limited router (route_topk)
     topk_groups: int = 0,
@@ -183,11 +251,12 @@ def moe_mlp(
     reach_out: list | None = None,  # with `held`: gets this layer's
     # `held_reach` appended (the caller carries it out of its program)
 ) -> jax.Array:
-    """Gated expert MLPs weighted by the top-k router weights, grouped by
-    chosen expert or dense over all of them (module docstring). The caller
-    picks the form (`takes_grouped_form`, and whether the kernel can run).
+    """Gated expert MLPs weighted by the top-k router weights: by a list of
+    the chosen experts, by the chosen pairs in row tiles, or dense over all
+    experts (module docstring). `expert_form` picks, from the rows, the
+    router and whether the caller says kernels can run (`expert_base`).
 
-    With `held`, both forms take the router's choices over all its experts,
+    With `held`, every form takes the router's choices over all its experts,
     keep the pairs whose expert is held and index the held stack; on one
     chip there is no exchange, the other chips' pairs are theirs to add.
 
@@ -196,11 +265,20 @@ def moe_mlp(
     with psum outside. That takes the dense form.
     """
     b, t, d = x.shape
-    grouped = expert_base is not None
+    if expert_base is None:
+        form = "dense"
+    else:
+        routed = (
+            router_w if router_logits is None else router_logits
+        ).shape[-1]
+        form = expert_form(b * t, top_k, routed, True)
+        if form == "dense":
+            # the caller lifted the stacks where the rule would not have:
+            # the list form is right for any rows
+            form = "list"
     num_experts = (
         held[1] if held is not None
-        else gate_w.shape[0] if not grouped
-        else (router_w if router_logits is None else router_logits).shape[-1]
+        else gate_w.shape[0] if form == "dense" else routed
     )
     if router_weights is None:
         with jax.named_scope("moe_router"):
@@ -216,26 +294,27 @@ def moe_mlp(
                 idx, weights, here = _held_local(idx, weights, held)
                 if reach_out is not None:
                     reach_out.append(held_reach(x, idx, here, held[1]))
-            if grouped:
+            if form == "dense":
+                router_weights = _spread(idx, weights, num_experts).astype(
+                    x.dtype
+                )
+            else:
                 rows = x.reshape(b * t, d)
-                plan = _chosen_experts(
+                plan = (_chosen_experts if form == "list" else _tiled_plan)(
                     rows, idx.reshape(b * t, top_k),
                     weights.reshape(b * t, top_k), num_experts,
                     None if here is None else here.reshape(b * t, top_k),
                 )
-            else:
-                router_weights = _spread(idx, weights, num_experts).astype(
-                    x.dtype
-                )
     with jax.named_scope("moe_experts"):
-        if grouped:
+        if form != "dense":
             from bloombee_tpu.ops.pallas.grouped_experts import (
                 grouped_experts,
+                tiled_experts,
             )
 
-            slot_expert, live, slot_weights = plan
-            out = grouped_experts(
-                rows, slot_expert + expert_base, live, slot_weights,
+            slot_expert, *rest = plan
+            out = (grouped_experts if form == "list" else tiled_experts)(
+                rows, slot_expert + expert_base, *rest,
                 gate_w, up_w, down_w, interpret=interpret,
             )
             return out.astype(x.dtype).reshape(b, t, d)

@@ -73,6 +73,31 @@ def _i_tile(d: int, i: int, itemsize: int) -> int:
     return tile
 
 
+def _weight_blocks(gate_w: jax.Array):
+    """(tiles of the intermediate dim, the gate / up / down BlockSpecs of a
+    grid (slot, tile) steered by the first two scalar-prefetch operands, the
+    slots' experts and how many slots count, and the bytes of the three
+    double-buffered blocks)."""
+    _, d, i = gate_w.shape
+    ti = _i_tile(d, i, gate_w.dtype.itemsize)
+    n_j = i // ti
+
+    def tile(s, j, n):
+        # a dead slot keeps the last block fetched: no DMA
+        return jnp.where(s < n[0], j, n_j - 1)
+
+    in_block = pl.BlockSpec(  # gate and up: [D, tI] of slot s's expert
+        (None, d, ti), lambda s, j, e, n, *_: (e[s], 0, tile(s, j, n))
+    )
+    down_block = pl.BlockSpec(
+        (None, ti, d), lambda s, j, e, n, *_: (e[s], tile(s, j, n), 0)
+    )
+    return (
+        n_j, [in_block, in_block, down_block],
+        3 * 2 * d * ti * gate_w.dtype.itemsize,
+    )
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def grouped_experts(
     x: jax.Array,  # [R, D]
@@ -89,37 +114,23 @@ def grouped_experts(
     float32."""
     r, d = x.shape
     p = slot_expert.shape[0]
-    i = gate_w.shape[-1]
     # whole sublane tiles for the MXU's left operand; the added rows are
     # zeros with zero weights
     r_pad = -r % (16 if x.dtype.itemsize == 2 else 8)
     x = jnp.pad(x, ((0, r_pad), (0, 0)))
     w = jnp.pad(slot_weights.astype(jnp.float32), ((0, 0), (0, r_pad)))
     rows = r + r_pad
-    ti = _i_tile(d, i, gate_w.dtype.itemsize)
-    n_j = i // ti
-
-    def tile(s, j, n):
-        # a dead slot keeps the last block fetched: no DMA
-        return jnp.where(s < n[0], j, n_j - 1)
-
-    in_block = pl.BlockSpec(  # gate and up: [D, tI] of slot s's expert
-        (None, d, ti), lambda s, j, e, n: (e[s], 0, tile(s, j, n))
-    )
+    n_j, weight_blocks, block_bytes = _weight_blocks(gate_w)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(p, n_j),
         in_specs=[
             pl.BlockSpec((rows, d), lambda s, j, e, n: (0, 0)),
             pl.BlockSpec((None, rows, 1), lambda s, j, e, n: (s, 0, 0)),
-            in_block, in_block,
-            pl.BlockSpec(
-                (None, ti, d), lambda s, j, e, n: (e[s], tile(s, j, n), 0)
-            ),
+            *weight_blocks,
         ],
         out_specs=pl.BlockSpec((rows, d), lambda s, j, e, n: (0, 0)),
     )
-    block_bytes = 3 * 2 * d * ti * gate_w.dtype.itemsize
     out = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
@@ -136,3 +147,142 @@ def grouped_experts(
         x, w[:, :, None], gate_w, up_w, down_w,
     )
     return out[:r]
+
+
+# rows a grid step of the tiled form gathers, multiplies and scatters at a
+# time: an expert's run of (row, expert) pairs is walked in tiles of this
+# many INSIDE its grid step, so its weight blocks are fetched once however
+# long the run is. 32, 64 and 128 read the same time on the chip to 0.3%
+# (PERF.md section 6, PR 40): the step waits for the next expert's blocks
+ROW_TILE = 64
+
+
+def _tiled_kernel(
+    e_ref,  # [P] i32 scalar prefetch: slot s's expert (row of the stack)
+    n_ref,  # [1] i32: live slots
+    start_ref,  # [P] i32: where slot s's run of pairs starts in src / w
+    count_ref,  # [P] i32: how many pairs it has
+    src_ref,  # [N] i32: the row each sorted pair came from
+    w_ref,  # [N] f32: its router weight
+    x_hbm,  # [R, D] f32, where it lies
+    g_ref,  # [D, tI]
+    u_ref,  # [D, tI]
+    d_ref,  # [tI, D]
+    o_hbm,  # [R, D] f32, where it lies
+    x_ref,  # [R, D] f32 scratch: the rows, fetched once
+    o_ref,  # [R, D] f32 scratch: the sum, written back once
+    xt_ref,  # [tile, D] f32 scratch: a tile's gathered rows
+    yt_ref,  # [tile, D] f32 scratch: their outputs for this expert
+    sem,
+):
+    s = pl.program_id(0)
+    j = pl.program_id(1)
+    tile = xt_ref.shape[0]
+
+    @pl.when((s == 0) & (j == 0))
+    def _init():
+        rows_in = pltpu.make_async_copy(x_hbm, x_ref, sem)
+        rows_in.start()
+        o_ref[...] = jnp.zeros_like(o_ref)
+        # rows of a tile past its pairs are multiplied too and never read:
+        # they must hold numbers
+        xt_ref[...] = jnp.zeros_like(xt_ref)
+        rows_in.wait()
+
+    @pl.when(s < n_ref[0])
+    def _expert():
+        start = start_ref[s]
+        count = count_ref[s]
+
+        def row_tile(t, carry):
+            base = start + t * tile
+            live = jnp.minimum(count - t * tile, tile)
+
+            def gather(i, c):
+                xt_ref[pl.ds(i, 1), :] = x_ref[pl.ds(src_ref[base + i], 1), :]
+                return c
+
+            jax.lax.fori_loop(0, live, gather, 0)
+            x = xt_ref[...].astype(g_ref.dtype)
+            g = jnp.dot(x, g_ref[...], preferred_element_type=jnp.float32)
+            u = jnp.dot(x, u_ref[...], preferred_element_type=jnp.float32)
+            h = jax.nn.silu(g) * u
+            yt_ref[...] = jnp.dot(
+                h.astype(x.dtype), d_ref[...],
+                preferred_element_type=jnp.float32,
+            )
+
+            def scatter(i, c):
+                row = pl.ds(src_ref[base + i], 1)
+                o_ref[row, :] += w_ref[base + i] * yt_ref[pl.ds(i, 1), :]
+                return c
+
+            jax.lax.fori_loop(0, live, scatter, 0)
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(count, tile), row_tile, 0)
+
+    @pl.when(
+        (s == pl.num_programs(0) - 1) & (j == pl.num_programs(1) - 1)
+    )
+    def _done():
+        rows_out = pltpu.make_async_copy(o_ref, o_hbm, sem)
+        rows_out.start()
+        rows_out.wait()
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def tiled_experts(
+    x: jax.Array,  # [R, D]
+    slot_expert: jax.Array,  # [P] i32: the experts some pair chose, padded
+    # past `live` with the last live one
+    live: jax.Array,  # i32 scalar: how many slots count
+    slot_start: jax.Array,  # [P] i32: slot s's pairs are
+    # src[start : start + count]
+    slot_count: jax.Array,  # [P] i32
+    src: jax.Array,  # [N] i32: the pairs sorted by expert, each one's row
+    weights: jax.Array,  # [N] f32: each one's router weight
+    gate_w: jax.Array,  # [E, D, I]
+    up_w: jax.Array,  # [E, D, I]
+    down_w: jax.Array,  # [E, I, D]
+    interpret: bool = False,
+) -> jax.Array:
+    """sum over the listed pairs p of weights[p] * mlp_{expert(p)}(x[src[p]])
+    into row src[p]: [R, D] float32. Only the pairs' rows are multiplied
+    with an expert, `ROW_TILE` at a time; a row no pair names stays zero."""
+    r, d = x.shape
+    p = slot_expert.shape[0]
+    n_j, weight_blocks, block_bytes = _weight_blocks(gate_w)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=6,
+        grid=(p, n_j),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY), *weight_blocks],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[
+            pltpu.VMEM((r, d), jnp.float32),
+            pltpu.VMEM((r, d), jnp.float32),
+            pltpu.VMEM((ROW_TILE, d), jnp.float32),
+            pltpu.VMEM((ROW_TILE, d), jnp.float32),
+            pltpu.SemaphoreType.DMA(()),
+        ],
+    )
+    scratch_bytes = 2 * (r + ROW_TILE) * d * 4
+    return pl.pallas_call(
+        _tiled_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=block_bytes + scratch_bytes + 8 * 2**20,
+        ),
+        interpret=interpret,
+        name="tiled_experts",
+    )(
+        slot_expert.astype(jnp.int32),
+        jnp.asarray(live, jnp.int32).reshape(1),
+        slot_start.astype(jnp.int32),
+        slot_count.astype(jnp.int32),
+        src.astype(jnp.int32),
+        weights.astype(jnp.float32),
+        x.astype(jnp.float32), gate_w, up_w, down_w,
+    )

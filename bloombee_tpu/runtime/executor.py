@@ -25,7 +25,7 @@ import ml_dtypes
 from bloombee_tpu.kv.cache_manager import CacheHandle, CacheManager
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.runtime.step import (
-    experts_grouped,
+    experts_form,
     pack_plan,
     pack_ragged_plan,
     pack_ragged_ssm_tail,
@@ -354,7 +354,7 @@ class SpanExecutor:
         self.ragged_buckets_run: set[str] = set()
         # a family with experts: device dispatches by the form its experts
         # took (ops/moe.py), counted from the bucket's rows
-        self.moe_dispatches = {"grouped": 0, "dense": 0}
+        self.moe_dispatches = {"grouped": 0, "tiled": 0, "dense": 0}
         # a server that holds a share of the experts (spec.moe_held): what
         # each step's rows reached of them per sparse layer (ops/moe.py
         # `held_reach`), handed out of the step program as a device array
@@ -890,8 +890,9 @@ class SpanExecutor:
         )
         self.attn_dispatches["ragged" if used_kernel else "dense"] += 1
         self.ragged_buckets_run.add(tag)
-        self._count_moe(rb, used_kernel)
-        out = self._keep_arena(result, "fused", r, starts)
+        out = self._keep_arena(
+            result, "fused", r, starts, self._count_moe(rb, used_kernel)
+        )
         with jitwatch.span("bbtpu.slice"):
             return out[0, :r], combined
 
@@ -1100,7 +1101,8 @@ class SpanExecutor:
             arena["state"] = self.manager.state
         return arena
 
-    def _keep_arena(self, result, kind: str, rows: int, starts):
+    def _keep_arena(self, result, kind: str, rows: int, starts,
+                    experts: str | None = None):
         """Store a span step's returned arenas (K, V and, where the family
         has one, the state arena) on the manager; returns the step's output.
         A step of a latent-attention family, or of one with linear-attention
@@ -1108,13 +1110,15 @@ class SpanExecutor:
         the zero-length span `bbtpu.step`: its `kind` ("decode" | "chunk" |
         "fused"), real `rows` and `context` (its sequences' mean cached
         tokens before it, `starts`): the attention core's time follows the
-        context, and a trace's reader has to know WHICH steps it holds.
+        context, and a trace's reader has to know WHICH steps it holds;
+        `experts` is the form its experts took (`_count_moe`).
         `kind` and `rows` are also kept beside what the rows reached of the
         held experts, where the step says it."""
         if self.spec.mla is not None or self.spec.gdn is not None:
             with jitwatch.span(
                 "bbtpu.step", kind=kind, rows=rows,
                 context=int(np.mean(starts)),
+                **({"experts": experts} if experts else {}),
             ):
                 pass
         if self.spec.moe_held is not None:
@@ -1134,10 +1138,14 @@ class SpanExecutor:
             slots[: handle.batch_size] = self.manager.state_slots(handle)
         return slots
 
-    def _count_moe(self, rows: int, kernels: bool) -> None:
-        if self.spec.num_experts:
-            grouped = experts_grouped(self.spec, self.params, rows, kernels)
-            self.moe_dispatches["grouped" if grouped else "dense"] += 1
+    def _count_moe(self, rows: int, kernels: bool) -> str | None:
+        """Count the dispatch under the form its experts took (ops/moe.py:
+        the list form counts as `grouped`) and return it."""
+        if not self.spec.num_experts:
+            return None
+        form = experts_form(self.spec, self.params, rows, kernels)
+        self.moe_dispatches["grouped" if form == "list" else form] += 1
+        return form
 
     @staticmethod
     def _arena_consumed(arena) -> bool:
@@ -1155,10 +1163,18 @@ class SpanExecutor:
         caller adds what its own step needs (mesh, arena quantisation, row
         budget, sparsity)."""
         return bool(
-            not self._paged_broken
+            self._kernels_ok()
             and context_tokens >= env.get("BBTPU_PAGED_MIN_CONTEXT")
             and not self.spec.alibi
             and not self.spec.attn_logit_softcap
+        )
+
+    def _kernels_ok(self) -> bool:
+        """The part of `_paged_kernel_ok` that is no matter of attention: no
+        kernel has failed on this device, and the kernels are switched on
+        and can run here."""
+        return bool(
+            not self._paged_broken
             and env.get("BBTPU_PAGED_ATTENTION")
             and _kernels_available("BBTPU_PAGED_INTERPRET")
         )
@@ -1407,7 +1423,7 @@ class SpanExecutor:
                 # latent attention: the paged decode kernel for T == 1, the
                 # flash form over the gathered latent rows for any chunk
                 # (it blocks its queries itself); `use_paged` says kernels
-                # may run in this program, the experts' grouped form too
+                # may run in this program, the experts' kernel forms too
                 t1_ok = chunk_ok = True
             use_paged = bool(
                 self._paged_kernel_ok(pb * self.page_size)
@@ -1472,6 +1488,7 @@ class SpanExecutor:
                 _run_off, use_paged, arena, "offloaded step"
             )
             self.manager.arena = {"k": new_k, "v": new_v}
+            self._count_moe(bb * tb, use_paged)
         elif self.spec.heterogeneous:
             from bloombee_tpu.runtime.hetero import span_step_hetero
 
@@ -1503,10 +1520,19 @@ class SpanExecutor:
                 _run_hetero, False, arena, "hetero span step"
             )
             self.manager.arena = {"k": new_k, "v": new_v}
+            self._count_moe(bb * tb, False)
         else:
             payload_dev, tm_dev = self._place_step_inputs(h_pad, plan, tm_pad)
 
-            def _run(use_paged_now: bool):
+            # a chunk that attends through flash runs no paged kernel, yet
+            # Pallas kernels run in its program: its experts may take a
+            # kernel form where what every such kernel needs holds
+            flash_experts = bool(
+                use_flash and not use_paged and self._kernels_ok()
+                and experts_form(spec, self.params, bb * tb, True) != "dense"
+            )
+
+            def _run(kernels_now: bool):
                 with jitwatch.region(
                     "span_step_packed", f"b{bb},t{tb},p{pb}"
                 ):
@@ -1527,19 +1553,21 @@ class SpanExecutor:
                         use_tree_mask=tree_mask is not None,
                         windows=self.windows,
                         use_flash=use_flash,
-                        use_paged=use_paged_now,
+                        use_paged=use_paged and kernels_now,
                         t_real=t,
+                        expert_kernels=flash_experts and kernels_now,
                     )
 
-            result, use_paged = self._dispatch(
-                _run, use_paged, arena, "span step"
+            result, kernels_used = self._dispatch(
+                _run, use_paged or flash_experts, arena, "span step"
             )
+            use_paged = use_paged and kernels_used
             out = self._keep_arena(
-                result, "decode" if t == 1 else "chunk", b * t, starts
+                result, "decode" if t == 1 else "chunk", b * t, starts,
+                self._count_moe(bb * tb, kernels_used),
             )
         path = "paged" if use_paged else "flash" if use_flash else "dense"
         self.attn_dispatches[path] += 1
-        self._count_moe(bb * tb, use_paged and not self.spec.heterogeneous)
         with jitwatch.span("bbtpu.slice"):
             out = out[:b, :t]
         if not fetch:

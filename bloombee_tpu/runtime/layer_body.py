@@ -151,7 +151,7 @@ def _mlp(x, params, spec, lora=None):
             pre_softmax=spec.moe_pre_softmax,
             norm_topk=spec.moe_norm_topk,
             # the step lifted the stacks out of the scan (runtime/step.py
-            # `lift_expert_stacks`): the grouped form, by index
+            # `lift_expert_stacks`): a kernel form, by index
             expert_base=params.get("expert_base"),
             interpret=env.get("BBTPU_PAGED_INTERPRET"),
             groups=spec.moe_groups,

@@ -60,7 +60,7 @@ from bloombee_tpu.models.layout import (
     stacked_layers,
 )
 from bloombee_tpu.models.spec import ModelSpec
-from bloombee_tpu.ops.moe import takes_grouped_form
+from bloombee_tpu.ops.moe import expert_form
 from bloombee_tpu.ops.rotary import mla_cos_sin, rotary_cos_sin
 from bloombee_tpu.runtime.layer_body import (
     SsmRows,
@@ -164,6 +164,7 @@ def span_step_packed_impl(
     use_paged: bool = False,
     attn_topk: int = 0,
     t_real: int | None = None,
+    expert_kernels: bool = False,
 ):
     """span_step over a pack_step_payload buffer (one h2d per step)."""
     hidden, plan = unpack_step_payload(payload, b, t, spec.hidden_size)
@@ -173,6 +174,7 @@ def span_step_packed_impl(
         spec=spec, page_size=page_size, max_pages=max_pages,
         use_tree_mask=use_tree_mask, windows=windows, use_flash=use_flash,
         use_paged=use_paged, attn_topk=attn_topk, t_real=t_real,
+        expert_kernels=expert_kernels,
     )
 
 
@@ -180,7 +182,7 @@ span_step_packed = functools.partial(
     jax.jit,
     static_argnames=(
         "spec", "b", "t", "page_size", "max_pages", "use_tree_mask",
-        "windows", "use_flash", "use_paged", "attn_topk",
+        "windows", "use_flash", "use_paged", "attn_topk", "expert_kernels",
     ),
     donate_argnames=("arena_k", "arena_v", "state"),
 )(span_step_packed_impl)
@@ -227,21 +229,24 @@ def _rope_by_window(spec: ModelSpec, q_positions: jax.Array, dtype):
 EXPERT_STACKS = ("experts_gate", "experts_up", "experts_down")
 
 
-def experts_grouped(
+def experts_form(
     spec: ModelSpec, stacked_params: dict, rows: int, kernels: bool
-) -> bool:
-    """Does a step of `rows` rows take the experts' grouped form
-    (ops/moe.py)? Fewer rows than can hit every expert, a program in which
-    Pallas kernels may run, and stacks the kernel can read as they lie (a
+) -> str:
+    """The form a step of `rows` rows takes for its experts, "list", "tiled"
+    or "dense" (ops/moe.py `expert_form`): a kernel form needs a program in
+    which Pallas kernels may run and stacks a kernel can read as they lie (a
     quantised stack is dequantised a layer at a time and stays dense)."""
-    return bool(
+    if not (
         spec.num_experts
         and kernels
-        and takes_grouped_form(rows, spec.num_experts_per_tok, spec.num_experts)
         and all(
             isinstance(stacked_params.get(k), jax.Array)
             for k in EXPERT_STACKS
         )
+    ):
+        return "dense"
+    return expert_form(
+        rows, spec.num_experts_per_tok, spec.num_experts, True
     )
 
 
@@ -251,15 +256,16 @@ def lift_expert_stacks(
     """(the params that ride the scan as xs, the expert stacks held WHOLE or
     None).
 
-    The grouped form walks the chosen experts over the stacks where they lie,
-    so the stacks must not ride the scan: a layer's [E, D, I] slice of xs
-    handed to a kernel is a copy of it (1.2 GB a layer at 128 experts). Like
-    the arena they are viewed flat over (layer, expert), closed over by the
-    layer, and the scan carries only `expert_base`, the row where layer l's
-    experts start. Every other step (the rows hit all experts anyway, no
-    kernel may run, quantised stacks, a family without experts) gets its
-    params back as they came and traces what it traced before."""
-    if not experts_grouped(spec, stacked_params, rows, kernels):
+    The list and the tiled form walk the chosen experts over the stacks where
+    they lie, so the stacks must not ride the scan: a layer's [E, D, I] slice
+    of xs handed to a kernel is a copy of it (1.2 GB a layer at 128 experts).
+    Like the arena they are viewed flat over (layer, expert), closed over by
+    the layer, and the scan carries only `expert_base`, the row where layer
+    l's experts start. Every other step (the dense form: rows under the
+    ridge that hit all experts anyway, no kernel may run, quantised stacks;
+    a family without experts) gets its params back as they came and traces
+    what it traced before."""
+    if experts_form(spec, stacked_params, rows, kernels) == "dense":
         return stacked_params, None
     xs = {k: w for k, w in stacked_params.items() if k not in EXPERT_STACKS}
     n = stacked_params[EXPERT_STACKS[0]].shape[0]
@@ -539,6 +545,8 @@ def span_step_impl(
     use_paged: bool = False,
     attn_topk: int = 0,
     t_real: int | None = None,
+    expert_kernels: bool = False,  # the experts' kernels may run though the
+    # paged attention kernels do not (a chunk that attends through flash)
 ):
     """Run all local blocks over one step; returns (hidden, arena_k, arena_v)
     and, given a state arena, that as a fourth value.
@@ -587,8 +595,8 @@ def span_step_impl(
         )
 
     return _scan_runs(
-        run_layer, spec, stacked_params, b * t, use_paged, hidden, arena_k,
-        arena_v, slots, page_table, layer_active,
+        run_layer, spec, stacked_params, b * t, use_paged or expert_kernels,
+        hidden, arena_k, arena_v, slots, page_table, layer_active,
         (windows_arr, prompts, lora), page_size,
         state=state, state_slots=state_slots, ssm_rows=ssm_rows,
     )
@@ -598,7 +606,7 @@ span_step = functools.partial(
     jax.jit,
     static_argnames=(
         "spec", "page_size", "max_pages", "use_tree_mask", "windows",
-        "use_flash", "use_paged", "attn_topk",
+        "use_flash", "use_paged", "attn_topk", "expert_kernels",
     ),
     donate_argnames=("arena_k", "arena_v", "state"),
 )(span_step_impl)

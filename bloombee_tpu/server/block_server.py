@@ -1977,7 +1977,8 @@ class BlockServer(PromotionLoopMixin):
             "attn_dispatches": dict(self.executor.attn_dispatches),
             "kernel_fallbacks": self.executor.kernel_fallbacks,
             # a family with experts: dispatches by the form the experts
-            # took, grouped by chosen expert or dense over all (ops/moe.py)
+            # took: grouped (a list of the chosen experts), tiled (the
+            # chosen pairs in row tiles) or dense over all (ops/moe.py)
             **(
                 {"moe": {
                     f"{form}_dispatches": n

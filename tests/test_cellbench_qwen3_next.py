@@ -194,9 +194,50 @@ def test_scopetrace_reduces_a_synthetic_trace_by_the_scopes_it_is_given():
     assert scopetrace.median_ms(None, "chunk", "gdn_rule") is None
 
 
+def test_chunk_experts_ms_reads_the_sparse_layer_of_a_chunk_run(monkeypatch):
+    """`chunk_experts_ms_p50` (PR 40): per chunk run the router (with a
+    kernel form's plan: the sort), the shared experts and the experts, the
+    tiled kernel's call under `moe_experts` like the dense form's fusions;
+    a decode run and the trace's edge runs are left out; a program without
+    the scopes reads nothing."""
+    from cellbench import scopetrace
+    from cellbench.metrics import chunk_experts_ms_p50 as metric
+
+    ms = 1e-3
+    step = "jit(span_step_packed_impl)/jit(main)/while/body/"
+    prog = "jit_span_step_packed_impl(1)"
+
+    def op(name, start, dur, op_name):
+        return (f"%{name} = f32[8]{{0}} {name.split('.')[0]}()", start * ms,
+                dur * ms, op_name)
+
+    raw = {"device": [{"name": "/device:TPU:0", "modules": [
+        (prog, 0.0, 5 * ms), (prog, 10 * ms, 10 * ms), (prog, 30 * ms, 10 * ms),
+        (prog, 50 * ms, 10 * ms), (prog, 70 * ms, 5 * ms),
+    ], "ops": [
+        op("fusion.0", 0, 5, step + "moe_experts/dot_general:"),
+        op("paged_decode_attention.1", 10, 3, step + "attention/pallas_call:"),
+        op("grouped_experts.2", 13, 2, step + "moe_experts/jit(grouped_experts)/pallas_call:"),
+        op("sort.3", 30, 0.5, step + "moe_router/sort:"),
+        op("fusion.4", 30.5, 0.25, step + "moe_shared/dot_general:"),
+        op("tiled_experts.5", 31, 4, step + "moe_experts/jit(tiled_experts)/pallas_call:"),
+        op("fusion.6", 35, 3, step + "gdn_rule/dot_general:"),
+        op("fusion.7", 50, 1, step + "moe_router/dot_general:"),
+        op("fusion.8", 51, 8, step + "moe_experts/dot_general:"),
+        op("fusion.9", 70, 5, step + "moe_experts/dot_general:"),
+    ]}]}
+    monkeypatch.setattr(
+        scopetrace, "reduced",
+        lambda ctx, name, scopes, move: scopetrace.reduce(raw, scopes, move))
+    assert metric.read({}) == pytest.approx((4.75 + 9.0) / 2)
+    raw["device"][0]["ops"] = [
+        o for o in raw["device"][0]["ops"] if "moe_" not in o[3]]
+    assert metric.read({}) is None
+
+
 @pytest.mark.parametrize("name", [
     "step_gdn_ms_p50", "chunk_gdn_ms_p50", "gdn_rule_roofline",
-    "gdn_state_move_share"])
+    "gdn_state_move_share", "chunk_experts_ms_p50"])
 def test_a_linear_mixer_metric_reads_nothing_where_there_is_no_trace(
         tmp_path, name):
     """An untraced run, or the parent's program: None, not a made-up number."""

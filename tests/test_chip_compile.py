@@ -41,6 +41,7 @@ from bloombee_tpu.ops.pallas.flash_attention import (  # noqa: E402
 )
 from bloombee_tpu.ops.pallas.grouped_experts import (  # noqa: E402
     grouped_experts,
+    tiled_experts,
 )
 from bloombee_tpu.ops.pallas.paged_attention import (  # noqa: E402
     paged_chunk_attention,
@@ -183,6 +184,23 @@ def _kernel_cases():
             grouped_experts,
             [((rows, d), bf16), ((slots,), i32), ((), i32),
              ((slots, rows), jnp.float32), ((layers_experts, d, i), bf16),
+             ((layers_experts, d, i), bf16), ((layers_experts, i, d), bf16)],
+        )
+    # a 512-row chunk's chosen pairs in row tiles: top-10 over the 128 held
+    # experts of Qwen3-Next (one [D, I] block an expert, 8 layers' stacks)
+    # and top-6 over DeepSeek-V2's 20 held (the intermediate dim in tiles, 4
+    # layers' stacks); a 1024-row fused pack at DeepSeek-V2's widths, where
+    # the rows and their sums take the most VMEM
+    for name, (layers_experts, d, i, rows, top_k, slots) in {
+        "tiled_experts_qwen3next": (8 * 128, 2048, 512, 512, 10, 128),
+        "tiled_experts_deepseekv2": (4 * 20, 5120, 1536, 512, 6, 20),
+        "tiled_experts_deepseekv2_r1024": (4 * 20, 5120, 1536, 1024, 6, 20),
+    }.items():
+        cases[name] = (
+            tiled_experts,
+            [((rows, d), bf16), ((slots,), i32), ((), i32), ((slots,), i32),
+             ((slots,), i32), ((rows * top_k,), i32),
+             ((rows * top_k,), jnp.float32), ((layers_experts, d, i), bf16),
              ((layers_experts, d, i), bf16), ((layers_experts, i, d), bf16)],
         )
     return cases
@@ -521,6 +539,10 @@ def test_cell_span_step_copies_no_parameter(v5e, cell, program):
     assert "%stacked_params__q_proj" in text  # the names the check reads
     copied = re.findall(r"copy\([^)\n]*%(stacked_params\w+)", text)
     assert not copied, copied
+    if program != "decode":
+        # 128 and 256 rows lie under the ridge or run no kernel: the dense
+        # form, as before the tiled one existed (ops/moe.py `expert_form`)
+        assert not re.search(r"jit\((tiled|grouped)_experts\)", text)
     if program in bounds:
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < bounds[program] * 1e6, temp
@@ -644,8 +666,12 @@ def test_deepseek_v2_span_step_compiles_and_copies_no_parameter(v5e, program):
     assert not re.findall(r"copy\([^)\n]*%arena_[kv]", text)
     kernels = text.count("tpu_custom_call")
     # decode: the paged latent kernel in both runs + the grouped experts;
-    # chunk: the flash form in both runs; fused: both kernels in both runs
-    assert kernels == {"decode": 3, "chunk": 2, "fused": 4}[program]
+    # chunk: the flash form in both runs + the tiled experts (512 rows lie
+    # above the ridge); fused: both kernels in both runs + the tiled experts
+    assert kernels == {"decode": 3, "chunk": 3, "fused": 5}[program]
+    assert ("jit(tiled_experts)" in text) == (program != "decode")
+    assert ("jit(grouped_experts)" in text) == (program == "decode")
+    # no temporary the size of a layer's held stack (944 MB)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < {"decode": 20, "chunk": 300, "fused": 600}[program] * 1e6
 
@@ -750,7 +776,8 @@ def test_qwen3_next_span_step_compiles_and_copies_no_parameter(v5e, program):
     else:
         b, t, case = {
             "decode": (4, 1, dict(use_paged=True)),
-            "chunk": (1, 512, dict(use_flash=True, t_real=512)),
+            "chunk": (1, 512, dict(
+                use_flash=True, t_real=512, expert_kernels=True)),
             "tail": (1, 8, dict(use_paged=True, t_real=5)),
         }[program]
         plan_len = b * t + b * pages + b * t + b + layers + b
@@ -768,6 +795,11 @@ def test_qwen3_next_span_step_compiles_and_copies_no_parameter(v5e, program):
     # second-minor dimension, as Falcon-H1's: `gdn_state_move_share` reads it)
     assert not re.findall(r"copy\([^)\n]*%(arena_[kv]|state__ssm)", text)
     assert "tpu_custom_call" in text
+    # the chunk and the pack lie above the ridge: their chosen pairs in row
+    # tiles; a decode group and the 8-row tail list their chosen experts
+    tiled = program in ("chunk", "fused")
+    assert ("jit(tiled_experts)" in text) == tiled
+    assert ("jit(grouped_experts)" in text) == (not tiled)
     temp = compiled.memory_analysis().temp_size_in_bytes
     # decode and tail: one state-arena-sized and one slab-sized buffer (the
     # scatters' results, as Falcon-H1's decode program); ONE stack for the

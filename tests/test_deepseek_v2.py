@@ -732,7 +732,8 @@ def test_client_logits_through_a_block_server_match_the_family_file(
     assert info["kernel_fallbacks"] == 0 and info["prefill_chunks"] >= 3
     assert info["experts_held"] == [4, 4]
     assert info["latent_bytes_per_token"] == (16 + 128) * 2
-    assert set(info["moe"]) == {"grouped_dispatches", "dense_dispatches"}
+    assert set(info["moe"]) == {
+        "grouped_dispatches", "tiled_dispatches", "dense_dispatches"}
     reach = info["moe_reach"]
     assert reach["steps"] >= 9 and reach["rows"] == 43
     assert len(reach["held_hit_last"]) == LAYERS - 1

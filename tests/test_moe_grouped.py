@@ -1,6 +1,8 @@
 """Experts by index (ops/moe.py): a decode group's rows go to their top-k
-experts through the grouped Pallas kernel (interpret mode on the CPU), the
-chunk keeps the dense einsum, and a family without experts reaches neither.
+experts through the grouped Pallas kernel (interpret mode on the CPU), a
+chunk above the ridge computes only its chosen pairs through the tiled one,
+a chunk under it keeps the dense einsum, and a family without experts
+reaches none of them.
 
 The plain copy of the mathematics is the benchmark's family file
 (cellbench/families/qwen3_moe.py `_moe`: softmax over all experts, top-k,
@@ -24,10 +26,11 @@ from bloombee_tpu.kv.cache_manager import CacheManager  # noqa: E402
 from bloombee_tpu.models.checkpoint import load_span_params  # noqa: E402
 from bloombee_tpu.ops import moe  # noqa: E402
 from bloombee_tpu.ops.moe import (  # noqa: E402
+    TILED_MIN_ROWS,
+    expert_form,
     moe_mlp,
     route_topk,
     router_topk_weights,
-    takes_grouped_form,
 )
 from bloombee_tpu.runtime import step as step_module  # noqa: E402
 from bloombee_tpu.runtime.executor import SpanExecutor  # noqa: E402
@@ -82,13 +85,52 @@ def test_grouped_equals_dense_equals_the_family_file(form, rows):
     np.testing.assert_allclose(grouped, want, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("rows,top_k,num_experts,grouped", [
-    (1, 8, 128, True), (8, 8, 128, True), (15, 8, 128, True),
-    (16, 8, 128, False), (128, 8, 128, False),
-    (3, 2, 8, True), (4, 2, 8, False),
+# every row bucket the five cells' servers compile (a decode group of 1-4
+# rows, an 8-row tail, the prefill chunk, the warm-up's fused pack), by the
+# router of the cell's configuration; Mistral and Falcon-H1 have no expert
+CELL_BUCKETS = {
+    # cell: (top_k, experts the router scores, chunk rows, fused rows)
+    "qwen3moe-longdoc": (8, 128, 128, 256),
+    "deepseekv2-longctx": (6, 160, 512, 1024),
+    "qwen3next-longctx": (10, 512, 512, 1024),
+}
+
+
+def _rule_cases():
+    cases = []
+    for cell, (top_k, experts, chunk, fused) in CELL_BUCKETS.items():
+        for rows in (1, 2, 4, 8):
+            cases.append((cell, rows, top_k, experts, True, "list"))
+        cases.append((cell, 1, top_k, experts, False, "dense"))
+        over = "tiled" if chunk >= TILED_MIN_ROWS else "dense"
+        cases.append((cell, chunk, top_k, experts, True, over))
+        cases.append((cell, chunk, top_k, experts, False, "dense"))
+        cases.append((cell, fused, top_k, experts, True, "tiled"))
+    # where the list form ends: the rows can hit every expert; Mixtral
+    cases += [
+        ("qwen3moe-longdoc", 15, 8, 128, True, "list"),
+        ("qwen3moe-longdoc", 16, 8, 128, True, "dense"),
+        ("deepseekv2-longctx", 26, 6, 160, True, "list"),
+        ("deepseekv2-longctx", 32, 6, 160, True, "dense"),
+        ("qwen3next-longctx", 64, 10, 512, True, "dense"),
+        ("mixtral", 3, 2, 8, True, "list"),
+        ("mixtral", 4, 2, 8, True, "dense"),
+        ("mixtral", 255, 2, 8, True, "dense"),
+        ("mixtral", 256, 2, 8, True, "tiled"),
+        ("mixtral", 512, 2, 8, False, "dense"),
+    ]
+    return cases
+
+
+@pytest.mark.parametrize("cell,rows,top_k,num_experts,kernels,form", [
+    pytest.param(*c, id=f"{c[0]}-r{c[1]}-{'kernels' if c[4] else 'off'}")
+    for c in _rule_cases()
 ])
-def test_the_form_follows_the_rows(rows, top_k, num_experts, grouped):
-    assert takes_grouped_form(rows, top_k, num_experts) is grouped
+def test_the_form_follows_the_rows(
+        cell, rows, top_k, num_experts, kernels, form):
+    """The one rule: the shape the program is compiled for and whether
+    kernels may run in it; how many experts the server holds is no part."""
+    assert expert_form(rows, top_k, num_experts, kernels) == form
 
 
 def test_padding_rows_add_no_expert_and_come_out_zero():
@@ -203,6 +245,131 @@ def test_the_intermediate_dim_is_tiled_when_a_block_would_not_fit(monkeypatch):
     np.testing.assert_allclose(grouped, dense, rtol=1e-5, atol=1e-5)
 
 
+# ------------------------------------------------------- the tiled form
+def _pair_loop(x, idx, weights, w, held=None):
+    """The mathematics with nothing shared: one (row, expert) pair at a
+    time, float64."""
+    x = np.asarray(x, np.float64)[0]
+    gate, up, down = (np.asarray(w[k], np.float64)
+                      for k in ("gate", "up", "down"))
+    out = np.zeros_like(x)
+    first, count = held or (0, gate.shape[0])
+    for r in range(x.shape[0]):
+        for e, p in zip(np.asarray(idx)[0, r], np.asarray(weights)[0, r]):
+            if first <= e < first + count:
+                g, u = x[r] @ gate[e - first], x[r] @ up[e - first]
+                out[r] += p * ((g / (1 + np.exp(-g)) * u) @ down[e - first])
+    return out[None]
+
+
+def _tiled_case(name):
+    """name -> (x [1, R, D], weights, top_k, moe_mlp's routing keywords)."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    f = lambda *s: jnp.asarray(rng.standard_normal(s) * 0.2, jnp.float32)  # noqa: E731
+    experts, top_k, held, kw, rows = 16, 2, None, dict(
+        pre_softmax=True, norm_topk=True), 300
+    if name == "qwen3next-top10-of-512-held-128":
+        experts, top_k, held, rows = 512, 10, (256, 128), 256
+    elif name == "deepseekv2-group-limited-i-tiled":
+        experts, top_k, held, rows = 32, 6, (8, 8), 256
+        kw = dict(groups=8, topk_groups=3, route_scale=16.0)
+    elif name == "held-most-pairs-elsewhere":
+        experts, top_k, held = 64, 4, (60, 4)
+    held_n = held[1] if held else experts
+    w = dict(router=f(D, experts), gate=f(held_n, D, I), up=f(held_n, D, I),
+             down=f(held_n, I, D))
+    x = f(1, rows, D)
+    if name == "all-rows-to-one-expert":
+        # expert 3 wins every row by far: its run is 300 pairs, five row
+        # tiles inside one grid step; the second choice still differs
+        w["router"] = w["router"].at[:, 3].set(0.0)
+        x = x.at[..., 0].set(9.0)
+        w["router"] = w["router"].at[0, 3].set(5.0)
+    elif name == "experts-with-no-row":
+        x = x.at[..., 0].set(9.0)
+        w["router"] = w["router"].at[0, ::2].set(-5.0)  # the even ones lose
+    elif name == "padding-rows":
+        x = x.at[0, 200:].set(0.0).at[0, 17].set(0.0)
+    return x, w, top_k, dict(kw, held=held)
+
+
+TILED_CASES = [
+    "uniform", "all-rows-to-one-expert", "experts-with-no-row",
+    "padding-rows", "held-most-pairs-elsewhere",
+    "deepseekv2-group-limited-i-tiled", "qwen3next-top10-of-512-held-128",
+]
+
+
+@pytest.mark.parametrize("name", TILED_CASES)
+def test_tiled_equals_dense_equals_a_loop_over_the_pairs(name, monkeypatch):
+    from bloombee_tpu.ops.pallas import grouped_experts as kernel
+
+    if "i-tiled" in name:  # 128 columns of the intermediate dim in 4 tiles
+        monkeypatch.setattr(kernel, "_i_tile", lambda d, i, itemsize: 32)
+    x, w, top_k, kw = _tiled_case(name)
+    rows = x.shape[1]
+    assert moe.expert_form(rows, top_k, w["router"].shape[1], True) == "tiled"
+    call = lambda **more: moe_mlp(  # noqa: E731
+        x, w["router"], w["gate"], w["up"], w["down"], top_k, **kw, **more)
+    with jax.default_matmul_precision("highest"):
+        dense = call()
+        tiled = call(expert_base=jnp.int32(0), interpret=True)
+        idx, weights = route_topk(
+            x @ w["router"], top_k,
+            **{("scale" if k == "route_scale" else k): v
+               for k, v in kw.items() if k != "held"})
+    np.testing.assert_allclose(tiled, dense, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        tiled, _pair_loop(x, idx, weights, w, kw["held"]),
+        rtol=1e-4, atol=1e-5)
+    assert float(np.abs(np.asarray(dense)).max()) > 1e-2  # no empty test
+    # the plan: every live pair held here in exactly one expert's run
+    held = kw["held"]
+    held_n = held[1] if held else w["router"].shape[1]
+    here = None
+    if held is not None:
+        idx, weights, here = moe._held_local(idx, weights, held)
+        here = here[0]
+    slot_expert, n, start, count, src, _ = (
+        np.asarray(a) for a in moe._tiled_plan(
+            x[0], idx[0], weights[0], held_n, here))
+    live = np.asarray(jnp.any(x[0] != 0, -1))[:, None] & (
+        np.ones_like(idx[0], bool) if here is None else np.asarray(here))
+    assert count.sum() == live.sum() and (count[n:] == 0).all()
+    assert list(slot_expert[:n]) == sorted(set(np.asarray(idx[0])[live]))
+    for e, s0, c in zip(slot_expert[:n], start[:n], count[:n]):
+        rows_of_e = np.nonzero((np.asarray(idx[0]) == e) & live)[0]
+        assert sorted(src[s0:s0 + c]) == sorted(rows_of_e)
+    if name == "all-rows-to-one-expert":
+        assert count.max() == rows > 4 * kernel.ROW_TILE
+    if name == "experts-with-no-row":
+        assert 0 < n <= held_n // 2 and not (slot_expert[:n] % 2 == 0).any()
+    if name == "padding-rows":
+        assert not np.asarray(tiled)[0, 200:].any()
+        assert not np.asarray(tiled)[0, 17].any()
+        assert not set(src[: count.sum()]) & ({17} | set(range(200, rows)))
+    if name == "held-most-pairs-elsewhere":
+        assert live.sum() < 0.2 * live.size
+        assert (np.asarray(tiled)[0][~live.any(-1)] == 0).all()
+
+
+def test_tiled_walks_several_layers_stacks_by_base():
+    """The stacks of three layers flat over (layer, expert), as
+    `lift_expert_stacks` hands them: layer l's experts start at l * E."""
+    x, w, top_k, kw = _tiled_case("uniform")
+    layers = [_tiled_case("uniform")[1], _tiled_case("padding-rows")[1], w]
+    flat = {k: jnp.concatenate([l[k] for l in layers])
+            for k in ("gate", "up", "down")}
+    with jax.default_matmul_precision("highest"):
+        for at, layer in enumerate(layers):
+            want = moe_mlp(x, layer["router"], layer["gate"], layer["up"],
+                           layer["down"], top_k, **kw)
+            got = moe_mlp(x, layer["router"], flat["gate"], flat["up"],
+                          flat["down"], top_k, **kw, interpret=True,
+                          expert_base=jnp.int32(16 * at))
+            np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
 # ------------------------------------------------ through the span step
 def _config(model_type, **more):
     return {
@@ -292,12 +459,12 @@ def test_decode_groups_take_the_grouped_form_and_match_the_family_file(
                 m.allocate(1, 64) as hc:
             for h, hidden in ((ha, a), (hb, b), (hc, c)):
                 ex.prefill_chunk(h, hidden[:, :20])
-            assert ex.moe_dispatches == {"grouped": 0, "dense": 3}
+            assert ex.moe_dispatches == {"grouped": 0, "tiled": 0, "dense": 3}
             got["solo"] = ex.decode(ha, a[:, 20:21], commit=False)
             two, _ = ex.decode_group([ha, hb], [a[:, 21:22], b[:, 20:21]])
             three, _ = ex.decode_group(
                 [ha, hb, hc], [a[:, 22:23], b[:, 21:22], c[:, 20:21]])
-            assert ex.moe_dispatches == {"grouped": 3, "dense": 3}
+            assert ex.moe_dispatches == {"grouped": 3, "tiled": 0, "dense": 3}
             assert ex.kernel_fallbacks == 0
         return got, two, three
 
@@ -315,6 +482,35 @@ def test_decode_groups_take_the_grouped_form_and_match_the_family_file(
     close(three[2], want["c"][20])
 
 
+def test_a_chunk_above_the_ridge_takes_the_tiled_form(
+        spans, kernels_on, monkeypatch):
+    """A 256-row chunk that attends through flash: no paged kernel in its
+    program, yet its experts take the tiled form, equal to the family file's
+    forward; the 32-row tail after it (32 * 2 >= 16 experts) stays dense.
+    Without the flash switch the same chunk attends densely and so do its
+    experts."""
+    path, config, span = spans["qwen3_moe"]
+    a = _hidden(4, 288)
+
+    async def run():
+        ex = _executor(span)
+        async with ex.manager.allocate(1, 320) as ha:
+            first = ex.prefill_chunk(ha, a[:, :256])
+            tail = ex.prefill_chunk(ha, a[:, 256:288])
+        return ex, np.concatenate([np.asarray(first), np.asarray(tail)], 1)
+
+    with jax.default_matmul_precision("highest"):
+        plain, want_dense = asyncio.run(run())
+        monkeypatch.setenv("BBTPU_FLASH_INTERPRET", "1")
+        ex, got = asyncio.run(run())
+    assert plain.moe_dispatches == {"grouped": 0, "tiled": 0, "dense": 2}
+    assert ex.moe_dispatches == {"grouped": 0, "tiled": 1, "dense": 1}
+    assert ex.attn_dispatches["flash"] == 1 and ex.kernel_fallbacks == 0
+    want = _reference_hidden(path, config, a[0])
+    np.testing.assert_allclose(got[0], want, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(want_dense[0], want, rtol=2e-4, atol=2e-5)
+
+
 def test_without_the_kernel_switch_every_step_stays_dense(spans):
     """Off the TPU with no interpret switch no Pallas kernel may run: the
     decode step computes the dense form, as under a mesh."""
@@ -326,7 +522,7 @@ def test_without_the_kernel_switch_every_step_stays_dense(spans):
         async with ex.manager.allocate(1, 64) as ha:
             ex.prefill_chunk(ha, a[:, :20])
             out = ex.decode(ha, a[:, 20:21], commit=False)
-            assert ex.moe_dispatches == {"grouped": 0, "dense": 2}
+            assert ex.moe_dispatches == {"grouped": 0, "tiled": 0, "dense": 2}
         return out
 
     with jax.default_matmul_precision("highest"):
@@ -341,10 +537,13 @@ def test_quantised_stacks_stay_dense(spans, kernels_on):
 
     params, spec = spans["qwen3_moe"][2]
     quantised = quantize_span_params(params, 8)
-    assert step_module.experts_grouped(spec, params, 2, True)
-    assert not step_module.experts_grouped(spec, quantised, 2, True)
-    assert not step_module.experts_grouped(spec, params, 2, False)
-    assert not step_module.experts_grouped(spec, params, 8, True)
+    assert step_module.experts_form(spec, params, 2, True) == "list"
+    assert step_module.experts_form(spec, params, 512, True) == "tiled"
+    for stacks, rows, kernels in (
+        (quantised, 2, True), (quantised, 512, True), (params, 2, False),
+        (params, 512, False), (params, 8, True),
+    ):
+        assert step_module.experts_form(spec, stacks, rows, kernels) == "dense"
     assert step_module.lift_expert_stacks(spec, quantised, 2, True) == (
         quantised, None)
 
@@ -362,9 +561,9 @@ def test_a_family_without_experts_never_reaches_the_expert_code(
         raise AssertionError("the expert path was reached")
 
     for module, name in (
-        (moe, "moe_mlp"), (moe, "route_topk"), (moe, "takes_grouped_form"),
-        (layer_body, "moe_mlp"), (step_module, "takes_grouped_form"),
-        (kernel, "grouped_experts"),
+        (moe, "moe_mlp"), (moe, "route_topk"), (moe, "expert_form"),
+        (layer_body, "moe_mlp"), (step_module, "expert_form"),
+        (kernel, "grouped_experts"), (kernel, "tiled_experts"),
     ):
         monkeypatch.setattr(module, name, unreachable)
     a, b = _hidden(1, 24), _hidden(2, 24)
@@ -382,7 +581,7 @@ def test_a_family_without_experts_never_reaches_the_expert_code(
             assert ex.attn_dispatches["paged"] >= 2
             return dict(ex.moe_dispatches)
 
-    assert asyncio.run(run()) == {"grouped": 0, "dense": 0}
+    assert asyncio.run(run()) == {"grouped": 0, "tiled": 0, "dense": 0}
 
 
 async def _served(path, uid, steps):
@@ -424,6 +623,7 @@ def test_rpc_info_counts_the_forms_only_for_a_family_with_experts(
     before = asyncio.run(_served(spans["qwen3_moe"][0], "tiny-moe", 0))
     info = asyncio.run(_served(spans["qwen3_moe"][0], "tiny-moe", 3))
     moved = {k: info["moe"][k] - before["moe"][k] for k in info["moe"]}
-    assert moved == {"grouped_dispatches": 3, "dense_dispatches": 0}
+    assert moved == {
+        "grouped_dispatches": 3, "tiled_dispatches": 0, "dense_dispatches": 0}
     assert info["kernel_fallbacks"] == 0
     assert "moe" not in asyncio.run(_served(spans["mistral"][0], "tiny-m", 2))
