@@ -344,6 +344,15 @@ def main(argv=None):
                             f"{name}={v['total_ms'] / v['n']:.3f}msx{v['n']}"
                             for name, v in sorted(spans.items()) if v["n"]
                         )
+                    # a session's turn, reply to reply, by the step's
+                    # class: the mean of each leg on the server's clock
+                    # (wire/turn.py; negative_wire says a stamp is wrong)
+                    for cls, rec in sorted((probe.get("turn") or {}).items()):
+                        if rec.get("n"):
+                            line += f"  turn.{cls} n={rec['n']} " + " ".join(
+                                f"{k[:-3]}={v / rec['n']:.3f}ms"
+                                for k, v in rec.items() if k.endswith("_ms")
+                            ) + f" negative_wire={rec['negative_wire']}"
                     # compile-artifact counters (BBTPU_ARTIFACT_DIR runs):
                     # fallback_compiles > 0 means a server abandoned
                     # pre-installed artifacts and paid local compiles;

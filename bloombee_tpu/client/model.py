@@ -26,6 +26,7 @@ from bloombee_tpu.models.head import embed_impl, norm_head_impl
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.ops import rms_norm
 from bloombee_tpu.ops.norms import layer_norm
+from bloombee_tpu.utils import jitwatch
 
 _embed = functools.partial(
     jax.jit, static_argnames=("embedding_multiplier", "has_embed_norm", "eps")
@@ -197,6 +198,22 @@ class DistributedModelForCausalLM:
             )
         )
 
+    def _embed_for(self, session, input_ids) -> np.ndarray:
+        """`embed`, its time told to the session as the turn's `c_embed`
+        (`wire/turn.py`; the span `bbtpu.client.embed` with the witness on)."""
+        with jitwatch.stopwatch("bbtpu.client.embed") as sw:
+            hidden = self.embed(input_ids)
+        session.note_embed_ms(sw.ms)
+        return hidden
+
+    def _logits_for(self, session, hidden) -> np.ndarray:
+        """`logits`, its time told to the session as the turn's `c_head`
+        (the span `bbtpu.client.head` with the witness on)."""
+        with jitwatch.stopwatch("bbtpu.client.head") as sw:
+            logits = self.logits(hidden)
+        session.note_head_ms(sw.ms)
+        return logits
+
     def inference_session(
         self, max_length: int, batch_size: int = 1,
         microbatch: int | str | None = None,
@@ -262,13 +279,13 @@ class DistributedModelForCausalLM:
                 return await self._generate_server_decode(
                     session, input_ids, max_length, eos_token_id
                 )
-            hidden = self.embed(input_ids)
+            hidden = self._embed_for(session, input_ids)
             # the head reads the prompt's last position only
             out = await session.step(hidden, ids=input_ids, reply_tail=1)
             ids = input_ids
             finished = np.zeros((b,), dtype=bool)
             for _ in range(max_new_tokens):
-                logits = self.logits(out[:, -1:])[:, 0]  # [B, V]
+                logits = self._logits_for(session, out[:, -1:])[:, 0]  # [B, V]
                 next_ids = self._select(
                     logits, do_sample, temperature, top_p, rng
                 )
@@ -281,7 +298,8 @@ class DistributedModelForCausalLM:
                 if ids.shape[1] >= max_length:
                     break
                 out = await session.step(
-                    self.embed(next_ids[:, None]), ids=next_ids[:, None]
+                    self._embed_for(session, next_ids[:, None]),
+                    ids=next_ids[:, None],
                 )
             return ids
         finally:
@@ -319,9 +337,9 @@ class DistributedModelForCausalLM:
                 c = min(c, int(server_max))
             return 1 << (c.bit_length() - 1)
         head_dtype = str(self.params["lm_head"].dtype)
-        hidden = self.embed(input_ids)
+        hidden = self._embed_for(session, input_ids)
         out = await session.step(hidden, ids=input_ids, reply_tail=1)
-        logits = self.logits(out[:, -1:])[:, 0]
+        logits = self._logits_for(session, out[:, -1:])[:, 0]
         finished = np.zeros((b,), dtype=bool)
         next_ids, finished = self._greedy_next(logits, finished, eos_token_id)
         ids = np.concatenate([input_ids, next_ids[:, None]], axis=1)
@@ -387,9 +405,10 @@ class DistributedModelForCausalLM:
             eos_token_id is not None and finished.all()
         ):
             out = await session.step(
-                self.embed(next_ids[:, None]), ids=next_ids[:, None]
+                self._embed_for(session, next_ids[:, None]),
+                ids=next_ids[:, None],
             )
-            logits = self.logits(out[:, -1:])[:, 0]
+            logits = self._logits_for(session, out[:, -1:])[:, 0]
             next_ids, finished = self._greedy_next(
                 logits, finished, eos_token_id
             )

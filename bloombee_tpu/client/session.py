@@ -30,6 +30,7 @@ from bloombee_tpu.client.sequence_manager import (
 )
 from bloombee_tpu.swarm.data import RemoteSpanInfo
 from bloombee_tpu.utils import env, ledger
+from bloombee_tpu.wire import turn
 from bloombee_tpu.wire.rpc import (
     Connection,
     OverloadedError,
@@ -254,17 +255,35 @@ class InferenceSession:
         self._warned_no_embed = False
         # per-step timing rows (the client half of the reference's
         # [TIMING_TABLE], handler.py:1276-1605): one entry per step with
-        # per-span compute ms and the end-to-end wall ms
+        # per-span compute ms and `total_ms`, which ends when the last reply
+        # is in `out` and counts the server's ingest, queue wait and reply
+        # beside the wire. In `step` it starts AFTER the request was cast,
+        # encoded and written (the turn's `c_send`, wire/turn.py, is in no
+        # `total_ms`); in the tree step and in `decode_n` it starts BEFORE
+        # the send
         self.timings: list[dict] = []
+        # this client's parts of a turn, told to span 0 in every request
+        self._legs = turn.ClientLegs()
 
     # ------------------------------------------------------------- lifecycle
+    def note_head_ms(self, ms: float) -> None:
+        """The caller's final norm + LM head since the last reply (the turn's
+        `c_head`); unreported time is `c_other`."""
+        self._legs.note_head_ms(ms)
+
+    def note_embed_ms(self, ms: float) -> None:
+        """The caller's embedding of the next step's ids (`c_embed`)."""
+        self._legs.note_embed_ms(ms)
+
     async def __aenter__(self) -> "InferenceSession":
+        self._legs.entering()
         await self.manager.update(force=True)
         route = self.manager.make_sequence(
             cache_tokens_needed=self.batch_size * self.max_length,
             relay=not self.use_push,
         )
         self._spans = [await self._open_span(s) for s in route]
+        self._legs.opened()
         self._init_repl()
         return self
 
@@ -954,6 +973,7 @@ class InferenceSession:
         depths_list = np.asarray(depths).tolist()
         keep = None
 
+        send_ns = turn.now_ns()
         t_start = time.perf_counter()
         compute_ms = []
         for i, span_sess in enumerate(self._spans):
@@ -972,10 +992,13 @@ class InferenceSession:
             if i == 0 and prune is not None:
                 meta["prune"] = prune
             try:
+                if i == 0:
+                    self._legs.ride(meta, span_sess.stream, send_ns)
                 await span_sess.stream.send(meta, [chunk, mask_u8])
                 item = await asyncio.wait_for(
                     span_sess.stream.recv(), self.step_timeout
                 )
+                self._legs.replied(span_sess.stream.read_ns)
             except OverloadedError as e:
                 self._note_shed_exc(e, span_sess.span.peer_id)
                 raise
@@ -1030,6 +1053,7 @@ class InferenceSession:
         # stream must reuse this exact id (the server's at-most-once dedup
         # keys on it) and the same prefix_skip (same suffix bytes)
         self._last_sent = (step_id, prefix_skip)
+        send_ns = turn.now_ns()
         meta_base = {
             "step": step_id,
             "commit": commit,
@@ -1126,6 +1150,9 @@ class InferenceSession:
                 meta["route"] = route
             if tail and (route or len(self._spans) == 1):
                 meta["reply_tail"] = tail
+            if k == 0:
+                # the turn's entry rides the step's first frame
+                self._legs.ride(meta, self._spans[0].stream, send_ns)
             await self._spans[0].stream.send(
                 meta, [hidden_w[lo - row_base:hi - row_base]] + extra
             )
@@ -1158,6 +1185,7 @@ class InferenceSession:
                 if item is None:
                     self.manager.ban_peer(span_sess.span.peer_id)
                     raise RpcError(f"span {i} closed mid-session")
+                self._legs.replied(span_sess.stream.read_ns)
                 resp_meta, resp_tensors = item
                 _raise_if_session_lost(resp_meta)
                 self._raise_if_shed(resp_meta, span_sess.span.peer_id)
@@ -1411,6 +1439,7 @@ class InferenceSession:
         span_sess = self._spans[0]
         t_start = time.perf_counter()
         try:
+            self._legs.ride(meta, span_sess.stream, turn.now_ns())
             await span_sess.stream.send(meta, [ids])
             # one RPC covers n whole-model steps; chained routes also pay
             # per-token server-to-server hops and may hit cold XLA
@@ -1430,6 +1459,7 @@ class InferenceSession:
         if item is None:
             self.manager.ban_peer(span_sess.span.peer_id)
             raise RpcError("span closed mid-session")
+        self._legs.replied(span_sess.stream.read_ns)
         resp_meta, resp_tensors = item
         _raise_if_session_lost(resp_meta)
         self._raise_if_shed(resp_meta, span_sess.span.peer_id)
@@ -1653,6 +1683,7 @@ class InferenceSession:
 
     async def _recover_once(self) -> None:
         """One rebuild + replay attempt (see _recover)."""
+        self._legs.entering()
         await self.manager.update(force=True)
         route = self.manager.make_sequence(
             cache_tokens_needed=self.batch_size * self.max_length,
@@ -1677,6 +1708,7 @@ class InferenceSession:
                 await sp.close()
             raise
         self._spans = spans
+        self._legs.opened()
         # the rebuilt chain may have different span boundaries and replays
         # skip relay recording: spans > 0 lose auditability (span 0 keeps
         # it — its input always re-embeds from the id history)

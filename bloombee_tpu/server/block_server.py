@@ -51,6 +51,7 @@ from bloombee_tpu.server.compute_queue import (
 )
 from bloombee_tpu.swarm.data import ServerInfo, ServerState
 from bloombee_tpu.utils import clock, env, jitwatch, ledger, lockwatch
+from bloombee_tpu.wire import turn
 from bloombee_tpu.wire.flow import FlowLimiter
 from bloombee_tpu.wire.rpc import (
     Connection,
@@ -308,6 +309,9 @@ class _Session:
         self.sum_dispatch_ms = 0.0
         self.sum_fetch_ms = 0.0
         self.opened_at = 0.0
+        # this session's stamps of the turn's legs; a session a server
+        # opens sums into the server's account (_rpc_inference)
+        self.turns = turn.ServerTurns(turn.TurnAccount(), session_id, None)
         # last pruned tree step's (hidden, tokens, parents) for online
         # pruner-head training when its accept arrives
         self.last_tree = None
@@ -773,6 +777,8 @@ class BlockServer(PromotionLoopMixin):
         # failover; not tree, speculative or ragged-replay steps): the bytes
         # sent against the bytes of every row of their outputs (`reply_tail`)
         self.prefill_reply = {"n": 0, "reply_bytes": 0, "full_bytes": 0}
+        # every session's turns, leg by leg (wire/turn.py): rpc_info["turn"]
+        self.turn_account = turn.TurnAccount()
         self.peers = _PeerPool()
         # server-side multi-step decode (decode_n): needs the checkpoint's
         # embed/norm/lm_head trio; lazy-loaded from model_dir on first use
@@ -2043,6 +2049,9 @@ class BlockServer(PromotionLoopMixin):
             # compute worker's wall time by cause
             "host_spans": jitwatch.host_spans(),
             "worker": self.compute.worker_stats_ms(),
+            # a session's turn, reply to reply, leg by leg on this server's
+            # clock (sums by the step's class; kept with the witness off)
+            "turn": self.turn_account.stats_ms(),
             # overload observability: shed/admit counters, retry_after
             # histogram, and per-client fair-share debt (None with the
             # admission controller off; the live load snapshot itself rides
@@ -2592,6 +2601,9 @@ class BlockServer(PromotionLoopMixin):
             session.has_state = self.spec.recurrent is not None
             session.opened_at = clock.monotonic()
             session.last_step_at = session.opened_at
+            session.turns = turn.ServerTurns(
+                self.turn_account, session_id, stream.open_read_ns
+            )
             self._sessions[session_id] = session
             self._drain_pending_pushes(session)
             cur_stream = stream
@@ -2646,13 +2658,20 @@ class BlockServer(PromotionLoopMixin):
                         pass
                 if session.n_steps:
                     wall = clock.monotonic() - session.opened_at
+                    turns = session.turns
+                    per_turn = 1e3 * max(turns.n, 1)
                     logger.info(
                         "[TIMING_TABLE] session=%s steps=%d tokens=%d "
                         "mean_dispatch_ms=%.2f mean_fetch_ms=%.2f "
+                        "mean_away_ms=%.2f mean_ingest_ms=%.2f "
+                        "mean_reply_ms=%.2f "
                         "wall_s=%.2f steps_per_s=%.2f",
                         session.id, session.n_steps, session.sum_tokens,
                         session.sum_dispatch_ms / session.n_steps,
                         session.sum_fetch_ms / session.n_steps,
+                        turns.sum_away_us / per_turn,
+                        turns.sum_ingest_us / per_turn,
+                        turns.sum_reply_us / per_turn,
                         wall, session.n_steps / max(wall, 1e-9),
                     )
 
@@ -2893,10 +2912,13 @@ class BlockServer(PromotionLoopMixin):
                     item = stream_next.result()
                     if item is None:
                         break  # client closed the session
+                    # the item's own note: no other recv() is armed yet
+                    session.turns.noted(stream.read_ns)
                     await self._handle_item(session, stream, *item)
                     stream_next = asyncio.ensure_future(stream.recv())
                 if push_next in done:
                     meta, tensors = push_next.result()
+                    session.turns.noted(None)  # another server's push
                     await self._handle_item(session, stream, meta, tensors)
                     push_next = asyncio.ensure_future(session.push_inbox.get())
         finally:
@@ -2933,7 +2955,15 @@ class BlockServer(PromotionLoopMixin):
         self, session: _Session, stream: Stream, meta: dict, tensors: list
     ) -> None:
         if int(meta.get("mb_of", 1)) <= 1:
-            await self._run_step(session, stream, meta, tensors)
+            try:
+                await self._run_step(session, stream, meta, tensors)
+            finally:
+                # a turn that got no reply (an expired deadline, a lost
+                # session, a send that raised) is forgotten, and whatever
+                # the step sent last (a typed error, a decline, a retry
+                # answered from the record) is where the next `away` starts
+                session.turns.dropped(meta.get("step"))
+                session.turns.replied(meta.get("step"), stream.write_ns)
             return
         task = asyncio.create_task(
             self._run_step_logged(session, stream, meta, tensors)
@@ -3051,6 +3081,7 @@ class BlockServer(PromotionLoopMixin):
             self.steps_deduped += 1
             ledger.recovery("server.resume_dedup")
             resp, out_t = cached
+            # (the turn was counted when the step was served)
             await stream.send({**resp, "deduped": True}, out_t)
             return
         # client deadline budget: "deadline_s" is RELATIVE remaining time
@@ -3225,6 +3256,13 @@ class BlockServer(PromotionLoopMixin):
             or tree_mask is not None
         ):
             tail = None
+        turns, step = session.turns, meta.get("step")
+        turns.arrive(
+            step,
+            "prefill" if hidden.shape[1] > 1 and tree_mask is None
+            else "decode",
+            meta.get(turn.META_KEY), frames=int(meta.get("mb_of", 1)),
+        )
         try:
             if self._batchable(commit, hidden, tree_mask, depths,
                                commit_lens, meta.get("prefix_skip")):
@@ -3296,8 +3334,10 @@ class BlockServer(PromotionLoopMixin):
                     )
         except DeadlineExpired:
             self._note_deadline_expired(meta, "while queued")
+            turns.dropped(step)
             return
         except Exception as e:
+            turns.dropped(step)
             if await self._maybe_reply_session_lost(
                 session, stream, meta, e
             ):
@@ -3307,6 +3347,7 @@ class BlockServer(PromotionLoopMixin):
         out, t_fetch_ms = await asyncio.to_thread(
             self._fetch_timed, out_dev, session, tail=tail
         )
+        turns.fetched(step)
         if self.liar_p > 0 and self._liar_rng.random() < self.liar_p:
             # TEST HOOK: lie BEFORE the digest/serialization below, so the
             # reply is a well-formed frame whose digest matches the lie —
@@ -3314,12 +3355,9 @@ class BlockServer(PromotionLoopMixin):
             # catch it (exactly the threat model they exist for)
             out = self._liar_perturb(out)
             self.liar_steps += 1
-        t_compute_ms = t_dispatch_ms + t_fetch_ms
-        timing_meta = {
-            "t_compute_ms": t_compute_ms,
-            "t_dispatch_ms": t_dispatch_ms,
-            "t_fetch_ms": t_fetch_ms,
-        }
+        # the reply says what the client's timing row keeps: the step's
+        # compute (dispatch + fetch)
+        timing_meta = {"t_compute_ms": t_dispatch_ms + t_fetch_ms}
         session.n_steps += 1
         session.sum_tokens += int(hidden.shape[0]) * int(hidden.shape[1])
         session.sum_dispatch_ms += t_dispatch_ms
@@ -3388,6 +3426,7 @@ class BlockServer(PromotionLoopMixin):
                 remaining = deadline - clock.monotonic()
                 if remaining <= 0:
                     self._note_deadline_expired(meta, "before forwarding")
+                    turns.dropped(step)
                     return
                 push_meta["deadline_s"] = remaining
             push_tensors = [out]  # executor output is already wire dtype
@@ -3434,6 +3473,7 @@ class BlockServer(PromotionLoopMixin):
             # so this reply is the step's only at-most-once fence
             self._record_reply(session, meta, resp, [out])
             await stream.send(resp, [out])
+        turns.replied(step, stream.write_ns)
 
     async def _run_decode_n(
         self, session: _Session, stream: Stream, meta: dict, tensors: list
@@ -3513,6 +3553,9 @@ class BlockServer(PromotionLoopMixin):
                 }
             )
             return
+        session.turns.arrive(
+            meta.get("step"), "decode", meta.get(turn.META_KEY)
+        )
         if route or self._decode_n_ineligible(session) is not None:
             await self._run_decode_n_stepped(
                 session, stream, meta, tensors, route
@@ -3523,7 +3566,8 @@ class BlockServer(PromotionLoopMixin):
     def _fetch_timed(self, out_dev, session: _Session, fetch=None,
                      tail: int | None = None):
         """Runs on a fetch thread: the d2h wait as the span `bbtpu.fetch`,
-        whose duration is the wire's t_fetch_ms. `step` is the session's
+        whose duration is the fetch's part of the wire's t_compute_ms (and
+        the [TIMING_TABLE]'s mean_fetch_ms). `step` is the session's
         count of steps served so far, the same on every span of one wire
         step. `tail`: only the last `tail` positions of each sequence are
         copied to the host: cut on the device, here and not on the compute
@@ -3588,6 +3632,7 @@ class BlockServer(PromotionLoopMixin):
             self._fetch_timed, out_dev, session,
             lambda dev: np.asarray(dev, dtype=np.int32),
         )
+        session.turns.fetched(meta.get("step"))
         session.n_steps += n
         session.sum_tokens += int(ids.shape[0]) * n
         session.sum_dispatch_ms += t_dispatch_ms
@@ -3599,14 +3644,13 @@ class BlockServer(PromotionLoopMixin):
         resp = {
             "step": meta.get("step"),
             "t_compute_ms": t_dispatch_ms + t_fetch_ms,
-            "t_dispatch_ms": t_dispatch_ms,
-            "t_fetch_ms": t_fetch_ms,
         }
         # the fused loop committed n KV slots per row: record before
         # delivery so a post-resume retry resends these exact tokens
         # instead of decoding (and committing) n more
         self._record_reply(session, meta, resp, [toks])
         await stream.send(resp, [toks])
+        session.turns.replied(meta.get("step"), stream.write_ns)
 
     async def _run_decode_n_stepped(
         self, session: _Session, stream: Stream, meta: dict, tensors: list,
@@ -3753,6 +3797,7 @@ class BlockServer(PromotionLoopMixin):
             # the ragged KV no longer blocks a later park
             session.kv_dirty = False
             return
+        session.turns.fetched(meta.get("step"))
         total_ms = (clock.perf_counter() - t_start) * 1000.0
         session.n_steps += n
         session.sum_tokens += b * n
@@ -3763,11 +3808,10 @@ class BlockServer(PromotionLoopMixin):
         resp = {
             "step": meta.get("step"),
             "t_compute_ms": total_ms,
-            "t_dispatch_ms": t_dispatch_sum,
-            "t_fetch_ms": max(total_ms - t_dispatch_sum, 0.0),
         }
         self._record_reply(session, meta, resp, [toks])
         await stream.send(resp, [toks])
+        session.turns.replied(meta.get("step"), stream.write_ns)
 
     async def _push_hop(
         self, route: list, chain: dict, step, head_dtype, out,

@@ -36,9 +36,10 @@ read back through :func:`host_spans` / ``rpc_info["host_spans"]``. A
 :func:`region` IS a span, named ``bbtpu.jit.<function>`` with the bucket
 as an id. Spans are synchronous: never hold one across an ``await`` (an
 event-loop thread interleaves coroutines and would break the nesting).
-:func:`stopwatch` is the span whose duration its caller reads (the
-wire's ``t_dispatch_ms`` / ``t_fetch_ms``): it reads the clock with the
-witness off too, and is named and counted only with it on.
+:func:`stopwatch` is the span whose duration its caller reads (the two
+parts of the wire's ``t_compute_ms``, the client's ``c_head`` /
+``c_embed``): it reads the clock with the witness off too, and is named
+and counted only with it on.
 
 At interpreter exit the witness appends one JSON line to
 ``BBTPU_JITWATCH_REPORT`` (append mode, multi-process merge — same

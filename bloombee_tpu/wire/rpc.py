@@ -39,7 +39,7 @@ import msgpack
 import numpy as np
 
 from bloombee_tpu.utils import clock, env, lockwatch
-from bloombee_tpu.wire import faults, tensor_codec
+from bloombee_tpu.wire import faults, tensor_codec, turn
 from bloombee_tpu.wire.pipeline import CodecPipeline, decode_now
 
 logger = logging.getLogger(__name__)
@@ -132,11 +132,26 @@ class Stream:
     reference: handler.py:798-1257)."""
 
     def __init__(self, conn: "Connection", stream_id: int, meta: dict,
-                 tensors: list[np.ndarray]):
+                 tensors: list[np.ndarray], read_ns: int | None = None):
         self.conn = conn
         self.id = stream_id
         self.open_meta = meta
         self.open_tensors = tensors
+        # where _read_loop read the last byte of the frame that opened this
+        # stream / of the item recv() handed out last (turn.now_ns; a local
+        # note, never sent): a turn's `ingest` and `c_recv` start there
+        self.open_read_ns = read_ns
+        self.read_ns = read_ns
+        # a sender may set this for its NEXT frame: called once that frame's
+        # tensors are encoded, just before its header is packed, the last
+        # moment `meta` can say how long the frame took to make
+        # (wire/turn.py, `c_send`)
+        self.before_write = None
+        # where the last frame sent here was handed to the socket (read just
+        # before the write, so that no wait for this thread's turn after the
+        # system call can put it later than the peer's read of the frame):
+        # a turn's `reply` ends there and its `away` begins
+        self.write_ns: int | None = None
         self._inbox: asyncio.Queue = asyncio.Queue()
         self._closed_local = False
         self._closed_remote = False
@@ -145,8 +160,10 @@ class Stream:
                    compression: bool = True) -> None:
         if self._closed_local:
             raise RpcError("stream closed")
-        await self.conn._send_payload(
-            {"t": "sitem", "id": self.id, "meta": meta}, tensors, compression
+        before_write, self.before_write = self.before_write, None
+        self.write_ns = await self.conn._send_payload(
+            {"t": "sitem", "id": self.id, "meta": meta}, tensors, compression,
+            before_write,
         )
 
     async def recv(self) -> tuple[dict, list[np.ndarray]] | None:
@@ -159,7 +176,8 @@ class Stream:
             return None
         if isinstance(item, Exception):
             raise item
-        return item
+        meta, tensors, self.read_ns = item
+        return meta, tensors
 
     async def close(self, meta: dict | None = None) -> None:
         """Half-close: tells the peer no more items will be sent."""
@@ -376,8 +394,10 @@ class Connection:
         header: dict,
         tensors: list[np.ndarray] | None,
         compression: bool = True,
-    ) -> None:
-        """Encode + send one tensor-carrying frame. Serialization runs in
+        before_write=None,
+    ) -> int | None:
+        """Encode + send one tensor-carrying frame; returns `_send`'s note
+        of where it was handed to the socket. Serialization runs in
         the codec pool under a FlowLimiter slot: a peer that drains slowly
         inflates this connection's send times, the AIMD law shrinks its
         concurrency, and waiters park on the limiter instead of stacking
@@ -387,9 +407,14 @@ class Connection:
                 tensors or [], compression, self._allowed_codecs()
             )
             header["tm"] = tm
-            await self._send(header, blobs)
+            if before_write is not None:
+                before_write()
+            return await self._send(header, blobs)
 
-    async def _send(self, header: dict, blobs: list) -> None:
+    async def _send(self, header: dict, blobs: list) -> int | None:
+        """Write one frame; returns `turn.now_ns()` as read just before the
+        bytes were handed to the socket (None for a frame a fault plan
+        dropped)."""
         if not self._advertised:
             # negotiation advert rides the first outgoing frame(s): older
             # peers ignore unknown header keys, newer peers switch their
@@ -404,7 +429,7 @@ class Connection:
             # frame below is encoded from the mutated pair), or ask for a
             # silent discard (injected partition blackhole)
             if await self.fault_plan.on_send(self, header, blobs) == "drop":
-                return
+                return None
         bufs = _frame_buffers(header, blobs)
         async with self._send_lock:
             if self.writer.transport.is_closing():
@@ -412,9 +437,11 @@ class Connection:
                 # writelines() once closed (write() only logged and
                 # drain() then raised this); say what happened instead
                 raise ConnectionResetError("connection lost")
+            wrote_ns = turn.now_ns()
             self.writer.writelines(bufs)
             await self.writer.drain()
         self._advertised = True
+        return wrote_ns
 
     async def _keepalive_loop(self) -> None:
         """Ping on idle, declare the peer dead when silent too long.
@@ -453,7 +480,11 @@ class Connection:
                 if total > MAX_FRAME:
                     raise RpcError(f"frame too large: {total}")
                 body = await self.reader.readexactly(total - 4)
+                # the frame's last byte is read: the note rides with the
+                # frame to whoever recv()s it (Stream.read_ns)
+                read_ns = turn.now_ns()
                 header = msgpack.unpackb(body[:hlen], raw=False)
+                header["read_ns"] = read_ns
                 # zero-copy receive: slice the frame body into memoryviews
                 # so raw-codec payloads reach np.frombuffer uncopied
                 mv = memoryview(body)
@@ -606,13 +637,16 @@ class Connection:
         elif t == "push":
             self._spawn(self._handle_push(header, payload))
         elif t == "sopen":
-            stream = Stream(self, rid, header.get("meta", {}), payload)
+            stream = Stream(self, rid, header.get("meta", {}), payload,
+                            header.get("read_ns"))
             self._streams[rid] = stream
             self._spawn(self._handle_stream(header["m"], stream))
         elif t == "sitem":
             stream = self._streams.get(rid)
             if stream is not None:
-                stream._push_inbound((header.get("meta", {}), payload))
+                stream._push_inbound(
+                    (header.get("meta", {}), payload, header.get("read_ns"))
+                )
         elif t == "send":
             stream = self._streams.get(rid)
             if stream is not None:

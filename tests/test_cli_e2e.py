@@ -158,6 +158,9 @@ def test_cli_registry_server_client_health(tmp_path):
         assert "tx_wire_bytes=" in probe.stdout, probe.stdout
         assert "pipeline=on" in probe.stdout, probe.stdout
         assert "rx_jobs=" in probe.stdout, probe.stdout
+        # a session's turn, leg by leg, by the step's class
+        assert "turn.prefill n=1 away=" in probe.stdout, probe.stdout
+        assert "turn.decode n=" in probe.stdout, probe.stdout
     finally:
         for p in procs:
             p.terminate()

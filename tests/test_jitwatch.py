@@ -547,7 +547,7 @@ def test_e2e_served_steps_grow_host_spans_by_the_right_counts(
     chunks, then one decode step, read through rpc_info["host_spans"] and
     rpc_info["worker"] before and after each. Every boundary of the
     served step shows up the right number of times, and the wire's
-    t_dispatch_ms / t_fetch_ms are the spans' own durations."""
+    t_compute_ms is the dispatch and fetch spans' own durations."""
     import jax.numpy as jnp
 
     from bloombee_tpu.client.config import ClientConfig
