@@ -353,6 +353,16 @@ def main(argv=None):
                                 f"{k[:-3]}={v / rec['n']:.3f}ms"
                                 for k, v in rec.items() if k.endswith("_ms")
                             ) + f" negative_wire={rec['negative_wire']}"
+                    # prefills that reached this span in parts along the
+                    # sequence, and how long their chunk loops stood
+                    # waiting for rows still on their way (near 0: the
+                    # device sets the pace; large: the upload still does)
+                    parts = probe.get("prefill_parts") or {}
+                    if parts.get("steps"):
+                        line += "  prefill_parts " + " ".join(
+                            f"{k}={parts.get(k)}"
+                            for k in ("steps", "parts", "wait_ms")
+                        )
                     # compile-artifact counters (BBTPU_ARTIFACT_DIR runs):
                     # fallback_compiles > 0 means a server abandoned
                     # pre-installed artifacts and paid local compiles;

@@ -65,6 +65,22 @@ env.declare(
     "as before",
 )
 
+# A part of a prompt on the client -> first span hop: the rows that make about
+# this many bytes in the span's wire dtype, in whole chunks of the span's
+# advertised chunk length and at least one (`_part_rows`). The span starts on
+# a part while the client casts and encodes the next, so a part is what a
+# prompt still uploads with nothing under it, and a frame's fixed cost is paid
+# once a part: PERF.md section 6 (PR 43) has the readings it came from.
+_PART_BYTES = 8 << 20
+
+
+def _part_rows(chunk: int, row_bytes: int) -> int:
+    """Rows of one part of a step whose position (every sequence of the
+    batch) takes `row_bytes` on the wire, for a span that plans its
+    prefills in chunks of `chunk` rows."""
+    return max(1, _PART_BYTES // (chunk * row_bytes)) * chunk
+
+
 # the first no-embed_fn decode_n session in the process warns loudly; later
 # sessions demote to DEBUG (a client spawning many raw sessions would
 # otherwise repeat the identical warning once per session)
@@ -1076,8 +1092,8 @@ class InferenceSession:
             meta_base["prefix_skip"] = int(prefix_skip)
         # ship hidden in the first span's advertised wire dtype (bf16 for
         # bf16-compute servers: half the bytes on the latency-critical hop)
-        wire_dt = dtype_for_name(self._spans[0].span.server_info.wire_dtype)
-        hidden_w = hidden.astype(wire_dt)
+        first_info = self._spans[0].span.server_info
+        wire_dt = np.dtype(dtype_for_name(first_info.wire_dtype))
         extra = [tree_mask.astype(np.uint8)] if tree_mask is not None else []
         # only the span that answers the caller cuts its reply: in push mode
         # the field rides the route to it, in relay mode the client tells it
@@ -1128,6 +1144,23 @@ class InferenceSession:
                 for k in range(mb)
             ]
 
+        # a plain committing prefill to a first span that says which chunk
+        # length it plans with goes as ONE step in PARTS along the sequence
+        # (one id, one deadline, one reply): each part is cast, encoded and
+        # written in turn, and the span computes the chunks of one while the
+        # next is made here. Anything else, and a prompt of one part's
+        # size, is one part: the frame it always was
+        t = hidden.shape[1]
+        per = max(1, t)
+        chunk = first_info.prefill_chunk
+        if (
+            isinstance(chunk, int) and chunk > 0 and mb == 1
+            and tree_mask is None and commit and commit_lens is None
+            and rows is None
+        ):
+            per = _part_rows(chunk, b * hidden.shape[2] * wire_dt.itemsize)
+        cuts = [(r, min(r + per, t)) for r in range(0, max(1, t), per)]
+
         route = []
         if self.use_push and len(self._spans) > 1:
             route = [
@@ -1150,12 +1183,20 @@ class InferenceSession:
                 meta["route"] = route
             if tail and (route or len(self._spans) == 1):
                 meta["reply_tail"] = tail
-            if k == 0:
-                # the turn's entry rides the step's first frame
-                self._legs.ride(meta, self._spans[0].stream, send_ns)
-            await self._spans[0].stream.send(
-                meta, [hidden_w[lo - row_base:hi - row_base]] + extra
-            )
+            if len(cuts) > 1:
+                meta["parts"] = [len(cuts), t]
+            for part, (r0, r1) in enumerate(cuts):
+                if part:
+                    meta = {"step": step_id, "part": part}
+                piece = hidden[lo - row_base:hi - row_base, r0:r1].astype(
+                    wire_dt
+                )
+                if k == 0 and part == 0:
+                    # the turn's entry rides the step's first frame: its
+                    # `c_send` is the first part's, the rest lie under
+                    # the span's `served`
+                    self._legs.ride(meta, self._spans[0].stream, send_ns)
+                await self._spans[0].stream.send(meta, [piece] + extra)
 
         t_start = time.perf_counter()
         out = np.zeros(hidden.shape, dtype=np.float32)

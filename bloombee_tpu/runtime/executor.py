@@ -94,23 +94,32 @@ def next_pow2(n: int, floor: int = 1) -> int:
     return v
 
 
-def plan_prefill_chunks(
-    t: int, budget: int, cap: int | None = None
-) -> list[tuple[int, int]]:
-    """Split a t-token prefill into [start, end) chunk spans of at most
-    `budget` tokens each (pow2-rounded so every full chunk compiles into
-    the SAME (batch, tokens) bucket; `cap` bounds the rounded budget, e.g.
-    at max_chunk_tokens). budget<=0 or t<=budget -> one whole-prompt span,
-    i.e. chunking disabled."""
-    if budget <= 0 or t <= budget:
-        return [(0, t)]
+def prefill_chunk_len(budget: int, cap: int | None = None) -> int:
+    """The chunk length a `budget`-token prefill budget plans with: the
+    budget rounded DOWN to a power of two (never above the operator's
+    budget; `cap` bounds it further, e.g. at max_chunk_tokens), so that
+    every full chunk compiles into the SAME (batch, tokens) bucket. 0 where
+    the budget disables chunking."""
+    if budget <= 0:
+        return 0
     b = next_pow2(int(budget))
     if b > budget:
-        b //= 2  # round DOWN: never exceed the operator's token budget
+        b //= 2
     if cap is not None:
         while b > cap:
             b //= 2
-    b = max(1, b)
+    return max(1, b)
+
+
+def plan_prefill_chunks(
+    t: int, budget: int, cap: int | None = None
+) -> list[tuple[int, int]]:
+    """Split a t-token prefill into [start, end) chunk spans of
+    `prefill_chunk_len(budget, cap)` tokens each. budget<=0 or t<=budget ->
+    one whole-prompt span, i.e. chunking disabled."""
+    if budget <= 0 or t <= budget:
+        return [(0, t)]
+    b = prefill_chunk_len(budget, cap)
     if t <= b:
         return [(0, t)]
     return [(s, min(s + b, t)) for s in range(0, t, b)]

@@ -39,7 +39,11 @@ from bloombee_tpu.kv.cache_manager import (
     state_slots_for,
 )
 from bloombee_tpu.models.spec import ModelSpec
-from bloombee_tpu.runtime.executor import SpanExecutor, plan_prefill_chunks
+from bloombee_tpu.runtime.executor import (
+    SpanExecutor,
+    plan_prefill_chunks,
+    prefill_chunk_len,
+)
 from bloombee_tpu.server import artifacts
 from bloombee_tpu.server.promotion import PromotionLoopMixin
 from bloombee_tpu.server.compute_queue import (
@@ -208,6 +212,91 @@ def _tail_rows(out, n: int):
         if have >= n:
             break
     return kept[0] if len(kept) == 1 else kept[::-1]
+
+
+class _StepRows:
+    """A prefill step's rows along the sequence axis: whole in the step's
+    one frame, or still arriving in PARTS. A client whose first span
+    advertises the chunk length it plans with (`ServerInfo.prefill_chunk`)
+    sends a plain committing prefill as ONE step in several frames cut at
+    multiples of it: the first carries the step's meta and `parts: [count,
+    rows of the whole step]`, each later one `{"step", "part"}` and its
+    rows. `take` hands the chunk loop one chunk's rows and reads the next
+    part off the session's stream where they are not there yet (no other
+    `recv` is armed while a step is handled; the connection reads and
+    decodes ahead), so the device starts on the first part while the client
+    is still encoding the rest. A whole frame is the same with one part."""
+
+    def __init__(self, first: np.ndarray, meta: dict, stream: Stream,
+                 deadline: float | None, session_id: str, counts: dict):
+        self._parts = [(0, first)]  # (first row, [B, t, D]) in order
+        self._have = self.tokens = int(first.shape[1])
+        self.batch = int(first.shape[0])
+        self._stream, self._deadline = stream, deadline
+        self._session, self._step = session_id, meta.get("step")
+        self._counts = counts
+        parts = meta.get("parts")
+        if parts is None:
+            return
+        if (
+            not isinstance(parts, (list, tuple)) or len(parts) != 2
+            or not all(isinstance(x, int) and not isinstance(x, bool)
+                       for x in parts)
+            or parts[0] < 2 or parts[1] <= self._have
+        ):
+            raise ValueError(
+                f"parts must be [count >= 2, rows beyond the first part's "
+                f"{self._have}], got {parts!r}"
+            )
+        self.tokens = parts[1]
+        counts["steps"] += 1
+        counts["parts"] += 1
+
+    async def take(self, s: int, e: int) -> np.ndarray:
+        """Rows [s, e) of every sequence, in the sender's dtype."""
+        while self._have < e:
+            await self._next_part()
+        held = [
+            rows[:, max(s - at, 0):e - at] for at, rows in self._parts
+            if at < e and at + rows.shape[1] > s
+        ]
+        return held[0] if len(held) == 1 else np.concatenate(held, axis=1)
+
+    async def _next_part(self) -> None:
+        t0 = turn.now_ns()
+        try:
+            item = await asyncio.wait_for(
+                self._stream.recv(), clock.remaining(self._deadline)
+            )
+        except asyncio.TimeoutError:
+            raise DeadlineExpired(
+                "client deadline expired between the parts of a prefill"
+            ) from None
+        if item is None:
+            raise RpcError("stream closed between the parts of a prefill")
+        meta, tensors = item
+        no, first = len(self._parts), self._parts[0][1]
+        rows = np.asarray(tensors[0]) if tensors else None
+        if (
+            meta.get("step") != self._step or meta.get("part") != no
+            or rows is None or rows.dtype != first.dtype or rows.ndim != 3
+            or (rows.shape[0], rows.shape[2]) != (first.shape[0],
+                                                  first.shape[2])
+            or not 0 < rows.shape[1] <= self.tokens - self._have
+        ):
+            raise RpcError(
+                f"expected part {no} of step {self._step} "
+                f"({self.tokens - self._have} rows to come), got {meta}"
+            )
+        self._parts.append((self._have, rows))
+        self._have += int(rows.shape[1])
+        wait_us = max(0, turn.now_ns() - t0) // 1000
+        self._counts["parts"] += 1
+        self._counts["wait_us"] += wait_us
+        with jitwatch.span("bbtpu.prefill.part", session=self._session,
+                           step=self._step, part=no,
+                           rows=int(rows.shape[1]), wait_us=wait_us):
+            pass
 
 
 class _ChainError(RuntimeError):
@@ -777,6 +866,11 @@ class BlockServer(PromotionLoopMixin):
         # failover; not tree, speculative or ragged-replay steps): the bytes
         # sent against the bytes of every row of their outputs (`reply_tail`)
         self.prefill_reply = {"n": 0, "reply_bytes": 0, "full_bytes": 0}
+        # prefill steps that came in more than one part (`_StepRows`), their
+        # parts, and how long their chunk loops stood waiting for a part
+        # that had not arrived: near 0 says the device sets the pace, large
+        # says the upload still does. rpc_info["prefill_parts"]
+        self.prefill_parts = {"steps": 0, "parts": 0, "wait_us": 0}
         # every session's turns, leg by leg (wire/turn.py): rpc_info["turn"]
         self.turn_account = turn.TurnAccount()
         self.peers = _PeerPool()
@@ -1725,6 +1819,14 @@ class BlockServer(PromotionLoopMixin):
             # advertising a store; a draining server is about to leave
             # and must not attract artifact fetch traffic
             artifacts=self.artifact_store is not None and not self._draining,
+            # a client may cut a long prompt into parts at multiples of the
+            # chunk length `_chunk_spans` plans with (0: it never chunks)
+            prefill_chunk=(
+                0 if self.executor.sp_mesh is not None
+                else prefill_chunk_len(
+                    self._chunk_budget(), self.executor.max_chunk_tokens
+                )
+            ),
         )
 
     async def _announce(self, state: ServerState) -> None:
@@ -2052,6 +2154,13 @@ class BlockServer(PromotionLoopMixin):
             # a session's turn, reply to reply, leg by leg on this server's
             # clock (sums by the step's class; kept with the witness off)
             "turn": self.turn_account.stats_ms(),
+            # prefills that came in parts along the sequence axis, and the
+            # wait of their chunk loops for rows that had not arrived
+            "prefill_parts": {
+                "steps": self.prefill_parts["steps"],
+                "parts": self.prefill_parts["parts"],
+                "wait_ms": round(self.prefill_parts["wait_us"] / 1e3, 3),
+            },
             # overload observability: shed/admit counters, retry_after
             # histogram, and per-client fair-share debt (None with the
             # admission controller off; the live load snapshot itself rides
@@ -3058,6 +3167,11 @@ class BlockServer(PromotionLoopMixin):
     async def _run_step(
         self, session: _Session, stream: Stream, meta: dict, tensors: list
     ) -> None:
+        if meta.get("part"):
+            # a later part of a prefill in parts that no chunk loop took
+            # (`_StepRows`): its step was answered from the record, refused,
+            # dropped or failed at its first part, and said so there
+            return
         if meta.get("chain") is not None:
             # pushed hop of a chained decode_n (never from the client
             # stream): errors go back to the coordinator via chain_error,
@@ -3184,6 +3298,10 @@ class BlockServer(PromotionLoopMixin):
         # keep the sender's dtype (bf16 on the production wire); the executor
         # casts to compute dtype on device
         hidden = np.asarray(tensors[0])
+        # the step's rows along the sequence: all in `hidden`, or `hidden`
+        # the first part of them and the rest still on its way
+        seq = _StepRows(hidden, meta, stream, deadline, session.id,
+                        self.prefill_parts)
         tree_mask = None
         depths = None
         # kind-aware group_hint gauge: tree steps mark the session
@@ -3194,7 +3312,7 @@ class BlockServer(PromotionLoopMixin):
         # its prompt, so they leave the optimistic default alone.
         if meta.get("tree"):
             session.speculating = True
-        elif hidden.shape[1] == 1:
+        elif seq.tokens == 1:
             session.speculating = False
         if meta.get("tree"):
             tree_mask = np.asarray(tensors[1], dtype=bool)
@@ -3259,13 +3377,30 @@ class BlockServer(PromotionLoopMixin):
         turns, step = session.turns, meta.get("step")
         turns.arrive(
             step,
-            "prefill" if hidden.shape[1] > 1 and tree_mask is None
+            "prefill" if seq.tokens > 1 and tree_mask is None
             else "decode",
             meta.get(turn.META_KEY), frames=int(meta.get("mb_of", 1)),
         )
         try:
-            if self._batchable(commit, hidden, tree_mask, depths,
-                               commit_lens, meta.get("prefix_skip")):
+            spans = self._chunk_spans(
+                seq.tokens, commit, tree_mask, commit_lens
+            )
+            if spans is None:
+                # one task: a whole frame's rows are there, parts that this
+                # server plans no chunks for are waited for
+                hidden = await seq.take(0, seq.tokens)
+            if spans is not None:
+                # stall-free scheduling: the prefill becomes a stream of
+                # resumable chunk tasks re-entering the priority queue, so
+                # other sessions' decode steps run between chunks instead
+                # of stalling behind the whole prompt; a chunk runs as soon
+                # as the part that holds its rows has arrived
+                out_dev, t_dispatch_ms = await self._run_chunked_prefill(
+                    session, handle, seq, spans, deadline,
+                    meta.get("prefix_skip"),
+                )
+            elif self._batchable(commit, hidden, tree_mask, depths,
+                                 commit_lens, meta.get("prefix_skip")):
                 # continuous batching: compatible single-token decode steps
                 # of OTHER sessions that are queued right now (or arrive
                 # within BBTPU_BATCH_WINDOW_MS) share one merged span
@@ -3304,34 +3439,21 @@ class BlockServer(PromotionLoopMixin):
                     task_class="decode",
                 )
             else:
-                spans = self._chunk_spans(
-                    hidden, commit, tree_mask, commit_lens
+                is_prefill = seq.tokens > 1 and tree_mask is None
+                out_dev, t_dispatch_ms = await self.compute.submit(
+                    PRIORITY_INFERENCE,
+                    self._compute_step,
+                    session,
+                    handle,
+                    hidden,
+                    commit,
+                    tree_mask,
+                    depths,
+                    commit_lens,
+                    meta.get("prefix_skip"),
+                    deadline=deadline,
+                    task_class="prefill" if is_prefill else "decode",
                 )
-                if spans is not None:
-                    # stall-free scheduling: the prefill becomes a stream
-                    # of resumable chunk tasks re-entering the priority
-                    # queue, so other sessions' decode steps run between
-                    # chunks instead of stalling behind the whole prompt
-                    out_dev, t_dispatch_ms = await self._run_chunked_prefill(
-                        session, handle, hidden, spans, deadline,
-                        meta.get("prefix_skip"),
-                    )
-                else:
-                    is_prefill = hidden.shape[1] > 1 and tree_mask is None
-                    out_dev, t_dispatch_ms = await self.compute.submit(
-                        PRIORITY_INFERENCE,
-                        self._compute_step,
-                        session,
-                        handle,
-                        hidden,
-                        commit,
-                        tree_mask,
-                        depths,
-                        commit_lens,
-                        meta.get("prefix_skip"),
-                        deadline=deadline,
-                        task_class="prefill" if is_prefill else "decode",
-                    )
         except DeadlineExpired:
             self._note_deadline_expired(meta, "while queued")
             turns.dropped(step)
@@ -3359,19 +3481,20 @@ class BlockServer(PromotionLoopMixin):
         # compute (dispatch + fetch)
         timing_meta = {"t_compute_ms": t_dispatch_ms + t_fetch_ms}
         session.n_steps += 1
-        session.sum_tokens += int(hidden.shape[0]) * int(hidden.shape[1])
+        session.sum_tokens += seq.batch * seq.tokens
         session.sum_dispatch_ms += t_dispatch_ms
         session.sum_fetch_ms += t_fetch_ms
         if self.admission is not None:
             # fair-share accounting: charge processed tokens (batch x seq)
             # to the owning client so heavy clients accrue debt
             self.admission.note_tokens(
-                session.client_id,
-                int(hidden.shape[0]) * int(hidden.shape[1]),
+                session.client_id, seq.batch * seq.tokens
             )
         dump_dir = env.get("BBTPU_DUMP_ACTIVATIONS")
         if dump_dir:
-            self._dump_activations(dump_dir, session, meta, hidden, out)
+            self._dump_activations(
+                dump_dir, session, meta, await seq.take(0, seq.tokens), out
+            )
 
         # mid-chain tree pruning: score this span's output with the MidLMHead
         # and return only surviving rows + their indices (reference
@@ -3451,13 +3574,13 @@ class BlockServer(PromotionLoopMixin):
                 if meta.get(key) is not None:
                     resp[key] = meta[key]
             if (
-                hidden.shape[1] > 1 and tree_mask is None and commit
+                seq.tokens > 1 and tree_mask is None and commit
                 and commit_lens is None
             ):
                 self.prefill_reply["n"] += 1
                 self.prefill_reply["reply_bytes"] += int(out.nbytes)
                 self.prefill_reply["full_bytes"] += (
-                    int(out.nbytes) // int(out.shape[1]) * int(hidden.shape[1])
+                    int(out.nbytes) // int(out.shape[1]) * seq.tokens
                 )
             if keep is not None:
                 resp["keep"] = keep.tolist()
@@ -4120,7 +4243,7 @@ class BlockServer(PromotionLoopMixin):
         return int(env.get("BBTPU_PREFILL_CHUNK"))
 
     def _chunk_spans(
-        self, hidden, commit, tree_mask, commit_lens
+        self, tokens: int, commit, tree_mask, commit_lens
     ) -> list[tuple[int, int]] | None:
         """[start, end) chunk spans for this step, or None when the step
         must stay one monolithic compute task. Only plain committing
@@ -4133,7 +4256,7 @@ class BlockServer(PromotionLoopMixin):
         budget = self._chunk_budget()
         if (
             budget <= 0
-            or hidden.shape[1] <= 1
+            or tokens <= 1
             or tree_mask is not None
             or not commit
             or commit_lens is not None
@@ -4141,12 +4264,12 @@ class BlockServer(PromotionLoopMixin):
         ):
             return None
         spans = plan_prefill_chunks(
-            hidden.shape[1], budget, cap=self.executor.max_chunk_tokens
+            tokens, budget, cap=self.executor.max_chunk_tokens
         )
         return spans if len(spans) > 1 else None
 
     async def _run_chunked_prefill(
-        self, session: _Session, handle, hidden, spans, deadline,
+        self, session: _Session, handle, seq: _StepRows, spans, deadline,
         prefix_skip=None,
     ):
         """Drive one prefill as a stream of resumable chunk tasks. Each
@@ -4154,7 +4277,11 @@ class BlockServer(PromotionLoopMixin):
         priority (fresh streams yield to queued decode steps; an old
         stream reaches decode priority, so it cannot starve), with the
         client deadline re-checked both between chunks (here) and at each
-        chunk's queue pop (the submit's deadline=).
+        chunk's queue pop (the submit's deadline=). A chunk is submitted
+        once `seq` holds its rows: a prompt sent in parts is planned on its
+        whole length and computed as its parts arrive, and a stream that
+        closes or a deadline that passes between parts aborts the step
+        like a failed chunk.
 
         Chunks write their KV speculatively; the LAST chunk's compute-
         thread slot commits the whole prompt (same pattern as the batched
@@ -4174,11 +4301,12 @@ class BlockServer(PromotionLoopMixin):
                     raise DeadlineExpired(
                         "client deadline expired between prefill chunks"
                     )
+                hidden = await seq.take(s, e)
                 # a chunk of SEVERAL sequences with recurrent state (or a
                 # latent cache) goes alone: a ragged pack runs the mixer's
                 # (latent attention's) chunk form on one
                 if self.mixed_batch and not (
-                    self.executor.one_chunk_a_pack and hidden.shape[0] > 1
+                    self.executor.one_chunk_a_pack and seq.batch > 1
                 ):
                     # batchable chunk: the worker may fuse this chunk with
                     # queued decode steps — and, with --spec-batch also
@@ -4189,7 +4317,7 @@ class BlockServer(PromotionLoopMixin):
                         ("chunkm", session.layers, session.adapter,
                          str(hidden.dtype), e - s),
                         _ChunkMember(
-                            session, handle, hidden[:, s:e],
+                            session, handle, hidden,
                             idx == 0, idx == last, prefix_skip,
                         ),
                         self._compute_ragged_group,
@@ -4202,7 +4330,7 @@ class BlockServer(PromotionLoopMixin):
                         self._compute_prefill_chunk,
                         session,
                         handle,
-                        hidden[:, s:e],
+                        hidden,
                         idx == 0,
                         idx == last,
                         prefix_skip,
@@ -4212,7 +4340,7 @@ class BlockServer(PromotionLoopMixin):
                 outs.append(out)
                 total_ms += dt_ms
                 self.prefill_chunks += 1
-                self.prefill_chunk_tokens += int(hidden.shape[0]) * (e - s)
+                self.prefill_chunk_tokens += seq.batch * (e - s)
         except BaseException:
             # free the partial prefill's speculative pages — a session
             # holding pages for a prompt nobody will finish is a leak

@@ -86,6 +86,13 @@ class ServerInfo:
     # peers drop the field via from_wire filtering and default False, so
     # mixed swarms simply never trade artifacts.
     artifacts: bool = False
+    # the chunk length this server plans a long prefill with
+    # (`executor.prefill_chunk_len`), advertised only where it chunks at
+    # all: a client may then send a plain committing prefill as ONE step in
+    # several PARTS cut at multiples of it, and the server's chunk loop
+    # starts on the first part while the rest is still on its way. 0 (and
+    # every old peer, via from_wire filtering): the whole prompt in one frame.
+    prefill_chunk: int = 0
 
     def to_wire(self) -> dict:
         d = dataclasses.asdict(self)

@@ -21,7 +21,11 @@ with `wire` (both directions together) the one unknown. The legs, in order:
     c_embed   embedding of the next step's ids
     c_send    the request's tensors cast and encoded, up to the moment its
               header is packed. A frame cannot carry how long its own write
-              took: the write overlaps the server's read and is `wire`
+              took: the write overlaps the server's read and is `wire`. Of a
+              prompt sent in PARTS (`client/session.py` `_part_rows`) the
+              entry rides the first, so `c_send`, `wire` and `ingest` are
+              the first part's; the later parts are made, sent and read
+              while the span computes, under its `served`
     open      first turn only: `__aenter__` -> the stream's open frame
               written. It lies BEFORE the first turn's `away`, which the
               server starts where it read that open frame
