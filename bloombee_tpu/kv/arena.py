@@ -12,6 +12,20 @@ Layout: per layer, a flat slot dimension of num_pages * page_size tokens:
     k, v: [L, num_pages * page_size, n_kv_heads, head_dim]
 Slot ids come from the host-side PagedKVTable (page * page_size + offset).
 
+The layout follows from the shape (`folds`). The paged kernels read a slab as
+pages of [page_size * n_kv_heads, head_dim] rows. The device tiles the two
+minor dimensions in (8, 128) tiles, so [S_tot, n_kv_heads, head_dim] holds
+those rows byte for byte only where a token's heads fill whole tiles
+(n_kv_heads a multiple of 8) or are one tile column's part (head_dim 128 and
+n_kv_heads 1, 2, 4): Mistral 8 x 128, Qwen3-30B-A3B 4 x 128, Falcon-H1
+8 x 128. Anywhere else (Qwen3-Next 2 x 256, phi4flash 10 x 128) the view is
+a re-lay-out of the WHOLE slab in front of every kernel, so the arena is
+stored FOLDED, a layer's slab [S_tot * n_kv_heads, head_dim], token-major
+then head: exactly those rows. Slots and pages stay TOKEN slots and pages
+everywhere; a slab of two dimensions IS a folded one, and `arena_write`,
+`gather_pages`, `heads_view` and `slot_rows` see it in their input. An int4
+arena, a latent page and an arena sharded over a mesh stay unfolded.
+
 Addressing inside a step: no layer ever gets its slab as an array of its own.
 The step views the stored arena as ONE flat slab [L * S_tot, n_kv, hd]
 (`flat_arena`, a bitcast), carries that whole through its layer scan, and
@@ -38,6 +52,25 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+
+SUBLANES, LANES = 8, 128  # the device's tile of a 2- or 4-byte type
+
+
+def folds(n_kv_heads: int, head_dim: int, dtype) -> bool:
+    """Whether a K/V arena of this shape is stored folded: whether
+    [S_tot, n_kv_heads, head_dim] and [S_tot * n_kv_heads, head_dim] are
+    DIFFERENT bytes in the device's tiled layout (the module docstring's
+    rule; tests/test_chip_compile.py compiles both layouts' decode programs
+    for a described v5e and finds the slab-sized re-lay-out exactly where
+    this says). A head_dim of no whole lanes is re-laid out by the kernels
+    either way and an 8-bit type tiles otherwise: neither folds."""
+    if head_dim % LANES or jnp.dtype(dtype).itemsize not in (2, 4):
+        return False
+    if n_kv_heads % SUBLANES == 0:
+        return False
+    return not (head_dim == LANES and SUBLANES % n_kv_heads == 0)
 
 
 def make_arena(
@@ -49,7 +82,7 @@ def make_arena(
     dtype=jnp.bfloat16,
     quant: str | None = None,
     payload: tuple[tuple[int, ...], tuple[int, ...]] | None = None,
-    fold_heads: bool = False,
+    sharded: bool = False,
 ) -> dict:
     """quant="int4": store the slabs group-quantized (the reference's
     TorchCompressedDevice KV capacity lever, compression.py:22-210) — ~3.2x
@@ -63,19 +96,11 @@ def make_arena(
     `layer_slots`, `layer_pages`, `arena_write`, `gather_pages` only ever
     see [L, S_tot, ...] and ids.
 
-    `fold_heads`: store a layer's slab as [S_tot * n_kv_heads, head_dim],
-    token-major then head, for a family whose KV heads are no whole number
-    of sublane tiles (phi4flash: 10 K/V pairs). The TPU lays [.., 10, 128]
-    out with the 10 padded to 16, so the paged kernels' view of a page as
-    [page_size * heads, head_dim] rows is no bitcast of it and every step
-    would re-lay the whole arena out (and hold 1.6 times its bytes); folded,
-    it is exactly those rows. Only that family's own step addresses such an
-    arena (runtime/sambay.py)."""
-    if fold_heads:
-        if quant not in (None, "none") or payload is not None:
-            raise ValueError("a folded arena has no int4 or latent form")
-        shape = (num_layers, num_pages * page_size * n_kv_heads, head_dim)
-        return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    `sharded`: the arena will be committed to a mesh on its head axis
+    (`--tp`), which a folded slab does not have; every dispatch is dense
+    there, so no kernel views its pages. Otherwise plain K and V head slabs
+    are stored folded, [L, S_tot * n_kv_heads, head_dim], wherever `folds`
+    says the kernels' page view would re-lay them out."""
     if payload is not None:
         if quant not in (None, "none"):
             raise ValueError(
@@ -94,7 +119,48 @@ def make_arena(
         return {"k": make_quant_slab(shape), "v": make_quant_slab(shape)}
     if quant not in (None, "none"):
         raise ValueError(f"unknown KV quant mode {quant!r}")
+    if not sharded and folds(n_kv_heads, head_dim, dtype):
+        shape = (num_layers, shape[1] * n_kv_heads, head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def is_folded(slab) -> bool:
+    """Whether a layer's slab (or the flat arena) of K/V heads is stored
+    folded, [tokens * heads, head_dim]: it has two dimensions. (A latent
+    page has two as well: its callers never ask.)"""
+    return getattr(slab, "ndim", 3) == 2
+
+
+def arena_tokens(arena_k, n_kv_heads: int | None) -> int:
+    """S_tot of a stored arena [L, S_tot, ...]; `n_kv_heads`: the K/V heads
+    a token's rows hold (None: a latent page), so that a folded arena's
+    [L, S_tot * n_kv_heads, head_dim] counts its tokens and not its rows."""
+    rows = arena_k.shape[1]
+    folded = n_kv_heads is not None and getattr(arena_k, "ndim", 4) == 3
+    return rows // n_kv_heads if folded else rows
+
+
+def heads_view(slab, n_kv_heads: int):
+    """A slab as a paged kernel takes it, [tokens, heads, head_dim]: itself,
+    or a folded one reshaped. The kernel views that as pages of
+    [page_size * heads, head_dim] rows at once, so the two reshapes are one
+    bitcast of the folded slab."""
+    if is_folded(slab):
+        return slab.reshape(-1, n_kv_heads, slab.shape[-1])
+    return slab
+
+
+def slot_rows(slots, fold: int):
+    """Token slots [N] -> the rows they take along a slab's token axis:
+    themselves (`fold` 1), or in a folded slab the `fold` = n_kv_heads rows
+    slot * fold + head, token-major [N * fold]. A slot out of range stays
+    out of range. numpy in, numpy out; jax in, jax out."""
+    if fold == 1:
+        return slots
+    xp = jnp if isinstance(slots, jax.Array) else np
+    return (
+        slots[:, None] * fold + xp.arange(fold, dtype=slots.dtype)[None, :]
+    ).reshape(-1)
 
 
 def make_state_arena(
@@ -174,8 +240,15 @@ def arena_write(
     Out-of-bounds slot ids are dropped — the span step points padding rows at
     slot == num_slots to discard their writes (`layer_slots` keeps them out
     of bounds when the slab is the flat arena).
+
+    A folded slab [S * n_kv, hd] takes the same TOKEN slots and rows: each
+    token's heads go to rows slot * n_kv + head.
     """
     from bloombee_tpu.kv.quant import QuantSlab, quantize
+
+    if is_folded(k_layer) and k_new.ndim == 3:
+        # (the rows themselves are reshaped below, to the slab's own rows)
+        slots = slot_rows(slots, k_new.shape[1])
 
     if isinstance(k_layer, QuantSlab):
         new_k, new_v = quantize(k_new), quantize(v_new)
@@ -192,8 +265,14 @@ def arena_write(
             )
         )
         return k_layer, v_layer
-    k_layer = k_layer.at[slots].set(k_new.astype(k_layer.dtype), mode="drop")
-    v_layer = v_layer.at[slots].set(v_new.astype(v_layer.dtype), mode="drop")
+    k_layer = k_layer.at[slots].set(
+        k_new.reshape(-1, *k_layer.shape[1:]).astype(k_layer.dtype),
+        mode="drop",
+    )
+    v_layer = v_layer.at[slots].set(
+        v_new.reshape(-1, *v_layer.shape[1:]).astype(v_layer.dtype),
+        mode="drop",
+    )
     return k_layer, v_layer
 
 
@@ -201,8 +280,14 @@ def gather_pages(
     layer_slab: jax.Array,  # [S, n_kv, hd]: a layer's slab or the flat arena
     page_table: jax.Array,  # [B, max_pages] int32 page ids INTO that slab
     page_size: int,
+    n_kv_heads: int | None = None,  # given by a caller whose slab holds K/V
+    # heads and may be stored folded
 ) -> jax.Array:
     """Gather each sequence's pages: returns [B, max_pages*page_size, n_kv, hd].
+
+    A folded slab [S * n_kv, hd] is gathered BY PAGE through its
+    [pages, page_size * n_kv, hd] view (a bitcast): one index a page, not
+    one a row.
 
     Invalid (padding) pages gather garbage rows; callers mask by context
     length — the clamped-read invariant lives in the attention mask, mirroring
@@ -211,6 +296,10 @@ def gather_pages(
     from bloombee_tpu.kv.quant import QuantSlab, dequantize
 
     b, max_pages = page_table.shape
+    if n_kv_heads is not None and is_folded(layer_slab):
+        hd = layer_slab.shape[-1]
+        pages = layer_slab.reshape(-1, page_size * n_kv_heads, hd)[page_table]
+        return pages.reshape(b, max_pages * page_size, n_kv_heads, hd)
     slots = (
         page_table[:, :, None] * page_size
         + jnp.arange(page_size, dtype=page_table.dtype)[None, None, :]

@@ -226,8 +226,8 @@ class CacheManager:
         # arena, rows of the state arena) where the span's layers keep ONE
         # cache kind each (models/spec.py `ModelSpec.arena_layers`); None =
         # every layer has a row in each arena the family has
-        fold_heads: bool = False,  # kv/arena.py `make_arena`: a layer's slab
-        # stored [tokens * heads, head_dim] (phi4flash)
+        sharded: bool = False,  # the arena will be committed to a mesh on
+        # its head axis (--tp): never stored folded (kv/arena.py)
     ):
         dtype = dtype or jnp.bfloat16
         if quant is None:
@@ -274,10 +274,16 @@ class CacheManager:
         else:
             self._make_arena = lambda: arena_ops.make_arena(
                 kv_layers, num_pages, page_size, n_kv_heads, head_dim,
-                dtype, quant=self.quant, payload=payload,
-                fold_heads=fold_heads,
+                dtype, quant=self.quant, payload=payload, sharded=sharded,
             )
         self.arena = self._make_arena()
+        # whether the slabs are stored folded, [L, tokens * heads, head_dim]
+        # (kv/arena.py `folds`: a rule of the shape), and the rows a token
+        # takes along a slab's token axis: what `_rows` scales a slot by
+        self.folded = (
+            payload is None and getattr(self.arena["k"], "ndim", 0) == 3
+        )
+        self._fold = n_kv_heads if self.folded else 1
         # recurrent state beside the pages (kv/arena.py): one slot per
         # sequence and layer, taken at allocate() and given back at its
         # exit. A slot is never zeroed by a write of its own: a sequence at
@@ -637,6 +643,22 @@ class CacheManager:
                     continue
                 return self._accept_speculative(handle, accepted_indices)
 
+    def _token_shape(self, leaf) -> tuple[int, ...]:
+        """What one token holds in an arena leaf, as the wire and the host
+        see it: [heads, head_dim] also where the arena is stored folded."""
+        if self.folded:
+            return (self._fold, leaf.shape[-1])
+        return tuple(leaf.shape[2:])
+
+    def _rows(self, slots):
+        """Token slots -> indices along the stored slabs' token axis (axis
+        1): themselves, or a folded arena's `n_kv_heads` rows a token
+        (kv/arena.py `slot_rows`). Every path that addresses the arena by
+        slot outside a span step goes through here: the speculative
+        compaction, copy-on-write, park / unpark, the replication export and
+        install. An out-of-range slot stays out of range (dropped)."""
+        return arena_ops.slot_rows(slots, self._fold)
+
     @_locked
     def _accept_speculative(
         self, handle: CacheHandle, accepted_indices: list
@@ -666,7 +688,7 @@ class CacheManager:
         dst_p[: len(dst)] = dst
         self.arena["k"], self.arena["v"] = _reorder_all_layers(
             self.arena["k"], self.arena["v"],
-            jnp.asarray(src_p), jnp.asarray(dst_p),
+            jnp.asarray(self._rows(src_p)), jnp.asarray(self._rows(dst_p)),
         )
 
     def ensure_resident(self, handle: CacheHandle) -> None:
@@ -731,7 +753,7 @@ class CacheManager:
         dst_p[: len(dst)] = dst
         self.arena["k"], self.arena["v"] = _reorder_all_layers(
             self.arena["k"], self.arena["v"],
-            jnp.asarray(src_p), jnp.asarray(dst_p),
+            jnp.asarray(self._rows(src_p)), jnp.asarray(self._rows(dst_p)),
         )
 
     @_locked
@@ -837,8 +859,13 @@ class CacheManager:
         slots = self.table.range_slots(
             seq_id, lo_page * self.page_size, hi * self.page_size
         )
-        idx = jnp.asarray(slots)
-        return self.arena["k"][:, idx], self.arena["v"][:, idx], hi
+        idx = jnp.asarray(self._rows(slots))
+
+        def take(a):  # the wire's page layout is the unfolded one
+            return a[:, idx].reshape(
+                a.shape[0], len(slots), *self._token_shape(a))
+
+        return take(self.arena["k"]), take(self.arena["v"]), hi
 
     @_locked
     def install_replicated(self, hashes, k_pages, v_pages) -> int:
@@ -851,13 +878,13 @@ class CacheManager:
         if not self.repl_supported:
             return 0
         lead = (len(hashes), self.num_layers, self.page_size)
-        want = lead + tuple(self.arena["k"].shape[2:])
+        want = lead + self._token_shape(self.arena["k"])
         k_pages = np.asarray(k_pages)
         v_pages = np.asarray(v_pages)
         # the two slabs' rows differ for a latent page (latent | rotary key)
         if (
             k_pages.shape != want
-            or v_pages.shape != lead + tuple(self.arena["v"].shape[2:])
+            or v_pages.shape != lead + self._token_shape(self.arena["v"])
         ):
             raise ValueError(
                 f"replicated page payload {k_pages.shape} does not match "
@@ -873,22 +900,19 @@ class CacheManager:
             return 0
         ps = self.page_size
         offs = np.arange(ps, dtype=np.int64)
-        slots = jnp.asarray(
+        slots = jnp.asarray(self._rows(
             np.concatenate([p * ps + offs for p in pages]).astype(np.int32)
-        )
+        ))
 
-        def flat(a):  # [m, L, ps, kv, hd] -> [L, m*ps, kv, hd]
-            sel = a[np.asarray(rows)]
-            return np.swapaxes(sel, 0, 1).reshape(
-                a.shape[1], len(rows) * ps, *a.shape[3:]
-            )
+        def put(stored, a):  # [m, L, ps, kv, hd] -> [L, m*ps, kv, hd], or
+            # as a folded arena stores them, [L, m*ps*kv, hd]
+            sel = np.swapaxes(a[np.asarray(rows)], 0, 1)
+            return stored.at[:, slots].set(jnp.asarray(
+                sel.reshape(a.shape[1], -1, *stored.shape[2:])
+            ).astype(stored.dtype))
 
-        self.arena["k"] = self.arena["k"].at[:, slots].set(
-            jnp.asarray(flat(k_pages)).astype(self.arena["k"].dtype)
-        )
-        self.arena["v"] = self.arena["v"].at[:, slots].set(
-            jnp.asarray(flat(v_pages)).astype(self.arena["v"].dtype)
-        )
+        self.arena["k"] = put(self.arena["k"], k_pages)
+        self.arena["v"] = put(self.arena["v"], v_pages)
         self.repl_pages_installed += len(pages)
         return len(pages)
 
@@ -1042,7 +1066,9 @@ class CacheManager:
             # un-trimmed adopted length and desync the client's suffix
             # offset on unpark — skip; the reclaimer finds other victims
             return
-        slots = self.table.prefix_slots(seq_id, committed_only=False)
+        slots = self._rows(
+            self.table.prefix_slots(seq_id, committed_only=False)
+        )
         state = self.table.seq(seq_id)
 
         hetero = isinstance(self.arena["k"], tuple)
@@ -1147,7 +1173,7 @@ class CacheManager:
             seq_id, l_seq, commit=False)  # bbtpu: noqa[BB001]
         del self._parked[seq_id]
         self.table.restore_committed(seq_id, l_acc)
-        slots = jnp.asarray(slots_np)
+        slots = jnp.asarray(self._rows(slots_np))
         from bloombee_tpu.kv.quant import QuantSlab, dequantize
 
         if self.quant is None and isinstance(k_host, QuantSlab):

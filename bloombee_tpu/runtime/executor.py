@@ -142,13 +142,15 @@ def _arena_write_all(arena_k, arena_v, slots, k_new, v_new):
     arena_write): ONE scatter into the flat arena, layer l's rows at its
     offset slots — the same addressing as a span step's layer."""
     from bloombee_tpu.kv.arena import (
+        arena_tokens,
         arena_write,
         flat_arena,
         layer_slots,
         stacked_arena,
     )
 
-    num_layers, s_tot = arena_k.shape[:2]
+    num_layers = arena_k.shape[0]
+    s_tot = arena_tokens(arena_k, k_new.shape[2])
     all_slots = layer_slots(
         slots[None, :], jnp.arange(num_layers, dtype=slots.dtype)[:, None],
         s_tot, num_layers,
@@ -226,13 +228,26 @@ class SpanExecutor:
                     f"layer with recurrent state) (arena_layers={want}), "
                     f"got {(manager.kv_layers, manager.state_layers)}"
                 )
-        if spec.mamba is not None:
-            if manager.arena["k"].ndim != 3:
+        if (spec.mla is None and manager.quant is None
+                and not spec.heterogeneous):
+            # the arena's layout is the rule's (kv/arena.py `folds`): the
+            # step programs address what the shape says, nothing else
+            from bloombee_tpu.kv.arena import folds
+
+            want = mesh is None and folds(
+                spec.num_key_value_heads, spec.head_dim,
+                manager.arena["k"].dtype,
+            )
+            if manager.folded != want:
                 raise ValueError(
-                    f"{spec.family} needs a CacheManager whose K/V arena is "
-                    "stored folded (fold_heads=True): its K/V pairs are no "
-                    "whole number of sublane tiles (kv/arena.py)"
+                    f"{spec.family}: the K/V arena is stored "
+                    f"{'folded' if manager.folded else 'unfolded'} where "
+                    f"{spec.num_key_value_heads} KV heads x {spec.head_dim}"
+                    f"{' under --tp' if mesh is not None else ''} take the "
+                    "other layout (kv/arena.py `folds`; CacheManager("
+                    "sharded=True) for a mesh)"
                 )
+        if spec.mamba is not None:
             for on, what in (
                 (bool(adapters), "LoRA adapters"),
                 (attn_sparsity < 1.0, "--attn-sparsity"),
@@ -1207,7 +1222,8 @@ class SpanExecutor:
         "fused"), real `rows` and `context` (its sequences' mean cached
         tokens before it, `starts`): the attention core's time follows the
         context, and a trace's reader has to know WHICH steps it holds;
-        `experts` is the form its experts took (`_count_moe`).
+        `experts` is the form its experts took (`_count_moe`), `arena` the
+        K/V slabs' layout ("folded" | "unfolded": kv/arena.py `folds`).
         `kind` and `rows` are also kept beside what the rows reached of the
         held experts, where the step says it."""
         if self.spec.mamba is not None:
@@ -1234,6 +1250,7 @@ class SpanExecutor:
             with jitwatch.span(
                 "bbtpu.step", kind=kind, rows=rows,
                 context=int(np.mean(starts)),
+                arena="folded" if self.manager.folded else "unfolded",
                 **({"experts": experts} if experts else {}),
                 **({"cross_rows": cross_rows} if self._cross_layers else {}),
             ):

@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from bloombee_tpu.kv.arena import arena_write, gather_pages
+from bloombee_tpu.kv.arena import arena_write, gather_pages, heads_view
 from bloombee_tpu.models.layout import in_axis_of, project
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.models.wquant import maybe_dequantize
@@ -775,7 +775,8 @@ def layer_body(
     page_size: int,
     hidden: jax.Array,  # [B, T, D]
     params: dict,  # one layer's params
-    k_slab: jax.Array,  # [S, Hkv, hd]: the flat arena (or a layer's slab)
+    k_slab: jax.Array,  # [S, Hkv, hd]: the flat arena (or a layer's slab);
+    # stored folded (kv/arena.py): [S * Hkv, hd], same slots and pages
     v_slab: jax.Array,
     cos: jax.Array,
     sin: jax.Array,
@@ -859,6 +860,9 @@ def layer_body(
         # the kernels compile for the device; interpret mode is only ever
         # the explicit test switch (read at trace time, like the spec)
         interpret = env.get("BBTPU_PAGED_INTERPRET")
+        # (a folded slab goes in as its heads' view, a bitcast: kv/arena.py)
+        k_pages = heads_view(k_slab, kv_heads)
+        v_pages = heads_view(v_slab, kv_heads)
         with jax.named_scope("attention"):
             if t == 1:
                 kernel = (
@@ -867,14 +871,14 @@ def layer_body(
                     else paged_decode_attention
                 )
                 attn = kernel(
-                    q[:, 0], k_slab, v_slab, page_table, total_lens,
+                    q[:, 0], k_pages, v_pages, page_table, total_lens,
                     page_size=page_size, scale=attn_scale(spec),
                     interpret=interpret,
                     window=window,  # per-layer traced scalar (0 = full)
                 )[:, None]  # [B, 1, H, hd]
             else:
                 attn = paged_chunk_attention(
-                    q, k_slab, v_slab, page_table, total_lens,
+                    q, k_pages, v_pages, page_table, total_lens,
                     page_size=page_size, tree_mask=tree_mask,
                     scale=attn_scale(spec), interpret=interpret,
                     window=window, has_tree=tree_mask is not None,
@@ -886,10 +890,10 @@ def layer_body(
         )
     with jax.named_scope("arena_gather"):
         k_ctx = gather_pages(
-            k_slab, page_table, page_size
+            k_slab, page_table, page_size, kv_heads
         ).astype(hidden.dtype)
         v_ctx = gather_pages(
-            v_slab, page_table, page_size
+            v_slab, page_table, page_size, kv_heads
         ).astype(hidden.dtype)
 
     if use_flash:
@@ -1038,6 +1042,7 @@ def _attend_by_rows(spec, page_size, q, k_slab, v_slab, page_table,
     from bloombee_tpu.ops.pallas.paged_attention import paged_decode_attention
 
     r, h, hd = q.shape
+    kv_heads = spec.num_key_value_heads
     scale = attn_scale(spec)
     out = jnp.zeros((r, h, hd), q.dtype)
     if rows.step_form:
@@ -1045,7 +1050,8 @@ def _attend_by_rows(spec, page_size, q, k_slab, v_slab, page_table,
         at = jnp.clip(rows.row0, 0, r - 1)
         with jax.named_scope("attention"):
             o = paged_decode_attention(
-                q[at], k_slab, v_slab, page_table,
+                q[at], heads_view(k_slab, kv_heads),
+                heads_view(v_slab, kv_heads), page_table,
                 jnp.where(one, total_lens, 0), page_size=page_size,
                 scale=scale, interpret=env.get("BBTPU_PAGED_INTERPRET"),
             )
@@ -1056,8 +1062,10 @@ def _attend_by_rows(spec, page_size, q, k_slab, v_slab, page_table,
         r0, n_c = rows.row0[c], rows.nt[c]
         q_c = _window_rows(q, r0, w)
         with jax.named_scope("arena_gather"):
-            k_ctx = gather_pages(k_slab, page_table[c][None], page_size)
-            v_ctx = gather_pages(v_slab, page_table[c][None], page_size)
+            k_ctx = gather_pages(
+                k_slab, page_table[c][None], page_size, kv_heads)
+            v_ctx = gather_pages(
+                v_slab, page_table[c][None], page_size, kv_heads)
         start = q_pos[jnp.clip(r0, 0, r - 1)]
         with jax.named_scope("attention"):
             if w % 128 == 0 and k_ctx.shape[1] % 128 == 0:
@@ -1084,7 +1092,8 @@ def layer_body_ragged(
     page_size: int,
     hidden: jax.Array,  # [1, R, D] — every member's tokens, ragged-packed
     params: dict,  # one layer's params
-    k_slab: jax.Array,  # [S, Hkv, hd]: the flat arena (or a layer's slab)
+    k_slab: jax.Array,  # [S, Hkv, hd]: the flat arena (or a layer's slab);
+    # stored folded (kv/arena.py): [S * Hkv, hd], same slots and pages
     v_slab: jax.Array,
     cos: jax.Array,
     sin: jax.Array,
@@ -1162,7 +1171,8 @@ def layer_body_ragged(
 
         with jax.named_scope("attention"):
             attn = paged_ragged_attention(
-                q[0], k_slab, v_slab, page_table, total_lens,
+                q[0], heads_view(k_slab, kv_heads),
+                heads_view(v_slab, kv_heads), page_table, total_lens,
                 q_seq, q_positions[0],
                 page_size=page_size, scale=attn_scale(spec),
                 interpret=env.get("BBTPU_PAGED_INTERPRET"),
@@ -1172,10 +1182,10 @@ def layer_body_ragged(
     else:
         with jax.named_scope("arena_gather"):
             k_ctx = gather_pages(
-                k_slab, page_table, page_size
+                k_slab, page_table, page_size, kv_heads
             ).astype(hidden.dtype)
             v_ctx = gather_pages(
-                v_slab, page_table, page_size
+                v_slab, page_table, page_size, kv_heads
             ).astype(hidden.dtype)
         with jax.named_scope("attention"):
             attn = attend_ragged(

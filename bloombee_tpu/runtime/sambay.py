@@ -38,12 +38,12 @@ published form concatenates from two calls. Under plain GQA query half
 2p + j reads K/V pair p // 2, as published. The queries go in as float32 so
 the two softmaxes come back unrounded before they are subtracted.
 
-The K/V arena is stored FOLDED, a layer's slab [tokens * pairs, 128]
-(kv/arena.py `make_arena(fold_heads=True)`): 10 pairs are no whole number of
+At the published widths the K/V arena is stored FOLDED, a layer's slab
+[tokens * pairs, 128] (kv/arena.py `folds`): 10 pairs are no whole number of
 sublane tiles, so [tokens, 10, 128] is laid out with the 10 padded to 16 and
 the kernels' view of a page as [16 * 10, 128] rows would be a copy of the
-whole arena. Here a slab is those rows: `_write_kv` and `_gather_kv` address
-them, and the kernels get `_heads_view`, whose reshape folds into theirs.
+whole arena. The step addresses either layout through the arena's own
+helpers (`arena_write`, `gather_pages`, `heads_view`), as every family's.
 """
 
 from __future__ import annotations
@@ -53,7 +53,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from bloombee_tpu.kv.arena import (
+    arena_tokens,
+    arena_write,
     flat_arena,
+    gather_pages,
+    heads_view,
     layer_pages,
     layer_slots,
     layer_state_slots,
@@ -82,37 +86,6 @@ def cross_block(rows: int, n_seqs: int) -> int:
     """Rows of the gathered cross-decoder's block for a step of `rows` row
     buckets and `n_seqs` sequence buckets: a reply row a sequence fits."""
     return min(rows, max(CROSS_BLOCK, n_seqs))
-
-
-# ------------------------------------------------------ the folded K/V slab
-def _heads_view(slab, kvh: int):
-    """[rows, hd] -> [tokens, kvh, hd] for a paged kernel, which views it as
-    pages of [page_size * kvh, hd] rows at once: one bitcast of the slab."""
-    return slab.reshape(-1, kvh, slab.shape[-1])
-
-
-def _write_kv(k_slab, v_slab, slots, k, v):
-    """k, v [R, kvh, hd] at token slots [R] (out of range: dropped)."""
-    kvh, hd = k.shape[1:]
-    rows = (
-        slots[:, None] * kvh + jnp.arange(kvh, dtype=slots.dtype)[None, :]
-    ).reshape(-1)
-    return (
-        k_slab.at[rows].set(
-            k.reshape(-1, hd).astype(k_slab.dtype), mode="drop"),
-        v_slab.at[rows].set(
-            v.reshape(-1, hd).astype(v_slab.dtype), mode="drop"),
-    )
-
-
-def _gather_kv(slab, page_table, page_size: int, kvh: int):
-    """Each sequence's pages [B, NP] -> [B, NP * page_size, kvh, hd]."""
-    b, n = page_table.shape
-    rows = (
-        page_table[:, :, None] * (page_size * kvh)
-        + jnp.arange(page_size * kvh, dtype=page_table.dtype)[None, None, :]
-    ).reshape(b, n * page_size * kvh)
-    return slab[rows].reshape(b, n * page_size, kvh, slab.shape[-1])
 
 
 # --------------------------------------------------------------- the mixers
@@ -283,8 +256,10 @@ def _diff_attend(spec: ModelSpec, page_size: int, q, k_slab, v_slab,
     scale = attn_scale(spec)
     if not kernels:
         with jax.named_scope("arena_gather"):
-            k_ctx = _gather_kv(k_slab, page_table, page_size, kvh).astype(F32)
-            v_ctx = _gather_kv(v_slab, page_table, page_size, kvh).astype(F32)
+            k_ctx = gather_pages(
+                k_slab, page_table, page_size, kvh).astype(F32)
+            v_ctx = gather_pages(
+                v_slab, page_table, page_size, kvh).astype(F32)
         with jax.named_scope("attention"):
             return attend_ragged(
                 spec, q, k_ctx, v_ctx, q_pos, rows.q_seq, total_lens,
@@ -299,7 +274,7 @@ def _diff_attend(spec: ModelSpec, page_size: int, q, k_slab, v_slab,
         at = jnp.clip(rows.row0, 0, r - 1)
         with jax.named_scope("attention"):
             o = paged_decode_attention(
-                q[at], _heads_view(k_slab, kvh), _heads_view(v_slab, kvh),
+                q[at], heads_view(k_slab, kvh), heads_view(v_slab, kvh),
                 page_table, jnp.where(one, total_lens, 0),
                 page_size=page_size,
                 scale=scale, interpret=env.get("BBTPU_PAGED_INTERPRET"),
@@ -315,8 +290,8 @@ def _diff_attend(spec: ModelSpec, page_size: int, q, k_slab, v_slab,
         start = q_pos[jnp.clip(r0, 0, r - 1)]
         pages, p0 = _chunk_pages(page_table[c], start, w, window, page_size)
         with jax.named_scope("arena_gather"):
-            k_ctx = _gather_kv(k_slab, pages[None], page_size, kvh)
-            v_ctx = _gather_kv(v_slab, pages[None], page_size, kvh)
+            k_ctx = gather_pages(k_slab, pages[None], page_size, kvh)
+            v_ctx = gather_pages(v_slab, pages[None], page_size, kvh)
         shift = p0 * page_size
         with jax.named_scope("attention"):
             if w % 128 == 0 and k_ctx.shape[1] % 128 == 0:
@@ -350,7 +325,7 @@ def _self_attention(spec, page_size, params, x, k_slab, v_slab, slots,
         k = _proj(x, params, "k_proj").reshape(r, kvh, hd)
         v = _proj(x, params, "v_proj").reshape(r, kvh, hd)
     with jax.named_scope("arena_write"):
-        k_slab, v_slab = _write_kv(k_slab, v_slab, slots, k, v)
+        k_slab, v_slab = arena_write(k_slab, v_slab, slots, k, v)
     a = _diff_attend(
         spec, page_size, q, k_slab, v_slab, page_table, q_pos, total_lens,
         rows, window, kernels,
@@ -376,7 +351,7 @@ def _cross_attention(spec, page_size, params, x, k_slab, v_slab, page_table,
 
             with jax.named_scope("attention"):
                 a = paged_decode_attention(
-                    q, _heads_view(k_slab, kvh), _heads_view(v_slab, kvh),
+                    q, heads_view(k_slab, kvh), heads_view(v_slab, kvh),
                     page_table[jnp.clip(q_seq, 0, n_seqs - 1)],
                     jnp.where(real, q_pos + 1, 0), page_size=page_size,
                     scale=attn_scale(spec),
@@ -384,9 +359,9 @@ def _cross_attention(spec, page_size, params, x, k_slab, v_slab, page_table,
                 )
         else:
             with jax.named_scope("arena_gather"):
-                k_ctx = _gather_kv(
+                k_ctx = gather_pages(
                     k_slab, page_table, page_size, kvh).astype(F32)
-                v_ctx = _gather_kv(
+                v_ctx = gather_pages(
                     v_slab, page_table, page_size, kvh).astype(F32)
             with jax.named_scope("attention"):
                 a = attend_ragged(
@@ -424,7 +399,8 @@ def sambay_span(
     page_size: int,
     stacked_params: dict,
     hidden: jax.Array,  # [R, D] flat rows
-    arena_k: jax.Array,  # [kv rows, S_tot * pairs, 128]: folded
+    arena_k: jax.Array,  # [kv rows, S_tot * pairs, 128] folded, or
+    # [kv rows, S_tot, pairs, head_dim] (kv/arena.py `folds`)
     arena_v: jax.Array,
     state: dict,  # {"ssm": [state rows, slots, N, C], "conv": ...}
     slots: jax.Array,  # [R] (out of range: a padding row)
@@ -444,7 +420,7 @@ def sambay_span(
     runs = split_sambay(stacked_params)
     r = hidden.shape[0]
     kv_layers = arena_k.shape[0]
-    s_tot = arena_k.shape[1] // spec.num_key_value_heads
+    s_tot = arena_tokens(arena_k, spec.num_key_value_heads)
     num_pages = s_tot // page_size
     state_layers, num_state_slots = state["ssm"].shape[:2]
     k_flat, v_flat = flat_arena(arena_k), flat_arena(arena_v)

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from bloombee_tpu.kv.arena import (
+    arena_tokens,
     flat_arena,
     layer_pages,
     layer_slots,
@@ -301,6 +302,9 @@ def _scan_layers(
     reach: bool = False,  # static: these layers route over experts of which
     # the server holds a share; the result then ends with what each layer's
     # rows reached of them, i32 [n, 3] (ops/moe.py `held_reach`)
+    *,
+    s_tot: int,  # the arena's tokens a layer (`_arena_dims`): not its
+    # second dimension where it is stored folded
 ):
     """The span's layer scan, the arena WHOLE in the carry.
 
@@ -313,7 +317,7 @@ def _scan_layers(
     The recurrent-state arena, where the family has one, is carried and
     addressed the same way; with it the result has a fourth value.
     """
-    num_layers, s_tot = arena_k.shape[:2]
+    num_layers = arena_k.shape[0]
     num_pages = s_tot // page_size
     n = layer_active.shape[0]
     num_state_slots = 0 if state is None else state["ssm"].shape[1]
@@ -373,6 +377,13 @@ def _scan_layers(
     return (*out, reached) if reach else out
 
 
+def _arena_dims(spec: ModelSpec, arena_k) -> tuple[int, int]:
+    """(rows, S_tot) of a stored arena [rows, S_tot, ...]; a folded one
+    (kv/arena.py `folds`) holds S_tot * kv_heads rows of head_dim a layer."""
+    kv_heads = None if spec.mla is not None else spec.num_key_value_heads
+    return arena_k.shape[0], arena_tokens(arena_k, kv_heads)
+
+
 def _scan_periods(run_layer, spec, stacked_params, rows, kernels, hidden,
                   arena_k, arena_v, slots, page_table, layer_active,
                   per_layer, page_size, state, state_slots, ssm_rows):
@@ -396,7 +407,7 @@ def _scan_periods(run_layer, spec, stacked_params, rows, kernels, hidden,
         lift_expert_stacks(spec, params, rows, kernels)
         for params in (*linear, full)
     ]
-    kv_layers, s_tot = arena_k.shape[:2]
+    kv_layers, s_tot = _arena_dims(spec, arena_k)
     num_pages = s_tot // page_size
     state_layers, num_state_slots = state["ssm"].shape[:2]
 
@@ -513,7 +524,7 @@ def _scan_runs(run_layer, spec, stacked_params, rows, kernels, hidden,
         out = _scan_layers(
             run, out[0], out[1], out[2], slots, page_table,
             cut(layer_active), xs, page_size, first_layer=first, reach=reach,
-            **state,
+            s_tot=_arena_dims(spec, out[1])[1], **state,
         )
         if reach:
             *out, reached_run = out
@@ -875,7 +886,7 @@ def layer_step_impl(
     cos, sin = rotary_cos_sin(q_positions, spec.head_dim, theta)
     cos = cos.astype(hidden.dtype)
     sin = sin.astype(hidden.dtype)
-    num_layers, s_tot = arena_k.shape[:2]
+    num_layers, s_tot = _arena_dims(spec, arena_k)
     hidden, k_flat, v_flat = layer_body(
         spec, page_size, hidden, params_l,
         flat_arena(arena_k), flat_arena(arena_v), cos, sin,

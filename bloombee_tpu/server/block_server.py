@@ -763,7 +763,7 @@ class BlockServer(PromotionLoopMixin):
             ),
             payload=spec.mla.page_payload if spec.mla is not None else None,
             arena_layers=spec.arena_layers(start, end),
-            fold_heads=spec.mamba is not None,
+            sharded=tp > 1,
         )
         self.idle_park_s = idle_park_s
         if oversubscribe > 1.0:
@@ -1144,8 +1144,9 @@ class BlockServer(PromotionLoopMixin):
                 self.rebalance_period, self.rebalance_unsupported(),
             )
         logger.info(
-            "server %s serving %s[%d:%d] on port %d",
-            self.server_id, self.model_uid, self.start_block, self.end_block, self.port,
+            "server %s serving %s[%d:%d] on port %d; kv arena %s",
+            self.server_id, self.model_uid, self.start_block, self.end_block,
+            self.port, "folded" if self.manager.folded else "unfolded",
         )
         from bloombee_tpu import native
         from bloombee_tpu.utils.memory import device_report
@@ -1689,7 +1690,7 @@ class BlockServer(PromotionLoopMixin):
                     spec.mla.page_payload if spec.mla is not None else None
                 ),
                 arena_layers=spec.arena_layers(start, end),
-                fold_heads=spec.mamba is not None,
+                sharded=self.tp > 1,
             )
             if self.manager.reclaimer is not None:
                 manager.reclaimer = self._reclaim_idle
@@ -2126,13 +2127,18 @@ class BlockServer(PromotionLoopMixin):
                 {"sambay": {
                     k: self.executor.sambay[k] for k in (
                         "self_rows", "cross_rows", "short_steps",
-                        "long_steps", "shared_kv_reads")},
-                 "kv": {
-                    "held_tokens": self.executor.sambay["kv_held_tokens"],
-                    "window_dead_tokens":
-                        self.executor.sambay["window_dead_tokens"]}}
+                        "long_steps", "shared_kv_reads")}}
                 if self.spec.mamba is not None else {}
             ),
+            # which layout the arena's slabs have (kv/arena.py `folds`)
+            "kv": {
+                "folded": self.manager.folded,
+                **({
+                    "held_tokens": self.executor.sambay["kv_held_tokens"],
+                    "window_dead_tokens":
+                        self.executor.sambay["window_dead_tokens"]}
+                   if self.spec.mamba is not None else {}),
+            },
             # a share of the experts held: what the steps read so far
             # reached of it (sums over steps and sparse layers; per sparse
             # layer the distinct held experts the last step's rows chose)

@@ -746,7 +746,31 @@ def _qwen3_next_shapes(one_chip, pages=5376):
         "ssm": s((state_layers, slots), *g.state_shape, dtype=f32),
         "conv": s((state_layers, slots), *g.tail_shape),
     }
-    return spec, params, s((kv_layers,), pages * PAGE, kv, hd), state
+    return spec, params, _arena_shape(s, kv_layers, pages, kv, hd), state
+
+
+def _arena_shape(s, kv_layers, pages, kv, hd, dtype=bf16):
+    """A cell's K/V arena as `make_arena` stores it: the rule's layout."""
+    from bloombee_tpu.kv.arena import folds
+
+    if folds(kv, hd, dtype):
+        return s((kv_layers,), pages * PAGE * kv, hd)
+    return s((kv_layers,), pages * PAGE, kv, hd)
+
+
+def _slab_moves(text: str, elems: int) -> list[str]:
+    """The compiled program's reshape / copy / transpose operations whose
+    result holds at least `elems` elements: a re-lay-out of a whole slab (a
+    reshape that is free reads `bitcast`)."""
+    import math
+    import re
+
+    return [
+        f"{m.group(3)} {m.group(1)}[{m.group(2)}]"
+        for m in re.finditer(
+            r"= (\w+)\[([\d,]+)\]\S* (reshape|copy|transpose)\(", text)
+        if math.prod(int(x) for x in m.group(2).split(",")) >= elems
+    ]
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk", "tail", "fused"])
@@ -800,12 +824,19 @@ def test_qwen3_next_span_step_compiles_and_copies_no_parameter(v5e, program):
     tiled = program in ("chunk", "fused")
     assert ("jit(tiled_experts)" in text) == tiled
     assert ("jit(grouped_experts)" in text) == (not tiled)
+    # 2 KV heads x 256 are no whole tile: the arena is stored folded (the
+    # rule, kv/arena.py `folds`), so the paged kernels' page view is a
+    # bitcast and no program re-lays a slab out (88 MB; unfolded, a decode
+    # step did it four times: `reshape` 1.08 s of a traced 5 s, ledger PR 45)
+    assert arena.shape == (2, 5376 * PAGE * 2, 256)
+    slab = arena.shape[1] * arena.shape[2]
+    assert not _slab_moves(text, slab), _slab_moves(text, slab)
     temp = compiled.memory_analysis().temp_size_in_bytes
-    # decode and tail: one state-arena-sized and one slab-sized buffer (the
-    # scatters' results, as Falcon-H1's decode program); ONE stack for the
+    # decode 12.2 MB and tail 11.6 MB (400 MB was the bound while they held
+    # a slab-sized buffer), chunk 43 MB, fused 205 MB; ONE stack for the
     # three linear positions made the chunk program's 2,742 MB
-    assert temp < {"decode": 400, "chunk": 150, "tail": 400,
-                   "fused": 500}[program] * 1e6, temp
+    assert temp < {"decode": 20, "chunk": 60, "tail": 20,
+                   "fused": 300}[program] * 1e6, temp
 
 
 def _phi4flash_shapes(one_chip, pages=5376):
@@ -876,7 +907,7 @@ def _phi4flash_shapes(one_chip, pages=5376):
         "ssm": s((state_layers, slots), *mb.state_shape, dtype=f32),
         "conv": s((state_layers, slots), *mb.tail_shape),
     }
-    return spec, params, s((kv_layers,), pages * PAGE * kv, hd), state
+    return spec, params, _arena_shape(s, kv_layers, pages, kv, hd), state
 
 
 @pytest.mark.parametrize("program", ["decode", "chunk", "tail", "fused"])
@@ -927,5 +958,93 @@ def test_phi4flash_span_step_compiles_and_copies_no_parameter(v5e, program):
     assert not re.findall(r"copy\([^)\n]*%(arena_[kv]|state__ssm)", text)
     assert "tpu_custom_call" in text
     assert ("jit(selective_scan)" in text) == (program != "decode")
+    slab = arena.shape[1] * arena.shape[2]
+    assert not _slab_moves(text, slab), _slab_moves(text, slab)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1500e6, temp
+
+
+# ---------------------------------------------- the arena's layout rule (PR 46)
+# kv/arena.py `folds`: [S_tot, kv_heads, head_dim] is stored folded,
+# [S_tot * kv_heads, head_dim], exactly where the paged kernels' view of it
+# as pages of [page_size * kv_heads, head_dim] rows is no bitcast on the
+# device. The six cells' K/V shapes and two more; DeepSeek-V2's page is a
+# latent and a rotary key, not heads: never folded, its own kernel.
+_LAYOUT_CASES = {
+    "mistral-8x128": (8, 128, False),
+    "qwen3moe-4x128": (4, 128, False),
+    "falconh1-8x128": (8, 128, False),
+    "qwen3next-2x256": (2, 256, True),
+    "phi4flash-10x128": (10, 128, True),
+    "4x256": (4, 256, True),
+    "16x256": (16, 256, False),
+}
+
+
+def _arena_part_of_a_decode_step(kv, hd, folded: bool, pages=5376):
+    """(fn, shapes): four rows' K and V written into one layer's slab and
+    attended through the paged decode kernel, the slab in the given layout
+    and addressed through the arena's helpers as `layer_body` does."""
+    from bloombee_tpu.kv.arena import arena_write, heads_view
+
+    def fn(q, k_slab, v_slab, slots, k_new, v_new, table, lens):
+        k_slab, v_slab = arena_write(k_slab, v_slab, slots, k_new, v_new)
+        out = paged_decode_attention(
+            q, heads_view(k_slab, kv), heads_view(v_slab, kv), table, lens,
+            page_size=PAGE)
+        return out, k_slab, v_slab
+
+    slab = (pages * PAGE * kv, hd) if folded else (pages * PAGE, kv, hd)
+    h = max(4 * kv, 8)
+    return fn, [
+        ((4, h, hd), bf16), (slab, bf16), (slab, bf16), ((4,), i32),
+        ((4, kv, hd), bf16), ((4, kv, hd), bf16), ((4, 1024), i32),
+        ((4,), i32)]
+
+
+@pytest.mark.parametrize("case", sorted(_LAYOUT_CASES) + ["deepseekv2-latent"])
+def test_chosen_layout_holds_no_slab_sized_move(v5e, case):
+    """The layout the rule chooses compiles to a decode program with no
+    reshape / copy / transpose the size of a slab and no slab-sized
+    temporary; where it chooses to fold, the unfolded layout DOES hold one
+    (so the rule folds nothing that did not need it: the other cases' chosen
+    layout is the unfolded one)."""
+    import math
+
+    from bloombee_tpu.kv.arena import folds
+
+    one_chip = SingleDeviceSharding(v5e[0])
+
+    def compile_(fn, shapes, donate=()):
+        return jax.jit(fn, donate_argnums=donate).lower(*(
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
+        )).compile()
+
+    if case == "deepseekv2-latent":
+        from bloombee_tpu.ops.pallas.latent_attention import (
+            paged_decode_attention_latent,
+        )
+
+        elems = 5 * 5376 * PAGE * 512
+        compiled = compile_(
+            functools.partial(
+                paged_decode_attention_latent, page_size=PAGE, scale=0.1147),
+            [((8, 128, 512), bf16), ((8, 128, 128), bf16),
+             ((5 * 5376 * PAGE, 512), bf16), ((5 * 5376 * PAGE, 128), bf16),
+             ((8, 1024), i32), ((8,), i32)])
+        assert not _slab_moves(compiled.as_text(), elems // 5)
+        return
+    kv, hd, folded = _LAYOUT_CASES[case]
+    assert folds(kv, hd, bf16) is folded
+    assert folds(kv, hd, jnp.float32) is folded
+    elems = 5376 * PAGE * kv * hd
+    fn, shapes = _arena_part_of_a_decode_step(kv, hd, folded)
+    compiled = compile_(fn, shapes, donate=(1, 2))
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not _slab_moves(text, elems), _slab_moves(text, elems)
+    assert compiled.memory_analysis().temp_size_in_bytes < elems  # < half a slab
+    if folded:
+        fn, shapes = _arena_part_of_a_decode_step(kv, hd, False)
+        moved = _slab_moves(compile_(fn, shapes, donate=(1, 2)).as_text(), elems)
+        assert len(moved) >= 2, moved  # K's slab and V's

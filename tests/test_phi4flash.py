@@ -54,6 +54,16 @@ KERNELS = {"BBTPU_PAGED_INTERPRET": "1", "BBTPU_PAGED_MIN_CONTEXT": "0",
 TOL = dict(rtol=1e-5, atol=1e-6)
 
 
+@pytest.fixture(autouse=True)
+def _folded_as_published(monkeypatch):
+    """The published ten pairs of 128 are stored folded (kv/arena.py
+    `folds`); the tiny model's one pair of 64 would not be. These tests
+    address the layout the cell runs: the rule says yes here."""
+    from bloombee_tpu.kv import arena
+
+    monkeypatch.setattr(arena, "folds", lambda *_: True)
+
+
 @pytest.fixture(scope="module")
 def ckpt(tmp_path_factory):
     path = tmp_path_factory.mktemp("tiny_phi4flash")
@@ -88,7 +98,7 @@ def _manager(spec, layers=(0, LAYERS), pages=96, **kw):
     return CacheManager(
         layers[1] - layers[0], pages, 4, spec.num_key_value_heads,
         spec.head_dim, dtype=jnp.float32, ssm=spec.recurrent,
-        arena_layers=spec.arena_layers(*layers), fold_heads=True, **kw)
+        arena_layers=spec.arena_layers(*layers), **kw)
 
 
 def _executor(span, manager=None, **kw):
@@ -428,7 +438,7 @@ def test_paths_that_cut_or_copy_a_cache_are_refused(ckpt, span):
     with pytest.raises(ValueError, match="arena_layers"):
         SpanExecutor(params, spec, CacheManager(
             LAYERS, 16, 4, 1, 64, dtype=jnp.float32, ssm=spec.recurrent,
-            state_slots=2, fold_heads=True), compute_dtype=jnp.float32)
+            state_slots=2), compute_dtype=jnp.float32)
     ex = _executor(span)
 
     async def go():
