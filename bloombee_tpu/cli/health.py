@@ -315,6 +315,11 @@ def main(argv=None):
                         line += "  moe " + " ".join(
                             f"{k}={v}" for k, v in sorted(moe.items())
                         )
+                    # the tile a chunk's flash kernel multiplies at the
+                    # span's shapes, by layer kind (block_q x block_k x
+                    # query heads a tile: ops/pallas/flash_attention.py)
+                    if probe.get("flash"):
+                        line += f"  flash={probe['flash']}"
                     # a server that holds a share of the routed experts,
                     # and what a cached token costs where the page is a
                     # latent (deepseek_v2)

@@ -28,6 +28,7 @@ from bloombee_tpu.runtime.step import (
     experts_form,
     pack_plan,
     pack_ragged_plan,
+    pack_chunk_on_flash,
     pack_cross_tail,
     pack_ragged_ssm_tail,
     pack_step_payload,
@@ -35,6 +36,8 @@ from bloombee_tpu.runtime.step import (
     span_step_ragged,
 )
 from bloombee_tpu.ops.moe import reach_fields
+from bloombee_tpu.ops.pallas.flash_attention import flash_takes, flash_tiles
+from bloombee_tpu.runtime.layer_body import chunk_run_pages
 from bloombee_tpu.utils import env, jitwatch
 
 logger = logging.getLogger(__name__)
@@ -426,6 +429,16 @@ class SpanExecutor:
         # a family with experts: device dispatches by the form its experts
         # took (ops/moe.py), counted from the bucket's rows
         self.moe_dispatches = {"grouped": 0, "tiled": 0, "dense": 0}
+        # the tile the last chunk's flash kernel multiplied, by layer kind
+        # (`_flash_form`, made once a (rows, page bucket)); None until a
+        # chunk has attended through it. The windows are those of the
+        # span's layers that attend (a linear, Mamba or GMU layer does not)
+        self.flash_form: str | None = None
+        self._flash_forms: dict[tuple[int, int], str | None] = {}
+        self._attn_windows = {
+            w for i, w in enumerate(self.windows)
+            if spec.layer_type(start_block + i) in ("full", "sliding", "cross")
+        }
         # a server that holds a share of the experts (spec.moe_held): what
         # each step's rows reached of them per sparse layer (ops/moe.py
         # `held_reach`), handed out of the step program as a device array
@@ -1013,9 +1026,13 @@ class SpanExecutor:
         )
         self.attn_dispatches["ragged" if used_kernel else "dense"] += 1
         self.ragged_buckets_run.add(tag)
+        # where a pack's one chunk attends through the flash kernel, it is
+        # rb rows wide there (runtime/step.py `pack_chunk_on_flash`)
+        flash_now = used_kernel and pack_chunk_on_flash(spec)
         out = self._keep_arena(
             result, "fused", r, starts, self._count_moe(rb, used_kernel),
             cross_rows=None if cross_idx is None else len(cross_idx),
+            flash=self._flash_form(rb, pb) if flash_now else None,
         )
         with jitwatch.span("bbtpu.slice"):
             return out[0, :r], combined
@@ -1241,7 +1258,8 @@ class SpanExecutor:
 
     def _keep_arena(self, result, kind: str, rows: int, starts,
                     experts: str | None = None,
-                    cross_rows: int | None = None):
+                    cross_rows: int | None = None,
+                    flash: str | None = None):
         """Store a span step's returned arenas (K, V and, where the family
         has one, the state arena) on the manager; returns the step's output.
         A step of a latent-attention family, of one with linear-attention
@@ -1252,7 +1270,8 @@ class SpanExecutor:
         tokens before it, `starts`): the attention core's time follows the
         context, and a trace's reader has to know WHICH steps it holds;
         `experts` is the form its experts took (`_count_moe`), `arena` the
-        K/V slabs' layout ("folded" | "unfolded": kv/arena.py `folds`).
+        K/V slabs' layout ("folded" | "unfolded": kv/arena.py `folds`),
+        `flash` the tile a chunk's flash kernel multiplied (`_flash_form`).
         `kind` and `rows` are also kept beside what the rows reached of the
         held experts, where the step says it."""
         if self._window_layers:
@@ -1281,6 +1300,7 @@ class SpanExecutor:
                 context=int(np.mean(starts)),
                 arena="folded" if self.manager.folded else "unfolded",
                 **({"experts": experts} if experts else {}),
+                **({"flash": flash} if flash else {}),
                 **({"cross_rows": cross_rows} if self._cross_layers else {}),
             ):
                 pass
@@ -1308,6 +1328,39 @@ class SpanExecutor:
             return None
         form = experts_form(self.spec, self.params, rows, kernels)
         self.moe_dispatches["grouped" if form == "list" else form] += 1
+        return form
+
+    def _flash_form(self, rows: int, max_pages: int) -> str | None:
+        """The tile the flash kernel multiplies where a step's chunk of
+        `rows` rows a sequence attends at the `max_pages` bucket, by the kind
+        of attention layer the span holds: "full:128x512x4" or
+        "window:256x512x6,full:256x512x6" (block_q x block_k x query heads a
+        tile); None where the kernel takes no layer's call. Made once a
+        (rows, bucket), as the step's program is, from what the layers
+        themselves decide by: the run a layer gathers (`chunk_run_pages`),
+        the test its caller makes of it (`flash_takes`) and the kernel's
+        own rule (`flash_tiles`)."""
+        key = (rows, max_pages)
+        if key not in self._flash_forms:
+            spec = self.spec
+            itemsize = np.dtype(self.compute_dtype).itemsize
+            kinds = {}
+            for window in sorted(self._attn_windows, reverse=True):
+                keys = self.page_size * chunk_run_pages(
+                    rows, window, self.page_size, max_pages
+                )
+                if flash_takes(rows, keys):
+                    kinds["window" if window else "full"] = "x".join(map(
+                        str, flash_tiles(
+                            rows, keys, spec.gqa_groups, spec.head_dim,
+                            itemsize,
+                        )
+                    ))
+            self._flash_forms[key] = ",".join(
+                f"{k}:{v}" for k, v in kinds.items()
+            ) or None
+        form = self._flash_forms[key]
+        self.flash_form = form or self.flash_form
         return form
 
     @staticmethod
@@ -1636,10 +1689,7 @@ class SpanExecutor:
                 and self.spec.mla is None  # its own flash form (use_paged)
                 and self.spec.mamba is None  # likewise
                 and tree_mask is None
-                and tb >= 128
-                and tb % 128 == 0
-                and s_ctx % 128 == 0
-                and s_ctx >= tb
+                and flash_takes(tb, s_ctx)
                 and not self.spec.alibi
                 and not self.spec.attn_logit_softcap
                 and self._flash_windows_ok
@@ -1753,10 +1803,18 @@ class SpanExecutor:
                 _run, use_paged or flash_experts, arena, "span step"
             )
             use_paged = use_paged and kernels_used
+            # a chunk attends through the flash kernel: the plain path's
+            # `use_flash`, or a SambaY span's own step with kernels on,
+            # which takes every sequence with more than one row as a chunk
+            # (runtime/sambay.py `_diff_attend`)
+            flash_now = use_flash or (
+                self.spec.mamba is not None and use_paged and tb > 1
+            )
             out = self._keep_arena(
                 result, "decode" if t == 1 else "chunk", b * t, starts,
                 self._count_moe(bb * tb, kernels_used),
                 cross_rows=cross_rows,
+                flash=self._flash_form(tb, pb) if flash_now else None,
             )
         path = "paged" if use_paged else "flash" if use_flash else "dense"
         self.attn_dispatches[path] += 1

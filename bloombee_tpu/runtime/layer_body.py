@@ -71,6 +71,7 @@ from bloombee_tpu.ops.alibi import alibi_slopes
 from bloombee_tpu.ops.attention import NEG_INF, repeat_kv
 from bloombee_tpu.ops.moe import moe_mlp
 from bloombee_tpu.ops.norms import layer_norm
+from bloombee_tpu.ops.pallas.flash_attention import BLOCK_K, flash_takes
 from bloombee_tpu.ops.linear_attention import (
     gdn_sequence,
     gdn_step,
@@ -933,15 +934,24 @@ def layer_body(
     )
 
 
+def chunk_run_pages(w: int, window: int, page_size: int, max_pages: int):
+    """How many pages a chunk of up to `w` rows gathers under a static
+    `window`: the bucket's `max_pages` for window 0 or a run that would
+    pass them; else the pages that span (start - window, start + w), a
+    whole number of the flash kernel's widest K blocks, so that the widest
+    divides the run (ops/pallas/flash_attention.py `flash_tiles`)."""
+    n = -(-(window + w + page_size) // BLOCK_K) * BLOCK_K // page_size
+    return max_pages if not window or n >= max_pages else n
+
+
 def _chunk_pages(page_table_q, start, w: int, window: int, page_size: int):
     """The pages a chunk of up to `w` rows from position `start` attends
     under a static `window`: (pages [n], the first one's index). Every page
-    for window 0; else the run of pages that spans
-    (start - window, start + w), a whole number of 128-key blocks."""
+    for window 0; else the run of `chunk_run_pages` that spans
+    (start - window, start + w)."""
     max_pages = page_table_q.shape[0]
-    tokens = -(-(window + w + page_size) // 128) * 128
-    n = tokens // page_size
-    if not window or n >= max_pages:
+    n = chunk_run_pages(w, window, page_size, max_pages)
+    if n == max_pages:
         return page_table_q, jnp.int32(0)
     p0 = jnp.clip((start - window + 1) // page_size, 0, max_pages - n)
     return lax.dynamic_slice_in_dim(page_table_q, p0, n), p0
@@ -1137,7 +1147,7 @@ def _attend_by_rows(spec, page_size, q, k_slab, v_slab, page_table,
                 v_slab, page_table[c][None], page_size, kv_heads)
         start = q_pos[jnp.clip(r0, 0, r - 1)]
         with jax.named_scope("attention"):
-            if w % 128 == 0 and k_ctx.shape[1] % 128 == 0:
+            if flash_takes(w, k_ctx.shape[1]):
                 o_c = flash_attention(
                     q_c[None], k_ctx.astype(q.dtype), v_ctx.astype(q.dtype),
                     causal=True, scale=scale, starts=start[None],

@@ -202,11 +202,14 @@ def _gmu(params: dict, x, m):
 
 # ------------------------------------------------- differential attention
 def _query_halves(spec: ModelSpec, params: dict, x):
-    """x [R, D] -> the query halves as 128-wide heads [R, H, 128] float32:
-    half j of a pair in lanes j * 64 .., zeros in the other half."""
+    """x [R, D] -> the query halves as 128-wide heads [R, H, 128] in the
+    projection's type: half j of a pair in lanes j * 64 .., zeros in the
+    other half. Who attends with them widens them where its output takes
+    the queries' type (the paged decode kernel, the dense path); a chunk's
+    flash call takes them as they are."""
     r = x.shape[0]
     h, hd = spec.num_attention_heads, spec.head_dim
-    q = _proj(x, params, "q_proj").astype(F32).reshape(r, h // 2, 2, hd // 2)
+    q = _proj(x, params, "q_proj").reshape(r, h // 2, 2, hd // 2)
     zeros = jnp.zeros_like(q[:, :, 0])
     return jnp.stack([
         jnp.concatenate([q[:, :, 0], zeros], -1),
@@ -232,11 +235,13 @@ def _diff_out(spec: ModelSpec, params: dict, a, dtype):
 def _diff_attend(spec: ModelSpec, page_size: int, q, k_slab, v_slab,
                  page_table, q_pos, total_lens, rows: SsmRows, window: int,
                  kernels: bool):
-    """q [R, H, 128] float32 against the slabs' pages, sequence by sequence
-    as `rows` tells them apart: -> [R, H, 128] float32. A sequence with one
-    row streams its pages through the paged decode kernel (pages below its
-    window skipped whole); one with more runs the flash kernel over its
-    gathered pages, under a window only the pages its rows' windows span.
+    """q [R, H, 128] in the projection's type against the slabs' pages,
+    sequence by sequence as `rows` tells them apart: -> [R, H, 128] float32,
+    for the differential subtraction. A sequence with one row streams its
+    pages through the paged decode kernel on float32 queries (pages below
+    its window skipped whole); one with more runs the flash kernel over its
+    gathered pages with the queries as they are and a float32 output, under
+    a window only the pages its rows' windows span.
     Without kernels: dense scores over every sequence's pages."""
     r, h, hd = q.shape
     kvh = spec.num_key_value_heads
@@ -249,10 +254,13 @@ def _diff_attend(spec: ModelSpec, page_size: int, q, k_slab, v_slab,
                 v_slab, page_table, page_size, kvh).astype(F32)
         with jax.named_scope("attention"):
             return attend_ragged(
-                spec, q, k_ctx, v_ctx, q_pos, rows.q_seq, total_lens,
-                jnp.int32(window),
+                spec, q.astype(F32), k_ctx, v_ctx, q_pos, rows.q_seq,
+                total_lens, jnp.int32(window),
             )
-    from bloombee_tpu.ops.pallas.flash_attention import flash_attention
+    from bloombee_tpu.ops.pallas.flash_attention import (
+        flash_attention,
+        flash_takes,
+    )
     from bloombee_tpu.ops.pallas.paged_attention import paged_decode_attention
 
     out = jnp.zeros((r, h, hd), F32)
@@ -261,7 +269,8 @@ def _diff_attend(spec: ModelSpec, page_size: int, q, k_slab, v_slab,
         at = jnp.clip(rows.row0, 0, r - 1)
         with jax.named_scope("attention"):
             o = paged_decode_attention(
-                q[at], heads_view(k_slab, kvh), heads_view(v_slab, kvh),
+                q[at].astype(F32), heads_view(k_slab, kvh),
+                heads_view(v_slab, kvh),
                 page_table, jnp.where(one, total_lens, 0),
                 page_size=page_size,
                 scale=scale, interpret=env.get("BBTPU_PAGED_INTERPRET"),
@@ -281,16 +290,18 @@ def _diff_attend(spec: ModelSpec, page_size: int, q, k_slab, v_slab,
             v_ctx = gather_pages(v_slab, pages[None], page_size, kvh)
         shift = p0 * page_size
         with jax.named_scope("attention"):
-            if w % 128 == 0 and k_ctx.shape[1] % 128 == 0:
+            if flash_takes(w, k_ctx.shape[1]):
                 o_c = flash_attention(
-                    q_c[None], k_ctx, v_ctx, causal=True, scale=scale,
-                    starts=(start - shift)[None],
+                    q_c[None], k_ctx.astype(q.dtype), v_ctx.astype(q.dtype),
+                    causal=True, scale=scale, starts=(start - shift)[None],
                     lens=(total_lens[c] - shift)[None], window=window,
+                    out_dtype=F32,
                     interpret=env.get("BBTPU_FLASH_INTERPRET"),
                 )[0]
             else:  # a bucket under the flash kernel's tile (a short tail)
                 o_c = attend_ragged(
-                    spec, q_c, k_ctx.astype(F32), v_ctx.astype(F32),
+                    spec, q_c.astype(F32), k_ctx.astype(F32),
+                    v_ctx.astype(F32),
                     start - shift + jnp.arange(w, dtype=jnp.int32),
                     jnp.zeros((w,), jnp.int32),
                     (total_lens[c] - shift)[None], jnp.int32(window),
@@ -327,7 +338,7 @@ def _cross_attention(spec, page_size, params, x, k_slab, v_slab, page_table,
     against the shared layer's pages, causally: -> [C, D] after o_proj."""
     with jax.named_scope("cross_attention"):
         with jax.named_scope("attn_proj"):
-            q = _query_halves(spec, params, x)
+            q = _query_halves(spec, params, x).astype(F32)
         n_seqs = page_table.shape[0]
         kvh = spec.num_key_value_heads
         real = (q_seq >= 0) & (q_seq < n_seqs)

@@ -734,6 +734,15 @@ def pack_ragged_plan(
     return np.concatenate(parts)
 
 
+def pack_chunk_on_flash(spec: ModelSpec) -> bool:
+    """Whether a fused pack's ONE chunk attends through the flash kernel,
+    sequence by sequence, where kernels run: the full layers among linear
+    ones (layer_body.py `_attend_by_rows`) and a SambaY span
+    (runtime/sambay.py `_diff_attend`). Every other family's pack takes the
+    ragged paged kernel, latent attention its own flash form."""
+    return spec.gdn is not None or spec.mamba is not None
+
+
 def pack_ragged_ssm_tail(state_slots, row0, nt, chunk_seq: int):
     """What a ragged plan of a family with recurrent state ends with: per
     sequence its state slot, its first row and its real row count, then THE
@@ -813,7 +822,8 @@ def span_step_ragged_impl(
     # latent attention, and the full layers among linear ones (their packs'
     # contexts are long: layer_body.py `_attend_by_rows`), attend by rows
     rows = (
-        ssm_rows if spec.mla is not None or spec.gdn is not None else None
+        ssm_rows if spec.mla is not None or pack_chunk_on_flash(spec)
+        else None
     )
     if state is None:
         state_slots = ssm_rows = None

@@ -2142,6 +2142,12 @@ class BlockServer(PromotionLoopMixin):
                         self.executor.kv_held["window_dead_tokens"]}
                    if any(self.executor.windows) else {}),
             },
+            # the tile the last chunk's flash kernel multiplied, by layer
+            # kind (runtime/executor.py `_flash_form`)
+            **(
+                {"flash": self.executor.flash_form}
+                if self.executor.flash_form else {}
+            ),
             # a share of the experts held: what the steps read so far
             # reached of it (sums over steps and sparse layers; per sparse
             # layer the distinct held experts the last step's rows chose)

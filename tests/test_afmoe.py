@@ -333,6 +333,53 @@ def test_chunked_prefill_then_decode_through_the_cache_match_the_family_file(
         max(s - 32, 0) for s in starts)
 
 
+def test_a_chunk_steps_span_says_the_tile_its_flash_kernel_multiplied(
+        span, monkeypatch):
+    """`bbtpu.step` of a chunk that attended through flash carries `flash`,
+    the rule's tile by layer kind at the step's shapes; a decode step and a
+    chunk on the dense path carry none; `flash_form` keeps the last one for
+    `rpc_info` and `health --probe`."""
+    from bloombee_tpu.ops.pallas.flash_attention import flash_tiles
+    from bloombee_tpu.utils import jitwatch
+
+    monkeypatch.setenv("BBTPU_JITWATCH", "1")
+    seen = []
+    real = jitwatch.span
+
+    def stamped(name, **ids):
+        if name == "bbtpu.step":
+            seen.append(ids)
+        return real(name, **ids)
+
+    monkeypatch.setattr(jitwatch, "span", stamped)
+    h = _hidden(5, 129)
+
+    async def run(ex):
+        async with ex.manager.allocate(1, 136) as handle:
+            ex.prefill_chunked(handle, h[:, :128], 128)
+            ex.decode(handle, h[:, 128:])
+
+    ex = _executor(span)
+    asyncio.run(run(ex))
+    assert [s["kind"] for s in seen] == ["chunk", "decode"]
+    assert not any("flash" in s for s in seen) and ex.flash_form is None
+    del seen[:]
+    for k, v in KERNELS.items():
+        monkeypatch.setenv(k, v)
+    ex = _executor(span)
+    spec = span[1]
+    asyncio.run(run(ex))
+    assert [s["kind"] for s in seen] == ["chunk", "decode"]
+    n_rep = spec.num_attention_heads // spec.num_key_value_heads
+    # the chunk's 128 tokens fill the 8-page bucket, which a 32-token
+    # window's run of whole 512-key blocks would pass: both kinds gather the
+    # bucket's 128 keys
+    want = "x".join(map(str, flash_tiles(128, 128, n_rep, spec.head_dim, 4)))
+    assert seen[0]["flash"] == f"window:{want},full:{want}"
+    assert "flash" not in seen[1]
+    assert ex.flash_form == seen[0]["flash"]
+
+
 def test_fused_pack_and_decode_group_match_the_family_file(ckpt, span):
     """The ragged pack (one sequence's chunk beside another's decode row)
     and the packed decode group, each row against its own sequence's

@@ -49,6 +49,7 @@ from bloombee_tpu.ops.pallas.paged_attention import (  # noqa: E402
     paged_decode_attention_int4,
     paged_ragged_attention,
 )
+from bloombee_tpu.runtime.layer_body import chunk_run_pages  # noqa: E402
 from bloombee_tpu.runtime.step import (  # noqa: E402
     span_step_packed,
     span_step_ragged,
@@ -134,6 +135,36 @@ def _kernel_cases():
              ((1, 512, HKV, HD), bf16), ((1,), i32), ((1,), i32)],
         ),
     }
+    # the cells' flash calls, one sequence's chunk each, at the tile
+    # `flash_tiles` gives the shape and over the keys the serving path
+    # gathers (`chunk_run_pages` at the cell's page bucket: 5,120 under
+    # Trinity's window, 1,536 under phi4flash's, the bucket without one):
+    # Trinity's window and full layers, Qwen3-Next's full layer at head_dim
+    # 256, phi4flash's windowed call on query halves with the float32
+    # output and its full layer, the two 128-row cells; and the two window
+    # runs as they were gathered until PR 48 (37 and 9 K blocks of 128: the
+    # only block that divides them)
+    for name, (t, keys, heads, kv_heads, hd, window, out) in {
+        "trinity_window": (512, 1024 * PAGE, 48, 8, 128, 4096, None),
+        "trinity_full": (512, 1024 * PAGE, 48, 8, 128, 0, None),
+        "qwen3next_full": (512, 1024 * PAGE, 16, 2, 256, 0, None),
+        "phi4flash_window": (512, 1024 * PAGE, 40, 10, 128, 512, jnp.float32),
+        "phi4flash_full": (512, 1024 * PAGE, 40, 10, 128, 0, jnp.float32),
+        "falconh1_t128": (128, 256 * PAGE, 20, 4, 128, 0, None),
+        "qwen3moe_t128": (128, 256 * PAGE, 32, 4, 128, 0, None),
+        "trinity_window_4736": (512, 4736, 48, 8, 128, 4096, None),
+        "phi4flash_window_1152": (512, 1152, 40, 10, 128, 512, jnp.float32),
+    }.items():
+        s = PAGE * chunk_run_pages(t, window, PAGE, keys // PAGE)
+        cases[f"flash_{name}"] = (
+            functools.partial(
+                lambda q, k, v, st, ln, window, out: flash_attention(
+                    q, k, v, causal=True, starts=st, lens=ln, window=window,
+                    out_dtype=out,
+                ), window=window, out=out),
+            [((1, t, heads, hd), bf16), ((1, s, kv_heads, hd), bf16),
+             ((1, s, kv_heads, hd), bf16), ((1,), i32), ((1,), i32)],
+        )
     # the cells' decode steps: one to four rows at the 4096-token page
     # bucket of a 1280-page arena, 8 pages a grid step at 8 KV heads and
     # one at 4 (`_pages_per_step`)

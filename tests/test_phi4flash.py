@@ -424,6 +424,39 @@ def test_kernel_paths_equal_the_reference(ckpt, span, monkeypatch):
     keep = np.r_[0:16, 22:27]
     np.testing.assert_allclose(got[keep], want[keep], rtol=1e-3, atol=1e-4)
     assert ex.attn_dispatches["paged"] == 5 and ex.kernel_fallbacks == 0
+    # 16- and 8-row chunks are under the flash kernel's block: the layers
+    # took their dense tail, and the executor says no tile
+    assert ex.flash_form is None
+
+
+@pytest.mark.parametrize("layers,kinds", [
+    ((0, 8), ("window", "full")), ((0, 4), ("window",)), ((4, 8), ("full",)),
+])
+def test_the_flash_form_names_the_attention_layers_a_span_holds(
+        ckpt, layers, kinds):
+    """`_flash_form` goes by the layers that attend: a span of (mamba,
+    window) pairs alone has no full layer to name, the cross-decoder's
+    span (the full layer and the cross layers that read it) no window; each
+    kind's tile is the rule's at the run `_diff_attend` gathers, and a
+    chunk under the kernel's block has none."""
+    from bloombee_tpu.ops.pallas.flash_attention import flash_tiles
+    from bloombee_tpu.runtime.layer_body import chunk_run_pages
+
+    params, spec = load_span_params(str(ckpt), *layers, dtype=jnp.float32)
+    manager = _manager(spec, layers, pages=512)
+    ex = SpanExecutor(params, spec, manager, compute_dtype=jnp.float32,
+                      start_block=layers[0])
+    want = {}
+    for kind in kinds:
+        window = spec.sliding_window if kind == "window" else 0
+        keys = 4 * chunk_run_pages(128, window, 4, 256)
+        want[kind] = "x".join(map(str, flash_tiles(
+            128, keys, spec.gqa_groups, spec.head_dim, 4)))
+    assert ex._flash_form(128, 256) == ",".join(
+        f"{k}:{v}" for k, v in want.items())
+    assert ex.flash_form == ex._flash_form(128, 256)
+    assert ex._flash_form(16, 256) is None
+    assert ex.flash_form is not None  # the last tile a chunk had stays
 
 
 def test_paths_that_cut_or_copy_a_cache_are_refused(ckpt, span):

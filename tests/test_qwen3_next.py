@@ -249,6 +249,14 @@ def test_fused_pack_and_decode_group_match_the_family_file(
     assert ex.kernel_fallbacks == 0
     assert ex.attn_dispatches["ragged" if kernels else "dense"] >= 1
     assert (ex.moe_dispatches["grouped"] > 0) == kernels
+    # the pack's chunk attended through the flash kernel, 128 rows wide
+    # (90 in the bucket) over the 32-page bucket's 128 keys; the 13- and
+    # 9-row prefills before it took the dense tail
+    from bloombee_tpu.ops.pallas.flash_attention import flash_tiles
+
+    tile = "x".join(map(str, flash_tiles(
+        128, 128, span[1].gqa_groups, span[1].head_dim, 4)))
+    assert ex.flash_form == (f"full:{tile}" if kernels else None)
     # a share of the experts held: every layer is sparse and counted
     ex.fetch(jnp.zeros(()))
     assert len(ex.moe_reach["held_hit_last"]) == LAYERS
