@@ -8,6 +8,21 @@ scatters, reads are page gathers. XLA turns the donated scatter into an
 in-place HBM update — the slab-write-vs-cat win of the reference's arch reform
 (tests/bench_arch_reform.py) is the default here.
 
+What a scatter costs on the device is its INDICES, not its bytes (about 75 ns
+an index: PERF.md section 6, PR 49), so `arena_write` has two forms of one
+write. A decode row, tree rows, a chunk that starts inside a page and an int4
+slab go row by row, one index a (token, head) row of a folded slab and one a
+token otherwise. A dispatch whose rows come as PAGE GROUPS goes page by page,
+one index a page, through the slab's [pages, page_size * n_kv, hd] view (the
+paged kernels' own): the host sees it in the slots it already has
+(`rows_fill_pages`: every page_size rows of the bucket are one page's leading
+tokens from its first, or padding; a prompt chunk that starts on a page
+boundary is that) and hands the slots down as `PageSlots`; a page the rows
+fill only in part is read, its real rows replaced and written back, so the
+slab afterwards is the row scatter's bit for bit. `page_view_free` says by the
+slab's shape where that view is a bitcast on the device; a slab where it is
+not keeps the row scatter.
+
 Layout: per layer, a flat slot dimension of num_pages * page_size tokens:
     k, v: [L, num_pages * page_size, n_kv_heads, head_dim]
 Slot ids come from the host-side PagedKVTable (page * page_size + offset).
@@ -49,6 +64,8 @@ family or resets the slot (kv/cache_manager.py).
 """
 
 from __future__ import annotations
+
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -163,6 +180,62 @@ def slot_rows(slots, fold: int):
     ).reshape(-1)
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class PageSlots:
+    """A dispatch's token slots [N] that come as PAGE GROUPS, as
+    `rows_fill_pages` says on the host: rows [g * page_size, (g + 1) *
+    page_size) are the leading tokens of ONE page from its first, the rest of
+    the group (and any whole group) out of range. `arena_write` writes such
+    rows one index a page; everything else that takes slots (`layer_slots`)
+    hands the type through."""
+
+    slots: jax.Array
+    page_size: int = dataclasses.field(metadata={"static": True})
+
+
+def rows_fill_pages(slots: np.ndarray, page_size: int, oob: int) -> bool:
+    """The rule of the page write, read on the host from a dispatch's padded
+    slots (row order as the program gets them; `oob` and above: a padding
+    row): every group of `page_size` rows is one page's leading tokens, in
+    order from the page's first slot, then padding, or padding alone, and
+    some group holds more than one token. A prompt chunk that starts on a
+    page boundary reads so whatever its length; a decode row, tree rows, a
+    chunk that starts inside a page and a pack with single rows before its
+    chunk do not."""
+    flat = np.ravel(slots)
+    if flat.size < page_size or flat.size % page_size:
+        return False
+    groups = flat.reshape(-1, page_size)
+    first = groups[:, :1]
+    real = groups < oob
+    runs_on = groups == first + np.arange(page_size, dtype=flat.dtype)
+    return bool(
+        np.all((first % page_size == 0) | ~real[:, :1])
+        and np.all(runs_on | ~real)  # (so no row is real after a padding first)
+        and real[:, 1:].any()
+    )
+
+
+def page_view_free(slab_shape: tuple, dtype) -> bool:
+    """Whether a plain slab of this shape (a layer's, or the flat arena's:
+    the leading dimension does not matter) can be viewed as pages, [pages,
+    page_size * the rows a token takes, lanes], by a bitcast in the device's
+    tiled layout, so that a write through the view moves nothing
+    (tests/test_chip_compile.py compiles both sides for a described v5e).
+    The minor dimension must be whole lanes of a 2- or 4-byte type; a folded
+    slab and a latent page, [rows, lanes], then always are; a slab of K/V
+    heads [S, n_kv, hd] is where `folds` leaves it unfolded BECAUSE its bytes
+    are the folded slab's (a folding shape stored unfolded, as under a
+    `--tp` mesh, is not, and keeps the row scatter)."""
+    lanes = slab_shape[-1]
+    if lanes % LANES or jnp.dtype(dtype).itemsize not in (2, 4):
+        return False
+    if len(slab_shape) == 3:
+        return not folds(slab_shape[1], lanes, dtype)
+    return len(slab_shape) == 2
+
+
 def make_state_arena(
     num_layers: int, num_slots: int, ssm, conv_dtype=jnp.bfloat16
 ) -> dict:
@@ -217,7 +290,12 @@ def layer_slots(slots: jax.Array, layer, s_tot: int, num_layers: int):
     """Layer `layer`'s slot ids inside the flat arena: `slots + layer * S_tot`.
     A padding row's id (anything outside [0, S_tot)) maps past the END of the
     flat arena, so `mode="drop"` still discards it — offset naively, slot
-    S_tot of layer l would be slot 0 of layer l + 1."""
+    S_tot of layer l would be slot 0 of layer l + 1. `PageSlots` stay so
+    (a layer's pages start at a multiple of page_size in the flat arena)."""
+    if isinstance(slots, PageSlots):
+        return dataclasses.replace(
+            slots, slots=layer_slots(slots.slots, layer, s_tot, num_layers)
+        )
     valid = (slots >= 0) & (slots < s_tot)
     return jnp.where(valid, slots + layer * s_tot, num_layers * s_tot)
 
@@ -228,14 +306,35 @@ def layer_pages(page_table: jax.Array, layer, num_pages: int):
     return page_table + layer * num_pages
 
 
+def _write_pages(slab, slots: PageSlots, new):
+    """`new` [N, ...] written into `slab` (its page view free:
+    `page_view_free`) one index a PAGE. A page the rows fill in part (a
+    chunk's tail) is read, its real rows replaced, and written back; a group
+    of padding rows alone has its page id out of range and is dropped, like
+    a padding row of the row scatter."""
+    page_size = slots.page_size
+    n = slots.slots.shape[0]
+    lanes = slab.shape[-1]
+    token_rows = new.size // (n * lanes)  # the K/V heads, or 1 (a latent)
+    pages = slab.reshape(-1, page_size * token_rows, lanes)
+    groups = slots.slots.reshape(n // page_size, page_size)
+    page_ids = groups[:, 0] // page_size
+    real = groups < pages.shape[0] * page_size
+    real = jnp.repeat(real, token_rows, axis=1)[:, :, None]
+    new = new.reshape(-1, *pages.shape[1:]).astype(slab.dtype)
+    old = pages.at[page_ids].get(mode="clip")
+    pages = pages.at[page_ids].set(jnp.where(real, new, old), mode="drop")
+    return pages.reshape(slab.shape)
+
+
 def arena_write(
     k_layer: jax.Array,  # [S, n_kv, hd]: a layer's slab or the flat arena
     v_layer: jax.Array,
-    slots: jax.Array,  # [N] int32 flat slot ids
+    slots,  # [N] int32 flat slot ids, or PageSlots of them
     k_new: jax.Array,  # [N, n_kv, hd]
     v_new: jax.Array,
 ) -> tuple[jax.Array, jax.Array]:
-    """Scatter new KV rows into a slab (functional; donate the slab).
+    """Write new KV rows into a slab (functional; donate the slab).
 
     Out-of-bounds slot ids are dropped — the span step points padding rows at
     slot == num_slots to discard their writes (`layer_slots` keeps them out
@@ -243,8 +342,25 @@ def arena_write(
 
     A folded slab [S * n_kv, hd] takes the same TOKEN slots and rows: each
     token's heads go to rows slot * n_kv + head.
+
+    `PageSlots` (the host saw that the rows come as page groups) are written
+    one index a page wherever the slab's page view is free (`page_view_free`:
+    every plain slab the cells hold); plain slots, an int4 slab and a slab
+    whose page view would move it are scattered row by row. The slab
+    afterwards is the same either way, bit for bit.
     """
     from bloombee_tpu.kv.quant import QuantSlab, quantize
+
+    if isinstance(slots, PageSlots):
+        if not isinstance(k_layer, QuantSlab) and all(
+            page_view_free(slab.shape, slab.dtype)
+            for slab in (k_layer, v_layer)
+        ):
+            return (
+                _write_pages(k_layer, slots, k_new),
+                _write_pages(v_layer, slots, v_new),
+            )
+        slots = slots.slots
 
     if is_folded(k_layer) and k_new.ndim == 3:
         # (the rows themselves are reshaped below, to the slab's own rows)

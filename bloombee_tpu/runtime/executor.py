@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 import ml_dtypes
 
+from bloombee_tpu.kv.arena import page_view_free, rows_fill_pages
 from bloombee_tpu.kv.cache_manager import CacheHandle, CacheManager
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.runtime.step import (
@@ -463,6 +464,24 @@ class SpanExecutor:
         # for them, and those in window layers' pages that no later query
         # can see (what a page table a layer kind would free)
         self.kv_held = {"kv_held_tokens": 0, "window_dead_tokens": 0}
+        # dispatches that held a sequence of more than one row, by how its
+        # K/V rows went into the arena: one index a page (`_page_groups`)
+        # or one a row
+        self.kv_writes = {"chunk_page_writes": 0, "chunk_row_writes": 0}
+        # whether both slabs' page view is free (kv/arena.py
+        # `page_view_free`, by their shape; an int4 arena, one sharded over
+        # a mesh and a heterogeneous span's per-layer slabs keep the row
+        # scatter)
+        self._page_view_free = bool(
+            manager.quant is None
+            and mesh is None
+            and not spec.heterogeneous
+            and all(
+                page_view_free(manager.arena[key].shape[1:],
+                               manager.arena[key].dtype)
+                for key in ("k", "v")
+            )
+        )
         self._window_layers = sum(w > 0 for w in self.windows)
         # a chunk may attend through flash where no layer has a window, or
         # every window is the family's `flash_window` (window layers among
@@ -1029,6 +1048,7 @@ class SpanExecutor:
         # where a pack's one chunk attends through the flash kernel, it is
         # rb rows wide there (runtime/step.py `pack_chunk_on_flash`)
         flash_now = used_kernel and pack_chunk_on_flash(spec)
+        self.kv_writes["chunk_row_writes"] += 1
         out = self._keep_arena(
             result, "fused", r, starts, self._count_moe(rb, used_kernel),
             cross_rows=None if cross_idx is None else len(cross_idx),
@@ -1259,7 +1279,7 @@ class SpanExecutor:
     def _keep_arena(self, result, kind: str, rows: int, starts,
                     experts: str | None = None,
                     cross_rows: int | None = None,
-                    flash: str | None = None):
+                    flash: str | None = None, write: str = "rows"):
         """Store a span step's returned arenas (K, V and, where the family
         has one, the state arena) on the manager; returns the step's output.
         A step of a latent-attention family, of one with linear-attention
@@ -1271,7 +1291,9 @@ class SpanExecutor:
         context, and a trace's reader has to know WHICH steps it holds;
         `experts` is the form its experts took (`_count_moe`), `arena` the
         K/V slabs' layout ("folded" | "unfolded": kv/arena.py `folds`),
-        `flash` the tile a chunk's flash kernel multiplied (`_flash_form`).
+        `flash` the tile a chunk's flash kernel multiplied (`_flash_form`),
+        `write` how the rows went into the arena ("pages" | "rows":
+        `_page_groups`).
         `kind` and `rows` are also kept beside what the rows reached of the
         held experts, where the step says it."""
         if self._window_layers:
@@ -1299,6 +1321,7 @@ class SpanExecutor:
                 "bbtpu.step", kind=kind, rows=rows,
                 context=int(np.mean(starts)),
                 arena="folded" if self.manager.folded else "unfolded",
+                write=write,
                 **({"experts": experts} if experts else {}),
                 **({"flash": flash} if flash else {}),
                 **({"cross_rows": cross_rows} if self._cross_layers else {}),
@@ -1362,6 +1385,17 @@ class SpanExecutor:
         form = self._flash_forms[key]
         self.flash_form = form or self.flash_form
         return form
+
+    def _page_groups(self, slots_pad: np.ndarray) -> bool:
+        """Whether a dispatch's K/V rows go into the arena one index a PAGE:
+        its padded slots come as page groups (kv/arena.py `rows_fill_pages`:
+        a prompt chunk that starts on a page boundary) and both slabs' page
+        view is free by their shape. Read from the dispatch's own slots and
+        shapes, as `use_flash` is; the step program takes the answer as a
+        static argument."""
+        return self._page_view_free and rows_fill_pages(
+            slots_pad, self.page_size, self.manager.capacity_tokens
+        )
 
     @staticmethod
     def _arena_consumed(arena) -> bool:
@@ -1698,6 +1732,10 @@ class SpanExecutor:
                 and _kernels_available("BBTPU_FLASH_INTERPRET")
             )
 
+            # the arena write by page: read from the slots (`_page_groups`);
+            # the offloaded step keeps the row scatter
+            page_groups = not self.host_layers and self._page_groups(slots_pad)
+
             attn_topk = 0
             if self.attn_sparsity < 1.0 and tb == 1 and tree_mask is None:
                 # decode-only approximation (FlexGen applies sparsity at
@@ -1797,6 +1835,7 @@ class SpanExecutor:
                         use_paged=use_paged and kernels_now,
                         t_real=t,
                         expert_kernels=flash_experts and kernels_now,
+                        page_groups=page_groups,
                     )
 
             result, kernels_used = self._dispatch(
@@ -1815,7 +1854,12 @@ class SpanExecutor:
                 self._count_moe(bb * tb, kernels_used),
                 cross_rows=cross_rows,
                 flash=self._flash_form(tb, pb) if flash_now else None,
+                write="pages" if page_groups else "rows",
             )
+        if t > 1:
+            self.kv_writes[
+                "chunk_page_writes" if page_groups else "chunk_row_writes"
+            ] += 1
         path = "paged" if use_paged else "flash" if use_flash else "dense"
         self.attn_dispatches[path] += 1
         with jitwatch.span("bbtpu.slice"):

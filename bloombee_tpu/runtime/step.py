@@ -12,8 +12,9 @@ The paged KV arena rides the scan's CARRY, whole, as one flat slab
 [L * S_tot, Hkv, hd] (a bitcast of the stored [L, S_tot, Hkv, hd]); the
 layer index rides as xs, and layer `l` writes at `slots + l * S_tot` and
 reads through `page_table + l * num_pages` (kv/arena.py `layer_slots` /
-`layer_pages`). So a layer scatters its 2-128 rows into the donated buffer
-in place and streams only its context's pages out of it. No step slices a
+`layer_pages`). So a layer scatters its rows into the donated buffer in
+place (a chunk whose rows come as page groups, `page_groups`, one index a
+PAGE: kv/arena.py `PageSlots`) and streams only its context's pages out of it. No step slices a
 layer's slab out of the arena or stacks one back: as xs/ys of the scan every
 layer would copy a whole slab out and in, and the donated buffer could not
 be shared between xs and ys, so every run would copy the whole arena too
@@ -48,6 +49,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from bloombee_tpu.kv.arena import (
+    PageSlots,
     arena_tokens,
     flat_arena,
     layer_pages,
@@ -166,6 +168,7 @@ def span_step_packed_impl(
     attn_topk: int = 0,
     t_real: int | None = None,
     expert_kernels: bool = False,
+    page_groups: bool = False,
 ):
     """span_step over a pack_step_payload buffer (one h2d per step)."""
     hidden, plan = unpack_step_payload(payload, b, t, spec.hidden_size)
@@ -175,7 +178,7 @@ def span_step_packed_impl(
         spec=spec, page_size=page_size, max_pages=max_pages,
         use_tree_mask=use_tree_mask, windows=windows, use_flash=use_flash,
         use_paged=use_paged, attn_topk=attn_topk, t_real=t_real,
-        expert_kernels=expert_kernels,
+        expert_kernels=expert_kernels, page_groups=page_groups,
     )
 
 
@@ -184,6 +187,7 @@ span_step_packed = functools.partial(
     static_argnames=(
         "spec", "b", "t", "page_size", "max_pages", "use_tree_mask",
         "windows", "use_flash", "use_paged", "attn_topk", "expert_kernels",
+        "page_groups",
     ),
     donate_argnames=("arena_k", "arena_v", "state"),
 )(span_step_packed_impl)
@@ -566,6 +570,9 @@ def span_step_impl(
     t_real: int | None = None,
     expert_kernels: bool = False,  # the experts' kernels may run though the
     # paged attention kernels do not (a chunk that attends through flash)
+    page_groups: bool = False,  # the host read in the slots that the rows
+    # come as page groups (kv/arena.py `rows_fill_pages`): they are handed
+    # down as `PageSlots`, which `arena_write` writes one index a page
 ):
     """Run all local blocks over one step; returns (hidden, arena_k, arena_v)
     and, given a state arena, that as a fourth value.
@@ -584,11 +591,13 @@ def span_step_impl(
     if spec.mamba is not None:
         return _sambay_packed(
             stacked_params, arena_k, arena_v, hidden, plan, state, spec, n,
-            page_size, max_pages, use_paged, t_real,
+            page_size, max_pages, use_paged, t_real, page_groups,
         )
     slots, page_table, q_positions, total_lens, layer_active = unpack_plan(
         plan, b, t, max_pages, n
     )
+    if page_groups:
+        slots = PageSlots(slots, page_size)
     rope = _rope_by_window(spec, q_positions, hidden.dtype)
     tm = tree_mask if use_tree_mask else None
     windows_arr = jnp.asarray(
@@ -627,7 +636,8 @@ def span_step_impl(
 
 
 def _sambay_packed(stacked_params, arena_k, arena_v, hidden, plan, state,
-                   spec, n, page_size, max_pages, kernels, t_real):
+                   spec, n, page_size, max_pages, kernels, t_real,
+                   page_groups):
     """A SambaY span's packed [B, T] step (runtime/sambay.py): the plan ends
     with each row's state slot [B] and, for T > 1, the flat rows the caller
     reads and their count (`pack_cross_tail`); a decode step's rows are all
@@ -643,6 +653,8 @@ def _sambay_packed(stacked_params, arena_k, arena_v, hidden, plan, state,
         plan, b, t, max_pages, n
     )
     state_slots = plan[-b:]
+    if page_groups:
+        slots = PageSlots(slots, page_size)
     rows = packed_ssm_rows(
         b, t, q_positions, state_slots, state["ssm"].shape[1], t_real
     )
@@ -671,6 +683,7 @@ span_step = functools.partial(
     static_argnames=(
         "spec", "page_size", "max_pages", "use_tree_mask", "windows",
         "use_flash", "use_paged", "attn_topk", "expert_kernels",
+        "page_groups",
     ),
     donate_argnames=("arena_k", "arena_v", "state"),
 )(span_step_impl)

@@ -2136,6 +2136,10 @@ class BlockServer(PromotionLoopMixin):
             # (what a page table a layer kind would free)
             "kv": {
                 "folded": self.manager.folded,
+                # dispatches that held a sequence of more than one row, by
+                # how its rows went into the arena (one index a page where
+                # they come as page groups: kv/arena.py `rows_fill_pages`)
+                **self.executor.kv_writes,
                 **({
                     "held_tokens": self.executor.kv_held["kv_held_tokens"],
                     "window_dead_tokens":
@@ -2230,6 +2234,8 @@ class BlockServer(PromotionLoopMixin):
         # operator-pollable memory accounting (reference memory_usage.py's
         # logging surface, as a remote field instead of a local probe)
         info["memory"] = server_memory_report(self)
+        # (once more where a reader that keeps `memory` alone finds it)
+        info["memory"]["kv_writes"] = dict(self.executor.kv_writes)
         if self.spec.mamba is not None or any(self.executor.windows):
             # the window-dead accounting and the SambaY counters once more,
             # beside the K/V arena they are about (a reader that keeps a

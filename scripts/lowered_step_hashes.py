@@ -1,6 +1,8 @@
 #!/usr/bin/env python3
-"""Lower the six older cells' step programs (decode, chunk, tail, fused, at
-the cells' shapes, as tests/test_chip_compile.py builds them) for a described
+"""Lower the seven cells' step programs (decode, chunk, tail, fused, at
+the cells' shapes, as tests/test_chip_compile.py and tests/test_afmoe.py
+build them; every chunk with the row scatter, `page_groups` off: what a
+chunk that starts inside a page still runs) for a described
 v5e in the tree this runs from (cwd), and print one sha256 of the StableHLO
 text a program, the Pallas kernels' serialized bodies cut out (they embed the
 source files' paths and line numbers: compare `git diff -- bloombee_tpu/ops/
@@ -74,4 +76,13 @@ h("phi4flash.fused", span_step_ragged.lower(params, arena, arena, T._cell_payloa
 for prog, (b, t, t_real) in {"decode": (4, 1, 1), "chunk": (1, 512, 512), "tail": (1, 8, 5)}.items():
     plan_len = b * t + b * pages + b * t + b + layers + b + (b * t + 1 if t > 1 else 0)
     h(f"phi4flash.{prog}", span_step_packed.lower(params, arena, arena, T._cell_payload(spec, b * t, plan_len, one), None, None, state, b=b, t=t, use_paged=True, t_real=t_real, **common))
+
+# trinity (afmoe; PR 47's cell, added here in PR 49): 1024-page bucket
+import test_afmoe as A
+spec, params, arena = A._cell_shapes(one)
+layers, pages = 5, 1024
+common = dict(spec=spec, page_size=16, max_pages=pages, windows=windows(spec, layers))
+for prog, (b, t, case) in {"decode": (4, 1, dict(use_paged=True)), "chunk": (1, 512, dict(use_flash=True, t_real=512, expert_kernels=True)), "tail": (1, 8, dict(use_paged=True, t_real=5))}.items():
+    plan_len = b * t + b * pages + b * t + b + layers
+    h(f"trinity.{prog}", span_step_packed.lower(params, arena, arena, T._cell_payload(spec, b * t, plan_len, one), None, None, None, b=b, t=t, **case, **common))
 print(json.dumps(out, indent=0))

@@ -588,8 +588,10 @@ def test_cell_span_step_compiles_for_v5e_and_copies_no_parameter(
     windows = tuple(spec.window_for_layer(i) for i in range(layers))
     b, t, case = {
         "decode": (4, 1, dict(use_paged=True)),
+        # (a chunk's rows come as page groups: written by page, PR 49)
         "chunk": (1, 512, dict(
-            use_flash=True, t_real=512, expert_kernels=True)),
+            use_flash=True, t_real=512, expert_kernels=True,
+            page_groups=True)),
         "tail": (1, 8, dict(use_paged=True, t_real=5)),
     }[program]
     plan_len = b * t + b * pages + b * t + b + layers
@@ -613,4 +615,13 @@ def test_cell_span_step_compiles_for_v5e_and_copies_no_parameter(
             assert f"{kind}/arena_gather" in text
     # no temporary the size of a layer's held stack (1.8 GB)
     assert compiled.memory_analysis().temp_size_in_bytes < 300e6
+    # no program moves a slab, and the chunk's K/V go into the arena one
+    # index a PAGE (32 a slab, where the row scatter takes 512): PR 49
+    from test_chip_compile import _scatter_indices, _slab_moves
+
+    slab = arena.shape[1] * arena.shape[2] * arena.shape[3]
+    assert not _slab_moves(text, slab), _slab_moves(text, slab)
+    writes = _scatter_indices(text)  # (one more, of 32: the reach counters)
+    assert writes.count({"decode": 4, "chunk": 32, "tail": 8}[program]) >= 4
+    assert 512 not in writes, writes
 

@@ -9,7 +9,11 @@ on a tiny preset added to a copy of the benchmark by files only: `correct`
 true; false with an int8-weight server (the control); false with each fault
 of `scripts/plant_sambay_fault.py` planted in a copy of the program. The
 pinned values were produced by this file's own code when the family was added
-(PR 45): a later edit that moves one has to say so here.
+(PR 45): a later edit that moves one has to say so here. PR 49 added a
+second preset, the same at hidden size 256 (`WIDE_PHI4FLASH`), whose K/V
+pair is 128 wide (whole lanes, as the published pair), so that the sound
+rehearsal writes its chunks into the arena by page (`page_write_share`);
+the controls keep the narrow one.
 """
 
 from __future__ import annotations
@@ -43,6 +47,10 @@ TINY_PHI4FLASH = {
     "hidden_act": "silu", "embd_pdrop": 0, "resid_pdrop": 0,
     "torch_dtype": "bfloat16",
 }
+
+# a K/V pair of 128 lanes: its slab has a free page view (kv/arena.py
+# `page_view_free`), so its chunks go into the arena by page
+WIDE_PHI4FLASH = dict(TINY_PHI4FLASH, hidden_size=256)
 
 PHI_PINS = {
     "plan": (
@@ -150,7 +158,8 @@ def test_sambay_metrics_read_nothing_where_there_is_no_trace(tmp_path):
            "info0": {}, "info1": {}}
     for name in ("chunk_mamba_ms_p50", "step_mamba_ms_p50",
                  "mamba_scan_roofline", "step_cross_ms_p50",
-                 "cross_rows_share", "window_dead_share"):
+                 "cross_rows_share", "window_dead_share",
+                 "page_write_share"):  # (a program without the counter)
         assert cell_run.read_metric(name, dict(ctx)) is None
 
 
@@ -171,16 +180,19 @@ def test_sambay_metrics_on_a_synthetic_reduction_and_counters():
            "device_kind": "TPU v5 lite", "_scopetrace_sambay": got,
            "info0": {"memory": {"sambay": {
                "cross_rows": 10, "self_rows": 100, "kv_held_tokens": 0,
-               "window_dead_tokens": 0}}},
+               "window_dead_tokens": 0}, "kv_writes": {
+               "chunk_page_writes": 40, "chunk_row_writes": 7}}},
            "info1": {"memory": {"sambay": {
                "cross_rows": 110, "self_rows": 10100, "kv_held_tokens": 900,
-               "window_dead_tokens": 720}}}}
+               "window_dead_tokens": 720}, "kv_writes": {
+               "chunk_page_writes": 115, "chunk_row_writes": 32}}}}
     read = lambda name: cell_run.read_metric(name, ctx)  # noqa: E731
     assert read("chunk_mamba_ms_p50") == 7.0
     assert read("step_mamba_ms_p50") == 1.25
     assert read("step_cross_ms_p50") == 2.0
     assert read("cross_rows_share") == pytest.approx(1.0)
     assert read("window_dead_share") == pytest.approx(80.0)
+    assert read("page_write_share") == pytest.approx(75.0)  # 75 of 100
     needs = families.of(_published()).mamba1_scan_needs(
         _published(), 512, "chunk")
     least = max(needs["bytes"] / 819e9, needs["flops"] / 197e12)
@@ -211,19 +223,23 @@ def tree_phi():
             "int8_projection_limit": 0.5})))
     (cb / "traffic" / "tiny-ctx.json").write_text(json.dumps({
         "loop": "closed", "sessions": 2, "stagger_s": 0.1,
-        "prompt_tokens": [300, 171, 260], "new_tokens": [4, 5, 4],
+        "prompt_tokens": [300, 171, 276], "new_tokens": [4, 5, 4],
         "judge": {"requests": 2, "new_tokens": 4}}))
-    (cb / "cells" / "tiny-phi4flash-ctx.json").write_text(
-        '{"num_pages": 128}')
-    bench["configs"].append(
-        {"name": "tiny-phi4flash", "source": "none", "reduced": [],
-         "file": "cellbench/configs/tiny-phi4flash.json", "why": "rehearsal"})
-    bench["workloads"].append(
-        {"name": "tiny-phi4flash-ctx", "config": "tiny-phi4flash",
-         "traffic": "tiny-ctx", "chips": 1, "why": "rehearsal"})
-    for metric in bench["end_to_end"] + bench["per_layer"]:
-        if "phi4flash-longctx" in metric.get("workloads", ()):
-            metric["workloads"].append("tiny-phi4flash-ctx")
+    wide = json.loads((cb / "configs" / "tiny-phi4flash.json").read_text())
+    wide.update(WIDE_PHI4FLASH)
+    wide["cellbench"]["uid"] = "tiny-phi4flash-wide"
+    (cb / "configs" / "tiny-phi4flash-wide.json").write_text(json.dumps(wide))
+    for name in ("tiny-phi4flash", "tiny-phi4flash-wide"):
+        (cb / "cells" / f"{name}-ctx.json").write_text('{"num_pages": 128}')
+        bench["configs"].append(
+            {"name": name, "source": "none", "reduced": [],
+             "file": f"cellbench/configs/{name}.json", "why": "rehearsal"})
+        bench["workloads"].append(
+            {"name": f"{name}-ctx", "config": name, "traffic": "tiny-ctx",
+             "chips": 1, "why": "rehearsal"})
+        for metric in bench["end_to_end"] + bench["per_layer"]:
+            if "phi4flash-longctx" in metric.get("workloads", ()):
+                metric["workloads"].append(f"{name}-ctx")
     (TREE_PHI / "BENCHMARK.json").write_text(json.dumps(bench))
     return TREE_PHI
 
@@ -234,7 +250,7 @@ PHI_LIMIT = 2e-5
 
 
 def test_phi4flash_cell_rehearsal_is_correct(tree_phi):
-    rc, last, out = _run(tree_phi, "--workload", "tiny-phi4flash-ctx",
+    rc, last, out = _run(tree_phi, "--workload", "tiny-phi4flash-wide-ctx",
                          "--seed", str(2**31 + 45), "--seconds", "3",
                          "--trace", "1")
     assert last is not None and rc == 0, out[-3000:]
@@ -246,6 +262,13 @@ def test_phi4flash_cell_rehearsal_is_correct(tree_phi):
     # the second exit is taken: a prompt's rows stop at the shared layer
     assert 0 < last["metrics"]["cross_rows_share"]["value"] < 20
     assert 0 < last["metrics"]["window_dead_share"]["value"] < 100
+    # the chunks' K/V went into the arena by page (the wide preset's K/V
+    # pair is 128 lanes, as the published one, so that its slab has a free
+    # page view: kv/arena.py `page_view_free`; every chunk starts on a page
+    # boundary and no tail is under a page). 100 where no pack formed in the
+    # window: a chunk that rides a fused pack behind decode rows writes by
+    # row (read 100.0 in my runs, PR 49)
+    assert last["metrics"]["page_write_share"]["value"] > 50
     # a CPU run reports no device metric under a device metric's name
     for name in ("chunk_mamba_ms_p50", "step_mamba_ms_p50",
                  "mamba_scan_roofline", "step_cross_ms_p50",
@@ -290,3 +313,57 @@ def test_phi4flash_cell_rehearsal_sees_a_planted_fault(
     assert last["failed"] == 0 and rc != 0
     err, limit = _compared(out)["logit_err_median"]
     assert err > 10 * limit, (err, limit)
+
+
+def test_a_prompt_by_page_decode_rows_and_a_prompt_inside_a_page(tmp_path):
+    """Executor level (PR 49): a prompt's chunk from a page's first token
+    goes into the arena by page, decode rows and a second prompt that starts
+    inside a page row by row (`chunk_row_writes` counts it), and every
+    step's output and both arenas are those of the parent's path, the row
+    scatter throughout, bit for bit."""
+    import asyncio
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bloombee_tpu.kv.cache_manager import CacheManager
+    from bloombee_tpu.models.checkpoint import load_span_params
+    from bloombee_tpu.runtime.executor import SpanExecutor
+
+    checkpoint.write_checkpoint(tmp_path, WIDE_PHI4FLASH, SEED)
+    layers = WIDE_PHI4FLASH["num_hidden_layers"]
+    params, spec = load_span_params(str(tmp_path), 0, layers, dtype=jnp.float32)
+    rng = np.random.default_rng(49)
+    hidden = (0.05 * rng.standard_normal(
+        (1, 55, spec.hidden_size))).astype(np.float32)
+
+    def drive(by_page: bool):
+        manager = CacheManager(
+            layers, 16, 16, spec.num_key_value_heads, spec.head_dim,
+            dtype=jnp.float32, ssm=spec.recurrent, state_slots=4,
+            arena_layers=spec.arena_layers(0, layers))
+        assert manager.arena["k"].shape[1:] == (16 * 16, 1, 128)
+        ex = SpanExecutor(params, spec, manager, compute_dtype=jnp.float32)
+        if not by_page:
+            ex._page_groups = lambda slots_pad: False
+
+        async def go():
+            async with manager.allocate(1, 64) as h:
+                outs = [ex.prefill_chunk(
+                    h, hidden[:, :32], commit=True, fetch=True)]
+                outs += [ex.decode(h, hidden[:, t:t + 1])
+                         for t in range(32, 35)]
+                outs.append(ex.prefill_chunk(
+                    h, hidden[:, 35:], commit=True, fetch=True))
+                return [np.asarray(o) for o in outs], [
+                    np.asarray(manager.arena[key]) for key in ("k", "v")]
+
+        return asyncio.run(go()), dict(ex.kv_writes)
+
+    (outs, arenas), writes = drive(True)
+    (want_outs, want_arenas), parent_writes = drive(False)
+    assert writes == {"chunk_page_writes": 1, "chunk_row_writes": 1}
+    assert parent_writes == {"chunk_page_writes": 0, "chunk_row_writes": 2}
+    for got, want in zip(outs + arenas, want_outs + want_arenas):
+        assert np.isfinite(got).all() and np.abs(got).max() > 0
+        np.testing.assert_array_equal(got, want)

@@ -292,8 +292,11 @@ _SPAN_STEPS = {
     # decode through the paged kernel, prefill chunk through flash, a short
     # chunk through the chunk kernel (what warm-up + chip_smoke dispatch)
     "decode_paged": dict(b=B, t=1, pages=NP, use_paged=True),
-    "prefill_flash": dict(b=1, t=128, pages=8, use_flash=True),
-    "chunk_paged": dict(b=1, t=64, pages=32, use_paged=True, t_real=44),
+    # (both chunks' rows come as page groups: written by page, PR 49)
+    "prefill_flash": dict(
+        b=1, t=128, pages=8, use_flash=True, page_groups=True),
+    "chunk_paged": dict(
+        b=1, t=64, pages=32, use_paged=True, t_real=44, page_groups=True),
 }
 
 
@@ -471,8 +474,11 @@ _FALCON_H1_STEPS = {
     # the paged kernel (one recurrence step a row), a full chunk through
     # flash (the SSD chunk form), a tail through the chunk kernel
     "decode_paged": dict(b=4, t=1, pages=256, use_paged=True),
-    "prefill_flash": dict(b=1, t=128, pages=256, use_flash=True, t_real=128),
-    "tail_paged": dict(b=1, t=64, pages=256, use_paged=True, t_real=44),
+    # (both chunks' rows come as page groups: written by page, PR 49)
+    "prefill_flash": dict(
+        b=1, t=128, pages=256, use_flash=True, t_real=128, page_groups=True),
+    "tail_paged": dict(
+        b=1, t=64, pages=256, use_paged=True, t_real=44, page_groups=True),
 }
 
 
@@ -557,7 +563,8 @@ def test_cell_span_step_copies_no_parameter(v5e, cell, program):
     else:
         b, t, case = (
             (b, 1, dict(use_paged=True)) if program == "decode"
-            else (1, 128, dict(use_flash=True, t_real=128))
+            else (1, 128, dict(
+                use_flash=True, t_real=128, page_groups=True))
         )
         plan_len = b * t + b * pages + b * t + b + layers
         plan_len += 0 if state is None else b
@@ -687,7 +694,8 @@ def test_deepseek_v2_span_step_compiles_and_copies_no_parameter(v5e, program):
         compiled = span_step_packed.lower(
             params, latent, rotary,
             _cell_payload(spec, b * t, plan_len, one_chip), None, None, None,
-            b=b, t=t, use_paged=True, t_real=None if t == 1 else t, **common,
+            b=b, t=t, use_paged=True, t_real=None if t == 1 else t,
+            page_groups=t > 1, **common,
         ).compile()
     text = compiled.as_text()
     assert "%stacked_params__lead_q_a_proj" in text  # the names read below
@@ -804,6 +812,19 @@ def _slab_moves(text: str, elems: int) -> list[str]:
     ]
 
 
+def _scatter_indices(text: str) -> list[int]:
+    """How many indices each `scatter` of a compiled program takes (the
+    leading dimension of its second operand): what the device pays for."""
+    import re
+
+    shapes = dict(re.findall(r"%([\w.-]+) = \w+\[([\d,]*)\]", text))
+    shapes.update(re.findall(r"[(, ]([\w.-]+): \w+\[([\d,]*)\]", text))
+    return [
+        int(shapes[m.group(1)].split(",")[0] or 1)
+        for m in re.finditer(r" scatter\(%[\w.-]+, %([\w.-]+),", text)
+    ]
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk", "tail", "fused"])
 def test_qwen3_next_span_step_compiles_and_copies_no_parameter(v5e, program):
     """The cell's step programs (a decode group through the paged kernel and
@@ -831,8 +852,11 @@ def test_qwen3_next_span_step_compiles_and_copies_no_parameter(v5e, program):
     else:
         b, t, case = {
             "decode": (4, 1, dict(use_paged=True)),
+            # (a chunk's rows come as page groups: kv/arena.py
+            # `rows_fill_pages`; an 8-row tail is under one page)
             "chunk": (1, 512, dict(
-                use_flash=True, t_real=512, expert_kernels=True)),
+                use_flash=True, t_real=512, expert_kernels=True,
+                page_groups=True)),
             "tail": (1, 8, dict(use_paged=True, t_real=5)),
         }[program]
         plan_len = b * t + b * pages + b * t + b + layers + b
@@ -862,6 +886,14 @@ def test_qwen3_next_span_step_compiles_and_copies_no_parameter(v5e, program):
     assert arena.shape == (2, 5376 * PAGE * 2, 256)
     slab = arena.shape[1] * arena.shape[2]
     assert not _slab_moves(text, slab), _slab_moves(text, slab)
+    # the chunk's K/V go in one index a PAGE (32 a slab); every other
+    # program one a (token, head) row: 2 a token
+    writes = _scatter_indices(text)
+    if program == "chunk":
+        assert writes.count(512 // PAGE) == 2 and 512 * 2 not in writes, writes
+    else:
+        rows = {"decode": 4, "tail": 8, "fused": 1024}[program]
+        assert writes.count(rows * 2) == 2, writes
     temp = compiled.memory_analysis().temp_size_in_bytes
     # decode 12.2 MB and tail 11.6 MB (400 MB was the bound while they held
     # a slab-sized buffer), chunk 43 MB, fused 205 MB; ONE stack for the
@@ -977,7 +1009,9 @@ def test_phi4flash_span_step_compiles_and_copies_no_parameter(v5e, program):
         compiled = span_step_packed.lower(
             params, arena, arena,
             _cell_payload(spec, b * t, plan_len, one_chip), None, None, state,
-            b=b, t=t, use_paged=True, t_real=t_real, **common,
+            b=b, t=t, use_paged=True, t_real=t_real,
+            # (a chunk's rows come as page groups; a tail of 8 is no page)
+            page_groups=program == "chunk", **common,
         ).compile()
     text = compiled.as_text()
     assert "%stacked_params__sa0_mamba_in_proj" in text  # the names read below
@@ -993,6 +1027,17 @@ def test_phi4flash_span_step_compiles_and_copies_no_parameter(v5e, program):
     assert not _slab_moves(text, slab), _slab_moves(text, slab)
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 1500e6, temp
+    # the chunk's K/V go in one index a PAGE, 32 a slab where the row
+    # scatter takes 5,120 (kv/arena.py `arena_write` on `PageSlots`; the
+    # pair scan holds one write, layer 17 the other: 2 x K and V); every
+    # other program one a (token, pair) row
+    writes, pairs = _scatter_indices(text), spec.num_key_value_heads
+    if program == "chunk":
+        assert writes.count(512 // PAGE) == 4 and 512 * pairs not in writes, (
+            writes)
+    else:
+        rows = {"decode": 4, "tail": 8, "fused": 1024}[program]
+        assert writes.count(rows * pairs) == 4, writes
 
 
 # ---------------------------------------------- the arena's layout rule (PR 46)
@@ -1033,13 +1078,49 @@ def _arena_part_of_a_decode_step(kv, hd, folded: bool, pages=5376):
         ((4,), i32)]
 
 
+def _page_write_moves_no_slab(compile_, slabs, token_rows, rows: int):
+    """The chunk side (PR 49): 512 rows that come as page groups, written
+    by page into donated slabs of these shapes (kv/arena.py `arena_write` on
+    `PageSlots`, through the [pages, page_size * n_kv, lanes] view): 32
+    indices a slab where the row scatter takes `rows`, no reshape / copy /
+    transpose the size of a slab, a temporary under half a slab, the slabs
+    updated in place. Through the split view [pages, page_size, n_kv, hd] a
+    4 x 128 slab is re-laid out twice (my compile, PR 49): one view for
+    every plain slab."""
+    import math
+
+    from bloombee_tpu.kv.arena import PageSlots, arena_write, page_view_free
+
+    def write(k_slab, v_slab, slots, k_new, v_new):
+        return arena_write(
+            k_slab, v_slab, PageSlots(slots, PAGE), k_new, v_new)
+
+    assert all(page_view_free(slab, bf16) for slab in slabs)
+    shapes = [(slabs[0], bf16), (slabs[1], bf16), ((512,), i32),
+              ((512, *token_rows[0]), bf16), ((512, *token_rows[1]), bf16)]
+    compiled = compile_(write, shapes, donate=(0, 1))
+    text = compiled.as_text()
+    elems = min(math.prod(slab) for slab in slabs)
+    assert not _slab_moves(text, elems), _slab_moves(text, elems)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < elems  # < half the smaller slab
+    assert memory.alias_size_in_bytes == 2 * sum(
+        math.prod(slab) for slab in slabs)
+    assert _scatter_indices(text) == [512 // PAGE] * 2
+    by_row = compile_(arena_write, shapes, donate=(0, 1))
+    folded = len(slabs[0]) == 2 and len(token_rows[0]) == 2
+    assert _scatter_indices(by_row.as_text()) == [
+        rows if folded else 512] * 2
+
+
 @pytest.mark.parametrize("case", sorted(_LAYOUT_CASES) + ["deepseekv2-latent"])
 def test_chosen_layout_holds_no_slab_sized_move(v5e, case):
     """The layout the rule chooses compiles to a decode program with no
     reshape / copy / transpose the size of a slab and no slab-sized
     temporary; where it chooses to fold, the unfolded layout DOES hold one
     (so the rule folds nothing that did not need it: the other cases' chosen
-    layout is the unfolded one)."""
+    layout is the unfolded one). And the chunk side: a chunk's rows written
+    by page into the chosen layout move no slab either."""
     import math
 
     from bloombee_tpu.kv.arena import folds
@@ -1064,6 +1145,9 @@ def test_chosen_layout_holds_no_slab_sized_move(v5e, case):
              ((5 * 5376 * PAGE, 512), bf16), ((5 * 5376 * PAGE, 128), bf16),
              ((8, 1024), i32), ((8,), i32)])
         assert not _slab_moves(compiled.as_text(), elems // 5)
+        _page_write_moves_no_slab(
+            compile_, [(5 * 5376 * PAGE, 512), (5 * 5376 * PAGE, 128)],
+            [(512,), (128,)], rows=512)
         return
     kv, hd, folded = _LAYOUT_CASES[case]
     assert folds(kv, hd, bf16) is folded
@@ -1075,6 +1159,9 @@ def test_chosen_layout_holds_no_slab_sized_move(v5e, case):
     assert "tpu_custom_call" in text
     assert not _slab_moves(text, elems), _slab_moves(text, elems)
     assert compiled.memory_analysis().temp_size_in_bytes < elems  # < half a slab
+    slab = shapes[1][0]
+    _page_write_moves_no_slab(
+        compile_, [slab, slab], [(kv, hd), (kv, hd)], rows=512 * kv)
     if folded:
         fn, shapes = _arena_part_of_a_decode_step(kv, hd, False)
         moved = _slab_moves(compile_(fn, shapes, donate=(1, 2)).as_text(), elems)

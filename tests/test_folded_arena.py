@@ -392,14 +392,25 @@ def test_rpc_info_and_the_step_span_say_the_layout(q3n_dir, monkeypatch):
     for folded in (True, False):
         server = _server("qwen3_next", folded, q3n_dir)
         info, _ = asyncio.run(server._rpc_info({}, []))
-        assert info["kv"] == {"folded": folded}
+        assert info["kv"] == {
+            "folded": folded, "chunk_page_writes": 0, "chunk_row_writes": 0}
 
         async def step(server=server):
             async with server.manager.allocate(1, 32) as h:
                 server.executor.prefill(h, _hidden(19, 7))
+                server.executor.prefill(h, _hidden(20, 19))
+            async with server.manager.allocate(1, 32) as h:
+                server.executor.prefill(h, _hidden(21, 19))
 
         asyncio.run(step())
         assert seen[-1]["arena"] == ("folded" if folded else "unfolded")
+        # 7 rows are under one page, 19 more start inside it: row by row.
+        # 19 rows from a page's first token, in a bucket of 32, are page
+        # groups, written by page in both layouts (a slab the server's rule
+        # left unfolded has a free page view under that rule)
+        assert [ids["write"] for ids in seen[-3:]] == ["rows", "rows", "pages"]
+        assert server.executor.kv_writes == {
+            "chunk_page_writes": 1, "chunk_row_writes": 2}
     # a shape the rule leaves alone says so too
     plain = CacheManager(2, 4, 4, 8, 128)
     assert plain.folded is False and plain.arena["k"].ndim == 4
