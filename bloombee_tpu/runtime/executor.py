@@ -1357,9 +1357,11 @@ class SpanExecutor:
         """The tile the flash kernel multiplies where a step's chunk of
         `rows` rows a sequence attends at the `max_pages` bucket, by the kind
         of attention layer the span holds: "full:128x512x4" or
-        "window:256x512x6,full:256x512x6" (block_q x block_k x query heads a
-        tile); None where the kernel takes no layer's call. Made once a
-        (rows, bucket), as the step's program is, from what the layers
+        "window:256x512x6+full:256x512x6" (block_q x block_k x query heads a
+        tile; the kinds joined by "+", neither the comma the profiler cuts an
+        id at nor the ";" of an id that is a list of counts:
+        utils/jitwatch.py); None where the kernel takes no layer's call. Made
+        once a (rows, bucket), as the step's program is, from what the layers
         themselves decide by: the run a layer gathers (`chunk_run_pages`),
         the test its caller makes of it (`flash_takes`) and the kernel's
         own rule (`flash_tiles`)."""
@@ -1379,7 +1381,7 @@ class SpanExecutor:
                             itemsize,
                         )
                     ))
-            self._flash_forms[key] = ",".join(
+            self._flash_forms[key] = "+".join(
                 f"{k}:{v}" for k, v in kinds.items()
             ) or None
         form = self._flash_forms[key]

@@ -2147,7 +2147,7 @@ class BlockServer(PromotionLoopMixin):
                    if any(self.executor.windows) else {}),
             },
             # the tile the last chunk's flash kernel multiplied, by layer
-            # kind (runtime/executor.py `_flash_form`)
+            # kind, the kinds joined by "+" (runtime/executor.py `_flash_form`)
             **(
                 {"flash": self.executor.flash_form}
                 if self.executor.flash_form else {}

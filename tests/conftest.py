@@ -25,3 +25,34 @@ def rng():
     import jax
 
     return jax.random.PRNGKey(0)
+
+
+def _is_cell_rehearsal(item) -> bool:
+    return (item.path.name == "test_cell_rehearsal.py"
+            and item.name.startswith("test_cell_rehearsal_"))
+
+
+@pytest.hookimpl(trylast=True)
+def pytest_collection_modifyitems(items):
+    """Deal the cell rehearsals (tests/test_cell_rehearsal.py: 25 cases of
+    about a minute, each a swarm of processes) through the first four fifths
+    of the collection, each at a boundary between two modules.
+
+    xdist's `load` hands a worker CONSECUTIVE tests, some fifty at a time at
+    the start of a run of this size, so cases that stand in a row queue on
+    one or two workers while the others run out of work; dealt out, two or
+    three run at once and none starts in the run's last minutes. A module's
+    own tests stay together (its module fixtures are built once). Every
+    worker collects the same list, so every worker deals the same way.
+    """
+    heavy = [it for it in items if _is_cell_rehearsal(it)]
+    rest = [it for it in items if not _is_cell_rehearsal(it)]
+    bounds = [k for k, it in enumerate(rest)
+              if k == 0 or it.path != rest[k - 1].path]
+    if not heavy or len(bounds) < 2:
+        return
+    at: dict[int, list] = {}
+    for j, it in enumerate(heavy):
+        want = j * 0.8 * len(rest) / len(heavy)
+        at.setdefault(min(bounds, key=lambda k: abs(k - want)), []).append(it)
+    items[:] = [it for k, one in enumerate(rest) for it in (*at.get(k, ()), one)]

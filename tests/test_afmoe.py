@@ -375,7 +375,7 @@ def test_a_chunk_steps_span_says_the_tile_its_flash_kernel_multiplied(
     # window's run of whole 512-key blocks would pass: both kinds gather the
     # bucket's 128 keys
     want = "x".join(map(str, flash_tiles(128, 128, n_rep, spec.head_dim, 4)))
-    assert seen[0]["flash"] == f"window:{want},full:{want}"
+    assert seen[0]["flash"] == f"window:{want}+full:{want}"
     assert "flash" not in seen[1]
     assert ex.flash_form == seen[0]["flash"]
 

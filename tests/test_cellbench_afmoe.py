@@ -1,33 +1,23 @@
-"""The benchmark's `afmoe` family file and cell, in tier 1 (a file of its own
-beside tests/test_cellbench_families.py, whose helpers it borrows: the driver
-hands test FILES to its workers).
+"""The benchmark's `afmoe` family file, in tier 1.
 
-The plan at the published size, every key of the needs, the new metric
-readers on synthetic reductions and without a trace, and the CPU rehearsal of
-the cell `trinity-longctx` through `cellbench/run.py` on a tiny preset added
-to a copy of the benchmark by files only: `correct` true; false with an
-int8-weight server (the control); false with each of the eight faults of
-`scripts/plant_afmoe_fault.py` planted in a copy of the program.
+The plan at the published size, every key of the needs, and the family's
+metric readers on synthetic reductions and without a trace. The CPU rehearsal
+of the cell `trinity-longctx` is a row of `tests/test_cell_rehearsal.py`,
+which takes its tiny configuration from here (as `tests/test_afmoe.py` does).
 """
 
 from __future__ import annotations
 
-import importlib.util
 import json
 import pathlib
-import shutil
 import sys
 
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
-sys.path.insert(0, str(ROOT / "tests"))
 
 from cellbench import checkpoint, families  # noqa: E402
-from test_cellbench_families import _compared, _run  # noqa: E402
-
-TREE = ROOT / ".cache" / "cellbench_rehearsal_afmoe"
 
 # five layers of the cell's kinds (dense; window, window, full, window), 8
 # routed experts of which this share holds 4, window 32
@@ -182,106 +172,3 @@ def test_afmoe_metrics_on_synthetic_reductions():
     ctx["_mlatrace"] = None
     assert read("held_experts_hit_share") is None
     assert read("chunk_moe_ms_p50") is None
-
-
-@pytest.fixture(scope="module")
-def tree():
-    """A copy of the benchmark with a tiny afmoe configuration, a traffic mix
-    and a cell ADDED (the family file and the metric readers are already
-    there), no file edited."""
-    shutil.rmtree(TREE, ignore_errors=True)
-    TREE.mkdir(parents=True)
-    shutil.copytree(ROOT / "cellbench", TREE / "cellbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    (TREE / "bloombee_tpu").symlink_to(ROOT / "bloombee_tpu")
-    cb = TREE / "cellbench"
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    (cb / "configs" / "tiny-afmoe.json").write_text(json.dumps(dict(
-        TINY_AFMOE, cellbench={
-            "source": "none: a rehearsal preset", "uid": "tiny-afmoe",
-            "reduced": {"everything": "tiny"},
-            # a float32 server, as the tiny presets of the other families
-            "server_flags": ["--mixed-batch", "--prefill-chunk", "128",
-                             "--dtype", "float32", "--experts", "2:4"],
-            "prefill_chunk": 128, "logit_error_limit": LIMIT,
-            "int8_projection_limit": 0.5})))
-    (cb / "traffic" / "tiny-ctx.json").write_text(json.dumps({
-        "loop": "closed", "sessions": 2, "stagger_s": 0.1,
-        "prompt_tokens": [300, 171, 260], "new_tokens": [4, 5, 4],
-        "judge": {"requests": 2, "new_tokens": 4}}))
-    (cb / "cells" / "tiny-afmoe-ctx.json").write_text('{"num_pages": 128}')
-    bench["configs"].append(
-        {"name": "tiny-afmoe", "source": "none", "reduced": [],
-         "file": "cellbench/configs/tiny-afmoe.json", "why": "rehearsal"})
-    bench["workloads"].append(
-        {"name": "tiny-afmoe-ctx", "config": "tiny-afmoe",
-         "traffic": "tiny-ctx", "chips": 1, "why": "rehearsal"})
-    for metric in bench["end_to_end"] + bench["per_layer"]:
-        if "trinity-longctx" in metric.get("workloads", ()):
-            metric["workloads"].append("tiny-afmoe-ctx")
-    (TREE / "BENCHMARK.json").write_text(json.dumps(bench))
-    return TREE
-
-
-# sound readings are float32's order of sums (a router near-tie aside: the
-# program's float32 product and the reference's differ in the last bits);
-# each planted fault reads hundreds of times that (below); an int8-weight
-# server's projection reads 1
-LIMIT = 1e-4
-
-
-def test_afmoe_cell_rehearsal_is_correct(tree):
-    rc, last, out = _run(tree, "--workload", "tiny-afmoe-ctx",
-                         "--seed", str(2**31 + 47), "--seconds", "3",
-                         "--trace", "1")
-    assert last is not None and rc == 0, out[-3000:]
-    assert last["correct"] is True and last["failed"] == 0, out[-3000:]
-    assert last["attempted"] >= 2
-    # prompts of 171-300 tokens outgrow the 32-token window many times over:
-    # four of the five layers hold mostly dead tokens
-    assert 50 < last["metrics"]["window_dead_share"]["value"] < 80
-    # a CPU run reports no device metric under a device metric's name
-    for name in ("chunk_router_ms_p50", "chunk_window_attn_ms_p50",
-                 "chunk_full_attn_ms_p50", "device_idle_share"):
-        assert name not in last["metrics"]
-
-
-def test_afmoe_cell_rehearsal_int8_server_is_not_correct(tree):
-    rc, last, out = _run(
-        tree, "--workload", "tiny-afmoe-ctx", "--seed", "17",
-        "--seconds", "2", "--trace", "0", "--server-arg=--weight-quant",
-        "--server-arg=int8")
-    assert last is not None and last["correct"] is False, out[-3000:]
-    assert rc != 0
-    got = _compared(out)
-    assert got["int8_projection_median"][0] == pytest.approx(1.0, abs=0.15)
-
-
-# tier 1 plants the four faults of what this family brought (the router's
-# bias, positions by layer kind, the window); the four that other families'
-# rehearsals plant in the same shared code run with the `slow` tests
-@pytest.mark.parametrize("fault", [
-    "bias_choice", "biased_weights", "full_rope", "window",
-    *(pytest.param(f, marks=pytest.mark.slow) for f in (
-        "route_scale", "attn_gate", "routed_sum", "embed_scale"))])
-def test_afmoe_cell_rehearsal_sees_a_planted_fault(tree, tmp_path, fault):
-    """The timed path BROKEN underneath the harness, in a copy of the
-    program (`scripts/plant_afmoe_fault.py`, which planted the same eight on
-    the chip). The served tokens still come, no request fails, and `correct`
-    is false by the logit error."""
-    broken = tmp_path / "tree"
-    shutil.copytree(tree, broken, symlinks=True)
-    (broken / "bloombee_tpu").unlink()
-    shutil.copytree(ROOT / "bloombee_tpu", broken / "bloombee_tpu",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    spec = importlib.util.spec_from_file_location(
-        "plant_afmoe_fault", ROOT / "scripts" / "plant_afmoe_fault.py")
-    planter = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(planter)
-    planter.plant(broken, fault)
-    rc, last, out = _run(broken, "--workload", "tiny-afmoe-ctx",
-                         "--seed", "23", "--seconds", "2", "--trace", "0")
-    assert last is not None and last["correct"] is False, out[-3000:]
-    assert last["failed"] == 0 and rc != 0
-    err, limit = _compared(out)["logit_err_median"]
-    assert err > 10 * limit, (err, limit)

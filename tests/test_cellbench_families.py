@@ -10,19 +10,18 @@
    The values were produced by this file's own code when the family was added
    (PR 30, after the review moved the fills of in_proj, the taps and D): a
    later edit that moves one has to say so here.
-3. A CPU rehearsal of the new cell's path through `cellbench/run.py` on a tiny
-   falcon_h1 preset, added to a copy of the benchmark by files only: `correct`
-   true, and false when the server holds int8 weights (the control).
+3. The same pins for `deepseek_v2`, and the trace readers both families
+   brought, on synthetic traces and without one.
+
+The CPU rehearsal of each family's cell through `cellbench/run.py` is
+`tests/test_cell_rehearsal.py`, which takes its tiny configurations from here.
 """
 
 from __future__ import annotations
 
 import importlib.util
 import json
-import os
 import pathlib
-import shutil
-import subprocess
 import sys
 
 import pytest
@@ -34,8 +33,6 @@ from cellbench import checkpoint, families  # noqa: E402
 from cellbench.tests.test_families import *  # noqa: E402,F401,F403
 from cellbench.tests.conftest import EXPECTED_FAILURES  # noqa: E402
 from cellbench.tests.test_families import SEED, _sha  # noqa: E402
-
-TREE = ROOT / ".cache" / "cellbench_rehearsal_falcon_h1"
 
 TINY_FALCON_H1 = {
     "model_type": "falcon_h1", "architectures": ["FalconH1ForCausalLM"],
@@ -148,133 +145,6 @@ def test_falcon_h1_needs_every_key():
     assert repr(got) == repr(FH1_PINS["needs"])
 
 
-# ------------------------------------------------- the cell's CPU rehearsal
-@pytest.fixture(scope="module")
-def tree() -> pathlib.Path:
-    """A copy of the benchmark with a tiny falcon_h1 configuration and cell
-    ADDED (the family file is already there), no file edited."""
-    shutil.rmtree(TREE, ignore_errors=True)
-    TREE.mkdir(parents=True)
-    shutil.copytree(ROOT / "cellbench", TREE / "cellbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    (TREE / "bloombee_tpu").symlink_to(ROOT / "bloombee_tpu")
-    cb = TREE / "cellbench"
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    (cb / "configs" / "tiny-falcon-h1.json").write_text(json.dumps(dict(
-        TINY_FALCON_H1, cellbench={
-            "source": "none: a rehearsal preset", "uid": "tiny-falcon-h1",
-            "reduced": {"everything": "tiny"},
-            # a float32 server: at these widths bfloat16's own rounding
-            # (0.003) is twenty times the int8-weight reference's distance
-            # from the reference (0.00016), and the projection on it is noise
-            "server_flags": ["--mixed-batch", "--prefill-chunk", "128",
-                             "--dtype", "float32"],
-            # sound 2.2e-7; padding fed to the state 2.0e-5 (at these widths
-            # the state carries little); an int8-weight server 1.6e-4
-            "prefill_chunk": 128, "logit_error_limit": 2e-6,
-            "int8_projection_limit": 0.5})))
-    (cb / "traffic" / "tiny-doc.json").write_text(json.dumps({
-        "loop": "closed", "sessions": 3, "stagger_s": 0.1,
-        "prompt_tokens": [300, 136, 261, 200], "new_tokens": [5, 4, 6, 4],
-        "judge": {"requests": 2, "new_tokens": 4}}))
-    (cb / "cells" / "tiny-falcon-h1-doc.json").write_text('{"num_pages": 128}')
-    bench["configs"].append(
-        {"name": "tiny-falcon-h1", "source": "none", "reduced": [],
-         "file": "cellbench/configs/tiny-falcon-h1.json", "why": "rehearsal"})
-    bench["workloads"].append(
-        {"name": "tiny-falcon-h1-doc", "config": "tiny-falcon-h1",
-         "traffic": "tiny-doc", "chips": 1, "why": "rehearsal"})
-    for metric in bench["end_to_end"] + bench["per_layer"]:
-        if "falconh1-longdoc" in metric.get("workloads", ()):
-            metric["workloads"].append("tiny-falcon-h1-doc")
-    (TREE / "BENCHMARK.json").write_text(json.dumps(bench))
-    return TREE
-
-
-def _run(tree: pathlib.Path, *argv: str):
-    env = dict(os.environ, CELLBENCH_REHEARSAL="1", JAX_PLATFORMS="cpu")
-    env.pop("JAX_COMPILATION_CACHE_DIR", None)
-    env.pop("XLA_FLAGS", None)  # conftest's 8 virtual devices: one is the cell's
-    for attempt in (1, 2):
-        proc = subprocess.run(
-            [sys.executable, "cellbench/run.py", *argv], cwd=tree, env=env,
-            capture_output=True, text=True, timeout=900)
-        lines = proc.stdout.strip().splitlines()
-        try:
-            last = json.loads(lines[-1]) if lines else None
-        except ValueError:
-            last = None
-        if last is not None and "correct" not in last:
-            last = None  # the summary line of a run without a verdict
-        # A run that ended WITHOUT a verdict is tried once more; a run that
-        # gave one, right or wrong, is never run again. Two ways are known,
-        # both the harness's own under a loaded machine (six test workers)
-        # and neither the program's: exit code 4 with the fault in the
-        # summary line (a child that could not start: the port run.py's
-        # `_free_port` found was taken by another worker's swarm before the
-        # child bound it), and an UNCAUGHT exception of run.py, exit code 1
-        # with no result line (it polls `window.started.exists()` and then
-        # `float(read_text())` while loadgen.py writes the file with
-        # `write_text`: an empty read dies with ValueError; PERF.md section 7
-        # row 14, 1 of PR 40's 38 chip runs).
-        if last is not None or attempt == 2:
-            break
-    return proc.returncode, last, proc.stdout + proc.stderr
-
-
-def _compared(out: str) -> dict:
-    """Each number the run compared, beside its limit."""
-    line = next(ln for ln in out.splitlines() if '"phase": "correctness"' in ln)
-    return json.loads(line)["compared"]
-
-
-def test_falcon_h1_cell_rehearsal_is_correct(tree):
-    rc, last, out = _run(tree, "--workload", "tiny-falcon-h1-doc", "--seed",
-                         str(2**31 + 30), "--seconds", "4", "--trace", "1")
-    assert last is not None and rc == 0, out[-3000:]
-    assert last["correct"] is True and last["failed"] == 0, out[-3000:]
-    assert last["attempted"] >= 3
-    # a CPU run reports no device metric under a device metric's name
-    for name in ("step_ssm_ms_p50", "chunk_ssm_ms_p50", "ssm_scan_roofline",
-                 "state_io_move_share", "device_idle_share"):
-        assert name not in last["metrics"]
-
-
-def test_falcon_h1_cell_rehearsal_int8_server_is_not_correct(tree):
-    rc, last, out = _run(
-        tree, "--workload", "tiny-falcon-h1-doc", "--seed", "17", "--seconds",
-        "2", "--trace", "0", "--server-arg=--weight-quant",
-        "--server-arg=int8")
-    assert last is not None and last["correct"] is False, out[-3000:]
-    assert rc != 0
-    got = _compared(out)
-    assert got["int8_projection_median"][0] == pytest.approx(1.0, abs=0.05)
-
-
-def test_falcon_h1_cell_rehearsal_sees_padding_fed_to_the_state(tree, tmp_path):
-    """The timed path BROKEN underneath the harness: in a copy of the program
-    a chunk's bucket tail advances the recurrent state (the mask on `dt`
-    taken off: `scripts/plant_state_fault.py`, which planted it on the chip
-    too). The served tokens still come, no request fails, and `correct` is
-    false by the logit error: the judge sees the state."""
-    broken = tmp_path / "tree"
-    shutil.copytree(tree, broken, symlinks=True)
-    (broken / "bloombee_tpu").unlink()
-    shutil.copytree(ROOT / "bloombee_tpu", broken / "bloombee_tpu",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    spec = importlib.util.spec_from_file_location(
-        "plant_state_fault", ROOT / "scripts" / "plant_state_fault.py")
-    planter = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(planter)
-    planter.plant(broken, "pad")  # the fault the chip run planted too
-    rc, last, out = _run(broken, "--workload", "tiny-falcon-h1-doc", "--seed",
-                         "23", "--seconds", "2", "--trace", "0")
-    assert last is not None and last["correct"] is False, out[-3000:]
-    assert last["failed"] == 0 and rc != 0
-    err, limit = _compared(out)["logit_err_median"]
-    assert err > 5 * limit, (err, limit)
-
-
 # ------------------------------------------ the mixer's scopes in a trace
 def test_ssmtrace_reduces_a_synthetic_trace_to_known_numbers():
     """`cellbench/ssmtrace.py` on a trace whose answers are worked out by
@@ -345,13 +215,8 @@ def test_a_mixer_metric_reads_nothing_where_there_is_no_trace(tmp_path, name):
 
 # ---------------------------------------------------- deepseek_v2's pins
 # (PR 35) the plan at the published size, a tiny checkpoint's files, every
-# key of the needs, and the CPU rehearsal of the cell `deepseekv2-longctx`
-# through `cellbench/run.py`: `correct` true; false with an int8-weight
-# server; false with each fault of `scripts/plant_mla_fault.py` planted in a
-# copy of the program. The values were produced by this file's own code when
+# key of the needs. The values were produced by this file's own code when
 # the family was added: a later edit that moves one has to say so here.
-TREE_DSV2 = ROOT / ".cache" / "cellbench_rehearsal_deepseek_v2"
-
 TINY_DEEPSEEK_V2 = {
     "model_type": "deepseek_v2", "hidden_size": 128,
     "num_attention_heads": 4, "num_key_value_heads": 4, "q_lora_rank": 48,
@@ -450,103 +315,6 @@ def test_deepseek_v2_needs_every_key():
     assert family.latent_row_bytes(config) == 1152
     assert family._expert_reach(config, 512)[1] == pytest.approx(20, abs=1e-6)
     assert family._expert_reach(config, 2)[1] == pytest.approx(1.47, abs=0.01)
-
-
-@pytest.fixture(scope="module")
-def tree_dsv2() -> pathlib.Path:
-    """A copy of the benchmark with a tiny deepseek_v2 configuration, a
-    traffic mix and a cell ADDED (the family file and the metric readers are
-    already there), no file edited."""
-    shutil.rmtree(TREE_DSV2, ignore_errors=True)
-    TREE_DSV2.mkdir(parents=True)
-    shutil.copytree(ROOT / "cellbench", TREE_DSV2 / "cellbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    (TREE_DSV2 / "bloombee_tpu").symlink_to(ROOT / "bloombee_tpu")
-    cb = TREE_DSV2 / "cellbench"
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    (cb / "configs" / "tiny-deepseek-v2.json").write_text(json.dumps(dict(
-        TINY_DEEPSEEK_V2, cellbench={
-            "source": "none: a rehearsal preset", "uid": "tiny-deepseek-v2",
-            "reduced": {"everything": "tiny"},
-            # a float32 server, as the tiny falcon_h1 above: sound 2.1e-7;
-            # the rotary key zeroed in the cache write 5.2e-4, the route
-            # scale left out 0.028, the routed sum dropped 0.030; an
-            # int8-weight server 6.5e-4 with projection 1.0000
-            "server_flags": ["--mixed-batch", "--prefill-chunk", "128",
-                             "--experts", "4:4", "--dtype", "float32"],
-            "prefill_chunk": 128, "logit_error_limit": 2e-5,
-            "int8_projection_limit": 0.5})))
-    (cb / "traffic" / "tiny-ctx.json").write_text(json.dumps({
-        "loop": "closed", "sessions": 2, "stagger_s": 0.1,
-        "prompt_tokens": [300, 171, 260], "new_tokens": [4, 5, 4],
-        "judge": {"requests": 2, "new_tokens": 4}}))
-    (cb / "cells" / "tiny-deepseek-v2-ctx.json").write_text(
-        '{"num_pages": 128}')
-    bench["configs"].append(
-        {"name": "tiny-deepseek-v2", "source": "none", "reduced": [],
-         "file": "cellbench/configs/tiny-deepseek-v2.json", "why": "rehearsal"})
-    bench["workloads"].append(
-        {"name": "tiny-deepseek-v2-ctx", "config": "tiny-deepseek-v2",
-         "traffic": "tiny-ctx", "chips": 1, "why": "rehearsal"})
-    for metric in bench["end_to_end"] + bench["per_layer"]:
-        if "deepseekv2-longctx" in metric.get("workloads", ()):
-            metric["workloads"].append("tiny-deepseek-v2-ctx")
-    (TREE_DSV2 / "BENCHMARK.json").write_text(json.dumps(bench))
-    return TREE_DSV2
-
-
-def test_deepseek_v2_cell_rehearsal_is_correct(tree_dsv2):
-    rc, last, out = _run(tree_dsv2, "--workload", "tiny-deepseek-v2-ctx",
-                         "--seed", str(2**31 + 35), "--seconds", "4",
-                         "--trace", "1")
-    assert last is not None and rc == 0, out[-3000:]
-    assert last["correct"] is True and last["failed"] == 0, out[-3000:]
-    # the two sessions' first requests: a third starts only once one has
-    # finished, which a window of seconds does not promise on a machine six
-    # test workers share (the qwen3_next rehearsal read 2 there, 12 alone)
-    assert last["attempted"] >= 2
-    # a CPU run reports no device metric under a device metric's name
-    for name in ("chunk_mla_ms_p50", "step_mla_ms_p50", "chunk_moe_ms_p50",
-                 "mla_attention_roofline", "mla_decode_roofline",
-                 "latent_io_move_share", "device_idle_share"):
-        assert name not in last["metrics"]
-
-
-def test_deepseek_v2_cell_rehearsal_int8_server_is_not_correct(tree_dsv2):
-    rc, last, out = _run(
-        tree_dsv2, "--workload", "tiny-deepseek-v2-ctx", "--seed", "17",
-        "--seconds", "2", "--trace", "0", "--server-arg=--weight-quant",
-        "--server-arg=int8")
-    assert last is not None and last["correct"] is False, out[-3000:]
-    assert rc != 0
-    got = _compared(out)
-    assert got["int8_projection_median"][0] == pytest.approx(1.0, abs=0.05)
-
-
-@pytest.mark.parametrize("fault", ["rope_key", "route_scale", "routed_sum"])
-def test_deepseek_v2_cell_rehearsal_sees_a_planted_fault(
-        tree_dsv2, tmp_path, fault):
-    """The timed path BROKEN underneath the harness, in a copy of the
-    program (`scripts/plant_mla_fault.py`, which planted the same three on
-    the chip): the rotary key zeroed in the cache write, the route scale
-    left out, the held experts' partial sum dropped. The served tokens still
-    come, no request fails, and `correct` is false by the logit error."""
-    broken = tmp_path / "tree"
-    shutil.copytree(tree_dsv2, broken, symlinks=True)
-    (broken / "bloombee_tpu").unlink()
-    shutil.copytree(ROOT / "bloombee_tpu", broken / "bloombee_tpu",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    spec = importlib.util.spec_from_file_location(
-        "plant_mla_fault", ROOT / "scripts" / "plant_mla_fault.py")
-    planter = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(planter)
-    planter.plant(broken, fault)
-    rc, last, out = _run(broken, "--workload", "tiny-deepseek-v2-ctx",
-                         "--seed", "23", "--seconds", "2", "--trace", "0")
-    assert last is not None and last["correct"] is False, out[-3000:]
-    assert last["failed"] == 0 and rc != 0
-    err, limit = _compared(out)["logit_err_median"]
-    assert err > 10 * limit, (err, limit)
 
 
 def test_a_latent_metric_reads_nothing_where_there_is_no_trace(tmp_path):

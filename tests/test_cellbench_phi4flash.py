@@ -1,40 +1,30 @@
-"""The benchmark's `phi4flash` family file and cell, in tier 1 (a file of its
-own beside tests/test_cellbench_families.py and test_cellbench_qwen3_next.py,
-whose helpers it borrows: the driver hands test FILES to its workers).
+"""The benchmark's `phi4flash` family file, in tier 1.
 
 The plan at the published size, a tiny checkpoint's files, every key of the
-needs, the SambaY metric readers on a synthetic trace and without one, and
-the CPU rehearsal of the cell `phi4flash-longctx` through `cellbench/run.py`
-on a tiny preset added to a copy of the benchmark by files only: `correct`
-true; false with an int8-weight server (the control); false with each fault
-of `scripts/plant_sambay_fault.py` planted in a copy of the program. The
-pinned values were produced by this file's own code when the family was added
-(PR 45): a later edit that moves one has to say so here. PR 49 added a
-second preset, the same at hidden size 256 (`WIDE_PHI4FLASH`), whose K/V
-pair is 128 wide (whole lanes, as the published pair), so that the sound
-rehearsal writes its chunks into the arena by page (`page_write_share`);
-the controls keep the narrow one.
+needs, and the SambaY metric readers on a synthetic trace and without one.
+The pinned values were produced by this file's own code when the family was
+added (PR 45): a later edit that moves one has to say so here. The CPU
+rehearsal of the cell `phi4flash-longctx` is a row of
+`tests/test_cell_rehearsal.py`, which takes its tiny configurations from
+here. PR 49 added the second one, the same at hidden size 256
+(`WIDE_PHI4FLASH`), whose K/V pair is 128 wide (whole lanes, as the published
+pair), so that the sound rehearsal writes its chunks into the arena by page
+(`page_write_share`); the controls keep the narrow one.
 """
 
 from __future__ import annotations
 
-import importlib.util
 import json
 import pathlib
-import shutil
 import sys
 
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
-sys.path.insert(0, str(ROOT / "tests"))
 
 from cellbench import checkpoint, families  # noqa: E402
 from cellbench.tests.test_families import SEED, _sha  # noqa: E402
-from test_cellbench_families import _compared, _run  # noqa: E402
-
-TREE_PHI = ROOT / ".cache" / "cellbench_rehearsal_phi4flash"
 
 TINY_PHI4FLASH = {
     "model_type": "phi4flash", "hidden_size": 128, "intermediate_size": 256,
@@ -198,121 +188,6 @@ def test_sambay_metrics_on_a_synthetic_reduction_and_counters():
     least = max(needs["bytes"] / 819e9, needs["flops"] / 197e12)
     assert read("mamba_scan_roofline") == pytest.approx(100 * least / 4e-3)
     assert 0 < read("mamba_scan_roofline") < 100
-
-
-@pytest.fixture(scope="module")
-def tree_phi():
-    """A copy of the benchmark with a tiny phi4flash configuration, a traffic
-    mix and a cell ADDED (the family file and the metric readers are already
-    there), no file edited."""
-    shutil.rmtree(TREE_PHI, ignore_errors=True)
-    TREE_PHI.mkdir(parents=True)
-    shutil.copytree(ROOT / "cellbench", TREE_PHI / "cellbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    (TREE_PHI / "bloombee_tpu").symlink_to(ROOT / "bloombee_tpu")
-    cb = TREE_PHI / "cellbench"
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    (cb / "configs" / "tiny-phi4flash.json").write_text(json.dumps(dict(
-        TINY_PHI4FLASH, cellbench={
-            "source": "none: a rehearsal preset", "uid": "tiny-phi4flash",
-            "reduced": {"everything": "tiny"},
-            # a float32 server, as the tiny presets of the other families
-            "server_flags": ["--mixed-batch", "--prefill-chunk", "128",
-                             "--dtype", "float32"],
-            "prefill_chunk": 128, "logit_error_limit": PHI_LIMIT,
-            "int8_projection_limit": 0.5})))
-    (cb / "traffic" / "tiny-ctx.json").write_text(json.dumps({
-        "loop": "closed", "sessions": 2, "stagger_s": 0.1,
-        "prompt_tokens": [300, 171, 276], "new_tokens": [4, 5, 4],
-        "judge": {"requests": 2, "new_tokens": 4}}))
-    wide = json.loads((cb / "configs" / "tiny-phi4flash.json").read_text())
-    wide.update(WIDE_PHI4FLASH)
-    wide["cellbench"]["uid"] = "tiny-phi4flash-wide"
-    (cb / "configs" / "tiny-phi4flash-wide.json").write_text(json.dumps(wide))
-    for name in ("tiny-phi4flash", "tiny-phi4flash-wide"):
-        (cb / "cells" / f"{name}-ctx.json").write_text('{"num_pages": 128}')
-        bench["configs"].append(
-            {"name": name, "source": "none", "reduced": [],
-             "file": f"cellbench/configs/{name}.json", "why": "rehearsal"})
-        bench["workloads"].append(
-            {"name": f"{name}-ctx", "config": name, "traffic": "tiny-ctx",
-             "chips": 1, "why": "rehearsal"})
-        for metric in bench["end_to_end"] + bench["per_layer"]:
-            if "phi4flash-longctx" in metric.get("workloads", ()):
-                metric["workloads"].append(f"{name}-ctx")
-    (TREE_PHI / "BENCHMARK.json").write_text(json.dumps(bench))
-    return TREE_PHI
-
-
-# sound readings are float32's order of sums; each planted fault reads
-# thousands of times that (below); an int8-weight server's projection reads 1
-PHI_LIMIT = 2e-5
-
-
-def test_phi4flash_cell_rehearsal_is_correct(tree_phi):
-    rc, last, out = _run(tree_phi, "--workload", "tiny-phi4flash-wide-ctx",
-                         "--seed", str(2**31 + 45), "--seconds", "3",
-                         "--trace", "1")
-    assert last is not None and rc == 0, out[-3000:]
-    assert last["correct"] is True and last["failed"] == 0, out[-3000:]
-    # the two sessions' first requests: a third starts only once one has
-    # finished, which a window of seconds does not promise on a machine six
-    # test workers share (the qwen3_next rehearsal read 2 there, 12 alone)
-    assert last["attempted"] >= 2
-    # the second exit is taken: a prompt's rows stop at the shared layer
-    assert 0 < last["metrics"]["cross_rows_share"]["value"] < 20
-    assert 0 < last["metrics"]["window_dead_share"]["value"] < 100
-    # the chunks' K/V went into the arena by page (the wide preset's K/V
-    # pair is 128 lanes, as the published one, so that its slab has a free
-    # page view: kv/arena.py `page_view_free`; every chunk starts on a page
-    # boundary and no tail is under a page). 100 where no pack formed in the
-    # window: a chunk that rides a fused pack behind decode rows writes by
-    # row (read 100.0 in my runs, PR 49)
-    assert last["metrics"]["page_write_share"]["value"] > 50
-    # a CPU run reports no device metric under a device metric's name
-    for name in ("chunk_mamba_ms_p50", "step_mamba_ms_p50",
-                 "mamba_scan_roofline", "step_cross_ms_p50",
-                 "device_idle_share"):
-        assert name not in last["metrics"]
-
-
-def test_phi4flash_cell_rehearsal_int8_server_is_not_correct(tree_phi):
-    rc, last, out = _run(
-        tree_phi, "--workload", "tiny-phi4flash-ctx", "--seed", "17",
-        "--seconds", "2", "--trace", "0", "--server-arg=--weight-quant",
-        "--server-arg=int8")
-    assert last is not None and last["correct"] is False, out[-3000:]
-    assert rc != 0
-    got = _compared(out)
-    assert got["int8_projection_median"][0] == pytest.approx(1.0, abs=0.1)
-
-
-@pytest.mark.parametrize(
-    "fault", ["cross_row", "memory", "lambda", "state_reset"])
-def test_phi4flash_cell_rehearsal_sees_a_planted_fault(
-        tree_phi, tmp_path, fault):
-    """The timed path BROKEN underneath the harness, in a copy of the
-    program (`scripts/plant_sambay_fault.py`, which planted the same four on
-    the chip): the cross layers reading another row than the full layer's,
-    the gated memory units reading zeros, lambda left out, the state emptied
-    at a prompt's last chunk boundary. The served tokens still come, no
-    request fails, and `correct` is false by the logit error."""
-    broken = tmp_path / "tree"
-    shutil.copytree(tree_phi, broken, symlinks=True)
-    (broken / "bloombee_tpu").unlink()
-    shutil.copytree(ROOT / "bloombee_tpu", broken / "bloombee_tpu",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    spec = importlib.util.spec_from_file_location(
-        "plant_sambay_fault", ROOT / "scripts" / "plant_sambay_fault.py")
-    planter = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(planter)
-    planter.plant(broken, fault)
-    rc, last, out = _run(broken, "--workload", "tiny-phi4flash-ctx",
-                         "--seed", "23", "--seconds", "2", "--trace", "0")
-    assert last is not None and last["correct"] is False, out[-3000:]
-    assert last["failed"] == 0 and rc != 0
-    err, limit = _compared(out)["logit_err_median"]
-    assert err > 10 * limit, (err, limit)
 
 
 def test_a_prompt_by_page_decode_rows_and_a_prompt_inside_a_page(tmp_path):

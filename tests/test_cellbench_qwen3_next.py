@@ -1,16 +1,11 @@
-"""The benchmark's `qwen3_next` family file and cell, in tier 1 (a file of
-its own beside tests/test_cellbench_families.py, whose helpers it borrows:
-the driver hands test FILES to its workers, and five more rehearsals in that
-file would make it the run's longest).
+"""The benchmark's `qwen3_next` family file, in tier 1.
 
 The plan at the published size, a tiny checkpoint's files, every key of the
-needs, the generic scope reducer on a synthetic trace, and the CPU rehearsal
-of the cell `qwen3next-longctx` through `cellbench/run.py` on a tiny preset
-added to a copy of the benchmark by files only: `correct` true; false with an
-int8-weight server (the control); false with each fault of
-`scripts/plant_gdn_fault.py` planted in a copy of the program. The pinned
-values were produced by this file's own code when the family was added
-(PR 39): a later edit that moves one has to say so here.
+needs, and the generic scope reducer on a synthetic trace. The pinned values
+were produced by this file's own code when the family was added (PR 39): a
+later edit that moves one has to say so here. The CPU rehearsal of the cell
+`qwen3next-longctx` is a row of `tests/test_cell_rehearsal.py`, which takes
+its tiny configuration from here.
 """
 
 from __future__ import annotations
@@ -18,20 +13,15 @@ from __future__ import annotations
 import importlib.util
 import json
 import pathlib
-import shutil
 import sys
 
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT))
-sys.path.insert(0, str(ROOT / "tests"))
 
 from cellbench import checkpoint, families  # noqa: E402
 from cellbench.tests.test_families import SEED, _sha  # noqa: E402
-from test_cellbench_families import _compared, _run  # noqa: E402
-
-TREE_Q3N = ROOT / ".cache" / "cellbench_rehearsal_qwen3_next"
 
 TINY_QWEN3_NEXT = {
     "model_type": "qwen3_next", "hidden_size": 128, "num_attention_heads": 4,
@@ -248,104 +238,3 @@ def test_a_linear_mixer_metric_reads_nothing_where_there_is_no_trace(
     ctx = {"trace_dir": str(tmp_path / "trace"), "config": _published_q3n(),
            "prefill_chunk": 512, "device_kind": "TPU v5 lite"}
     assert module.read(ctx) is None
-
-
-@pytest.fixture(scope="module")
-def tree_q3n() -> pathlib.Path:
-    """A copy of the benchmark with a tiny qwen3_next configuration, a
-    traffic mix and a cell ADDED (the family file and the metric readers are
-    already there), no file edited."""
-    shutil.rmtree(TREE_Q3N, ignore_errors=True)
-    TREE_Q3N.mkdir(parents=True)
-    shutil.copytree(ROOT / "cellbench", TREE_Q3N / "cellbench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    (TREE_Q3N / "bloombee_tpu").symlink_to(ROOT / "bloombee_tpu")
-    cb = TREE_Q3N / "cellbench"
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    (cb / "configs" / "tiny-qwen3-next.json").write_text(json.dumps(dict(
-        TINY_QWEN3_NEXT, cellbench={
-            "source": "none: a rehearsal preset", "uid": "tiny-qwen3-next",
-            "reduced": {"everything": "tiny"},
-            # a float32 server, as the tiny presets above
-            "server_flags": ["--mixed-batch", "--prefill-chunk", "128",
-                             "--experts", "2:4", "--dtype", "float32"],
-            "prefill_chunk": 128, "logit_error_limit": Q3N_LIMIT,
-            "int8_projection_limit": 0.5})))
-    (cb / "traffic" / "tiny-ctx.json").write_text(json.dumps({
-        "loop": "closed", "sessions": 2, "stagger_s": 0.1,
-        "prompt_tokens": [300, 171, 260], "new_tokens": [4, 5, 4],
-        "judge": {"requests": 2, "new_tokens": 4}}))
-    (cb / "cells" / "tiny-qwen3-next-ctx.json").write_text(
-        '{"num_pages": 128}')
-    bench["configs"].append(
-        {"name": "tiny-qwen3-next", "source": "none", "reduced": [],
-         "file": "cellbench/configs/tiny-qwen3-next.json", "why": "rehearsal"})
-    bench["workloads"].append(
-        {"name": "tiny-qwen3-next-ctx", "config": "tiny-qwen3-next",
-         "traffic": "tiny-ctx", "chips": 1, "why": "rehearsal"})
-    for metric in bench["end_to_end"] + bench["per_layer"]:
-        if "qwen3next-longctx" in metric.get("workloads", ()):
-            metric["workloads"].append("tiny-qwen3-next-ctx")
-    (TREE_Q3N / "BENCHMARK.json").write_text(json.dumps(bench))
-    return TREE_Q3N
-
-
-# sound 3.4e-7 .. 4.0e-7 (float32's order of sums); the state emptied at a
-# prompt's last chunk boundary, beta left out and the output gate left out
-# read thousands of times that (below); an int8-weight server's projection
-# reads 1.0
-Q3N_LIMIT = 4e-6
-
-
-def test_qwen3_next_cell_rehearsal_is_correct(tree_q3n):
-    rc, last, out = _run(tree_q3n, "--workload", "tiny-qwen3-next-ctx",
-                         "--seed", str(2**31 + 39), "--seconds", "3",
-                         "--trace", "1")
-    assert last is not None and rc == 0, out[-3000:]
-    assert last["correct"] is True and last["failed"] == 0, out[-3000:]
-    # the two sessions' first requests: a third starts only once one has
-    # finished, which a window of seconds does not promise on a machine six
-    # test workers share (2 attempted where a run alone attempts 12)
-    assert last["attempted"] >= 2
-    # a CPU run reports no device metric under a device metric's name
-    for name in ("chunk_gdn_ms_p50", "step_gdn_ms_p50", "gdn_rule_roofline",
-                 "gdn_state_move_share", "device_idle_share"):
-        assert name not in last["metrics"]
-
-
-def test_qwen3_next_cell_rehearsal_int8_server_is_not_correct(tree_q3n):
-    rc, last, out = _run(
-        tree_q3n, "--workload", "tiny-qwen3-next-ctx", "--seed", "17",
-        "--seconds", "2", "--trace", "0", "--server-arg=--weight-quant",
-        "--server-arg=int8")
-    assert last is not None and last["correct"] is False, out[-3000:]
-    assert rc != 0
-    got = _compared(out)
-    assert got["int8_projection_median"][0] == pytest.approx(1.0, abs=0.05)
-
-
-@pytest.mark.parametrize("fault", ["state_reset", "beta", "attn_gate"])
-def test_qwen3_next_cell_rehearsal_sees_a_planted_fault(
-        tree_q3n, tmp_path, fault):
-    """The timed path BROKEN underneath the harness, in a copy of the
-    program (`scripts/plant_gdn_fault.py`, which planted the same three on
-    the chip): the state emptied at a prompt's last chunk boundary, beta
-    left out of the update, the attention output gate left out. The served
-    tokens still come, no request fails, and `correct` is false by the
-    logit error."""
-    broken = tmp_path / "tree"
-    shutil.copytree(tree_q3n, broken, symlinks=True)
-    (broken / "bloombee_tpu").unlink()
-    shutil.copytree(ROOT / "bloombee_tpu", broken / "bloombee_tpu",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    spec = importlib.util.spec_from_file_location(
-        "plant_gdn_fault", ROOT / "scripts" / "plant_gdn_fault.py")
-    planter = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(planter)
-    planter.plant(broken, fault)
-    rc, last, out = _run(broken, "--workload", "tiny-qwen3-next-ctx",
-                         "--seed", "23", "--seconds", "2", "--trace", "0")
-    assert last is not None and last["correct"] is False, out[-3000:]
-    assert last["failed"] == 0 and rc != 0
-    err, limit = _compared(out)["logit_err_median"]
-    assert err > 10 * limit, (err, limit)

@@ -452,7 +452,7 @@ def test_the_flash_form_names_the_attention_layers_a_span_holds(
         keys = 4 * chunk_run_pages(128, window, 4, 256)
         want[kind] = "x".join(map(str, flash_tiles(
             128, keys, spec.gqa_groups, spec.head_dim, 4)))
-    assert ex._flash_form(128, 256) == ",".join(
+    assert ex._flash_form(128, 256) == "+".join(
         f"{k}:{v}" for k, v in want.items())
     assert ex.flash_form == ex._flash_form(128, 256)
     assert ex._flash_form(16, 256) is None
