@@ -115,6 +115,7 @@ def _register_builtins() -> None:
     import bloombee_tpu.models.falcon_h1  # noqa: F401
     import bloombee_tpu.models.gemma2  # noqa: F401
     import bloombee_tpu.models.gemma4  # noqa: F401
+    import bloombee_tpu.models.kimi_linear  # noqa: F401
     import bloombee_tpu.models.mistral  # noqa: F401
     import bloombee_tpu.models.mixtral  # noqa: F401
     import bloombee_tpu.models.phi4flash  # noqa: F401

@@ -114,6 +114,25 @@ def stack_expert_weights(
     }
 
 
+def split_query_rows(q, heads: int, nope: int, rope: int, dtype=None,
+                     perm=None) -> dict:
+    """The query's up-projection (`q_b_proj` [heads * (nope + rope),
+    q_rank], or a full-rank `q_proj` [.., D]) as the rows that make q_nope
+    and the rows that make q_pe, each a projection of its own (cut out of
+    the fused product, every layer re-laid its weight out first); `perm`
+    de-interleaves the rotary rows."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    q = np.asarray(q).reshape(heads, nope + rope, -1)
+    q_rope = q[:, nope:] if perm is None else q[:, nope:][:, perm]
+    return {
+        "q_b_nope": jnp.asarray(
+            q[:, :nope].reshape(heads * nope, -1), dtype=dtype),
+        "q_b_rope": jnp.asarray(q_rope.reshape(heads * rope, -1), dtype=dtype),
+    }
+
+
 def load_spec(model_dir: str, experts=None) -> ModelSpec:
     """ModelSpec from a local model dir via the family registry."""
     from bloombee_tpu.models.auto import get_family
@@ -134,7 +153,7 @@ def _stack_settled(per_layer: list):
     return jax.block_until_ready(stack_params(per_layer))
 
 
-def _stack_runs(layers: list[dict], period: int = 0,
+def _stack_runs(layers: list[dict], period_runs: tuple = (),
                 runs: list[str] | None = None) -> dict:
     """Per-layer params -> the stacked dict, leaf by leaf, letting go of
     each layer's tensor once it is stacked (the span is never held twice).
@@ -142,12 +161,13 @@ def _stack_runs(layers: list[dict], period: int = 0,
     layers have other keys than the rest (a dense MLP before sparse ones)
     is two, the leading run under models/layout.py `LEAD`. Each leaf's stack
     is settled before the next leaf's layers are let go (`_stack_settled`).
-    `period` > 0:
-    the kinds interleave, the last layer of every `period` of another kind
-    than the ones before it: one stack a POSITION in the period, each
-    [periods, ...], the j-th leading layers' under `linear_prefix(j)`, the
-    closing layers' under the plain keys. `runs`: each layer's run of a
-    SambaY span ("a" | "b" | "c")."""
+    `period_runs` (`ModelSpec.period_runs`): the kinds interleave, the last
+    layer of every period of another kind than the ones before it: one stack
+    a POSITION in the period, each [periods, ...], the j-th leading layers'
+    under `linear_prefix(j)`, the closing layers' under the plain keys; of
+    two runs of like periods (the model's leading dense layer inside the
+    first period, or a short last period) the first's stacks under `LEAD`
+    besides. `runs`: each layer's run of a SambaY span ("a" | "b" | "c")."""
     from bloombee_tpu.models.layout import LEAD, linear_prefix
 
     stack_params = _stack_settled
@@ -167,13 +187,20 @@ def _stack_runs(layers: list[dict], period: int = 0,
                         [p.pop(key) for p in stack]
                     )
         return out
-    if period:
-        out = {}
-        for j in range(period):
-            prefix = linear_prefix(j) if j < period - 1 else ""
-            run = layers[j::period]
-            for key in list(run[0]):
-                out[prefix + key] = stack_params([p.pop(key) for p in run])
+    if period_runs:
+        out, at = {}, 0
+        for r, (kinds, periods) in enumerate(period_runs):
+            per = len(kinds)
+            lead = LEAD if r == 0 and len(period_runs) > 1 else ""
+            mine = layers[at : at + periods * per]
+            at += periods * per
+            for j in range(per):
+                prefix = lead + (linear_prefix(j) if j < per - 1 else "")
+                run = mine[j::per]
+                for key in list(run[0]):
+                    out[prefix + key] = stack_params(
+                        [p.pop(key) for p in run]
+                    )
         return out
     kinds = [frozenset(p) for p in layers]
     cut = next((i for i, k in enumerate(kinds) if k != kinds[0]), len(layers))
@@ -216,9 +243,10 @@ def load_span_params(
         )
     if spec.gdn is not None and adapter_dirs:
         raise ValueError(
-            f"LoRA adapters unsupported for {spec.family}: q_proj is stored "
-            "split into its query rows and its gate rows, and the linear "
-            "layers have no projection an adapter names"
+            f"LoRA adapters unsupported for {spec.family}: the full layers' "
+            "q_proj is stored split (query and gate rows, or a latent "
+            "query's nope and rope rows), and the linear layers have no "
+            "projection an adapter names"
         )
     adapters = [LoraAdapter(d) for d in (adapter_dirs or [])]
     layers = []
@@ -233,8 +261,10 @@ def load_span_params(
         return tuple(layers), spec
     if spec.mamba is not None:
         return _stack_runs(layers, runs=sambay_runs(spec, start, end)), spec
-    period = len(spec.layer_types) if spec.gdn is not None else 0
-    return _stack_runs(layers, period), spec
+    return _stack_runs(
+        layers,
+        spec.period_runs(start, end) if spec.gdn is not None else (),
+    ), spec
 
 
 def sambay_runs(spec: ModelSpec, start: int, end: int) -> list[str]:

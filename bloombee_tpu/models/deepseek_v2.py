@@ -9,7 +9,9 @@ What a layer holds, stored the way the step programs read it
 `kv_b_k` and its value half `kv_b_v`, each [heads, dim, kv_rank], which the
 ABSORBED attention contracts directly, and `q_b_proj` into the rows that
 make q_nope (`q_b_nope`) and those that make q_pe (`q_b_rope`)
-(runtime/layer_body.py); `o_proj` [in, out]. A dense layer has `gate/up/down_proj`; a sparse one `router_t`
+(runtime/layer_body.py); `o_proj` [in, out]. With `q_lora_rank` null
+(V2-Lite) there is no `q_a_proj` / `q_a_norm` and the two keys hold the
+full-rank `q_proj`'s rows, fed the hidden rows (`MlaSpec.q_rank` 0). A dense layer has `gate/up/down_proj`; a sparse one `router_t`
 [E, D] over ALL the model's experts, the stacks `experts_*` of the experts
 this server HOLDS ([first, first + count) of the published numbering:
 `run_server --experts`, default every expert the checkpoint has) and the
@@ -34,6 +36,7 @@ from bloombee_tpu.models.checkpoint import (
     read_tensor as _t,
     read_weight,
     refine_held,
+    split_query_rows,
     stack_expert_weights,
 )
 from bloombee_tpu.models.spec import MlaSpec, ModelSpec
@@ -46,11 +49,6 @@ def deepseek_v2_spec_from_hf(config: Any) -> ModelSpec:
 
     if get("attention_bias", False):
         raise NotImplementedError("deepseek_v2 with attention_bias")
-    if not get("q_lora_rank"):
-        raise NotImplementedError(
-            "deepseek_v2 without q_lora_rank (V2-Lite's full-rank queries) "
-            "is not supported"
-        )
     if get("moe_layer_freq", 1) != 1:
         raise NotImplementedError("deepseek_v2 with moe_layer_freq != 1")
     if get("norm_topk_prob", False):
@@ -87,7 +85,8 @@ def deepseek_v2_spec_from_hf(config: Any) -> ModelSpec:
         # form without renormalisation, times the scale
         moe_pre_softmax=True,
         mla=MlaSpec(
-            q_rank=config.q_lora_rank,
+            # null: V2-Lite's ONE full-rank q_proj, no query norm
+            q_rank=get("q_lora_rank") or 0,
             kv_rank=config.kv_lora_rank,
             nope_dim=config.qk_nope_head_dim,
             rope_dim=config.qk_rope_head_dim,
@@ -119,11 +118,9 @@ def _load_block(reader, layer_idx: int, dtype=None) -> dict:
         "post_attention_layernorm": _t(
             reader, f"{p}.post_attention_layernorm.weight", dtype
         ),
-        "q_a_norm": _t(reader, f"{a}.q_a_layernorm.weight", dtype),
         "kv_a_norm": _t(reader, f"{a}.kv_a_layernorm.weight", dtype),
     }
-    for key in ("q_a_proj", "o_proj"):
-        params[key] = read_weight(reader, f"{a}.{key}.weight", key, dtype)
+    params["o_proj"] = read_weight(reader, f"{a}.o_proj.weight", "o_proj", dtype)
     heads = cfg["num_attention_heads"]
     nope, rope, vd = (cfg["qk_nope_head_dim"], cfg["qk_rope_head_dim"],
                       cfg["v_head_dim"])
@@ -136,18 +133,15 @@ def _load_block(reader, layer_idx: int, dtype=None) -> dict:
     params["kv_a_proj"] = jnp.asarray(
         np.concatenate([kv_a[:kvr], kv_a[kvr:][perm]]), dtype=dtype
     )
-    # q_b: torch [heads * (nope + rope), q_rank] -> the rows that make
-    # q_nope and the rows that make q_pe, each a projection of its own (cut
-    # out of the fused product, every layer re-laid its weight out first)
-    q_b = np.asarray(reader.tensor(f"{a}.q_b_proj.weight")).reshape(
-        heads, nope + rope, cfg["q_lora_rank"]
-    )
-    params["q_b_nope"] = jnp.asarray(
-        q_b[:, :nope].reshape(heads * nope, -1), dtype=dtype
-    )
-    params["q_b_rope"] = jnp.asarray(
-        q_b[:, nope:][:, perm].reshape(heads * rope, -1), dtype=dtype
-    )
+    if cfg.get("q_lora_rank"):
+        params["q_a_norm"] = _t(reader, f"{a}.q_a_layernorm.weight", dtype)
+        params["q_a_proj"] = read_weight(
+            reader, f"{a}.q_a_proj.weight", "q_a_proj", dtype
+        )
+        q_up = reader.tensor(f"{a}.q_b_proj.weight")
+    else:  # ONE full-rank projection: the same rows, fed the hidden rows
+        q_up = reader.tensor(f"{a}.q_proj.weight")
+    params.update(split_query_rows(q_up, heads, nope, rope, dtype, perm))
     # kv_b: torch [heads * (nope + v), kv_rank] -> the key half and the value
     # half, split on the host so the device never holds the fused tensor too
     kv_b = np.asarray(reader.tensor(f"{a}.kv_b_proj.weight")).reshape(

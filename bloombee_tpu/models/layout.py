@@ -47,15 +47,19 @@ LANES = 128  # the TPU's vector width: a minor dimension is tiled by it
 LEAD = "lead."
 
 
-# A span whose layer kinds INTERLEAVE with a period (qwen3_next: linear,
-# linear, linear, full) is stored as one stack a POSITION in the period:
+# A span whose layer kinds INTERLEAVE with a period (qwen3_next,
+# kimi_linear: linear, linear, linear, full) is stored as one stack a
+# POSITION in the period:
 # the j-th linear layer of every period under `linear_prefix(j)`, the full
 # layers under the plain keys, each [periods, ...]. The step scans PERIODS
 # and runs a period's layers in order (runtime/step.py `_scan_periods`),
 # each stack an xs of its own: ONE stack [periods, 3, ...] for the linear
 # kind made every period copy its three layers out of it before the first
 # ran (2.4 GB of experts at qwen3-next's widths: the slice has three
-# consumers and is no view of any).
+# consumers and is no view of any). Where one period differs from the rest
+# (kimi_linear: the model's leading dense layer stands in the first, the
+# last is short) the span is two runs of like periods, the first's stacks
+# under `LEAD` besides (`lead.lin0.gate_proj`), each run a scan of its own.
 LINEAR = "lin"
 
 
@@ -92,9 +96,9 @@ def split_sambay(stacked: dict) -> dict[str, tuple[dict, dict]]:
 
 
 def plain_key(key: str) -> str:
-    """A stacked dict's key without its run's prefix."""
+    """A stacked dict's key without its run's and its position's prefix."""
     if key.startswith(LEAD):
-        return key[len(LEAD):]
+        key = key[len(LEAD):]
     head, dot, rest = key.partition(".")
     if dot and len(head) == 3 and head[0] == "s" and head[1] in SAMBAY_RUNS \
             and head[2] in "01":
@@ -139,12 +143,12 @@ def stacked_layers(stacked: dict) -> int:
             2 * jax.tree.leaves(mixers)[0].shape[0]
             for mixers, _ in runs.values()
         )
-    linear, main = split_kinds(stacked)
-    if linear:
-        return jax.tree.leaves(main)[0].shape[0] * (len(linear) + 1)
-    lead, main = split_runs(stacked)
-    n = jax.tree.leaves(main)[0].shape[0]
-    return n if lead is None else n + jax.tree.leaves(lead)[0].shape[0]
+    total = 0
+    for run in split_runs(stacked):
+        if run:  # its periods (layers, where the kinds do not interleave)
+            linear, full = split_kinds(run)
+            total += jax.tree.leaves(full)[0].shape[0] * (len(linear) + 1)
+    return total
 
 
 def in_axis_of(key: str) -> int:

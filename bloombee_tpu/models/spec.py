@@ -59,14 +59,23 @@ class SsmSpec:
 
 @dataclasses.dataclass(frozen=True)
 class GdnSpec:
-    """A gated-DeltaNet linear-attention mixer IN PLACE of attention in the
-    layers of kind "linear" (qwen3_next: three of every four). Nested and
-    hashable like `SsmSpec`. Per sequence and linear layer the mixer keeps
-    ONE matrix a value head, S [value_heads, key_dim, value_dim], and the
-    last `conv - 1` rows of the convolution's input (channels q | k | v);
-    both live in the state arena, which then has a row a LINEAR layer and
-    none for the others (`ModelSpec.cache_rows`). Key head g serves value
-    heads g * r .. g * r + r - 1, r = value_heads // key_heads."""
+    """A delta-rule linear-attention mixer IN PLACE of attention in the
+    layers of kind "linear" (qwen3_next, kimi_linear: three of every four).
+    Nested and hashable like `SsmSpec`. Per sequence and linear layer the
+    mixer keeps ONE matrix a value head, S [value_heads, key_dim,
+    value_dim], and the last `conv - 1` rows of the convolution's input
+    (channels q | k | v); both live in the state arena, which then has a row
+    a LINEAR layer and none for the others (`ModelSpec.cache_rows`). Key
+    head g serves value heads g * r .. g * r + r - 1, r = value_heads //
+    key_heads.
+
+    Two published forms, one mixer (runtime/layer_body.py `_gdn_mixer`), the
+    differences read from here. Gated DeltaNet (qwen3_next, the defaults):
+    ONE scalar decay a head, in_proj makes q | k | v | z, the output is
+    norm(o) * silu(z). Kimi delta attention (kimi_linear): `channel_decay`,
+    a decay a KEY CHANNEL, made by a low-rank pair of `gate_rank` (f_a, f_b)
+    with a dt_bias a channel; in_proj makes q | k | v only; the output is
+    norm(o) * sigmoid(gate), the gate a second low-rank pair (g_a, g_b)."""
 
     key_heads: int
     value_heads: int
@@ -74,6 +83,9 @@ class GdnSpec:
     value_dim: int  # per head
     conv: int  # depthwise causal convolution width
     chunk: int = 64  # block length of the chunk form (triangular inside)
+    channel_decay: bool = False  # the decay is a vector over key_dim (KDA)
+    gate_rank: int = 0  # > 0: the decay and a SIGMOID output gate come from
+    # low-rank pairs of this rank; 0: `a` and a silu(z) gate out of in_proj
 
     @property
     def d_key(self) -> int:
@@ -90,8 +102,15 @@ class GdnSpec:
 
     @property
     def proj_dim(self) -> int:
-        """in_proj's output as stored: q | k | v | z."""
-        return self.conv_dim + self.d_value
+        """in_proj's output as stored: q | k | v | z (no z where the gate
+        has projections of its own)."""
+        return self.conv_dim + (0 if self.gate_rank else self.d_value)
+
+    @property
+    def scope(self) -> str:
+        """The prefix of the mixer's device scopes (`<scope>_proj`,
+        `<scope>_conv`, `<scope>_rule`): a trace tells the two rules apart."""
+        return "kda" if self.channel_decay else "gdn"
 
     @property
     def state_shape(self) -> tuple[int, ...]:
@@ -140,14 +159,17 @@ SAMBAY_OWNS = {"mamba": "state", "sliding": "kv", "full": "kv", "gmu": None,
 
 @dataclasses.dataclass(frozen=True)
 class MlaSpec:
-    """Multi-head latent attention (deepseek_v2): queries and keys/values
-    go through low-rank projections with their own RMSNorms, and the cache
-    keeps per token and layer ONE latent of `kv_rank` values (after its
-    norm) and ONE rotary key of `rope_dim` values shared by all heads (after
-    rotary) instead of per-head K and V. Nested and hashable like `SsmSpec`;
-    it declares the page payload the arena has to hold (`page_payload`)."""
+    """Multi-head latent attention (deepseek_v2; kimi_linear's full layers):
+    queries and keys/values go through low-rank projections with their own
+    RMSNorms, and the cache keeps per token and layer ONE latent of
+    `kv_rank` values (after its norm) and ONE shared key of `rope_dim`
+    values for all heads (after rotary, where the family has positions
+    there: `rope`) instead of per-head K and V. Nested and hashable like
+    `SsmSpec`; it declares the page payload the arena has to hold
+    (`page_payload`)."""
 
-    q_rank: int  # q_lora_rank
+    q_rank: int  # q_lora_rank; 0: ONE full-rank q_proj and no query norm
+    # (DeepSeek-V2-Lite, kimi_linear), stored as its nope and rope rows
     kv_rank: int  # kv_lora_rank: the cached latent's width
     nope_dim: int  # per head, the part of q/k without positions
     rope_dim: int  # per head q, ONE shared k: the rotary part
@@ -159,6 +181,9 @@ class MlaSpec:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # False (kimi_linear's `mla_use_nope`): the `rope_dim` columns of the
+    # queries and of the shared key are plain score dimensions, no rotary
+    rope: bool = True
 
     @property
     def qk_dim(self) -> int:
@@ -224,9 +249,11 @@ class ModelSpec:
     # "sliding" | "full" where window layers stand among full ones (gemma2,
     # gemma4, afmoe; ("sliding",) alone: mistral) and a kind sets the
     # attention's window, perhaps its rotary base or whether it has positions
-    # at all (`rope_window_only`); "linear" | "full" where a gated-DeltaNet
-    # mixer takes attention's place (`gdn`); all 32 layers' kinds of a SambaY
-    # stack (`mamba`). A kind never says which MLP a layer has (`mlp_kind`).
+    # at all (`rope_window_only`); "linear" | "full" where a delta-rule
+    # mixer takes attention's place (`gdn`: one period, qwen3_next, or every
+    # layer's kind where the model ends on a short period, kimi_linear); all
+    # 32 layers' kinds of a SambaY stack (`mamba`). A kind never says which
+    # MLP a layer has (`mlp_kind`).
     layer_types: tuple[str, ...] = ()
     sliding_window: int = 0
     # Falcon/Bloom-style extras
@@ -287,8 +314,8 @@ class ModelSpec:
     moe_held: tuple[int, int] | None = None
     # the shared expert's output is scaled by sigmoid(x @ w), w [D]
     moe_shared_gate: bool = False
-    # a gated-DeltaNet mixer in the layers `layer_types` calls "linear"
-    # (qwen3_next); None = every layer attends
+    # a delta-rule mixer in the layers `layer_types` calls "linear"
+    # (qwen3_next, kimi_linear); None = every layer attends
     gdn: GdnSpec | None = None
     # rotary on the first `rotary_dim` of a head's dims only (0 = all)
     rotary_dim: int = 0
@@ -331,7 +358,9 @@ class ModelSpec:
         """For each layer of the span [start, end): which arena it uses
         ("state" | "kv" | "none") and its row there, the layer's index
         AMONG ITS KIND in the span. Only a family whose kinds differ in
-        their cache (`gdn`, `mamba`) has arenas of fewer rows than layers.
+        their cache (`gdn`, `mamba`) has arenas of fewer rows than layers;
+        the K/V arena's rows are latent pages where the full layers attend
+        latents (`mla` beside `gdn`: kimi_linear).
         A SambaY cross layer owns no row and READS the full layer's: its
         entry is ("kv", that row); a gated memory unit's is ("none", -1)."""
         rows, n = [], {"state": 0, "kv": 0}
@@ -381,12 +410,15 @@ class ModelSpec:
 
     def span_unsupported(self, start: int, end: int) -> str | None:
         """Why this family cannot serve the span [start, end); None when it
-        can. A periodic pattern of layer kinds is scanned period by period
-        (runtime/step.py `_scan_periods`), so a span holds whole periods
-        from a period's first layer. Window layers among full ones (gemma2,
-        afmoe) are ONE kind of cache and one scan, the window a value that
-        rides it: such a span may be cut anywhere, and so may a dense layer
-        before sparse ones (two runs, `_scan_runs`)."""
+        can. Linear layers among full ones are scanned period by period
+        (runtime/step.py `_scan_periods`), a period ending on its full
+        layer, so a span holds whole periods from a period's first layer:
+        runs of LIKE periods (`period_runs`), at most two (the period with
+        the model's leading dense layer before the others, or the model's
+        short last period after them). Window layers among full ones
+        (gemma2, afmoe) are ONE kind of cache and one scan, the window a
+        value that rides it: such a span may be cut anywhere, and so may a
+        dense layer before sparse ones (two runs, `_scan_runs`)."""
         if self.mamba is not None:
             shared = self.cross_start - 2  # the last mamba layer: `m`'s source
             if start % 2 or end % 2 or end <= start:
@@ -406,15 +438,58 @@ class ModelSpec:
             return None
         if self.gdn is None:
             return None
-        per = len(self.layer_types)
-        if start % per or (end - start) % per or end <= start:
+        if (
+            end <= start or end > self.num_hidden_layers
+            or (start and self.layer_type(start - 1) != "full")
+            or self.layer_type(end - 1) != "full"
+        ):
+            per = self.period
+            tail = self.num_hidden_layers % per
             return (
                 f"a {self.family} span must hold whole periods of "
-                f"{per} layers {self.layer_types} from a period's first "
-                f"layer (got [{start}, {end})): the step scans periods, and "
+                f"{per} layers {self.layer_types[:per]} from a period's "
+                f"first layer" + (
+                    f" (the model's last period has {tail})" if tail else ""
+                ) + f" (got [{start}, {end})): the step scans periods, and "
                 "each kind's stack and arena have one row a period's layer"
             )
+        if len(self.period_runs(start, end)) > 2:
+            return (
+                f"a {self.family} span may hold two runs of like periods "
+                f"(got [{start}, {end}): "
+                f"{[n for _, n in self.period_runs(start, end)]} periods of "
+                "three kinds: the period with the leading dense layer, "
+                "the whole ones, the model's short last one)"
+            )
         return None
+
+    @property
+    def period(self) -> int:
+        """Layers of the FIRST period of a family whose linear layers stand
+        among full ones: up to and with its full layer."""
+        return self.layer_types.index("full") + 1
+
+    def period_runs(
+        self, start: int, end: int
+    ) -> tuple[tuple[tuple[str, ...], int], ...]:
+        """The span [start, end) of whole periods as runs of LIKE periods:
+        ((what each layer of a period is, how many such periods), ...). A
+        layer is "linear" | "full", with "+dense" where its MLP is dense
+        and the family's others have experts: periods differ where the
+        model's leading dense layer stands in one, or the last is short."""
+        runs, period = [], []
+        for i in range(start, end):
+            kind = self.layer_type(i)
+            if self.num_experts and self.mlp_kind(i) == "dense":
+                kind += "+dense"
+            period.append(kind)
+            if kind.startswith("full"):
+                if runs and runs[-1][0] == tuple(period):
+                    runs[-1][1] += 1
+                else:
+                    runs.append([tuple(period), 1])
+                period = []
+        return tuple((sig, n) for sig, n in runs)
 
     @property
     def experts_held(self) -> tuple[int, int]:

@@ -53,6 +53,10 @@ QUANT_KEYS = (
     # mixer's two wide projections (b | a, the taps, A_log, dt_bias and the
     # norms stay as the checkpoint has them)
     "q_gate_proj", "gdn_in_proj", "gdn_out_proj",
+    # kimi_linear: KDA's q | k | v and o are those two keys, its full-rank
+    # latent queries `q_b_nope` / `q_b_rope` above; the low-rank gates
+    # (`gdn_low_proj`, `gdn_f_b_proj`, `gdn_g_b_proj`) stay as the checkpoint
+    # has them, like qwen3_next's b | a
     # phi4flash: the Mamba-1 mixer's and the gated memory unit's two wide
     # projections each (x_proj, dt_proj, the taps, A, D and the norms stay as
     # the checkpoint has them)

@@ -265,8 +265,10 @@ class SpanExecutor:
         if spec.gdn is not None:
             if adapters:
                 raise ValueError(
-                    f"LoRA adapters unsupported for {spec.family}: q_proj is "
-                    "stored split into its query rows and its gate rows"
+                    f"LoRA adapters unsupported for {spec.family}: the "
+                    "full layers' q_proj is stored split (query and gate "
+                    "rows, or a latent query's nope and rope rows) and the "
+                    "linear layers have no projection an adapter names"
                 )
         if spec.mla is not None:
             # the latent page is attended by the single-chip span step's own

@@ -36,14 +36,17 @@ code path. Rows past a sequence's real count (bucket tails, padding rows) get
 dt = 0, which neither decays nor feeds the state, and are left out of the
 convolution's new tail.
 
-A family whose layer kinds differ (`spec.gdn`, qwen3_next) has LINEAR layers
-with a gated-DeltaNet mixer in attention's place (`_gdn_layer`: the scopes
-`gdn_proj` (in_proj, gated norm, out_proj), `gdn_conv`, `gdn_rule` (one rule
-step a decode row, the triangular chunk form for a prefill chunk) and
-`state_io`), told from its FULL layers by the keys their params hold. The
-full layers run the generic body with a gated query projection
-(`attn_gate`), rotary on the first `rotary_dim` dims and `1 + w` norms; a
-fused pack of theirs is attended sequence by sequence (`_attend_by_rows`).
+A family whose layer kinds differ (`spec.gdn`: qwen3_next, kimi_linear) has
+LINEAR layers with a delta-rule mixer in attention's place (`_gdn_layer`:
+the scopes `gdn_proj` (in_proj, gated norm, out_proj), `gdn_conv`,
+`gdn_rule` (one rule step a decode row, the triangular chunk form for a
+prefill chunk) and `state_io`; `kda_proj`, `kda_conv`, `kda_rule` where the
+decay is a vector a key channel: `GdnSpec.scope`), told from its FULL layers
+by the keys their params hold. qwen3_next's full layers run the generic body
+with a gated query projection (`attn_gate`), rotary on the first
+`rotary_dim` dims and `1 + w` norms; a fused pack of theirs is attended
+sequence by sequence (`_attend_by_rows`). kimi_linear's full layers are
+latent attention without positions (`_mla_layer`, `MlaSpec.rope` False).
 
 A layer never owns its K/V slab as an array: the span step hands it the
 whole arena viewed flat plus slot and page ids already offset to the layer
@@ -62,7 +65,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from bloombee_tpu.kv.arena import arena_write, gather_pages, heads_view
-from bloombee_tpu.models.layout import in_axis_of, project
+from bloombee_tpu.models.layout import LANES, in_axis_of, project
 from bloombee_tpu.models.spec import ModelSpec
 from bloombee_tpu.models.wquant import maybe_dequantize
 from bloombee_tpu.ops import apply_rotary, rms_norm, silu_mlp
@@ -75,6 +78,8 @@ from bloombee_tpu.ops.pallas.flash_attention import BLOCK_K, flash_takes
 from bloombee_tpu.ops.linear_attention import (
     gdn_sequence,
     gdn_step,
+    kda_sequence,
+    kda_step,
     l2_normalize,
 )
 from bloombee_tpu.ops.ssm import conv_taps, ssd_sequence, ssm_step
@@ -418,54 +423,60 @@ def _place_rows(rows_c, r0, r: int):
 
 def _gdn_mixer(spec: ModelSpec, params: dict, x, state: dict, slots,
                rows: SsmRows):
-    """The gated-DeltaNet mixer (models/spec.py GdnSpec) on flat rows: x
+    """The delta-rule mixer (models/spec.py GdnSpec) on flat rows: x
     [R, D] (the layer's normed input) -> (m [R, D], the state arena).
     `state` is the flat state arena, `slots` [S] each sequence's row of it
     for THIS layer (out of range: read clamped, write dropped). A sequence
     with one row takes one rule step, one with more the chunk form
     (ops/linear_attention.py), exactly as `_ssm_mixer` tells them apart.
     Rows past a sequence's real count get beta = 0 and g = 0, which neither
-    decay nor feed S, and are left out of the convolution's new tail."""
+    decay nor feed S, and are left out of the convolution's new tail.
+
+    Gated DeltaNet (qwen3_next) and Kimi delta attention (kimi_linear) are
+    this one mixer: the convolution, the state's reads and writes and the
+    row bookkeeping are shared; what the descriptor tells apart is where
+    the decay and the output gate come from (`_gdn_inputs`), the rule (a
+    scalar or a vector decay) and the gate's activation, and the scopes'
+    prefix (`GdnSpec.scope`)."""
     gdn = spec.gdn
     r = x.shape[0]
     s = rows.row0.shape[0]
     hk, hv, dk, dv = gdn.key_heads, gdn.value_heads, gdn.key_dim, gdn.value_dim
     rep = hv // hk
     f32 = jnp.float32
-    with jax.named_scope("gdn_proj"):
-        # q | k | v | z, every cut a whole number of lanes (the loader
-        # regrouped in_proj_qkvz: models/qwen3_next.py)
-        # the products are taken at the STORED widths and cut after the
-        # barrier: without it the compiler moves a cut onto the weight and
-        # every layer copies its in_proj out of the stack first (50 MB a
-        # layer in a decode step, as Falcon-H1's in_proj did)
-        qkvz, ba = lax.optimization_barrier((
-            _proj(x, params, "gdn_in_proj"), _proj(x, params, "gdn_ba_proj"),
-        ))
-        qkv, z = qkvz[:, : gdn.conv_dim], qkvz[:, gdn.conv_dim :]
-        ba = ba.astype(f32)
-        lanes = ba.shape[-1] // 2
-        b, a = ba[:, :hv], ba[:, lanes : lanes + hv]
+    scope = gdn.scope
+    rule_step, rule_sequence = (
+        (kda_step, kda_sequence) if gdn.channel_decay
+        else (gdn_step, gdn_sequence)
+    )
+    with jax.named_scope(f"{scope}_proj"):
+        qkv, a, b, z = _gdn_inputs(gdn, params, x)
     with jax.named_scope("state_io"):
         tails = state["conv"].at[slots].get(mode="clip")
         tails = jnp.where(rows.fresh[:, None, None], 0, tails)
-    with jax.named_scope("gdn_conv"):
+    with jax.named_scope(f"{scope}_conv"):
         taps, new_tails = conv_taps(qkv, tails, rows.q_seq, rows.row0, rows.nt)
         conv = jax.nn.silu(jnp.einsum(
             "rkc,kc->rc", taps.astype(f32), params["gdn_conv_w"].astype(f32)
         ))
-    with jax.named_scope("gdn_rule"):
+    with jax.named_scope(f"{scope}_rule"):
         q = l2_normalize(conv[:, : gdn.d_key].reshape(r, hk, dk)) * dk**-0.5
         k = l2_normalize(
             conv[:, gdn.d_key : 2 * gdn.d_key].reshape(r, hk, dk)
         )
         # key head g serves value heads g * rep .. g * rep + rep - 1
-        q, k = jnp.repeat(q, rep, axis=1), jnp.repeat(k, rep, axis=1)
+        if rep > 1:
+            q, k = jnp.repeat(q, rep, axis=1), jnp.repeat(k, rep, axis=1)
         v = conv[:, 2 * gdn.d_key :].reshape(r, hv, dv)
         beta = jax.nn.sigmoid(b)
-        g = -jnp.exp(params["gdn_a_log"]) * jax.nn.softplus(
-            a + params["gdn_dt_bias"]
-        )
+        # [R, Hv], or with a decay a key channel [R, Hv, dk]
+        dt = jax.nn.softplus(a + params["gdn_dt_bias"].reshape(a.shape[1:]))
+        shape = (hv,) + (1,) * (dt.ndim - 2)
+        g = -jnp.exp(params["gdn_a_log"]).reshape(shape) * dt
+
+    def keep(mask):  # a row mask [n] against g [n, Hv] or [n, Hv, dk]
+        return mask[(slice(None),) + (None,) * (g.ndim - 1)]
+
     o = jnp.zeros((r, hv, dv), f32)
     oob = state["ssm"].shape[0]
     writes = []  # (slots [n], states [n, Hv, dk, dv])
@@ -475,9 +486,9 @@ def _gdn_mixer(spec: ModelSpec, params: dict, x, state: dict, slots,
         with jax.named_scope("state_io"):
             s0 = state["ssm"].at[slots].get(mode="clip")
             s0 = jnp.where(rows.fresh[:, None, None, None], 0.0, s0)
-        with jax.named_scope("gdn_rule"):
-            o_s, s_new = gdn_step(
-                q[at], k[at], v[at], jnp.where(one[:, None], g[at], 0.0),
+        with jax.named_scope(f"{scope}_rule"):
+            o_s, s_new = rule_step(
+                q[at], k[at], v[at], jnp.where(keep(one), g[at], 0.0),
                 jnp.where(one[:, None], beta[at], 0.0), s0,
             )
             o = o.at[jnp.where(one, rows.row0, r)].set(o_s, mode="drop")
@@ -490,16 +501,17 @@ def _gdn_mixer(spec: ModelSpec, params: dict, x, state: dict, slots,
         with jax.named_scope("state_io"):
             s0 = state["ssm"].at[slot_c].get(mode="clip")
             s0 = jnp.where(rows.fresh[c], 0.0, s0)  # gdn
-        with jax.named_scope("gdn_rule"):
+        with jax.named_scope(f"{scope}_rule"):
             def take(z_rows):
                 return z_rows if whole else _window_rows(z_rows, r0, w)
 
-            valid = (jnp.arange(w, dtype=jnp.int32) < n_c)[:, None]
-            o_c, s_c = gdn_sequence(
-                take(q), take(k), take(v), jnp.where(valid, take(g), 0.0),
-                jnp.where(valid, take(beta), 0.0), s0, gdn.chunk,
+            real = jnp.arange(w, dtype=jnp.int32) < n_c  # no bucket tail
+            o_c, s_c = rule_sequence(
+                take(q), take(k), take(v),
+                jnp.where(keep(real), take(g), 0.0),
+                jnp.where(real[:, None], take(beta), 0.0), s0, gdn.chunk,
             )
-            o_c = jnp.where(valid[:, :, None], o_c, 0.0)
+            o_c = jnp.where(real[:, None, None], o_c, 0.0)
             o = o + (o_c if whole else _place_rows(o_c, r0, r))
         writes.append((slot_c[None], s_c[None]))
     with jax.named_scope("state_io"):
@@ -512,14 +524,52 @@ def _gdn_mixer(spec: ModelSpec, params: dict, x, state: dict, slots,
                 new_tails.astype(state["conv"].dtype), mode="drop"
             ),
         }
-    with jax.named_scope("gdn_proj"):
-        # the gated norm: per value head, PLAIN weight, then silu(z)
+    with jax.named_scope(f"{scope}_proj"):
+        # the gated norm: per value head, PLAIN weight, then silu(z), or
+        # where the gate has projections of its own sigmoid(gate)
         y = o * lax.rsqrt(
             jnp.mean(o * o, axis=-1, keepdims=True) + spec.rms_norm_eps
         )
         y = params["gdn_norm"] * y.astype(x.dtype)
-        y = y * jax.nn.silu(z.reshape(r, hv, dv).astype(f32)).astype(x.dtype)
+        gate = jax.nn.sigmoid if gdn.gate_rank else jax.nn.silu
+        y = y * gate(z.reshape(r, hv, dv).astype(f32)).astype(x.dtype)
         return _proj(y.reshape(r, gdn.d_value), params, "gdn_out_proj"), state
+
+
+def _gdn_inputs(gdn, params: dict, x):
+    """The delta-rule mixer's projections of its normed input x [R, D]:
+    (q | k | v before the convolution [R, conv_dim], the decay's input
+    before its bias and softplus (float32 [R, Hv], or a key channel's
+    [R, Hv, dk]), beta before its sigmoid (float32 [R, Hv]), the output
+    gate before its activation [R, d_value])."""
+    f32 = jnp.float32
+    hv = gdn.value_heads
+    if gdn.gate_rank:
+        # Kimi delta attention: in_proj is q | k | v; ONE product makes the
+        # three narrow ones, f_a | g_a | b, each in lanes of its own
+        # (models/kimi_linear.py); the decay and the gate come up through
+        # their second halves
+        qkv, low = lax.optimization_barrier((
+            _proj(x, params, "gdn_in_proj"), _proj(x, params, "gdn_low_proj"),
+        ))
+        rank = gdn.gate_rank
+        a = _proj(low[:, :rank], params, "gdn_f_b_proj").astype(f32)
+        z = _proj(low[:, LANES : LANES + rank], params, "gdn_g_b_proj")
+        b = low[:, 2 * LANES : 2 * LANES + hv].astype(f32)
+        return qkv, a.reshape(-1, hv, gdn.key_dim), b, z
+    # q | k | v | z, every cut a whole number of lanes (the loader
+    # regrouped in_proj_qkvz: models/qwen3_next.py)
+    # the products are taken at the STORED widths and cut after the
+    # barrier: without it the compiler moves a cut onto the weight and
+    # every layer copies its in_proj out of the stack first (50 MB a
+    # layer in a decode step, as Falcon-H1's in_proj did)
+    qkvz, ba = lax.optimization_barrier((
+        _proj(x, params, "gdn_in_proj"), _proj(x, params, "gdn_ba_proj"),
+    ))
+    ba = ba.astype(f32)
+    lanes = ba.shape[-1] // 2
+    return (qkvz[:, : gdn.conv_dim], ba[:, lanes : lanes + hv], ba[:, :hv],
+            qkvz[:, gdn.conv_dim :])
 
 
 def _gdn_layer(spec, hidden, params, k_slab, v_slab, ssm, lora=None):
@@ -571,7 +621,11 @@ def _mla_attention(spec: ModelSpec, page_size: int, params: dict, x,
 
     def rope(z, cos, sin):
         # the loader stored the rotary rows de-interleaved (evens, then
-        # odds: models/deepseek_v2.py), so this is the plain half-rotation
+        # odds: models/deepseek_v2.py), so this is the plain half-rotation.
+        # A family without positions here (`mla.rope` False: kimi_linear)
+        # keeps the columns as plain score dimensions
+        if not mla.rope:
+            return z
         return z * cos.astype(z.dtype) + _rotate_half(z) * sin.astype(z.dtype)
 
     # everything per head is kept HEAD-major [H, R, .]: the head is the
@@ -579,10 +633,12 @@ def _mla_attention(spec: ModelSpec, page_size: int, params: dict, x,
     # their results, and the flash form takes and gives that layout
     with jax.named_scope("attn_proj"):
         with jax.named_scope("mla_q"):
+            # q_rank 0: ONE full-rank projection, the same two keys fed
+            # the hidden rows, and no query norm
             c_q = rms_norm(
                 _proj(x, params, "q_a_proj", lora), params["q_a_norm"],
                 spec.rms_norm_eps,
-            )
+            ) if mla.q_rank else x
             # the barrier keeps the per-head reshape on the PRODUCT: without
             # it a decode program moves it onto the weight and every layer
             # copies its q_b_rope out of the stack first (25 MB a layer)
@@ -803,13 +859,13 @@ def layer_body(
     rows: SsmRows | None = None,  # latent attention (spec.mla): whose the
     # flat rows are; k_slab / v_slab are then the latent and rotary-key slabs
 ):
+    if spec.gdn is not None and "gdn_in_proj" in params:
+        return _gdn_layer(spec, hidden, params, k_slab, v_slab, ssm, lora)
     if spec.mla is not None:
         return _mla_layer(
             spec, page_size, hidden, params, k_slab, v_slab, cos, sin, slots,
             page_table, q_positions, total_lens, rows, use_paged, lora,
         )
-    if spec.gdn is not None and "gdn_in_proj" in params:
-        return _gdn_layer(spec, hidden, params, k_slab, v_slab, ssm, lora)
     b, t, d = hidden.shape
     h_heads, kv_heads, hd = (
         spec.num_attention_heads,
@@ -1196,13 +1252,13 @@ def layer_body_ragged(
     position-wise, so they need no per-member structure — only attention
     does, and it gets it from (q_seq, q_positions) per row instead of
     layer_body's block-uniform (B, T)."""
+    if spec.gdn is not None and "gdn_in_proj" in params:
+        return _gdn_layer(spec, hidden, params, k_slab, v_slab, ssm, lora)
     if spec.mla is not None:
         return _mla_layer(
             spec, page_size, hidden, params, k_slab, v_slab, cos, sin, slots,
             page_table, q_positions, total_lens, rows, use_kernel, lora,
         )
-    if spec.gdn is not None and "gdn_in_proj" in params:
-        return _gdn_layer(spec, hidden, params, k_slab, v_slab, ssm, lora)
     _, r, d = hidden.shape
     h_heads, kv_heads, hd = (
         spec.num_attention_heads,

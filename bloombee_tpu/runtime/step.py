@@ -398,95 +398,129 @@ def _arena_dims(spec: ModelSpec, arena_k) -> tuple[int, int]:
 def _scan_periods(run_layer, spec, stacked_params, rows, kernels, hidden,
                   arena_k, arena_v, slots, page_table, layer_active,
                   per_layer, page_size, state, state_slots, ssm_rows):
-    """The layer scan of a span whose layer KINDS interleave with a period
-    (`spec.gdn`: linear, linear, linear, full): ONE scan over periods whose
-    body runs the period's layers in order. Its xs are one stack a position
-    in the period (models/layout.py `split_kinds`), each [periods, ...];
-    both flat arenas ride the carry whole. A layer's row in ITS arena is
-    its index among its kind: linear layer j of period p reads and writes
-    row p * m + j of the state arena and has no row in the K/V arena; the
-    full layer of period p has row p there and none in the state arena
+    """The layer scan of a span whose layer KINDS interleave, a period
+    ending on its full layer (`spec.gdn`: linear, linear, linear, full): a
+    scan over the periods of each RUN of like periods (one run, or two where
+    a period differs from the rest: the model's leading dense layer stands
+    in the first, or its last is short; `ModelSpec.period_runs`, the first
+    run's stacks under models/layout.py `LEAD`). A scan's body runs the
+    period's layers in order; its xs are one stack a position in the period
+    (`split_kinds`), each [periods, ...]; both flat arenas ride the carry
+    whole, from one run's scan into the next's. A layer's row in ITS arena is
+    its index among its kind in the span: linear layer j of the run's period
+    p reads and writes row `state_row0 + p * m + j` of the state arena and
+    has no row in the K/V arena; the period's full layer has row `kv_row0 +
+    p` there (K and V, or a latent page) and none in the state arena
     (`ModelSpec.cache_rows`). `layer_active` still gates layer by layer (a
     session entering mid-span). Returns what `_scan_runs` returns with a
-    state arena."""
-    linear, full = split_kinds(stacked_params)
-    m = len(linear)
-    per = m + 1
-    periods = jax.tree.leaves(full)[0].shape[0]
-    reach = spec.moe_held is not None
-    stacks = [
-        lift_expert_stacks(spec, params, rows, kernels)
-        for params in (*linear, full)
-    ]
+    state arena: on a server that holds a share of the experts the result
+    ends with the SPARSE layers' reach, in layer order."""
+    held = spec.moe_held is not None
     kv_layers, s_tot = _arena_dims(spec, arena_k)
     num_pages = s_tot // page_size
     state_layers, num_state_slots = state["ssm"].shape[:2]
-
-    def at(j):  # [layers, ...] xs beside the params -> position j's [periods]
-        return lambda x: None if x is None else jax.tree.map(
-            lambda a: a.reshape(periods, per, *a.shape[1:])[:, j], x
-        )
-
-    def body(carry, xs_p):
-        p, *by_position = xs_p
-        # the period's ONE full layer: its row of the K/V arena
-        slots_l = layer_slots(slots, p, s_tot, kv_layers)
-        pages_l = layer_pages(page_table, p, num_pages)
-        reached = []
-        for j, (active, params_l, *extras_l) in enumerate(by_position):
-            is_linear = j < m
-            experts = stacks[j][1]
-            if experts is not None:
-                params_l = {**params_l, **experts}
-            xs_l = (params_l, *extras_l)
-
-            def run(h, k_flat, v_flat, state_flat, xs_l=xs_l,
-                    is_linear=is_linear, row=p * m + j):
-                ssm_l = None
-                if is_linear:
-                    ssm_l = (
-                        state_flat,
-                        layer_state_slots(
-                            state_slots, row, num_state_slots, state_layers
-                        ),
-                        ssm_rows,
-                    )
-                with collecting_reach() as sown:
-                    out = run_layer(
-                        h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l
-                    )
-                out = out if is_linear else (*out, state_flat)
-                return (*out, sown[0]) if reach else out
-
-            def skip(h, k_flat, v_flat, state_flat):
-                out = (h, k_flat, v_flat, state_flat)
-                return (*out, jnp.zeros((3,), jnp.int32)) if reach else out
-
-            out = lax.cond(active > 0, run, skip, *carry)
-            carry = out[:4]
-            if reach:
-                reached.append(out[4])
-        return carry, (jnp.stack(reached) if reach else None)
-
-    (hidden, k_flat, v_flat, state_flat), reached = lax.scan(
-        body,
-        (hidden, flat_arena(arena_k), flat_arena(arena_v), flat_arena(state)),
-        (
-            jnp.arange(periods, dtype=jnp.int32),
-            *(
-                (at(j)(layer_active), stacks[j][0],
-                 *(at(j)(x) for x in per_layer))
-                for j in range(per)
-            ),
-        ),
+    carry = (
+        hidden, flat_arena(arena_k), flat_arena(arena_v), flat_arena(state)
     )
+    layer0 = kv_row0 = state_row0 = 0
+    reached_runs = []
+    lead, main = split_runs(stacked_params)
+    for run_params in ([main] if lead is None else [lead, main]):
+        linear, full = split_kinds(run_params)
+        m = len(linear)
+        per = m + 1
+        periods = jax.tree.leaves(full)[0].shape[0]
+        # a position's reach vector: none where its MLP is dense
+        fields = [
+            len(reach_fields("expert_bias" in params)) if held and (
+                "router" in params or "router_t" in params) else 0
+            for params in (*linear, full)
+        ]
+        stacks = [
+            lift_expert_stacks(spec, params, rows, kernels)
+            for params in (*linear, full)
+        ]
+
+        # (everything below is traced inside this iteration's `lax.scan`:
+        # the closures read the run's own values)
+        def at(j):
+            # [span's layers, ...] xs beside the params -> the run's
+            # position j's [periods, ...]
+            return lambda x: None if x is None else jax.tree.map(
+                lambda a: a[layer0 : layer0 + periods * per].reshape(
+                    periods, per, *a.shape[1:])[:, j], x
+            )
+
+        def body(carry, xs_p):
+            p, *by_position = xs_p
+            # the period's ONE full layer: its row of the K/V arena
+            slots_l = layer_slots(slots, kv_row0 + p, s_tot, kv_layers)
+            pages_l = layer_pages(page_table, kv_row0 + p, num_pages)
+            reached = []
+            for j, (active, params_l, *extras_l) in enumerate(by_position):
+                is_linear = j < m
+                experts = stacks[j][1]
+                if experts is not None:
+                    params_l = {**params_l, **experts}
+                xs_l = (params_l, *extras_l)
+
+                def run(h, k_flat, v_flat, state_flat, xs_l=xs_l,
+                        is_linear=is_linear, row=state_row0 + p * m + j,
+                        reach=fields[j]):
+                    ssm_l = None
+                    if is_linear:
+                        ssm_l = (
+                            state_flat,
+                            layer_state_slots(
+                                state_slots, row, num_state_slots,
+                                state_layers,
+                            ),
+                            ssm_rows,
+                        )
+                    with collecting_reach() as sown:
+                        out = run_layer(
+                            h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l
+                        )
+                    out = out if is_linear else (*out, state_flat)
+                    return (*out, sown[0]) if reach else out
+
+                def skip(h, k_flat, v_flat, state_flat, reach=fields[j]):
+                    out = (h, k_flat, v_flat, state_flat)
+                    return (
+                        (*out, jnp.zeros((reach,), jnp.int32)) if reach
+                        else out
+                    )
+
+                out = lax.cond(active > 0, run, skip, *carry)
+                carry = out[:4]
+                if fields[j]:
+                    reached.append(out[4])
+            return carry, (jnp.stack(reached) if reached else None)
+
+        carry, reached = lax.scan(
+            body, carry,
+            (
+                jnp.arange(periods, dtype=jnp.int32),
+                *(
+                    (at(j)(layer_active), stacks[j][0],
+                     *(at(j)(x) for x in per_layer))
+                    for j in range(per)
+                ),
+            ),
+        )
+        if reached is not None:
+            reached_runs.append(reached.reshape(-1, reached.shape[-1]))
+        layer0 += periods * per
+        kv_row0 += periods
+        state_row0 += periods * m
+    hidden, k_flat, v_flat, state_flat = carry
     out = (
         hidden,
         stacked_arena(k_flat, kv_layers),
         stacked_arena(v_flat, kv_layers),
         stacked_arena(state_flat, state_layers),
     )
-    return (*out, reached.reshape(periods * per, 3)) if reach else out
+    return (*out, jnp.concatenate(reached_runs)) if reached_runs else out
 
 
 def _scan_runs(run_layer, spec, stacked_params, rows, kernels, hidden,
@@ -752,8 +786,11 @@ def pack_chunk_on_flash(spec: ModelSpec) -> bool:
     sequence by sequence, where kernels run: the full layers among linear
     ones (layer_body.py `_attend_by_rows`) and a SambaY span
     (runtime/sambay.py `_diff_attend`). Every other family's pack takes the
-    ragged paged kernel, latent attention its own flash form."""
-    return spec.gdn is not None or spec.mamba is not None
+    ragged paged kernel, latent attention its own flash form (also where
+    its layers stand among linear ones: kimi_linear)."""
+    return (
+        spec.gdn is not None and spec.mla is None
+    ) or spec.mamba is not None
 
 
 def pack_ragged_ssm_tail(state_slots, row0, nt, chunk_seq: int):

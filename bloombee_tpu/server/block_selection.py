@@ -252,16 +252,22 @@ def estimate_block_bytes(spec, dtype, layer: int | None = None) -> int:
         layer = spec.num_hidden_layers - 1
     if spec.attn_gate:
         attn += d * h * hd + 2 * hd  # the gate rows of q_proj, q/k norms
-    if spec.gdn is not None and spec.layer_type(layer) == "linear":
-        # a gated-DeltaNet mixer in attention's place: in_proj (q | k | v |
-        # z), b | a as stored (two whole lanes), out_proj, the taps, the
-        # gated norm, A_log / dt_bias
+    linear = spec.gdn is not None and spec.layer_type(layer) == "linear"
+    if linear:
+        # a delta-rule mixer in attention's place: in_proj (q | k | v | z),
+        # b | a as stored (two whole lanes), out_proj, the taps, the gated
+        # norm, A_log / dt_bias; with a decay a key channel (kimi_linear)
+        # in_proj is q | k | v, the narrow products three whole lanes, the
+        # decay's and the gate's second halves, a dt_bias a channel
         from bloombee_tpu.models.layout import LANES
 
         g = spec.gdn
         attn = (
-            d * g.proj_dim + d * 2 * LANES + g.d_value * d
-            + g.conv * g.conv_dim + g.value_dim + 2 * g.value_heads
+            d * g.proj_dim + g.d_value * d + g.conv * g.conv_dim
+            + g.value_dim + g.value_heads
+        ) + (
+            d * 3 * LANES + g.gate_rank * (g.d_key + g.d_value) + g.d_key
+            if g.gate_rank else d * 2 * LANES + g.value_heads
         )
     if spec.mamba is not None:
         # a SambaY layer by its kind: q | k | v (k, v as PAIRS of 128) and
@@ -278,12 +284,18 @@ def estimate_block_bytes(spec, dtype, layer: int | None = None) -> int:
             "gmu": 2 * d * c,
             "cross": 2 * d * d + 2 * d + hd + 2,
         }.get(kind, 2 * d * d + 2 * d * kv * hd + 2 * d + 2 * kv * hd + hd + 2)
-    if spec.mla is not None:
+    if spec.mla is not None and not linear:
         m = spec.mla
+        # the query through its low-rank pair and norm, or ONE full-rank
+        # projection (`q_rank` 0)
+        query = (
+            d * m.q_rank + m.q_rank * h * m.qk_dim + m.q_rank if m.q_rank
+            else d * h * m.qk_dim
+        )
         attn = (
-            d * m.q_rank + m.q_rank * h * m.qk_dim + d * (m.kv_rank + m.rope_dim)
+            query + d * (m.kv_rank + m.rope_dim)
             + m.kv_rank * h * (m.nope_dim + m.v_dim) + h * m.v_dim * d
-            + m.q_rank + m.kv_rank
+            + m.kv_rank
         )
     if spec.num_experts and spec.mlp_kind(layer) == "sparse" and (
         spec.moe_intermediate_size
@@ -357,7 +369,7 @@ def choose_num_blocks(
     if spec.gdn is not None:
         # the kinds interleave: count by whole periods, each layer's own
         # weights and the ONE arena it has a row in
-        per = len(spec.layer_types)
+        per = spec.period
         kv_layers, state_layers = spec.arena_layers(0, per)
         period = (
             estimate_span_bytes(spec, dtype, 0, per)

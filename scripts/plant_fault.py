@@ -31,11 +31,36 @@ SAMBAY = PROGRAM / "runtime" / "sambay.py"
 MOE = PROGRAM / "ops" / "moe.py"
 DEEPSEEK_V2 = PROGRAM / "models" / "deepseek_v2.py"
 AFMOE = PROGRAM / "models" / "afmoe.py"
+KIMI_LINEAR = PROGRAM / "models" / "kimi_linear.py"
 
 ROUTED_SUM = (
     BODY,
     "                out = out + shared.astype(out.dtype)",
     "                out = shared.astype(out.dtype)",
+)
+# the delta-rule mixer's lines that qwen3_next and kimi_linear share
+QWEN3_NEXT_STATE_RESET = (
+    "a chunk with a padded tail (a prompt's LAST chunk) starts from an empty "
+    "state S: what the linear layers kept of the prompt before that chunk "
+    "boundary is lost",
+    BODY,
+    "            s0 = jnp.where(rows.fresh[c], 0.0, s0)  # gdn",
+    "            s0 = jnp.where(rows.fresh[c] | (n_c < w), 0.0, s0)  # gdn",
+)
+QWEN3_NEXT_BETA = (
+    "beta left out of the update (taken as 1): every token overwrites what "
+    "its key reads instead of blending into it",
+    BODY,
+    "        beta = jax.nn.sigmoid(b)",
+    "        beta = jnp.ones_like(b)",
+)
+# the sigmoid router's line that afmoe and kimi_linear share
+AFMOE_BIAS_CHOICE = (
+    "the router's bias (`expert_bias`) left out of its choice: the top-k of "
+    "the unbiased scores",
+    MOE,
+    "            scores if bias is None else scores + bias.astype(jnp.float32),",
+    "            scores,",
 )
 
 FAULTS = {
@@ -80,21 +105,8 @@ FAULTS = {
     },
     # qwen3next-longctx
     "qwen3_next": {
-        "state_reset": (
-            "a chunk with a padded tail (a prompt's LAST chunk) starts from "
-            "an empty state S: what the linear layers kept of the prompt "
-            "before that chunk boundary is lost",
-            BODY,
-            "            s0 = jnp.where(rows.fresh[c], 0.0, s0)  # gdn",
-            "            s0 = jnp.where(rows.fresh[c] | (n_c < w), 0.0, s0)  # gdn",
-        ),
-        "beta": (
-            "beta left out of the update (taken as 1): every token "
-            "overwrites what its key reads instead of blending into it",
-            BODY,
-            "        beta = jax.nn.sigmoid(b)",
-            "        beta = jnp.ones_like(b)",
-        ),
+        "state_reset": QWEN3_NEXT_STATE_RESET,
+        "beta": QWEN3_NEXT_BETA,
         "attn_gate": (
             "the full-attention layers' output gate left out",
             BODY,
@@ -137,13 +149,7 @@ FAULTS = {
     },
     # trinity-longctx
     "afmoe": {
-        "bias_choice": (
-            "`expert_bias` left out of the router's choice: the top-k of the "
-            "unbiased scores",
-            MOE,
-            "            scores if bias is None else scores + bias.astype(jnp.float32),",
-            "            scores,",
-        ),
+        "bias_choice": AFMOE_BIAS_CHOICE,
         "biased_weights": (
             "the weights taken from the BIASED scores of the chosen experts",
             MOE,
@@ -187,6 +193,40 @@ FAULTS = {
             '            math.sqrt(config.hidden_size) if get("mup_enabled", False)',
             '            1.0 if get("mup_enabled", False)',
         ),
+    },
+    # kimilinear-longctx
+    "kimi_linear": {
+        "scalar_decay": (
+            "a head's decay replaced by its mean over the 128 key channels: "
+            "the program computes Gated DeltaNet",
+            BODY,
+            '        g = -jnp.exp(params["gdn_a_log"]).reshape(shape) * dt',
+            '        g = -jnp.exp(params["gdn_a_log"]).reshape(shape) * ('
+            "dt * 0 + dt.mean(-1, keepdims=True))",
+        ),
+        "beta_out": QWEN3_NEXT_BETA,
+        "state_reset": QWEN3_NEXT_STATE_RESET,
+        "rope_in_full": (
+            "rotary applied to the 64 shared-key columns of the latent "
+            "layers' queries and keys (`mla_use_nope` ignored)",
+            BODY,
+            "        if not mla.rope:",
+            "        if False:",
+        ),
+        "gate_out": (
+            "the KDA mixer's sigmoid output gate left out",
+            BODY,
+            "        gate = jax.nn.sigmoid if gdn.gate_rank else jax.nn.silu",
+            "        gate = jnp.ones_like if gdn.gate_rank else jax.nn.silu",
+        ),
+        "route_scale_out": (
+            "`routed_scaling_factor` left out of the router's weights: the "
+            "routed experts count 1 / 2.446",
+            KIMI_LINEAR,
+            '        moe_route_scale=float(get("routed_scaling_factor", 1.0)),',
+            "        moe_route_scale=1.0,",
+        ),
+        "bias_out": AFMOE_BIAS_CHOICE,
     },
 }
 

@@ -49,6 +49,7 @@ from test_cellbench_families import (  # noqa: E402
     TINY_DEEPSEEK_V2,
     TINY_FALCON_H1,
 )
+from test_cellbench_kimi_linear import TINY_KIMI_LINEAR  # noqa: E402
 from test_cellbench_phi4flash import (  # noqa: E402
     TINY_PHI4FLASH,
     WIDE_PHI4FLASH,
@@ -197,6 +198,19 @@ ROWS = {
             # prompts of 171-300 tokens outgrow the 32-token window many
             # times over: four of the five layers hold mostly dead tokens
             _shares_in(window_dead_share=(50, 80)), _reach_metrics_read)),
+    "kimi_linear": Row(
+        presets={"tiny-kimi-linear": TINY_KIMI_LINEAR},
+        joins="kimilinear-longctx", server_flags=("--experts", "2:4"),
+        # sound 1.3e-6 (float32's order of sums; a router near-tie aside,
+        # as afmoe's); each fault reads hundreds of times the limit
+        limit=1e-4, sound_seed=2**31 + 51, int8_abs=0.15,
+        sound_seconds=6,  # a traced 1.8 s: room for a full chunk's reach span
+        faults=("scalar_decay", "rope_in_full", "gate_out"),
+        # the lines qwen3_next's and afmoe's rows plant
+        slow_faults=("beta_out", "state_reset", "route_scale_out", "bias_out"),
+        device_metrics=(
+            "chunk_kda_ms_p50", "step_kda_ms_p50", "kda_rule_roofline",
+            "kda_state_move_share", "chunk_mla_ms_p50", "device_idle_share")),
 }
 
 

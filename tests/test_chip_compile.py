@@ -1166,3 +1166,142 @@ def test_chosen_layout_holds_no_slab_sized_move(v5e, case):
         fn, shapes = _arena_part_of_a_decode_step(kv, hd, False)
         moved = _slab_moves(compile_(fn, shapes, donate=(1, 2)).as_text(), elems)
         assert len(moved) >= 2, moved  # K's slab and V's
+
+
+# ------------------------------------------------------------- kimi_linear
+# Kimi delta attention layers (a decay a key channel) with a position-free
+# latent-attention layer every fourth, a LATENT arena beside a state arena,
+# the leading dense layer inside the first period, 64 of 256 experts held:
+# the span steps of the benchmark's cell (cellbench/configs/
+# kimi-linear-48b-ep4-span8.json: published widths, two periods, 5376 pages)
+# at the 1024-page bucket its contexts take. The two periods differ (layer
+# 0's MLP is dense), so the span is two runs of one period each, the first
+# under `lead.`.
+def _kimi_linear_shapes(one_chip, pages=5376):
+    import dataclasses
+    import json
+    import pathlib
+
+    from bloombee_tpu.kv.cache_manager import state_slots_for
+    from bloombee_tpu.models.auto import spec_from_config_dict
+    from bloombee_tpu.models.layout import LANES, LEAD, linear_prefix
+
+    config = json.loads((
+        pathlib.Path(__file__).resolve().parents[1]
+        / "cellbench/configs/kimi-linear-48b-ep4-span8.json").read_text())
+    held = tuple(config["experts_held"])
+    spec = dataclasses.replace(
+        spec_from_config_dict(config), num_experts=config["router_experts"],
+        moe_held=held)
+    d, h, g, m = (spec.hidden_size, spec.num_attention_heads, spec.gdn,
+                  spec.mla)
+    f32 = jnp.float32
+
+    def s(*shape, dtype=bf16):
+        return jax.ShapeDtypeStruct((1, *shape), dtype, sharding=one_chip)
+
+    i, e, si = spec.moe_intermediate_size, held[1], spec.moe_shared_intermediate
+    norms = {"input_layernorm": s(d), "post_attention_layernorm": s(d)}
+    sparse = {
+        "router_t": s(spec.num_experts, d),
+        "expert_bias": s(spec.num_experts, dtype=f32),
+        "experts_gate": s(e, d, i), "experts_up": s(e, d, i),
+        "experts_down": s(e, i, d), "shared_gate": s(d, si),
+        "shared_up": s(d, si), "shared_down": s(si, d),
+    }
+    dense = {"gate_proj": s(d, spec.intermediate_size),
+             "up_proj": s(d, spec.intermediate_size),
+             "down_proj": s(spec.intermediate_size, d)}
+    kda = {
+        **norms, "gdn_in_proj": s(d, g.proj_dim),
+        "gdn_low_proj": s(d, 3 * LANES),
+        "gdn_f_b_proj": s(g.gate_rank, g.d_key),
+        "gdn_g_b_proj": s(g.gate_rank, g.d_value),
+        "gdn_conv_w": s(g.conv, g.conv_dim),
+        "gdn_a_log": s(g.value_heads, dtype=f32),
+        "gdn_dt_bias": s(g.d_key, dtype=f32),
+        "gdn_norm": s(g.value_dim), "gdn_out_proj": s(g.d_value, d),
+    }
+    latent = {
+        **norms, "kv_a_norm": s(m.kv_rank),
+        "q_b_nope": s(h * m.nope_dim, d), "q_b_rope": s(h * m.rope_dim, d),
+        "kv_a_proj": s(m.kv_rank + m.rope_dim, d),
+        "kv_b_k": s(h, m.nope_dim, m.kv_rank),
+        "kv_b_v": s(h, m.v_dim, m.kv_rank), "o_proj": s(h * m.v_dim, d),
+    }
+    params = {}
+    for run, first_mlp in ((LEAD, dense), ("", sparse)):
+        for j in range(3):
+            mlp = first_mlp if j == 0 else sparse
+            params.update({run + linear_prefix(j) + k: v
+                           for k, v in {**kda, **mlp}.items()})
+        params.update({run + k: v for k, v in {**latent, **sparse}.items()})
+    assert spec.period_runs(0, 8) == (
+        (("linear+dense", "linear", "linear", "full"), 1),
+        (("linear", "linear", "linear", "full"), 1))
+    kv_layers, state_layers = spec.arena_layers(0, spec.num_hidden_layers)
+    slots = state_slots_for(spec, pages, PAGE, 8)
+
+    def a(*shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = {
+        "ssm": a(state_layers, slots, *g.state_shape, dtype=f32),
+        "conv": a(state_layers, slots, *g.tail_shape),
+    }
+    c, pe = m.page_payload
+    return (spec, params, a(kv_layers, pages * PAGE, *c),
+            a(kv_layers, pages * PAGE, *pe), state)
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "tail", "fused"])
+def test_kimi_linear_span_step_compiles_and_copies_no_parameter(v5e, program):
+    """The cell's step programs (a decode group, a solo 512-row chunk, an
+    8-row tail, a 1024-row fused pack; 1024-page bucket, kernels on): ONE
+    program scans the period with the dense layer, then the other, over a
+    2-row latent arena and a 6-row state arena; the compiled text holds no
+    `copy` of a `stacked_params` parameter and no copy of a whole arena, the
+    latent kernels and the experts' kernel forms are in it, and the
+    temporaries stay what the rows' activations need."""
+    import re
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    spec, params, latent, shared_key, state = _kimi_linear_shapes(one_chip)
+    assert (latent.shape, shared_key.shape[2], state["ssm"].shape[:2]) == (
+        (2, 5376 * PAGE, 512), 128, (6, 16))
+    layers, pages = 8, 1024
+    common = dict(
+        spec=spec, page_size=PAGE, max_pages=pages, windows=(0,) * layers)
+    if program == "fused":
+        r, n_seqs = 1024, 4
+        plan_len = r + n_seqs * pages + r + n_seqs + r + layers + 3 * n_seqs + 1
+        compiled = span_step_ragged.lower(
+            params, latent, shared_key,
+            _cell_payload(spec, r, plan_len, one_chip), None, state,
+            r=r, n_seqs=n_seqs, use_kernel=True, **common,
+        ).compile()
+    else:
+        b, t, t_real = {"decode": (4, 1, None), "chunk": (1, 512, 512),
+                        "tail": (1, 8, 5)}[program]
+        plan_len = b * t + b * pages + b * t + b + layers + b
+        compiled = span_step_packed.lower(
+            params, latent, shared_key,
+            _cell_payload(spec, b * t, plan_len, one_chip), None, None, state,
+            b=b, t=t, use_paged=True, t_real=t_real, page_groups=t == 512,
+            **common,
+        ).compile()
+    text = compiled.as_text()
+    assert "%stacked_params__lead_lin0_gdn_in_proj" in text  # the names read
+    assert "%stacked_params__q_b_rope" in text
+    copied = re.findall(r"copy\([^)\n]*%(stacked_params\w+)", text)
+    assert not copied, copied
+    assert not re.findall(r"copy\([^)\n]*%(arena_[kv]|state__ssm)", text)
+    # decode: the paged latent kernel in both runs + the grouped experts in
+    # the seven sparse layers' four distinct bodies... counted by name below
+    assert "tpu_custom_call" in text
+    tiled = program in ("chunk", "fused")
+    assert ("jit(tiled_experts)" in text) == tiled
+    assert ("jit(grouped_experts)" in text) == (not tiled)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < {"decode": 60, "chunk": 600, "tail": 60,
+                   "fused": 1200}[program] * 1e6, temp
