@@ -325,15 +325,29 @@ def main(argv=None):
             promote_jitter_s=args.promote_jitter_s,
             artifact_dir=args.artifact_dir,
         )
-        await server.start()
+        # the warm-up's task stands BEFORE the server announces itself:
+        # `rpc_info`'s `warmup_done` is "no start-up task is left", and
+        # `start()` still awaits after its announcement (the native
+        # components are built on a machine's first look), so a client that
+        # met the record in the registry read "done" before the task was
+        # made and sent its first steps into the queue beside the warm-up's
+        # compiles, where a cold cache outlasts their deadline
+        started = asyncio.Event()
         warm = None
         if args.warmup_batches:
             batches = tuple(
                 int(x) for x in args.warmup_batches.split(",") if x
             )
+
+            async def warm_once_started():
+                await started.wait()
+                await server.warmup(batches)
+
             warm = server._warmup_task = asyncio.create_task(
-                server.warmup(batches)
+                warm_once_started()
             )
+        await server.start()
+        started.set()
         from bloombee_tpu.server.throughput import measure_and_announce
 
         async def measure_when_warm():

@@ -23,10 +23,31 @@ and   S_C = exp(G_C) S_0 + (diag(exp(G_C - G)) K)^T U.
 
 The system is solved by forward substitution (`solve_triangular`), not by a
 series in the strictly-lower part: keys of one direction make that series
-cancel catastrophically, and substitution does not care. `gdn_sequence`
-walks a longer run block by block. All in float32, matmuls at `highest`
-precision (a few percent of a layer's projections, and S is a sum over
-thousands of positions).
+cancel catastrophically, and substitution does not care. All in float32,
+matmuls at `highest` precision (a few percent of a layer's projections, and
+S is a sum over thousands of positions).
+
+`gdn_sequence` takes a longer run (a 512-row chunk is eight blocks of 64).
+Only ONE term of a block reads the state it meets, and the system is linear
+in its right-hand side: with T = (I + A)^-1,
+
+    U = T beta V - (T beta diag(exp(G)) K) S_0 = U_v - W S_0.
+
+So everything that does not read the carried state is computed ONCE for all
+of the run's blocks, batched over [blocks, heads] in front of the walk
+(`_gdn_block` under `jax.vmap`): the running sums and decays, A, the
+decay-weighted Q K^T, the decayed K and Q, and one forward substitution
+against [beta V | beta K_in] side by side, which gives U_v and W
+(`_walk_blocks`; blocks and heads as ONE batch axis, which the chip's solve
+lays on its lanes where it walks a leading one). The walk (`lax.scan`)
+keeps what reads or writes S: U = U_v - W S, O = P U + Q_in S,
+S = exp(G_C) S + K_end^T U. It is the same arithmetic with one subtraction
+reassociated and the solve d_k columns wider, at an eighth of the dependent
+depth: a block's products are tiny (64 x 64 x 128 a head) and eight turns of
+ten small ops and a solve each, one after the other, were what a layer paid
+(`gdn_rule` 6.2 ms of a Qwen3-Next chunk for needs of 0.16: PERF.md
+section 6, PR 52). Substitution stays for the reason above. A run of one
+block (a tail, a short chunk) is `gdn_chunk` as it stands.
 
 A row with beta == 0 and g == 0 neither decays nor feeds the state: that is
 how padding rows and bucket tails are kept out of it (the caller zeroes
@@ -44,10 +65,15 @@ taken relative to G at I's FIRST row n, `(k_i * exp(G_i - G_n)) . (k_j *
 exp(G_n - G_j))`, both exponents <= 0 (a factor that underflows is one whose
 true product is smaller still); a diagonal sub-block is taken exactly, its
 `exp(G_i - G_j)` over [rows, rows, d_k] masked to j <= i BEFORE the
-exponential (the published kernel's form).
+exponential (the published kernel's form). `kda_sequence` batches and
+walks as `gdn_sequence` does (`_kda_block`: both pair matrices with their
+sub-block references and exact diagonals as they are; the block's decay
+scales S's rows).
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -117,20 +143,90 @@ def gdn_chunk(
     return o, s
 
 
+def sequence_blocks(t: int, chunk: int, channel_decay: bool) -> int:
+    """How many `chunk`-row blocks the chunk form of T rows takes in its one
+    batched pass: 1 where it is a single block (`gdn_chunk` / `kda_chunk`).
+    The vector-decay rule pads T to whole blocks; the scalar one takes a T
+    that is no multiple of `chunk` as one block."""
+    if t <= chunk or (t % chunk and not channel_decay):
+        return 1
+    return -(-t // chunk)
+
+
+def _gdn_block(q, k, v, g, beta):
+    """What ONE block of `gdn_chunk` holds that does not read the carried
+    state (`jax.vmap`ped over a chunk's blocks): (A [H, C, C] strictly
+    lower, beta [V | K_in] [H, C, V + K], P [H, C, C], Q_in [C, H, K],
+    K_end [C, H, K], the block's whole decay [H, 1, 1])."""
+    c = q.shape[0]
+    cum = jnp.cumsum(g, axis=0)  # [C, H] inclusive
+    diff = cum[:, None, :] - cum[None, :, :]  # [C(i), C(j), H], <= 0 on j <= i
+    causal = jnp.tril(jnp.ones((c, c), bool))
+    decay = jnp.exp(jnp.where(causal[:, :, None], diff, -jnp.inf))
+    decay = decay.transpose(2, 0, 1)  # [H, C, C]
+    kk = jnp.einsum("ihk,jhk->hij", k, k, precision=_HI)
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)
+    a = jnp.where(strict, kk * decay * beta.T[:, :, None], 0.0)
+    into = jnp.exp(cum)[..., None]  # decay from before the block to row i
+    p = jnp.einsum("ihk,jhk->hij", q, k, precision=_HI) * decay
+    to_end = jnp.exp(cum[-1][None, :] - cum)[..., None]
+    return (
+        a, _state_free_rhs(k * into, v, beta), p, q * into, k * to_end,
+        jnp.exp(cum[-1])[:, None, None],
+    )
+
+
+def _state_free_rhs(k_in, v, beta):
+    """beta [V | K_in], heads first: the two right-hand sides of a block's
+    system that do not read the state, side by side."""
+    rhs = jnp.concatenate([v, k_in], axis=-1) * beta[..., None]
+    return rhs.transpose(1, 0, 2)
+
+
+def _walk_blocks(s0, a, rhs, p, q_in, k_end, decay_end):
+    """A chunk's blocks (`_gdn_block`'s or `_kda_block`'s values, each with
+    a leading [blocks] axis) from the state s0 on: ONE batched forward
+    substitution, (I + A) [U_v | W] = beta [V | K_in], so that a block's
+    deltas are U = U_v - W S for whatever state S it meets; then the walk,
+    which holds only what reads or writes S. -> (o [blocks, C, H, V], the
+    state after the last block)."""
+    n, h, c, _ = a.shape
+    # (blocks and heads as ONE batch axis: the chip's solve walks the
+    # batch's leading axes and lays only the last on its lanes)
+    sol = jax.scipy.linalg.solve_triangular(
+        (a + jnp.eye(c, dtype=a.dtype)).reshape(n * h, c, c),
+        rhs.reshape(n * h, c, -1), lower=True, unit_diagonal=True,
+    ).reshape(n, h, c, -1)  # [blocks, H, C, V + K]
+    dv = s0.shape[-1]
+
+    def body(s, block):
+        u_v, w, p, q_in, k_end, decay_end = block
+        u = u_v - jnp.einsum("hik,hkv->hiv", w, s, precision=_HI)
+        o = jnp.einsum("hij,hjv->ihv", p, u, precision=_HI) + jnp.einsum(
+            "ihk,hkv->ihv", q_in, s, precision=_HI
+        )
+        s = s * decay_end + jnp.einsum("jhk,hjv->hkv", k_end, u, precision=_HI)
+        return s, o
+
+    s, o = lax.scan(
+        body, s0, (sol[..., :dv], sol[..., dv:], p, q_in, k_end, decay_end)
+    )
+    return o, s
+
+
 def gdn_sequence(q, k, v, g, beta, s0, chunk: int):
     """`gdn_chunk` over T rows, `chunk` rows at a time (T a multiple of
     `chunk`, or at most one block): the triangular system stays
-    [chunk, chunk]."""
+    [chunk, chunk], all of the blocks' systems solved in one batched pass
+    (`_gdn_block`) and the state walked through them after it."""
     t = q.shape[0]
-    if t <= chunk or t % chunk:
+    n = sequence_blocks(t, chunk, False)
+    if n == 1:
         return gdn_chunk(q, k, v, g, beta, s0)
-
-    def body(s, xs):
-        o, s = gdn_chunk(*xs, s)
-        return s, o
-
-    split = lambda z: z.reshape(t // chunk, chunk, *z.shape[1:])  # noqa: E731
-    s, o = lax.scan(body, s0, tuple(map(split, (q, k, v, g, beta))))
+    split = lambda z: z.reshape(n, chunk, *z.shape[1:])  # noqa: E731
+    o, s = _walk_blocks(
+        s0, *jax.vmap(_gdn_block)(*map(split, (q, k, v, g, beta)))
+    )
     return o.reshape(t, *o.shape[2:]), s
 
 
@@ -221,10 +317,26 @@ def kda_chunk(
     return o, s
 
 
+def _kda_block(q, k, v, g, beta, sub: int):
+    """`_gdn_block` with the decay a key channel: the block's decay comes
+    back [H, K, 1], on the state's rows."""
+    c = q.shape[0]
+    cum = jnp.cumsum(g, axis=0)  # [C, H, K] inclusive, <= 0
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)
+    a = jnp.where(strict, _kda_pairs(k, k, cum, sub), 0.0) * beta.T[:, :, None]
+    into = jnp.exp(cum)  # decay from before the block to row i
+    to_end = jnp.exp(cum[-1][None] - cum)  # [C, H, K]
+    return (
+        a, _state_free_rhs(k * into, v, beta), _kda_pairs(q, k, cum, sub),
+        q * into, k * to_end, jnp.exp(cum[-1])[..., None],
+    )
+
+
 def kda_sequence(q, k, v, g, beta, s0, chunk: int, sub: int = KDA_SUB):
-    """`kda_chunk` over T rows, `chunk` rows at a time. T is padded to whole
-    blocks (whole sub-blocks under one block) with rows of beta = 0, g = 0,
-    which leave the state alone; their outputs are cut off."""
+    """`kda_chunk` over T rows, `chunk` rows at a time, batched and walked
+    as `gdn_sequence`. T is padded to whole blocks (whole sub-blocks under
+    one block) with rows of beta = 0, g = 0, which leave the state alone;
+    their outputs are cut off."""
     t = q.shape[0]
     unit = chunk if t > chunk else min(sub, t)
     pad = -t % unit
@@ -233,15 +345,11 @@ def kda_sequence(q, k, v, g, beta, s0, chunk: int, sub: int = KDA_SUB):
             jnp.pad(z, ((0, pad),) + ((0, 0),) * (z.ndim - 1))
             for z in (q, k, v, g, beta)
         )
-    n = t + pad
-    if n <= chunk:
+    n = sequence_blocks(t, chunk, True)
+    if n == 1:
         o, s = kda_chunk(q, k, v, g, beta, s0, sub)
         return o[:t], s
-
-    def body(s, xs):
-        o, s = kda_chunk(*xs, s, sub)
-        return s, o
-
-    split = lambda z: z.reshape(n // chunk, chunk, *z.shape[1:])  # noqa: E731
-    s, o = lax.scan(body, s0, tuple(map(split, (q, k, v, g, beta))))
-    return o.reshape(n, *o.shape[2:])[:t], s
+    split = lambda z: z.reshape(n, chunk, *z.shape[1:])  # noqa: E731
+    block = functools.partial(_kda_block, sub=sub)
+    o, s = _walk_blocks(s0, *jax.vmap(block)(*map(split, (q, k, v, g, beta))))
+    return o.reshape(n * chunk, *o.shape[2:])[:t], s

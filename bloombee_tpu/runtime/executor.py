@@ -36,6 +36,7 @@ from bloombee_tpu.runtime.step import (
     span_step_packed,
     span_step_ragged,
 )
+from bloombee_tpu.ops.linear_attention import sequence_blocks
 from bloombee_tpu.ops.moe import reach_fields
 from bloombee_tpu.ops.pallas.flash_attention import flash_takes, flash_tiles
 from bloombee_tpu.runtime.layer_body import chunk_run_pages
@@ -1055,6 +1056,7 @@ class SpanExecutor:
             result, "fused", r, starts, self._count_moe(rb, used_kernel),
             cross_rows=None if cross_idx is None else len(cross_idx),
             flash=self._flash_form(rb, pb) if flash_now else None,
+            rule_rows=rb,
         )
         with jitwatch.span("bbtpu.slice"):
             return out[0, :r], combined
@@ -1281,7 +1283,8 @@ class SpanExecutor:
     def _keep_arena(self, result, kind: str, rows: int, starts,
                     experts: str | None = None,
                     cross_rows: int | None = None,
-                    flash: str | None = None, write: str = "rows"):
+                    flash: str | None = None, write: str = "rows",
+                    rule_rows: int = 0):
         """Store a span step's returned arenas (K, V and, where the family
         has one, the state arena) on the manager; returns the step's output.
         A step of a latent-attention family, of one with linear-attention
@@ -1295,7 +1298,11 @@ class SpanExecutor:
         K/V slabs' layout ("folded" | "unfolded": kv/arena.py `folds`),
         `flash` the tile a chunk's flash kernel multiplied (`_flash_form`),
         `write` how the rows went into the arena ("pages" | "rows":
-        `_page_groups`).
+        `_page_groups`), `rule_blocks` the blocks a delta-rule family's chunk
+        form took in its one batched pass over the `rule_rows` a chunk-form
+        sequence spans in the program (ops/linear_attention.py
+        `sequence_blocks`: 8 for a 512-row chunk, 1 for the single-block
+        form; no field on a step of decode rows alone).
         `kind` and `rows` are also kept beside what the rows reached of the
         held experts, where the step says it."""
         if self._window_layers:
@@ -1327,6 +1334,10 @@ class SpanExecutor:
                 **({"experts": experts} if experts else {}),
                 **({"flash": flash} if flash else {}),
                 **({"cross_rows": cross_rows} if self._cross_layers else {}),
+                **({"rule_blocks": sequence_blocks(
+                    rule_rows, self.spec.gdn.chunk,
+                    self.spec.gdn.channel_decay,
+                )} if self.spec.gdn is not None and rule_rows else {}),
             ):
                 pass
         if self.spec.moe_held is not None:
@@ -1859,6 +1870,7 @@ class SpanExecutor:
                 cross_rows=cross_rows,
                 flash=self._flash_form(tb, pb) if flash_now else None,
                 write="pages" if page_groups else "rows",
+                rule_rows=tb if tb > 1 else 0,
             )
         if t > 1:
             self.kv_writes[

@@ -1,5 +1,5 @@
 #!/usr/bin/env python3
-"""Lower the seven cells' step programs (decode, chunk, tail, fused, at
+"""Lower the eight cells' step programs (decode, chunk, tail, fused, at
 the cells' shapes, as tests/test_chip_compile.py and tests/test_afmoe.py
 build them; every chunk with the row scatter, `page_groups` off: what a
 chunk that starts inside a page still runs) for a described
@@ -85,4 +85,13 @@ common = dict(spec=spec, page_size=16, max_pages=pages, windows=windows(spec, la
 for prog, (b, t, case) in {"decode": (4, 1, dict(use_paged=True)), "chunk": (1, 512, dict(use_flash=True, t_real=512, expert_kernels=True)), "tail": (1, 8, dict(use_paged=True, t_real=5))}.items():
     plan_len = b * t + b * pages + b * t + b + layers
     h(f"trinity.{prog}", span_step_packed.lower(params, arena, arena, T._cell_payload(spec, b * t, plan_len, one), None, None, None, b=b, t=t, **case, **common))
+# kimi_linear (PR 51's cell, added here in PR 52): latent arena + state arena
+spec, params, latent, shared_key, state = T._kimi_linear_shapes(one)
+layers, pages = 8, 1024
+common = dict(spec=spec, page_size=T.PAGE, max_pages=pages, windows=(0,) * layers)
+plan_len = r + n_seqs * pages + r + n_seqs + r + layers + 3 * n_seqs + 1
+h("kimilinear.fused", span_step_ragged.lower(params, latent, shared_key, T._cell_payload(spec, r, plan_len, one), None, state, r=r, n_seqs=n_seqs, use_kernel=True, **common))
+for prog, (b, t, t_real) in {"decode": (4, 1, None), "chunk": (1, 512, 512), "tail": (1, 8, 5)}.items():
+    plan_len = b * t + b * pages + b * t + b + layers + b
+    h(f"kimilinear.{prog}", span_step_packed.lower(params, latent, shared_key, T._cell_payload(spec, b * t, plan_len, one), None, None, state, b=b, t=t, use_paged=True, t_real=t_real, **common))
 print(json.dumps(out, indent=0))

@@ -27,6 +27,26 @@ def rng():
     return jax.random.PRNGKey(0)
 
 
+@pytest.fixture
+def step_spans(monkeypatch):
+    """The ids of every zero-length `bbtpu.step` span the executor stamps
+    while the test runs (runtime/executor.py `_keep_arena`; `BBTPU_JITWATCH`
+    on), in order."""
+    from bloombee_tpu.utils import jitwatch
+
+    monkeypatch.setenv("BBTPU_JITWATCH", "1")
+    seen = []
+    real = jitwatch.span
+
+    def stamped(name, **ids):
+        if name == "bbtpu.step":
+            seen.append(ids)
+        return real(name, **ids)
+
+    monkeypatch.setattr(jitwatch, "span", stamped)
+    return seen
+
+
 def _is_cell_rehearsal(item) -> bool:
     return (item.path.name == "test_cell_rehearsal.py"
             and item.name.startswith("test_cell_rehearsal_"))

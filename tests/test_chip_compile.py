@@ -825,6 +825,21 @@ def _scatter_indices(text: str) -> list[int]:
     ]
 
 
+def _rule_solves(text: str) -> list[tuple[int, bool]]:
+    """The delta rule's triangular solves in a compiled step program, as
+    the chip runs them (`InvertDiagBlocksLowerTriangular` custom calls):
+    (the batch of systems one call takes, whether it stands INSIDE the walk
+    over a chunk's blocks, a `while` under the rule's scope)."""
+    import re
+
+    return [
+        (int(m.group(1)), "_rule/while" in m.group(2))
+        for m in re.finditer(
+            r"= f32\[(\d+),1,\d+,\d+\]\S* custom-call\([^\n]*custom_call_target="
+            r'"InvertDiagBlocksLowerTriangular"[^\n]*op_name="([^"]*)"', text)
+    ]
+
+
 @pytest.mark.parametrize("program", ["decode", "chunk", "tail", "fused"])
 def test_qwen3_next_span_step_compiles_and_copies_no_parameter(v5e, program):
     """The cell's step programs (a decode group through the paged kernel and
@@ -894,10 +909,20 @@ def test_qwen3_next_span_step_compiles_and_copies_no_parameter(v5e, program):
     else:
         rows = {"decode": 4, "tail": 8, "fused": 1024}[program]
         assert writes.count(rows * 2) == 2, writes
+    # the rule's chunk form: ONE triangular solve a linear layer (three
+    # bodies in the scanned period), over all of a chunk's 64-row blocks x
+    # 32 value heads at once and IN FRONT of the walk over the blocks (until
+    # PR 52 it stood inside the walk: batch 32, eight turns a layer); the
+    # 8-row tail is one block, decode rows take the step form
+    blocks = {"decode": 0, "chunk": 8, "tail": 1, "fused": 16}[program]
+    assert _rule_solves(text) == [(blocks * 32, False)] * (3 * bool(blocks))
     temp = compiled.memory_analysis().temp_size_in_bytes
     # decode 12.2 MB and tail 11.6 MB (400 MB was the bound while they held
-    # a slab-sized buffer), chunk 43 MB, fused 205 MB; ONE stack for the
-    # three linear positions made the chunk program's 2,742 MB
+    # a slab-sized buffer), chunk 43.7 MB, fused 206 MB; ONE stack for the
+    # three linear positions made the chunk program's 2,742 MB. The batched
+    # pass raised the chunk by 0.6 MB and the pack by 1.4 (43.1 and 204.9
+    # before PR 52): a layer's [8, 32, 64, 64] pair matrices and [8, 32, 64,
+    # 256] right-hand sides are 25 MB, under the experts' peak
     assert temp < {"decode": 20, "chunk": 60, "tail": 20,
                    "fused": 300}[program] * 1e6, temp
 
@@ -1302,6 +1327,15 @@ def test_kimi_linear_span_step_compiles_and_copies_no_parameter(v5e, program):
     tiled = program in ("chunk", "fused")
     assert ("jit(tiled_experts)" in text) == tiled
     assert ("jit(grouped_experts)" in text) == (not tiled)
+    # ONE triangular solve a KDA layer, as Qwen3-Next's above (six bodies:
+    # two runs of periods)
+    blocks = {"decode": 0, "chunk": 8, "tail": 1, "fused": 16}[program]
+    assert _rule_solves(text) == [(blocks * 32, False)] * (6 * bool(blocks))
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < {"decode": 60, "chunk": 600, "tail": 60,
-                   "fused": 1200}[program] * 1e6, temp
+    # the chunk reads 108 MB and the pack 409 (96 and 395 before PR 52: the
+    # batched pass holds a layer's eight blocks at once, for the vector decay
+    # the [8, 4, 64, 32, 128] factors up to each sub-block's reference, 34 MB,
+    # and the exact [8, 4, 16, 16, 32, 128] diagonals, 17 MB, where a block at
+    # a time held an eighth), tail 44 MB
+    assert temp < {"decode": 60, "chunk": 150, "tail": 60,
+                   "fused": 500}[program] * 1e6, temp

@@ -299,6 +299,27 @@ def test_prefill_in_chunks_then_decode_matches_the_family_file(
     assert float(np.abs(want - h[0]).max()) > 0.05
 
 
+def test_a_chunk_steps_span_says_the_blocks_its_rule_took_at_once(
+        span, step_spans):
+    """`bbtpu.step` of a chunk carries `rule_blocks`, the blocks the rule's
+    chunk form took in its one batched pass (ops/linear_attention.py
+    `sequence_blocks`): 2 for 128 rows, 1 for a 22-row tail in its 32-row
+    bucket (the single-block form); a decode step, whose rows take one rule
+    step each, carries none."""
+    h = _hidden(5, 151)
+    ex = _executor(span)
+
+    async def run():
+        async with ex.manager.allocate(1, 160) as handle:
+            ex.prefill(handle, h[:, :128])
+            ex.prefill(handle, h[:, 128:150])
+            ex.decode(handle, h[:, 150:])
+
+    asyncio.run(run())
+    assert [s["kind"] for s in step_spans] == ["chunk", "chunk", "decode"]
+    assert [s.get("rule_blocks") for s in step_spans] == [2, 1, None]
+
+
 @pytest.mark.parametrize("kernels", [False, True], ids=["dense", "kernels"])
 def test_fused_pack_and_decode_group_match_the_family_file(
         ckpt, span, kernels, monkeypatch):
