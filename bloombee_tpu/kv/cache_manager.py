@@ -174,7 +174,7 @@ def state_slots_for(spec, num_pages: int, page_size: int, max_batch: int) -> int
     family without recurrent state.
 
     The rule was written for a family with both caches in every layer. A
-    family whose layers keep ONE of them each (`spec.gdn`) gets twice the
+    family whose layers keep ONE of them each (`spec.kinds_interleave`) gets twice the
     batcher's width and no more: its K/V arena is sized for contexts
     hundreds of times a state's worth of tokens in a quarter of the layers,
     and a state arena as large (82 slots, 1 GB at qwen3-next's widths under
@@ -183,7 +183,7 @@ def state_slots_for(spec, num_pages: int, page_size: int, max_batch: int) -> int
     ssm = spec.recurrent
     if ssm is None:
         return 0
-    if spec.gdn is not None or spec.mamba is not None:
+    if spec.kinds_interleave or spec.mamba is not None:
         return 2 * int(max_batch)
     kv_token = 2 * spec.num_key_value_heads * spec.head_dim * 2  # bf16 K+V
     slot = arena_ops.state_slot_bytes(ssm)

@@ -118,6 +118,7 @@ def _register_builtins() -> None:
     import bloombee_tpu.models.kimi_linear  # noqa: F401
     import bloombee_tpu.models.mistral  # noqa: F401
     import bloombee_tpu.models.mixtral  # noqa: F401
+    import bloombee_tpu.models.nemotron_h  # noqa: F401
     import bloombee_tpu.models.phi4flash  # noqa: F401
     import bloombee_tpu.models.qwen2  # noqa: F401
     import bloombee_tpu.models.qwen3  # noqa: F401

@@ -154,7 +154,7 @@ def _stack_settled(per_layer: list):
 
 
 def _stack_runs(layers: list[dict], period_runs: tuple = (),
-                runs: list[str] | None = None) -> dict:
+                runs: list[str] | None = None, units: bool = False) -> dict:
     """Per-layer params -> the stacked dict, leaf by leaf, letting go of
     each layer's tensor once it is stacked (the span is never held twice).
     Layers of one kind (the same keys) are ONE stack; a span whose first
@@ -167,7 +167,9 @@ def _stack_runs(layers: list[dict], period_runs: tuple = (),
     under `linear_prefix(j)`, the closing layers' under the plain keys; of
     two runs of like periods (the model's leading dense layer inside the
     first period, or a short last period) the first's stacks under `LEAD`
-    besides. `runs`: each layer's run of a SambaY span ("a" | "b" | "c")."""
+    besides. `units`: `period_runs` is a LIST of runs of a repeated unit of
+    kinds (`ModelSpec.one_sublayer`). `runs`: each layer's run of a SambaY
+    span ("a" | "b" | "c")."""
     from bloombee_tpu.models.layout import LEAD, linear_prefix
 
     stack_params = _stack_settled
@@ -188,6 +190,10 @@ def _stack_runs(layers: list[dict], period_runs: tuple = (),
                     )
         return out
     if period_runs:
+        # `units`: one stack a (run, position in the run's unit), however
+        # many runs (models/layout.py `unit_prefix`)
+        from bloombee_tpu.models.layout import unit_prefix
+
         out, at = {}, 0
         for r, (kinds, periods) in enumerate(period_runs):
             per = len(kinds)
@@ -195,7 +201,8 @@ def _stack_runs(layers: list[dict], period_runs: tuple = (),
             mine = layers[at : at + periods * per]
             at += periods * per
             for j in range(per):
-                prefix = lead + (linear_prefix(j) if j < per - 1 else "")
+                prefix = unit_prefix(r, j) if units else lead + (
+                    linear_prefix(j) if j < per - 1 else "")
                 run = mine[j::per]
                 for key in list(run[0]):
                     out[prefix + key] = stack_params(
@@ -241,6 +248,11 @@ def load_span_params(
             f"LoRA adapters unsupported for {spec.family}: q/k/v are cut out "
             "of one fused projection and the mixers have none an adapter names"
         )
+    if spec.one_sublayer and adapter_dirs:
+        raise ValueError(
+            f"LoRA adapters unsupported for {spec.family}: its mixer and "
+            "expert layers have no projection an adapter names"
+        )
     if spec.gdn is not None and adapter_dirs:
         raise ValueError(
             f"LoRA adapters unsupported for {spec.family}: the full layers' "
@@ -263,7 +275,8 @@ def load_span_params(
         return _stack_runs(layers, runs=sambay_runs(spec, start, end)), spec
     return _stack_runs(
         layers,
-        spec.period_runs(start, end) if spec.gdn is not None else (),
+        spec.period_runs(start, end) if spec.kinds_interleave else (),
+        units=spec.one_sublayer,
     ), spec
 
 
@@ -286,7 +299,8 @@ def held_experts(reader, config_key: str) -> tuple[int, int]:
 
 
 def refine_held(spec: ModelSpec, reader, config_key: str,
-                router_name: str = "mlp.gate.weight") -> ModelSpec:
+                router_name: str = "mlp.gate.weight",
+                layer_prefix: str = "model.layers") -> ModelSpec:
     """What the config alone does not say of a family whose experts are
     shared among chips: the router's width (a checkpoint cut to one chip's
     share of the experts keeps the router over ALL of them, so it is read
@@ -298,12 +312,12 @@ def refine_held(spec: ModelSpec, reader, config_key: str,
         return spec
     first_sparse = next(
         (i for i in range(spec.num_hidden_layers)
-         if reader.has(f"model.layers.{i}.{router_name}")), None,
+         if reader.has(f"{layer_prefix}.{i}.{router_name}")), None,
     )
     width = spec.num_experts
     if first_sparse is not None:
         width = reader.tensor(
-            f"model.layers.{first_sparse}.{router_name}"
+            f"{layer_prefix}.{first_sparse}.{router_name}"
         ).shape[0]
     first, count = held_experts(reader, config_key)
     if first < 0 or count < 1 or first + count > width:

@@ -95,8 +95,52 @@ def split_sambay(stacked: dict) -> dict[str, tuple[dict, dict]]:
     return out
 
 
+# A span of layers that are ONE sublayer each (nemotron_h) is a LIST of runs
+# of a repeated unit of kinds, as many as the pattern has
+# (`ModelSpec.period_runs`: a period that repeats, or ((moe, mamba) x 3,
+# (full,) x 1, ...) for a period that stands alone): one stack
+# a (run, position in the unit), `unit_prefix(r, j)`, each [repeats, ...].
+# The step scans a run's repeats and runs a unit's layers in order, the
+# same scan as the periods above (`period_stacks` hands it either layout).
+def unit_prefix(run: int, position: int) -> str:
+    return f"r{run}p{position}."
+
+
+def _unit_of(key: str) -> tuple[int, int] | None:
+    """(run, position) of a key under a `unit_prefix`, None for any other."""
+    head, dot, _ = key.partition(".")
+    run, p, position = head[1:].partition("p")
+    if dot and head[:1] == "r" and p and run.isdigit() and position.isdigit():
+        return int(run), int(position)
+    return None
+
+
+def period_stacks(stacked: dict) -> list[list[dict]]:
+    """A span whose kinds interleave as the step scans it: a list of runs,
+    each the list of its positions' stacks under plain keys, every leaf
+    [repeats, ...]. From either stored layout: `unit_prefix` keys, or the
+    periods' (`LEAD`, `linear_prefix`, the closing layers' plain keys)."""
+    units = {k: _unit_of(k) for k in stacked}
+    if any(u is not None for u in units.values()):
+        runs: dict[int, dict[int, dict]] = {}
+        for key, (r, j) in units.items():
+            runs.setdefault(r, {}).setdefault(j, {})[
+                key.partition(".")[2]] = stacked[key]
+        return [
+            [runs[r][j] for j in sorted(runs[r])] for r in sorted(runs)
+        ]
+    lead, main = split_runs(stacked)
+    out = []
+    for run in ([main] if lead is None else [lead, main]):
+        linear, full = split_kinds(run)
+        out.append([*linear, full])
+    return out
+
+
 def plain_key(key: str) -> str:
     """A stacked dict's key without its run's and its position's prefix."""
+    if _unit_of(key) is not None:
+        return key.partition(".")[2]
     if key.startswith(LEAD):
         key = key[len(LEAD):]
     head, dot, rest = key.partition(".")
@@ -143,12 +187,11 @@ def stacked_layers(stacked: dict) -> int:
             2 * jax.tree.leaves(mixers)[0].shape[0]
             for mixers, _ in runs.values()
         )
-    total = 0
-    for run in split_runs(stacked):
-        if run:  # its periods (layers, where the kinds do not interleave)
-            linear, full = split_kinds(run)
-            total += jax.tree.leaves(full)[0].shape[0] * (len(linear) + 1)
-    return total
+    # a run's periods (layers, where the kinds do not interleave)
+    return sum(
+        jax.tree.leaves(positions[-1])[0].shape[0] * len(positions)
+        for positions in period_stacks(stacked)
+    )
 
 
 def in_axis_of(key: str) -> int:

@@ -220,6 +220,30 @@ class MlaSpec:
         return scale
 
 
+def _repeats(kinds: tuple[str, ...]) -> list[tuple[tuple[str, ...], int]]:
+    """A list of kinds as runs of a repeated unit, greedily from the left: a
+    pair of kinds that comes again at once, else one layer (or a stretch of
+    one kind)."""
+    runs, i = [], 0
+    while i < len(kinds):
+        pair = tuple(kinds[i : i + 2])
+        n = 1
+        while len(pair) == 2 and pair[0] != pair[1] and tuple(
+            kinds[i + 2 * n : i + 2 * n + 2]
+        ) == pair:
+            n += 1
+        if n > 1:
+            runs.append((pair, n))
+            i += 2 * n
+            continue
+        n = 1
+        while i + n < len(kinds) and kinds[i + n] == kinds[i]:
+            n += 1
+        runs.append(((kinds[i],), n))
+        i += n
+    return runs
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelSpec:
     family: str
@@ -271,7 +295,9 @@ class ModelSpec:
     # on the layer norms and q_norm / k_norm (qwen3_next); a client folds
     # the 1 into its final norm at load and runs "rms"
     norm_type: str = "rms"  # "rms" | "ln" | "rms1p"
-    mlp_type: str = "silu"  # "silu" | "gelu" | "gelu_tanh_gated"
+    # "relu2": UNGATED, down(relu(up(x)) ** 2), two matrices (nemotron_h:
+    # the routed experts and the shared one; no `gate` leaf anywhere)
+    mlp_type: str = "silu"  # "silu" | "gelu" | "gelu_tanh_gated" | "relu2"
     sandwich_norms: bool = False  # Gemma2-style post-attn/post-ffn norms
     attn_logit_softcap: float = 0.0
     # Gemma-4-style heterogeneous attention geometry: full-attention layers
@@ -343,6 +369,17 @@ class ModelSpec:
     # `num_key_value_heads` describe a K/V PAIR as the arena holds it
     # (k1 | k2 of 2 x 64, v of 128), `num_attention_heads` the query halves
     mamba: Mamba1Spec | None = None
+    # layers that are ONE sublayer each behind ONE norm, x + f(norm(x))
+    # (nemotron_h): `layer_types` names every layer's kind, "mamba" (the
+    # state-space mixer `ssm` alone: a row in the state arena), "moe" (the
+    # expert layer alone: no row in either arena) or "full" (attention
+    # alone: a row in the K/V arena). A span is whole periods, a period
+    # closed by its full layer or by the model's end (`span_unsupported`),
+    # scanned as runs of repeated kinds (`period_runs`)
+    one_sublayer: bool = False
+    # False: no layer's queries and keys get a positional encoding at all
+    # (nemotron_h: positions come from the state-space layers)
+    rope: bool = True
 
     @property
     def recurrent(self):
@@ -380,13 +417,24 @@ class ModelSpec:
                     )
             return tuple(rows)
         for i in range(start, end):
-            arena = (
-                "state" if self.gdn is not None
-                and self.layer_type(i) == "linear" else "kv"
-            )
+            arena = self.layer_arena(i)
+            if arena is None:
+                rows.append(("none", -1))
+                continue
             rows.append((arena, n[arena]))
             n[arena] += 1
         return tuple(rows)
+
+    def layer_arena(self, layer_idx: int) -> str | None:
+        """The arena the layer at this ABSOLUTE index keeps its cache in,
+        "state" | "kv", or None (an expert layer that is a layer of its
+        own). Not asked of a SambaY stack (`SAMBAY_OWNS`) nor of a family
+        whose every layer has a row in each arena it has (`ssm` beside
+        attention)."""
+        kind = self.layer_type(layer_idx)
+        if self.one_sublayer:
+            return {"mamba": "state", "full": "kv"}.get(kind)
+        return "state" if self.gdn is not None and kind == "linear" else "kv"
 
     def arena_layers(self, start: int, end: int) -> tuple[int, int]:
         """(rows of the K/V arena, rows of the state arena) of a span: its
@@ -397,10 +445,19 @@ class ModelSpec:
                 SAMBAY_OWNS[self.layer_type(i)] for i in range(start, end)
             ]
             return owns.count("kv"), owns.count("state")
-        if self.gdn is None:
+        if not self.kinds_interleave:
             return n, (n if self.ssm is not None else 0)
         kinds = [arena for arena, _ in self.cache_rows(start, end)]
         return kinds.count("kv"), kinds.count("state")
+
+    @property
+    def kinds_interleave(self) -> bool:
+        """Layer kinds that keep DIFFERENT caches stand among each other,
+        each with a row in its own arena only, and the span is scanned as
+        runs of periods (runtime/step.py `_scan_periods`): delta-rule
+        layers among full ones (`gdn`), or layers that are one sublayer
+        each (`one_sublayer`). A SambaY stack has its own step."""
+        return self.gdn is not None or self.one_sublayer
 
     @property
     def cross_start(self) -> int:
@@ -436,6 +493,8 @@ class ModelSpec:
                     "pages, which live on the server that ran those layers"
                 )
             return None
+        if self.one_sublayer:
+            return self._sublayer_span_unsupported(start, end)
         if self.gdn is None:
             return None
         if (
@@ -463,6 +522,45 @@ class ModelSpec:
             )
         return None
 
+    def period_starts(self) -> tuple[int, ...]:
+        """Where a span of a `one_sublayer` family may be cut: layer 0, the
+        layer after every full layer, and the model's end."""
+        cuts = [0] + [
+            i + 1 for i, kind in enumerate(self.layer_types) if kind == "full"
+        ]
+        if cuts[-1] != self.num_hidden_layers:
+            cuts.append(self.num_hidden_layers)
+        return tuple(cuts)
+
+    def _sublayer_span_unsupported(self, start: int, end: int) -> str | None:
+        cuts = self.period_starts()
+        if end > start and start in cuts and end in cuts:
+            return None
+        pattern = "".join(
+            {"mamba": "M", "moe": "E", "full": "*"}[k]
+            for k in self.layer_types
+        )
+        if not 0 <= start < end <= self.num_hidden_layers:
+            why = f"the model has layers 0-{self.num_hidden_layers - 1}"
+        elif start not in cuts:
+            why = (
+                f"layer {start} stands inside the period that layer "
+                f"{max(c for c in cuts if c < start)} opens"
+            )
+        else:
+            why = (
+                f"layer {end - 1} closes no period: the period it stands in "
+                f"ends with layer {min(c for c in cuts if c > end) - 1}"
+            )
+        return (
+            f"a {self.family} span must hold whole periods of the pattern "
+            f"{pattern}, a period closed by its attention layer (*) or by "
+            f"the model's end: it may be cut at layers {list(cuts)} (got "
+            f"[{start}, {end}): {why}): servers are cut at the pattern's own "
+            "seams, so that a span's runs of kinds, its stacks and both "
+            "arenas' rows are whole periods'"
+        )
+
     @property
     def period(self) -> int:
         """Layers of the FIRST period of a family whose linear layers stand
@@ -477,6 +575,8 @@ class ModelSpec:
         layer is "linear" | "full", with "+dense" where its MLP is dense
         and the family's others have experts: periods differ where the
         model's leading dense layer stands in one, or the last is short."""
+        if self.one_sublayer:
+            return self._sublayer_runs(start, end)
         runs, period = [], []
         for i in range(start, end):
             kind = self.layer_type(i)
@@ -490,6 +590,37 @@ class ModelSpec:
                     runs.append([tuple(period), 1])
                 period = []
         return tuple((sig, n) for sig, n in runs)
+
+    def _sublayer_runs(self, start: int, end: int):
+        """`period_runs` of a `one_sublayer` span: the list of kinds as runs
+        of a REPEATED unit. Like periods that follow each other are one run
+        whose unit is the period (EMEMEM*EMEMEM* is (E, M, E, M, E, M, *) x
+        2: the pattern's own repeat, four of the published model's seven
+        periods). A period that stands alone (the published first period of
+        6 layers, the last of 9, the tail with no full layer) is factored
+        greedily from the left into a pair of kinds that comes again at
+        once ((moe, mamba) x 3), else one layer ((full,) x 1): a scan's
+        body is the unit, so such a period traces three layer bodies and
+        not nine."""
+        periods, period = [], []
+        for i in range(start, end):
+            period.append(self.layer_type(i))
+            if period[-1] == "full":
+                periods.append(tuple(period))
+                period = []
+        if period:
+            periods.append(tuple(period))
+        runs, i = [], 0
+        while i < len(periods):
+            n = 1
+            while i + n < len(periods) and periods[i + n] == periods[i]:
+                n += 1
+            if n > 1:
+                runs.append((periods[i], n))
+            else:
+                runs.extend(_repeats(periods[i]))
+            i += n
+        return tuple(runs)
 
     @property
     def experts_held(self) -> tuple[int, int]:

@@ -266,10 +266,27 @@ def _tiled_plan(x, idx, weights, num_experts: int, here=None):
     )
 
 
+def relu2(x: jax.Array) -> jax.Array:
+    """relu(x) ** 2, the activation of an UNGATED expert (nemotron_h)."""
+    return jnp.square(jax.nn.relu(x))
+
+
+def expert_hidden(g: jax.Array | None, u: jax.Array, activation: str):
+    """An expert's hidden activations from its products: silu(g) * u, the
+    gated form every family but one has, or with no gate product
+    `activation`(u), an expert of TWO matrices ("relu2": nemotron_h). One
+    copy for the dense einsums and both kernels."""
+    if g is not None:
+        return jax.nn.silu(g) * u
+    if activation != "relu2":
+        raise ValueError(f"no ungated expert activation {activation!r}")
+    return relu2(u)
+
+
 def moe_mlp(
     x: jax.Array,  # [B, T, D]
     router_w: jax.Array | None,  # [D, E]; None with `router_logits`
-    gate_w: jax.Array,  # [E, D, I]
+    gate_w: jax.Array | None,  # [E, D, I]; None: an UNGATED expert
     up_w: jax.Array,  # [E, D, I]
     down_w: jax.Array,  # [E, I, D]
     top_k: int,
@@ -291,8 +308,11 @@ def moe_mlp(
     # `held_reach` appended (the caller carries it out of its program)
     sigmoid: bool = False,  # the sigmoid router (route_topk)
     router_bias: jax.Array | None = None,  # [E]: its choice's correction
+    activation: str = "silu",  # static; "relu2" with `gate_w` None: an
+    # expert is down(relu(up(x)) ** 2), two matrices (`expert_hidden`)
 ) -> jax.Array:
-    """Gated expert MLPs weighted by the top-k router weights: by a list of
+    """Expert MLPs (gated, or ungated two-matrix ones: `activation`)
+    weighted by the top-k router weights: by a list of
     the chosen experts, by the chosen pairs in row tiles, or dense over all
     experts (module docstring). `expert_form` picks, from the rows, the
     router and whether the caller says kernels can run (`expert_base`).
@@ -319,7 +339,7 @@ def moe_mlp(
             form = "list"
     num_experts = (
         held[1] if held is not None
-        else gate_w.shape[0] if form == "dense" else routed
+        else up_w.shape[0] if form == "dense" else routed
     )
     if router_weights is None:
         with jax.named_scope("moe_router"):
@@ -362,12 +382,14 @@ def moe_mlp(
             out = (grouped_experts if form == "list" else tiled_experts)(
                 rows, slot_expert + expert_base, *rest,
                 gate_w, up_w, down_w, interpret=interpret,
+                activation=activation,
             )
             return out.astype(x.dtype).reshape(b, t, d)
-        g = jnp.einsum("btd,edi->btei", x, gate_w)
+        g = None if gate_w is None else jnp.einsum(
+            "btd,edi->btei", x, gate_w)
         u = jnp.einsum("btd,edi->btei", x, up_w)
         # the router's weight goes in before the down projection (linear, so
         # the same sum): one contraction over (expert, intermediate), the
         # stack read as it lies, and no [B, T, E, D] partial outputs
-        h = jax.nn.silu(g) * u * router_weights[..., None]
+        h = expert_hidden(g, u, activation) * router_weights[..., None]
         return jnp.einsum("btei,eid->btd", h, down_w)

@@ -14,6 +14,11 @@ so Pallas elides its DMA and `pl.when` skips its compute.
 Every grid step multiplies ALL of the step's rows with one expert and weights
 the result per row (zero for a row that did not choose it): on the MXU one
 row costs what sixteen do, and the per-row weights are the whole routing.
+
+An expert is three matrices, down(silu(gate(x)) * up(x)), or TWO where the
+family's experts have no gate (`gate_w` None, `activation` "relu2":
+down(relu(up(x)) ** 2), nemotron_h): the kernels then take two weight blocks
+a grid step and the same budget buys a wider tile.
 """
 
 from __future__ import annotations
@@ -26,10 +31,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# what the three double-buffered weight blocks of one grid step may take of
-# VMEM (a v5e core has 128 MiB; the default scoped limit is 16 MiB, raised
+from bloombee_tpu.ops.moe import expert_hidden
+
+# what the double-buffered weight blocks (three, or an ungated expert's two)
+# of one grid step may take of VMEM (a v5e core has 128 MiB; the default scoped limit is 16 MiB, raised
 # below to what the blocks need)
 _WEIGHT_BLOCKS_BYTES = 24 * 2**20
+
+
+def _hidden(x, g_ref, u_ref, activation: str):
+    """One expert's hidden activations for rows `x` from its weight blocks
+    (`g_ref` None: an ungated expert), float32 [rows, tI]."""
+    g = None if g_ref is None else jnp.dot(
+        x, g_ref[...], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, u_ref[...], preferred_element_type=jnp.float32)
+    return expert_hidden(g, u, activation)
 
 
 def _kernel(
@@ -37,11 +53,12 @@ def _kernel(
     n_ref,  # [1] i32 scalar prefetch: live slots
     x_ref,  # [R, D]
     w_ref,  # [R, 1] f32: each row's router weight for slot s's expert
-    g_ref,  # [D, tI]
-    u_ref,  # [D, tI]
-    d_ref,  # [tI, D]
-    o_ref,  # [R, D] f32, resident over the whole grid
+    *refs,  # g_ref [D, tI] (a gated expert only), u_ref [D, tI],
+    # d_ref [tI, D], o_ref [R, D] f32, resident over the whole grid
+    activation: str,
 ):
+    *g_ref, u_ref, d_ref, o_ref = refs
+    g_ref = g_ref[0] if g_ref else None
     s = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -52,19 +69,19 @@ def _kernel(
     @pl.when(s < n_ref[0])
     def _expert():
         x = x_ref[...]
-        g = jnp.dot(x, g_ref[...], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, u_ref[...], preferred_element_type=jnp.float32)
-        h = jax.nn.silu(g) * u * w_ref[...]
+        h = _hidden(x, g_ref, u_ref, activation) * w_ref[...]
         o_ref[...] += jnp.dot(
             h.astype(x.dtype), d_ref[...], preferred_element_type=jnp.float32
         )
 
 
-def _i_tile(d: int, i: int, itemsize: int) -> int:
-    """Largest tile of the intermediate dim whose three double-buffered
+def _i_tile(d: int, i: int, itemsize: int, matrices: int = 3) -> int:
+    """Largest tile of the intermediate dim whose `matrices` double-buffered
     weight blocks fit the budget: all of it, or a multiple of 128 that
-    divides it (Mosaic's lane tiling)."""
-    fits = _WEIGHT_BLOCKS_BYTES // (3 * 2 * d * itemsize)
+    divides it (Mosaic's lane tiling). A width of no whole lanes cannot be
+    tiled and goes in whole: a loader pads such a stack (models/
+    nemotron_h.py: 1856 -> 1920)."""
+    fits = _WEIGHT_BLOCKS_BYTES // (matrices * 2 * d * itemsize)
     if i <= fits or i % 128:
         return i
     tile = max(128, fits // 128 * 128)
@@ -73,13 +90,16 @@ def _i_tile(d: int, i: int, itemsize: int) -> int:
     return tile
 
 
-def _weight_blocks(gate_w: jax.Array):
-    """(tiles of the intermediate dim, the gate / up / down BlockSpecs of a
-    grid (slot, tile) steered by the first two scalar-prefetch operands, the
-    slots' experts and how many slots count, and the bytes of the three
+def _weight_blocks(up_w: jax.Array, gated: bool):
+    """(tiles of the intermediate dim, the [gate /] up / down BlockSpecs of
+    a grid (slot, tile) steered by the first two scalar-prefetch operands,
+    the slots' experts and how many slots count, and the bytes of the
     double-buffered blocks)."""
-    _, d, i = gate_w.shape
-    ti = _i_tile(d, i, gate_w.dtype.itemsize)
+    _, d, i = up_w.shape
+    matrices = 3 if gated else 2
+    itemsize = up_w.dtype.itemsize
+    ti = _i_tile(d, i, itemsize) if gated else _i_tile(
+        d, i, itemsize, matrices=2)
     n_j = i // ti
 
     def tile(s, j, n):
@@ -93,22 +113,23 @@ def _weight_blocks(gate_w: jax.Array):
         (None, ti, d), lambda s, j, e, n, *_: (e[s], tile(s, j, n), 0)
     )
     return (
-        n_j, [in_block, in_block, down_block],
-        3 * 2 * d * ti * gate_w.dtype.itemsize,
+        n_j, [in_block] * (matrices - 1) + [down_block],
+        matrices * 2 * d * ti * up_w.dtype.itemsize,
     )
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "activation"))
 def grouped_experts(
     x: jax.Array,  # [R, D]
     slot_expert: jax.Array,  # [P] i32: the experts some row chose, padded
     # past `live` with the last live one
     live: jax.Array,  # i32 scalar: how many slots count
     slot_weights: jax.Array,  # [P, R] f32: row r's weight for slot s
-    gate_w: jax.Array,  # [E, D, I]
+    gate_w: jax.Array | None,  # [E, D, I]; None: an ungated expert
     up_w: jax.Array,  # [E, D, I]
     down_w: jax.Array,  # [E, I, D]
     interpret: bool = False,
+    activation: str = "silu",
 ) -> jax.Array:
     """sum over live slots s of w[s, r] * mlp_{slot_expert[s]}(x[r]): [R, D]
     float32."""
@@ -120,7 +141,9 @@ def grouped_experts(
     x = jnp.pad(x, ((0, r_pad), (0, 0)))
     w = jnp.pad(slot_weights.astype(jnp.float32), ((0, 0), (0, r_pad)))
     rows = r + r_pad
-    n_j, weight_blocks, block_bytes = _weight_blocks(gate_w)
+    n_j, weight_blocks, block_bytes = _weight_blocks(
+        up_w, gate_w is not None)
+    weights = [w for w in (gate_w, up_w, down_w) if w is not None]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(p, n_j),
@@ -132,7 +155,7 @@ def grouped_experts(
         out_specs=pl.BlockSpec((rows, d), lambda s, j, e, n: (0, 0)),
     )
     out = pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, activation=activation),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -144,7 +167,7 @@ def grouped_experts(
     )(
         slot_expert.astype(jnp.int32),
         jnp.asarray(live, jnp.int32).reshape(1),
-        x, w[:, :, None], gate_w, up_w, down_w,
+        x, w[:, :, None], *weights,
     )
     return out[:r]
 
@@ -165,16 +188,15 @@ def _tiled_kernel(
     src_ref,  # [N] i32: the row each sorted pair came from
     w_ref,  # [N] f32: its router weight
     x_hbm,  # [R, D] f32, where it lies
-    g_ref,  # [D, tI]
-    u_ref,  # [D, tI]
-    d_ref,  # [tI, D]
-    o_hbm,  # [R, D] f32, where it lies
-    x_ref,  # [R, D] f32 scratch: the rows, fetched once
-    o_ref,  # [R, D] f32 scratch: the sum, written back once
-    xt_ref,  # [tile, D] f32 scratch: a tile's gathered rows
-    yt_ref,  # [tile, D] f32 scratch: their outputs for this expert
-    sem,
+    *refs,  # g_ref [D, tI] (a gated expert only), u_ref [D, tI],
+    # d_ref [tI, D], o_hbm [R, D] f32, where it lies, then the scratch:
+    # x_ref [R, D] f32, the rows, fetched once; o_ref [R, D] f32, the sum,
+    # written back once; xt_ref [tile, D] f32, a tile's gathered rows;
+    # yt_ref [tile, D] f32, their outputs for this expert; sem
+    activation: str,
 ):
+    *g_ref, u_ref, d_ref, o_hbm, x_ref, o_ref, xt_ref, yt_ref, sem = refs
+    g_ref = g_ref[0] if g_ref else None
     s = pl.program_id(0)
     j = pl.program_id(1)
     tile = xt_ref.shape[0]
@@ -203,10 +225,8 @@ def _tiled_kernel(
                 return c
 
             jax.lax.fori_loop(0, live, gather, 0)
-            x = xt_ref[...].astype(g_ref.dtype)
-            g = jnp.dot(x, g_ref[...], preferred_element_type=jnp.float32)
-            u = jnp.dot(x, u_ref[...], preferred_element_type=jnp.float32)
-            h = jax.nn.silu(g) * u
+            x = xt_ref[...].astype(u_ref.dtype)
+            h = _hidden(x, g_ref, u_ref, activation)
             yt_ref[...] = jnp.dot(
                 h.astype(x.dtype), d_ref[...],
                 preferred_element_type=jnp.float32,
@@ -231,7 +251,7 @@ def _tiled_kernel(
         rows_out.wait()
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "activation"))
 def tiled_experts(
     x: jax.Array,  # [R, D]
     slot_expert: jax.Array,  # [P] i32: the experts some pair chose, padded
@@ -242,17 +262,19 @@ def tiled_experts(
     slot_count: jax.Array,  # [P] i32
     src: jax.Array,  # [N] i32: the pairs sorted by expert, each one's row
     weights: jax.Array,  # [N] f32: each one's router weight
-    gate_w: jax.Array,  # [E, D, I]
+    gate_w: jax.Array | None,  # [E, D, I]; None: an ungated expert
     up_w: jax.Array,  # [E, D, I]
     down_w: jax.Array,  # [E, I, D]
     interpret: bool = False,
+    activation: str = "silu",
 ) -> jax.Array:
     """sum over the listed pairs p of weights[p] * mlp_{expert(p)}(x[src[p]])
     into row src[p]: [R, D] float32. Only the pairs' rows are multiplied
     with an expert, `ROW_TILE` at a time; a row no pair names stays zero."""
     r, d = x.shape
     p = slot_expert.shape[0]
-    n_j, weight_blocks, block_bytes = _weight_blocks(gate_w)
+    n_j, weight_blocks, block_bytes = _weight_blocks(
+        up_w, gate_w is not None)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,
         grid=(p, n_j),
@@ -268,7 +290,7 @@ def tiled_experts(
     )
     scratch_bytes = 2 * (r + ROW_TILE) * d * 4
     return pl.pallas_call(
-        _tiled_kernel,
+        functools.partial(_tiled_kernel, activation=activation),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, d), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -284,5 +306,6 @@ def tiled_experts(
         slot_count.astype(jnp.int32),
         src.astype(jnp.int32),
         weights.astype(jnp.float32),
-        x.astype(jnp.float32), gate_w, up_w, down_w,
+        x.astype(jnp.float32),
+        *(w for w in (gate_w, up_w, down_w) if w is not None),
     )

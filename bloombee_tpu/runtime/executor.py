@@ -223,7 +223,7 @@ class SpanExecutor:
         )
         if reason is not None:
             raise ValueError(reason)
-        if spec.gdn is not None or spec.mamba is not None:
+        if spec.kinds_interleave or spec.mamba is not None:
             want = spec.arena_layers(
                 start_block, start_block + manager.num_layers
             )
@@ -264,6 +264,11 @@ class SpanExecutor:
                         f"{what} unsupported for {spec.family}: its layers "
                         "run the SambaY span step only (runtime/sambay.py)"
                     )
+        if spec.one_sublayer and adapters:
+            raise ValueError(
+                f"LoRA adapters unsupported for {spec.family}: its mixer "
+                "and expert layers have no projection an adapter names"
+            )
         if spec.gdn is not None:
             if adapters:
                 raise ValueError(
@@ -480,6 +485,15 @@ class SpanExecutor:
             manager.quant is None
             and mesh is None
             and not spec.heterogeneous
+            # an attention layer in a run of ONE repeat (a period of a
+            # one-sublayer family that stands alone in its span): the
+            # compiler unrolls that scan, and the page write's read of the
+            # old page then costs a copy of the whole arena a slab (88 MB
+            # four times a chunk: my chip run, PR 54); such a span keeps the
+            # row scatter
+            and not (spec.one_sublayer and any(
+                n == 1 and "full" in kinds for kinds, n in spec.period_runs(
+                    start_block, start_block + manager.num_layers)))
             and all(
                 page_view_free(manager.arena[key].shape[1:],
                                manager.arena[key].dtype)
@@ -487,6 +501,15 @@ class SpanExecutor:
             )
         )
         self._window_layers = sum(w > 0 for w in self.windows)
+        # layers that are one sublayer each: how many of each kind the span
+        # holds, as the `bbtpu.step` span says it ("mamba:6+moe:6+full:2":
+        # neither the comma nor the ";" the profiler cuts an id at)
+        self._kinds = "+".join(
+            f"{kind}:{n}" for kind, n in collections.Counter(
+                spec.layer_type(start_block + i)
+                for i in range(manager.num_layers)
+            ).items()
+        ) if spec.one_sublayer else ""
         # a chunk may attend through flash where no layer has a window, or
         # every window is the family's `flash_window` (window layers among
         # full ones: runtime/layer_body.py `_flash_by_window`)
@@ -1008,7 +1031,7 @@ class SpanExecutor:
                 # latent attention's kernels block their queries
                 # themselves; a family with linear layers attends a pack
                 # sequence by sequence (layer_body.py `_attend_by_rows`)
-                and (spec.mla is not None or spec.gdn is not None
+                and (spec.mla is not None or spec.kinds_interleave
                      or spec.mamba is not None
                      or rb * spec.num_attention_heads <= 2048)
             )
@@ -1299,7 +1322,8 @@ class SpanExecutor:
         K/V slabs' layout ("folded" | "unfolded": kv/arena.py `folds`),
         `flash` the tile a chunk's flash kernel multiplied (`_flash_form`),
         `write` how the rows went into the arena ("pages" | "rows":
-        `_page_groups`), `rule_blocks` the blocks a delta-rule family's chunk
+        `_page_groups`), `kinds` the layers of each kind a span of layers
+        that are one sublayer each holds, `rule_blocks` the blocks a delta-rule family's chunk
         form took in its one batched pass over the `rule_rows` a chunk-form
         sequence spans in the program (ops/linear_attention.py
         `sequence_blocks`: 8 for a 512-row chunk, 1 for the single-block
@@ -1329,10 +1353,11 @@ class SpanExecutor:
             c["cross_rows"] += cross_rows
             c["long_steps" if cross_rows else "short_steps"] += 1
             c["shared_kv_reads"] += self._cross_layers * bool(cross_rows)
-        if (self.spec.mla is not None or self.spec.gdn is not None
+        if (self.spec.mla is not None or self.spec.kinds_interleave
                 or self.spec.mamba is not None or self.spec.flash_window):
             with jitwatch.span(
                 "bbtpu.step", kind=kind, rows=rows,
+                **({"kinds": self._kinds} if self._kinds else {}),
                 context=int(np.mean(starts)),
                 arena="folded" if self.manager.folded else "unfolded",
                 write=write,

@@ -48,6 +48,13 @@ with a gated query projection (`attn_gate`), rotary on the first
 sequence by sequence (`_attend_by_rows`). kimi_linear's full layers are
 latent attention without positions (`_mla_layer`, `MlaSpec.rope` False).
 
+A family whose layers are ONE sublayer each (`spec.one_sublayer`:
+nemotron_h) runs `_sublayer` for a mixer layer (`_ssm_mixer` alone, the
+scopes above) and for an expert layer (`_mlp` alone: `moe_router`,
+`moe_shared`, `moe_experts`), and the generic body without rotary
+(`spec.rope` False) and without an MLP for an attention layer; a layer's
+kind is read from the keys its params hold.
+
 A layer never owns its K/V slab as an array: the span step hands it the
 whole arena viewed flat plus slot and page ids already offset to the layer
 (runtime/step.py `_scan_layers`); runtime/hetero.py hands it a per-layer
@@ -72,7 +79,7 @@ from bloombee_tpu.ops import apply_rotary, rms_norm, silu_mlp
 from bloombee_tpu.ops.rotary import _rotate_half
 from bloombee_tpu.ops.alibi import alibi_slopes
 from bloombee_tpu.ops.attention import NEG_INF, repeat_kv
-from bloombee_tpu.ops.moe import moe_mlp
+from bloombee_tpu.ops.moe import moe_mlp, relu2
 from bloombee_tpu.ops.norms import layer_norm
 from bloombee_tpu.ops.pallas.flash_attention import BLOCK_K, flash_takes
 from bloombee_tpu.ops.linear_attention import (
@@ -141,8 +148,9 @@ def _mlp(x, params, spec, lora=None):
     # (deepseek_v2) hands such a layer no router
     if spec.num_experts and ("router" in params or "router_t" in params):
         with jax.named_scope("moe_experts"):
+            # an ungated expert (`mlp_type` "relu2") holds no gate leaf
             gate, up, down = (
-                maybe_dequantize(params[k], x.dtype)
+                maybe_dequantize(params[k], x.dtype) if k in params else None
                 for k in ("experts_gate", "experts_up", "experts_down")
             )
         out = moe_mlp(
@@ -172,15 +180,22 @@ def _mlp(x, params, spec, lora=None):
             sigmoid=spec.moe_router == "sigmoid",
             # afmoe: a buffer beside the router that corrects its CHOICE
             router_bias=params.get("expert_bias"),
+            activation=spec.mlp_type if gate is None else "silu",
         )
         if spec.moe_shared_intermediate:
             with jax.named_scope("moe_shared"):
-                shared = silu_mlp(
-                    x, *(
-                        maybe_dequantize(params[k], x.dtype) for k in
-                        ("shared_gate", "shared_up", "shared_down")
-                    ),
-                )
+                if gate is None:
+                    # the shared expert has the routed ones' form
+                    shared = relu2(x @ maybe_dequantize(
+                        params["shared_up"], x.dtype
+                    )) @ maybe_dequantize(params["shared_down"], x.dtype)
+                else:
+                    shared = silu_mlp(
+                        x, *(
+                            maybe_dequantize(params[k], x.dtype) for k in
+                            ("shared_gate", "shared_up", "shared_down")
+                        ),
+                    )
                 if spec.moe_shared_gate:
                     # qwen3_next: ONE shared expert, scaled a row by
                     # sigmoid(x @ w); every chip computes it alike
@@ -588,6 +603,22 @@ def _gdn_layer(spec, hidden, params, k_slab, v_slab, ssm, lora=None):
     )
 
 
+def _sublayer(spec, hidden, params, k_slab, v_slab, ssm, lora=None):
+    """A whole layer of a family whose layers are ONE sublayer each
+    (`spec.one_sublayer`: nemotron_h) that is no attention layer, on
+    [B, T, D] (or the ragged [1, R, D]) rows: x + f(norm(x)), f the
+    state-space mixer alone (a layer that holds `ssm_in_proj`: `ssm` is its
+    row of the state arena, which comes back as a fourth value) or the
+    expert layer alone (no row in either arena). The K/V slabs pass through
+    untouched. The family's attention layers run the generic body, which
+    ends after the attention's residual (`_residual_mlp`)."""
+    x = _norm(hidden, params, "input_layernorm", spec)
+    if "ssm_in_proj" in params:
+        mix, state = _mix(spec, params, x, ssm)
+        return hidden + mix, k_slab, v_slab, state
+    return hidden + _mlp(x, params, spec, lora), k_slab, v_slab
+
+
 def _mla_attention(spec: ModelSpec, page_size: int, params: dict, x,
                    c_slab, pe_slab, cos, sin, slots, page_table, q_pos,
                    total_lens, rows: SsmRows, kernels: bool, lora=None):
@@ -861,6 +892,8 @@ def layer_body(
 ):
     if spec.gdn is not None and "gdn_in_proj" in params:
         return _gdn_layer(spec, hidden, params, k_slab, v_slab, ssm, lora)
+    if spec.one_sublayer and "q_proj" not in params:
+        return _sublayer(spec, hidden, params, k_slab, v_slab, ssm, lora)
     if spec.mla is not None:
         return _mla_layer(
             spec, page_size, hidden, params, k_slab, v_slab, cos, sin, slots,
@@ -893,7 +926,7 @@ def layer_body(
         )
         if spec.qk_norm:
             q, k = _qk_norm(spec, params, q, k)
-        if not spec.alibi:
+        if not spec.alibi and spec.rope:
             q, k = apply_rotary(q, k, cos, sin)
 
     with jax.named_scope("arena_write"):
@@ -1254,6 +1287,8 @@ def layer_body_ragged(
     layer_body's block-uniform (B, T)."""
     if spec.gdn is not None and "gdn_in_proj" in params:
         return _gdn_layer(spec, hidden, params, k_slab, v_slab, ssm, lora)
+    if spec.one_sublayer and "q_proj" not in params:
+        return _sublayer(spec, hidden, params, k_slab, v_slab, ssm, lora)
     if spec.mla is not None:
         return _mla_layer(
             spec, page_size, hidden, params, k_slab, v_slab, cos, sin, slots,
@@ -1284,7 +1319,7 @@ def layer_body_ragged(
         )
         if spec.qk_norm:
             q, k = _qk_norm(spec, params, q, k)
-        if not spec.alibi:
+        if not spec.alibi and spec.rope:
             q, k = apply_rotary(q, k, cos, sin)
 
     with jax.named_scope("arena_write"):
@@ -1346,6 +1381,8 @@ def dense_unsupported(spec: ModelSpec) -> str | None:
         return "attention logit soft-cap lives inside attention"
     if spec.heterogeneous:
         return "heterogeneous head_dim layers"
+    if spec.one_sublayer:
+        return "layers that are a mixer or an expert layer alone (recurrent state)"
     if spec.ssm is not None:
         return "a state-space mixer beside attention (recurrent state)"
     if spec.gdn is not None:
@@ -1411,6 +1448,9 @@ def _finish_layer(spec, params, hidden, x, attn_out, k_slab, v_slab,
 
 
 def _residual_mlp(spec, params, hidden, x, attn_out, k_slab, v_slab, lora):
+    if spec.one_sublayer:
+        # attention is the layer's only sublayer: no MLP follows it
+        return hidden + attn_out, k_slab, v_slab
     if spec.parallel_attn:
         # falcon: parallel residual. 7b shares one input norm for attention
         # AND the MLP; 40b/180b new-arch uses two (ln_attn already fed the

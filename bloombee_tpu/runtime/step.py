@@ -29,10 +29,13 @@ and return it as a fourth value; the plan carries each sequence's slot at its
 end. A family without one passes `state=None` and compiles the programs it
 compiled before.
 
-A family whose layer KINDS interleave with a period and keep different
-caches (`spec.gdn`: three linear layers, then a full one) is scanned period
-by period (`_scan_periods`): the K/V arena has a row a full layer, the state
-arena a row a linear one, and both ride that one scan's carry.
+A family whose layer KINDS interleave and keep different caches
+(`spec.gdn`: three linear layers, then a full one; `spec.one_sublayer`:
+layers that are a mixer, an expert layer or attention ALONE, in a pattern
+whose periods differ in length) is scanned as a list of runs of a repeated
+unit (`_scan_periods`): the K/V arena has a row a layer that attends, the
+state arena a row a layer with recurrent state, an expert layer neither,
+and both arenas ride every run's carry.
 
 Shape discipline (SURVEY.md section 7 hard part #1): everything is padded to
 static buckets — batch, step tokens T, and cache pages — and validity is
@@ -58,7 +61,8 @@ from bloombee_tpu.kv.arena import (
     stacked_arena,
 )
 from bloombee_tpu.models.layout import (
-    split_kinds,
+    period_stacks,
+    plain_key,
     split_runs,
     stacked_layers,
 )
@@ -240,6 +244,12 @@ def _rope_by_window(spec: ModelSpec, q_positions: jax.Array, dtype):
 EXPERT_STACKS = ("experts_gate", "experts_up", "experts_down")
 
 
+def _expert_stacks(stacked_params: dict) -> tuple[str, ...]:
+    """The expert stacks these layers hold: all three, or an ungated
+    expert's two (no `experts_gate`: nemotron_h)."""
+    return tuple(k for k in EXPERT_STACKS if k in stacked_params)
+
+
 def experts_form(
     spec: ModelSpec, stacked_params: dict, rows: int, kernels: bool
 ) -> str:
@@ -247,13 +257,15 @@ def experts_form(
     or "dense" (ops/moe.py `expert_form`): a kernel form needs a program in
     which Pallas kernels may run and stacks a kernel can read as they lie (a
     quantised stack is dequantised a layer at a time and stays dense)."""
+    # (a span stored by run and position holds them under prefixed keys)
+    stacks = [
+        w for k, w in stacked_params.items() if plain_key(k) in EXPERT_STACKS
+    ]
     if not (
         spec.num_experts
         and kernels
-        and all(
-            isinstance(stacked_params.get(k), jax.Array)
-            for k in EXPERT_STACKS
-        )
+        and stacks
+        and all(isinstance(w, jax.Array) for w in stacks)
     ):
         return "dense"
     return expert_form(
@@ -278,17 +290,17 @@ def lift_expert_stacks(
     what it traced before."""
     if experts_form(spec, stacked_params, rows, kernels) == "dense":
         return stacked_params, None
-    xs = {k: w for k, w in stacked_params.items() if k not in EXPERT_STACKS}
-    n = stacked_params[EXPERT_STACKS[0]].shape[0]
+    stacks = _expert_stacks(stacked_params)
+    xs = {k: w for k, w in stacked_params.items() if k not in stacks}
+    n = stacked_params[stacks[0]].shape[0]
     # a layer's experts in the stack: all the router scores, or the share
     # this server holds
     xs["expert_base"] = (
-        jnp.arange(n, dtype=jnp.int32)
-        * stacked_params[EXPERT_STACKS[0]].shape[1]
+        jnp.arange(n, dtype=jnp.int32) * stacked_params[stacks[0]].shape[1]
     )
     whole = {
         k: stacked_params[k].reshape(-1, *stacked_params[k].shape[2:])
-        for k in EXPERT_STACKS
+        for k in stacks
     }
     return xs, whole
 
@@ -395,26 +407,46 @@ def _arena_dims(spec: ModelSpec, arena_k) -> tuple[int, int]:
     return arena_k.shape[0], arena_tokens(arena_k, kv_heads)
 
 
+def _position_arena(params: dict) -> str | None:
+    """The arena a period's position has a row in, read from the keys its
+    stack holds: "state" (a delta-rule or state-space mixer in attention's
+    place), "kv" (a layer that attends: every attention form ends in
+    `o_proj`), None (an expert layer that is a layer of its own)."""
+    if "gdn_in_proj" in params or "ssm_in_proj" in params:
+        return "state"
+    return "kv" if "o_proj" in params else None
+
+
 def _scan_periods(run_layer, spec, stacked_params, rows, kernels, hidden,
                   arena_k, arena_v, slots, page_table, layer_active,
                   per_layer, page_size, state, state_slots, ssm_rows):
-    """The layer scan of a span whose layer KINDS interleave, a period
-    ending on its full layer (`spec.gdn`: linear, linear, linear, full): a
-    scan over the periods of each RUN of like periods (one run, or two where
-    a period differs from the rest: the model's leading dense layer stands
-    in the first, or its last is short; `ModelSpec.period_runs`, the first
-    run's stacks under models/layout.py `LEAD`). A scan's body runs the
-    period's layers in order; its xs are one stack a position in the period
-    (`split_kinds`), each [periods, ...]; both flat arenas ride the carry
-    whole, from one run's scan into the next's. A layer's row in ITS arena is
-    its index among its kind in the span: linear layer j of the run's period
-    p reads and writes row `state_row0 + p * m + j` of the state arena and
-    has no row in the K/V arena; the period's full layer has row `kv_row0 +
-    p` there (K and V, or a latent page) and none in the state arena
-    (`ModelSpec.cache_rows`). `layer_active` still gates layer by layer (a
-    session entering mid-span). Returns what `_scan_runs` returns with a
-    state arena: on a server that holds a share of the experts the result
-    ends with the SPARSE layers' reach, in layer order."""
+    """The layer scan of a span whose layer KINDS interleave and keep
+    different caches (`spec.kinds_interleave`): ONE scan over a LIST of
+    runs, each run a unit of layers repeated (`ModelSpec.period_runs`,
+    models/layout.py `period_stacks`). Delta-rule layers among full ones
+    (`spec.gdn`) are one run of like periods (linear, linear, linear, full),
+    or two where a period differs from the rest (the model's leading dense
+    layer stands in the first, or its last is short: the first run's stacks
+    under `LEAD`); layers that are one sublayer each (`spec.one_sublayer`)
+    are as many runs as the pattern has, a unit a period that comes again
+    at once, else a pair of kinds that does, or a single layer ((moe, mamba,
+    moe, mamba, moe, mamba, full) x 2; (moe, mamba) x 4, (full,) x 1). A
+    run's scan body runs the
+    unit's layers in order; its xs are one stack a position in the unit,
+    each [repeats, ...]; both flat arenas ride the carry whole, from one
+    run's scan into the next's. A layer's row in ITS arena is its index
+    among its kind in the span (`ModelSpec.cache_rows`): in a run whose
+    unit has m layers with recurrent state and f that attend, the j-th
+    stateful layer of repeat p has row `state_row0 + p * m + j` of the state
+    arena and none in the K/V arena, the i-th attending one row `kv_row0 +
+    p * f + i` there (K and V, or a latent page) and none in the state
+    arena; an expert layer that is a layer of its own has neither.
+    `layer_active` still gates layer by layer (a session entering
+    mid-span). Returns what `_scan_runs` returns with a state arena: on a
+    server that holds a share of the experts the result ends with the
+    SPARSE layers' reach, in layer order. A span with no attending layer
+    (the tail of a `one_sublayer` model) has a K/V arena of no rows, which
+    comes back as it came."""
     held = spec.moe_held is not None
     kv_layers, s_tot = _arena_dims(spec, arena_k)
     num_pages = s_tot // page_size
@@ -424,21 +456,22 @@ def _scan_periods(run_layer, spec, stacked_params, rows, kernels, hidden,
     )
     layer0 = kv_row0 = state_row0 = 0
     reached_runs = []
-    lead, main = split_runs(stacked_params)
-    for run_params in ([main] if lead is None else [lead, main]):
-        linear, full = split_kinds(run_params)
-        m = len(linear)
-        per = m + 1
-        periods = jax.tree.leaves(full)[0].shape[0]
+    for positions in period_stacks(stacked_params):
+        per = len(positions)
+        arenas = [_position_arena(params) for params in positions]
+        # a position's index among its kind in the unit
+        among = [arenas[:j].count(arenas[j]) for j in range(per)]
+        m, f = arenas.count("state"), arenas.count("kv")
+        periods = jax.tree.leaves(positions[-1])[0].shape[0]
         # a position's reach vector: none where its MLP is dense
         fields = [
             len(reach_fields("expert_bias" in params)) if held and (
                 "router" in params or "router_t" in params) else 0
-            for params in (*linear, full)
+            for params in positions
         ]
         stacks = [
             lift_expert_stacks(spec, params, rows, kernels)
-            for params in (*linear, full)
+            for params in positions
         ]
 
         # (everything below is traced inside this iteration's `lax.scan`:
@@ -451,24 +484,38 @@ def _scan_periods(run_layer, spec, stacked_params, rows, kernels, hidden,
                     periods, per, *a.shape[1:])[:, j], x
             )
 
+        def kv_row(p, j):
+            # kv_row0 + p * f + among[j], a period's ONE full layer traced
+            # as the `kv_row0 + p` it always was
+            row = kv_row0 + (p if f == 1 else p * f)
+            return row + among[j] if among[j] else row
+
         def body(carry, xs_p):
             p, *by_position = xs_p
-            # the period's ONE full layer: its row of the K/V arena
-            slots_l = layer_slots(slots, kv_row0 + p, s_tot, kv_layers)
-            pages_l = layer_pages(page_table, kv_row0 + p, num_pages)
+            # the attending layers' rows of the K/V arena; a layer without
+            # one is handed the plan's own ids and never reads them
+            addressed = {
+                j: (
+                    layer_slots(slots, kv_row(p, j), s_tot, kv_layers),
+                    layer_pages(page_table, kv_row(p, j), num_pages),
+                )
+                for j in range(per) if arenas[j] == "kv"
+            }
+            fallback = next(iter(addressed.values()), (slots, page_table))
             reached = []
             for j, (active, params_l, *extras_l) in enumerate(by_position):
-                is_linear = j < m
+                stateful = arenas[j] == "state"
+                slots_l, pages_l = addressed.get(j, fallback)
                 experts = stacks[j][1]
                 if experts is not None:
                     params_l = {**params_l, **experts}
                 xs_l = (params_l, *extras_l)
 
                 def run(h, k_flat, v_flat, state_flat, xs_l=xs_l,
-                        is_linear=is_linear, row=state_row0 + p * m + j,
-                        reach=fields[j]):
+                        stateful=stateful, slots_l=slots_l, pages_l=pages_l,
+                        row=state_row0 + p * m + among[j], reach=fields[j]):
                     ssm_l = None
-                    if is_linear:
+                    if stateful:
                         ssm_l = (
                             state_flat,
                             layer_state_slots(
@@ -481,7 +528,7 @@ def _scan_periods(run_layer, spec, stacked_params, rows, kernels, hidden,
                         out = run_layer(
                             h, k_flat, v_flat, slots_l, pages_l, xs_l, ssm_l
                         )
-                    out = out if is_linear else (*out, state_flat)
+                    out = out if stateful else (*out, state_flat)
                     return (*out, sown[0]) if reach else out
 
                 def skip(h, k_flat, v_flat, state_flat, reach=fields[j]):
@@ -511,13 +558,13 @@ def _scan_periods(run_layer, spec, stacked_params, rows, kernels, hidden,
         if reached is not None:
             reached_runs.append(reached.reshape(-1, reached.shape[-1]))
         layer0 += periods * per
-        kv_row0 += periods
+        kv_row0 += periods * f
         state_row0 += periods * m
     hidden, k_flat, v_flat, state_flat = carry
     out = (
         hidden,
-        stacked_arena(k_flat, kv_layers),
-        stacked_arena(v_flat, kv_layers),
+        stacked_arena(k_flat, kv_layers) if kv_layers else arena_k,
+        stacked_arena(v_flat, kv_layers) if kv_layers else arena_v,
         stacked_arena(state_flat, state_layers),
     )
     return (*out, jnp.concatenate(reached_runs)) if reached_runs else out
@@ -537,7 +584,7 @@ def _scan_runs(run_layer, spec, stacked_params, rows, kernels, hidden,
     experts (`spec.moe_held`) the result ends with what the sparse layers'
     rows reached of them, i32 [sparse layers, 3 or 5] (`_scan_layers`). A span
     whose kinds interleave goes to `_scan_periods`."""
-    if spec.gdn is not None:
+    if spec.kinds_interleave:
         return _scan_periods(
             run_layer, spec, stacked_params, rows, kernels, hidden, arena_k,
             arena_v, slots, page_table, layer_active, per_layer, page_size,
@@ -789,7 +836,7 @@ def pack_chunk_on_flash(spec: ModelSpec) -> bool:
     ragged paged kernel, latent attention its own flash form (also where
     its layers stand among linear ones: kimi_linear)."""
     return (
-        spec.gdn is not None and spec.mla is None
+        spec.kinds_interleave and spec.mla is None
     ) or spec.mamba is not None
 
 
@@ -839,7 +886,8 @@ def span_step_ragged_impl(
     # the span's layers: the K/V arena's rows, except where the layer kinds
     # differ in their cache and the arena has a row a FULL layer
     num_layers = (
-        arena_k.shape[0] if spec.gdn is None and spec.mamba is None
+        arena_k.shape[0]
+        if not spec.kinds_interleave and spec.mamba is None
         else stacked_layers(stacked_params)
     )
     cross = None

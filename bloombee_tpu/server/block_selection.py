@@ -250,6 +250,27 @@ def estimate_block_bytes(spec, dtype, layer: int | None = None) -> int:
     attn = d * h * hd + 2 * d * kv * hd + h * hd * d
     if layer is None:
         layer = spec.num_hidden_layers - 1
+    if spec.one_sublayer:
+        # ONE sublayer and one norm, as stored (models/nemotron_h.py): the
+        # mixer's in_proj and an expert's intermediate width in whole lanes,
+        # two matrices an expert; the recurrence's vectors and the router's
+        # bias stay float32 whatever the dtype
+        from bloombee_tpu.models.layout import lane_padded
+
+        ssm, kind = spec.ssm, spec.layer_type(layer)
+        params, f32 = {
+            "mamba": (
+                d * lane_padded(ssm.proj_dim) + ssm.d_ssm * d
+                + (ssm.conv + 1) * ssm.conv_dim + ssm.d_ssm, 3 * ssm.heads),
+            "moe": (
+                spec.experts_held[1] * 2 * d
+                * lane_padded(spec.moe_intermediate_size)
+                + d * spec.num_experts
+                + 2 * d * spec.moe_shared_intermediate, spec.num_experts),
+            "full": (attn, 0),
+        }[kind]
+        itemsize = np.dtype(dtype).itemsize if dtype is not None else 2
+        return (params + d) * itemsize + f32 * 4
     if spec.attn_gate:
         attn += d * h * hd + 2 * hd  # the gate rows of q_proj, q/k norms
     linear = spec.gdn is not None and spec.layer_type(layer) == "linear"
@@ -366,6 +387,18 @@ def choose_num_blocks(
             spec, num_pages, page_size, max_batch
         ) * state_slot_bytes(spec.recurrent, np.dtype(dtype).itemsize)
     budget = limit * memory_fraction
+    if spec.one_sublayer:
+        # the periods differ in length: the longest window of whole periods
+        # whose weights and arena rows fit (at least the shortest period)
+        cuts = spec.period_starts()
+        fits = [
+            b - a for a in cuts for b in cuts if b > a and (
+                estimate_span_bytes(spec, dtype, a, b)
+                + spec.arena_layers(a, b)[0] * arena_bytes
+                + spec.arena_layers(a, b)[1] * state_bytes
+            ) <= budget
+        ]
+        return max(fits or [min(b - a for a, b in zip(cuts, cuts[1:]))])
     if spec.gdn is not None:
         # the kinds interleave: count by whole periods, each layer's own
         # weights and the ONE arena it has a row in

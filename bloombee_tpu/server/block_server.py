@@ -2115,7 +2115,7 @@ class BlockServer(PromotionLoopMixin):
                 {"layer_kinds": dict(collections.Counter(
                     self.spec.layer_type(i)
                     for i in range(self.start_block, self.end_block)
-                ))} if self.spec.gdn is not None
+                ))} if self.spec.kinds_interleave
                 or self.spec.mamba is not None else {}
             ),
             # a SambaY span (runtime/sambay.py): rows through the

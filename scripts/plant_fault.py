@@ -32,6 +32,7 @@ MOE = PROGRAM / "ops" / "moe.py"
 DEEPSEEK_V2 = PROGRAM / "models" / "deepseek_v2.py"
 AFMOE = PROGRAM / "models" / "afmoe.py"
 KIMI_LINEAR = PROGRAM / "models" / "kimi_linear.py"
+NEMOTRON_H = PROGRAM / "models" / "nemotron_h.py"
 
 ROUTED_SUM = (
     BODY,
@@ -223,6 +224,32 @@ FAULTS = {
             "`routed_scaling_factor` left out of the router's weights: the "
             "routed experts count 1 / 2.446",
             KIMI_LINEAR,
+            '        moe_route_scale=float(get("routed_scaling_factor", 1.0)),',
+            "        moe_route_scale=1.0,",
+        ),
+        "bias_out": AFMOE_BIAS_CHOICE,
+    },
+    # nemotron3nano-longctx
+    "nemotron_h": {
+        "state_reset": (
+            "a chunk with a padded tail (a prompt's LAST chunk) starts from "
+            "an empty state S: what the Mamba-2 layers kept of the prompt "
+            "before that chunk boundary is lost",
+            BODY,
+            "            s0 = jnp.where(rows.fresh[c], 0.0, s0)",
+            "            s0 = jnp.where(rows.fresh[c] | (n_c < w), 0.0, s0)",
+        ),
+        "relu2_silu": (
+            "relu(u) ** 2 replaced by silu(u) in the ROUTED experts (every "
+            "form: the dense einsums and both kernels share the line)",
+            MOE,
+            "    return relu2(u)",
+            "    return jax.nn.silu(u)",
+        ),
+        "route_scale_out": (
+            "`routed_scaling_factor` left out of the router's weights: the "
+            "routed experts count 1 / 2.5",
+            NEMOTRON_H,
             '        moe_route_scale=float(get("routed_scaling_factor", 1.0)),',
             "        moe_route_scale=1.0,",
         ),

@@ -50,6 +50,7 @@ from test_cellbench_families import (  # noqa: E402
     TINY_FALCON_H1,
 )
 from test_cellbench_kimi_linear import TINY_KIMI_LINEAR  # noqa: E402
+from test_cellbench_nemotron_h import TINY_NEMOTRON_H  # noqa: E402
 from test_cellbench_phi4flash import (  # noqa: E402
     TINY_PHI4FLASH,
     WIDE_PHI4FLASH,
@@ -211,6 +212,23 @@ ROWS = {
         device_metrics=(
             "chunk_kda_ms_p50", "step_kda_ms_p50", "kda_rule_roofline",
             "kda_state_move_share", "chunk_mla_ms_p50", "device_idle_share")),
+    "nemotron_h": Row(
+        presets={"tiny-nemotron-h": TINY_NEMOTRON_H},
+        joins="nemotron3nano-longctx", server_flags=("--experts", "2:4"),
+        # sound 2.5e-7 on two seeds (float32's order of sums); the state
+        # emptied at a prompt's last chunk boundary 1.3e-4 (at these widths
+        # the state carries little), the other faults hundreds of times that
+        limit=1e-5, sound_seed=2**31 + 54, int8_abs=0.15,
+        sound_seconds=6,  # a traced 1.8 s: room for a full chunk's reach span
+        faults=("state_reset",),
+        # the ungated form's line, and those kimi_linear's and afmoe's rows
+        # plant
+        slow_faults=("relu2_silu", "route_scale_out", "bias_out"),
+        device_metrics=(
+            "chunk_ssm_ms_p50", "step_ssm_ms_p50", "ssm_scan_roofline",
+            "state_io_move_share", "chunk_experts_roofline",
+            "chunk_experts_ms_p50", "device_idle_share"),
+        also=(_reach_metrics_read,)),
 }
 
 

@@ -267,13 +267,13 @@ def test_a_kda_metric_reads_nothing_where_there_is_no_trace(tmp_path, name):
 
 
 def test_the_new_cell_joins_the_metrics_it_reports_and_adds_four():
-    """BENCHMARK.json: the cell is an ADDITION (the eighth of eight one-chip
-    cells), every list it joined had the seven or is one ISSUE 51 names, and
-    the four KDA metrics list it alone."""
+    """BENCHMARK.json: the cell was an ADDITION (the eighth one-chip cell;
+    later PRs add theirs after it), every list it joined had the seven or is
+    one ISSUE 51 names, and the four KDA metrics list it alone."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     cell = "kimilinear-longctx"
-    assert [w["name"] for w in bench["workloads"]][-1] == cell
-    assert len(bench["workloads"]) == len(bench["configs"]) == 8
+    assert [w["name"] for w in bench["workloads"]][7] == cell
+    assert len(bench["workloads"]) == len(bench["configs"]) >= 8
     assert all(w["chips"] == 1 for w in bench["workloads"])
     joined = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]
               if cell in m.get("workloads", ())}

@@ -1089,6 +1089,7 @@ _LAYOUT_CASES = {
     "phi4flash-10x128": (10, 128, True),
     "4x256": (4, 256, True),
     "16x256": (16, 256, False),
+    "nemotronh-2x128": (2, 128, False),
 }
 
 
@@ -1349,3 +1350,150 @@ def test_kimi_linear_span_step_compiles_and_copies_no_parameter(v5e, program):
     # a time held an eighth), tail 44 MB
     assert temp < {"decode": 60, "chunk": 150, "tail": 60,
                    "fused": 500}[program] * 1e6, temp
+
+
+# -------------------------------------------------------------- nemotron_h
+# Layers that are ONE sublayer each (a Mamba-2 mixer, an ungated relu²
+# expert layer of 64 held two-matrix experts, position-free GQA attention
+# alone): the span steps of the benchmark's cell (cellbench/configs/
+# nemotron3-nano-30b-ep2-span14.json: published widths, EMEMEM*EMEMEM*, 5376
+# pages) at the 1024-page bucket its contexts take. The span is ONE run, the
+# period (moe, mamba, moe, mamba, moe, mamba, full) twice, one stack a
+# position in it.
+def _nemotron_h_shapes(one_chip, pages=5376):
+    import dataclasses
+    import json
+    import pathlib
+
+    from bloombee_tpu.kv.cache_manager import state_slots_for
+    from bloombee_tpu.models.auto import spec_from_config_dict
+    from bloombee_tpu.models.layout import lane_padded, unit_prefix
+
+    config = json.loads((
+        pathlib.Path(__file__).resolve().parents[1]
+        / "cellbench/configs/nemotron3-nano-30b-ep2-span14.json").read_text())
+    held = tuple(config["experts_held"])
+    spec = dataclasses.replace(
+        spec_from_config_dict(config), num_experts=config["router_experts"],
+        moe_held=held)
+    d, h, kv, hd, ssm = (spec.hidden_size, spec.num_attention_heads,
+                         spec.num_key_value_heads, spec.head_dim, spec.ssm)
+    f32 = jnp.float32
+    i, si = lane_padded(spec.moe_intermediate_size), spec.moe_shared_intermediate
+    assert (i, ssm.proj_dim, lane_padded(ssm.proj_dim)) == (1920, 10304, 10368)
+
+    def stacks(n):
+        def s(*shape, dtype=bf16):
+            return jax.ShapeDtypeStruct((n, *shape), dtype, sharding=one_chip)
+
+        norm = {"input_layernorm": s(d)}
+        return {
+            "moe": {
+                **norm, "router_t": s(spec.num_experts, d),
+                "expert_bias": s(spec.num_experts, dtype=f32),
+                "experts_up": s(held[1], d, i),
+                "experts_down": s(held[1], i, d),
+                "shared_up": s(d, si), "shared_down": s(si, d)},
+            "mamba": {
+                **norm, "ssm_in_proj": s(d, lane_padded(ssm.proj_dim)),
+                "ssm_out_proj": s(ssm.d_ssm, d),
+                "ssm_conv_w": s(ssm.conv, ssm.conv_dim),
+                "ssm_conv_b": s(ssm.conv_dim), "ssm_norm": s(ssm.d_ssm),
+                "ssm_a_log": s(ssm.heads, dtype=f32),
+                "ssm_d": s(ssm.heads, dtype=f32),
+                "ssm_dt_bias": s(ssm.heads, dtype=f32)},
+            "full": {
+                **norm, "q_proj": s(h * hd, d), "k_proj": s(kv * hd, d),
+                "v_proj": s(kv * hd, d), "o_proj": s(h * hd, d)},
+        }
+
+    runs = spec.period_runs(0, spec.num_hidden_layers)
+    assert runs == ((("moe", "mamba") * 3 + ("full",), 2),)
+    params = {}
+    for r, (kinds, repeats) in enumerate(runs):
+        for j, kind in enumerate(kinds):
+            params.update({unit_prefix(r, j) + k: v
+                           for k, v in stacks(repeats)[kind].items()})
+    kv_layers, state_layers = spec.arena_layers(0, spec.num_hidden_layers)
+    slots = state_slots_for(spec, pages, PAGE, 8)
+
+    def a(*shape, dtype=bf16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    state = {
+        "ssm": a(state_layers, slots, *ssm.state_shape, dtype=f32),
+        "conv": a(state_layers, slots, *ssm.tail_shape),
+    }
+    # 2 KV heads of 128 do not fold (kv/arena.py `folds`)
+    return spec, params, a(kv_layers, pages * PAGE, kv, hd), state
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk", "tail", "fused"])
+def test_nemotron_h_span_step_compiles_and_copies_no_parameter(v5e, program):
+    """The cell's step programs (a decode group, a solo 512-row chunk, an
+    8-row tail, a 1024-row fused pack; 1024-page bucket, kernels on): ONE
+    program scans the period twice over a 2-row K/V arena and a 6-row
+    state arena; the compiled text holds no `copy` of a `stacked_params`
+    parameter and no copy of a whole arena, the ungated expert kernels are
+    in it with weight blocks inside the budget, and the temporaries stay
+    what the rows' activations need."""
+    import re
+    import time
+
+    from bloombee_tpu.kv.arena import folds
+
+    one_chip = SingleDeviceSharding(v5e[0])
+    spec, params, arena, state = _nemotron_h_shapes(one_chip)
+    assert not folds(2, 128, bf16)
+    assert (arena.shape, state["ssm"].shape) == (
+        (2, 5376 * PAGE, 2, 128), (6, 16, 64, 64, 128))
+    slab = arena.shape[1] * arena.shape[2] * arena.shape[3]
+    layers, pages = 14, 1024
+    common = dict(
+        spec=spec, page_size=PAGE, max_pages=pages, windows=(0,) * layers)
+    t0 = time.time()
+    if program == "fused":
+        r, n_seqs = 1024, 4
+        plan_len = r + n_seqs * pages + r + n_seqs + r + layers + 3 * n_seqs + 1
+        compiled = span_step_ragged.lower(
+            params, arena, arena,
+            _cell_payload(spec, r, plan_len, one_chip), None, state,
+            r=r, n_seqs=n_seqs, use_kernel=True, **common,
+        ).compile()
+    else:
+        b, t, t_real = {"decode": (4, 1, None), "chunk": (1, 512, 512),
+                        "tail": (1, 8, 5)}[program]
+        plan_len = b * t + b * pages + b * t + b + layers + b
+        compiled = span_step_packed.lower(
+            params, arena, arena,
+            _cell_payload(spec, b * t, plan_len, one_chip), None, None, state,
+            b=b, t=t, use_paged=t < 512, use_flash=t == 512,
+            expert_kernels=True, t_real=t_real, page_groups=t == 512,
+            **common,
+        ).compile()
+    seconds = time.time() - t0
+    text = compiled.as_text()
+    assert "%stacked_params__r0p0_experts_up" in text  # the names read
+    assert "%stacked_params__r0p6_q_proj" in text
+    copied = re.findall(r"copy\([^)\n]*%(stacked_params\w+)", text)
+    assert not copied, copied
+    assert not re.findall(r"copy\([^)\n]*%(arena_[kv]|state__ssm)", text)
+    assert "tpu_custom_call" in text
+    tiled = program in ("chunk", "fused")
+    assert ("jit(tiled_experts)" in text) == tiled
+    assert ("jit(grouped_experts)" in text) == (not tiled)
+    # no program re-lays a K/V slab out or copies one (while the two
+    # attention layers stood in runs of ONE repeat each, whose scans the
+    # compiler unrolls, a chunk's page write copied the whole 88 MB arena
+    # four times: 0.126 s of a traced 5 s, my chip run, PR 54, call 1); the
+    # chunk's K/V go in one index a PAGE
+    moved = [m for m in _slab_moves(text, slab)
+             if f"[{2 * arena.shape[1]},2,128]" in m]
+    assert not moved, moved
+    if program == "chunk":  # K and V of the period's one attention layer
+        assert _scatter_indices(text).count(512 // PAGE) == 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < {"decode": 80, "chunk": 400, "tail": 80,
+                   "fused": 800}[program] * 1e6, temp
+    # far from the client's 120 s step_timeout, on this machine's CPU
+    assert seconds < 100, seconds
