@@ -39,7 +39,10 @@ from bloombee_tpu.runtime.step import (
 from bloombee_tpu.ops.linear_attention import sequence_blocks
 from bloombee_tpu.ops.moe import reach_fields
 from bloombee_tpu.ops.pallas.flash_attention import flash_takes, flash_tiles
-from bloombee_tpu.ops.pallas.paged_attention import _pages_per_step
+from bloombee_tpu.ops.pallas.paged_attention import (
+    _pages_per_step,
+    walk_bounds,
+)
 from bloombee_tpu.runtime.layer_body import chunk_run_pages
 from bloombee_tpu.utils import env, jitwatch
 
@@ -442,13 +445,14 @@ class SpanExecutor:
         # the tile the last chunk's flash kernel multiplied, by layer kind
         # (`_flash_form`, made once a (rows, page bucket)); None until a
         # chunk has attended through it. The windows are those of the
-        # span's layers that attend (a linear, Mamba or GMU layer does not)
+        # span's layers that attend (a linear, Mamba or GMU layer does not),
+        # each with the number of such layers
         self.flash_form: str | None = None
         self._flash_forms: dict[tuple[int, int], str | None] = {}
-        self._attn_windows = {
+        self._attn_windows = collections.Counter(
             w for i, w in enumerate(self.windows)
             if spec.layer_type(start_block + i) in ("full", "sliding", "cross")
-        }
+        )
         # a server that holds a share of the experts (spec.moe_held): what
         # each step's rows reached of them per sparse layer (ops/moe.py
         # `held_reach`), handed out of the step program as a device array
@@ -477,6 +481,12 @@ class SpanExecutor:
         # K/V rows went into the arena: one index a page (`_page_groups`)
         # or one a row
         self.kv_writes = {"chunk_page_writes": 0, "chunk_row_writes": 0}
+        # decode dispatches whose rows attend through the paged decode
+        # kernel: the turns (grid steps) its calls walked over the span's
+        # attending layers, and those of them that held a live page
+        # (ops/pallas/paged_attention.py `walk_bounds`, the wrapper's own
+        # rule on the step's padded lengths)
+        self.kv_walk = {"turns": 0, "live_turns": 0}
         # whether both slabs' page view is free (kv/arena.py
         # `page_view_free`, by their shape; an int4 arena, one sharded over
         # a mesh and a heterogeneous span's per-layer slabs keep the row
@@ -1901,6 +1911,9 @@ class SpanExecutor:
                 t == 1 and use_paged and spec.mla is None
                 and self.manager.quant is None
             )
+            decode_pages = _pages_per_step(
+                pb, self.page_size * spec.num_key_value_heads
+            ) if decode_kernel else None
             out = self._keep_arena(
                 result, "decode" if t == 1 else "chunk", b * t, starts,
                 self._count_moe(bb * tb, kernels_used),
@@ -1908,10 +1921,14 @@ class SpanExecutor:
                 flash=self._flash_form(tb, pb) if flash_now else None,
                 write="pages" if page_groups else "rows",
                 rule_rows=tb if tb > 1 else 0,
-                decode_pages=_pages_per_step(
-                    pb, self.page_size * spec.num_key_value_heads
-                ) if decode_kernel else None,
+                decode_pages=decode_pages,
             )
+            if decode_kernel:
+                for window, layers in self._attn_windows.items():
+                    _, extent, live = walk_bounds(
+                        lens_pad, window, self.page_size, decode_pages, np)
+                    self.kv_walk["turns"] += layers * bb * int(extent)
+                    self.kv_walk["live_turns"] += layers * int(live)
         if t > 1:
             self.kv_writes[
                 "chunk_page_writes" if page_groups else "chunk_row_writes"

@@ -2236,6 +2236,7 @@ class BlockServer(PromotionLoopMixin):
         info["memory"] = server_memory_report(self)
         # (once more where a reader that keeps `memory` alone finds it)
         info["memory"]["kv_writes"] = dict(self.executor.kv_writes)
+        info["memory"]["kv_walk"] = dict(self.executor.kv_walk)
         if self.spec.mamba is not None or any(self.executor.windows):
             # the window-dead accounting and the SambaY counters once more,
             # beside the K/V arena they are about (a reader that keeps a

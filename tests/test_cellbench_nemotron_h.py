@@ -285,7 +285,8 @@ def test_the_new_cell_joins_the_metrics_it_reports_and_adds_one():
     # finds nothing beside any other attention: the cell reports the reach
     # through `held_experts_reached_share`, as trinity-longctx does
     assert "held_experts_hit_share" not in joined
-    metric = bench["per_layer"][-1]
+    metric = next(m for m in bench["per_layer"]
+                  if m["name"] == "chunk_experts_roofline")
     assert metric == {
         "name": "chunk_experts_roofline", "unit": "%", "better": "higher",
         "source": "device_trace", "layer": "kernels",

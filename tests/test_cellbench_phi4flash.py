@@ -171,11 +171,13 @@ def test_sambay_metrics_on_a_synthetic_reduction_and_counters():
            "info0": {"memory": {"sambay": {
                "cross_rows": 10, "self_rows": 100, "kv_held_tokens": 0,
                "window_dead_tokens": 0}, "kv_writes": {
-               "chunk_page_writes": 40, "chunk_row_writes": 7}}},
+               "chunk_page_writes": 40, "chunk_row_writes": 7},
+               "kv_walk": {"turns": 4096, "live_turns": 1336}}},
            "info1": {"memory": {"sambay": {
                "cross_rows": 110, "self_rows": 10100, "kv_held_tokens": 900,
                "window_dead_tokens": 720}, "kv_writes": {
-               "chunk_page_writes": 115, "chunk_row_writes": 32}}}}
+               "chunk_page_writes": 115, "chunk_row_writes": 32},
+               "kv_walk": {"turns": 6096, "live_turns": 3236}}}}
     read = lambda name: cell_run.read_metric(name, ctx)  # noqa: E731
     assert read("chunk_mamba_ms_p50") == 7.0
     assert read("step_mamba_ms_p50") == 1.25
@@ -183,6 +185,15 @@ def test_sambay_metrics_on_a_synthetic_reduction_and_counters():
     assert read("cross_rows_share") == pytest.approx(1.0)
     assert read("window_dead_share") == pytest.approx(80.0)
     assert read("page_write_share") == pytest.approx(75.0)  # 75 of 100
+    assert read("decode_walk_live_share") == pytest.approx(95.0)  # of 2000
+    # a program without the counter (the parent of PR 56), and a window
+    # with no decode dispatch through the kernel: nothing to read
+    for info in ctx["info0"], ctx["info1"]:
+        info["memory"]["kv_walk"]["turns"] = 4096
+    assert read("decode_walk_live_share") is None
+    for info in ctx["info0"], ctx["info1"]:
+        del info["memory"]["kv_walk"]
+    assert read("decode_walk_live_share") is None
     needs = families.of(_published()).mamba1_scan_needs(
         _published(), 512, "chunk")
     least = max(needs["bytes"] / 819e9, needs["flops"] / 197e12)

@@ -181,12 +181,21 @@ def _kernel_cases():
     # phi4flash's: the 1024-page bucket of the cell's 5376-page arena, 40
     # query halves of 128 in float32 (`_diff_attend` widens them) against 10
     # K/V pairs: four 160-row bfloat16 blocks a grid step
+    # (the grid's extent is a traced scalar since PR 56, `walk_bounds`: by
+    # the rows' lengths alone, and in the `_w512` cases by a window that is
+    # an operand too, as a window layer's call hands it over)
     phi_slab = ((5376 * PAGE, 10, HD), bf16)
     for rows in (1, 4):
+        phi_call = [((rows, 40, HD), jnp.float32), phi_slab, phi_slab,
+                    ((rows, 1024), i32), ((rows,), i32)]
         cases[f"paged_decode_phi4flash_b{rows}_p1024"] = (
             functools.partial(paged_decode_attention, page_size=PAGE),
-            [((rows, 40, HD), jnp.float32), phi_slab, phi_slab,
-             ((rows, 1024), i32), ((rows,), i32)],
+            phi_call,
+        )
+        cases[f"paged_decode_phi4flash_b{rows}_p1024_w512"] = (
+            lambda q, k, v, p, ln, w: paged_decode_attention(
+                q, k, v, p, ln, page_size=PAGE, window=w),
+            phi_call + [((), i32)],
         )
     # T=64 and R=64 sit on the executor's `rows * H <= 2048` VMEM edge
     for t in (16, 64):
@@ -302,6 +311,12 @@ _SPAN_STEPS = {
     # decode through the paged kernel, prefill chunk through flash, a short
     # chunk through the chunk kernel (what warm-up + chip_smoke dispatch)
     "decode_paged": dict(b=B, t=1, pages=NP, use_paged=True),
+    # window layers among full ones in one stack (the Mistral / Trinity
+    # shape): the layer scan hands the kernel each layer's window as a
+    # traced scalar, and the walk's extent follows it (PR 56)
+    "decode_paged_windows": dict(
+        b=B, t=1, pages=NP, use_paged=True,
+        windows=(512, 512, 512, 0) * (LAYERS // 4)),
     # (both chunks' rows come as page groups: written by page, PR 49)
     "prefill_flash": dict(
         b=1, t=128, pages=8, use_flash=True, page_groups=True),
@@ -315,13 +330,13 @@ def test_span_step_compiles_for_v5e(v5e, name):
     one_chip = SingleDeviceSharding(v5e[0])
     case = dict(_SPAN_STEPS[name])
     b, t, pages = case.pop("b"), case.pop("t"), case.pop("pages")
+    case.setdefault("windows", (0,) * LAYERS)
     params, arena = _span_shapes(lambda kind, key: one_chip)
     compiled = span_step_packed.lower(
         params, arena, arena,
         _payload(b * t, _packed_plan_len(b, t, pages), one_chip),
         None, None,
-        spec=SPEC, b=b, t=t, page_size=PAGE, max_pages=pages,
-        windows=(0,) * LAYERS, **case,
+        spec=SPEC, b=b, t=t, page_size=PAGE, max_pages=pages, **case,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
 

@@ -199,7 +199,9 @@ def test_span_decode_paged_kernel_matches_dense():
 def test_span_decode_paged_kernel_sliding_windows():
     """Mistral/gemma-style alternating sliding-window layers run through
     the paged kernel (the per-layer window rides the scan) and match the
-    dense path exactly."""
+    dense path exactly. The executor counts the turns the two decode steps'
+    calls walked (`kv_walk`): 2 layers x 2 rows x the 2 pages that hold 20
+    and 21 tokens, where the 4-page bucket held 4, all of them live."""
     import asyncio
     import os
 
@@ -250,16 +252,18 @@ def test_span_decode_paged_kernel_sliding_windows():
                 outs = [ex.prefill(handle, prefill)]
                 for s in steps:
                     outs.append(ex.decode(handle, s))
-                return outs
+                return outs, dict(ex.kv_walk)
         finally:
             del os.environ["BBTPU_PAGED_ATTENTION"]
             del os.environ["BBTPU_PAGED_INTERPRET"]
             del os.environ["BBTPU_PAGED_MIN_CONTEXT"]
 
-    outs_paged = asyncio.run(run_one(True))
-    outs_dense = asyncio.run(run_one(False))
+    outs_paged, walk = asyncio.run(run_one(True))
+    outs_dense, no_walk = asyncio.run(run_one(False))
     for got, want in zip(outs_paged, outs_dense):
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    assert walk == {"turns": 16, "live_turns": 16}
+    assert no_walk == {"turns": 0, "live_turns": 0}
 
 
 def test_paged_kernel_context_threshold():
@@ -446,9 +450,12 @@ def test_int4_arena_uses_paged_kernel_and_matches_dense_path():
         np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
 
 
-def test_int4_paged_kernel_sliding_window():
-    """int4 kernel honors the sliding window (shared softmax body): match
-    the host-dequantized windowed reference."""
+@pytest.mark.parametrize("win", [11, 3, 64])
+def test_int4_paged_kernel_sliding_window(win):
+    """int4 kernel honors the sliding window (shared softmax body and the
+    bounded walk: a window inside the last page, one over two pages, one
+    longer than the context): match the host-dequantized windowed
+    reference."""
     import jax.numpy as jnp
 
     from bloombee_tpu.kv.quant import dequantize, quantize
@@ -459,7 +466,6 @@ def test_int4_paged_kernel_sliding_window():
     rng = np.random.default_rng(3)
     B, H, HKV, hd = 2, 4, 2, 64
     page_size, n_pages, max_pages = 8, 8, 4
-    win = 11
     q = jnp.asarray(rng.standard_normal((B, H, hd)), jnp.float32)
     k_dense = jnp.asarray(
         rng.standard_normal((n_pages * page_size, HKV, hd)), jnp.float32
@@ -971,6 +977,22 @@ def test_paged_ragged_tree_matches_dense_reference(seed):
 
 
 # ----------------------------------------- several pages a grid step
+def _walk_case(rng, hkv, n_pages, lens, page_size=16, hd=64, n_phys=40):
+    """Queries, slabs and a page table of shuffled physical pages whose
+    padding entries are page 0, for rows of the given lengths."""
+    b, h = len(lens), 2 * hkv
+    q = rng.standard_normal((b, h, hd)).astype(np.float32)
+    k_slab = rng.standard_normal(
+        (n_phys * page_size, hkv, hd)).astype(np.float32)
+    v_slab = rng.standard_normal(
+        (n_phys * page_size, hkv, hd)).astype(np.float32)
+    page_table = np.zeros((b, n_pages), np.int32)
+    for i, n in enumerate(lens):
+        live = -(-int(n) // page_size)
+        page_table[i, :live] = rng.permutation(n_phys)[:live]
+    return q, k_slab, v_slab, page_table, np.asarray(lens, np.int32)
+
+
 @pytest.mark.parametrize("n_pages, rows, want", [
     (256, 128, 8),    # Mistral-7B in the cell: 16-token pages x 8 KV heads
     (64, 128, 8),
@@ -1011,20 +1033,12 @@ def test_paged_decode_several_steps_of_several_pages(n_pages, window, hkv):
     steps, against the dense reference."""
     from bloombee_tpu.ops.pallas.paged_attention import _pages_per_step
 
-    rng = np.random.default_rng(11)
-    b, h, hd, page_size, n_phys = 3, 2 * hkv, 64, 16, 40
+    page_size = 16
     assert _pages_per_step(n_pages, page_size * hkv) == (
         4 if hkv == 10 or n_pages < 32 else 8)
-    q = rng.standard_normal((b, h, hd)).astype(np.float32)
-    k_slab = rng.standard_normal(
-        (n_phys * page_size, hkv, hd)).astype(np.float32)
-    v_slab = rng.standard_normal(
-        (n_phys * page_size, hkv, hd)).astype(np.float32)
-    lens = np.array([n_pages * page_size, 16 * 5 + 3, 1], np.int32)
-    page_table = np.zeros((b, n_pages), np.int32)
-    for i in range(b):
-        live = -(-int(lens[i]) // page_size)
-        page_table[i, :live] = rng.permutation(n_phys)[:live]
+    q, k_slab, v_slab, page_table, lens = _walk_case(
+        np.random.default_rng(11), hkv, n_pages,
+        [n_pages * page_size, 16 * 5 + 3, 1])
     got = np.asarray(
         paged_decode_attention(
             jnp.asarray(q), jnp.asarray(k_slab), jnp.asarray(v_slab),
@@ -1035,3 +1049,124 @@ def test_paged_decode_several_steps_of_several_pages(n_pages, window, hkv):
     want = dense_reference(
         q, k_slab, v_slab, page_table, lens, page_size, window=window)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------------- the bounded page walk
+@pytest.mark.parametrize("hkv, pages", [(8, 8), (10, 4), (4, 1)])
+@pytest.mark.parametrize("window", [
+    0,     # full attention: every row walks from its first group
+    200,   # starts inside a group and inside a page of the longest row
+    129,   # one key more than a group of 8 pages holds
+    1000,  # longer than every context
+])
+def test_paged_decode_walk_is_bounded_by_window_and_context(
+        hkv, pages, window):
+    """Rows of unlike spans in one call (the bucket's full length, a length
+    that ends inside a group and inside a page, length 1, a padding row of
+    length 0) at 128-, 160- and 64-row pages (8, 4 and 1 a grid step): the
+    grid walks the longest row's span from each row's own first live group,
+    a shorter row's trailing turns hold no live page, and the row of length
+    0 emits zeros. Against the dense reference."""
+    from bloombee_tpu.ops.pallas.paged_attention import (
+        _pages_per_step,
+        walk_bounds,
+    )
+
+    n_pages, page_size = 32, 16
+    assert _pages_per_step(n_pages, page_size * hkv) == pages
+    lens = [n_pages * page_size, 16 * 13 + 5, 1, 0]
+    q, k_slab, v_slab, page_table, lens = _walk_case(
+        np.random.default_rng(56), hkv, n_pages, lens)
+    lo, extent, live = walk_bounds(lens, window, page_size, pages, np)
+    groups = n_pages // pages
+    assert 1 <= extent <= groups and live <= len(lens) * extent
+    if 0 < window < 512 and pages > 1:
+        assert lo[0] > 0 and extent < groups  # turns below the window went
+    got = np.asarray(
+        paged_decode_attention(
+            jnp.asarray(q), jnp.asarray(k_slab), jnp.asarray(v_slab),
+            jnp.asarray(page_table), jnp.asarray(lens),
+            page_size=page_size, interpret=True, window=window,
+        )
+    )
+    want = dense_reference(
+        q[:3], k_slab, v_slab, page_table[:3], lens[:3], page_size,
+        window=window)
+    np.testing.assert_allclose(got[:3], want, rtol=2e-5, atol=2e-5)
+    np.testing.assert_array_equal(got[3], 0.0)
+
+
+def test_one_program_serves_a_traced_window_of_0_and_of_512():
+    """The window is a traced operand (a layer scan hands each layer its
+    own) and so is the grid's extent: ONE jitted function, traced once,
+    serves full attention and a 512-token window, each equal to the dense
+    reference."""
+    import jax
+
+    page_size, n_pages, hkv = 16, 64, 8
+    q, k_slab, v_slab, page_table, lens = _walk_case(
+        np.random.default_rng(57), hkv, n_pages, [1000, 700, 30], n_phys=64)
+    traces = []
+
+    def attend(q, k_slab, v_slab, page_table, lens, window):
+        traces.append(window)
+        return paged_decode_attention(
+            q, k_slab, v_slab, page_table, lens, page_size=page_size,
+            interpret=True, window=window)
+
+    attend = jax.jit(attend)
+    for window in (0, 512):
+        got = np.asarray(attend(
+            jnp.asarray(q), jnp.asarray(k_slab), jnp.asarray(v_slab),
+            jnp.asarray(page_table), jnp.asarray(lens), jnp.int32(window)))
+        want = dense_reference(
+            q, k_slab, v_slab, page_table, lens, page_size, window=window)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+    assert len(traces) == 1
+
+
+@pytest.mark.parametrize("rows, context, before, after", [
+    # phi4flash-longctx at its mean context: 8 calls under the 512-token
+    # window and 8 full or cross calls over the 1024-page bucket, 4 pages a
+    # turn: (turns, live turns) a decode step
+    (1, 10100, (4096, 1336), (1336, 1336)),
+    (1, 8200, (4096, 1104), (1104, 1104)),
+    (4, 12000, (16384, 6304), (6304, 6304)),
+])
+def test_the_turns_a_decode_step_walks(rows, context, before, after):
+    """`walk_bounds` is the one formula of the wrapper's grid and of the
+    executor's `kv_walk` counter: the bucket's walk held a live page in a
+    third of its turns at the cell's shape, the bounded walk in all of
+    them up to the groups' edges."""
+    from bloombee_tpu.ops.pallas.paged_attention import walk_bounds
+
+    lens = np.full((rows,), context, np.int32)
+    turns = live = 0
+    for window in (512, 0):
+        lo, extent, n = walk_bounds(lens, window, 16, 4, np)
+        jlo, jextent, jn = walk_bounds(
+            jnp.asarray(lens), jnp.int32(window), 16, 4)
+        assert (np.asarray(jlo) == lo).all()
+        assert (int(jextent), int(jn)) == (int(extent), int(n))
+        turns += 8 * rows * int(extent)
+        live += 8 * int(n)
+    assert (16 * rows * (1024 // 4), live) == before
+    assert (turns, live) == after
+
+
+@pytest.mark.parametrize("lens, window, pages, want", [
+    ([0, 0], 0, 4, ([0, 0], 1, 0)),         # padding rows alone: one turn
+    ([0, 0], 512, 4, ([0, 0], 1, 0)),
+    ([1], 0, 4, ([0], 1, 1)),
+    ([64], 0, 4, ([0], 1, 1)),              # ends on a group's edge
+    ([65], 0, 4, ([0], 2, 2)),
+    ([640, 65, 0], 100, 4, ([8, 0, 0], 2, 4)),  # 540 // 64; row 1 whole
+    ([640, 65, 0], 100, 1, ([33, 0, 0], 7, 12)),
+    ([300], 1000, 8, ([0], 3, 3)),          # a window longer than the context
+])
+def test_walk_bounds_by_rows(lens, window, pages, want):
+    from bloombee_tpu.ops.pallas.paged_attention import walk_bounds
+
+    lo, extent, live = walk_bounds(
+        np.asarray(lens, np.int32), window, 16, pages, np)
+    assert (list(lo), int(extent), int(live)) == want

@@ -439,7 +439,10 @@ def test_a_decode_step_streams_pages_by_the_rows_of_a_page(
     full layer and the cross layer (the context ends inside the second
     step); equal to the same step without kernels on the same caches, and
     `bbtpu.step` says `decode_pages` 4. A span of 4 pairs has 64-row pages:
-    one a step."""
+    one a step. The executor counts the turns the step's four calls walked
+    (`kv_walk`: two window layers, the full and the cross layer), every one
+    of them live: 1 + 1 + 2 + 2 groups of 4 pages where the bucket's walk
+    took 2 a call, 3 + 3 + 7 + 7 pages where it took 8."""
     config = {**CONFIG, "hidden_size": 32 * kv_pairs, "sliding_window": 24,
               "num_attention_heads": 2 * kv_pairs,
               "num_key_value_heads": 2 * kv_pairs}
@@ -474,6 +477,9 @@ def test_a_decode_step_streams_pages_by_the_rows_of_a_page(
     assert ex.attn_dispatches["paged"] == 1 and ex.kernel_fallbacks == 0
     decodes = [s for s in step_spans if s["kind"] == "decode"]
     assert [s.get("decode_pages") for s in decodes] == [None, pages]
+    turns = 6 if pages == 4 else 20
+    assert ex.kv_walk == {"turns": turns, "live_turns": turns}
+    assert plain.kv_walk == {"turns": 0, "live_turns": 0}
     assert all("decode_pages" not in s for s in step_spans
                if s["kind"] == "chunk")
 
