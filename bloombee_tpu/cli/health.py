@@ -96,6 +96,7 @@ def main(argv=None):
                 line += f"  cache_tokens_left={info.cache_tokens_left}"
             if getattr(info, "kv_repl", False):
                 line += "  kv_repl"
+            path_lines: list[str] = []
             if args.probe:
                 conn = None
                 try:
@@ -349,6 +350,38 @@ def main(argv=None):
                             f"{name}={v['total_ms'] / v['n']:.3f}msx{v['n']}"
                             for name, v in sorted(spans.items()) if v["n"]
                         )
+                    # the worker's busy time by kind of dispatch, a line a
+                    # kind: a task's wall, then each leg's (the self time
+                    # of its span; `unnamed` is what no span covers), each
+                    # with the share of it the thread was on the CPU in the
+                    # tasks read in full, then of THEIR launches those that found
+                    # the device idle and the jit call's mean on an idle and
+                    # on a busy device (cost alone, cost + back-pressure)
+                    for kind, rec in sorted(
+                        (probe.get("host_path") or {}).items()
+                    ):
+                        n, on_idle = rec["n"], rec["launches_on_idle"]
+                        busy = rec["launches"] - on_idle
+                        path_lines.append(
+                            f"    host_path.{kind} n={n} "
+                            f"wall={rec['wall_ms'] / n:.3f}ms "
+                            + ("(%.0f%%cpu of %d read in full)  " % (
+                                100 * rec["cpu_ms"] / rec["cpu_wall_ms"],
+                                rec["full_n"],
+                            ) if rec["cpu_wall_ms"] else " ") + " ".join(
+                                f"{leg.removeprefix('bbtpu.')}="
+                                f"{v['wall_ms'] / n:.3f}" + (
+                                    "(%.0f%%cpu)" % (
+                                        100 * v["cpu_ms"] / v["cpu_wall_ms"]
+                                    ) if v["cpu_wall_ms"] else ""
+                                ) for leg, v in rec["legs"].items()
+                            ) + f"  launches={rec['launches']} "
+                            f"on_idle={on_idle}"
+                            + (f" jit_idle={rec['jit_idle_ms'] / on_idle:.3f}ms"
+                               if on_idle else "")
+                            + (f" jit_busy={rec['jit_busy_ms'] / busy:.3f}ms"
+                               if busy else "")
+                        )
                     # a session's turn, reply to reply, by the step's
                     # class: the mean of each leg on the server's clock
                     # (wire/turn.py; negative_wire says a stamp is wrong)
@@ -515,7 +548,7 @@ def main(argv=None):
                 finally:
                     if conn is not None:
                         await conn.close()
-            print(line)
+            print("\n".join([line, *path_lines]))
         if missing:
             print(f"  MISSING blocks: {missing}")
             raise SystemExit(1)

@@ -765,7 +765,8 @@ class SpanExecutor:
         out, combined = self.ragged_group(
             handles, hiddens, layers=layers, adapter=adapter
         )
-        return out[:, None, :], combined
+        with jitwatch.span("bbtpu.slice"):
+            return out[:, None, :], combined
 
     def ragged_unsupported(self, has_tree: bool = False) -> str | None:
         """Why this executor can't run the universal ragged dispatch; None
@@ -894,7 +895,8 @@ class SpanExecutor:
                 combined, hidden, commit=False, layers=layers, fetch=False,
                 adapter=adapter,
             )
-            return out.reshape(out.shape[0], out.shape[2]), combined
+            with jitwatch.span("bbtpu.slice"):
+                return out.reshape(out.shape[0], out.shape[2]), combined
         reason = self.ragged_unsupported(has_tree=has_tree)
         if reason is not None:
             raise ValueError(f"ragged_group unsupported: {reason}")
@@ -1080,18 +1082,22 @@ class SpanExecutor:
         result, used_kernel = self._dispatch(
             _run, use_kernel, arena, "ragged group step"
         )
-        self.attn_dispatches["ragged" if used_kernel else "dense"] += 1
-        self.ragged_buckets_run.add(tag)
-        # where a pack's one chunk attends through the flash kernel, it is
-        # rb rows wide there (runtime/step.py `pack_chunk_on_flash`)
-        flash_now = used_kernel and pack_chunk_on_flash(spec)
-        self.kv_writes["chunk_row_writes"] += 1
-        out = self._keep_arena(
-            result, "fused", r, starts, self._count_moe(rb, used_kernel),
-            cross_rows=None if cross_idx is None else len(cross_idx),
-            flash=self._flash_form(rb, pb) if flash_now else None,
-            rule_rows=rb,
-        )
+        with jitwatch.span("bbtpu.counters"):
+            self.attn_dispatches["ragged" if used_kernel else "dense"] += 1
+            self.ragged_buckets_run.add(tag)
+            # where a pack's one chunk attends through the flash kernel, it
+            # is rb rows wide there (runtime/step.py `pack_chunk_on_flash`)
+            flash_now = used_kernel and pack_chunk_on_flash(spec)
+            self.kv_writes["chunk_row_writes"] += 1
+            out = self._keep_arena(
+                result, "fused", r, starts, self._count_moe(rb, used_kernel),
+                cross_rows=None if cross_idx is None else len(cross_idx),
+                flash=self._flash_form(rb, pb) if flash_now else None,
+                rule_rows=rb,
+            )
+        # (as `_step`'s: the step's device buffers die under a name)
+        with jitwatch.span("bbtpu.release"):
+            del payload_dev, result, arena, _run
         with jitwatch.span("bbtpu.slice"):
             return out[0, :r], combined
 
@@ -1277,10 +1283,9 @@ class SpanExecutor:
         with jitwatch.span("bbtpu.slice"):
             return toks[:b, :n]
 
-    def _place_step_inputs(self, h_pad, plan, tm_pad):
-        """Pack and commit one step's (payload, tree mask) to the device —
+    def _place_step_inputs(self, payload, tm_pad):
+        """Commit one step's packed (payload, tree mask) to the device —
         replicated over the tp mesh when serving sharded."""
-        payload = pack_step_payload(h_pad, plan)
         with jitwatch.span("bbtpu.h2d"):
             if self.mesh is not None:
                 from bloombee_tpu.parallel import serving as tp_serving
@@ -1454,6 +1459,17 @@ class SpanExecutor:
         )
 
     @staticmethod
+    def _arena_ready(arena) -> bool | None:
+        """Whether the device has finished everything it was given: the
+        arena a dispatch donates is the last program's output, so its being
+        ready (asked without blocking, no reference kept) says so. A K/V
+        arena of no rows says nothing: the first slab that holds bytes is
+        asked, so a span of mixers alone asks its state arena. Asked by the
+        witness only (`jitwatch.launch`)."""
+        last = next((a for a in jax.tree.leaves(arena) if a.size), None)
+        return None if last is None else last.is_ready()
+
+    @staticmethod
     def _arena_consumed(arena) -> bool:
         return any(
             getattr(a, "is_deleted", lambda: False)()
@@ -1496,6 +1512,7 @@ class SpanExecutor:
         keeps the server answering, but it is a kernel bug, so it is
         logged, counted in kernel_fallbacks, and the kernel path stays
         off for the life of the process."""
+        jitwatch.launch(self._arena_ready, arena)
         try:
             return run(use_kernel), use_kernel
         except Exception:
@@ -1809,6 +1826,11 @@ class SpanExecutor:
                     ),
                 )
 
+            if not self.host_layers:
+                # one host buffer for the one h2d: a copy of the step's rows
+                # (a chunk's 5-8 MB), so it is packing and not the transfer
+                payload = pack_step_payload(h_pad, plan)
+
         arena = self._arena()
         if self.host_layers:
             def _run_off(use_paged_now: bool):
@@ -1827,7 +1849,7 @@ class SpanExecutor:
         elif self.spec.heterogeneous:
             from bloombee_tpu.runtime.hetero import span_step_hetero
 
-            payload_dev, tm_dev = self._place_step_inputs(h_pad, plan, tm_pad)
+            payload_dev, tm_dev = self._place_step_inputs(payload, tm_pad)
 
             def _run_hetero(_use_kernel: bool):
                 with jitwatch.region(
@@ -1857,7 +1879,7 @@ class SpanExecutor:
             self.manager.arena = {"k": new_k, "v": new_v}
             self._count_moe(bb * tb, False)
         else:
-            payload_dev, tm_dev = self._place_step_inputs(h_pad, plan, tm_pad)
+            payload_dev, tm_dev = self._place_step_inputs(payload, tm_pad)
 
             # a chunk that attends through flash runs no paged kernel, yet
             # Pallas kernels run in its program: its experts may take a
@@ -1898,37 +1920,48 @@ class SpanExecutor:
                 _run, use_paged or flash_experts, arena, "span step"
             )
             use_paged = use_paged and kernels_used
-            # a chunk attends through the flash kernel: the plain path's
-            # `use_flash`, or a SambaY span's own step with kernels on,
-            # which takes every sequence with more than one row as a chunk
-            # (runtime/sambay.py `_diff_attend`)
-            flash_now = use_flash or (
-                self.spec.mamba is not None and use_paged and tb > 1
-            )
-            # decode rows stream their pages through `paged_decode_attention`
-            # (the latent and the int4 kernels keep grids of their own)
-            decode_kernel = (
-                t == 1 and use_paged and spec.mla is None
-                and self.manager.quant is None
-            )
-            decode_pages = _pages_per_step(
-                pb, self.page_size * spec.num_key_value_heads
-            ) if decode_kernel else None
-            out = self._keep_arena(
-                result, "decode" if t == 1 else "chunk", b * t, starts,
-                self._count_moe(bb * tb, kernels_used),
-                cross_rows=cross_rows,
-                flash=self._flash_form(tb, pb) if flash_now else None,
-                write="pages" if page_groups else "rows",
-                rule_rows=tb if tb > 1 else 0,
-                decode_pages=decode_pages,
-            )
-            if decode_kernel:
-                for window, layers in self._attn_windows.items():
-                    _, extent, live = walk_bounds(
-                        lens_pad, window, self.page_size, decode_pages, np)
-                    self.kv_walk["turns"] += layers * bb * int(extent)
-                    self.kv_walk["live_turns"] += layers * int(live)
+            # what the step was, for the counters and the `bbtpu.step`
+            # stamp: paid on the compute thread after every launch
+            with jitwatch.span("bbtpu.counters"):
+                # a chunk attends through the flash kernel: the plain
+                # path's `use_flash`, or a SambaY span's own step with
+                # kernels on, which takes every sequence with more than one
+                # row as a chunk (runtime/sambay.py `_diff_attend`)
+                flash_now = use_flash or (
+                    self.spec.mamba is not None and use_paged and tb > 1
+                )
+                # decode rows stream their pages through
+                # `paged_decode_attention` (the latent and the int4 kernels
+                # keep grids of their own)
+                decode_kernel = (
+                    t == 1 and use_paged and spec.mla is None
+                    and self.manager.quant is None
+                )
+                decode_pages = _pages_per_step(
+                    pb, self.page_size * spec.num_key_value_heads
+                ) if decode_kernel else None
+                out = self._keep_arena(
+                    result, "decode" if t == 1 else "chunk", b * t, starts,
+                    self._count_moe(bb * tb, kernels_used),
+                    cross_rows=cross_rows,
+                    flash=self._flash_form(tb, pb) if flash_now else None,
+                    write="pages" if page_groups else "rows",
+                    rule_rows=tb if tb > 1 else 0,
+                    decode_pages=decode_pages,
+                )
+                if decode_kernel:
+                    for window, layers in self._attn_windows.items():
+                        _, extent, live = walk_bounds(
+                            lens_pad, window, self.page_size, decode_pages,
+                            np)
+                        self.kv_walk["turns"] += layers * bb * int(extent)
+                        self.kv_walk["live_turns"] += layers * int(live)
+            # the step's buffers on the device die HERE, under a name, and
+            # not at the function's return, where no span would own their
+            # destructors: the donated arena's old arrays, the payload, the
+            # program's result tuple (the output and the new arenas live on)
+            with jitwatch.span("bbtpu.release"):
+                del payload_dev, tm_dev, result, arena, _run
         if t > 1:
             self.kv_writes[
                 "chunk_page_writes" if page_groups else "chunk_row_writes"

@@ -2193,6 +2193,10 @@ class BlockServer(PromotionLoopMixin):
             # compute worker's wall time by cause
             "host_spans": jitwatch.host_spans(),
             "worker": self.compute.worker_stats_ms(),
+            # the worker's busy time by kind of dispatch (decode | chunk |
+            # fused | other) and by leg, wall and thread CPU, and what each
+            # launch found the device doing (compute_queue._WorkerAccount)
+            "host_path": self.compute.host_path(),
             # a session's turn, reply to reply, leg by leg on this server's
             # clock (sums by the step's class; kept with the witness off)
             "turn": self.turn_account.stats_ms(),
@@ -2237,6 +2241,7 @@ class BlockServer(PromotionLoopMixin):
         # (once more where a reader that keeps `memory` alone finds it)
         info["memory"]["kv_writes"] = dict(self.executor.kv_writes)
         info["memory"]["kv_walk"] = dict(self.executor.kv_walk)
+        info["memory"]["host_path"] = info["host_path"]
         if self.spec.mamba is not None or any(self.executor.windows):
             # the window-dead accounting and the SambaY counters once more,
             # beside the K/V arena they are about (a reader that keeps a
@@ -4604,25 +4609,29 @@ class BlockServer(PromotionLoopMixin):
         one member's fault never sinks its co-batched peers."""
         results: list = [None] * len(members)
         ready: list[int] = []
-        for i, m in enumerate(members):
-            if not self.manager.epoch_valid(m.handle):
-                results[i] = SessionKVLost(
-                    "server KV arena was rebuilt; session cache lost — "
-                    "replay"
-                )
-            elif (self.manager.has_parked(m.handle)
-                  or (not m.session.adoption_settled
-                      and self.manager.has_adopted(m.handle))):
-                # unparking inside a merged dispatch could OutOfPages the
-                # whole batch; alone, only this member wears the failure.
-                # An UNSETTLED prefix adoption likewise needs the solo
-                # path (_compute_step trims it to the declared skip before
-                # computing) — but only until its first step settles it:
-                # a settled adopted session batches like any other instead
-                # of soloing for the rest of its life
-                results[i] = self._solo_member_step(m)
-            else:
-                ready.append(i)
+        # the members' hygiene (a solo step inside it is a `bbtpu.dispatch`
+        # of its own: the span's self time is the checks alone)
+        with jitwatch.span("bbtpu.group", members=len(members)):
+            for i, m in enumerate(members):
+                if not self.manager.epoch_valid(m.handle):
+                    results[i] = SessionKVLost(
+                        "server KV arena was rebuilt; session cache lost — "
+                        "replay"
+                    )
+                elif (self.manager.has_parked(m.handle)
+                      or (not m.session.adoption_settled
+                          and self.manager.has_adopted(m.handle))):
+                    # unparking inside a merged dispatch could OutOfPages
+                    # the whole batch; alone, only this member wears the
+                    # failure. An UNSETTLED prefix adoption likewise needs
+                    # the solo path (_compute_step trims it to the declared
+                    # skip before computing) — but only until its first
+                    # step settles it: a settled adopted session batches
+                    # like any other instead of soloing for the rest of its
+                    # life
+                    results[i] = self._solo_member_step(m)
+                else:
+                    ready.append(i)
         if len(ready) == 1:
             results[ready[0]] = self._solo_member_step(members[ready[0]])
         elif ready:
@@ -4806,32 +4815,36 @@ class BlockServer(PromotionLoopMixin):
         decode_idx: list[int] = []
         tree_idx: list[int] = []
         chunk_idx: list[int] = []
-        for i, m in enumerate(members):
-            if not self.manager.epoch_valid(m.handle):
-                results[i] = SessionKVLost(
-                    "server KV arena was rebuilt; session cache lost — "
-                    "replay"
-                )
-            elif isinstance(m, _ChunkMember):
-                if (self.manager.has_parked(m.handle)
-                        or (m.first and self.manager.has_adopted(m.handle))):
-                    # unpark / adoption settle mutate the table mid-group;
-                    # the solo chunk path owns those side effects
-                    results[i] = self._solo_chunk_step(m)
+        # the members' hygiene, as _compute_step_group's
+        with jitwatch.span("bbtpu.group", members=len(members)):
+            for i, m in enumerate(members):
+                if not self.manager.epoch_valid(m.handle):
+                    results[i] = SessionKVLost(
+                        "server KV arena was rebuilt; session cache lost — "
+                        "replay"
+                    )
+                elif isinstance(m, _ChunkMember):
+                    if (self.manager.has_parked(m.handle)
+                            or (m.first
+                                and self.manager.has_adopted(m.handle))):
+                        # unpark / adoption settle mutate the table
+                        # mid-group; the solo chunk path owns those side
+                        # effects
+                        results[i] = self._solo_chunk_step(m)
+                    else:
+                        chunk_idx.append(i)
+                elif (self.manager.has_parked(m.handle)
+                      or (not m.session.adoption_settled
+                          and self.manager.has_adopted(m.handle))):
+                    # same solo carve-outs as _compute_step_group
+                    results[i] = (
+                        self._solo_tree_step(m) if isinstance(m, _TreeMember)
+                        else self._solo_member_step(m)
+                    )
+                elif isinstance(m, _TreeMember):
+                    tree_idx.append(i)
                 else:
-                    chunk_idx.append(i)
-            elif (self.manager.has_parked(m.handle)
-                  or (not m.session.adoption_settled
-                      and self.manager.has_adopted(m.handle))):
-                # same solo carve-outs as _compute_step_group
-                results[i] = (
-                    self._solo_tree_step(m) if isinstance(m, _TreeMember)
-                    else self._solo_member_step(m)
-                )
-            elif isinstance(m, _TreeMember):
-                tree_idx.append(i)
-            else:
-                decode_idx.append(i)
+                    decode_idx.append(i)
 
         def decode_members() -> None:
             # exact _compute_step_group semantics
@@ -4865,12 +4878,13 @@ class BlockServer(PromotionLoopMixin):
         # LAST (its multi-token row-group caps the ragged packing)
         order = decode_idx + tree_idx + chunk_idx
         group = [members[i] for i in order]
-        if (
-            chunk_idx and not tree_idx and self._warm_fenced
-            and self.executor.ragged_bucket(
-                [m.handle for m in group], [m.hidden for m in group]
-            ) not in self.executor.ragged_buckets_run
-        ):
+        cold = False
+        if chunk_idx and not tree_idx and self._warm_fenced:
+            with jitwatch.span("bbtpu.group", members=len(group)):
+                cold = self.executor.ragged_bucket(
+                    [m.handle for m in group], [m.hidden for m in group]
+                ) not in self.executor.ragged_buckets_run
+        if cold:
             # FUSE ONLY INTO A PROGRAM THAT EXISTS: which packs form
             # depends on arrival times, so no warm-up list meets every
             # bucket, and a fused program compiled now holds the compute

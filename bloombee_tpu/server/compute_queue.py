@@ -23,6 +23,7 @@ import collections
 import dataclasses
 import functools
 import itertools
+import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Hashable
@@ -108,6 +109,28 @@ class _GroupTask:
     enqueued_ns: int = 0
 
 
+def task_kind(ids: dict) -> str:
+    """The kind of dispatch a task is, from the ids its `bbtpu.task` span
+    carries: `decode` (a lone task of class "decode", or a group of
+    "decode1" members), `chunk` (class "prefill", or a "chunkm" member
+    alone), `fused` (a group holding both), `other` (tree rows, decode_n,
+    warm-up, training: anything else)."""
+    kinds = ids.get("kinds")
+    if kinds is None:
+        return _CLASS_KIND.get(ids.get("class"), "other")
+    kind = _GROUP_KIND.get(kinds)
+    if kind is None:
+        kind = "fused" if {"decode1", "chunkm"} <= set(
+            kinds.split("+")
+        ) else "other"
+    return kind
+
+
+_CLASS_KIND = {"decode": "decode", "prefill": "chunk"}
+_GROUP_KIND = {"decode1": "decode", "chunkm": "chunk",
+               "chunkm+decode1": "fused"}
+
+
 class _WorkerAccount:
     """Where the serial worker's wall time went, on the real
     perf-counter clock, per task that reached the compute thread:
@@ -116,9 +139,16 @@ class _WorkerAccount:
     enqueue) to the task's start on the compute thread (work existed,
     nobody ran it: event-loop turn, gather window, run_in_executor),
     `busy` the task itself. The three add up to the time from the
-    account's creation to the last task's end. Rides BBTPU_JITWATCH like
-    the spans: with it off `wrap` returns the function itself and every
-    counter stays 0."""
+    account's creation to the last task's end.
+
+    `busy` is also kept by kind of dispatch (`task_kind`) and by leg: the
+    task's ledger (utils/jitwatch.py `_TaskSpan`: the self time of every
+    span under it, and in the one task of 32 that is read in full the
+    thread's CPU beside it and what its launches found the device doing)
+    is folded in as the task closes, from the SAME clock pair
+    as `busy`, so the kinds' wall is `busy` and a kind's legs its wall.
+    Rides BBTPU_JITWATCH like the spans: with it off `wrap` returns the
+    function itself and every counter stays 0 / empty."""
 
     def __init__(self) -> None:
         self.tasks = 0
@@ -126,6 +156,12 @@ class _WorkerAccount:
         self._hop_ns = 0
         self._busy_ns = 0
         self._prev_end_ns = time.perf_counter_ns()
+        # kind -> {"n", "wall", and of the tasks read in full: "full_n",
+        # "cpu", "cpu_wall", "launches": [4]; "spans": {name: [n, wall, self
+        # wall, and of the tasks read in full: self CPU, self wall]}},
+        # written on the compute thread, read on rpc_info's
+        self._kinds: dict[str, dict] = {}
+        self._mu = threading.Lock()
 
     def wrap(self, fn: Callable[[], Any], enqueued_ns: int, **ids):
         if not jitwatch.enabled():
@@ -135,24 +171,55 @@ class _WorkerAccount:
             start = time.perf_counter_ns()
             ready = max(self._prev_end_ns, enqueued_ns)
             starved, hop = ready - self._prev_end_ns, max(0, start - ready)
-            try:
-                # hot_wrap: while this runs on the compute thread any host
-                # sync counts against jitwatch's hot-path budget (the queue
-                # serializes device work, so a sync here convoys every
-                # session), and the call is the span `bbtpu.task`
-                return jitwatch.hot_wrap(
-                    fn, starved_us=starved // 1000, hop_us=hop // 1000,
-                    **ids,
-                )()
-            finally:
-                end = time.perf_counter_ns()
-                self.tasks += 1
-                self._starved_ns += starved
-                self._hop_ns += hop
-                self._busy_ns += end - start
-                self._prev_end_ns = end
+            self._starved_ns += starved
+            self._hop_ns += hop
+            # hot_wrap: while this runs on the compute thread any host
+            # sync counts against jitwatch's hot-path budget (the queue
+            # serializes device work, so a sync here convoys every
+            # session), and the call is the span `bbtpu.task`, timed from
+            # `start` and folded in below as it closes
+            return jitwatch.hot_wrap(
+                fn, self._fold, start,
+                starved_us=starved // 1000, hop_us=hop // 1000, **ids,
+            )()
 
         return _accounted
+
+    def _fold(self, task) -> None:
+        kind = task_kind(task.ids)
+        # one task of 32 is read in full (`jitwatch.read_in_full`): CPU
+        # beside wall, the device asked at its launches. Its wall is kept a
+        # second time, to hold its CPU against
+        full = task.full
+        with self._mu:
+            self.tasks += 1
+            self._busy_ns += task.ns
+            self._prev_end_ns = task._t0 + task.ns
+            rec = self._kinds.get(kind)
+            if rec is None:
+                rec = self._kinds[kind] = {
+                    "n": 0, "wall": 0, "full_n": 0, "cpu": 0, "cpu_wall": 0,
+                    "launches": [0, 0, 0, 0], "spans": {},
+                }
+            rec["n"] += 1
+            rec["wall"] += task.ns
+            if full:
+                rec["full_n"] += 1
+                rec["cpu"] += task.cpu_ns
+                rec["cpu_wall"] += task.ns
+                for i, v in enumerate(task.launches):
+                    rec["launches"][i] += v
+            spans = rec["spans"]  # by span name; by leg where it is read
+            for name, got in task.spans.items():
+                into = spans.get(name)
+                if into is None:
+                    into = spans[name] = [0, 0, 0, 0, 0]
+                into[0] += got[0]
+                into[1] += got[1]
+                into[2] += got[2]
+                if full:
+                    into[3] += got[3]
+                    into[4] += got[2]
 
     def stats_ms(self) -> dict:
         return {
@@ -161,6 +228,38 @@ class _WorkerAccount:
             "hop_ms": round(self._hop_ns / 1e6, 3),
             "busy_ms": round(self._busy_ns / 1e6, 3),
         }
+
+    def host_path(self) -> dict:
+        """{kind: {n, wall_ms, full_n, cpu_ms, cpu_wall_ms, launches,
+        launches_on_idle, jit_idle_ms, jit_busy_ms, legs: {leg: {wall_ms,
+        cpu_ms, cpu_wall_ms}}}} since the account's creation; empty with
+        the witness off. `n` and every `wall_ms` count every task; `cpu_ms`
+        and the launches count the `full_n` tasks read in full, and
+        `cpu_wall_ms` is the wall of THOSE tasks, to hold their CPU
+        against."""
+        def ms(ns: int) -> float:
+            return round(ns / 1e6, 6)
+
+        with self._mu:
+            return {
+                kind: {
+                    "n": rec["n"], "wall_ms": ms(rec["wall"]),
+                    "full_n": rec["full_n"], "cpu_ms": ms(rec["cpu"]),
+                    "cpu_wall_ms": ms(rec["cpu_wall"]),
+                    "launches": rec["launches"][0],
+                    "launches_on_idle": rec["launches"][1],
+                    "jit_idle_ms": ms(rec["launches"][2]),
+                    "jit_busy_ms": ms(rec["launches"][3]),
+                    "legs": {
+                        leg: {"wall_ms": ms(w), "cpu_ms": ms(c),
+                              "cpu_wall_ms": ms(cw)}
+                        for leg, (w, c, cw) in sorted(
+                            jitwatch.legs_of(rec["spans"]).items()
+                        )
+                    },
+                }
+                for kind, rec in sorted(self._kinds.items())
+            }
 
 
 def _kind(key: Hashable) -> str:
@@ -223,6 +322,12 @@ class ComputeQueue:
         """{"tasks", "starved_ms", "hop_ms", "busy_ms"}: the worker's wall
         time by cause (_WorkerAccount), `rpc_info["worker"]`."""
         return self._account.stats_ms()
+
+    def host_path(self) -> dict:
+        """The same busy time by kind of dispatch and by leg, wall and
+        thread CPU, with what each launch found the device doing
+        (_WorkerAccount.host_path), `rpc_info["host_path"]`."""
+        return self._account.host_path()
 
     def _enqueue(self, priority: float, task) -> None:
         task.seq = seq = next(self._seq)

@@ -41,6 +41,22 @@ parts of the wire's ``t_compute_ms``, the client's ``c_head`` /
 ``c_embed``): it reads the clock with the witness off too, and is named
 and counted only with it on.
 
+Spans that close while a ``bbtpu.task`` is open on their thread (the
+compute thread: :func:`hot_wrap`) also feed that task's **ledger**: each
+adds its SELF time (its duration less what its direct children cover) under
+its name (read by leg: every ``bbtpu.jit.*`` is ``jit_call``, the self
+time of ``bbtpu.task`` and ``bbtpu.dispatch`` is ``unnamed``), in wall
+and in thread-CPU nanoseconds (``time.thread_time_ns`` is read beside
+``perf_counter_ns`` on such a thread only, and there in the one task of 32
+that is read in full, :func:`read_in_full`: ``wall - cpu`` is the time the
+thread did not run). A leg's numbers come from the ONE clock pair
+its span reads, so a task's legs sum to its wall to the nanosecond.
+:func:`launch` says, just before a dispatch of such a task, whether the
+device had finished what it was given: the next region carries it (``device`` =
+``idle`` | ``busy``) and the task counts it. When the task closes its
+ledger goes to the `sink` its wrapper gave (server/compute_queue.py
+``_WorkerAccount``: ``rpc_info["host_path"]``).
+
 At interpreter exit the witness appends one JSON line to
 ``BBTPU_JITWATCH_REPORT`` (append mode, multi-process merge — same
 contract as lockwatch/ledger). ``python -m bloombee_tpu.utils.jitwatch
@@ -204,6 +220,17 @@ class _Witness:
                 rec[0] += 1
                 rec[1] += ns
 
+    def record_spans(self, spans: dict) -> None:
+        """A closed task's {name: [n, total_ns, ...]}, under one lock."""
+        with self._mu:
+            for name, rec in spans.items():
+                into = self.spans.get(name)
+                if into is None:
+                    self.spans[name] = [rec[0], rec[1]]
+                else:
+                    into[0] += rec[0]
+                    into[1] += rec[1]
+
     def _spans_ms(self) -> dict:  # caller holds self._mu
         return {
             name: {"n": n, "total_ms": round(ns / 1e6, 3)}
@@ -274,6 +301,8 @@ class _Witness:
         self._regions().clear()
         self._tls.hot = 0
         self._tls.cache_hit = False
+        self._tls.task = None
+        self._tls.launch = None
 
 
 _witness = _Witness()
@@ -327,12 +356,9 @@ def install() -> bool:
 _trace_annotation = None  # jax.profiler.TraceAnnotation, imported lazily
 
 
-def _annotation(name: str, ids: dict):
-    """A ``jax.profiler.TraceAnnotation`` for one span, or None where JAX
-    is absent. Outside a profiler session entering it costs a flag read;
-    inside one the span lands in the profiler's own trace. The profiler
-    encodes ids as ``name#k=v,k=v#``, so a comma inside a value (a bucket
-    tag ``b2,t1,p64``) would cut it short: commas travel as ``;``."""
+def _annotation_class():
+    """``jax.profiler.TraceAnnotation``, imported on first use; False where
+    JAX is absent (analysis / CLI contexts)."""
     global _trace_annotation
     if _trace_annotation is None:
         try:
@@ -340,12 +366,30 @@ def _annotation(name: str, ids: dict):
         except Exception:  # jax-free analysis/CLI contexts
             TraceAnnotation = False
         _trace_annotation = TraceAnnotation
-    if not _trace_annotation:
+    return _trace_annotation
+
+
+def _annotation(name: str, ids: dict):
+    """A ``jax.profiler.TraceAnnotation`` for one span, or None where JAX
+    is absent. Inside a profiler session the span lands in the profiler's
+    own trace. The profiler encodes ids as ``name#k=v,k=v#``, so a comma
+    inside a value (a bucket tag ``b2,t1,p64``) would cut it short: commas
+    travel as ``;``."""
+    cls = _annotation_class()
+    if not cls:
         return None
-    return _trace_annotation(name, **{
+    return cls(name, **{
         k: v.replace(",", ";") if isinstance(v, str) else v
         for k, v in ids.items()
     })
+
+
+def _recording() -> bool:
+    """Whether a profiler session is recording on this host right now: an
+    annotation entered outside one is dropped by the profiler, so a span
+    makes none (most of a span's cost in a server nobody is tracing)."""
+    cls = _annotation_class()
+    return bool(cls) and cls.is_enabled()
 
 
 class _NoSpan:
@@ -369,10 +413,13 @@ _NOOP = _NoSpan()
 class _Span:
     """One named host interval on this thread's stack. `function` is set
     for a region only: compiles fired while it is the innermost region
-    are pinned to (function, shape)."""
+    are pinned to (function, shape). `_task` is the `bbtpu.task` open on
+    the thread when the span opened (None on every other thread): only
+    then is the thread's CPU clock read, and the span's self time added to
+    the task's ledger when it closes."""
 
-    __slots__ = ("name", "ids", "function", "shape", "ns", "_on", "_t0",
-                 "_ann")
+    __slots__ = ("name", "ids", "function", "shape", "ns", "cpu_ns", "_on",
+                 "_t0", "_c0", "_ann", "_task", "_kids_ns", "_kids_cpu")
 
     def __init__(self, name: str, ids: dict, on: bool,
                  function: str | None = None, shape: str = ""):
@@ -381,42 +428,170 @@ class _Span:
         self.function = function
         self.shape = shape
         self.ns = 0
+        self.cpu_ns = 0
         self._on = on
         self._ann = None
+        self._task = None
+        self._kids_ns = 0  # what the direct children cover: wall, CPU
+        self._kids_cpu = 0
 
     def __enter__(self):
-        if self._on:
-            st = _witness._regions()
-            if st and "task" in st[-1].ids and "task" not in self.ids:
-                # everything a queue task runs repeats its number
-                self.ids = dict(self.ids, task=st[-1].ids["task"])
-            st.append(self)
+        if not self._on:
+            self._t0 = time.perf_counter_ns()
+            return self
+        # the clocks first and (in __exit__) last: the span's own
+        # bookkeeping is inside its pair, not in its parent's self time
+        task = self._task = getattr(_witness._tls, "task", None)
+        self._t0 = time.perf_counter_ns()
+        if task is not None and task.full:
+            # inside the wall pair, so a span's CPU never passes its wall
+            self._c0 = time.thread_time_ns()
+        st = _witness._regions()
+        if st and "task" in st[-1].ids and "task" not in self.ids:
+            # everything a queue task runs repeats its number
+            self.ids = dict(self.ids, task=st[-1].ids["task"])
+        st.append(self)
+        if _recording():
             self._ann = _annotation(self.name, self.ids)
             if self._ann is not None:
                 self._ann.__enter__()
-        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> None:
-        self.ns = time.perf_counter_ns() - self._t0
-        if self._on:
-            if self._ann is not None:
-                self._ann.__exit__(*exc)
-            st = _witness._regions()
-            if st:
-                st.pop()
-            _witness.record_span(self.name, self.ns)
+        if not self._on:
+            self.ns = time.perf_counter_ns() - self._t0
+            return
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        st = _witness._regions()
+        if st:
+            st.pop()
+        task = self._task
+        if task is None:
+            ns = self.ns = time.perf_counter_ns() - self._t0
+            _witness.record_span(self.name, ns)
+            return
+        if task.full:
+            cpu = self.cpu_ns = time.thread_time_ns() - self._c0
+        else:
+            cpu = 0
+        ns = self.ns = time.perf_counter_ns() - self._t0
+        # under a task: [n, wall, self wall, self CPU] by name in its
+        # ledger, which reaches the witness's sums when the task closes
+        if st and self is not task:
+            st[-1]._kids_ns += ns
+            st[-1]._kids_cpu += cpu
+        own = ns - self._kids_ns
+        rec = task.spans.get(self.name)
+        if rec is None:
+            task.spans[self.name] = [1, ns, own, cpu - self._kids_cpu]
+        else:
+            rec[0] += 1
+            rec[1] += ns
+            rec[2] += own
+            rec[3] += cpu - self._kids_cpu
+        device = self.function and self.ids.get("device")
+        if device:  # a region whose launch said what the device was doing
+            idle = device == "idle"
+            task.launches[0] += 1
+            task.launches[1] += idle
+            task.launches[2 if idle else 3] += own
 
     @property
     def ms(self) -> float:
         return self.ns / 1e6
 
 
+def read_in_full(number: int) -> bool:
+    """Whether the task of this number is one of the (one in 32) tasks read
+    in FULL: its spans read the thread's CPU clock and its launches ask the
+    device. Both cost a system call's time on the machine with the chip
+    (`time.thread_time_ns` 5 us alone and 12 in a task, against 0.09 for the
+    wall clock; `is_ready()` of a TPU array 70), a dozen spans a task would
+    pay the first twice each, and the compute thread is what the account is
+    there to watch. A multiplicative hash of the number, so that no period
+    of the traffic (every fourth task a chunk) meets the same tasks."""
+    return (number * 0x9E3779B1 >> 15) & 31 == 0
+
+
+class _TaskSpan(_Span):
+    """The `bbtpu.task` span of one compute-queue task, and the task's
+    ledger: `spans` {name: [n, wall ns, self wall ns, self CPU ns]} of every
+    span that closed under it (itself included), `launches` [dispatches
+    that said what the device was doing, those that found it idle, the
+    self wall ns of the idle ones' regions, of the busy ones']. `t0` is a
+    start its caller read already (the worker's account: ONE clock pair a
+    task). `full`: this task is read in full (:func:`read_in_full`, by its
+    number): CPU beside wall in every span, the device asked at every
+    launch; every other task keeps wall times alone. As the task closes, on
+    its thread, the spans' (n, wall) join the witness's sums and
+    `sink(task)` is called."""
+
+    __slots__ = ("spans", "launches", "full", "_sink", "_outer", "_start")
+
+    def __init__(self, ids: dict, sink=None, t0: int | None = None):
+        super().__init__("bbtpu.task", ids, True)
+        self.spans: dict[str, list[int]] = {}
+        self.launches = [0, 0, 0, 0]
+        self.full = read_in_full(ids.get("task", 0))
+        self._sink = sink
+        self._start = t0
+
+    def __enter__(self):
+        tls = _witness._tls
+        # a task inside a task is a plain span of the outer one's ledger
+        self._outer = getattr(tls, "task", None)
+        if self._outer is None:
+            tls.task = self
+        super().__enter__()
+        if self._start is not None:
+            self._t0 = self._start
+        return self
+
+    def __exit__(self, *exc) -> None:
+        super().__exit__(*exc)
+        if self._outer is None:
+            _witness._tls.task = None
+            _witness.record_spans(self.spans)
+            if self._sink is not None:
+                self._sink(self)
+
+
+def legs_of(spans: dict) -> dict[str, list[int]]:
+    """A ledger's {name: [n, wall, self wall, self CPU, ...]} by LEG, {leg:
+    [self wall ns, self CPU ns, ...]} (the fields from the third on, summed):
+    every `bbtpu.jit.*` under `jit_call`, the self time of `bbtpu.task` and
+    `bbtpu.dispatch` under `unnamed`, every other span under its name. A
+    task's legs sum to its `ns` (and, where its spans read the CPU clock, to
+    its `cpu_ns`)."""
+    legs: dict[str, list[int]] = {}
+    for name, rec in spans.items():
+        leg = ("jit_call" if name.startswith("bbtpu.jit.")
+               else "unnamed" if name in _UNNAMED else name)
+        into = legs.get(leg)
+        if into is None:
+            legs[leg] = list(rec[2:])
+        else:
+            for i, v in enumerate(rec[2:]):
+                into[i] += v
+    return legs
+
+
+_UNNAMED = ("bbtpu.task", "bbtpu.dispatch")
+
+
+def _on() -> bool:
+    """:func:`enabled`, without the read of the environment where a task is
+    open on this thread: it opened with the witness on, and what runs under
+    it is on to its end (a dozen spans a task pay no switch)."""
+    return getattr(_witness._tls, "task", None) is not None or enabled()
+
+
 def span(name: str, **ids):
     """Name one synchronous host interval: ``with jitwatch.span(
     "bbtpu.pack", session=sid, step=n): ...``. Witness off: the shared
     no-op frame."""
-    if not enabled():
+    if not _on():
         return _NOOP
     return _Span(name, ids, True)
 
@@ -425,32 +600,54 @@ def stopwatch(name: str, **ids) -> _Span:
     """A span whose duration the caller reads (``.ms`` / ``.ns`` after the
     block): ONE clock pair feeds both the caller's number and, with the
     witness on, the span's. Witness off: the clock pair alone."""
-    return _Span(name, ids, enabled())
+    return _Span(name, ids, _on())
 
 
 def region(function: str, shape: str):
     """Wrap one jit dispatch: ``with jitwatch.region("span_step",
     "b2,t1,p64"): ...``. A span named ``bbtpu.jit.<function>`` that also
-    owns the compiles fired inside it. Cheap no-op frame when the
-    witness is off."""
-    if not enabled():
+    owns the compiles fired inside it, and carries what :func:`launch` said
+    of the device just before it (``device`` = ``idle`` | ``busy``). Cheap
+    no-op frame when the witness is off."""
+    if not _on():
         return _NOOP
-    return _Span("bbtpu.jit." + function, {"bucket": shape}, True,
-                 function, shape)
+    ids = {"bucket": shape}
+    device = getattr(_witness._tls, "launch", None)
+    if device is not None:
+        _witness._tls.launch = None
+        ids["device"] = device
+    return _Span("bbtpu.jit." + function, ids, True, function, shape)
 
 
-def hot_wrap(fn, **ids):
+def launch(device_idle, *args) -> None:
+    """Say, just before a dispatch, whether the device had finished
+    everything it was given: `device_idle(*args)` is asked in a task read
+    in full only (True | False | None for "cannot say"; never with the
+    witness off), the next :func:`region` on this thread carries the bit,
+    and the task counts the launch and the call's wall under it. A launch
+    on an idle device is a bubble the host made; the call's wall on a busy
+    one holds the device queue's back-pressure besides its own cost."""
+    task = getattr(_witness._tls, "task", None)
+    if task is not None and task.full:
+        idle = device_idle(*args)
+        if idle is not None:
+            _witness._tls.launch = "idle" if idle else "busy"
+
+
+def hot_wrap(fn, sink=None, t0: int | None = None, **ids):
     """Mark `fn` as compute-queue hot-path work: host syncs recorded
     while it runs count as ``host_syncs_hot_path``, and the call is the
-    span ``bbtpu.task`` carrying `ids`. Returns `fn` unchanged when the
-    witness is off (zero-overhead contract)."""
+    span ``bbtpu.task`` carrying `ids`, whose ledger (the self time of
+    every span under it, by leg: :class:`_TaskSpan`) goes to `sink` as it
+    closes. Returns `fn` unchanged when the witness is off (zero-overhead
+    contract)."""
     if not enabled():
         return fn
 
     def _hot(*args, **kwargs):
         _witness._tls.hot = _witness._hot_depth() + 1
         try:
-            with _Span("bbtpu.task", ids, True):
+            with _TaskSpan(ids, sink, t0):
                 return fn(*args, **kwargs)
         finally:
             _witness._tls.hot = _witness._hot_depth() - 1

@@ -638,3 +638,55 @@ def test_chunk_stream_interleaves_queued_decodes():
         asyncio.run(run())
     finally:
         vclock.install(prev)
+
+
+def test_host_path_kinds_add_up_to_the_workers_busy_time(monkeypatch):
+    """With the witness on every task the worker ran is in `host_path` under
+    its kind, from the same clock pair as `worker.busy_ms`: the kinds' wall
+    is the busy time, a kind's legs its wall; `starved + hop + busy` still
+    cover the worker's life."""
+    from bloombee_tpu.utils import jitwatch
+
+    monkeypatch.setenv("BBTPU_JITWATCH", "1")
+    jitwatch.reset()
+
+    def work(ms):
+        with jitwatch.stopwatch("bbtpu.dispatch"):
+            with jitwatch.span("bbtpu.pack"):
+                time.sleep(ms / 1e3)
+        return ms
+
+    def run_group(payloads):
+        return [work(p) for p in payloads]
+
+    async def run():
+        q = ComputeQueue()
+        q.start()
+        await asyncio.gather(
+            q.submit(PRIORITY_INFERENCE, work, 3, task_class="decode"),
+            q.submit(PRIORITY_INFERENCE, work, 4, task_class="prefill"),
+            q.submit(PRIORITY_INFERENCE, work, 2),
+            q.submit_group(PRIORITY_INFERENCE, ("decode1", 0), 2, run_group),
+            q.submit_group(PRIORITY_INFERENCE, ("decode1", 0), 1, run_group),
+            q.submit_group(PRIORITY_INFERENCE, ("chunkm", 0), 5, run_group),
+            q.submit_group(PRIORITY_INFERENCE, ("tree", 0), 1, run_group),
+        )
+        worker, path = q.worker_stats_ms(), q.host_path()
+        await q.stop()
+        return worker, path
+
+    worker, path = asyncio.run(run())
+    jitwatch.reset()
+    assert {k: rec["n"] for k, rec in path.items()} == {
+        "decode": 2, "chunk": 2, "other": 2,
+    }
+    assert sum(rec["n"] for rec in path.values()) == worker["tasks"] == 6
+    assert sum(rec["wall_ms"] for rec in path.values()) == pytest.approx(
+        worker["busy_ms"], abs=2e-3)
+    for rec in path.values():
+        assert sum(v["wall_ms"] for v in rec["legs"].values()) == (
+            pytest.approx(rec["wall_ms"], abs=1e-5))
+        assert set(rec["legs"]) == {"bbtpu.pack", "unnamed"}
+        assert rec["cpu_ms"] <= 0.5 * rec["cpu_wall_ms"]  # it slept
+        assert rec["launches"] == 0
+    assert path["decode"]["wall_ms"] >= 6.0 and path["chunk"]["wall_ms"] >= 9.0

@@ -634,3 +634,297 @@ def test_e2e_served_steps_grow_host_spans_by_the_right_counts(
         await reg.stop()
 
     asyncio.run(run())
+
+
+# ------------------------------------------- the task's ledger (host_path)
+FULL = [n for n in range(2000) if jitwatch.read_in_full(n)]
+PLAIN = [n for n in range(2000) if not jitwatch.read_in_full(n)]
+
+
+def _run_task(body, **ids):
+    """Run `body` as one compute-queue task; returns the closed task span."""
+    got = []
+    jitwatch.hot_wrap(body, got.append, **ids)()
+    return got[0]
+
+
+def test_task_ledger_keeps_self_time_and_legs_sum_to_the_nanosecond(watch_on):
+    """A span's leg gets its duration less what its direct children cover;
+    the legs sum to the task's wall, and to its CPU, exactly."""
+    import time
+
+    held = {}
+
+    def body():
+        with jitwatch.stopwatch("bbtpu.dispatch", session="s") as outer:
+            with jitwatch.span("bbtpu.pack") as pack:
+                with jitwatch.span("bbtpu.step", kind="decode") as step:
+                    sum(range(2000))
+                sum(range(2000))
+            with jitwatch.span("bbtpu.slice") as cut:
+                pass
+            time.sleep(0.002)
+        held.update(outer=outer, pack=pack, step=step, cut=cut)
+
+    task = _run_task(body, task=FULL[1], **{"class": "decode"})
+    spans = task.spans
+    assert spans["bbtpu.step"][:3] == [1, held["step"].ns, held["step"].ns]
+    assert spans["bbtpu.pack"][2] == held["pack"].ns - held["step"].ns
+    assert spans["bbtpu.dispatch"][2] == (
+        held["outer"].ns - held["pack"].ns - held["cut"].ns
+    )
+    assert spans["bbtpu.task"][2] == task.ns - held["outer"].ns
+    legs = jitwatch.legs_of(task.spans)
+    assert set(legs) == {"unnamed", "bbtpu.pack", "bbtpu.step", "bbtpu.slice"}
+    assert legs["unnamed"][0] == (
+        spans["bbtpu.task"][2] + spans["bbtpu.dispatch"][2]
+    )
+    assert sum(w for w, _ in legs.values()) == task.ns
+    assert task.full and sum(c for _, c in legs.values()) == task.cpu_ns
+    assert legs["unnamed"][0] >= 2_000_000  # the sleep lies under no leg
+    # the same spans reached the witness's sums as the task closed
+    assert jitwatch.host_spans()["bbtpu.pack"]["n"] == 1
+    assert jitwatch.host_spans()["bbtpu.task"]["total_ms"] == round(
+        task.ns / 1e6, 3
+    )
+
+
+def test_task_ledger_reads_cpu_beside_wall_and_a_sleep_is_off_cpu(watch_on):
+    import time
+
+    def body():
+        with jitwatch.span("bbtpu.pack"):
+            t0 = time.perf_counter()
+            while time.perf_counter() - t0 < 0.01:
+                pass
+        with jitwatch.span("bbtpu.h2d"):
+            time.sleep(0.02)
+
+    task = _run_task(body, task=FULL[2])
+    assert task.full
+    # a span's CPU pair is read inside its wall pair; a SELF time is a
+    # difference of such pairs, so its CPU may pass its wall by the clocks'
+    # own cost (microseconds), never by more
+    for name, (_, wall, own, own_cpu) in task.spans.items():
+        assert 0 <= own <= wall and 0 <= own_cpu <= own + 50_000, (
+            name, task.spans)
+    assert task.cpu_ns <= task.ns
+    _, _, busy, busy_cpu = task.spans["bbtpu.pack"]
+    _, _, slept, slept_cpu = task.spans["bbtpu.h2d"]
+    assert busy_cpu >= 0.5 * busy  # spinning: on the CPU
+    assert slept >= 20_000_000 and slept_cpu <= 0.1 * slept  # off it
+
+
+def test_one_task_in_32_is_read_in_full(watch_on, monkeypatch):
+    """`read_in_full` picks about one task number in 32, spread so that no
+    short period meets it; only such a task reads the CPU clock (a pair a
+    span) and asks the device at a launch, and the account keeps the wall
+    of THOSE tasks beside their CPU."""
+    import time
+
+    from bloombee_tpu.server.compute_queue import _WorkerAccount
+
+    assert jitwatch.read_in_full(0)  # a task nobody numbered
+    assert 40 <= len(FULL) <= 90  # of 2000
+    for period in (2, 3, 4, 5, 8, 16, 32):
+        assert len({n % period for n in FULL}) == period, period
+
+    reads, asked = [], []
+    real = time.thread_time_ns
+
+    class _Clock:
+        perf_counter_ns = staticmethod(time.perf_counter_ns)
+
+        @staticmethod
+        def thread_time_ns():
+            reads.append(1)
+            return real()
+
+    monkeypatch.setattr(jitwatch, "time", _Clock)
+
+    def body():
+        with jitwatch.stopwatch("bbtpu.dispatch"):
+            with jitwatch.span("bbtpu.pack"):
+                sum(range(20000))
+            jitwatch.launch(lambda: asked.append(1) or True)
+            with jitwatch.region("span_step_packed", "b1,t1,p4"):
+                pass
+
+    account = _WorkerAccount()
+    numbers = PLAIN[:30] + FULL[1:3]
+    for number in numbers:
+        del reads[:]
+        account.wrap(body, 0, task=number, kinds="decode1")()
+        # four spans, a pair each, or none
+        assert len(reads) == (8 if jitwatch.read_in_full(number) else 0)
+    assert len(asked) == 2
+    rec = account.host_path()["decode"]
+    assert (rec["n"], rec["full_n"]) == (32, 2)
+    assert (rec["launches"], rec["launches_on_idle"]) == (2, 2)
+    assert 0 < rec["cpu_ms"] <= rec["cpu_wall_ms"] < 0.2 * rec["wall_ms"]
+    pack = rec["legs"]["bbtpu.pack"]
+    assert 0 < pack["cpu_wall_ms"] < 0.2 * pack["wall_ms"]  # 2 tasks of 32
+    assert 0.5 * pack["cpu_wall_ms"] <= pack["cpu_ms"] <= (
+        pack["cpu_wall_ms"] + 0.05)
+    assert rec["jit_idle_ms"] <= rec["legs"]["jit_call"]["cpu_wall_ms"] + 1e-6
+    walls = sum(v["wall_ms"] for v in rec["legs"].values())
+    assert walls == pytest.approx(rec["wall_ms"], abs=1e-5)
+
+
+def test_a_span_on_another_thread_adds_nothing_to_the_task(watch_on):
+    import threading
+
+    def elsewhere():
+        with jitwatch.span("bbtpu.fetch") as sp:
+            pass
+        assert sp._task is None and sp.cpu_ns == 0
+
+    def body():
+        t = threading.Thread(target=elsewhere)
+        t.start()
+        t.join()
+
+    task = _run_task(body, task=FULL[1])
+    assert set(task.spans) == {"bbtpu.task"}
+    assert jitwatch.host_spans()["bbtpu.fetch"]["n"] == 1
+    # and a span outside any task reads no CPU clock
+    with jitwatch.span("bbtpu.pack") as sp:
+        pass
+    assert sp._task is None and sp.cpu_ns == 0
+
+
+def test_jit_regions_fold_to_one_leg_and_carry_the_launch_bit(watch_on):
+    """Every `bbtpu.jit.*` is the leg `jit_call`; `launch` says what the
+    device was doing, the next region carries it as the id `device`, and
+    the task splits the leg's wall by it."""
+    regions = []
+
+    def body():
+        jitwatch.launch(lambda: True)
+        with jitwatch.region("span_step_packed", "b1,t1,p4") as reg:
+            pass
+        regions.append(reg)
+        jitwatch.launch(bool, 0)
+        with jitwatch.region("span_step_ragged", "r8,s2,p4") as reg:
+            pass
+        regions.append(reg)
+        with jitwatch.region("decode_loop", "b1,n4,p4") as reg:
+            pass  # nobody said: counted in the leg, in no launch
+        regions.append(reg)
+
+    task = _run_task(body, task=FULL[1], kinds="chunkm+decode1")
+    idle, busy, unsaid = regions
+    assert idle.ids["device"] == "idle" and busy.ids["device"] == "busy"
+    assert "device" not in unsaid.ids
+    assert "," not in idle.ids["device"] and ";" not in idle.ids["device"]
+    assert task.launches == [2, 1, idle.ns, busy.ns]
+    legs = jitwatch.legs_of(task.spans)
+    assert set(legs) == {"unnamed", "jit_call"}
+    assert legs["jit_call"][0] == idle.ns + busy.ns + unsaid.ns
+
+
+@pytest.mark.parametrize("ids, kind", [
+    ({"class": "decode"}, "decode"),
+    ({"class": "prefill"}, "chunk"),
+    ({"class": ""}, "other"),
+    ({}, "other"),
+    ({"kinds": "decode1"}, "decode"),
+    ({"kinds": "chunkm"}, "chunk"),
+    ({"kinds": "chunkm+decode1"}, "fused"),
+    ({"kinds": "chunkm+decode1+tree"}, "fused"),
+    ({"kinds": "tree"}, "other"),
+    ({"kinds": "decode1+tree"}, "other"),
+])
+def test_task_kind_by_class_and_kinds(ids, kind):
+    from bloombee_tpu.server.compute_queue import task_kind
+
+    assert task_kind(ids) == kind
+
+
+class _NeverAsked:
+    """An arena slab that fails the test if anybody asks about it."""
+
+    size = 8
+
+    def is_ready(self):
+        raise AssertionError("is_ready() asked with the witness off")
+
+
+def test_witness_off_contract_no_cpu_clock_no_is_ready_no_account(monkeypatch):
+    """BBTPU_JITWATCH unset: `hot_wrap(fn) is fn`, the account wraps
+    nothing and `host_path` is {}, no span reads the thread's CPU clock
+    (nor does a stopwatch), and a dispatch never asks the arena whether it
+    is ready."""
+    from bloombee_tpu.runtime.executor import SpanExecutor
+    from bloombee_tpu.server.compute_queue import ComputeQueue, _WorkerAccount
+
+    monkeypatch.delenv("BBTPU_JITWATCH", raising=False)
+
+    class _Clock:
+        perf_counter_ns = staticmethod(jitwatch.time.perf_counter_ns)
+
+        @staticmethod
+        def thread_time_ns():
+            raise AssertionError("thread_time_ns read with the witness off")
+
+    monkeypatch.setattr(jitwatch, "time", _Clock)
+
+    def fn():
+        with jitwatch.stopwatch("bbtpu.dispatch") as sw:
+            with jitwatch.span("bbtpu.pack"):
+                jitwatch.launch(_NeverAsked().is_ready)
+                with jitwatch.region("span_step_packed", "b1,t1,p4"):
+                    pass
+        return sw.ns
+
+    assert jitwatch.hot_wrap(fn, lambda task: None, task=1) is fn
+    account = _WorkerAccount()
+    assert account.wrap(fn, 0, task=1, kinds="decode1") is fn
+    assert fn() > 0
+    assert account.host_path() == {}
+
+    async def empty():
+        return ComputeQueue().host_path()
+
+    assert asyncio.run(empty()) == {}
+    result, used = SpanExecutor._dispatch(
+        SpanExecutor, lambda kernel: "out", False, {"k": _NeverAsked()},
+        "test",
+    )
+    assert (result, used) == ("out", False)
+    assert jitwatch.host_spans() == {}
+
+
+def test_witness_on_a_dispatch_asks_the_first_slab_that_holds_bytes(watch_on):
+    """With the witness on `_dispatch` asks the arena it donates, once,
+    before the call; a K/V arena of no rows asks the state arena."""
+    from bloombee_tpu.runtime.executor import SpanExecutor
+
+    asked = []
+
+    class _Slab:
+        def __init__(self, name, size, ready):
+            self.name, self.size, self.ready = name, size, ready
+
+        def is_ready(self):
+            asked.append(self.name)
+            return self.ready
+
+    def run(_kernel):
+        with jitwatch.region("span_step_packed", "b1,t1,p4") as reg:
+            pass
+        return reg
+
+    def body():
+        arenas = (
+            {"k": _Slab("k", 8, False), "v": _Slab("v", 8, False)},
+            {"k": _Slab("k0", 0, True), "v": _Slab("v0", 0, True),
+             "state": _Slab("state", 4, True)},
+        )
+        return [SpanExecutor._dispatch(SpanExecutor, run, False, a, "test")[0]
+                for a in arenas]
+
+    got = []
+    jitwatch.hot_wrap(lambda: got.extend(body()), task=FULL[1])()
+    assert asked == ["k", "state"]
+    assert [r.ids["device"] for r in got] == ["busy", "idle"]

@@ -101,6 +101,54 @@ def test_prefill_then_decode_matches_dense(setup):
     asyncio.run(run())
 
 
+def test_host_path_of_a_served_chunk_and_decode_step(setup, monkeypatch):
+    """A chunk and a decode step run as compute-queue tasks with the witness
+    on: `host_path` holds one task and ONE launch a kind, the launch said what
+    the device was doing (the region carries `device`), the legs sum to the
+    task's wall and the unnamed rest is under the sum of the named legs.
+    (Task number 0 is one of those read in full: CPU and launches.)"""
+    from bloombee_tpu.server.compute_queue import _WorkerAccount
+    from bloombee_tpu.utils import jitwatch
+
+    monkeypatch.setenv("BBTPU_JITWATCH", "1")
+    jitwatch.reset()
+    model, config, spec, params = setup
+    torch.manual_seed(5)
+    hidden = torch.randn(1, 9, config.hidden_size).numpy()
+    manager, ex = make_executor(spec, params)
+    account = _WorkerAccount()
+
+    async def run():
+        async with manager.allocate(1, 32) as handle:
+            account.wrap(
+                lambda: ex.prefill_chunk(
+                    handle, hidden[:, :8], commit=True, fetch=False),
+                0, task=0, **{"class": "prefill"},
+            )()
+            account.wrap(
+                lambda: ex.decode(handle, hidden[:, 8:9], fetch=False),
+                0, task=0, kinds="decode1",
+            )()
+
+    asyncio.run(run())
+    path = account.host_path()
+    assert set(path) == {"chunk", "decode"}
+    for kind, rec in path.items():
+        assert (rec["n"], rec["launches"]) == (1, 1), (kind, rec)
+        legs = rec["legs"]
+        assert {"bbtpu.pack", "bbtpu.h2d", "jit_call", "bbtpu.counters",
+                "bbtpu.slice", "unnamed"} <= set(legs), legs
+        walls = {leg: v["wall_ms"] for leg, v in legs.items()}
+        assert sum(walls.values()) == pytest.approx(rec["wall_ms"], abs=1e-5)
+        assert walls["unnamed"] < sum(walls.values()) - walls["unnamed"]
+        assert rec["jit_idle_ms"] + rec["jit_busy_ms"] == pytest.approx(
+            walls["jit_call"], abs=1e-5)
+        assert rec["full_n"] == 1 and rec["cpu_ms"] <= rec["cpu_wall_ms"]
+    assert sum(r["wall_ms"] for r in path.values()) == pytest.approx(
+        account.stats_ms()["busy_ms"], abs=2e-3)
+    jitwatch.reset()
+
+
 def test_chunked_prefill_matches(setup):
     model, config, spec, params = setup
     b, total = 1, 11
