@@ -155,10 +155,12 @@ def conv_taps(
 #
 # The decay differs by channel AND state column, so the SSD chunk form above
 # (a scalar decay a head) does not apply: `mamba1_chunk` walks the tokens, the
-# state read and written once (ops/pallas/selective_scan.py is the same walk
-# with S resident in VMEM). S and A are kept STATE-major [N, C]: the channels
-# lie along the lanes, B_t and C_t broadcast along them. A row with dt == 0
-# neither decays nor feeds S, exactly as `ssm_step`'s.
+# state read and written once (ops/pallas/selective_scan.py is the same
+# recurrence with S carried in registers: a tile of 8 tokens a turn, a
+# token's channels over sublanes and lanes, B_t and C_t as scalars). S and A
+# are kept STATE-major [N, C] here: the channels lie along the lanes, B_t and
+# C_t broadcast along them. A row with dt == 0 neither decays nor feeds S,
+# exactly as `ssm_step`'s.
 
 
 def mamba1_step(

@@ -1073,6 +1073,10 @@ def test_phi4flash_span_step_compiles_and_copies_no_parameter(v5e, program):
     assert not re.findall(r"copy\([^)\n]*%(arena_[kv]|state__ssm)", text)
     assert "tpu_custom_call" in text
     assert ("jit(selective_scan)" in text) == (program != "decode")
+    # B and C reach the scan kernel as flat [T * N] scalars (PR 59): no
+    # [T, N, 1] column array, a 128-lane row an element, in front of the call
+    columns = re.findall(rf"f32\[\d+,{spec.mamba.state},1\]", text)
+    assert not columns, set(columns)
     slab = arena.shape[1] * arena.shape[2]
     assert not _slab_moves(text, slab), _slab_moves(text, slab)
     temp = compiled.memory_analysis().temp_size_in_bytes

@@ -136,33 +136,64 @@ def _token_by_token(x, dt, a_t, b, c, d, s):
     return jnp.stack(ys), s
 
 
-@pytest.mark.parametrize("t", [5, 64, 200])
+# (rows, channels, the kernel's block_c; None: `scan_block_c`'s own tile)
+_SCAN_CASES = {
+    "t5": (5, 256, 128), "t64": (64, 256, 128), "t200": (200, 256, 128),
+    # PR 59: the tail program's one tile of 8 rows, 5 real; a pad inside the
+    # last tile; four token blocks at the rule's own tile, a register a
+    # state column a quarter and a half full
+    "t5-rule": (5, 256, None), "t13-rule": (13, 256, None),
+    "t512-c256-rule": (512, 256, None), "t512-c512-rule": (512, 512, None),
+}
+
+
+@pytest.mark.parametrize("case", list(_SCAN_CASES))
 @pytest.mark.parametrize("form", ["chunk", "kernel"])
-def test_scan_forms_equal_the_token_loop(form, t):
+def test_scan_forms_equal_the_token_loop(form, case):
     """`mamba1_chunk` and the Pallas kernel (interpret mode; 200 rows cross a
     token block and end in a padded one) against one recurrence step a
-    token, from a state that is not empty."""
-    args = _scan_inputs(t, t)
-    want_y, want_s = _token_by_token(*args)
+    token, from a state that is not empty; the kernel's state after the last
+    row against `mamba1_chunk`'s too."""
+    t, c, block_c = _SCAN_CASES[case]
+    args = _scan_inputs(t, t, c)
+    chunk_y, chunk_s = mamba1_chunk(*args)
+    # (512 rows: the chunk form, held to the token loop by the cases above)
+    want_y, want_s = _token_by_token(*args) if t <= 200 else (chunk_y, chunk_s)
     if form == "chunk":
-        y, s = mamba1_chunk(*args)
+        y, s = chunk_y, chunk_s
     else:
-        y, s = selective_scan(*args, block_c=128, interpret=True)
+        y, s = selective_scan(*args, block_c=block_c, interpret=True)
+        np.testing.assert_allclose(s, chunk_s, rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(y, want_y, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(s, want_s, rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("form", ["chunk", "kernel"])
-def test_rows_with_dt_zero_are_inert(form):
-    """dt = 0 rows (a bucket's tail, a padding row) neither decay nor feed
-    the state, wherever they lie."""
+# dt == 0 rows of 24: a bucket's tail; rows in the MIDDLE of a tile; a whole
+# tile of them between two live ones
+_INERT_ROWS = {
+    "tail": list(range(16, 24)), "mid-tile": [2, 3, 5, 11],
+    "whole-tile": list(range(8, 16)),
+}
+
+
+@pytest.mark.parametrize("where", list(_INERT_ROWS))
+@pytest.mark.parametrize("form", ["chunk", "kernel", "kernel-rule"])
+def test_rows_with_dt_zero_are_inert(form, where):
+    """dt = 0 rows (a bucket's tail, a padding row, a fused pack's rows past
+    a sequence's own) neither decay nor feed the state, wherever they lie:
+    the state after them is the state of the live rows alone."""
     x, dt, a_t, b, c, d, s0 = _scan_inputs(7, 24)
-    dt = dt.at[16:].set(0.0)
+    dead = np.asarray(_INERT_ROWS[where])
+    live = np.setdiff1d(np.arange(24), dead)
+    dt = dt.at[dead].set(0.0)
     fn = mamba1_chunk if form == "chunk" else (
-        lambda *a: selective_scan(*a, block_c=128, interpret=True))
-    _, s_padded = fn(x, dt, a_t, b, c, d, s0)
-    _, s_real = mamba1_chunk(x[:16], dt[:16], a_t, b[:16], c[:16], d, s0)
+        lambda *a: selective_scan(
+            *a, block_c=128 if form == "kernel" else None, interpret=True))
+    y_padded, s_padded = fn(x, dt, a_t, b, c, d, s0)
+    y_real, s_real = mamba1_chunk(
+        x[live], dt[live], a_t, b[live], c[live], d, s0)
     np.testing.assert_allclose(s_padded, s_real, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(y_padded[live], y_real, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize("window", [0, 40, 128])
